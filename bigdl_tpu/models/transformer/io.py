@@ -37,8 +37,7 @@ from typing import Dict
 import numpy as np
 import jax.numpy as jnp
 
-from bigdl_tpu.utils.torch_import import (_to_numpy,
-                                          chunked_device_array)
+from bigdl_tpu.utils.torch_import import _to_numpy, device_array
 
 
 def load_gpt2_state_dict(model, state_dict) -> "TransformerLM":
@@ -70,7 +69,7 @@ def load_gpt2_state_dict(model, state_dict) -> "TransformerLM":
                              f"model {tuple(expect_shape)}")
         return a.astype(np.float32)
 
-    params["embed"] = chunked_device_array(
+    params["embed"] = device_array(
         take("wte.weight", (model.vocab_size, h)))
     if model.pos_encoding != "learned":
         raise ValueError("GPT-2 checkpoints carry learned positions — "
@@ -83,7 +82,7 @@ def load_gpt2_state_dict(model, state_dict) -> "TransformerLM":
     if wpe.shape[0] < model.max_len:
         raise ValueError(f"checkpoint wpe covers {wpe.shape[0]} positions "
                          f"< model max_len {model.max_len}")
-    params["pos"] = chunked_device_array(wpe[:model.max_len])
+    params["pos"] = device_array(wpe[:model.max_len])
 
     blocks: Dict[str, list] = {}
 
@@ -115,14 +114,14 @@ def load_gpt2_state_dict(model, state_dict) -> "TransformerLM":
         d = stacked
         for key in path[:-1]:
             d = d.setdefault(key, {})
-        d[path[-1]] = chunked_device_array(np.stack(per_layer))
+        d[path[-1]] = device_array(np.stack(per_layer))
     params["blocks"] = stacked
 
     params["ln_f"] = {"weight": jnp.asarray(take("ln_f.weight", (h,))),
                       "bias": jnp.asarray(take("ln_f.bias", (h,)))}
     if not model.tie_embeddings:
         head = take("lm_head.weight", (model.vocab_size, h))
-        params["head"] = chunked_device_array(np.ascontiguousarray(head.T))
+        params["head"] = device_array(np.ascontiguousarray(head.T))
     elif "lm_head.weight" in sd:
         # a fine-tuned checkpoint may have UNTIED its head; silently
         # substituting wte for a diverged lm_head would change the

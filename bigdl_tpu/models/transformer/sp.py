@@ -16,13 +16,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-try:  # newer jax: top-level alias; vma checking handles the flash kernel
-    from jax import shard_map
-    _SHARD_MAP_COMPAT = {}
-except ImportError:  # older jax: check_rep has no pallas/cond rules
-    from jax.experimental.shard_map import shard_map
-    _SHARD_MAP_COMPAT = {"check_rep": False}
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from bigdl_tpu.models.transformer import TransformerLM
@@ -184,6 +178,5 @@ def _sequence_parallel_apply(model, params, ids, mesh, *, seq_axis,
     io_spec = P(data_axis, seq_axis)
     fn = shard_map(local_fwd, mesh=mesh,
                    in_specs=(P(), io_spec),
-                   out_specs=P(data_axis, seq_axis, None),
-                   **_SHARD_MAP_COMPAT)
+                   out_specs=P(data_axis, seq_axis, None))
     return fn(params, ids)

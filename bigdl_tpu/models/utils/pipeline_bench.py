@@ -1,5 +1,5 @@
 """Input-pipeline-fed ResNet-50 bench: prove the host path can feed the
-chip (VERDICT r2 #5; ref dataset/DataSet.scala:380-433 SequenceFile
+chip (ref dataset/DataSet.scala:380-433 SequenceFile
 ImageNet path + MTLabeledBGRImgToBatch.scala:52-80 threaded host decode).
 
     python -m bigdl_tpu.models.utils.pipeline_bench --batch 256 --iters 20
@@ -239,7 +239,6 @@ def run_host_only(batch: int, iters: int, warmup: int, workdir: str,
         sink += int(x[:, ::32, ::32].sum()) + int(y.sum())
     dt = time.perf_counter() - t0
     ips = batch * iters / dt
-    chip_rate = 2103.66  # BENCH_r01.json, images/sec/chip
     from bigdl_tpu import native
     return {
         "metric": "input_pipeline_host_delivery_images_per_sec",
@@ -247,8 +246,6 @@ def run_host_only(batch: int, iters: int, warmup: int, workdir: str,
         "unit": "images/sec (host only, no device step)",
         "batch": batch, "iterations": iters, "stored_records": n_records,
         "native_batcher": native.get() is not None,
-        "chip_consumption_rate_r1": chip_rate,
-        "headroom_vs_r1_chip_rate": round(ips / chip_rate, 3),
         "checksum": sink % 1000,
     }
 
@@ -270,7 +267,7 @@ def main(argv=None) -> None:
 
     from bigdl_tpu.utils.engine import Engine
 
-    Engine.init()  # honors BIGDL_TPU_PLATFORM, like the sibling benches
+    Engine.init()  # the platform is JAX_PLATFORMS', like the sibling benches
 
     workdir = args.workdir or tempfile.mkdtemp(prefix="bigdl_tpu_pipebench_")
     cleanup = args.workdir is None

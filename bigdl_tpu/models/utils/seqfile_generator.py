@@ -68,7 +68,7 @@ def generate(folder: str, output: str, parallel: int,
         # thread the encode/write across the host pool (the role the
         # reference's Spark job played for SequenceFile generation)
         if not Engine.is_initialized():
-            Engine.init()  # honors BIGDL_TPU_PLATFORM internally
+            Engine.init()
         written = Engine.default().invoke_and_wait(
             [lambda i=i: write_one(i) for i in range(n_shards)])
         assert sum(written) == len(records)

@@ -26,8 +26,8 @@ Six pieces, one spine:
   a classified backend-lost, a fault-injector fire, or a shed burst,
   atomically dump ONE correlated bundle (last spans + time-series
   window + ``Engine.diagnose_tpu()`` + serving state + active request
-  ids) to ``FLIGHT_<ts>.json`` and append a pointer into
-  ``TUNNEL_INCIDENTS.json``.  Armed via ``BIGDL_TPU_FLIGHT=1``.
+  ids) to ``FLIGHT_<ts>.json`` and append a pointer into the incident
+  ledger.  Armed via ``BIGDL_TPU_FLIGHT=1``.
 - :mod:`~bigdl_tpu.obs.watchdog` — StallWatchdog: rolling-median step
   cadence; a hung step captures ``Engine.diagnose_tpu()`` + all-thread
   stacks into the trace before the process looks merely "slow".
@@ -69,7 +69,7 @@ from bigdl_tpu.obs.watchdog import (StallWatchdog, env_watchdog_enabled,
 # Flight names resolve lazily (PEP 562): an eager `from ...flight
 # import` here would put bigdl_tpu.obs.flight in sys.modules before
 # runpy executes it, so every `python -m bigdl_tpu.obs.flight dump`
-# (chip_opportunist's incident recorder) logged a RuntimeWarning about
+# (an external incident recorder) logged a RuntimeWarning about
 # the double import.  Everything else in the tree already imports
 # flight lazily; the package facade now does too.
 _FLIGHT_NAMES = ("FlightRecorder", "get_flight_recorder", "note_shed")

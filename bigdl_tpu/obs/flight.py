@@ -12,13 +12,13 @@ all of it at the moment of the incident into one atomically-written
   incident) and the active request ids;
 - the time-series window from the process sampler (the time axis
   around the incident), when one is installed;
-- ``Engine.diagnose_tpu()`` — the port-level tunnel state, safe to
-  read while wedged;
+- ``Engine.diagnose_tpu()`` — stale chip holders and memory-ledger
+  state, safe to read while wedged;
 - registered state providers (BlockPool/placement/spec stats,
   ReplicaSet circuit states, …) — engines register themselves at
   init, latest owner wins, and a provider that raises contributes its
   error string instead of killing the dump;
-- a pointer row appended into ``TUNNEL_INCIDENTS.json`` through
+- a pointer row appended into the incident ledger through
   ``traffic.incidents`` so the incident ledger and the bundle
   cross-reference each other.
 
@@ -32,7 +32,7 @@ incident-heavy soak can never grow the directory without bound.
 fault-matrix sweep collapses to its first bundle per site instead of a
 bundle per occurrence.
 
-CLI (what ``chip_opportunist.sh`` calls on a probe/stage death)::
+CLI (for a probe loop to call on a probe/stage death)::
 
     python -m bigdl_tpu.obs.flight dump <stage> <rc> [--dir DIR]
 
@@ -248,9 +248,9 @@ class FlightRecorder:
                                  path: str) -> None:
         try:
             from bigdl_tpu.traffic import incidents
-            # a CLI dump carries the opportunist's stage/rc verbatim so
-            # the ledger row looks exactly like the old bare append
-            # (plus the pointer); in-process triggers self-name
+            # a CLI dump carries the caller's stage/rc verbatim so the
+            # ledger row looks exactly like a bare append (plus the
+            # pointer); in-process triggers self-name
             stage = f"flight/{kind}"
             rc = 0
             if isinstance(detail, dict):

@@ -24,6 +24,7 @@ from __future__ import annotations
 
 import bisect
 import logging
+import numbers
 import os
 import threading
 from typing import Callable, Dict, List, Optional
@@ -333,7 +334,9 @@ class MetricRegistry:
         wrote = 0
         for name, snap in self.snapshot().items():
             if "value" in snap:
-                if snap["value"] is not None:
+                # label-valued gauges (e.g. spec/compute_mode = "int8")
+                # have no place in a scalar stream: numbers only
+                if isinstance(snap["value"], numbers.Real):
                     summary.add_scalar(prefix + name, float(snap["value"]),
                                        step)
                     wrote += 1

@@ -81,8 +81,8 @@ def _fwd_kernel(*refs, scale: float, causal: bool, segmented: bool,
         if segmented:
             # packed-document isolation: a query attends only within its
             # own segment (pad fills -1/-2 can never match)
-            mask = jnp.logical_and(
-                mask, sq_ref[0][:, None] == sk_ref[0][None, :])
+            # (sq is a (block_q, 1) column, sk a (1, block_k) row)
+            mask = jnp.logical_and(mask, sq_ref[0] == sk_ref[0])
         s = jnp.where(mask, s, _NEG)
         m_prev = m_ref[:, :1]
         l_prev = l_ref[:, :1]
@@ -101,8 +101,12 @@ def _fwd_kernel(*refs, scale: float, causal: bool, segmented: bool,
         l = l_ref[:, :1]
         l_safe = jnp.where(l == 0.0, 1.0, l)
         o_ref[0, 0] = (acc_ref[:] / l_safe).astype(o_ref.dtype)
-        lse_ref[0, 0] = (m_ref[:, 0] + jnp.log(l_safe[:, 0])).astype(
-            jnp.float32)
+        # lse leaves as a (1, block_q) ROW of the (B, H, 1, Tq) output:
+        # one transpose of the lane-replicated (block_q, 128) statistic
+        # per query block, instead of a 1-D store Mosaic cannot lay out
+        l_all = l_ref[:]
+        lse = m_ref[:] + jnp.log(jnp.where(l_all == 0.0, 1.0, l_all))
+        lse_ref[0, 0] = lse.T[:1].astype(jnp.float32)
 
 
 def _sds(shape, dtype, *like):
@@ -111,12 +115,8 @@ def _sds(shape, dtype, *like):
     over — e.g. replicated q with sequence-sharded k/v), so the kernel
     works inside shard_map (check_vma) and outside it."""
     vma = frozenset()
-    # jax.typeof is newer than 0.4.x; without it there is no vma concept
-    # (shard_map check_vma came with it) so a plain struct is correct
-    typeof = getattr(jax, "typeof", None)
-    if typeof is not None:
-        for x in like:
-            vma = vma | (getattr(typeof(x), "vma", None) or frozenset())
+    for x in like:
+        vma = vma | (jax.typeof(x).vma or frozenset())
     if vma:
         return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
     return jax.ShapeDtypeStruct(shape, dtype)
@@ -140,10 +140,32 @@ def _pad_seg(seg, block, fill):
     return jnp.pad(seg, [(0, 0), (0, block - rem)], constant_values=fill)
 
 
+def _check_compiled_blocks(block_q: int, block_k: int) -> None:
+    """The compiled (Mosaic) kernels tile per-row statistics and segment
+    ids along LANES, so both block sizes must be multiples of 128; the
+    interpreter takes any size (the tests' small tiles)."""
+    if block_q % _LANES or block_k % _LANES:
+        raise ValueError(
+            f"flash attention on TPU needs block_q and block_k to be "
+            f"multiples of {_LANES} (got block_q={block_q}, "
+            f"block_k={block_k})")
+
+
+# Layout of the small per-row operands (what Mosaic's block-shape rule
+# allows: the last two block dims are the array's own or 8/128-aligned):
+#   a ROW    is (..., 1, T) blocked (..., 1, block): lane-major, no padding
+#   a COLUMN is (..., T, 1) blocked (..., block, 1): one value per sublane
+# lse / delta travel as rows (they are H x T f32, a column layout would
+# pad each value to a 128-lane tile in HBM); segment ids travel in
+# whichever form the kernel's score orientation broadcasts directly.
+
+
 @functools.partial(jax.jit, static_argnames=(
     "causal", "scale", "block_q", "block_k", "interpret"))
 def _flash_fwd(q, k, v, seg_q, seg_k, causal, scale, block_q, block_k,
                interpret):
+    if not interpret:
+        _check_compiled_blocks(block_q, block_k)
     b, h, tq, d = q.shape
     tk = k.shape[2]
     segmented = seg_q is not None
@@ -167,11 +189,14 @@ def _flash_fwd(q, k, v, seg_q, seg_k, causal, scale, block_q, block_k,
     operands = [qp, kp, vp]
     if segmented:
         in_specs += [
-            pl.BlockSpec((1, block_q), lambda bi, hi, qi, ki: (bi, qi)),
-            pl.BlockSpec((1, block_k), lambda bi, hi, qi, ki: (bi, ki)),
+            pl.BlockSpec((1, block_q, 1),             # query ids: column
+                         lambda bi, hi, qi, ki: (bi, qi, 0)),
+            pl.BlockSpec((1, 1, block_k),             # key ids: row
+                         lambda bi, hi, qi, ki: (bi, 0, ki)),
         ]
-        operands += [_pad_seg(seg_q.astype(jnp.int32), block_q, -1),
-                     _pad_seg(seg_k.astype(jnp.int32), block_k, -2)]
+        operands += [
+            _pad_seg(seg_q.astype(jnp.int32), block_q, -1)[:, :, None],
+            _pad_seg(seg_k.astype(jnp.int32), block_k, -2)[:, None, :]]
     o, lse = pl.pallas_call(
         kernel,
         grid=(b, h, n_q, n_k),  # j innermost: scratch accumulates over it
@@ -179,12 +204,12 @@ def _flash_fwd(q, k, v, seg_q, seg_k, causal, scale, block_q, block_k,
         out_specs=[
             pl.BlockSpec((1, 1, block_q, d),
                          lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-            pl.BlockSpec((1, 1, block_q),
-                         lambda bi, hi, qi, ki: (bi, hi, qi)),
+            pl.BlockSpec((1, 1, 1, block_q),
+                         lambda bi, hi, qi, ki: (bi, hi, 0, qi)),
         ],
         out_shape=[
             _sds((b, h, tq_pad, d), q.dtype, q, k, v),
-            _sds((b, h, tq_pad), jnp.float32, q, k, v),
+            _sds((b, h, 1, tq_pad), jnp.float32, q, k, v),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, d), jnp.float32),       # acc
@@ -192,8 +217,31 @@ def _flash_fwd(q, k, v, seg_q, seg_k, causal, scale, block_q, block_k,
             pltpu.VMEM((block_q, _LANES), jnp.float32),  # running sum
         ],
         interpret=interpret,
+        name="flash_attention_fwd",
     )(*operands)
-    return o[:, :, :tq], lse[:, :, :tq]
+    return o[:, :, :tq], lse[:, :, 0, :tq]
+
+
+def _bwd_probs_t(q, kb, lse_row, sq_ref, sk_ref, *, scale, causal,
+                 segmented, tq_real, tk_real, q0, k0, block_q, block_k):
+    """Recompute the TRANSPOSED probability tile p^T (block_k, block_q)
+    from the saved logsumexp.  Keys on sublanes, queries on lanes: the
+    per-query scalars (lse, delta) arrive as (1, block_q) rows and
+    broadcast down the sublanes with no relayout."""
+    st = jax.lax.dot_general(
+        kb, q, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale
+    k_pos = k0 + jax.lax.broadcasted_iota(
+        jnp.int32, (block_k, block_q), 0)
+    q_pos = q0 + jax.lax.broadcasted_iota(
+        jnp.int32, (block_k, block_q), 1)
+    mask = jnp.logical_and(q_pos < tq_real, k_pos < tk_real)
+    if causal:
+        mask = jnp.logical_and(mask, q_pos + (tk_real - tq_real) >= k_pos)
+    if segmented:
+        # sk is a (block_k, 1) column, sq a (1, block_q) row
+        mask = jnp.logical_and(mask, sk_ref[0] == sq_ref[0])
+    return jnp.where(mask, jnp.exp(st - lse_row), 0.0)
 
 
 def _bwd_dkv_kernel(*refs, scale: float, causal: bool, segmented: bool,
@@ -203,10 +251,10 @@ def _bwd_dkv_kernel(*refs, scale: float, causal: bool, segmented: bool,
     dk/dv pair accumulates in VMEM scratch while (block_q, d) q/do tiles
     stream past — the mirror image of the forward's streaming direction."""
     if segmented:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dlse_ref,
+        (q_ref, k_ref, v_ref, do_ref, lse_ref, rest_ref,
          sq_ref, sk_ref, dk_ref, dv_ref, dk_acc, dv_acc) = refs
     else:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dlse_ref,
+        (q_ref, k_ref, v_ref, do_ref, lse_ref, rest_ref,
          dk_ref, dv_ref, dk_acc, dv_acc) = refs
         sq_ref = sk_ref = None
     ik = pl.program_id(2)
@@ -232,25 +280,18 @@ def _bwd_dkv_kernel(*refs, scale: float, causal: bool, segmented: bool,
         kb = k_ref[0, 0]
         vb = v_ref[0, 0]
         do = do_ref[0, 0]
-        lse = lse_ref[0, 0][:, None]
-        rest = (delta_ref[0, 0] - dlse_ref[0, 0])[:, None]
-        s = jnp.dot(q, kb.T, preferred_element_type=jnp.float32) * scale
-        q_pos = iq * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        k_pos = ik * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        mask = jnp.logical_and(q_pos < tq_real, k_pos < tk_real)
-        if causal:
-            mask = jnp.logical_and(mask, q_pos + (tk_real - tq_real) >= k_pos)
-        if segmented:
-            mask = jnp.logical_and(
-                mask, sq_ref[0][:, None] == sk_ref[0][None, :])
-        p = jnp.where(mask, jnp.exp(s - lse), 0.0)
-        dv_acc[:] += jnp.dot(p.T.astype(do.dtype), do,
+        pt = _bwd_probs_t(
+            q, kb, lse_ref[0, 0], sq_ref, sk_ref, scale=scale,
+            causal=causal, segmented=segmented, tq_real=tq_real,
+            tk_real=tk_real, q0=iq * block_q, k0=ik * block_k,
+            block_q=block_q, block_k=block_k)
+        dv_acc[:] += jnp.dot(pt.astype(do.dtype), do,
                              preferred_element_type=jnp.float32)
-        dp = jnp.dot(do, vb.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - rest)
-        dk_acc[:] += jnp.dot(ds.T.astype(q.dtype), q,
+        dpt = jax.lax.dot_general(
+            vb, do, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dst = pt * (dpt - rest_ref[0, 0])
+        dk_acc[:] += jnp.dot(dst.astype(q.dtype), q,
                              preferred_element_type=jnp.float32) * scale
 
     @pl.when(iq == n_q - 1)
@@ -266,10 +307,10 @@ def _bwd_dq_kernel(*refs, scale: float, causal: bool, segmented: bool,
     accumulates in scratch while K/V tiles stream past (same streaming
     direction as the forward)."""
     if segmented:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dlse_ref,
+        (q_ref, k_ref, v_ref, do_ref, lse_ref, rest_ref,
          sq_ref, sk_ref, dq_ref, dq_acc) = refs
     else:
-        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dlse_ref,
+        (q_ref, k_ref, v_ref, do_ref, lse_ref, rest_ref,
          dq_ref, dq_acc) = refs
         sq_ref = sk_ref = None
     iq = pl.program_id(2)
@@ -293,36 +334,31 @@ def _bwd_dq_kernel(*refs, scale: float, causal: bool, segmented: bool,
         kb = k_ref[0, 0]
         vb = v_ref[0, 0]
         do = do_ref[0, 0]
-        lse = lse_ref[0, 0][:, None]
-        rest = (delta_ref[0, 0] - dlse_ref[0, 0])[:, None]
-        s = jnp.dot(q, kb.T, preferred_element_type=jnp.float32) * scale
-        q_pos = iq * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        k_pos = j * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        mask = jnp.logical_and(q_pos < tq_real, k_pos < tk_real)
-        if causal:
-            mask = jnp.logical_and(mask, q_pos + (tk_real - tq_real) >= k_pos)
-        if segmented:
-            mask = jnp.logical_and(
-                mask, sq_ref[0][:, None] == sk_ref[0][None, :])
-        p = jnp.where(mask, jnp.exp(s - lse), 0.0)
-        dp = jnp.dot(do, vb.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - rest)
-        dq_acc[:] += jnp.dot(ds.astype(kb.dtype), kb,
-                             preferred_element_type=jnp.float32) * scale
+        pt = _bwd_probs_t(
+            q, kb, lse_ref[0, 0], sq_ref, sk_ref, scale=scale,
+            causal=causal, segmented=segmented, tq_real=tq_real,
+            tk_real=tk_real, q0=iq * block_q, k0=j * block_k,
+            block_q=block_q, block_k=block_k)
+        dpt = jax.lax.dot_general(
+            vb, do, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dst = pt * (dpt - rest_ref[0, 0])
+        # dq = ds @ k with ds = dst^T: contract the key (sublane) axis
+        dq_acc[:] += jax.lax.dot_general(
+            dst.astype(kb.dtype), kb, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
 
     @pl.when(j == n_k - 1)
     def _():
         dq_ref[0, 0] = dq_acc[:].astype(dq_ref.dtype)
 
 
-def _pad1_t(x, block):
-    t = x.shape[2]
-    rem = t % block
-    if rem == 0:
-        return x
-    return jnp.pad(x, [(0, 0), (0, 0), (0, block - rem)])
+def _row(x, block):
+    """(B, H, T) per-query scalars -> block-padded (B, H, 1, T) rows."""
+    rem = x.shape[2] % block
+    if rem:
+        x = jnp.pad(x, [(0, 0), (0, 0), (0, block - rem)])
+    return x[:, :, None, :]
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -332,48 +368,58 @@ def _flash_bwd(q, k, v, o, lse, do, dlse, seg_q, seg_k, causal, scale,
     """Tiled backward: dq, dk, dv with nothing of size (Tq, Tk) resident.
     ``delta = rowsum(do * o)`` is the standard flash backward scalar; the
     optional lse cotangent folds in as ``ds += p * dlse``."""
+    if not interpret:
+        _check_compiled_blocks(block_q, block_k)
     b, h, tq, d = q.shape
     tk = k.shape[2]
     segmented = seg_q is not None
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     qp, dop = _pad_t(q, block_q), _pad_t(do, block_q)
     kp, vp = _pad_t(k, block_k), _pad_t(v, block_k)
-    lsep = _pad1_t(lse, block_q)
-    deltap = _pad1_t(delta, block_q)
-    dlsep = _pad1_t(dlse.astype(jnp.float32), block_q)
+    lsep = _row(lse, block_q)
+    restp = _row(delta - dlse.astype(jnp.float32), block_q)
     tq_pad, tk_pad = qp.shape[2], kp.shape[2]
     n_q, n_k = tq_pad // block_q, tk_pad // block_k
+    seg_operands = []
     if segmented:
-        sqp = _pad_seg(seg_q.astype(jnp.int32), block_q, -1)
-        skp = _pad_seg(seg_k.astype(jnp.int32), block_k, -2)
+        seg_operands = [
+            _pad_seg(seg_q.astype(jnp.int32), block_q, -1)[:, None, :],
+            _pad_seg(seg_k.astype(jnp.int32), block_k, -2)[:, :, None]]
+    operands = [qp, kp, vp, dop, lsep, restp] + seg_operands
+    kw = dict(scale=scale, causal=causal, segmented=segmented,
+              tq_real=tq, tk_real=tk, block_q=block_q, block_k=block_k)
 
-    qspec = pl.BlockSpec((1, 1, block_q, d),
-                         lambda bi, hi, oi, ii: (bi, hi, ii, 0))
-    kspec_o = pl.BlockSpec((1, 1, block_k, d),
-                           lambda bi, hi, oi, ii: (bi, hi, oi, 0))
-    rowspec = pl.BlockSpec((1, 1, block_q),
-                           lambda bi, hi, oi, ii: (bi, hi, ii))
-    dkv_kernel = functools.partial(
-        _bwd_dkv_kernel, scale=scale, causal=causal, segmented=segmented,
-        tq_real=tq, tk_real=tk, block_q=block_q, block_k=block_k)
-    in_specs = [qspec, kspec_o, kspec_o, qspec, rowspec, rowspec, rowspec]
-    operands = [qp, kp, vp, dop, lsep, deltap, dlsep]
-    if segmented:
-        in_specs += [
-            pl.BlockSpec((1, block_q), lambda bi, hi, oi, ii: (bi, ii)),
-            pl.BlockSpec((1, block_k), lambda bi, hi, oi, ii: (bi, oi)),
-        ]
-        operands += [sqp, skp]
+    def specs(qi_of, ki_of):
+        """Input specs for a grid (b, h, outer, inner); ``qi_of`` /
+        ``ki_of`` pick the query / key block index out of (outer, inner)."""
+        qspec = pl.BlockSpec(
+            (1, 1, block_q, d),
+            lambda bi, hi, oi, ii: (bi, hi, qi_of(oi, ii), 0))
+        kspec = pl.BlockSpec(
+            (1, 1, block_k, d),
+            lambda bi, hi, oi, ii: (bi, hi, ki_of(oi, ii), 0))
+        rowspec = pl.BlockSpec(
+            (1, 1, 1, block_q),
+            lambda bi, hi, oi, ii: (bi, hi, 0, qi_of(oi, ii)))
+        out = [qspec, kspec, kspec, qspec, rowspec, rowspec]
+        if segmented:
+            out += [
+                pl.BlockSpec((1, 1, block_q),         # query ids: row
+                             lambda bi, hi, oi, ii: (bi, 0, qi_of(oi, ii))),
+                pl.BlockSpec((1, block_k, 1),         # key ids: column
+                             lambda bi, hi, oi, ii: (bi, ki_of(oi, ii), 0)),
+            ]
+        return out
+
+    outer = lambda oi, ii: oi  # noqa: E731
+    inner = lambda oi, ii: ii  # noqa: E731
+    kv_out = pl.BlockSpec((1, 1, block_k, d),
+                          lambda bi, hi, oi, ii: (bi, hi, oi, 0))
     dk, dv = pl.pallas_call(
-        dkv_kernel,
+        functools.partial(_bwd_dkv_kernel, **kw),
         grid=(b, h, n_k, n_q),  # query blocks innermost
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, block_k, d),
-                         lambda bi, hi, oi, ii: (bi, hi, oi, 0)),
-            pl.BlockSpec((1, 1, block_k, d),
-                         lambda bi, hi, oi, ii: (bi, hi, oi, 0)),
-        ],
+        in_specs=specs(qi_of=inner, ki_of=outer),
+        out_specs=[kv_out, kv_out],
         out_shape=[
             _sds((b, h, tk_pad, d), k.dtype, q, k, v, do),
             _sds((b, h, tk_pad, d), v.dtype, q, k, v, do),
@@ -383,30 +429,13 @@ def _flash_bwd(q, k, v, o, lse, do, dlse, seg_q, seg_k, causal, scale,
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_bwd_dkv",
     )(*operands)
 
-    qspec2 = pl.BlockSpec((1, 1, block_q, d),
-                          lambda bi, hi, oi, ii: (bi, hi, oi, 0))
-    kspec2 = pl.BlockSpec((1, 1, block_k, d),
-                          lambda bi, hi, oi, ii: (bi, hi, ii, 0))
-    rowspec2 = pl.BlockSpec((1, 1, block_q),
-                            lambda bi, hi, oi, ii: (bi, hi, oi))
-    dq_kernel = functools.partial(
-        _bwd_dq_kernel, scale=scale, causal=causal, segmented=segmented,
-        tq_real=tq, tk_real=tk, block_q=block_q, block_k=block_k)
-    in_specs2 = [qspec2, kspec2, kspec2, qspec2, rowspec2, rowspec2,
-                 rowspec2]
-    operands2 = [qp, kp, vp, dop, lsep, deltap, dlsep]
-    if segmented:
-        in_specs2 += [
-            pl.BlockSpec((1, block_q), lambda bi, hi, oi, ii: (bi, oi)),
-            pl.BlockSpec((1, block_k), lambda bi, hi, oi, ii: (bi, ii)),
-        ]
-        operands2 += [sqp, skp]
     (dq,) = pl.pallas_call(
-        dq_kernel,
+        functools.partial(_bwd_dq_kernel, **kw),
         grid=(b, h, n_q, n_k),  # key blocks innermost
-        in_specs=in_specs2,
+        in_specs=specs(qi_of=outer, ki_of=inner),
         out_specs=[
             pl.BlockSpec((1, 1, block_q, d),
                          lambda bi, hi, oi, ii: (bi, hi, oi, 0)),
@@ -414,7 +443,8 @@ def _flash_bwd(q, k, v, o, lse, do, dlse, seg_q, seg_k, causal, scale,
         out_shape=[_sds((b, h, tq_pad, d), q.dtype, q, k, v, do)],
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
-    )(*operands2)
+        name="flash_attention_bwd_dq",
+    )(*operands)
     return dq[:, :, :tq], dk[:, :, :tk], dv[:, :, :tk]
 
 

@@ -37,14 +37,17 @@ def _paged_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
     s = pl.program_id(0)
     m = pl.program_id(2)
     n_m = pl.num_programs(2)
-    k_scr[pl.ds(m * block_len, block_len), :] = k_ref[0, 0]
-    v_scr[pl.ds(m * block_len, block_len), :] = v_ref[0, 0]
+    # block_len is a multiple of the sublane tile (checked by the
+    # wrapper when compiling), so the hint makes the dynamic store aligned
+    row = pl.multiple_of(m * block_len, block_len)
+    k_scr[pl.ds(row, block_len), :] = k_ref[0, 0]
+    v_scr[pl.ds(row, block_len), :] = v_ref[0, 0]
 
     @pl.when(m == n_m - 1)
     def _():
         # the dense-gather math verbatim (f32 end to end) so the kernel
         # and the fallback produce token-identical streams
-        q = q_ref[0].astype(jnp.float32)                      # (1, D)
+        q = q_ref[0, 0].astype(jnp.float32)                   # (1, D)
         kk = k_scr[:].astype(jnp.float32)                     # (ctx, D)
         scores = jax.lax.dot_general(
             q, kk, (((1,), (1,)), ((), ())),
@@ -53,13 +56,28 @@ def _paged_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
         k_pos = jax.lax.broadcasted_iota(jnp.int32, (1, ctx), 1)
         scores = jnp.where(k_pos <= pos_ref[s], scores, -1e30)
         w = jax.nn.softmax(scores, axis=-1)
-        o_ref[0] = jax.lax.dot_general(
+        o_ref[0, 0] = jax.lax.dot_general(
             w, v_scr[:].astype(jnp.float32), (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
 
 def _use_interpret() -> bool:
     return jax.default_backend() != "tpu"
+
+
+def check_paged_kernel_shapes(block_len: int, dtype) -> None:
+    """Raise where the COMPILED kernel cannot take the pool's geometry:
+    each grid step stores one (block_len, D) block at a dynamic row of
+    the context scratch, and Mosaic wants that row aligned to the
+    dtype's sublane tile (8 rows of 32-bit, 16 of 16-bit, 32 of 8-bit).
+    The serving engine calls this at construction so a pool the kernel
+    cannot read is an error there, not a silent gather."""
+    tile = 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+    if block_len % tile:
+        raise ValueError(
+            f"decode_attn='paged_kernel' needs block_len to be a multiple "
+            f"of {tile} for {jnp.dtype(dtype).name} KV blocks on TPU "
+            f"(got block_len={block_len})")
 
 
 def paged_decode_attention(q, k_arena, v_arena, tables, pos, *,
@@ -73,20 +91,24 @@ def paged_decode_attention(q, k_arena, v_arena, tables, pos, *,
     f32 attention output shaped like q.
     """
     squeeze = q.ndim == 4
-    q3 = q[:, :, 0, :] if squeeze else q
-    s, h, d = q3.shape
+    # (S, H, 1, D): the two tiled (last) dims of every q/o block are the
+    # array's own, which is what Mosaic's block-shape rule asks for
+    q4 = q if squeeze else q[:, :, None, :]
+    s, h, _, d = q4.shape
     n, _, blk, _ = k_arena.shape
     m = tables.shape[1]
     ctx = m * blk
     if interpret is None:
         interpret = _use_interpret()
+    if not interpret:
+        check_paged_kernel_shapes(blk, k_arena.dtype)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(s, h, m),  # table column innermost: scratch fills over it
         in_specs=[
-            pl.BlockSpec((1, 1, d),
-                         lambda si, hi, mi, tbl, pos: (si, hi, 0)),
+            pl.BlockSpec((1, 1, 1, d),
+                         lambda si, hi, mi, tbl, pos: (si, hi, 0, 0)),
             pl.BlockSpec((1, 1, blk, d),
                          lambda si, hi, mi, tbl, pos:
                          (tbl[si, mi], hi, 0, 0)),
@@ -94,8 +116,8 @@ def paged_decode_attention(q, k_arena, v_arena, tables, pos, *,
                          lambda si, hi, mi, tbl, pos:
                          (tbl[si, mi], hi, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, d),
-                               lambda si, hi, mi, tbl, pos: (si, hi, 0)),
+        out_specs=pl.BlockSpec((1, 1, 1, d),
+                               lambda si, hi, mi, tbl, pos: (si, hi, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((ctx, d), k_arena.dtype),
             pltpu.VMEM((ctx, d), v_arena.dtype),
@@ -104,11 +126,12 @@ def paged_decode_attention(q, k_arena, v_arena, tables, pos, *,
                                head_dim=d)
     o = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s, h, d), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((s, h, 1, d), jnp.float32),
         interpret=interpret,
-    )(tables.astype(jnp.int32), pos.astype(jnp.int32), q3, k_arena,
+        name="paged_decode_attention",
+    )(tables.astype(jnp.int32), pos.astype(jnp.int32), q4, k_arena,
       v_arena)
-    return o[:, :, None, :] if squeeze else o
+    return o if squeeze else o[:, :, 0, :]
 
 
 def paged_decode_attention_reference(q, k_arena, v_arena, tables, pos):

@@ -39,15 +39,8 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # newer jax: top-level alias, replication check spelled check_vma
-    from jax import shard_map
-    _SHARD_MAP_NO_CHECK = {"check_vma": False}
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
-    _SHARD_MAP_NO_CHECK = {"check_rep": False}
 
 from bigdl_tpu.dataset.dataset import AbstractDataSet
 from bigdl_tpu.nn.module import Criterion, Module
@@ -180,7 +173,7 @@ class DistriOptimizer(Optimizer):
             in_specs=(shard, opt_specs, buf_specs, batch_spec, batch_spec,
                       repl, repl),
             out_specs=(shard, opt_specs, buf_specs, repl),
-            **_SHARD_MAP_NO_CHECK,
+            check_vma=False,
         )
         return jax.jit(mapped, donate_argnums=(0, 1))
 
@@ -335,7 +328,7 @@ class DistriOptimizer(Optimizer):
             return batch, data, labels
 
         # step-cadence stall detection: a wedged backend mid-step looks
-        # merely "slow" from outside (NOTES_r4.md); the watchdog names
+        # merely "slow" from outside; the watchdog names
         # it — diagnose_tpu + thread stacks into the trace/log
         watchdog = None
         if env_watchdog_enabled():

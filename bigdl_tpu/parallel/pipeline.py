@@ -31,15 +31,8 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:  # newer jax: top-level alias, replication check spelled check_vma
-    from jax import shard_map
-    _SHARD_MAP_NO_CHECK = {"check_vma": False}
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
-    _SHARD_MAP_NO_CHECK = {"check_rep": False}
 
 from bigdl_tpu.parallel.mesh import PIPELINE_AXIS
 
@@ -280,13 +273,13 @@ def pipeline_apply_hetero(stage_fns, stage_params, x, mesh: Mesh, *,
         # inference path: no rematerialization stash
         return shard_map(_make_fwd_body(False), mesh=mesh,
                          in_specs=(p_specs, P()), out_specs=P(),
-                         **_SHARD_MAP_NO_CHECK)(params_tuple, x_micro)
+                         check_vma=False)(params_tuple, x_micro)
 
     def pipe_fwd(params_tuple, x_micro):
         y, res = shard_map(_make_fwd_body(True), mesh=mesh,
                            in_specs=(p_specs, P()),
                            out_specs=(P(), res_spec),
-                           **_SHARD_MAP_NO_CHECK)(params_tuple, x_micro)
+                           check_vma=False)(params_tuple, x_micro)
         return y, (params_tuple, x_micro, res)
 
     def pipe_bwd(saved, dy_micro):
@@ -295,7 +288,7 @@ def pipeline_apply_hetero(stage_fns, stage_params, x, mesh: Mesh, *,
             bwd_body, mesh=mesh,
             in_specs=(p_specs, P(), res_spec, P()),
             out_specs=(P(axis, None), P()),
-            **_SHARD_MAP_NO_CHECK,
+            check_vma=False,
         )(params_tuple, x_micro, res, dy_micro.astype(dtype))
         dparams = tuple(
             unravels[j](dp_stack[j, :p_sizes[j]]) for j in range(n))

@@ -17,16 +17,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:  # newer jax: top-level alias; its vma checking handles pallas_call
-    from jax import shard_map
-    _SHARD_MAP_COMPAT = {}
-except ImportError:  # pragma: no cover — 0.4.x: check_rep has no
-    # replication rule for pallas_call, so the flash hops need it off
-    from jax.experimental.shard_map import shard_map
-    _SHARD_MAP_COMPAT = {"check_rep": False}
 
 from bigdl_tpu.nn.attention import (NEG_INF, _block_scores, _finalize,
                                     segment_mask,
@@ -200,16 +192,14 @@ def ring_attention(q, k, v, mesh: Mesh, *, axis: str = SEQUENCE_AXIS,
         fn = shard_map(
             partial(ring_attention_local, axis_name=axis, causal=causal,
                     impl=impl, block_size=block_size),
-            mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-            **_SHARD_MAP_COMPAT)
+            mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
         return fn(q, k, v)
     seg_spec = P(batch_axis, axis)
     fn = shard_map(
         lambda q, k, v, seg: ring_attention_local(
             q, k, v, axis_name=axis, causal=causal, impl=impl,
             block_size=block_size, segment_ids=seg),
-        mesh=mesh, in_specs=(spec, spec, spec, seg_spec), out_specs=spec,
-        **_SHARD_MAP_COMPAT)
+        mesh=mesh, in_specs=(spec, spec, spec, seg_spec), out_specs=spec)
     return fn(q, k, v, segment_ids)
 
 
@@ -263,15 +253,13 @@ def ulysses_attention(q, k, v, mesh: Mesh, *, axis: str = SEQUENCE_AXIS,
     if segment_ids is None:
         fn = shard_map(
             partial(ulysses_attention_local, axis_name=axis, causal=causal),
-            mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-            **_SHARD_MAP_COMPAT)
+            mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
         return fn(q, k, v)
     seg_spec = P(batch_axis, axis)
     fn = shard_map(
         lambda q, k, v, seg: ulysses_attention_local(
             q, k, v, axis_name=axis, causal=causal, segment_ids=seg),
-        mesh=mesh, in_specs=(spec, spec, spec, seg_spec), out_specs=spec,
-        **_SHARD_MAP_COMPAT)
+        mesh=mesh, in_specs=(spec, spec, spec, seg_spec), out_specs=spec)
     return fn(q, k, v, segment_ids)
 
 

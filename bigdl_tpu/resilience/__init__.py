@@ -6,7 +6,7 @@ is recomputed, arXiv 1804.05839); under JAX nothing is free, so this
 package supplies the pieces explicitly:
 
 - :mod:`~bigdl_tpu.resilience.errors` — the transient / backend-lost /
-  fatal failure taxonomy (``classify_error``);
+  fatal failure classification (``classify_error``);
 - :mod:`~bigdl_tpu.resilience.retry` — ``with_backoff``, the bounded
   exponential-backoff policy wired into ``chunked_device_put`` (with
   automatic chunk-size downshift toward an 8 MB floor);

@@ -336,7 +336,6 @@ class ReplicaSet(ReplicaSetCore):
                  max_wait_ms: float = 5.0,
                  max_queue: int = 256,
                  dtype="float32",
-                 platform: Optional[str] = None,
                  use_shared_pool: bool = True,
                  placement=None,
                  **engine_kwargs):
@@ -371,7 +370,7 @@ class ReplicaSet(ReplicaSetCore):
         self._engine_cls = ServingEngine
         self._engine_cfg = dict(input_shape=input_shape, buckets=buckets,
                                 max_batch_size=max_batch_size, dtype=dtype,
-                                platform=platform, **engine_kwargs)
+                                **engine_kwargs)
         self.placement = placement
         self._next_idx = n_replicas
         self._replicas = []
@@ -417,7 +416,7 @@ class ReplicaSet(ReplicaSetCore):
 
     def _acquire_slot(self, *, required: bool):
         """One mesh slot from the placement policy; raises (required)
-        or returns None (opportunistic growth) when the devices are
+        or returns None (best-effort growth) when the devices are
         fully packed."""
         slot = self.placement.acquire()
         if slot is None and required:

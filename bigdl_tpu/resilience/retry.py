@@ -1,8 +1,8 @@
 """Bounded exponential backoff around backend-touching calls.
 
-The policy layer between "the relay wobbled" and "the round is lost":
-transient failures retry with exponential backoff (bounded — round 4
-taught that unbounded waiting IS the failure), backend-lost failures
+The policy layer between "the backend wobbled" and "the run is lost":
+transient failures retry with exponential backoff (bounded — unbounded
+waiting IS the failure), backend-lost failures
 are surfaced immediately as :class:`BackendLostError` for the caller's
 checkpoint/failover path, and fatal (programming) errors pass straight
 through untouched.  Every retry and terminal loss is counted in the

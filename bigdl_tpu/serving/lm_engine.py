@@ -74,7 +74,7 @@ from bigdl_tpu.serving.batcher import (ServingClosed, ServingQueueFull,
 from bigdl_tpu.serving.compile_cache import CompileCache
 from bigdl_tpu.serving.kvcache import (BlockPool, PoolExhausted, RadixCache,
                                        RequestExceedsPool)
-from bigdl_tpu.utils.engine import select_platform
+from bigdl_tpu.utils.engine import configure_compile_cache
 
 _tracer = get_tracer()
 log = logging.getLogger("bigdl_tpu.serving")
@@ -567,7 +567,6 @@ class LMServingEngine:
         eos_id: default 1-based stop token; generation also stops at
             ``max_new``.
         max_queue: admission queue bound (``ServingQueueFull`` beyond).
-        platform: optional jax platform pin.
         donate_cache: donate k/v arenas into decode/insert (the no-copy
             hot path); disable only for debugging.
         decode_attn: decode attention over the paged cache —
@@ -636,7 +635,6 @@ class LMServingEngine:
                  eos_id: Optional[int] = None,
                  max_queue: int = 256,
                  max_cache_entries: int = 16,
-                 platform: Optional[str] = None,
                  donate_cache: bool = True,
                  decode_attn: str = "auto",
                  kv_quant: Optional[str] = None,
@@ -650,7 +648,7 @@ class LMServingEngine:
                  honor_lifecycle: bool = True,
                  metrics: Optional[LMMetrics] = None,
                  metrics_prefix: str = "serving/lm/"):
-        select_platform(platform)
+        configure_compile_cache()
         import jax
         from bigdl_tpu.models.transformer.generate import (
             _decode_step_paged, _insert_blocks, _prefill_parts,
@@ -830,6 +828,13 @@ class LMServingEngine:
             decode_attn = ("paged_kernel"
                            if tuned is not None and tuned.use_kernel
                            else "gather")
+        if decode_attn == "paged_kernel":
+            # a pool geometry the COMPILED kernel cannot read is an error
+            # here, not a silent gather (the interpreter takes any)
+            from bigdl_tpu.ops.paged_attention import (
+                _use_interpret, check_paged_kernel_shapes)
+            if not _use_interpret():
+                check_paged_kernel_shapes(self.block_len, dt)
         self.decode_attn = decode_attn
 
         if _kvq:
@@ -2106,7 +2111,7 @@ class LMServingEngine:
                     k, v, ks, vs, extra_blocks=0,
                     device=self.pool.k.sharding)
             except PoolExhausted:
-                # promotion is opportunistic — never deepen the very
+                # promotion is best-effort — never deepen the very
                 # pressure it is trying to relieve
                 return matched
         self.kvtier.record_promote(nbytes, time.perf_counter() - t0)

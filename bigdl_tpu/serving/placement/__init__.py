@@ -23,8 +23,7 @@ Three layers, smallest first:
   with headroom accounting), publish ``serving/placement/*`` gauges.
 
 Everything is proven on CPU with the 8-virtual-device fake mesh
-(``XLA_FLAGS=--xla_force_host_platform_device_count=8`` — the
-mosaic_export_check pattern): ``bench.py --serve --mesh`` writes the
+(``XLA_FLAGS=--xla_force_host_platform_device_count=8``): ``bench.py --serve --mesh`` writes the
 resumable BENCH_MESH.json comparing single-device vs 2-slot x TP2 vs
 1-slot x TP4 against the unsharded oracle.
 """

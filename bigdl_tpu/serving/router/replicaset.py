@@ -8,10 +8,10 @@ least-loaded fallback, in that order) and the request's whole
 prefill+decode life runs there, so the replica's RadixCache actually
 accumulates the session's prefix.
 
-Failover is stream-granular and bit-exact: a relay thread forwards the
+Failover is stream-granular and bit-exact: a forwarder thread forwards the
 inner engine stream into the client-visible :class:`RoutedLMStream`;
 when the inner stream dies with a re-routable error (transient,
-backend-lost, or the member engine closing), the relay re-submits the
+backend-lost, or the member engine closing), the forwarder re-submits the
 SAME prompt with the SAME seed/temperature to another replica and
 skips the tokens it already forwarded — deterministic prefill plus the
 seeded sampling chain make the replayed tokens identical, so the
@@ -23,7 +23,7 @@ Hibernation composes: :meth:`hibernate` swaps the stream into its
 replica's host tier and records that replica in the session table;
 :meth:`resume` prefers it (chunked promote — no recompute).  If the
 sticky replica died meanwhile, its ``_fail_all`` already resolved the
-hibernated inner stream with an error, the relay has re-prefilled and
+hibernated inner stream with an error, the forwarder has re-prefilled and
 replayed elsewhere, and the session is repointed — degraded, never
 stranded.
 """
@@ -57,7 +57,7 @@ _tracer = get_tracer()
 
 class RoutedLMStream(LMStream):
     """Client handle for a routed request: an :class:`LMStream` whose
-    tokens arrive via the relay, surviving replica failover underneath.
+    tokens arrive via the forwarder, surviving replica failover underneath.
     ``replica_name`` / ``inner`` track the CURRENT placement (they move
     on failover); ``re_dispatches`` counts the hops; ``hedged`` marks a
     request that fired a speculative duplicate dispatch."""
@@ -210,7 +210,7 @@ class LMReplicaSet(ReplicaSetCore):
     def _dispatch(self, prompt, kw: dict, ctx: dict, tried: set):
         """Pick a replica and enqueue the prompt there, walking the
         candidates on replica-local failures.  Returns ``(rep, inner)``
-        with the pick's inflight slot held (released by the relay's
+        with the pick's inflight slot held (released by the forwarder's
         success/failure record).  Raises the last typed overload when
         every candidate shed, BackendLostError when none was left."""
         last: Optional[BaseException] = None
@@ -300,14 +300,14 @@ class LMReplicaSet(ReplicaSetCore):
         out.replica_name, out.inner = rep.name, inner
         t = threading.Thread(
             target=self._relay, args=(out, rep, inner, prompt, kw, ctx),
-            name=f"{self.name}-relay-{rid}", daemon=True)
+            name=f"{self.name}-forwarder-{rid}", daemon=True)
         t.start()
         return out
 
     def _relay(self, out: RoutedLMStream, rep, inner, prompt, kw, ctx):
         """Forward the inner stream into the routed one; on a
         re-routable death, re-submit the same request elsewhere and
-        skip what the client already saw (bit-exact replay).  The relay
+        skip what the client already saw (bit-exact replay).  The forwarder
         is also where the request's lifecycle rides the hops: a hedge
         window opens before the first token, failover forwards the
         REMAINING deadline budget, and a client cancel noticed here
@@ -419,7 +419,7 @@ class LMReplicaSet(ReplicaSetCore):
         policy's tail trigger; past it (and within the hedge budget),
         duplicate the request onto the next-best replica and race the
         two streams.  Returns the winning ``(rep, inner)`` pair for the
-        relay to forward, or None to continue with the primary.  Both
+        forwarder to forward, or None to continue with the primary.  Both
         replicas compute identical tokens (same prompt, same seed), so
         whichever finishes first IS the answer — the loser is
         cooperatively cancelled and frees its slot within one scheduler
@@ -466,7 +466,7 @@ class LMReplicaSet(ReplicaSetCore):
                  hrep.name, waited, trig)
         # a side stream's inflight/breaker accounting settles when its
         # cancel is honored (next scheduler round on its engine) — a
-        # tiny waiter keeps the relay free to forward the winner NOW
+        # tiny waiter keeps the forwarder free to forward the winner NOW
         def _settle(side_stream, side_rep):
             def _run():
                 with side_stream._cond:
@@ -483,7 +483,7 @@ class LMReplicaSet(ReplicaSetCore):
         # first completion WITHOUT an error wins; a mid-hedge replica
         # kill resolves its stream with an error, which simply forfeits
         # the race to the survivor.  Both dead -> hand the primary back
-        # and let the relay's failover path re-dispatch (both names are
+        # and let the forwarder's failover path re-dispatch (both names are
         # in ``tried``).
         while True:
             p_done, h_done = inner.done(), hinner.done()
@@ -503,7 +503,7 @@ class LMReplicaSet(ReplicaSetCore):
                 return None
             if out.cancel_requested:
                 # client cancelled mid-race: both inners already got
-                # the cancel via RoutedLMStream.cancel; let the relay's
+                # the cancel via RoutedLMStream.cancel; let the forwarder's
                 # normal path observe the primary's truncation, and
                 # settle the hedge seat when its cancel lands
                 pol.note_outcome(False)
@@ -542,7 +542,7 @@ class LMReplicaSet(ReplicaSetCore):
         """Wake a hibernated stream.  Fast path: its replica is alive
         and promotes the chain back from its tier.  Degraded path: the
         replica died — its ``_fail_all`` resolved the inner stream, the
-        relay already re-prefilled and replayed on a survivor, and this
+        forwarder already re-prefilled and replayed on a survivor, and this
         just repoints the session (returns True: the stream IS live).
         False only when the stream was never hibernated."""
         rep = self._by_name(stream.replica_name)
@@ -554,7 +554,7 @@ class LMReplicaSet(ReplicaSetCore):
                 if stream.re_dispatches == 0:
                     return False
                 # not hibernated HERE because the holder died and the
-                # relay already moved the stream: degraded path below
+                # forwarder already moved the stream: degraded path below
             except ServingClosed:
                 pass
         self.resume_re_routes += 1
@@ -568,7 +568,7 @@ class LMReplicaSet(ReplicaSetCore):
         """Abrupt replica death (chaos hook): the member stops serving
         NOW and every stream it held — seated, queued, or hibernated —
         resolves with a backend-lost error, which is exactly what wakes
-        each relay into its re-route+replay path.  The replica never
+        each forwarder into its re-route+replay path.  The replica never
         returns (DRAINING)."""
         rep = self._by_name(name)
         if rep is None:

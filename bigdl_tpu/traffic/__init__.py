@@ -9,9 +9,9 @@ Three pieces that close the serving loop the way production does:
 - :mod:`~bigdl_tpu.traffic.slo` — SLOController: windowed p99 read
   out of the obs histograms, scale-then-shed actuation ladder, plus
   :func:`~bigdl_tpu.traffic.slo.detect_knee` for goodput curves.
-- :mod:`~bigdl_tpu.traffic.chaos` — replay of the RECORDED tunnel
-  incidents (TUNNEL_INCIDENTS.json) as a seeded fault schedule through
-  the existing ``fault_point`` sites, mid-load.
+- :mod:`~bigdl_tpu.traffic.chaos` — replay of an incident list (a
+  seeded synthetic one, or a recorded ledger) as a seeded fault schedule
+  through the existing ``fault_point`` sites, mid-load.
 
 Entry point: ``python bench.py --slo`` sweeps offered load, runs the
 chaos row, and writes the resumable ``BENCH_SLO.json`` goodput curve.

@@ -1,13 +1,12 @@
 """Incremental, resumable measurement artifacts — the shared protocol.
 
 Every measurement tool in this package (attention_bench, lm_perf,
-tpu_profile_bench, tunnel_stress) follows one contract, born of a
-backend with short availability windows (NOTES_r4.md):
+tpu_profile_bench) follows one contract, for runs under a time limit:
 
 - the artifact is rewritten ATOMICALLY after every row, so a sweep
-  killed when the window closes keeps everything it measured;
-- ``complete`` stays false until the final flush, so the opportunist
-  runner keeps firing a stage until its sweep truly finished;
+  killed at its limit keeps everything it measured;
+- ``complete`` stays false until the final flush, so a rerun resumes a
+  stage until its sweep truly finished;
 - on restart, rows are reused only when the caller's ``match``
   predicate accepts them (platform + full configuration + iteration
   count — a CPU debug row must never publish as a TPU number).

@@ -28,7 +28,7 @@ the two halves of its BlockManager all-reduce.
 from __future__ import annotations
 
 import re
-from typing import Any, Optional
+from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -107,25 +107,42 @@ def env_source(name: str) -> str:
     return _ENV_SOURCES.get(name, "default")
 
 
-#: planning numbers for the roofline attribution — default v5e (~197
-#: TFLOP/s bf16 MXU peak, ~819 GB/s HBM).  Override for other chip
-#: generations via BIGDL_TPU_PEAK_TFLOPS / BIGDL_TPU_HBM_GBPS (before
-#: first import).  Only their RATIO matters for splitting a measured
-#: step across layers, so being a generation off shifts the split, not
-#: the total.
-PEAK_FLOPS = _env_float("BIGDL_TPU_PEAK_TFLOPS", 197.0) * 1e12
-PEAK_HBM_BYTES_S = _env_float("BIGDL_TPU_HBM_GBPS", 819.0) * 1e9
+class DevicePeaks(NamedTuple):
+    """Published peak rates of one chip."""
+    bf16_flops: float    # FLOP/s, bf16 MXU
+    int8_ops: float      # OP/s, int8 MXU
+    hbm_bytes_s: float   # bytes/s, HBM
+    source: str
+
+
+#: THE peak table, keyed by ``jax.Device.device_kind``.  A device that is
+#: not here is an error (``device_peaks`` raises), never a default: a
+#: utilization against somebody else's peak is not a number.
+DEVICE_PEAKS = {
+    "TPU v5 lite": DevicePeaks(
+        bf16_flops=197e12, int8_ops=393e12, hbm_bytes_s=819e9,
+        source='Google Cloud documentation, "TPU v5e"'),
+}
+
+
+def device_peaks(device_kind: Optional[str] = None) -> DevicePeaks:
+    """Peaks for ``device_kind`` (default: the attached device's)."""
+    if device_kind is None:
+        device_kind = jax.devices()[0].device_kind
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peak rates for device kind {device_kind!r}; "
+            f"known: {sorted(DEVICE_PEAKS)} — add a sourced row to "
+            f"utils/profiling.py:DEVICE_PEAKS") from None
 
 
 def _cost_of_compiled(compiled) -> tuple[float, float]:
     """(flops, bytes accessed) of a compiled program, per XLA."""
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # one dict per device on old jax
-        cost = cost[0]
     return (float(cost.get("flops", 0.0) or 0.0),
             float(cost.get("bytes accessed", 0.0) or 0.0))
-
-
 
 
 def _layer_flops(child: Module, params, buffers, inp, training: bool,
@@ -185,26 +202,29 @@ def profile_layers(model: Module, x, training: bool = True,
 
 def attribute_step_time(model: Module, x, step_time_s: float,
                         training: bool = True,
-                        mode: str = "roofline") -> list[dict]:
+                        mode: str = "roofline",
+                        device_kind: Optional[str] = None) -> list[dict]:
     """Distribute a measured fused-step wall time over layers and write
     the result into each layer's ``forward_time``/``backward_time`` so
     ``get_times()`` — the reference's per-module timing API — reports
     per-layer cost from a *jitted* run.
 
     ``mode="roofline"`` (default) weighs each layer by
-    max(flops/PEAK_FLOPS, bytes/PEAK_HBM_BYTES_S) — a bandwidth-bound
+    max(flops/peak FLOP/s, bytes/peak HBM bytes/s) of ``device_kind``
+    (default: the attached device; see ``DEVICE_PEAKS``) — a bandwidth-bound
     BatchNorm or transpose is billed for its HBM traffic instead of its
     ~0 flops (which the old flop-share split mis-billed to the convs).
     ``mode="flops"`` keeps the pure flop-proportional split.  Each row
     carries ``bound`` ("compute"/"memory") for roofline mode."""
     if mode not in ("roofline", "flops"):
         raise ValueError(f"mode must be 'roofline'|'flops', got {mode!r}")
+    peaks = device_peaks(device_kind) if mode == "roofline" else None
     rows = profile_layers(model, x, training=training)
 
     def weight(flops, bytes_):
         if mode == "flops":
             return flops
-        return max(flops / PEAK_FLOPS, bytes_ / PEAK_HBM_BYTES_S)
+        return max(flops / peaks.bf16_flops, bytes_ / peaks.hbm_bytes_s)
 
     total = sum(weight(r["flops_train"], r["bytes_train"]) for r in rows) or 1.0
     for r in rows:
@@ -212,8 +232,8 @@ def attribute_step_time(model: Module, x, step_time_s: float,
         t = (w / total) * step_time_s
         if mode == "roofline":
             r["bound"] = ("compute"
-                          if r["flops_train"] / PEAK_FLOPS
-                          >= r["bytes_train"] / PEAK_HBM_BYTES_S
+                          if r["flops_train"] / peaks.bf16_flops
+                          >= r["bytes_train"] / peaks.hbm_bytes_s
                           else "memory")
         # forward/backward split from the compiled fwd vs train weights
         # (the backward ~2x forward rule falls out of the numbers
@@ -375,7 +395,9 @@ def collective_footprint(compiled_text: str) -> dict[str, int]:
            "collective-permute": 0, "all-to-all": 0}
     for line in compiled_text.splitlines():
         s = line.strip()
-        m = re.match(r"^(?:ROOT )?%?[\w.\-]+ = (\(?[^)=]*\)?) (all-gather|"
+        # the shape may carry a TPU layout with parentheses of its own,
+        # e.g. bf16[25583592]{0:T(1024)(128)(2,1)S(1)}
+        m = re.match(r"^(?:ROOT )?%?[\w.\-]+ = ([^=]*?) (all-gather|"
                      r"reduce-scatter|all-reduce|collective-permute|"
                      r"all-to-all)(-start|-done)?\(", s)
         if not m:

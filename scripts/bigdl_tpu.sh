@@ -6,7 +6,7 @@
 #   ./scripts/bigdl_tpu.sh [--platform cpu|tpu] [--hosts N] -- <cmd...>
 #
 # Exports:
-#   BIGDL_TPU_PLATFORM       pin the JAX platform (Engine.init honors it)
+#   JAX_PLATFORMS            the JAX platform (--platform sets it)
 #   BIGDL_TPU_CHECK_SINGLETON one trainer per process guard (default on)
 #   XLA_FLAGS                 host-device count for CPU simulation
 set -euo pipefail
@@ -23,7 +23,7 @@ while [[ $# -gt 0 ]]; do
 done
 
 if [[ -n "$PLATFORM" ]]; then
-  export BIGDL_TPU_PLATFORM="$PLATFORM"
+  export JAX_PLATFORMS="$PLATFORM"
   if [[ "$PLATFORM" == "cpu" && -n "$HOSTS" ]]; then
     # simulate an N-device mesh on CPU (the test/dry-run configuration)
     export XLA_FLAGS="${XLA_FLAGS:-} --xla_force_host_platform_device_count=${HOSTS}"

@@ -1,4 +1,4 @@
-"""Convergence-on-chip proof (VERDICT r4 item 5).
+"""Convergence proof on the current platform.
 
 Trains two flagship configurations END TO END on the current platform
 and records their trajectories, the analog of the reference's
@@ -15,21 +15,20 @@ hardware:
   * VGG on synthetic CIFAR for a short run — the loss trajectory must
     fall to <=0.7x its first epoch.
 
-Measurement-protocol invariants (CLAUDE.md): the artifact rewrites
+Measurement-protocol invariants (utils/artifacts.py): the artifact rewrites
 atomically after EVERY epoch with ``complete: false`` until the final
-flush; rows resume across windows keyed on platform + full config,
+flush; rows resume across runs keyed on platform + full config,
 backed by the real checkpoint/resume cycle (each epoch runs a fresh
-Optimizer restored from the newest model/state pair, so a window
-closing mid-run loses at most one epoch — and the elastic-resume path
+Optimizer restored from the newest model/state pair, so a run
+cut at its time limit loses at most one epoch — and the elastic-resume path
 gets exercised once per epoch as a side effect).
 
 When a committed CPU reference artifact exists (--cpu-ref, default
 CONVERGENCE_CPU.json committed from the rehearsal), the TPU run records
-per-epoch loss deltas against it — the numerics-parity comparison the
-verdict asks for.
+per-epoch loss deltas against it — the numerics-parity comparison.
 
     python scripts/convergence_bench.py --json CONVERGENCE_r05.json
-    BIGDL_TPU_PLATFORM=cpu python scripts/convergence_bench.py \
+    JAX_PLATFORMS=cpu python scripts/convergence_bench.py \
         --json CONVERGENCE_CPU.json   # rehearsal / reference trajectory
 """
 from __future__ import annotations

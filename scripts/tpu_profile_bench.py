@@ -1,15 +1,19 @@
-"""Profile the bench ResNet-50 step and attribute its cost per layer
-(VERDICT r2 #2: point the repo's own tools at the bench on the real chip).
+"""Profile the bench ResNet-50 step and attribute its cost per layer.
 
     python scripts/tpu_profile_bench.py --batches 256,512,1024 \
-        --json PROFILE_TPU.json
+        --json PROFILE_RESNET.json
+
+One process per chip: THIS process pins itself to the CPU before jax is
+imported and never touches an accelerator; each measurement is a child
+``bench.py`` that takes the chip alone (a fresh process is also what a
+different XLA flag preset needs), one at a time.
 
 Two phases:
  1. measure: for each batch size, run the exact bench.py training step in
-    a fresh subprocess on the default (TPU) backend and record the
-    steady-state step time (same supervisor discipline as bench.py — a
-    wedged backend times out instead of hanging the profile).
- 2. attribute: on the CPU backend (fast, cached), split the best measured
+    a fresh subprocess on the caller's platform and record the
+    steady-state step time (under a timeout — a wedged backend times out
+    instead of hanging the profile).
+ 2. attribute: here, on the CPU backend, split the best measured
     step time across layers with the roofline model
     (utils/profiling.attribute_step_time): compiled flops vs bytes per
     layer are shape properties, so the CPU-compiled cost analysis is
@@ -29,16 +33,20 @@ import time
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
+#: what the children run on: the caller's JAX_PLATFORMS (None = default
+#: device).  main() pins the parent itself to the CPU.
+_CHILD_PLATFORM = os.environ.get("JAX_PLATFORMS")
+
 
 def _measure_one(batch: int, timeout: float, iters: int,
                  xla_flags: str = "") -> dict:
     env = dict(os.environ)
-    env["BIGDL_TPU_BENCH_INNER"] = "1"
+    if _CHILD_PLATFORM is None:
+        env.pop("JAX_PLATFORMS", None)
+    else:
+        env["JAX_PLATFORMS"] = _CHILD_PLATFORM
     env["BIGDL_TPU_BENCH_BATCH"] = str(batch)
     env["BIGDL_TPU_BENCH_ITERS"] = str(iters)
-    # profiler rows are experiments, not the recipe measurement — they
-    # must never become bench.py's replay source
-    env["BIGDL_TPU_BENCH_NO_LAST"] = "1"
     if xla_flags:
         env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " "
                             + xla_flags).strip()
@@ -131,11 +139,12 @@ def sweep_flags(batch: int, timeout: float, iters: int, deadline: float,
     return rows
 
 
+#: the chip whose published peaks weigh the roofline split
+ATTRIBUTION_DEVICE_KIND = "TPU v5 lite"
+
+
 def attribute_cpu(step_s: float, batch: int, top_n: int = 25) -> list[dict]:
     os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
     import numpy as np
 
     sys.path.insert(0, REPO)
@@ -147,7 +156,8 @@ def attribute_cpu(step_s: float, batch: int, top_n: int = 25) -> list[dict]:
     # tiny batch for the per-layer compiles; flop/byte RATIOS scale
     # linearly with batch so the split is batch-invariant
     x = np.random.RandomState(0).randn(8, 224, 224, 3).astype(np.float32)
-    rows = attribute_step_time(model, x, step_s, mode="roofline")
+    rows = attribute_step_time(model, x, step_s, mode="roofline",
+                               device_kind=ATTRIBUTION_DEVICE_KIND)
     rows.sort(key=lambda r: -r["time_s"])
     out = []
     for r in rows[:top_n]:
@@ -178,17 +188,18 @@ def main(argv=None) -> None:
                         "overrun are recorded as skipped, and the artifact "
                         "is rewritten after every row so an outer kill "
                         "keeps everything measured so far")
-    p.add_argument("--json", default="PROFILE_TPU.json")
+    p.add_argument("--json", default="PROFILE_RESNET.json")
     args = p.parse_args(argv)
 
+    os.environ["JAX_PLATFORMS"] = "cpu"  # before anything imports jax
     deadline = time.time() + args.deadline
     batches = [int(b) for b in args.batches.split(",")]
     sys.path.insert(0, REPO)
-    # the inner bench runs on the default platform unless the escape
-    # hatch redirects it; resume must never mix rows across platforms
-    inner_platform = os.environ.get("BIGDL_TPU_BENCH_PLATFORM", "default")
+    # the inner bench runs on the caller's platform; resume must never
+    # mix rows across platforms
+    inner_platform = _CHILD_PLATFORM or "default"
     # resume: reuse successful rows from a prior killed run so repeated
-    # short backend windows make net progress (keyed by batch+iters for
+    # time-limited calls make net progress (keyed by batch+iters for
     # the sweep, by preset+flagstring+batch for the flag experiments —
     # an edited preset definition must be re-measured, not answered
     # with the old flags' number)
@@ -260,13 +271,14 @@ def main(argv=None) -> None:
     if step_s:
         result["attribution"] = {
             "step_s": step_s, "batch": batch,
-            "model": "roofline(flops/197e12, bytes/819e9), v5e",
+            "model": f"roofline against the published peaks of "
+                     f"{ATTRIBUTION_DEVICE_KIND!r} (DEVICE_PEAKS)",
             "layers": attribute_cpu(step_s, batch)}
     else:
         result["error"] = "no successful TPU measurement to attribute"
     # complete means "every configured row got a real attempt": rows the
-    # deadline skipped or that timed out (backend window closed) leave
-    # the artifact incomplete so an opportunistic re-run fills them;
+    # deadline skipped or that timed out leave the artifact incomplete
+    # so a re-run fills them;
     # genuine failures (OOM-class) count as attempted
     unattempted = [
         r for r in (result.get("measurements", [])

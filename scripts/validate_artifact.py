@@ -7,8 +7,8 @@ regeneration consume — a truncated or key-drifted artifact fails SILENTLY ther
 (rows skipped, resume identity never matching, `complete` read as
 falsy).  This linter makes the contract explicit and cheap to check:
 
-  * the file parses as JSON — or as JSON-LINES, which BENCH_SMOKE.json
-    legitimately is (one metric record per line);
+  * the file parses as JSON — or as JSON-LINES (one metric record
+    per line);
   * supervisor records (BENCH_r<round>*.json: {'n','cmd','rc',...})
     carry their replay keys;
   * row-carrying artifacts carry a boolean ``complete`` (the resumable
@@ -569,7 +569,7 @@ def validate(path: str) -> list:
     try:
         doc = json.loads(text)
     except json.JSONDecodeError:
-        # JSON-LINES fallback (e.g. BENCH_SMOKE.json): every non-blank
+        # JSON-LINES fallback: every non-blank
         # line must parse on its own
         recs = []
         for i, line in enumerate(text.splitlines()):

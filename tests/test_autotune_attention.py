@@ -75,8 +75,8 @@ def test_certified_doc_survives_allreuse_and_killed_reruns(tmp_path,
                                                            monkeypatch):
     """A complete:true doc must not be rewritten by a rerun until a
     candidate genuinely re-measures — an all-reuse pass, or one killed
-    mid-measurement of its first new candidate (the opportunist's
-    timeout), leaves the certified artifact byte-identical."""
+    mid-measurement of its first new candidate (a run's time
+    limit), leaves the certified artifact byte-identical."""
     path = str(tmp_path / "tune.json")
     doc = autotune.autotune_attention([32], iters=1, path=path, **TINY)
     assert doc["complete"] is True
@@ -216,7 +216,7 @@ def test_repo_cache_has_cpu_crossover_verdict(monkeypatch):
 
 @pytest.mark.slow
 def test_bench_attn_cli_resume(tmp_path):
-    env = dict(os.environ, BIGDL_TPU_BENCH_PLATFORM="cpu",
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                BIGDL_TPU_TUNE_CACHE=str(tmp_path / "tune.json"))
     bench_json = str(tmp_path / "attn.json")
     argv = [sys.executable, os.path.join(REPO, "bench.py"), "--attn",

@@ -1,0 +1,214 @@
+"""Ask the TPU compiler, with no chip attached.
+
+The one test file that loads the TPU compiler: the Pallas kernels of the
+main path at real widths (GPT-2 XL's 25 heads x 64 and the (1, 8, 4096,
+128) shape where ``attention_impl="auto"`` picks flash), compiled — NOT
+interpreted — for a described ``v5e:2x2`` device, plus the two whole step
+programs ``chip_smoke.py`` runs (the LM paged decode step and the ResNet-50
+bf16/NHWC training step).  A compile that passes is not a chip run; it is
+what the chip's compiler would refuse, found at no chip time.
+
+Everything built from the topology lives in module-scoped, non-autouse
+fixtures of THIS file (on-chip-measurement guide section 2): only the
+xdist worker that is handed this file loads libtpu.  Nothing here runs at
+import, in a skipif, in parametrize arguments or in conftest.py, and the
+tests compile in their own process (a child could not load the library).
+"""
+import importlib
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+fa = importlib.import_module("bigdl_tpu.ops.flash_attention")
+pa = importlib.import_module("bigdl_tpu.ops.paged_attention")
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    # a compile for a described device is written to the persistent cache
+    # but cannot be read back without a chip: keep the cache off here
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2")
+    except Exception as e:
+        jax.config.update("jax_enable_compilation_cache", prev)
+        cc.reset_cache()
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield t
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def sds(one_chip):
+    def make(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype),
+                                    sharding=one_chip)
+    return make
+
+
+def _compile(fn, *args, **jit_kw):
+    compiled = jax.jit(fn, **jit_kw).lower(*args).compile()
+    return compiled, compiled.as_text()
+
+
+#: (B, H, T, D, dtype): GPT-2 XL's head geometry in both dtypes, and the
+#: shape at FLASH_AUTO_MIN_T where "auto" selects the flash kernel
+FLASH_SHAPES = [
+    (2, 25, 1024, 64, "bfloat16"),
+    (2, 25, 1024, 64, "float32"),
+    (1, 8, 4096, 128, "bfloat16"),
+]
+
+
+@pytest.mark.parametrize("segmented", [False, True],
+                         ids=["plain", "segmented"])
+@pytest.mark.parametrize("shape", FLASH_SHAPES,
+                         ids=lambda s: "x".join(map(str, s)))
+def test_flash_forward_compiles_for_v5e(sds, shape, segmented):
+    b, h, t, d, dt = shape
+    q = sds((b, h, t, d), dt)
+    seg = sds((b, t), jnp.int32) if segmented else None
+
+    def fwd(q, k, v, seg):
+        return fa._flash_fwd(q, k, v, seg, seg, True, 0.125, 128, 128,
+                             False)   # interpret=False: Mosaic compiles it
+
+    _, text = _compile(fwd, q, q, q, seg)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("segmented", [False, True],
+                         ids=["plain", "segmented"])
+@pytest.mark.parametrize("shape", FLASH_SHAPES,
+                         ids=lambda s: "x".join(map(str, s)))
+def test_flash_backward_kernels_compile_for_v5e(sds, shape, segmented):
+    """Both backward kernels (dk/dv over query blocks, dq over key
+    blocks) in one program."""
+    b, h, t, d, dt = shape
+    q = sds((b, h, t, d), dt)
+    lse = sds((b, h, t), jnp.float32)
+    seg = sds((b, t), jnp.int32) if segmented else None
+
+    def bwd(q, k, v, o, lse, do, seg):
+        return fa._flash_bwd(q, k, v, o, lse, do,
+                             jnp.zeros(lse.shape, jnp.float32), seg, seg,
+                             True, 0.125, 128, 128, False)
+
+    _, text = _compile(bwd, q, q, q, q, lse, q, seg)
+    assert text.count("tpu_custom_call") >= 2
+
+
+#: (S, H, D, block_len, table width M, dtype): GPT-2 XL, GPT-2, 16 x 128
+PAGED_SHAPES = [
+    (8, 25, 64, 16, 64, "bfloat16"),
+    (8, 25, 64, 16, 64, "float32"),
+    (8, 12, 64, 16, 64, "bfloat16"),
+    (8, 16, 128, 16, 64, "bfloat16"),
+]
+
+
+@pytest.mark.parametrize("shape", PAGED_SHAPES,
+                         ids=lambda s: "x".join(map(str, s)))
+def test_paged_decode_compiles_for_v5e(sds, shape):
+    """cache_len 1024 (M x block_len): the whole-context VMEM scratch and
+    its f32 upcast fit the kernel's memory."""
+    s, h, d, blk, m, dt = shape
+    q = sds((s, h, 1, d), dt)
+    arena = sds((s * m + 1, h, blk, d), dt)
+
+    def decode(q, ka, va, tables, pos):
+        return pa.paged_decode_attention(q, ka, va, tables, pos,
+                                         interpret=False)
+
+    _, text = _compile(decode, q, arena, arena, sds((s, m), jnp.int32),
+                       sds((s,), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+def test_kernel_shapes_the_compiler_cannot_take_raise():
+    """A geometry the compiled kernels cannot lay out is an error at the
+    call that asks for it, never a silent fallback (no compiler needed)."""
+    with pytest.raises(ValueError, match="multiple"):
+        pa.check_paged_kernel_shapes(8, jnp.bfloat16)   # 16-row bf16 tile
+    pa.check_paged_kernel_shapes(8, jnp.float32)
+    pa.check_paged_kernel_shapes(16, jnp.bfloat16)
+    q = jnp.zeros((1, 1, 64, 64), jnp.float32)
+    with pytest.raises(ValueError, match="multiples of 128"):
+        fa._flash_fwd(q, q, q, None, None, True, 0.125, 64, 64, False)
+
+
+def test_lm_decode_step_compiles_for_v5e(sds, monkeypatch):
+    """``LMServingEngine``'s paged decode step at GPT-2 XL widths (depth
+    cut to 2: the layers are one ``lax.scan`` body), gather and Pallas."""
+    from bigdl_tpu.models.transformer import TransformerLM
+    from bigdl_tpu.models.transformer import generate as G
+
+    # the step asks jax.default_backend(), which is the CPU here: steer
+    # it from the test, not through an option of the program
+    monkeypatch.setattr(pa, "_use_interpret", lambda: False)
+    layers, slots, width, blk, blocks = 2, 8, 64, 16, 96
+    model = TransformerLM(50257, 1600, 25, layers, max_len=1024)
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    arena = sds((layers, blocks, 25, blk, 64), jnp.float32)
+    args = (params, sds((slots,), jnp.int32), sds((slots,), jnp.int32),
+            sds((slots, width), jnp.int32), arena, arena)
+    for impl, wants_kernel in (("gather", False), ("paged_kernel", True)):
+        def step(p, tok, pos, tables, k, v, impl=impl):
+            return G._decode_step_paged(model, p, tok, pos, tables, k, v,
+                                        attn_impl=impl)
+
+        compiled, text = _compile(step, *args, donate_argnums=(4, 5))
+        assert ("tpu_custom_call" in text) == wants_kernel
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes >= 2 * np.prod(arena.shape) * 4 * 0.99
+
+
+def test_resnet50_train_step_compiles_for_v5e_and_fits(sds):
+    """The ResNet-50 bf16/NHWC step ``chip_smoke.py`` trains, at its batch:
+    the compiler takes it and one program's footprint leaves room in the
+    chip's 16 GB."""
+    import chip_smoke
+    from bigdl_tpu import nn
+    from bigdl_tpu.models import ResNet
+    from bigdl_tpu.optim import SGD
+    from bigdl_tpu.optim.optimizer import LocalOptimizer
+
+    batch = chip_smoke.REAL.train_batch
+    model = ResNet(class_num=1000, depth=50, dataset="imagenet",
+                   data_format="NHWC")
+    method = SGD(learning_rate=0.02, momentum=0.9, dampening=0.0)
+
+    def place(tree):
+        return jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype), tree)
+
+    params = place(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    buffers = place(jax.eval_shape(model.init_buffers))
+    opt_state = place(jax.eval_shape(method.init_state, params))
+    opt = LocalOptimizer(model, None, nn.ClassNLLCriterion())
+    opt.set_optim_method(method).set_compute_dtype(jnp.bfloat16)
+    compiled = opt._build_step().lower(
+        params, buffers, opt_state, sds((batch, 224, 224, 3), jnp.bfloat16),
+        sds((batch,), jnp.float32), sds((2,), jnp.uint32), 1).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < 12 * 2 ** 30, total

@@ -431,7 +431,7 @@ class TestResnetCli:
         computation and no test drove this CLI)."""
         from bigdl_tpu.models.resnet import train as cli
 
-        monkeypatch.setenv("BIGDL_TPU_PLATFORM", "cpu")
+        monkeypatch.setenv("JAX_PLATFORMS", "cpu")
         # tiny run: trim the synthetic dataset so one epoch is 2 batches
         from bigdl_tpu.dataset import cifar
         real_synth = cifar.synthetic
@@ -447,7 +447,7 @@ class TestResnetCli:
                                                       write_sequence_file)
         from bigdl_tpu.models.resnet import train as cli
 
-        monkeypatch.setenv("BIGDL_TPU_PLATFORM", "cpu")
+        monkeypatch.setenv("JAX_PLATFORMS", "cpu")
         rng = np.random.RandomState(0)
         records = [(str(i % 4 + 1).encode(),
                     encode_bgr_image((rng.rand(3, 256, 256) * 255)

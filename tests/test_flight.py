@@ -9,7 +9,7 @@ The three observability pillars this file pins:
   failover re-dispatch, reassembled per request from the flat ring;
 - :class:`FlightRecorder` — exactly ONE schema-valid ``FLIGHT_*.json``
   bundle per distinct incident, cross-referenced from the
-  ``TUNNEL_INCIDENTS.json`` ledger.
+  incident ledger.
 
 The chaos soak at the bottom is the acceptance test: replica death plus
 an injected stall mid-load must yield a span tree for every accepted
@@ -67,7 +67,7 @@ def recorder(tmp_path):
     old = flight_mod.get_flight_recorder()
     rec = flight_mod.configure(
         enabled=True, out_dir=str(tmp_path),
-        incidents_path=str(tmp_path / "TUNNEL_INCIDENTS.json"))
+        incidents_path=str(tmp_path / "INCIDENTS.json"))
     yield rec
     flight_mod._GLOBAL = old
 
@@ -279,7 +279,7 @@ def test_watchdog_stall_dumps_bundle(tmp_path, recorder):
 
 
 def test_cli_dump_writes_bundle_and_ledger_row(tmp_path):
-    env = dict(os.environ, PYTHONPATH=REPO, BIGDL_TPU_PLATFORM="cpu")
+    env = dict(os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu")
     env.pop("BIGDL_TPU_FLIGHT", None)  # CLI arms itself
     proc = subprocess.run(
         [sys.executable, "-m", "bigdl_tpu.obs.flight", "dump",
@@ -291,7 +291,7 @@ def test_cli_dump_writes_bundle_and_ledger_row(tmp_path):
     assert out["flight"] == "probe_death"
     assert validate_artifact(out["path"]) == []
     # ledger row looks like the old bare append PLUS the pointer
-    ledger = json.loads((tmp_path / "TUNNEL_INCIDENTS.json").read_text())
+    ledger = json.loads((tmp_path / "INCIDENTS.json").read_text())
     (row,) = ledger["incidents"]
     assert row["stage"] == "probe" and row["rc"] == 1
     assert row["flight"] == os.path.basename(out["path"])
@@ -451,7 +451,7 @@ def test_chaos_soak_span_trees_and_bundles(tmp_path, recorder,
             for i in range(12):
                 if i == 6:
                     # the injected stall, mid-load: a held-open step
-                    # past its deadline (the hung-relay signature)
+                    # past its deadline (the hung-backend signature)
                     wd = StallWatchdog(
                         "soak", deadline_s=0.01, poll_s=30.0,
                         tracer=Tracer(enabled=False),

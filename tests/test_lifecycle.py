@@ -60,7 +60,7 @@ _PROMPT = np.arange(1, 9, dtype=np.int32)
 # deadlines                                                                   #
 # --------------------------------------------------------------------------- #
 
-def test_deadline_typed_taxonomy():
+def test_deadline_typed_classification():
     """A blown deadline IS an overload shed: the SLO ladder and loadgen
     shed accounting must keep working unchanged."""
     assert issubclass(ServingDeadlineExceeded, ServingOverloaded)
@@ -111,7 +111,7 @@ def test_deadline_expires_while_queued_typed_shed(lc_model):
     try:
         eng.warmup()
         # occupy both slots with long decodes
-        busy = [eng.submit(_PROMPT, max_new_tokens=12) for _ in range(2)]
+        busy = [eng.submit(_PROMPT, max_new_tokens=48) for _ in range(2)]
         s = eng.submit(_PROMPT + 1, max_new_tokens=12, deadline_s=0.001)
         with pytest.raises(ServingDeadlineExceeded):
             s.result(timeout=60)
@@ -135,7 +135,9 @@ def test_cancel_frees_slot_and_conserves_refcounts(lc_model):
         eng.generate(_PROMPT, max_new_tokens=2, timeout=60)
         assert _wait(lambda: eng.stats()["active"] == 0)
         idle_free = eng.pool.free_count
-        s = eng.submit(_PROMPT, max_new_tokens=12)
+        # the whole context as budget: a poller starved under a loaded
+        # host must still find the stream mid-decode when it cancels
+        s = eng.submit(_PROMPT, max_new_tokens=48)
         _wait(lambda: len(s.generated) >= 1)   # seated and decoding
         assert s.cancel() is True
         s.result(timeout=60)
@@ -186,7 +188,7 @@ def test_cancel_while_queued_never_prefills(lc_model):
     eng = LMServingEngine(lc_model, **_ENG_KW)
     try:
         eng.warmup()
-        busy = [eng.submit(_PROMPT, max_new_tokens=12) for _ in range(2)]
+        busy = [eng.submit(_PROMPT, max_new_tokens=48) for _ in range(2)]
         s = eng.submit(_PROMPT + 2, max_new_tokens=12)
         assert s.cancel() is True
         s.result(timeout=60)
@@ -209,7 +211,7 @@ def test_cancel_hibernated_stream_without_resume(lc_model):
                           **_ENG_KW)
     try:
         eng.warmup()
-        s = eng.submit(_PROMPT, max_new_tokens=12)
+        s = eng.submit(_PROMPT, max_new_tokens=48)   # see the cancel test
         _wait(lambda: len(s.generated) >= 2)
         assert eng.hibernate(s, timeout=30.0)
         assert s.cancel() is True

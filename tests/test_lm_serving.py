@@ -380,8 +380,7 @@ def test_serve_lm_bench_cli(tmp_path):
     """bench.py --serve-lm end to end on CPU: resumable artifact with
     both continuous and static numbers and a final summary."""
     out = tmp_path / "BENCH_LM_SERVE.json"
-    env = dict(os.environ, BIGDL_TPU_BENCH_PLATFORM="cpu",
-               JAX_PLATFORMS="cpu")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     proc = subprocess.run(
         [sys.executable, "bench.py", "--serve-lm", "--json", str(out),
          "--requests", "8", "--slots", "2", "--cache-len", "128",

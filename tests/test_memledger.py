@@ -51,7 +51,7 @@ def recorder(tmp_path):
     old = flight_mod.get_flight_recorder()
     rec = flight_mod.configure(
         enabled=True, out_dir=str(tmp_path),
-        incidents_path=str(tmp_path / "TUNNEL_INCIDENTS.json"))
+        incidents_path=str(tmp_path / "INCIDENTS.json"))
     yield rec
     flight_mod._GLOBAL = old
 

@@ -374,16 +374,18 @@ def test_export_import_roundtrip_random_compositions(seed):
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y2))
 
 
-def test_chunked_device_array_slicing():
-    """The <=limit leading-axis slicing reassembles exactly (force=True
-    exercises the chunk path on CPU, where it normally short-circuits)."""
-    from bigdl_tpu.utils.torch_import import chunked_device_array
+def test_device_array_is_a_plain_put():
+    """Imported weights go to the device in one put, values and dtype
+    intact (the 32 MB slicing for the old backend is gone)."""
+    import jax
+    from bigdl_tpu.utils.torch_import import device_array
     a = np.arange(7 * 5, dtype=np.float32).reshape(7, 5)
-    out = chunked_device_array(a, limit_bytes=2 * 5 * 4, force=True)  # 2 rows/slice
+    out = device_array(a)
+    assert isinstance(out, jax.Array) and out.dtype == np.float32
     np.testing.assert_array_equal(np.asarray(out), a)
-    small = chunked_device_array(a)
-    np.testing.assert_array_equal(np.asarray(small), a)
-    scalar = chunked_device_array(np.float32(3.0))
+    half = device_array(a, np.float16)
+    assert half.dtype == np.float16
+    scalar = device_array(np.float32(3.0))
     assert float(scalar) == 3.0
 
 

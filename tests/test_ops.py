@@ -218,71 +218,6 @@ class TestPallasBackwardKernel:
                                        atol=1e-4, rtol=1e-4)
 
 
-@pytest.mark.slow
-def test_flash_kernels_lower_for_tpu_platform():
-    """Compile-level hardware-free proof (VERDICT r2 weak #3: 'flash
-    could fail to compile on the TPU backend'): jax.export with
-    platforms=['tpu'] runs the full Mosaic/TPU lowering pipeline on this
-    CPU host — tile-shape or layout errors in the Pallas kernels surface
-    here, not on the chip."""
-    import jax
-    import jax.numpy as jnp
-    from jax import export
-
-    from bigdl_tpu.ops import flash_attention
-
-    shape = (1, 4, 1024, 128)
-    args = [jax.ShapeDtypeStruct(shape, jnp.bfloat16)] * 3
-    fwd = export.export(
-        jax.jit(lambda q, k, v: flash_attention(q, k, v, causal=True)),
-        platforms=["tpu"])(*args)
-    assert fwd.platforms == ("tpu",)
-    assert len(fwd.mlir_module_serialized) > 0
-
-    def train(q, k, v):
-        return jax.grad(lambda a, b, c: flash_attention(
-            a, b, c, causal=True).astype(jnp.float32).sum(),
-            argnums=(0, 1, 2))(q, k, v)
-
-    bwd = export.export(jax.jit(train), platforms=["tpu"])(*args)
-    assert bwd.platforms == ("tpu",)
-
-    # the composed hot path: a small TransformerLM train step with
-    # flash + RoPE + remat + Adam must lower too (scripts/
-    # mosaic_export_check.py exports the full-size config)
-    from bigdl_tpu import nn
-    from bigdl_tpu.models import TransformerLM
-    from bigdl_tpu.nn._util import cast_f32_leaves
-    from bigdl_tpu.optim import Adam
-
-    model = TransformerLM(vocab_size=256, hidden_size=128, n_head=2,
-                          n_layers=2, max_len=512, remat=True,
-                          pos_encoding="rope",
-                          attention_impl="flash").build(seed=1)
-    crit = nn.TimeDistributedCriterion(nn.ClassNLLCriterion(), True)
-    method = Adam(learning_rate=1e-3)
-    params = model.params
-    opt_state = method.init_state(params)
-
-    def lm_step(params, opt_state, x, y):
-        def loss_fn(p):
-            out, _ = model.apply(cast_f32_leaves(p, jnp.bfloat16), x)
-            return crit.loss(out.astype(jnp.float32), y)
-        loss, grads = jax.value_and_grad(loss_fn)(params)
-        grads = jax.tree_util.tree_map(
-            lambda g: g.astype(jnp.float32), grads)
-        params, opt_state = method.update(grads, opt_state, params)
-        return params, opt_state, loss
-
-    sds = lambda a: jax.ShapeDtypeStruct(  # noqa: E731
-        jnp.asarray(a).shape, jnp.asarray(a).dtype)
-    xs = jax.ShapeDtypeStruct((1, 512), jnp.float32)
-    lm = export.export(jax.jit(lm_step), platforms=["tpu"])(
-        jax.tree_util.tree_map(sds, params),
-        jax.tree_util.tree_map(sds, opt_state), xs, xs)
-    assert lm.platforms == ("tpu",)
-
-
 class TestSegmentedFlash:
     """Packed-document isolation: segment_ids mask attention across
     document boundaries inside the flash tiles.  Oracle: the plain XLA

@@ -81,12 +81,10 @@ class TestAllReduceParameter:
             g_shard = arp.scatter_gradients({"w": g[: arp.size]}, mean=True)
             return w_full, g_shard
 
-        from bigdl_tpu.parallel.distri_optimizer import (_SHARD_MAP_NO_CHECK,
-                                                         shard_map)
-        mapped = shard_map(cycle, mesh=mesh,
-                           in_specs=(P(DATA_AXIS), P()),
-                           out_specs=(P(), P(DATA_AXIS)),
-                           **_SHARD_MAP_NO_CHECK)
+        mapped = jax.shard_map(cycle, mesh=mesh,
+                               in_specs=(P(DATA_AXIS), P()),
+                               out_specs=(P(), P(DATA_AXIS)),
+                               check_vma=False)
         grads = jnp.arange(arp.padded_size, dtype=jnp.float32)
         w_full, g_scat = mapped(w_flat, grads)
         # every device contributed the same grads; mean over 8 devices = grads

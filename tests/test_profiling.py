@@ -64,7 +64,8 @@ def test_attribute_step_time_fills_get_times_from_jitted_run(nprng):
     step_time = time.perf_counter() - t0
 
     m.reset_times()
-    rows = profiling.attribute_step_time(m, x, step_time, training=True)
+    rows = profiling.attribute_step_time(m, x, step_time, training=True,
+                                         device_kind="TPU v5 lite")
     assert abs(sum(r["time_s"] for r in rows) - step_time) < 1e-9
     times = m.get_times()
     per_layer = {mod.get_name(): f + b for mod, f, b in times
@@ -236,7 +237,8 @@ def test_roofline_attribution_bills_memory_bound_layers(nprng):
         nn.SpatialConvolution(3, 8, 3, 3, 1, 1, 1, 1),
         nn.SpatialBatchNormalization(8),
         nn.ReLU()).build(seed=1)
-    rows_rf = attribute_step_time(model2, x, 1.0, mode="roofline")
+    rows_rf = attribute_step_time(model2, x, 1.0, mode="roofline",
+                                  device_kind="TPU v5 lite")
 
     def share(rows, name_frag):
         return sum(r["time_s"] for r in rows if name_frag in type(r["module"]).__name__)

@@ -367,7 +367,7 @@ def test_kill_hibernation_holder_resume_re_routes(rt_model, rt_reference):
         assert rs.hibernate(st)
         victim = st.replica_name
         rs.kill_replica(victim)          # tier entry dies with it
-        # _fail_all woke the relay; give it a beat to re-dispatch
+        # _fail_all woke the forwarder; give it a beat to re-dispatch
         deadline = time.perf_counter() + 30
         while st.re_dispatches == 0 and time.perf_counter() < deadline:
             time.sleep(0.01)

@@ -76,7 +76,8 @@ def test_pipeline_bench_stream_shapes(tmp_path):
 def test_pipeline_bench_host_only_mode(tmp_path):
     """--host-only measures delivery with no device step (it must work
     with a wedged accelerator: no jax backend use anywhere on the path)
-    and reports the headroom against the recorded chip rate."""
+    and assumes no chip rate: the comparison belongs to whoever has
+    measured one."""
     import bigdl_tpu.models.utils.pipeline_bench as pb
     crop, stored = pb.CROP, pb.STORED
     pb.CROP, pb.STORED = 16, 24
@@ -87,6 +88,5 @@ def test_pipeline_bench_host_only_mode(tmp_path):
         pb.CROP, pb.STORED = crop, stored
     assert r["value"] > 0
     assert r["metric"] == "input_pipeline_host_delivery_images_per_sec"
-    assert 0 < r["headroom_vs_r1_chip_rate"] == round(
-        r["value"] / r["chip_consumption_rate_r1"], 3)
+    assert not any("chip" in k for k in r)
     assert isinstance(r["native_batcher"], bool)

@@ -154,7 +154,7 @@ def test_batcher_close_timeout_resolves_inflight_and_queued():
     served = []
 
     def wedged(x):
-        release.wait(20)  # the dead-tunnel stand-in: a stuck device call
+        release.wait(20)  # the dead-backend stand-in: a stuck device call
         served.append(x.shape)
         return x
 

@@ -144,7 +144,7 @@ def test_queue_full_is_typed_and_counted():
             try:
                 batcher.submit(np.zeros((1, 4), np.float32))
             except ServingQueueFull as e:
-                # the taxonomy contract: overload is transient —
+                # the classification contract: overload is transient —
                 # retryable after load drains, never a backend loss
                 assert isinstance(e, ServingOverloaded)
                 assert classify_error(e) == "transient"
