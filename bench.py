@@ -49,10 +49,8 @@ def main() -> None:
 def _run(batch: int) -> None:
     import jax
 
-    from bigdl_tpu.utils.engine import configure_compile_cache
     from bigdl_tpu.utils.profiling import device_peaks
 
-    configure_compile_cache()
     device = jax.devices()[0]
     if device.platform != "tpu":
         raise SystemExit(
@@ -314,8 +312,6 @@ def _serve_bench(argv) -> int:
     if args.trace:
         get_tracer().enable()
 
-    from bigdl_tpu.utils.engine import configure_compile_cache
-    configure_compile_cache()
     import jax
     import numpy as np
     from bigdl_tpu.models import LeNet5
@@ -536,8 +532,6 @@ def _serve_mesh_bench(argv) -> int:
             flags + f" --xla_force_host_platform_device_count="
             f"{args.devices}").strip()
 
-    from bigdl_tpu.utils.engine import configure_compile_cache
-    configure_compile_cache()
     import jax
     import numpy as np
     from bigdl_tpu import nn
@@ -814,8 +808,6 @@ def _serve_lm_bench(argv) -> int:
     if args.trace:
         get_tracer().enable()
 
-    from bigdl_tpu.utils.engine import configure_compile_cache
-    configure_compile_cache()
     import jax
     import numpy as np
     from bigdl_tpu.models.transformer import TransformerLM
@@ -1051,8 +1043,6 @@ def _serve_lm_spec_bench(argv) -> int:
         args.json = os.path.join(
             os.path.dirname(os.path.abspath(__file__)), "BENCH_SPEC.json")
 
-    from bigdl_tpu.utils.engine import configure_compile_cache
-    configure_compile_cache()
     import jax
     import numpy as np
     from bigdl_tpu.models.transformer import TransformerLM
@@ -1357,8 +1347,6 @@ def _serve_lm_spec2_bench(argv) -> int:
         args.json = os.path.join(
             os.path.dirname(os.path.abspath(__file__)), "BENCH_SPEC2.json")
 
-    from bigdl_tpu.utils.engine import configure_compile_cache
-    configure_compile_cache()
     import jax
     import numpy as np
     from bigdl_tpu.models.transformer import TransformerLM
@@ -1560,8 +1548,6 @@ def _serve_lm_qcompute_bench(argv) -> int:
             os.path.dirname(os.path.abspath(__file__)),
             "BENCH_QCOMPUTE.json")
 
-    from bigdl_tpu.utils.engine import configure_compile_cache
-    configure_compile_cache()
     import jax
     import numpy as np
     from bigdl_tpu.models.transformer import TransformerLM
@@ -1812,8 +1798,6 @@ def _serve_lm_prefix_bench(argv) -> int:
             os.path.dirname(os.path.abspath(__file__)),
             "BENCH_PREFIX.json")
 
-    from bigdl_tpu.utils.engine import configure_compile_cache
-    configure_compile_cache()
     import jax
     import numpy as np
     from bigdl_tpu.models.transformer import TransformerLM
@@ -2005,8 +1989,6 @@ def _serve_lm_kvtier_bench(argv) -> int:
             os.path.dirname(os.path.abspath(__file__)),
             "BENCH_KVTIER.json")
 
-    from bigdl_tpu.utils.engine import configure_compile_cache
-    configure_compile_cache()
     import jax
     import numpy as np
     from bigdl_tpu.models.transformer import TransformerLM
@@ -2311,8 +2293,6 @@ def _serve_lm_router_bench(argv) -> int:
     if args.turns < 2 or args.sessions < 2 or args.replicas < 2:
         ap.error("need >= 2 sessions, >= 2 turns, >= 2 replicas")
 
-    from bigdl_tpu.utils.engine import configure_compile_cache
-    configure_compile_cache()
     import jax
     import numpy as np
     from bigdl_tpu.models.transformer import TransformerLM
@@ -2676,8 +2656,6 @@ def _serve_lm_deadline_bench(argv) -> int:
     if args.replicas < 2:
         ap.error("need >= 2 replicas (chaos kills one mid-trace)")
 
-    from bigdl_tpu.utils.engine import configure_compile_cache
-    configure_compile_cache()
     import threading
 
     import jax
@@ -3019,8 +2997,6 @@ def _serve_lm_disagg_bench(argv) -> int:
             os.path.dirname(os.path.abspath(__file__)),
             "BENCH_DISAGG.json")
 
-    from bigdl_tpu.utils.engine import configure_compile_cache
-    configure_compile_cache()
     import jax
     import numpy as np
     from bigdl_tpu.models.transformer import TransformerLM
@@ -3371,8 +3347,6 @@ def _slo_bench(argv) -> int:
             os.path.dirname(os.path.abspath(__file__)), "BENCH_SLO.json")
     loads = [float(v) for v in args.loads.split(",") if v.strip()]
 
-    from bigdl_tpu.utils.engine import configure_compile_cache
-    configure_compile_cache()
     import jax
     from bigdl_tpu.models.transformer import TransformerLM
     from bigdl_tpu.obs import get_registry
@@ -3582,8 +3556,6 @@ def _attn_bench(argv) -> int:
                     help="BENCH_ATTN output path (default: repo root)")
     args = ap.parse_args(argv)
 
-    from bigdl_tpu.utils.engine import configure_compile_cache
-    configure_compile_cache()
     from bigdl_tpu.ops import autotune
 
     seq_lens = [int(s) for s in args.sweep.split(",")]
@@ -3658,8 +3630,6 @@ def _memprofile_bench(argv) -> int:
             os.path.dirname(os.path.abspath(__file__)),
             "PROFILE_MEM.json")
 
-    from bigdl_tpu.utils.engine import configure_compile_cache
-    configure_compile_cache()
     import jax
     import numpy as np
     from bigdl_tpu.models import LeNet5
@@ -3814,6 +3784,8 @@ if __name__ == "__main__":
         # training bench: the tracer arms from the environment
         sys.argv = [a for a in sys.argv if a != "--trace"]
         os.environ["BIGDL_TPU_TRACE"] = "1"
+    from bigdl_tpu.utils.engine import configure_compile_cache
+    configure_compile_cache()       # once, for whichever mode runs
     if "--attn" in sys.argv:
         sys.exit(_attn_bench([a for a in sys.argv[1:] if a != "--attn"]))
     if "--memprofile" in sys.argv:

@@ -114,7 +114,10 @@ class Module:
         init_rng, self._rng = jax.random.split(rng)
         self.params = self.init(init_rng)
         self.buffers = self.init_buffers()
-        self.zero_grad_parameters()
+        # gradient buffers are a second copy of the weights that only the
+        # OO training shell reads: made on first use (_grads), never here,
+        # so a built model served at GPT-2 XL widths fits one chip
+        self.grad_params = None
         return self
 
     def reset(self, seed: int | jax.Array = 0) -> "Module":
@@ -263,11 +266,17 @@ class Module:
         if self.params is not None:
             self.grad_params = jax.tree_util.tree_map(jnp.zeros_like, self.params)
 
+    def _grads(self) -> Params:
+        """Accumulated parameter gradients; zeros before any backward."""
+        if self.grad_params is None:
+            self.zero_grad_parameters()
+        return self.grad_params
+
     def parameters(self):
         """(weights, gradWeights) as parallel leaf lists (ref :227)."""
         self._built()
         w = jax.tree_util.tree_leaves(self.params)
-        g = jax.tree_util.tree_leaves(self.grad_params)
+        g = jax.tree_util.tree_leaves(self._grads())
         return w, g
 
     def get_parameters(self):
@@ -277,7 +286,7 @@ class Module:
         from jax.flatten_util import ravel_pytree
         self._built()
         flat_w, unravel = ravel_pytree(self.params)
-        flat_g, _ = ravel_pytree(self.grad_params)
+        flat_g, _ = ravel_pytree(self._grads())
         return flat_w, flat_g, unravel
 
     def get_parameters_table(self):
@@ -285,10 +294,11 @@ class Module:
         from bigdl_tpu.utils.table import T
         self._built()
         table = T()
-        self._collect_param_table(table, self.get_name(), self.params, self.grad_params)
+        self._collect_param_table(table, self.get_name(), self.params, self._grads())
         return table
 
     def _collect_param_table(self, table, name, params, grads):
+        from bigdl_tpu.utils.table import T
         if isinstance(params, dict) and params:
             entry = T()
             for k, v in params.items():

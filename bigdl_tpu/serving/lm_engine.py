@@ -1127,23 +1127,35 @@ class LMServingEngine:
             log.exception("flight-recorder registration failed")
 
     def _publish_kv_metrics(self, registry) -> None:
+        # the process-wide registry outlives the engine: its gauges read
+        # through a weakref, or a closed engine (and the model's weights
+        # behind it) could never be collected
+        import weakref
+        ref = weakref.ref(self)
+
+        def gauge(read):
+            def fn():
+                eng = ref()
+                return None if eng is None else read(eng)
+            return FnGauge(fn)
+
         registry.register("kvcache/block_utilization",
-                          FnGauge(lambda: self.pool.utilization()),
+                          gauge(lambda e: e.pool.utilization()),
                           replace=True)
         registry.register(
             "kvcache/prefix_hit_rate",
-            FnGauge(lambda: self.radix.hit_rate()
-                    if self.radix is not None else None),
+            gauge(lambda e: e.radix.hit_rate()
+                  if e.radix is not None else None),
             replace=True)
         registry.register(
             "kvcache/prefill_tokens_saved",
-            FnGauge(lambda: self.radix.matched_tokens
-                    if self.radix is not None else 0),
+            gauge(lambda e: e.radix.matched_tokens
+                  if e.radix is not None else 0),
             replace=True)
         registry.register(
             "kvcache/evictions",
-            FnGauge(lambda: self.radix.evictions
-                    if self.radix is not None else 0),
+            gauge(lambda e: e.radix.evictions
+                  if e.radix is not None else 0),
             replace=True)
         registry.gauge("kvcache/arena_bytes",
                        unit="bytes").set(self.pool.arena_bytes)

@@ -264,6 +264,4 @@ def load_module(path: str, template=None):
                     f"template expects {tuple(r.shape)}")
     module.params = params
     module.buffers = state["buffers"]
-    if module.grad_params is None:
-        module.zero_grad_parameters()
     return module

@@ -214,16 +214,11 @@ def _peak_bytes() -> Optional[int]:
     return None if not stats else stats.get("peak_bytes_in_use")
 
 
-def _free_device_memory(*modules) -> None:
-    """Drop compiled programs and garbage; ``modules`` also give their
-    weights back now — jit caches keyed on a static module (offline
-    ``generate()``) would otherwise keep 6 GB of LM alive under the
-    trainer."""
-    for m in modules:
-        for leaf in jax.tree_util.tree_leaves((m.params, m.buffers)):
-            if isinstance(leaf, jax.Array) and not leaf.is_deleted():
-                leaf.delete()
-        m.params = m.buffers = None
+def _free_device_memory() -> None:
+    """Drop garbage and compiled programs.  ``jax.clear_caches()`` is what
+    lets go of a model the caller has dropped: offline ``generate()`` jits
+    with the module as a static argument, so its cache keeps the module,
+    and the weights on it, until it is cleared."""
     gc.collect()
     jax.clear_caches()
     gc.collect()
@@ -244,14 +239,9 @@ def _rel_err(a, b) -> float:
 # --------------------------------------------------------------------------
 
 def _build_lm(sz: Sizes, seed: int) -> TransformerLM:
-    model = TransformerLM(
+    return TransformerLM(
         vocab_size=sz.vocab, hidden_size=sz.hidden, n_head=sz.heads,
-        n_layers=sz.layers, max_len=sz.context).build(seed=seed)
-    # build() also allocates the training shell's gradient buffers, a
-    # second copy of the weights that serving never reads: at 48 layers
-    # in f32 that copy alone is 6.2 GB of the chip's 16
-    model.grad_params = None
-    return model.evaluate()
+        n_layers=sz.layers, max_len=sz.context).build(seed=seed).evaluate()
 
 
 def _requests(sz: Sizes, seed: int) -> list:
@@ -431,7 +421,7 @@ def phase_serve(sz: Sizes = REAL, seed: int = 0) -> Tuple[dict, tuple]:
         "dtype": str(jnp.dtype(model.params["embed"].dtype)),
         "dtype_note": "f32 weights and KV: the engine has no dtype "
                       "argument, arenas take the embedding's dtype",
-        "gradient_buffers": "dropped after build() (serving reads none)",
+        "gradient_buffers_allocated": model.grad_params is not None,
         "slots": sz.slots, "prefill_buckets": list(sz.prefill_buckets),
         "prompt_lens": [len(r["prompt"]) for r in reqs],
         "temperatures": [r["temperature"] for r in reqs],
@@ -684,10 +674,12 @@ def phase_multichip(sz: Sizes = REAL, seed: int = 0, devices=None,
         _check(len({d for d, _ in shards[name]}) == 4
                and len({i for _, i in shards[name]}) == 4,
                f"{name} do not sit one per device: {shards[name]}")
-    # the program as written (lowered) must hold the ZeRO-1 pair; the
-    # program as compiled for this platform must still move the parameter
-    # vector twice — the TPU compiler is free to spell either collective
-    # as an all-reduce, and the smoke prints what it chose
+    # the program as written (lowered) must hold the ZeRO-1 pair.  What
+    # the TPU compiler keeps of it is printed, not required: on a v5e 2x2
+    # it spells both collectives as whole-vector all-reduces (about twice
+    # the bytes), so the issue's criterion "the compiled program shows the
+    # reduce-scatter/all-gather" is NOT met — an open defect (ROADMAP S9),
+    # stated in the row and never passed off by a byte count
     from bigdl_tpu.utils import profiling
     lowered = profiling.collective_footprint(
         opt4._step_fn_ref.lower(*opt4._step_avals).as_text(dialect="hlo"))
@@ -695,13 +687,10 @@ def phase_multichip(sz: Sizes = REAL, seed: int = 0, devices=None,
     _check(lowered.get("all-gather", 0) > 0
            and lowered.get("reduce-scatter", 0) > 0,
            f"no all-gather/reduce-scatter in the lowered step: {lowered}")
-    # results counted: the gathered vector whole, the scattered one a
-    # quarter (bf16 transport); an all-reduce spelling counts more
-    vector = 2 * int(np.prod(w_aval.shape))
-    _check(sum(footprint.values()) >= vector + vector // 4,
-           f"the compiled step's collectives produce {footprint}: less "
-           f"than one gathered and one scattered parameter vector "
-           f"({vector} bytes whole)")
+    _check(sum(footprint.values()) > 0,
+           f"the compiled 4-device step holds no collective: {footprint}")
+    zero1_compiled = (footprint.get("all-gather", 0) > 0
+                      and footprint.get("reduce-scatter", 0) > 0)
     # what it is compared with: the same steps on ONE device.  Four
     # micro-batches there see the rows (and the BatchNorm statistics) the
     # four devices saw here, so the losses are the same arithmetic.
@@ -724,7 +713,8 @@ def phase_multichip(sz: Sizes = REAL, seed: int = 0, devices=None,
            "loss_rel_diff": [round(x, 5) for x in rel],
            "loss_rtol": MULTICHIP_LOSS_RTOL,
            "collective_footprint_bytes_lowered": lowered,
-           "collective_footprint_bytes_compiled": footprint, **shards,
+           "collective_footprint_bytes_compiled": footprint,
+           "zero1_collectives_in_compiled": zero1_compiled, **shards,
            **PROBE.since(mark), "peak_bytes": _peak_bytes()}
     _emit(row)
     return row
@@ -769,8 +759,6 @@ def main(argv=None) -> int:
                     help="4 runs ONLY the multi-chip phase and what it is "
                          "compared with")
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--lm-layers", type=int, default=REAL.layers,
-                    help="depth cut for the LM (printed); widths never")
     args = ap.parse_args(argv)
 
     cache_dir = configure_compile_cache()
@@ -778,16 +766,15 @@ def main(argv=None) -> int:
     device = _preflight(args.chips)
     _emit({"phase": "preflight", "ok": True, **device,
            "compile_cache_dir": cache_dir})
-    sz = dataclasses.replace(REAL, layers=args.lm_layers)
     t0 = time.perf_counter()
     if args.chips == 4:
-        phase_multichip(sz, args.seed)
+        phase_multichip(REAL, args.seed)
     else:
-        _, carry = phase_serve(sz, args.seed)
-        phase_kernels(sz, args.seed, carry=carry)
-        _free_device_memory(carry[0])       # the LM, before the trainer
-        del carry
-        phase_train(sz, args.seed)
+        _, carry = phase_serve(REAL, args.seed)
+        phase_kernels(REAL, args.seed, carry=carry)
+        del carry                   # the LM, before the trainer
+        _free_device_memory()
+        phase_train(REAL, args.seed)
     interpreted = sorted({n for n, i in PROBE.pallas_calls if i})
     _check(not interpreted,
            f"Pallas calls ran with interpret=True: {interpreted}")

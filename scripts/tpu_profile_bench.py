@@ -68,6 +68,7 @@ def _measure_one(batch: int, timeout: float, iters: int,
             if "value" in parsed:
                 row["images_per_s"] = parsed["value"]
                 row["mfu"] = parsed.get("mfu")
+                row["device_kind"] = parsed.get("device_kind")
                 row["step_s"] = round(batch / parsed["value"], 5) \
                     if parsed["value"] else None
                 break
@@ -139,11 +140,10 @@ def sweep_flags(batch: int, timeout: float, iters: int, deadline: float,
     return rows
 
 
-#: the chip whose published peaks weigh the roofline split
-ATTRIBUTION_DEVICE_KIND = "TPU v5 lite"
-
-
-def attribute_cpu(step_s: float, batch: int, top_n: int = 25) -> list[dict]:
+def attribute_cpu(step_s: float, batch: int, device_kind: str,
+                  top_n: int = 25) -> list[dict]:
+    """Roofline split of ``step_s`` against the published peaks of the
+    chip it was measured on (``device_kind``: unknown kinds raise)."""
     os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
     import numpy as np
 
@@ -157,7 +157,7 @@ def attribute_cpu(step_s: float, batch: int, top_n: int = 25) -> list[dict]:
     # linearly with batch so the split is batch-invariant
     x = np.random.RandomState(0).randn(8, 224, 224, 3).astype(np.float32)
     rows = attribute_step_time(model, x, step_s, mode="roofline",
-                               device_kind=ATTRIBUTION_DEVICE_KIND)
+                               device_kind=device_kind)
     rows.sort(key=lambda r: -r["time_s"])
     out = []
     for r in rows[:top_n]:
@@ -179,6 +179,9 @@ def main(argv=None) -> None:
     p.add_argument("--skip-measure", action="store_true",
                    help="attribution only, using --assume-step-s")
     p.add_argument("--assume-step-s", type=float, default=None)
+    p.add_argument("--device-kind", default=None,
+                   help="the chip --assume-step-s was taken on (a measured "
+                        "row carries its own)")
     p.add_argument("--flag-sweep", action="store_true",
                    help="after the batch sweep, re-measure the best batch "
                         "under each XLA flag preset (MFU experiment loop "
@@ -268,12 +271,16 @@ def main(argv=None) -> None:
     step_s = (args.assume_step_s if args.assume_step_s
               else (best["step_s"] if best else None))
     batch = best["batch"] if best else batches[0]
-    if step_s:
+    kind = args.device_kind or (best or {}).get("device_kind")
+    if step_s and not kind:
+        result["error"] = ("no device kind to attribute against: the row "
+                           "carries none and --device-kind was not given")
+    elif step_s:
         result["attribution"] = {
             "step_s": step_s, "batch": batch,
             "model": f"roofline against the published peaks of "
-                     f"{ATTRIBUTION_DEVICE_KIND!r} (DEVICE_PEAKS)",
-            "layers": attribute_cpu(step_s, batch)}
+                     f"{kind!r} (DEVICE_PEAKS)",
+            "layers": attribute_cpu(step_s, batch, kind)}
     else:
         result["error"] = "no successful TPU measurement to attribute"
     # complete means "every configured row got a real attempt": rows the

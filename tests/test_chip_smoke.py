@@ -71,9 +71,21 @@ def test_script_alone_without_the_program_fails(tmp_path):
 # the phases at toy size                                                #
 # --------------------------------------------------------------------- #
 
+def test_the_only_options_are_chips_and_seed():
+    """The LM's depth is the constant 48: no option cuts it (a one-layer
+    toy must not be able to print the smoke's last line)."""
+    assert chip_smoke.REAL.layers == 48
+    with pytest.raises(SystemExit):
+        chip_smoke.main(["--lm-layers", "1"])
+
+
 def test_serve_and_kernels_phases_pass_at_toy_size(probe, capsys):
+    import gc
+    import weakref
     serve, carry = chip_smoke.phase_serve(TOY, seed=0)
     assert serve["ok"] and serve["streams_exact"] == 6
+    # build() made no gradient buffers, and the smoke dropped none
+    assert serve["gradient_buffers_allocated"] is False
     assert serve["decode_attn_resolved"] == "gather"
     assert all(v == 0 for v in serve["compiles_after_warmup"].values())
     assert serve["prefix_cache"]["hits"] >= 1
@@ -89,6 +101,15 @@ def test_serve_and_kernels_phases_pass_at_toy_size(probe, capsys):
                for n in kernels["pallas_interpreted"])
     rows = [json.loads(line) for line in capsys.readouterr().out.splitlines()]
     assert [r["phase"] for r in rows] == ["serve", "kernels"]
+    # what main() does before the trainer: drop the LM and clear jax's
+    # caches.  Two closed engines and offline generate() have used the
+    # model; nothing may keep it (or its weights) alive after that
+    model = weakref.ref(carry[0])
+    leaf = weakref.ref(jax.tree_util.tree_leaves(carry[0].params)[0])
+    del carry
+    chip_smoke._free_device_memory()
+    gc.collect()
+    assert model() is None and leaf() is None
 
 
 def test_stream_divergence_passes_only_a_near_tie(probe):
@@ -127,6 +148,9 @@ def test_multichip_phase_passes_on_four_virtual_devices(probe):
     assert {d for d, _ in row["batch_shards"]} == set(row["mesh_devices"])
     lowered = row["collective_footprint_bytes_lowered"]
     assert lowered["all-gather"] > 0 and lowered["reduce-scatter"] > 0
+    # on the CPU the footprint is read off the lowered text, so the pair
+    # is there; on the chip the row says what the TPU compiler kept
+    assert row["zero1_collectives_in_compiled"] is True
     assert max(row["loss_rel_diff"]) <= chip_smoke.MULTICHIP_LOSS_RTOL
     # and it insists on real chips when asked to
     with pytest.raises(chip_smoke.SmokeFailure, match="not all tpu"):

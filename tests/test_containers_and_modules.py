@@ -39,6 +39,28 @@ class TestSequential:
         assert chex_equal
 
 
+    def test_gradient_buffers_are_made_on_first_use(self, tmp_path):
+        """build() and load() allocate no second copy of the weights (a
+        served model never reads one); the OO readers see zeros before any
+        backward, and backward accumulates as before."""
+        model = nn.Sequential(nn.Linear(4, 3)).build(seed=0)
+        assert model.grad_params is None
+        model.save(str(tmp_path / "m"))
+        assert nn.Module.load(str(tmp_path / "m")).grad_params is None
+        _, flat_g, _ = model.get_parameters()
+        assert flat_g.shape == (4 * 3 + 3,) and not flat_g.any()
+        fresh = nn.Sequential(nn.Linear(4, 3)).build(seed=0)
+        x = jnp.ones((2, 4))
+        for m in (model, fresh):        # zeros made above / never made
+            y = m.forward(x)
+            m.backward(x, jnp.ones_like(y))
+            m.backward(x, jnp.ones_like(y))
+        g1, g2 = model.get_parameters()[1], fresh.get_parameters()[1]
+        assert jnp.allclose(g1, g2) and bool(g1.any())
+        entry = model.get_parameters_table()["Linear@0"]
+        assert jnp.allclose(entry["gradBias"], g1[-3:])
+
+
 class TestBranches:
     def test_concat(self, rng):
         m = nn.Concat(2, nn.Linear(4, 3), nn.Linear(4, 5))

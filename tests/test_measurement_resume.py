@@ -147,7 +147,8 @@ def test_profile_resume_skips_measured_batches(tmp_path):
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "scripts", "tpu_profile_bench.py"),
          "--batches", "256,512", "--flag-sweep", "--deadline", "60",
-         "--json", art, "--assume-step-s", "0.24"],
+         "--json", art, "--assume-step-s", "0.24",
+         "--device-kind", "TPU v5 lite"],
         cwd=REPO, env=env, capture_output=True, text=True, timeout=300)
     assert proc.returncode == 0, proc.stderr[-800:]
     d = json.loads(art.read_text())
