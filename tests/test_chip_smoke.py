@@ -110,6 +110,10 @@ def test_serve_and_kernels_phases_pass_at_toy_size(probe, capsys):
     chip_smoke._free_device_memory()
     gc.collect()
     assert model() is None and leaf() is None
+    # ... and no weight, KV arena or logit row of that LM is left behind
+    lm_sized = [a.shape for a in jax.live_arrays()
+                if TOY.vocab in a.shape or TOY.num_blocks in a.shape]
+    assert not lm_sized, lm_sized
 
 
 def test_stream_divergence_passes_only_a_near_tie(probe):
