@@ -113,10 +113,10 @@ def _prefill_parts(model, params, ids0, last_index):
     return logits.astype(jnp.float32), k, v
 
 
-@functools.partial(jax.jit, static_argnums=(0, 3))
 def _prefill(model, params, ids0, cache_len):
     """Offline prefill: prompt logits + k/v padded to (L, B, H,
-    cache_len, D), ready for the in-place decode scan."""
+    cache_len, D), ready for the in-place decode scan.  Jitted per model
+    by :func:`_offline_programs`."""
     from bigdl_tpu.quant import dequantize_entry
     params = dequantize_entry(params)  # int8 clones generate too
     t = ids0.shape[1]
@@ -678,10 +678,10 @@ def _decode_step(model, params, token, pos, k_cache, v_cache):
                               k_cache, v_cache)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 2))
 def _decode_scan(model, params, max_new, first_token, pos0,
                  k_cache, v_cache, rng, temperature):
-    """max_new cached steps under one scan.  first_token is 0-based."""
+    """max_new cached steps under one scan.  first_token is 0-based.
+    Jitted per model by :func:`_offline_programs`."""
     from bigdl_tpu.quant import dequantize_entry
     params = dequantize_entry(params)
 
@@ -698,6 +698,21 @@ def _decode_scan(model, params, max_new, first_token, pos0,
     (_, _, _, _), out = lax.scan(
         step, (first_token, pos0, k_cache, v_cache), keys)
     return out.T  # (B, max_new), 0-based
+
+
+def _offline_programs(model):
+    """The jitted offline prefill and decode scan of ``model``, kept on the
+    module itself (``_jit_cache``, beside its jitted apply).  A module-level
+    jit with the module as a static argument would hold every model that
+    ever generated — and the weights on its shell — in jax's process-wide
+    cache until ``jax.clear_caches()``; kept here, they go when it goes."""
+    fns = model._jit_cache.get("generate")
+    if fns is None:
+        fns = model._jit_cache["generate"] = (
+            jax.jit(functools.partial(_prefill, model), static_argnums=(2,)),
+            jax.jit(functools.partial(_decode_scan, model),
+                    static_argnums=(1,)))
+    return fns
 
 
 def generate(model: TransformerLM, params, prompt_ids, max_new_tokens: int,
@@ -734,7 +749,8 @@ def generate(model: TransformerLM, params, prompt_ids, max_new_tokens: int,
         rng = jax.random.PRNGKey(0)
 
     ids0 = ids - 1
-    logits, k_cache, v_cache = _prefill(model, params, ids0, cache_len)
+    prefill, decode_scan = _offline_programs(model)
+    logits, k_cache, v_cache = prefill(params, ids0, cache_len)
     greedy = jnp.argmax(logits, axis=-1)
     if temperature > 0.0:
         rng, sub = jax.random.split(rng)
@@ -743,8 +759,8 @@ def generate(model: TransformerLM, params, prompt_ids, max_new_tokens: int,
         first = greedy
     if max_new_tokens == 1:
         return jnp.concatenate([ids, first[:, None] + 1], axis=1)
-    rest = _decode_scan(model, params, int(max_new_tokens) - 1,
-                        first, jnp.int32(t), k_cache, v_cache, rng,
-                        jnp.float32(temperature))
+    rest = decode_scan(params, int(max_new_tokens) - 1,
+                       first, jnp.int32(t), k_cache, v_cache, rng,
+                       jnp.float32(temperature))
     out = jnp.concatenate([first[:, None], rest], axis=1)
     return jnp.concatenate([ids, out + 1], axis=1)

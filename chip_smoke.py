@@ -215,10 +215,9 @@ def _peak_bytes() -> Optional[int]:
 
 
 def _free_device_memory() -> None:
-    """Drop garbage and compiled programs.  ``jax.clear_caches()`` is what
-    lets go of a model the caller has dropped: offline ``generate()`` jits
-    with the module as a static argument, so its cache keeps the module,
-    and the weights on it, until it is cleared."""
+    """Between phases: collect what the caller has dropped (a model goes
+    with its last reference — no cache of the program keeps one) and
+    unload the compiled programs of the phase that ended."""
     gc.collect()
     jax.clear_caches()
     gc.collect()

@@ -101,15 +101,16 @@ def test_serve_and_kernels_phases_pass_at_toy_size(probe, capsys):
                for n in kernels["pallas_interpreted"])
     rows = [json.loads(line) for line in capsys.readouterr().out.splitlines()]
     assert [r["phase"] for r in rows] == ["serve", "kernels"]
-    # what main() does before the trainer: drop the LM and clear jax's
-    # caches.  Two closed engines and offline generate() have used the
-    # model; nothing may keep it (or its weights) alive after that
+    # what main() does before the trainer: drop the LM.  Two closed
+    # engines and offline generate() have used the model; no registry and
+    # no jit cache of the program may keep it (or its weights) alive —
+    # the last reference gone, plain garbage collection takes it
     model = weakref.ref(carry[0])
     leaf = weakref.ref(jax.tree_util.tree_leaves(carry[0].params)[0])
     del carry
-    chip_smoke._free_device_memory()
     gc.collect()
     assert model() is None and leaf() is None
+    chip_smoke._free_device_memory()
     # ... and no weight, KV arena or logit row of that LM is left behind
     lm_sized = [a.shape for a in jax.live_arrays()
                 if TOY.vocab in a.shape or TOY.num_blocks in a.shape]
