@@ -1,0 +1,9 @@
+"""CPU rehearsals of the benchmark (not part of tier-1):
+``JAX_PLATFORMS=cpu python3 -m pytest benchmarks/tests -q``."""
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
