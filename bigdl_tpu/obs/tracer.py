@@ -5,7 +5,7 @@ the Spark driver (optim/Metrics.scala); a counter tells you the *mean*
 cost of a phase, never which iteration or which request was slow.  This
 module is the missing timeline: a thread-safe span API whose events
 export as Chrome trace-event JSON (loadable in Perfetto / chrome://
-tracing) and as a structured JSONL log.
+tracing).
 
 Design constraints, in order:
 
@@ -15,7 +15,9 @@ Design constraints, in order:
    attribute check returning a shared no-op context manager;
 2. thread-safe and allocation-bounded — events land in a ring buffer
    (``collections.deque`` with ``maxlen``), so a week-long serving
-   process can keep tracing without growing;
+   process can keep tracing without growing; what a full ring pushes
+   out is counted (``Tracer.dropped``), so a reader of the whole window
+   knows when its start is gone;
 3. retroactive spans — the batcher learns a request's queue wait only
    at dispatch time, so ``add_complete`` accepts an explicit start
    timestamp instead of requiring a context manager around the wait.
@@ -45,7 +47,6 @@ import threading
 import time
 import zlib
 from collections import deque
-from functools import wraps
 from typing import Optional
 
 
@@ -150,6 +151,8 @@ class Tracer:
         self.sample_rate = (_env_sample_rate() if sample_rate is None
                             else min(max(float(sample_rate), 0.0), 1.0))
         self._events: deque = deque(maxlen=int(capacity))
+        #: events a full ring pushed out since the last ``clear()``
+        self.dropped = 0
         self._lock = threading.Lock()
         # perf_counter epoch; the unix pair stamps exports with wall time
         self._epoch_perf = time.perf_counter()
@@ -185,6 +188,7 @@ class Tracer:
     def clear(self) -> None:
         with self._lock:
             self._events.clear()
+            self.dropped = 0
 
     def __len__(self) -> int:
         with self._lock:
@@ -197,20 +201,20 @@ class Tracer:
             return _NULL_SPAN
         return _Span(self, name, cat, args)
 
-    def traced(self, name: Optional[str] = None, cat: str = "obs"):
-        """Decorator form of ``span`` (span name defaults to the
-        function's qualified name)."""
-        def deco(fn):
-            label = name or fn.__qualname__
-
-            @wraps(fn)
-            def wrapper(*a, **kw):
-                if not self.enabled:
-                    return fn(*a, **kw)
-                with self.span(label, cat=cat):
-                    return fn(*a, **kw)
-            return wrapper
-        return deco
+    def annotation(self, name: str):
+        """An entered ``jax.profiler.TraceAnnotation`` (None when
+        disabled): the same label on the host plane of a device profile,
+        on the profiler's own clock, so a reader of the ``.xplane.pb``
+        needs no ``perf_counter`` mapping between a program phase and
+        the device ops under it.  The caller ends it with
+        ``__exit__(None, None, None)``.  jax is imported here, on first
+        use, so that this module stays free of it at import."""
+        if not self.enabled:
+            return None
+        from jax.profiler import TraceAnnotation
+        ann = TraceAnnotation(name)
+        ann.__enter__()
+        return ann
 
     def _ts_us(self, t_perf: float) -> float:
         return (t_perf - self._epoch_perf) * 1e6
@@ -229,7 +233,12 @@ class Tracer:
               "tid": tid if tid is not None else threading.get_ident()}
         if args:
             ev["args"] = args
+        self._push(ev)
+
+    def _push(self, ev: dict) -> None:
         with self._lock:
+            if len(self._events) == self._events.maxlen:
+                self.dropped += 1
             self._events.append(ev)
 
     def instant(self, name: str, cat: str = "obs", **args) -> None:
@@ -241,8 +250,7 @@ class Tracer:
               "pid": self._pid, "tid": threading.get_ident()}
         if args:
             ev["args"] = args
-        with self._lock:
-            self._events.append(ev)
+        self._push(ev)
 
     # -- reading / export ---------------------------------------------- #
     def events(self) -> list:
@@ -300,9 +308,11 @@ class Tracer:
         stack: list = []
         for n in nodes:
             end = n["ts"] + n["dur"]
+            # a nanosecond of slack: a phase and its envelope can end on
+            # ONE clock read, and ts + dur then differ by rounding alone
             while stack and not (n["ts"] >= stack[-1]["ts"]
                                  and end <= stack[-1]["ts"]
-                                 + stack[-1]["dur"]):
+                                 + stack[-1]["dur"] + 1e-3):
                 stack.pop()
             (stack[-1]["children"] if stack else roots).append(n)
             if n["ph"] == "X":
@@ -355,6 +365,7 @@ class Tracer:
             "otherData": {
                 "producer": "bigdl_tpu.obs",
                 "epoch_unix": self._epoch_unix,
+                "dropped": self.dropped,
             },
         }
         if path:
@@ -363,17 +374,6 @@ class Tracer:
                 json.dump(doc, f)
             os.replace(tmp, path)
         return doc
-
-    def export_jsonl(self, path: str) -> int:
-        """Structured event log: one JSON object per line (the grep/jq
-        side of the same buffer); returns the row count."""
-        events = self.events()
-        tmp = f"{path}.tmp"
-        with open(tmp, "w") as f:
-            for e in events:
-                f.write(json.dumps(e) + "\n")
-        os.replace(tmp, path)
-        return len(events)
 
 
 #: process-wide tracer — instrumented modules bind this once at import

@@ -50,11 +50,20 @@ slot occupancy (``serving/lm/*``) plus the paged-cache plane
 saved, evictions, and the arena's HBM footprint
 (``kvcache/arena_bytes``) — all in the process-wide registry, so
 ``ObsSummary`` and the SLO controller's headroom checks see cache
-memory, not just slots.
+memory, not just slots.  The worker thread's round loop is tiled with
+phase stamps (``ROUND_PHASES``; one ``perf_counter()`` read a boundary,
+``_stamp``): always on, they feed the round record behind
+``stats()["rounds"]`` — seconds by phase, the longest round with its own
+split, one WARNING for a slow round — and, with the tracer on, the
+``lm/round`` / ``lm/admit`` envelopes, one leaf span a phase and a
+profiler annotation of the same name; ``shared_watchdog("lm_round")``
+dumps every thread's stack while a round hangs.
 """
 from __future__ import annotations
 
 import logging
+import operator
+import statistics
 import threading
 import time
 from collections import deque
@@ -62,7 +71,8 @@ from typing import List, Optional, Sequence
 
 import numpy as np
 
-from bigdl_tpu.obs import get_registry, get_tracer
+from bigdl_tpu.obs import (env_watchdog_enabled, env_watchdog_kwargs,
+                           get_registry, get_tracer, shared_watchdog)
 from bigdl_tpu.obs.registry import FnGauge, Histogram
 from bigdl_tpu.obs.tracer import mint_request_id
 from bigdl_tpu.resilience.errors import (BackendLostError,
@@ -78,6 +88,21 @@ from bigdl_tpu.utils.engine import configure_compile_cache
 
 _tracer = get_tracer()
 log = logging.getLogger("bigdl_tpu.serving")
+
+#: the worker thread's leaf phases (span ``lm/<name>``) in the order a
+#: round meets them: while anything is in flight every instant of the
+#: thread lies in exactly one.  ``idle`` lies between rounds, the rest
+#: inside one ``lm/round``.
+ROUND_PHASES = ("idle", "sched", "admit_host", "prefill", "insert",
+                "first_token", "draft", "decode_dispatch", "decode_wait",
+                "emit", "tree_commit")
+(P_IDLE, P_SCHED, P_ADMIT_HOST, P_PREFILL, P_INSERT, P_FIRST_TOKEN, P_DRAFT,
+ P_DISPATCH, P_WAIT, P_EMIT, P_TREE_COMMIT) = range(len(ROUND_PHASES))
+_PHASE_SPANS = tuple("lm/" + p for p in ROUND_PHASES)
+#: a round is logged as slow when it took this long AND this many
+#: running medians of the plain (decode-only) rounds
+SLOW_ROUND_S = 1.0
+SLOW_ROUND_MEDIANS = 8.0
 
 
 def prefill_bucket_lengths(max_len: int, min_bucket: int = 8) -> tuple:
@@ -289,7 +314,13 @@ class LMMetrics:
     and ``itl_prefill_gap`` the gaps a prefill (or a KV-chain adoption)
     interrupted — the head-of-line blocking disaggregation exists to
     remove, now measurable straight from the registry
-    (``serving/lm/itl_decode`` vs ``serving/lm/itl_prefill_gap``)."""
+    (``serving/lm/itl_decode`` vs ``serving/lm/itl_prefill_gap``).
+
+    The round record (``record_round`` / ``rounds_snapshot``) is what the
+    engine's phase stamps feed whether or not the tracer is on: rounds
+    counted, seconds summed per phase, the last plain rounds for a
+    running median, and the longest round with its own phase split.  It
+    costs a fixed handful of additions a round."""
 
     def __init__(self, slots: int, throughput_window_s: float = 60.0):
         self._lock = threading.Lock()
@@ -311,6 +342,8 @@ class LMMetrics:
         self.started_at = time.perf_counter()
         self._window_s = float(throughput_window_s)
         self._recent: deque = deque()  # (t, n_tokens) per decode step
+        self._started_unix = time.time()
+        self.reset_rounds()
 
     def publish_to(self, registry,
                    prefix: str = "serving/lm/") -> "LMMetrics":
@@ -377,7 +410,64 @@ class LMMetrics:
         with self._lock:
             self.completed += 1
 
+    def reset_rounds(self) -> None:
+        """Start the round record afresh (a measured window opens)."""
+        with self._lock:
+            self.rounds = 0
+            self.plain_rounds = 0
+            self.slow_rounds = 0
+            self.phase_s = [0.0] * len(ROUND_PHASES)
+            self._plain: deque = deque(maxlen=64)
+            self._longest = None
+
+    def record_round(self, t0: float, dur_s: float, split: list,
+                     index: int, active: int, admitted: int,
+                     plain: bool) -> Optional[float]:
+        """Fold one finished round in: ``split`` is its seconds per
+        phase (indexed as ``ROUND_PHASES``; the record keeps the list).
+        Returns the running median of the plain rounds when this round
+        is a slow one (``SLOW_ROUND_S`` and ``SLOW_ROUND_MEDIANS``), for
+        the engine to log, else None."""
+        median = None
+        with self._lock:
+            self.rounds += 1
+            self.phase_s = list(map(operator.add, self.phase_s, split))
+            if self._longest is None or dur_s > self._longest[1]:
+                self._longest = (t0, dur_s, split, index, active, admitted)
+            if dur_s >= SLOW_ROUND_S and self._plain:
+                median = statistics.median(self._plain)
+                if dur_s >= SLOW_ROUND_MEDIANS * median:
+                    self.slow_rounds += 1
+                else:
+                    median = None
+            if plain:
+                self.plain_rounds += 1
+                self._plain.append(dur_s)
+        return median
+
     # -- reading -------------------------------------------------------- #
+    def rounds_snapshot(self) -> dict:
+        """The round record since start or the last ``reset_rounds()``."""
+        with self._lock:
+            out = {"count": self.rounds, "plain": self.plain_rounds,
+                   "slow": self.slow_rounds,
+                   "median_plain_s": (statistics.median(self._plain)
+                                      if self._plain else None),
+                   "phase_s": dict(zip(ROUND_PHASES, self.phase_s)),
+                   "longest": None}
+            longest = self._longest
+        if longest is not None:
+            t0, dur_s, split, index, active, admitted = longest
+            in_round = dict(zip(ROUND_PHASES[1:], split[1:]))
+            out["longest"] = {
+                "seconds": dur_s, "round": index,
+                "at_s": t0 - self.started_at,
+                "at_unix": self._started_unix + (t0 - self.started_at),
+                "active": active, "admitted": admitted,
+                "phase": max(in_round, key=in_round.get),
+                "phase_s": in_round}
+        return out
+
     def snapshot(self) -> dict:
         with self._lock:
             now = time.perf_counter()
@@ -1095,6 +1185,26 @@ class LMServingEngine:
         self._lc_counters = {
             k: _reg.counter(f"serving/lifecycle/{k}")
             for k in self.lifecycle}
+        # -- phase stamps: the worker thread's own timeline -------------- #
+        # one perf_counter() read at each phase boundary (_stamp) feeds
+        # the round record in LMMetrics always and, with the tracer on,
+        # the lm/* phase spans and the profiler's host-plane annotations
+        self._ph = P_SCHED                  # the leaf phase now open
+        self._ph_t0 = time.perf_counter()   # ... and since when
+        self._ph_args = None                # extra args of its span
+        self._ph_ann = None                 # its live TraceAnnotation
+        self._adm_rid = None                # sampled request being admitted
+        self._adm_note = None               # lm/admit args from its callees
+        self._rd = [0.0] * len(ROUND_PHASES)    # this round's split
+        self._rd_t0 = self._ph_t0
+        self._rd_index = 0
+        self._rd_active = 0
+        # round-cadence stall detection, wired as ServingEngine wires its
+        # dispatch: a round past k medians dumps every thread's stack
+        # WHILE it hangs; the round record sizes its phases afterwards
+        self.watchdog = (shared_watchdog("lm_round")
+                         .reset(**env_watchdog_kwargs())
+                         if env_watchdog_enabled() else None)
         self._worker = threading.Thread(
             target=self._run, daemon=True, name=f"lm-serve-{name}")
         self._worker.start()
@@ -1556,20 +1666,133 @@ class LMServingEngine:
         return pick_token(logits_row, temperature, key, clamp)
 
     # -- worker -------------------------------------------------------- #
+    def _stamp(self, nxt: int) -> float:
+        """A phase boundary of the worker thread: one clock read closes
+        the open leaf phase into this round's split and opens ``nxt``.
+        With the tracer on, the closed phase is also written as a span
+        (``lm/<phase>``, carrying its round's index) and ``nxt`` entered
+        as a profiler annotation of the same name."""
+        now = time.perf_counter()
+        self._rd[self._ph] += now - self._ph_t0
+        if _tracer.enabled or self._ph_ann is not None:
+            self._trace_phase(now, nxt)
+        self._ph = nxt
+        self._ph_t0 = now
+        return now
+
+    def _annotate(self, phase: Optional[int]) -> None:
+        """Leave the live profiler annotation and, with the tracer on,
+        enter ``phase``'s."""
+        if self._ph_ann is not None:
+            self._ph_ann.__exit__(None, None, None)
+        self._ph_ann = (None if phase is None
+                        else _tracer.annotation(_PHASE_SPANS[phase]))
+
+    def _trace_phase(self, now: float, nxt: int) -> None:
+        self._annotate(nxt)
+        args, self._ph_args = self._ph_args, None
+        if not _tracer.enabled:
+            return
+        args = dict(args or (), round=self._rd_index)
+        if self._adm_rid is not None:
+            args["request_id"] = self._adm_rid
+        _tracer.add_complete(_PHASE_SPANS[self._ph], self._ph_t0,
+                             now - self._ph_t0, cat="serve", args=args)
+
+    def _nothing_to_do(self) -> bool:
+        """Caller holds ``_cv``."""
+        return (not self._queue and not self._adopt_q
+                and not self._resume_q
+                and not self._n_active and not self._prefilling
+                and not self._closing and not self._abort
+                and not self._lc_nudge)
+
+    def _round_end(self, admitted: int) -> None:
+        """Close the round: its ``lm/round`` span (tracer on), its fold
+        into the round record, the slow-round line, the watchdog."""
+        now = self._stamp(P_SCHED)      # the next round starts here
+        t0, index, active = self._rd_t0, self._rd_index, self._rd_active
+        split, self._rd = self._rd, [0.0] * len(ROUND_PHASES)
+        self._rd_t0, self._rd_index, self._rd_active = now, index + 1, 0
+        if self.watchdog is not None:
+            self.watchdog.step_finished()
+        dur = now - t0
+        if _tracer.enabled:
+            _tracer.add_complete(
+                "lm/round", t0, dur, cat="serve",
+                args={"round": index, "active": active,
+                      "admitted": admitted})
+        # plain: a decode round that nothing interrupted -- what the
+        # running median, and so the slow-round rule, is taken over
+        plain = (admitted == 0 and split[P_PREFILL] == 0.0
+                 and split[P_WAIT] > 0.0)
+        median = self.metrics.record_round(t0, dur, split, index, active,
+                                           admitted, plain)
+        if median is not None:
+            log.warning(
+                "lm engine %s: slow round %d: %.3f s, %.1f x the running "
+                "median of %.4f s (active %d, admitted %d, %.3f s after "
+                "start); seconds by phase: %s", self.name, index, dur,
+                dur / median, median, active, admitted,
+                t0 - self.metrics.started_at,
+                {p: round(v, 4)
+                 for p, v in zip(ROUND_PHASES[1:], split[1:]) if v})
+
+    def _admit_each(self, kind: str, admit, pairs: list) -> list:
+        """Run one kind of admission (``admit(slot, item)`` -> seated?)
+        over ``(slot, item)`` pairs, each inside its ``lm/admit``
+        envelope; a failure fails that stream and frees its slot.
+        Returns the pairs deferred under pool pressure."""
+        deferred = []
+        for slot, item in pairs:
+            seated = None
+            t0 = self._stamp(P_ADMIT_HOST)
+            if _tracer.enabled:
+                self._adm_note = {}
+                if _tracer.sampled(item.rid):
+                    self._adm_rid = item.rid
+            try:
+                seated = admit(slot, item)
+            except BaseException as e:  # noqa: BLE001
+                item.stream._finish(error=e)
+                with self._cv:
+                    self._free.append(slot)
+            else:
+                if not seated:
+                    deferred.append((slot, item))
+            now = self._stamp(P_SCHED)
+            if _tracer.enabled:
+                args = {"kind": kind, "round": self._rd_index, "slot": slot,
+                        "prompt_len": int(len(item.stream.prompt)),
+                        "deferred": seated is False}
+                if seated is None:
+                    args["error"] = True
+                if self._adm_note:
+                    args.update(self._adm_note)
+                if self._adm_rid is not None:
+                    args["request_id"] = self._adm_rid
+                _tracer.add_complete("lm/admit", t0, now - t0, cat="serve",
+                                     args=args)
+            self._adm_rid = self._adm_note = None
+        return deferred
+
     def _run(self):
         try:
             while True:
                 with self._cv:
-                    while (not self._queue and not self._adopt_q
-                           and not self._resume_q
-                           and not self._n_active and not self._prefilling
-                           and not self._closing and not self._abort
-                           and not self._lc_nudge):
-                        if not self._cv.wait(self._lc_wait_timeout()):
-                            # a holding station's deadline came due
-                            # while the engine idled (e.g. a hibernated
-                            # stream): run the sweep
-                            self._lc_nudge = True
+                    if self._nothing_to_do():
+                        # the instants since the last round's end were
+                        # idle, not the start of a round
+                        self._ph = P_IDLE
+                        if self._ph_ann is not None:
+                            self._annotate(P_IDLE)
+                        while self._nothing_to_do():
+                            if not self._cv.wait(self._lc_wait_timeout()):
+                                # a holding station's deadline came due
+                                # while the engine idled (e.g. a hibernated
+                                # stream): run the sweep
+                                self._lc_nudge = True
+                        self._rd_t0 = self._stamp(P_SCHED)
                     if self._abort:
                         break
                     if (self._closing and not self._queue
@@ -1580,6 +1803,8 @@ class LMServingEngine:
                         # resolves any still-hibernated streams with
                         # ServingClosed instead of leaving them hanging
                         break
+                    if self.watchdog is not None:
+                        self.watchdog.step_started()
                     # cancelled/expired requests leave their holding
                     # stations BEFORE this round admits anything
                     self._lifecycle_sweep_locked()
@@ -1614,39 +1839,14 @@ class LMServingEngine:
                     self.metrics.record_step(
                         min(self.slots,
                             inflight + len(adopts) + len(admits)), [])
-                deferred_adopts = []
-                for slot, h in adopts:
-                    try:
-                        seated = self._adopt_into(slot, h)
-                    except BaseException as e:  # noqa: BLE001
-                        h.stream._finish(error=e)
-                        with self._cv:
-                            self._free.append(slot)
-                    else:
-                        if not seated:
-                            deferred_adopts.append((slot, h))
-                deferred_resumes = []
-                for slot, hib in resumes:
-                    try:
-                        seated = self._resume_into(slot, hib)
-                    except BaseException as e:  # noqa: BLE001
-                        hib.stream._finish(error=e)
-                        with self._cv:
-                            self._free.append(slot)
-                    else:
-                        if not seated:
-                            deferred_resumes.append((slot, hib))
-                deferred = []
-                for slot, req in admits:
-                    try:
-                        admitted = self._admit(slot, req)
-                    except BaseException as e:  # noqa: BLE001
-                        req.stream._finish(error=e)
-                        with self._cv:
-                            self._free.append(slot)
-                    else:
-                        if not admitted:
-                            deferred.append((slot, req))
+                deferred_adopts = self._admit_each(
+                    "adopt", self._adopt_into, adopts)
+                deferred_resumes = self._admit_each(
+                    "resume", self._resume_into, resumes)
+                deferred = self._admit_each("submit", self._admit, admits)
+                admitted = (len(adopts) + len(resumes) + len(admits)
+                            - len(deferred_adopts) - len(deferred_resumes)
+                            - len(deferred))
                 if deferred or deferred_adopts or deferred_resumes:
                     # pool pressure: requeue at the FRONT (FIFO order
                     # preserved) and return the slots — blocks free as
@@ -1676,6 +1876,9 @@ class LMServingEngine:
                     # then back to decoding — the decode stall per
                     # round is one chunk, not one prompt
                     pf = self._prefilling[0]
+                    self._stamp(P_ADMIT_HOST)
+                    if _tracer.sampled(pf.req.rid):
+                        self._adm_rid = pf.req.rid
                     try:
                         if self._prefill_chunk(pf):
                             self._prefilling.popleft()
@@ -1686,14 +1889,23 @@ class LMServingEngine:
                         pf.req.stream._finish(error=e)
                         with self._cv:
                             self._free.append(pf.slot)
+                    self._stamp(P_SCHED)
+                    self._adm_rid = self._adm_note = None
                 if self._n_active:
                     if self.draft is not None:
                         self._step_spec()
                     else:
                         self._step()
+                self._round_end(admitted)
         except BaseException as e:  # noqa: BLE001
             self._fail_all(e)
             return
+        finally:
+            # leave no annotation entered and no round in flight behind
+            # a thread that is gone
+            self._annotate(None)
+            if self.watchdog is not None:
+                self.watchdog.step_finished()
         self._fail_all(ServingClosed("engine closed before completion"))
 
     # -- request lifecycle (deadlines / cooperative cancel) ------------- #
@@ -1937,6 +2149,8 @@ class LMServingEngine:
                 matched = self._promote_extend(req.prompt0, matched,
                                                rid=req.rid)
         traced = _tracer.sampled(req.rid)
+        if self._adm_note is not None:
+            self._adm_note["matched_tokens"] = len(matched) * B
         if traced and self.radix is not None:
             _tracer.instant("lm/radix_match", cat="serve",
                             request_id=req.rid,
@@ -1962,7 +2176,7 @@ class LMServingEngine:
             wait = time.perf_counter() - req.stream.submitted_at
             _tracer.add_complete("lm/queue_wait",
                                  req.stream.submitted_at, wait,
-                                 cat="serve",
+                                 cat="request",
                                  args={"request_id": req.rid, "slot": slot})
         if self._chunk_cap is not None:
             # chunk-interleaved mode: allocation happens at admission
@@ -2379,7 +2593,7 @@ class LMServingEngine:
             end = time.perf_counter()
         _tracer.add_complete(
             "lm/request", stream.submitted_at,
-            end - stream.submitted_at, cat="serve",
+            end - stream.submitted_at, cat="request",
             args={"request_id": rid, "prompt_len": int(len(stream.prompt)),
                   "max_new": stream.max_new,
                   "emitted": len(stream._tokens)})
@@ -2408,48 +2622,54 @@ class LMServingEngine:
         chunk_full = (self._chunk_full if cap is None
                       else min(self._chunk_full, cap))
         p = pf.p
-        rid_args = ({"request_id": req.rid}
-                    if _tracer.sampled(req.rid) else {})
         rem = t - p
         ts = rem if rem <= largest_eff else chunk_full
         bucket = self.bucket_for(ts)
         ids = np.zeros((1, bucket), np.int32)
         ids[0, :ts] = req.prompt0[p:p + ts]
-        with _tracer.span("lm/prefill", cat="serve", bucket=bucket,
-                          prompt_len=t, prefix_len=p, **rid_args):
-            if p == 0:
-                logits, k, v = self.prefill_cache(
-                    self._params, self._buffers,
-                    {"ids": ids, "len": np.int32(ts)})
-            else:
-                nbp = p // B
-                pb = self._prefix_bucket_for(nbp)
-                pblocks = np.zeros((pb,), np.int32)
-                pblocks[:nbp] = blocks[:nbp]
-                x = {"ids": ids, "len": np.int32(ts),
-                     "prefix_len": np.int32(p), "blocks": pblocks,
-                     "k": self.pool.k, "v": self.pool.v}
-                if self.kv_quant is not None:
-                    x["ks"], x["vs"] = self.pool.ks, self.pool.vs
-                logits, k, v = self.prefix_prefill_cache(
-                    self._params, self._buffers, x)
-        # scatter the chunk's k/v into its (block-aligned) blocks;
+        # the chunk's k/v scatter into its (block-aligned) blocks;
         # bucket-padding rows land in trailing owned blocks or the
         # scratch block, always masked until overwritten
         nb_w = -(-bucket // B)
         ids_w = np.zeros((nb_w,), np.int32)
         owned = blocks[p // B:p // B + nb_w]
         ids_w[:len(owned)] = owned
-        with _tracer.span("lm/insert", cat="serve", slot=pf.slot,
-                          bucket=bucket, **rid_args):
+        if self._adm_note is not None:
+            self._adm_note["bucket"] = bucket
+        # lm/prefill and lm/insert time the ENQUEUE of the chunk program
+        # and of the scatter; the device wait lands in lm/first_token
+        self._stamp(P_PREFILL)
+        if _tracer.enabled:
+            self._ph_args = {"bucket": bucket, "prompt_len": t,
+                             "prefix_len": p}
+        if p == 0:
+            logits, k, v = self.prefill_cache(
+                self._params, self._buffers,
+                {"ids": ids, "len": np.int32(ts)})
+        else:
+            nbp = p // B
+            pb = self._prefix_bucket_for(nbp)
+            pblocks = np.zeros((pb,), np.int32)
+            pblocks[:nbp] = blocks[:nbp]
+            x = {"ids": ids, "len": np.int32(ts),
+                 "prefix_len": np.int32(p), "blocks": pblocks,
+                 "k": self.pool.k, "v": self.pool.v}
             if self.kv_quant is not None:
-                (self.pool.k, self.pool.v, self.pool.ks,
-                 self.pool.vs) = self._insert_compiled(bucket)(
-                    self.pool.k, self.pool.v, k, v, ids_w,
-                    self.pool.ks, self.pool.vs)
-            else:
-                self.pool.k, self.pool.v = self._insert_compiled(bucket)(
-                    self.pool.k, self.pool.v, k, v, ids_w)
+                x["ks"], x["vs"] = self.pool.ks, self.pool.vs
+            logits, k, v = self.prefix_prefill_cache(
+                self._params, self._buffers, x)
+        self._stamp(P_INSERT)
+        if _tracer.enabled:
+            self._ph_args = {"slot": pf.slot, "bucket": bucket}
+        if self.kv_quant is not None:
+            (self.pool.k, self.pool.v, self.pool.ks,
+             self.pool.vs) = self._insert_compiled(bucket)(
+                self.pool.k, self.pool.v, k, v, ids_w,
+                self.pool.ks, self.pool.vs)
+        else:
+            self.pool.k, self.pool.v = self._insert_compiled(bucket)(
+                self.pool.k, self.pool.v, k, v, ids_w)
+        self._stamp(P_ADMIT_HOST)
         self._prefill_since_step = True
         pf.logits = logits
         pf.p = p + ts
@@ -2471,6 +2691,8 @@ class LMServingEngine:
             # seat decode exactly where the handoff says it stands
             self._seat(req, t, pf.handoff.first0, blocks, slot)
             return
+        # where the device wait for the prefill and the insert lands
+        self._stamp(P_FIRST_TOKEN)
         logits = np.asarray(pf.logits)  # sync; (1, V) f32
         first0 = self._pick(logits[0], req.temperature, req.first_key,
                             clamp=False)
@@ -2529,6 +2751,7 @@ class LMServingEngine:
             self._n_active += 1
 
     def _step(self):
+        t0 = self._stamp(P_DISPATCH)
         token = np.zeros((self.slots,), np.int32)
         pos = np.zeros((self.slots,), np.int32)
         tables = np.zeros((self.slots, self.table_width), np.int32)
@@ -2541,27 +2764,29 @@ class LMServingEngine:
                 tables[i] = st.table
         if not active:
             return
-        t0 = time.perf_counter()
-        with _tracer.span("lm/decode_step", cat="serve",
-                          active=len(active)):
-            if self.kv_quant is not None:
-                (logits, self.pool.k, self.pool.v, self.pool.ks,
-                 self.pool.vs) = self._decode_compiled()(
-                    self._params, token, pos, tables, self.pool.k,
-                    self.pool.v, self.pool.ks, self.pool.vs)
-            else:
-                logits, self.pool.k, self.pool.v = self._decode_compiled()(
-                    self._params, token, pos, tables, self.pool.k,
-                    self.pool.v)
-            logits = np.asarray(logits)  # sync; (S, V) f32
-        now = time.perf_counter()
+        self._rd_active = len(active)
+        if self.kv_quant is not None:
+            (logits, self.pool.k, self.pool.v, self.pool.ks,
+             self.pool.vs) = self._decode_compiled()(
+                self._params, token, pos, tables, self.pool.k,
+                self.pool.v, self.pool.ks, self.pool.vs)
+        else:
+            logits, self.pool.k, self.pool.v = self._decode_compiled()(
+                self._params, token, pos, tables, self.pool.k,
+                self.pool.v)
+        self._stamp(P_WAIT)
+        logits = np.asarray(logits)  # sync; (S, V) f32
+        now = self._stamp(P_EMIT)
         if _tracer.enabled:
+            _tracer.add_complete("lm/decode_step", t0, now - t0, cat="serve",
+                                 args={"active": len(active),
+                                       "round": self._rd_index})
             # per-request view of the shared batched step: one
             # retroactive span per sampled slot, all spanning [t0, now]
             for i, st in active:
                 if _tracer.sampled(st.rid):
                     _tracer.add_complete(
-                        "lm/decode_round", t0, now - t0, cat="serve",
+                        "lm/decode_round", t0, now - t0, cat="request",
                         args={"request_id": st.rid, "slot": i,
                               "step": st.step_idx})
         itls = []
@@ -2630,6 +2855,7 @@ class LMServingEngine:
             return self._step_spec_tree()
         mode = cfg.sampling
         # -- choose who speculates this round --------------------------- #
+        self._stamp(P_DRAFT)
         jobs = {}
         for i, st in enumerate(self._slots):
             if st is None or not st.draft_ok:
@@ -2678,6 +2904,7 @@ class LMServingEngine:
             jobs = {}
 
         # -- one fixed-shape verify over every active slot -------------- #
+        t0 = self._stamp(P_DISPATCH)
         w = cfg.k + 1
         tokens = np.zeros((self.slots, w), np.int32)
         pos = np.zeros((self.slots,), np.int32)
@@ -2697,26 +2924,29 @@ class LMServingEngine:
             tables[i] = st.table
         if not active:
             return
-        t0 = time.perf_counter()
-        with _tracer.span("lm/verify_step", cat="serve",
-                          active=len(active), speculating=len(jobs)):
-            if self.kv_quant is not None:
-                (logits, self.pool.k, self.pool.v, self.pool.ks,
-                 self.pool.vs) = self._verify_compiled()(
-                    self._params, tokens, pos, ncand, tables,
-                    self.pool.k, self.pool.v, self.pool.ks, self.pool.vs)
-            else:
-                logits, self.pool.k, self.pool.v = self._verify_compiled()(
-                    self._params, tokens, pos, ncand, tables,
-                    self.pool.k, self.pool.v)
-            logits = np.asarray(logits)  # sync; (S, W, V) f32
-        now = time.perf_counter()
+        self._rd_active = len(active)
+        if self.kv_quant is not None:
+            (logits, self.pool.k, self.pool.v, self.pool.ks,
+             self.pool.vs) = self._verify_compiled()(
+                self._params, tokens, pos, ncand, tables,
+                self.pool.k, self.pool.v, self.pool.ks, self.pool.vs)
+        else:
+            logits, self.pool.k, self.pool.v = self._verify_compiled()(
+                self._params, tokens, pos, ncand, tables,
+                self.pool.k, self.pool.v)
+        self._stamp(P_WAIT)
+        logits = np.asarray(logits)  # sync; (S, W, V) f32
+        now = self._stamp(P_EMIT)
         if _tracer.enabled:
+            _tracer.add_complete(
+                "lm/verify_step", t0, now - t0, cat="serve",
+                args={"active": len(active), "speculating": len(jobs),
+                      "round": self._rd_index})
             for i in active:
                 st = self._slots[i]
                 if _tracer.sampled(st.rid):
                     _tracer.add_complete(
-                        "lm/verify_round", t0, now - t0, cat="serve",
+                        "lm/verify_round", t0, now - t0, cat="request",
                         args={"request_id": st.rid, "slot": i,
                               "step": st.step_idx,
                               "speculating": i in jobs})
@@ -2836,6 +3066,7 @@ class LMServingEngine:
         shapes = self._tree_shapes
         top = len(shapes) - 1
         # -- choose who speculates, and at which rung ------------------- #
+        self._stamp(P_DRAFT)
         jobs: dict = {}
         for i, st in enumerate(self._slots):
             if st is None or not st.draft_ok:
@@ -2892,6 +3123,7 @@ class LMServingEngine:
             jobs = {}
 
         # -- one verify at the round's widest rung ---------------------- #
+        t0 = self._stamp(P_DISPATCH)
         round_rung = max(jobs.values(), default=0)
         shp_round = shapes[round_rung]
         w = shp_round.width
@@ -2927,28 +3159,30 @@ class LMServingEngine:
                 ncand[i] = 1
         if not active:
             return
-        t0 = time.perf_counter()
-        with _tracer.span("lm/verify_step", cat="serve",
-                          active=len(active), speculating=len(jobs),
-                          tree_w=w):
-            if self.kv_quant is not None:
-                (logits, self.pool.k, self.pool.v, self.pool.ks,
-                 self.pool.vs) = self._verify_tree_compiled(round_rung)(
-                    self._params, tokens, pos, ncand, tables,
-                    self.pool.k, self.pool.v, self.pool.ks, self.pool.vs)
-            else:
-                (logits, self.pool.k,
-                 self.pool.v) = self._verify_tree_compiled(round_rung)(
-                    self._params, tokens, pos, ncand, tables,
-                    self.pool.k, self.pool.v)
-            logits = np.asarray(logits)  # sync; (S, W, V) f32
-        now = time.perf_counter()
+        self._rd_active = len(active)
+        if self.kv_quant is not None:
+            (logits, self.pool.k, self.pool.v, self.pool.ks,
+             self.pool.vs) = self._verify_tree_compiled(round_rung)(
+                self._params, tokens, pos, ncand, tables,
+                self.pool.k, self.pool.v, self.pool.ks, self.pool.vs)
+        else:
+            (logits, self.pool.k,
+             self.pool.v) = self._verify_tree_compiled(round_rung)(
+                self._params, tokens, pos, ncand, tables,
+                self.pool.k, self.pool.v)
+        self._stamp(P_WAIT)
+        logits = np.asarray(logits)  # sync; (S, W, V) f32
+        now = self._stamp(P_EMIT)
         if _tracer.enabled:
+            _tracer.add_complete(
+                "lm/verify_step", t0, now - t0, cat="serve",
+                args={"active": len(active), "speculating": len(jobs),
+                      "tree_w": w, "round": self._rd_index})
             for i in active:
                 st = self._slots[i]
                 if _tracer.sampled(st.rid):
                     _tracer.add_complete(
-                        "lm/verify_round", t0, now - t0, cat="serve",
+                        "lm/verify_round", t0, now - t0, cat="request",
                         args={"request_id": st.rid, "slot": i,
                               "step": st.step_idx,
                               "speculating": i in jobs})
@@ -3048,17 +3282,18 @@ class LMServingEngine:
                 else:
                     self.draft.push(i, emitted[0])
         if commit_src is not None:
-            with _tracer.span("lm/tree_commit", cat="serve"):
-                if self.kv_quant is not None:
-                    (self.pool.k, self.pool.v, self.pool.ks,
-                     self.pool.vs) = self._commit_compiled()(
-                        commit_src, pos, tables,
-                        self.pool.k, self.pool.v,
-                        self.pool.ks, self.pool.vs)
-                else:
-                    self.pool.k, self.pool.v = self._commit_compiled()(
-                        commit_src, pos, tables,
-                        self.pool.k, self.pool.v)
+            self._stamp(P_TREE_COMMIT)
+            if self.kv_quant is not None:
+                (self.pool.k, self.pool.v, self.pool.ks,
+                 self.pool.vs) = self._commit_compiled()(
+                    commit_src, pos, tables,
+                    self.pool.k, self.pool.v,
+                    self.pool.ks, self.pool.vs)
+            else:
+                self.pool.k, self.pool.v = self._commit_compiled()(
+                    commit_src, pos, tables,
+                    self.pool.k, self.pool.v)
+            self._stamp(P_EMIT)
         self.spec_metrics.record_verify_round(
             bool(jobs), n_emitted, self.draft.steps - steps_before)
         self.metrics.record_step(len(active), itls,
@@ -3171,8 +3406,22 @@ class LMServingEngine:
             "honor_lifecycle": self.honor_lifecycle,
             "lifecycle": self.lifecycle_stats(),
             "metrics": self.metrics.snapshot(),
+            "rounds": self.rounds_stats(),
             "spec": self._spec_stats(),
         }
+
+    def rounds_stats(self) -> dict:
+        """The always-on round record (``LMMetrics.rounds_snapshot``):
+        rounds counted, seconds by phase, the running median of the
+        plain rounds and the longest round with its own phase split --
+        beside what the two witnesses of a stall saw: events the trace
+        ring dropped, and the round watchdog's count."""
+        out = self.metrics.rounds_snapshot()
+        out["trace_dropped"] = _tracer.dropped
+        if self.watchdog is not None:
+            out["watchdog"] = {"stalls": self.watchdog.stall_count,
+                               "median_round_s": self.watchdog.median()}
+        return out
 
     def lifecycle_stats(self) -> dict:
         with self._lc_lock:
@@ -3223,6 +3472,9 @@ class LMServingEngine:
             self._worker.join(5.0)
             self._fail_all(ServingClosed("engine closed before "
                                          "completion"))
+        if self.watchdog is not None:
+            self.watchdog.reset()
+            self.watchdog.stop()
         # drop this engine's memory-ledger attributions (the weakref
         # providers would go stale anyway; explicit release keeps the
         # table clean for the next engine)

@@ -1,0 +1,293 @@
+"""By hand, on the chip: what the LM engine's round stamps see in whole runs
+of a benchmark cell.  Not part of the benchmark: it calls
+``benchmarks.run.run_cell`` and reads the engine from outside.
+
+    python3 scripts/lm_round_hunt.py --cell gpt2xl.saturated --seeds <n> [<n> ...]
+        one untraced run a seed, in this process; after each, one JSON line
+        with the result line's metrics and the engine's stats()["rounds"]
+        over the measured window (reset where the window opens, read where it
+        closes): seconds by phase, the running median, the longest round with
+        its own phase split -- the stall hunt
+    ... --tracer-on     the same with the span tracer enabled for the whole run
+                        (not the profiler): what tracing costs end to end
+    ... --trace         runs with --trace 1 instead; beside each result line,
+                        the interval that bounds the device clock's offset
+                        from the host's (device module inside the program's
+                        lm/decode_dispatch .. lm/decode_wait annotations of
+                        the same round, on the profiler's one clock), and how
+                        much of the window the worker's leaf spans cover
+    ... --stamp-cost    no cell: times the stamps of one plain round on an
+                        idle toy engine, tracer off and on
+
+On a tree without the stamps (the parent) the rounds read null.
+"""
+import argparse
+import json
+import os
+import statistics
+import sys
+import time
+import weakref
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+
+def _watch_engines():
+    """Reset every live LM engine's round record where a window opens and
+    read it where the window closes; returns the dict the readings land in."""
+    from benchmarks import run
+    from bigdl_tpu.serving import lm_engine
+    engines, seen = [], {}
+    init = lm_engine.LMServingEngine.__init__
+    open_window, close_window = run.Run.open_window, run.Run.close_window
+
+    def watched_init(self, *a, **kw):
+        init(self, *a, **kw)
+        engines.append(weakref.ref(self))
+
+    def live():
+        return [e for e in (r() for r in engines)
+                if e is not None and hasattr(e, "rounds_stats")]
+
+    def watched_open(self, at=None):
+        for e in live():
+            e.metrics.reset_rounds()
+        return open_window(self, at)
+
+    def watched_close(self, at=None):
+        t = close_window(self, at)
+        seen["rounds"] = [e.rounds_stats() for e in live()]
+        del engines[:]
+        return t
+
+    lm_engine.LMServingEngine.__init__ = watched_init
+    run.Run.open_window, run.Run.close_window = watched_open, watched_close
+    return seen
+
+
+def _clock_offset(xplane: str):
+    """[lo, hi] ms for (device clock - host clock) in one profile: in every
+    round the decode module starts after lm/decode_dispatch was entered and
+    ends before lm/decode_wait was left."""
+    from jax.profiler import ProfileData
+    dispatch, wait, modules = [], [], []
+    for plane in ProfileData.from_file(xplane).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if plane.name.startswith("/host:"):
+                    if ev.name == "lm/decode_dispatch":
+                        dispatch.append(ev.start_ns)
+                    elif ev.name == "lm/decode_wait":
+                        wait.append((ev.start_ns, ev.start_ns + ev.duration_ns))
+                elif (plane.name.startswith("/device:TPU:")
+                      and line.name == "XLA Modules" and "decode_fn" in ev.name):
+                    modules.append((ev.start_ns, ev.start_ns + ev.duration_ns))
+    dispatch.sort(), wait.sort(), modules.sort()
+    lows, highs = [], []
+    for m0, m1 in modules:
+        # the round of this module: the last dispatch entered before it ended,
+        # and the first wait left after it started (offsets are ~ms, rounds
+        # a quarter of a second)
+        d = [t for t in dispatch if t < m1]
+        w = [b for a, b in wait if b > m0]
+        if d and w and w[0] - d[-1] < 2 * (m1 - m0):
+            highs.append((m0 - d[-1]) * 1e-6)
+            lows.append((m1 - w[0]) * 1e-6)
+    if not lows:
+        return None
+    return {"rounds": len(lows), "device_minus_host_ms": [max(lows), min(highs)],
+            "module_start_after_dispatch_ms": statistics.median(highs),
+            "wait_end_after_module_end_ms": -statistics.median(lows)}
+
+
+def _watch_trace(seen: dict):
+    """Before the harness reduces (and deletes) a trace: the clock offset
+    from its planes, and the leaf spans' cover of the traced window."""
+    from benchmarks import run
+    from benchmarks.harness import trace_reduce
+    reduce_trace = run.Run.reduce_trace
+
+    def watched(self, spans):
+        try:
+            seen["clock_offset"] = _clock_offset(
+                trace_reduce.find_xplane(self._trace_dir))
+        except Exception as e:  # noqa: BLE001 -- a reading, not the run
+            seen["clock_offset"] = repr(e)
+        seen["cover"] = _cover(spans, (self.t_open, self.t_close))
+        seen["round_shapes"] = _round_shapes(spans, (self.t_open, self.t_close))
+        return reduce_trace(self, spans)
+
+    run.Run.reduce_trace = watched
+
+
+def _cover(spans, window):
+    """Share of the window that the worker thread's leaf spans cover, and the
+    share inside lm/round spans."""
+    try:
+        from bigdl_tpu.serving.lm_engine import ROUND_PHASES
+    except ImportError:
+        return None
+    leaves = {"lm/" + p for p in ROUND_PHASES}
+    lo, hi = window
+    clip = lambda s, d: max(0.0, min(s + d, hi) - max(s, lo))   # noqa: E731
+    by_name = {}
+    for n, s, d in spans:
+        if n in leaves or n == "lm/round":
+            by_name[n] = by_name.get(n, 0.0) + clip(s, d)
+    leaf_s = sum(v for n, v in by_name.items() if n != "lm/round")
+    return {"window_s": hi - lo, "leaf_share": leaf_s / (hi - lo),
+            "round_share": by_name.get("lm/round", 0.0) / (hi - lo),
+            "seconds_by_leaf": dict(sorted(by_name.items()))}
+
+
+def _round_shapes(spans, window):
+    """What an admission costs the rounds, from the spans of a traced run:
+    rounds by how many lm/admit they hold, with the medians of the round, of
+    its lm/first_token time and of its lm/decode_wait -- a prefill's insert
+    program is waited for by the NEXT decode step, not by the first token --
+    and the 95th percentile of all rounds (what a gap between tokens is)."""
+    from benchmarks.harness import stats
+    lo, hi = window
+    rounds = sorted((s, s + d) for n, s, d in spans
+                    if n == "lm/round" and lo <= s < hi)
+    if not rounds:
+        return None
+    by_admits = {}
+    for a, b in rounds:
+        inside = [(n, d) for n, s, d in spans if a <= s < b and n in
+                  ("lm/admit", "lm/first_token", "lm/decode_wait")]
+        k = sum(n == "lm/admit" for n, _ in inside)
+        by_admits.setdefault(k, []).append(
+            (b - a, sum(d for n, d in inside if n == "lm/first_token"),
+             sum(d for n, d in inside if n == "lm/decode_wait")))
+    ms = lambda v: stats.median(v) * 1e3                        # noqa: E731
+    return {"rounds": len(rounds),
+            "round_p95_ms": stats.percentile([b - a for a, b in rounds], 95) * 1e3,
+            "by_admits": {str(k): {"n": len(v), "round_ms": ms([x[0] for x in v]),
+                                   "first_token_ms": ms([x[1] for x in v]),
+                                   "decode_wait_ms": ms([x[2] for x in v])}
+                          for k, v in sorted(by_admits.items())}}
+
+
+def _stalls() -> int:
+    from bigdl_tpu.obs import shared_watchdog
+    return shared_watchdog("lm_round").stall_count
+
+
+def _new_stall(before: int):
+    """The round watchdog's last event, if it fired in this run: when it
+    saw the round (``inflight_s`` against ``threshold_s`` says whether its
+    own thread ran on time) and where the worker stood."""
+    from bigdl_tpu.obs import shared_watchdog
+    wd = shared_watchdog("lm_round")
+    if wd.stall_count == before or wd.last_event is None:
+        return None
+    ev = dict(wd.last_event)
+    stacks = ev.pop("thread_stacks", {})
+    ev["fired"] = wd.stall_count - before
+    ev["worker_stack"] = {k: v[-1500:] for k, v in stacks.items()
+                          if k.startswith("lm-serve")}
+    return ev
+
+
+def stamp_cost() -> dict:
+    """Seconds of host time the stamps of ONE plain round cost, on an engine
+    whose worker sleeps in lm/idle (so this thread may drive its stamps)."""
+    from bigdl_tpu.models.transformer import TransformerLM
+    from bigdl_tpu.obs import get_tracer
+    from bigdl_tpu.serving import LMServingEngine, lm_engine as le
+    model = TransformerLM(vocab_size=31, hidden_size=16, n_head=2, n_layers=1,
+                          max_len=32).build(seed=0)
+    eng = LMServingEngine(model, slots=2, cache_len=24, prefill_buckets=(8,))
+    time.sleep(0.2)                                 # the worker reaches idle
+    tracer, out = get_tracer(), {}
+
+    def one_round():
+        eng.watchdog.step_started()
+        eng._stamp(le.P_DISPATCH)
+        eng._stamp(le.P_WAIT)
+        eng._stamp(le.P_EMIT)
+        eng._round_end(0)
+
+    for label, on, n in (("off", False, 200000), ("on", True, 50000)):
+        tracer.enabled = on
+        for _ in range(2000):
+            one_round()
+        samples = []
+        for _ in range(5):
+            t0 = time.perf_counter()
+            for _ in range(n // 5):
+                one_round()
+            samples.append((time.perf_counter() - t0) / (n // 5))
+        out[f"round_stamps_us_tracer_{label}"] = {
+            "median": statistics.median(samples) * 1e6,
+            "min": min(samples) * 1e6, "max": max(samples) * 1e6, "rounds": n}
+    tracer.enabled = False
+    tracer.clear()
+    eng.close()
+    return out
+
+
+def main(argv) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--cell")
+    ap.add_argument("--seconds", type=float, default=40.0)
+    ap.add_argument("--seeds", type=int, nargs="*", default=[])
+    ap.add_argument("--trace", action="store_true")
+    ap.add_argument("--tracer-on", action="store_true")
+    ap.add_argument("--stamp-cost", action="store_true")
+    ap.add_argument("--cpu", action="store_true",
+                    help="a rehearsal: do not insist on an accelerator")
+    ap.add_argument("--root", default=ROOT,
+                    help="where BENCHMARK.json lies (a toy tree, to rehearse)")
+    ap.add_argument("--tag", default="")
+    a = ap.parse_args(argv)
+    os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
+    log = open(os.path.join(ROOT, "chiprun_out", "round_hunt.jsonl"), "a")
+
+    def out(row):
+        print(json.dumps(row), flush=True)
+        log.write(json.dumps(row) + "\n")
+        log.flush()
+
+    if a.stamp_cost:
+        out({"tag": a.tag, **stamp_cost()})
+    if not a.seeds:
+        return 0
+    from benchmarks import run
+    from bigdl_tpu.obs import get_tracer
+    seen = _watch_engines()
+    if a.trace:
+        _watch_trace(seen)
+    tracer = get_tracer()
+    for seed in a.seeds:
+        seen.clear()
+        if a.tracer_on:
+            tracer.clear()
+            tracer.enable()
+        stalls_before = _stalls()
+        t0 = time.perf_counter()
+        line = run.run_cell(a.root, a.cell, seed, a.seconds, a.trace,
+                            require_accelerator=not a.cpu)
+        row = {"tag": a.tag, "cell": a.cell, "seed": seed,
+               "trace": int(a.trace), "tracer_on": a.tracer_on,
+               "run_s": time.perf_counter() - t0, "line": line,
+               "rounds": seen.get("rounds")}
+        stall = _new_stall(stalls_before)
+        if stall is not None:
+            row["watchdog_event"] = stall
+        if a.tracer_on:
+            tracer.disable()
+            row["ring"] = {"events": len(tracer),
+                           "dropped": getattr(tracer, "dropped", None)}
+        if a.trace:
+            row["clock_offset"] = seen.get("clock_offset")
+            row["cover"] = seen.get("cover")
+            row["round_shapes"] = seen.get("round_shapes")
+        out(row)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main(sys.argv[1:]))

@@ -388,8 +388,12 @@ def test_lm_serving_request_span_trees(global_trace):
         assert root is not None, [n["name"] for n in tree["spans"]]
         names = {c["name"] for c in root["children"]}
         assert "lm/queue_wait" in names
-        assert "lm/prefill" in names
         assert "lm/decode_round" in names or "lm/verify_round" in names
+        # the admission is one envelope whose leaves tile it
+        admit = next(c for c in root["children"] if c["name"] == "lm/admit")
+        assert admit["args"]["deferred"] is False
+        assert {"lm/admit_host", "lm/prefill", "lm/insert",
+                "lm/first_token"} <= {c["name"] for c in admit["children"]}
         assert root["args"]["emitted"] >= 1
     # the enqueue instant precedes the root (recorded pre-admission)
     enq = [e for e in global_trace.events()

@@ -1,0 +1,300 @@
+"""The LM engine's round loop is tiled with phase stamps: with the tracer on,
+the worker thread's leaf spans cover every ``lm/round`` without a gap or an
+overlap; with it off, the same stamps fill ``stats()["rounds"]``, name the
+longest round's phase and log a slow round once; a round that hangs fires the
+``lm_round`` watchdog while it hangs."""
+import logging
+import time
+
+import numpy as np
+import pytest
+
+from bigdl_tpu.models.transformer import TransformerLM
+from bigdl_tpu.obs import Tracer, get_tracer, shared_watchdog
+from bigdl_tpu.serving import LMServingEngine, lm_engine
+from bigdl_tpu.serving.lm_engine import ROUND_PHASES
+from bigdl_tpu.serving.spec import SpecConfig
+
+LEAVES = {"lm/" + p for p in ROUND_PHASES}
+ADMIT_LEAVES = {"lm/admit_host", "lm/prefill", "lm/insert", "lm/first_token"}
+EPS_US = 1e-3       # stamps share one clock read; what is left is rounding
+
+ENGINES = {
+    "plain": {},
+    "chunked": {"max_prefill_chunk_tokens": 8},
+    "spec": {"spec": SpecConfig(k=3)},
+    "spec_tree": {"spec": SpecConfig(k=3, tree=True, promote_above=0.5)},
+}
+
+
+@pytest.fixture(scope="module")
+def model():
+    return TransformerLM(vocab_size=31, hidden_size=16, n_head=2, n_layers=1,
+                         max_len=48, pos_encoding="rope").build(seed=0)
+
+
+@pytest.fixture
+def tracer():
+    tr = get_tracer()
+    was = tr.enabled
+    tr.clear()
+    yield tr
+    tr.enabled = was
+    tr.clear()
+
+
+def _engine(model, **kw):
+    eng = LMServingEngine(model, slots=3, cache_len=48, block_len=4,
+                          max_new_tokens=8, prefill_buckets=(8, 16), **kw)
+    eng.warmup()
+    return eng
+
+
+def _serve(eng, n=6, max_new=6, seed=1):
+    """Staggered mixed-length requests, more than the slots hold, so that
+    admissions interrupt decode rounds and some requests queue."""
+    rng = np.random.RandomState(seed)
+    streams = []
+    for i in range(n):
+        streams.append(eng.submit(
+            rng.randint(1, 31, size=int(rng.choice([5, 9, 14]))),
+            max_new_tokens=max_new))
+        time.sleep(0.003 * (i % 3))
+    for s in streams:
+        s.result(timeout=120)
+    return streams
+
+
+def _close(eng):
+    """Closed, the worker thread has ended its last round."""
+    eng.close()
+    assert not eng._worker.is_alive()
+
+
+def _worker_events(tr, eng):
+    return [e for e in tr.events()
+            if e["ph"] == "X" and e["tid"] == eng._worker.ident]
+
+
+def _inside(ev, outer):
+    return (outer["ts"] - EPS_US <= ev["ts"]
+            and ev["ts"] + ev["dur"] <= outer["ts"] + outer["dur"] + EPS_US)
+
+
+@pytest.mark.parametrize("kind", sorted(ENGINES))
+def test_leaf_spans_tile_every_round(model, tracer, kind):
+    tracer.enable()
+    eng = _engine(model, **ENGINES[kind])
+    _serve(eng)
+    _close(eng)
+    evs = _worker_events(tracer, eng)
+    leaves = sorted((e for e in evs if e["name"] in LEAVES),
+                    key=lambda e: (e["ts"], e["dur"]))
+    rounds = [e for e in evs if e["name"] == "lm/round"]
+    assert len(rounds) >= 4 and leaves
+    # exactly one leaf at a time: each starts where the one before ended
+    for a, b in zip(leaves, leaves[1:]):
+        assert a["ts"] + a["dur"] <= b["ts"] + EPS_US, (a, b)
+    # rounds do not overlap either, and every leaf but lm/idle lies in the
+    # round whose index it carries
+    rounds.sort(key=lambda e: e["ts"])
+    for a, b in zip(rounds, rounds[1:]):
+        assert a["ts"] + a["dur"] <= b["ts"] + EPS_US
+        assert b["args"]["round"] == a["args"]["round"] + 1
+    by_index = {r["args"]["round"]: r for r in rounds}
+    for r in rounds:
+        inside = [e for e in leaves if e["name"] != "lm/idle"
+                  and e["args"]["round"] == r["args"]["round"]]
+        assert all(_inside(e, r) for e in inside)
+        assert sum(e["dur"] for e in inside) >= 0.99 * r["dur"], (kind, r)
+    for e in leaves:
+        if e["name"] == "lm/idle":
+            assert not any(_inside(e, r) and e["dur"] > EPS_US for r in rounds)
+        else:
+            assert e["args"]["round"] in by_index
+    if kind.startswith("spec"):
+        assert any(e["name"] == "lm/draft" for e in leaves)
+        assert any(e["name"] == "lm/verify_step" for e in evs)
+
+
+@pytest.mark.parametrize("kind", ["plain", "spec"])
+def test_decoding_round_holds_one_step_with_one_dispatch_and_one_wait(
+        model, tracer, kind):
+    tracer.enable()
+    eng = _engine(model, **ENGINES[kind])
+    _serve(eng)
+    _close(eng)
+    evs = _worker_events(tracer, eng)
+    step_name = "lm/decode_step" if kind == "plain" else "lm/verify_step"
+    decoded = 0
+    for r in (e for e in evs if e["name"] == "lm/round"):
+        mine = [e for e in evs if e is not r and e.get("args", {}).get("round")
+                == r["args"]["round"]]
+        steps = [e for e in mine if e["name"] == step_name]
+        assert len(steps) == (1 if r["args"]["active"] else 0)
+        if not steps:
+            continue
+        decoded += 1
+        assert steps[0]["args"]["active"] == r["args"]["active"]
+        for leaf in ("lm/decode_dispatch", "lm/decode_wait"):
+            found = [e for e in mine if e["name"] == leaf]
+            assert len(found) == 1 and _inside(found[0], steps[0]), (leaf, r)
+        # dispatch then wait fill the step: nothing else happens inside it
+        assert sum(e["dur"] for e in mine if e["name"] in
+                   ("lm/decode_dispatch", "lm/decode_wait")) \
+            == pytest.approx(steps[0]["dur"], abs=2 * EPS_US)
+    assert decoded >= 5
+    assert decoded == eng.metrics.decode_steps
+
+
+def test_admit_spans_count_the_prefills(model, tracer):
+    tracer.enable()
+    eng = _engine(model)
+    before = eng.metrics.prefills
+    streams = _serve(eng, n=7)
+    _close(eng)
+    evs = _worker_events(tracer, eng)
+    admits = [e for e in evs if e["name"] == "lm/admit"]
+    seated = [e for e in admits if not e["args"]["deferred"]]
+    assert len(seated) == eng.metrics.prefills - before == 7
+    assert sum(r["args"]["admitted"]
+               for r in evs if r["name"] == "lm/round") == 7
+    ids = {s.request_id for s in streams}
+    for a in seated:
+        assert a["args"]["kind"] == "submit" and a["args"]["request_id"] in ids
+        assert a["args"]["bucket"] in (8, 16)
+        assert a["args"]["prompt_len"] in (5, 9, 14)
+        assert a["args"]["matched_tokens"] % 4 == 0
+        # its leaves tile it and carry the request, so they nest in its tree
+        inside = [e for e in evs if e["name"] in ADMIT_LEAVES and _inside(e, a)]
+        assert {e["name"] for e in inside} == ADMIT_LEAVES
+        assert all(e["args"]["request_id"] == a["args"]["request_id"]
+                   for e in inside)
+        assert sum(e["dur"] for e in inside) == pytest.approx(a["dur"],
+                                                              abs=8 * EPS_US)
+    # envelopes of a request are told apart from work of the thread
+    by_cat = {e["name"]: e["cat"] for e in tracer.events()}
+    assert by_cat["lm/queue_wait"] == by_cat["lm/request"] \
+        == by_cat["lm/decode_round"] == "request"
+    assert by_cat["lm/admit"] == by_cat["lm/round"] == "serve"
+    tree = tracer.span_tree(streams[0].request_id)
+    root = next(n for n in tree["spans"] if n["name"] == "lm/request")
+    admit = next(c for c in root["children"] if c["name"] == "lm/admit")
+    assert {c["name"] for c in admit["children"]} == ADMIT_LEAVES
+
+
+def test_tracer_off_leaves_the_ring_empty_and_fills_the_round_record(
+        model, tracer):
+    tracer.disable()
+    eng = _engine(model)
+    eng.metrics.reset_rounds()
+    _serve(eng)
+    _close(eng)
+    assert len(tracer) == 0 and tracer.dropped == 0
+    rounds = eng.stats()["rounds"]
+    assert rounds["count"] >= 6 and rounds["plain"] >= 1
+    assert rounds["trace_dropped"] == 0 and rounds["slow"] == 0
+    assert set(rounds["phase_s"]) == set(ROUND_PHASES)
+    for phase in ("sched", "admit_host", "prefill", "insert", "first_token",
+                  "decode_dispatch", "decode_wait", "emit"):
+        assert rounds["phase_s"][phase] > 0, phase
+    assert rounds["phase_s"]["draft"] == rounds["phase_s"]["tree_commit"] == 0
+    assert 0 < rounds["median_plain_s"] <= rounds["longest"]["seconds"]
+    longest = rounds["longest"]
+    assert sum(longest["phase_s"].values()) == pytest.approx(
+        longest["seconds"], rel=1e-6)
+    assert longest["phase"] == max(longest["phase_s"],
+                                   key=longest["phase_s"].get)
+    assert 0 <= longest["at_s"] and abs(longest["at_unix"] - time.time()) < 600
+    assert 0 <= longest["active"] <= 3 and 0 <= longest["admitted"] <= 3
+    # the rounds' own time (all but idle) is what the rounds summed to
+    assert eng.metrics.rounds == rounds["count"]
+
+
+def test_slow_pick_is_named_by_the_longest_round_and_logged_once(
+        model, tracer, monkeypatch, caplog):
+    tracer.disable()
+    monkeypatch.setattr(lm_engine, "SLOW_ROUND_S", 0.2)
+    eng = _engine(model)
+    _serve(eng, n=3)                    # plain rounds for the running median
+    assert eng.metrics.plain_rounds >= 3
+    eng.metrics.reset_rounds()
+    _serve(eng, n=2, seed=3)
+    real, calls = lm_engine.LMServingEngine._pick, {"n": 0}
+
+    def slow_pick(*a, **kw):
+        calls["n"] += bool(kw.get("clamp"))     # a decode round's pick
+        if kw.get("clamp") and calls["n"] == 5:
+            time.sleep(0.3)
+        return real(*a, **kw)
+
+    monkeypatch.setattr(lm_engine.LMServingEngine, "_pick",
+                        staticmethod(slow_pick))
+    with caplog.at_level(logging.WARNING, logger="bigdl_tpu.serving"):
+        _serve(eng, n=3, max_new=8, seed=5)
+        _close(eng)
+    rounds = eng.stats()["rounds"]
+    longest = rounds["longest"]
+    assert longest["phase"] == "emit"
+    assert 0.3 <= longest["phase_s"]["emit"] <= longest["seconds"] < 3.0
+    assert rounds["slow"] == 1
+    lines = [r.getMessage() for r in caplog.records
+             if "slow round" in r.getMessage()]
+    assert len(lines) == 1
+    assert f"slow round {longest['round']}:" in lines[0]
+    assert f"'emit': {round(longest['phase_s']['emit'], 4)}" in lines[0]
+
+
+def test_held_round_fires_the_lm_round_watchdog(model, tracer, monkeypatch):
+    monkeypatch.setenv("BIGDL_TPU_WATCHDOG_K", "10")
+    wd = shared_watchdog("lm_round")
+    monkeypatch.setattr(wd, "_capture", {})     # no /proc scan in a test
+    monkeypatch.setattr(wd, "poll_s", 0.05)
+    wd.stop()
+    tracer.disable()
+    eng = _engine(model)
+    assert eng.watchdog is wd
+    _serve(eng, n=3)                    # completed rounds arm the median rule
+    assert len(wd._durations) >= wd.min_samples
+    real, calls, seen = lm_engine.LMServingEngine._pick, {"n": 0}, {}
+
+    def held_pick(*a, **kw):
+        calls["n"] += 1
+        if calls["n"] == 8:     # in a plain round, past both admissions
+            # the round hangs until it is seen (a toy's admission round can
+            # pass 10 medians by itself: only what fires DURING the hold counts)
+            before, deadline = wd.last_event, time.perf_counter() + 20.0
+            while wd.last_event is before and time.perf_counter() < deadline:
+                time.sleep(0.01)
+            seen["event"] = wd.last_event
+        return real(*a, **kw)
+
+    monkeypatch.setattr(lm_engine.LMServingEngine, "_pick",
+                        staticmethod(held_pick))
+    _serve(eng, n=2, seed=7)
+    event = seen["event"]
+    assert event["watchdog"] == "lm_round"
+    assert event["inflight_s"] >= event["threshold_s"]
+    # the dump names where the worker hung, while it hung
+    assert "held_pick" in event["thread_stacks"][eng._worker.name]
+    assert eng.stats()["rounds"]["watchdog"]["stalls"] == wd.stall_count >= 1
+    _close(eng)
+    # never across lm/idle, and disarmed at close()
+    assert wd._inflight_since is None and not wd._durations
+    assert wd._thread is None
+
+
+def test_tracer_counts_what_a_full_ring_drops():
+    tr = Tracer(capacity=8, enabled=True)
+    for i in range(8):
+        tr.instant(f"e{i}")
+    assert tr.dropped == 0 and len(tr) == 8
+    t0 = time.perf_counter()
+    for i in range(5):
+        tr.add_complete(f"s{i}", t0, 0.001)
+    assert tr.dropped == 5 and len(tr) == 8
+    assert {e["name"] for e in tr.events()} == {
+        "e5", "e6", "e7", "s0", "s1", "s2", "s3", "s4"}
+    assert tr.export_chrome()["otherData"]["dropped"] == 5
+    tr.clear()
+    assert tr.dropped == 0 and len(tr) == 0
