@@ -30,6 +30,7 @@ import numpy as np
 from jax import lax
 
 from bigdl_tpu.models.transformer import TransformerLM
+from bigdl_tpu.serving.kvcache.blocks import read_chain, write_rows
 
 
 def _block_qkv(model, bp, h):
@@ -181,17 +182,78 @@ def _kv_quantize_rows(x):
     return q, s
 
 
+def _paged_attention(mha, q, k, v, arenas, layer, blk, off, tables, mask):
+    """One layer's cached attention over PAGED arenas, shared by the
+    decode, verify and tree-verify steps: write the W new rows of each
+    slot (``k``/``v`` (S, H, W, D), row j at ``(blk, off)[s, j]``) into
+    ``arenas[..][layer]``, then attend ``q`` (S, H, W, D) over each
+    slot's chain under ``mask`` (S, 1, W, ctx).  ``arenas`` is ``(k, v)``
+    or, for an int8 pool, ``(k, v, k_scale, v_scale)``: rows are
+    quantized per (position, head) on the way in and the gather
+    dequantizes in flight.  The layout is the pool's
+    (``serving.kvcache.blocks``); scores and softmax are f32.  Returns
+    (o (S, H, W, D) f32, arenas')."""
+    block = (arenas[0].shape[2], mha.n_head, mha.head_dim)    # (B, H, D)
+    k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)   # (S, W, H, D)
+    if len(arenas) == 4:
+        ka, va, ksa, vsa = arenas
+        k, ksr = _kv_quantize_rows(k)
+        v, vsr = _kv_quantize_rows(v)
+        ksa = write_rows(ksa, layer, blk, off, ksr)
+        vsa = write_rows(vsa, layer, blk, off, vsr)
+    else:
+        ka, va = arenas
+    ka = write_rows(ka, layer, blk, off, k)
+    va = write_rows(va, layer, blk, off, v)
+    # gather-by-table: the gathered axis IS the position, (S, ctx, H, D)
+    kg = read_chain(ka, layer, tables, block).astype(jnp.float32)
+    vg = read_chain(va, layer, tables, block).astype(jnp.float32)
+    if len(arenas) == 4:            # dequant inside the gather
+        kg = kg * read_chain(ksa, layer, tables, block[:2])[..., None]
+        vg = vg * read_chain(vsa, layer, tables, block[:2])[..., None]
+        arenas = (ka, va, ksa, vsa)
+    else:
+        arenas = (ka, va)
+    scores = jnp.einsum("bhqd,bkhd->bhqk", q.astype(jnp.float32), kg)
+    scores = scores / jnp.sqrt(jnp.float32(mha.head_dim))
+    scores = jnp.where(mask, scores, -1e30)
+    w = jax.nn.softmax(scores, axis=-1)
+    return jnp.einsum("bhqk,bkhd->bhqd", w, vg), arenas
+
+
+def _scan_layers(params, h, arenas, layer_fn):
+    """The paged steps' layer loop: the arenas ride the CARRY whole and
+    each layer indexes them itself (``layer_fn(h, bp, layer, arenas) ->
+    (h, arenas)``), so the compiled loop updates the donated buffers in
+    place; threaded as ``xs`` and stacked ``ys`` they were re-laid out
+    and copied every layer (PERF.md, PR 25)."""
+    n = jax.tree_util.tree_leaves(params["blocks"])[0].shape[0]
+
+    def body(carry, x):
+        bp, layer = x
+        return layer_fn(carry[0], bp, layer, carry[1]), None
+
+    (h, arenas), _ = lax.scan(
+        body, (h, tuple(arenas)), (params["blocks"], jnp.arange(n)))
+    return h, arenas
+
+
+def _arenas(k_arena, v_arena, k_scale, v_scale):
+    return ((k_arena, v_arena) if k_scale is None
+            else (k_arena, v_arena, k_scale, v_scale))
+
+
 def _prefill_suffix_parts(model, params, ids0, last_index, prefix_len,
                           blocks, k_arena, v_arena,
                           k_scale=None, v_scale=None):
     """Prefill a prompt SUFFIX against a cached prefix held in paged KV
     blocks: ``ids0`` (1, Ts) is the (bucket-padded) suffix, whose tokens
     live at absolute positions ``prefix_len + i``; ``blocks`` (Pb,) is
-    the padded block chain holding the prefix k/v in the arenas
-    (L, N, H, B, D) — padded entries point at the scratch block and are
-    masked via ``prefix_len``.  Returns (logits at suffix index
-    ``last_index``, k, v) with k/v (L, 1, H, Ts, D), exactly like
-    :func:`_prefill_parts` for the suffix rows.
+    the padded block chain holding the prefix k/v in the pool's arenas
+    — padded entries point at the scratch block and are masked via
+    ``prefix_len``.  Returns (logits at suffix index ``last_index``, k,
+    v) with k/v (L, 1, H, Ts, D), exactly like :func:`_prefill_parts`
+    for the suffix rows.
 
     Numerics are the offline prefill's: suffix queries attend the SAME
     valid key set (cached prefix keys — stored post-RoPE, so directly
@@ -199,14 +261,15 @@ def _prefill_suffix_parts(model, params, ids0, last_index, prefix_len,
     ``dot_product_attention`` core, with padded/garbage keys masked to
     the same NEG_INF before the max-subtracted softmax.
 
-    ``k_scale``/``v_scale`` (L, N, H, B) f32 mark int8-quantized arenas
+    ``k_scale``/``v_scale`` mark int8-quantized arenas
     (``BlockPool(kv_quant="int8")``): the prefix gather dequantizes
     in-flight (int8 block x per-row scale); the returned suffix k/v stay
     full precision — the engine quantizes them at ``_insert_blocks``."""
     from bigdl_tpu.nn.attention import dot_product_attention
 
     b, ts = ids0.shape
-    B = k_arena.shape[3]
+    B = k_arena.shape[2]
+    block = (B, model._mha.n_head, model._mha.head_dim)
     pb = blocks.shape[0]
     h = params["embed"][ids0]
     positions = prefix_len + jnp.arange(ts)
@@ -222,34 +285,28 @@ def _prefill_suffix_parts(model, params, ids0, last_index, prefix_len,
     mask = ((jk < prefix_len)
             | ((jk >= pb * B) & (jk - pb * B <= jq)))[None, None]
 
-    quantized = k_scale is not None
+    def prefix(arena, scale, layer, dtype):
+        # the prefix chain (Pb*B, H, D) -> (1, H, Pb*B, D)
+        g = read_chain(arena, layer, blocks, block)
+        if scale is not None:       # dequant inside the gather
+            g = (g.astype(jnp.float32)
+                 * read_chain(scale, layer, blocks, block[:2])[..., None])
+        return g.transpose(1, 0, 2)[None].astype(dtype)
 
-    def body(h, layer):
-        if quantized:
-            bp, kc, vc, ks, vs = layer
-        else:
-            bp, kc, vc = layer      # kc/vc: (N, H, B, D) one layer
+    def body(h, x):
+        bp, layer = x
         q, k, v = _block_qkv(model, bp, h)
         q, k = model._rope(q, k, positions)
-        # gather the prefix chain: (Pb, H, B, D) -> (1, H, Pb*B, D)
-        kp = kc[blocks]
-        vp = vc[blocks]
-        if quantized:               # dequant inside the gather
-            kp = kp.astype(jnp.float32) * ks[blocks][..., None]
-            vp = vp.astype(jnp.float32) * vs[blocks][..., None]
-        kp = kp.transpose(1, 0, 2, 3).reshape(
-            1, kc.shape[1], pb * B, kc.shape[3]).astype(k.dtype)
-        vp = vp.transpose(1, 0, 2, 3).reshape(
-            1, vc.shape[1], pb * B, vc.shape[3]).astype(v.dtype)
+        kp = prefix(k_arena, k_scale, layer, k.dtype)
+        vp = prefix(v_arena, v_scale, layer, v.dtype)
         o = dot_product_attention(q, jnp.concatenate([kp, k], axis=2),
                                   jnp.concatenate([vp, v], axis=2),
                                   mask=mask)
         h = _finish_block(model, bp, h, o)
         return h, (k, v)
 
-    xs = ((params["blocks"], k_arena, v_arena, k_scale, v_scale)
-          if quantized else (params["blocks"], k_arena, v_arena))
-    h, (k, v) = lax.scan(body, h, xs)
+    h, (k, v) = lax.scan(
+        body, h, (params["blocks"], jnp.arange(k_arena.shape[0])))
     h = lax.dynamic_slice_in_dim(h, last_index, 1, axis=1)
     h = model._layer_norm(params["ln_f"], h)
     logits = _head_logits(model, params, h)[:, 0]
@@ -258,41 +315,36 @@ def _prefill_suffix_parts(model, params, ids0, last_index, prefix_len,
 
 def _insert_blocks(k_arena, v_arena, k_new, v_new, block_ids,
                    k_scale=None, v_scale=None):
-    """Scatter a prefilled chunk's k/v (L, 1, H, Tb, D) into arena
-    blocks (L, N, H, B, D): row i of the chunk lands in block
-    ``block_ids[i // B]`` at offset ``i % B`` (chunks always start
-    block-aligned).  ``block_ids`` is padded to ``ceil(Tb_bucket / B)``
-    with the scratch block, which absorbs the bucket-padding garbage —
-    by the time any real position in those rows is attended, decode has
-    overwritten it under the position mask.
+    """Scatter a prefilled chunk's k/v (L, 1, H, Tb, D) into the pool's
+    arena blocks: row i of the chunk lands in block ``block_ids[i // B]``
+    at offset ``i % B`` (chunks always start block-aligned).
+    ``block_ids`` is padded to ``ceil(Tb_bucket / B)`` with the scratch
+    block, which absorbs the bucket-padding garbage — by the time any
+    real position in those rows is attended, decode has overwritten it
+    under the position mask.
 
-    With ``k_scale``/``v_scale`` (L, N, H, B) f32 (int8-quantized pool)
-    the chunk rows are quantized per (position, head) on the way in and
-    the scale arenas are scattered alongside; returns a 4-tuple then."""
-    L, N, H, B, D = k_arena.shape
+    With ``k_scale``/``v_scale`` (int8-quantized pool) the chunk rows are
+    quantized per (position, head) on the way in and the scale arenas
+    are scattered alongside; returns a 4-tuple then."""
+    B = k_arena.shape[2]
     nb = block_ids.shape[0]
-    tb = k_new.shape[3]
-    pad = nb * B - tb
-    if pad:
-        padw = ((0, 0), (0, 0), (0, 0), (0, pad), (0, 0))
-        k_new = jnp.pad(k_new, padw)
-        v_new = jnp.pad(v_new, padw)
+
+    def blocks(x):      # (L, 1, H, Tb, D) -> whole blocks (L, nb, B, H, D)
+        x = x[:, 0].transpose(0, 2, 1, 3)
+        x = jnp.pad(x, ((0, 0), (0, nb * B - x.shape[1]), (0, 0), (0, 0)))
+        return x.reshape(x.shape[0], nb, B, *x.shape[2:])
+
+    every = slice(None)
+    kb, vb = blocks(k_new), blocks(v_new)
     if k_scale is not None:
-        kq, ksr = _kv_quantize_rows(k_new[:, 0])     # (L, H, nb*B, D/-)
-        vq, vsr = _kv_quantize_rows(v_new[:, 0])
-        kb = kq.reshape(L, H, nb, B, D).transpose(0, 2, 1, 3, 4)
-        vb = vq.reshape(L, H, nb, B, D).transpose(0, 2, 1, 3, 4)
-        ksb = ksr.reshape(L, H, nb, B).transpose(0, 2, 1, 3)
-        vsb = vsr.reshape(L, H, nb, B).transpose(0, 2, 1, 3)
-        k_arena = k_arena.at[:, block_ids].set(kb)
-        v_arena = v_arena.at[:, block_ids].set(vb)
-        k_scale = k_scale.at[:, block_ids].set(ksb)
-        v_scale = v_scale.at[:, block_ids].set(vsb)
+        kb, ksb = _kv_quantize_rows(kb)
+        vb, vsb = _kv_quantize_rows(vb)
+        k_scale = write_rows(k_scale, every, block_ids, None, ksb)
+        v_scale = write_rows(v_scale, every, block_ids, None, vsb)
+    k_arena = write_rows(k_arena, every, block_ids, None, kb)
+    v_arena = write_rows(v_arena, every, block_ids, None, vb)
+    if k_scale is not None:
         return k_arena, v_arena, k_scale, v_scale
-    kb = k_new[:, 0].reshape(L, H, nb, B, D).transpose(0, 2, 1, 3, 4)
-    vb = v_new[:, 0].reshape(L, H, nb, B, D).transpose(0, 2, 1, 3, 4)
-    k_arena = k_arena.at[:, block_ids].set(kb.astype(k_arena.dtype))
-    v_arena = v_arena.at[:, block_ids].set(vb.astype(v_arena.dtype))
     return k_arena, v_arena
 
 
@@ -306,14 +358,15 @@ def _decode_step_paged(model, params, token, pos, tables, k_arena,
     ONE AOT executable regardless of sequence lengths.  The new k/v
     scatter by (block, offset) derived from ``pos``; attention reads
     each slot's chain under the identical position mask / score math as
-    the slot engine — either by gathering it into a dense (S, H, M*B, D)
-    view (``attn_impl="gather"``, the XLA baseline) or in place via the
-    Pallas block-table kernel (``attn_impl="paged_kernel"``,
+    the slot engine — either by gathering it into a dense
+    (S, M*B, H, D) view (``attn_impl="gather"``, the XLA baseline) or in
+    place via the Pallas block-table kernel (``attn_impl="paged_kernel"``,
     ``ops.paged_attention`` — same f32 softmax formulation, so streams
-    stay token-exact across the two).  Arenas (L, N, H, B, D) are
-    donated by the serving engine.
+    stay token-exact across the two).  The arenas (the pool's layout,
+    ``serving.kvcache.blocks``) are donated by the serving engine and
+    carried whole through the layer loop (:func:`_scan_layers`).
 
-    ``k_scale``/``v_scale`` (L, N, H, B) f32 mark int8 arenas
+    ``k_scale``/``v_scale`` mark int8 arenas
     (``BlockPool(kv_quant="int8")``): the new k/v row is quantized per
     (slot, head) on write and the gather dequantizes in-flight.  The
     Pallas paged kernel reads raw blocks, so quantized pools require
@@ -326,7 +379,7 @@ def _decode_step_paged(model, params, token, pos, tables, k_arena,
                          "(the Pallas paged kernel reads raw blocks)")
     mha = model._mha
     s, m = tables.shape
-    B = k_arena.shape[3]
+    B = k_arena.shape[2]
     ctx = m * B
     h = params["embed"][token][:, None, :]
     if model.pos_encoding == "learned":
@@ -335,68 +388,31 @@ def _decode_step_paged(model, params, token, pos, tables, k_arena,
     mask = (jnp.arange(ctx)[None, :] <= pos[:, None])[:, None, None, :]
     # the block holding each slot's write position (idle slots carry an
     # all-scratch table: their garbage write lands in block 0 and is
-    # never attended)
-    blk = tables[jnp.arange(s), pos // B]
-    off = pos % B
+    # never attended); one new row a slot: (S, 1)
+    blk = tables[jnp.arange(s), pos // B][:, None]
+    off = (pos % B)[:, None]
 
-    quantized = k_scale is not None
-
-    def body(carry, layer):
-        h = carry
-        if quantized:
-            bp, kc, vc, ks, vs = layer
-        else:
-            bp, kc, vc = layer      # kc/vc: (N, H, B, D) one layer
+    def layer_fn(h, bp, layer, arenas):
         q, k, v = _block_qkv(model, bp, h)  # (S, H, 1, D)
         q, k = model._rope(q, k, positions)
-        if quantized:
-            kq, ksr = _kv_quantize_rows(k[:, :, 0, :])   # (S, H, D/-)
-            vq, vsr = _kv_quantize_rows(v[:, :, 0, :])
-            kc = kc.at[blk, :, off, :].set(kq)
-            vc = vc.at[blk, :, off, :].set(vq)
-            ks = ks.at[blk, :, off].set(ksr)
-            vs = vs.at[blk, :, off].set(vsr)
-        else:
-            kc = kc.at[blk, :, off, :].set(k[:, :, 0, :].astype(kc.dtype))
-            vc = vc.at[blk, :, off, :].set(v[:, :, 0, :].astype(vc.dtype))
         if attn_impl == "paged_kernel":
-            # in-place block reads via the table (no kc[tables] dense
-            # materialization); numerics identical to the gather below
+            # in-place block reads via the table (no dense gather);
+            # numerics identical to the gather
             from bigdl_tpu.ops import paged_decode_attention
-            o = paged_decode_attention(q, kc, vc, tables, pos)
+            arenas = tuple(
+                write_rows(a, layer, blk, off, x.transpose(0, 2, 1, 3))
+                for a, x in zip(arenas, (k, v)))
+            o = paged_decode_attention(q, *arenas, tables, pos, layer=layer)
         else:
-            # gather-by-table: (S, M, H, B, D) -> (S, H, M*B, D);
-            # position p maps to (p // B, p % B), so the gathered axis
-            # IS the position
-            kg, vg = kc[tables], vc[tables]       # (S, M, H, B, D)
-            if quantized:           # dequant inside the gather
-                kg = kg.astype(jnp.float32) * ks[tables][..., None]
-                vg = vg.astype(jnp.float32) * vs[tables][..., None]
-            kg = kg.transpose(0, 2, 1, 3, 4).reshape(
-                s, mha.n_head, ctx, mha.head_dim)
-            vg = vg.transpose(0, 2, 1, 3, 4).reshape(
-                s, mha.n_head, ctx, mha.head_dim)
-            scores = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
-                                kg.astype(jnp.float32))
-            scores = scores / jnp.sqrt(jnp.float32(mha.head_dim))
-            scores = jnp.where(mask, scores, -1e30)
-            w = jax.nn.softmax(scores, axis=-1)
-            o = jnp.einsum("bhqk,bhkd->bhqd", w, vg.astype(jnp.float32))
-        h = _finish_block(model, bp, h, o.astype(h.dtype))
-        return h, ((kc, vc, ks, vs) if quantized else (kc, vc))
+            o, arenas = _paged_attention(mha, q, k, v, arenas, layer, blk,
+                                         off, tables, mask)
+        return _finish_block(model, bp, h, o.astype(h.dtype)), arenas
 
-    if quantized:
-        h, (k_arena, v_arena, k_scale, v_scale) = lax.scan(
-            body, h, (params["blocks"], k_arena, v_arena, k_scale, v_scale))
-    else:
-        h, (k_arena, v_arena) = lax.scan(
-            body, h, (params["blocks"], k_arena, v_arena))
+    h, arenas = _scan_layers(
+        params, h, _arenas(k_arena, v_arena, k_scale, v_scale), layer_fn)
     h = model._layer_norm(params["ln_f"], h)
     logits = _head_logits(model, params, h)[:, 0]
-    logits = logits.astype(jnp.float32)
-    if quantized:
-        return logits, k_arena, v_arena, k_scale, v_scale
-    return logits, k_arena, v_arena
+    return (logits.astype(jnp.float32),) + arenas
 
 
 def _verify_step_paged(model, params, tokens, pos, n_cand, tables,
@@ -418,89 +434,56 @@ def _verify_step_paged(model, params, tokens, pos, n_cand, tables,
     position mask (`<= pos + j`) hides it until a later write overwrites
     that offset — the same stale-row invariant the plain decode step
     already relies on for recycled blocks.  Attention always uses the
-    dense gather (the Pallas paged kernel is single-query); its f32
-    score/softmax math is identical to ``_decode_step_paged``'s gather
-    branch, so emitted streams stay token-exact with every decode_attn
+    dense gather (the Pallas paged kernel is single-query); it IS
+    ``_decode_step_paged``'s gather branch (:func:`_paged_attention`),
+    so emitted streams stay token-exact with every decode_attn
     setting."""
+    w = tokens.shape[1]
+    abspos = pos[:, None] + jnp.arange(w)[None, :]   # (S, W)
+    ctx = tables.shape[1] * k_arena.shape[2]
+    # row j attends positions <= pos + j: (S, 1, W, ctx)
+    mask = (jnp.arange(ctx)[None, None, :] <= abspos[:, :, None])[:, None]
+    return _verify_rows(model, params, tokens, n_cand, tables,
+                        _arenas(k_arena, v_arena, k_scale, v_scale),
+                        store=abspos, rope=abspos, mask=mask)
+
+
+def _verify_rows(model, params, tokens, n_cand, tables, arenas, *, store,
+                 rope, mask):
+    """The body linear and tree verify share: row j of slot s is stored
+    at arena offset ``store[s, j]``, rotated at position ``rope[s, j]``
+    and attends under ``mask`` (S, 1, W, ctx)."""
     mha = model._mha
     s, w = tokens.shape
     m = tables.shape[1]
-    B = k_arena.shape[3]
-    ctx = m * B
-    offs = jnp.arange(w)
-    abspos = pos[:, None] + offs[None, :]            # (S, W)
+    B = arenas[0].shape[2]
     h = params["embed"][tokens]                      # (S, W, hidden)
     if model.pos_encoding == "learned":
         # clamp: padded rows of a near-full slot may index past the table
-        h = h + params["pos"][jnp.minimum(abspos, params["pos"].shape[0] - 1)]
+        h = h + params["pos"][jnp.minimum(rope, params["pos"].shape[0] - 1)]
     # (S, 1, W): broadcasts against (S, H, W, half) inside apply_rope
-    positions = abspos[:, None, :]
-    # row j attends positions <= pos + j: (S, 1, W, ctx)
-    mask = (jnp.arange(ctx)[None, None, :] <= abspos[:, :, None])[:, None]
-    # scatter targets: candidate j writes block tables[s, (pos+j) // B] at
-    # offset (pos+j) % B.  Two safety redirects: the column index clamps
-    # to the table width (a padded row of a chain-filling slot would
-    # otherwise gather-clamp onto the LAST real block), and rows >=
-    # n_cand go to the scratch block outright.
-    rowsel = jnp.arange(s)[:, None]
-    blkcol = jnp.minimum(abspos // B, m - 1)
-    blk = jnp.where(offs[None, :] < n_cand[:, None],
-                    tables[rowsel, blkcol], 0)       # (S, W)
-    off = abspos % B
+    positions = rope[:, None, :]
+    # scatter targets: row j writes block tables[s, store // B] at offset
+    # store % B.  Two safety redirects: the column index clamps to the
+    # table width (a padded row of a chain-filling slot would otherwise
+    # gather-clamp onto the LAST real block), and rows >= n_cand go to
+    # the scratch block outright.
+    blkcol = jnp.minimum(store // B, m - 1)
+    blk = jnp.where(jnp.arange(w)[None, :] < n_cand[:, None],
+                    tables[jnp.arange(s)[:, None], blkcol], 0)   # (S, W)
+    off = store % B
 
-    quantized = k_scale is not None
-
-    def body(carry, layer):
-        h = carry
-        if quantized:
-            bp, kc, vc, ks, vs = layer
-        else:
-            bp, kc, vc = layer      # kc/vc: (N, H, B, D) one layer
+    def layer_fn(h, bp, layer, arenas):
         q, k, v = _block_qkv(model, bp, h)  # (S, H, W, D)
         q, k = model._rope(q, k, positions)
-        # advanced-index write: (S, W) block/offset pairs each take an
-        # (H, D) row — update shaped (S, W, H, D)
-        if quantized:
-            kq, ksr = _kv_quantize_rows(k.transpose(0, 2, 1, 3))
-            vq, vsr = _kv_quantize_rows(v.transpose(0, 2, 1, 3))
-            kc = kc.at[blk, :, off, :].set(kq)
-            vc = vc.at[blk, :, off, :].set(vq)
-            ks = ks.at[blk, :, off].set(ksr)
-            vs = vs.at[blk, :, off].set(vsr)
-        else:
-            kc = kc.at[blk, :, off, :].set(
-                k.transpose(0, 2, 1, 3).astype(kc.dtype))
-            vc = vc.at[blk, :, off, :].set(
-                v.transpose(0, 2, 1, 3).astype(vc.dtype))
-        kg, vg = kc[tables], vc[tables]           # (S, M, H, B, D)
-        if quantized:               # dequant inside the gather
-            kg = kg.astype(jnp.float32) * ks[tables][..., None]
-            vg = vg.astype(jnp.float32) * vs[tables][..., None]
-        kg = kg.transpose(0, 2, 1, 3, 4).reshape(
-            s, mha.n_head, ctx, mha.head_dim)
-        vg = vg.transpose(0, 2, 1, 3, 4).reshape(
-            s, mha.n_head, ctx, mha.head_dim)
-        scores = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
-                            kg.astype(jnp.float32))
-        scores = scores / jnp.sqrt(jnp.float32(mha.head_dim))
-        scores = jnp.where(mask, scores, -1e30)
-        wts = jax.nn.softmax(scores, axis=-1)
-        o = jnp.einsum("bhqk,bhkd->bhqd", wts, vg.astype(jnp.float32))
-        h = _finish_block(model, bp, h, o.astype(h.dtype))
-        return h, ((kc, vc, ks, vs) if quantized else (kc, vc))
+        o, arenas = _paged_attention(mha, q, k, v, arenas, layer, blk, off,
+                                     tables, mask)
+        return _finish_block(model, bp, h, o.astype(h.dtype)), arenas
 
-    if quantized:
-        h, (k_arena, v_arena, k_scale, v_scale) = lax.scan(
-            body, h, (params["blocks"], k_arena, v_arena, k_scale, v_scale))
-    else:
-        h, (k_arena, v_arena) = lax.scan(
-            body, h, (params["blocks"], k_arena, v_arena))
+    h, arenas = _scan_layers(params, h, arenas, layer_fn)
     h = model._layer_norm(params["ln_f"], h)
     logits = _head_logits(model, params, h)      # (S, W, V)
-    logits = logits.astype(jnp.float32)
-    if quantized:
-        return logits, k_arena, v_arena, k_scale, v_scale
-    return logits, k_arena, v_arena
+    return (logits.astype(jnp.float32),) + arenas
 
 
 def _tree_verify_step_paged(model, params, tokens, pos, n_cand, tables,
@@ -518,8 +501,8 @@ def _tree_verify_step_paged(model, params, tokens, pos, n_cand, tables,
     rotates it at its TRUE position ``pos + depths[j]``, and its mask
     admits the committed prefix (``col < pos``) plus exactly its
     ancestor offsets.  A path node at depth d therefore attends the same
-    (position, key) set as linear-verify row d — identical f32
-    gather/score/softmax math, so logits along any root-to-leaf path are
+    (position, key) set as linear-verify row d — the same body
+    (:func:`_verify_rows`), so logits along any root-to-leaf path are
     bit-identical to ``_verify_step_paged`` scoring that path as a
     chain, and for chain shapes (``anc`` lower-triangular, ``depths[j]
     == j``) the whole step IS the linear verify.  After the host walk
@@ -528,22 +511,12 @@ def _tree_verify_step_paged(model, params, tokens, pos, n_cand, tables,
     the rewound pointer exactly as in linear verify.  Rows >= ``n_cand``
     (lower-rung or plain slots riding a wider executable) scatter to the
     scratch block."""
-    mha = model._mha
-    s, w = tokens.shape
-    m = tables.shape[1]
-    B = k_arena.shape[3]
-    ctx = m * B
-    offs = jnp.arange(w)
+    w = tokens.shape[1]
+    ctx = tables.shape[1] * k_arena.shape[2]
     depths = jnp.asarray(depths, jnp.int32)          # (W,) static
     ancm = jnp.asarray(np.asarray(anc), bool)        # (W, W) static
-    store = pos[:, None] + offs[None, :]             # (S, W) arena offsets
+    store = pos[:, None] + jnp.arange(w)[None, :]    # (S, W) arena offsets
     rope = pos[:, None] + depths[None, :]            # (S, W) true positions
-    h = params["embed"][tokens]                      # (S, W, hidden)
-    if model.pos_encoding == "learned":
-        # clamp: padded rows of a near-full slot may index past the table
-        h = h + params["pos"][jnp.minimum(rope, params["pos"].shape[0] - 1)]
-    # (S, 1, W): broadcasts against (S, H, W, half) inside apply_rope
-    positions = rope[:, None, :]
     # node j attends the committed prefix (col < pos) plus the offsets of
     # its ancestors-or-self (col == pos + i with anc[j, i]): (S, 1, W, ctx)
     rel = jnp.arange(ctx)[None, :] - pos[:, None]    # (S, ctx)
@@ -551,71 +524,13 @@ def _tree_verify_step_paged(model, params, tokens, pos, n_cand, tables,
     anc_cols = ancm[:, jnp.clip(rel, 0, w - 1)]      # (W, S, ctx)
     mask = ((rel < 0)[:, None, :]
             | (in_tree[:, None, :] & jnp.moveaxis(anc_cols, 0, 1)))
-    mask = mask[:, None]                             # (S, 1, W, ctx)
-    # scatter targets: node j writes block tables[s, (pos+j) // B] at
-    # offset (pos+j) % B — same column clamp and scratch redirect as
-    # _verify_step_paged
-    rowsel = jnp.arange(s)[:, None]
-    blkcol = jnp.minimum(store // B, m - 1)
-    blk = jnp.where(offs[None, :] < n_cand[:, None],
-                    tables[rowsel, blkcol], 0)       # (S, W)
-    off = store % B
-
-    quantized = k_scale is not None
-
-    def body(carry, layer):
-        h = carry
-        if quantized:
-            bp, kc, vc, ks, vs = layer
-        else:
-            bp, kc, vc = layer      # kc/vc: (N, H, B, D) one layer
-        q, k, v = _block_qkv(model, bp, h)  # (S, H, W, D)
-        q, k = model._rope(q, k, positions)
-        if quantized:
-            kq, ksr = _kv_quantize_rows(k.transpose(0, 2, 1, 3))
-            vq, vsr = _kv_quantize_rows(v.transpose(0, 2, 1, 3))
-            kc = kc.at[blk, :, off, :].set(kq)
-            vc = vc.at[blk, :, off, :].set(vq)
-            ks = ks.at[blk, :, off].set(ksr)
-            vs = vs.at[blk, :, off].set(vsr)
-        else:
-            kc = kc.at[blk, :, off, :].set(
-                k.transpose(0, 2, 1, 3).astype(kc.dtype))
-            vc = vc.at[blk, :, off, :].set(
-                v.transpose(0, 2, 1, 3).astype(vc.dtype))
-        kg, vg = kc[tables], vc[tables]           # (S, M, H, B, D)
-        if quantized:               # dequant inside the gather
-            kg = kg.astype(jnp.float32) * ks[tables][..., None]
-            vg = vg.astype(jnp.float32) * vs[tables][..., None]
-        kg = kg.transpose(0, 2, 1, 3, 4).reshape(
-            s, mha.n_head, ctx, mha.head_dim)
-        vg = vg.transpose(0, 2, 1, 3, 4).reshape(
-            s, mha.n_head, ctx, mha.head_dim)
-        scores = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
-                            kg.astype(jnp.float32))
-        scores = scores / jnp.sqrt(jnp.float32(mha.head_dim))
-        scores = jnp.where(mask, scores, -1e30)
-        wts = jax.nn.softmax(scores, axis=-1)
-        o = jnp.einsum("bhqk,bhkd->bhqd", wts, vg.astype(jnp.float32))
-        h = _finish_block(model, bp, h, o.astype(h.dtype))
-        return h, ((kc, vc, ks, vs) if quantized else (kc, vc))
-
-    if quantized:
-        h, (k_arena, v_arena, k_scale, v_scale) = lax.scan(
-            body, h, (params["blocks"], k_arena, v_arena, k_scale, v_scale))
-    else:
-        h, (k_arena, v_arena) = lax.scan(
-            body, h, (params["blocks"], k_arena, v_arena))
-    h = model._layer_norm(params["ln_f"], h)
-    logits = _head_logits(model, params, h)      # (S, W, V)
-    logits = logits.astype(jnp.float32)
-    if quantized:
-        return logits, k_arena, v_arena, k_scale, v_scale
-    return logits, k_arena, v_arena
+    return _verify_rows(model, params, tokens, n_cand, tables,
+                        _arenas(k_arena, v_arena, k_scale, v_scale),
+                        store=store, rope=rope, mask=mask[:, None])
 
 
 def _tree_commit_paged(src, pos, tables, k_arena, v_arena,
-                       k_scale=None, v_scale=None):
+                       k_scale=None, v_scale=None, *, n_heads=None):
     """Pointer-rewind's tree counterpart: after the host walk accepts a
     path, copy each accepted node's k/v row from its STORE offset
     ``pos + src[s, d-1]`` down to its POSITION offset ``pos + d`` so the
@@ -626,10 +541,12 @@ def _tree_commit_paged(src, pos, tables, k_arena, v_arena,
     some slot accepted an ALTERNATE need to run this at all — the engine
     skips the call otherwise.  Gathers complete before scatters
     (functional update), so an identity row can never read a
-    half-written block."""
+    half-written block.  Rows move whole, all layers at once: a data
+    row as the one "head" its lanes are; a quantized pool's scale rows
+    need ``n_heads``."""
     s, dmax = src.shape
     m = tables.shape[1]
-    B = k_arena.shape[3]
+    B = k_arena.shape[2]
     rowsel = jnp.arange(s)[:, None]
     src_abs = pos[:, None] + src
     dst_abs = pos[:, None] + 1 + jnp.arange(dmax)[None, :]
@@ -639,32 +556,19 @@ def _tree_commit_paged(src, pos, tables, k_arena, v_arena,
     soff = src_abs % B
     dblk = tables[rowsel, jnp.minimum(dst_abs // B, m - 1)]
     doff = dst_abs % B
+    every = slice(None)
 
-    quantized = k_scale is not None
+    def move(arena, block):
+        # each source row's whole block: (L, S, Dmax, B, ..)
+        g = read_chain(arena, every, sblk[..., None], block)
+        rows = g[:, rowsel, jnp.arange(dmax)[None, :], soff]
+        return write_rows(arena, every, dblk, doff, rows)
 
-    def body(carry, layer):
-        if quantized:
-            kc, vc, ks, vs = layer
-        else:
-            kc, vc = layer
-        kr = kc[sblk, :, soff, :]                 # (S, Dmax, H, D)
-        vr = vc[sblk, :, soff, :]
-        kc = kc.at[dblk, :, doff, :].set(kr)
-        vc = vc.at[dblk, :, doff, :].set(vr)
-        if quantized:
-            ksr = ks[sblk, :, soff]
-            vsr = vs[sblk, :, soff]
-            ks = ks.at[dblk, :, doff].set(ksr)
-            vs = vs.at[dblk, :, doff].set(vsr)
-            return carry, (kc, vc, ks, vs)
-        return carry, (kc, vc)
-
-    if quantized:
-        _, (k_arena, v_arena, k_scale, v_scale) = lax.scan(
-            body, 0, (k_arena, v_arena, k_scale, v_scale))
-        return k_arena, v_arena, k_scale, v_scale
-    _, (k_arena, v_arena) = lax.scan(body, 0, (k_arena, v_arena))
-    return k_arena, v_arena
+    out = (move(k_arena, (B, 1, k_arena.shape[3])),
+           move(v_arena, (B, 1, v_arena.shape[3])))
+    if k_scale is not None:
+        out += (move(k_scale, (B, n_heads)), move(v_scale, (B, n_heads)))
+    return out
 
 
 def _decode_step(model, params, token, pos, k_cache, v_cache):

@@ -479,9 +479,10 @@ def autotune_paged_decode(*, slots: int = 8, heads: int = 8,
 
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(ks[0], (slots, heads, head_dim), jnp.dtype(dtype))
-    ka = jax.random.normal(ks[1], (num_blocks, heads, block_len, head_dim),
-                           jnp.dtype(dtype))
-    va = jax.random.normal(ks[2], ka.shape, jnp.dtype(dtype))
+    from bigdl_tpu.serving.kvcache.blocks import pack_rows
+    rows = (num_blocks, block_len, heads, head_dim)
+    ka = pack_rows(jax.random.normal(ks[1], rows, jnp.dtype(dtype)))
+    va = pack_rows(jax.random.normal(ks[2], rows, jnp.dtype(dtype)))
     tables = jnp.arange(1, slots * width + 1, dtype=jnp.int32).reshape(
         slots, width)
     pos = jnp.full((slots,), cache_len - 1, jnp.int32)

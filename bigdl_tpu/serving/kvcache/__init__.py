@@ -1,7 +1,8 @@
 """Paged KV cache for LM serving: block arena + radix prefix sharing.
 
-- :class:`BlockPool` — one fixed-shape HBM k/v arena of
-  ``(L, num_blocks, H, block_len, D)`` blocks, host-side free list,
+- :class:`BlockPool` — one fixed-shape HBM k/v arena of contiguous,
+  lane-padded blocks (the layout is stated once, in
+  :mod:`~bigdl_tpu.serving.kvcache.blocks`), host-side free list,
   refcounted so block chains are shared copy-free.
 - :class:`RadixCache` — token-prefix trie over block chains with LRU
   eviction of unreferenced tails; admission reuses the longest cached

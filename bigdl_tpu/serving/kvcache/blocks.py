@@ -7,9 +7,30 @@ stored once per request.  ``BlockPool`` is the vLLM-style alternative
 (PagedAttention, SOSP'23), TPU-native: KV memory is ONE device array of
 fixed-size blocks
 
-    k, v : (L, num_blocks, H, block_len, D)
+    k, v : (L, num_blocks, block_len, W),   W = H * D rounded up to 128
 
 and a *sequence* is a host-side list of block ids (its block table).
+**This is the one place the arena layout is written down.**  A block is
+``block_len`` position rows, a row is the position's H heads of D values
+side by side, zero-padded to whole 128-lane tiles (1600 -> 1664 at GPT-2
+XL; nothing where ``H * D`` already is a multiple of 128), so on the TPU
+a block is contiguous and tile-dense and the compiler keeps the block
+index major: a scatter of rows and a gather of blocks touch those rows
+and blocks and nothing else.  (The previous ``(L, N, H, block_len, D)``
+compiled to a layout with N in the LANES, ``{1,4,3,2,0}``, and every
+program that touched a block first re-laid the whole arena out; PERF.md,
+PR 25.)  Every program reaches the arenas through :func:`write_rows` and
+:func:`read_chain` below and indexes them by layer itself — the arenas
+ride the layer loop's carry whole, never sliced and re-stacked — so
+nothing else knows the layout.  The int8 pool's per-(position, head)
+f32 scale arenas go through the same two functions; theirs is ``(L,
+num_blocks, C)``, a block's ``block_len * H`` scales (position-major) in
+ONE row padded to whole tiles (400 -> 512 at GPT-2 XL): the same
+compile-only reading as the data arenas (``(.., block_len, H)`` and
+``(.., H, block_len)`` both compile with N in the lanes and a copy of
+the arena every layer; H padded to 128 lanes stays in place at five
+times the bytes).
+
 The device arrays never change shape — prefill scatters rows into
 blocks, decode gathers by a padded int32 block-table operand — so the
 AOT executables of the serving engine survive untouched and donation
@@ -39,8 +60,10 @@ Exhaustion is two distinct conditions with two distinct types:
 
 Migration (disaggregated prefill/decode serving): a finished prefill's
 block chain moves between pools as a **block-major wire payload**
-``(n, L, H, block_len, D)`` — ``export_chain`` gathers it to the host
-in bounded slices, ``adopt_chain`` allocates destination blocks
+``(n, L, H, block_len, D)`` (the host tier's and the peers' format,
+independent of the arena layout: the conversion runs on the blocks that
+move) — ``export_chain`` gathers it to the host in bounded slices,
+``adopt_chain`` allocates destination blocks
 all-or-nothing and scatters the payload back in over
 :func:`~bigdl_tpu.utils.transfer.chunked_device_put` (bounded
 32 MB slices: a chain near ``cache_len`` at production geometry is
@@ -54,6 +77,86 @@ import threading
 from typing import List, Optional, Sequence
 
 SCRATCH_BLOCK = 0
+LANES = 128     # the TPU's minor tile: arena rows are whole multiples of it
+
+
+def _whole_tiles(n: int) -> int:
+    return -(-n // LANES) * LANES
+
+
+def row_width(n_heads: int, head_dim: int) -> int:
+    """Lanes of one position row: ``H * D`` rounded up to whole tiles."""
+    return _whole_tiles(n_heads * head_dim)
+
+
+def pack_rows(rows, width=None, tail: int = 2):
+    """Position rows in the arena's row layout: the last ``tail`` axes
+    (``(H, D)``) flattened side by side and zero-padded to ``width``
+    lanes (:func:`row_width` of them by default)."""
+    import jax.numpy as jnp
+
+    flat = rows.reshape(rows.shape[:rows.ndim - tail] + (-1,))
+    pad = (width or _whole_tiles(flat.shape[-1])) - flat.shape[-1]
+    if pad:
+        flat = jnp.pad(flat, [(0, 0)] * (flat.ndim - 1) + [(0, pad)])
+    return flat
+
+
+def write_rows(arena, layer, blk, off, rows):
+    """Scatter position rows into an arena, in place under donation:
+    ``rows[i]`` — ``(H, D)`` for a data arena ``(L, N, B, W)``, ``(H,)``
+    for a scale arena ``(L, N, C)`` — lands at position ``off[i]`` of
+    block ``blk[i]`` of ``layer``; ``blk`` and ``off`` share any index
+    shape.  ``off=None`` writes whole blocks (``rows[i]`` then leads with
+    the ``block_len`` axis), and ``layer`` may be ``slice(None)`` with
+    ``rows`` leading with L."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    every = isinstance(layer, slice)
+    rows = rows.astype(arena.dtype)
+    if off is None or arena.ndim == 4:
+        # index axes: [L] + blk's (+ block_len, a data arena's own axis);
+        # what follows them is one row of the arena's last axis
+        lead = every + jnp.ndim(blk) + (off is None and arena.ndim == 4)
+        flat = pack_rows(rows, arena.shape[-1], rows.ndim - lead)
+        if off is None:
+            return arena.at[layer, blk].set(flat)
+        return arena.at[layer, blk, off].set(flat)
+    # a scale arena's row is a whole block: position ``off`` is the
+    # window of H lanes at ``off * H`` of it
+    h = rows.shape[-1]
+    layers = (jnp.arange(arena.shape[0]).reshape((-1,) + (1,) * jnp.ndim(blk))
+              if every else layer)
+    idx = jnp.stack(jnp.broadcast_arrays(layers, blk, off * h), axis=-1)
+    return lax.scatter(
+        arena, idx, rows,
+        lax.ScatterDimensionNumbers(
+            update_window_dims=(rows.ndim - 1,), inserted_window_dims=(0, 1),
+            scatter_dims_to_operand_dims=(0, 1, 2)))
+
+
+def read_chain(arena, layer, tables, block):
+    """Gather block chains out of an arena: ``tables`` (..., M) block ids
+    -> ``(..., M * B, H, D)`` from a data arena ``(L, N, B, W)``,
+    ``(..., M * B, H)`` from a scale arena ``(L, N, C)``; ``block`` is
+    what one block holds, ``(B, H, D)`` or ``(B, H)``.  The gathered axis
+    IS the position, ``p -> (p // B, p % B)``.  ``layer=slice(None)``
+    keeps a leading L."""
+    import numpy as np
+
+    g = arena[layer, tables]                    # (..., M, B, W) | (..., M, C)
+    block = tuple(block)
+    if arena.ndim == 3:
+        g = g[..., :int(np.prod(block))]
+        return g.reshape(g.shape[:-2] + (g.shape[-2] * block[0],) + block[1:])
+    # merge (M, B) into the position axis BEFORE the padding is cut: in
+    # this order the TPU compiler keeps the gathered chain whole and puts
+    # the positions in the lanes for the f32 score math; cut first, it
+    # materialises the cut and an f32 (.., H, D) copy with D in the lanes
+    # (the same decode round read 103 ms, not 33: PERF.md, PR 25)
+    g = g.reshape(g.shape[:-3] + (g.shape[-3] * g.shape[-2], g.shape[-1]))
+    return g[..., :int(np.prod(block[1:]))].reshape(g.shape[:-1] + block[1:])
 
 
 class PoolExhausted(RuntimeError):
@@ -79,7 +182,7 @@ class BlockPool:
             params' embed dtype).
         kv_quant: ``None`` (full-precision arenas) or ``"int8"`` —
             int8 block arenas plus per-(position, head) f32 scale
-            arenas ``self.ks`` / ``self.vs`` shaped (L, N, H, B).  The
+            arenas ``self.ks`` / ``self.vs`` (module docstring).  The
             paged gather dequantizes in-flight (see
             ``generate._decode_step_paged``); storage drops ~4x minus
             the 1/D scale overhead.  Lossy: streams are NOT bit-exact
@@ -110,15 +213,24 @@ class BlockPool:
                 f"kv_quant must be None or 'int8', got {kv_quant!r}")
         self.block_len = int(block_len)
         self.num_blocks = int(num_blocks)
-        self.shape = (int(n_layers), self.num_blocks, int(n_heads),
-                      self.block_len, int(head_dim))
+        self.n_layers, self.n_heads, self.head_dim = (
+            int(n_layers), int(n_heads), int(head_dim))
+        #: the arenas' shape (module docstring): the lane padding follows
+        #: from the geometry the pool is built with, nothing selects it
+        self.shape = (self.n_layers, self.num_blocks, self.block_len,
+                      row_width(self.n_heads, self.head_dim))
+        self.scale_shape = self.shape[:2] + (
+            _whole_tiles(self.block_len * self.n_heads),)
+        #: one block on the wire / in the host tier: (L, H, block_len, D)
+        self.wire_shape = (self.n_layers, self.n_heads, self.block_len,
+                           self.head_dim)
         self.kv_quant = kv_quant
         if kv_quant == "int8":
             self.k = jnp.zeros(self.shape, jnp.int8)
             self.v = jnp.zeros(self.shape, jnp.int8)
             # per-(position, head) scales, block-major like the arenas
-            self.ks = jnp.zeros(self.shape[:4], jnp.float32)
-            self.vs = jnp.zeros(self.shape[:4], jnp.float32)
+            self.ks = jnp.zeros(self.scale_shape, jnp.float32)
+            self.vs = jnp.zeros(self.scale_shape, jnp.float32)
         else:
             dt = dtype if dtype is not None else jnp.float32
             self.k = jnp.zeros(self.shape, dt)
@@ -130,6 +242,20 @@ class BlockPool:
         self._free: List[int] = list(range(self.num_blocks - 1, 0, -1))
         self._ref = [0] * self.num_blocks
         self._adopt_jits: dict = {}  # padded wire width -> donated scatter
+
+    @property
+    def arenas(self) -> tuple:
+        """``(k, v)`` or, quantized, ``(k, v, ks, vs)``: what the donated
+        executables take and hand back (assign their outputs here)."""
+        return ((self.k, self.v) if self.ks is None
+                else (self.k, self.v, self.ks, self.vs))
+
+    @arenas.setter
+    def arenas(self, new) -> None:
+        if self.ks is None:
+            self.k, self.v = new
+        else:
+            self.k, self.v, self.ks, self.vs = new
 
     # -- capacity -------------------------------------------------------- #
     @property
@@ -215,10 +341,10 @@ class BlockPool:
     # -- migration (disaggregated prefill/decode) ------------------------ #
     @property
     def block_bytes(self) -> int:
-        """Bytes of one block's k (== v) rows across all layers — the
-        wire unit both chunkers slice on."""
-        L, _, H, B, D = self.shape
-        return L * H * B * D * self.dtype.itemsize
+        """Bytes of one block's k (== v) rows across all layers ON THE
+        WIRE (no lane padding) — the unit both chunkers slice on."""
+        import numpy as np
+        return int(np.prod(self.wire_shape)) * self.dtype.itemsize
 
     @property
     def scale_block_bytes(self) -> int:
@@ -226,8 +352,8 @@ class BlockPool:
         rows; 0 for full-precision pools."""
         if self.ks is None:
             return 0
-        L, _, H, B, _ = self.shape
-        return L * H * B * self.ks.dtype.itemsize
+        return (self.n_layers * self.n_heads * self.block_len
+                * self.ks.dtype.itemsize)
 
     @property
     def wire_block_bytes(self) -> int:
@@ -261,7 +387,7 @@ class BlockPool:
         from bigdl_tpu.utils.transfer import DEFAULT_CHUNK_BYTES
         cb = int(chunk_bytes) if chunk_bytes else DEFAULT_CHUNK_BYTES
         n = len(blocks)
-        L, _, H, B, D = self.shape
+        L, H, B, D = self.wire_shape
         quant = self.kv_quant is not None
         host_k = np.empty((n, L, H, B, D), self.dtype)
         host_v = np.empty((n, L, H, B, D), self.dtype)
@@ -269,12 +395,17 @@ class BlockPool:
         host_vs = np.empty((n, L, H, B), np.float32) if quant else None
         if n:
             idx = jnp.asarray(list(blocks), jnp.int32)
-            # device-side gather + transpose to block-major wire layout
-            kc = jnp.moveaxis(self.k[:, idx], 0, 1)
-            vc = jnp.moveaxis(self.v[:, idx], 0, 1)
+
+            def wire(arena, tail):
+                # device-side gather of the n blocks, then (L, n*B, H, ..)
+                # -> the block-major wire layout (n, L, H, B, ..)
+                g = read_chain(arena, slice(None), idx, (B,) + tail)
+                g = g.reshape((L, n, B) + tail)
+                return jnp.moveaxis(jnp.moveaxis(g, 3, 2), 1, 0)
+
+            kc, vc = wire(self.k, (H, D)), wire(self.v, (H, D))
             if quant:
-                ksc = jnp.moveaxis(self.ks[:, idx], 0, 1)
-                vsc = jnp.moveaxis(self.vs[:, idx], 0, 1)
+                ksc, vsc = wire(self.ks, (H,)), wire(self.vs, (H,))
             rows = max(1, cb // max(1, self.wire_block_bytes))
             for i in range(0, n, rows):
                 host_k[i:i + rows] = np.asarray(kc[i:i + rows])
@@ -299,22 +430,18 @@ class BlockPool:
             import jax
             import jax.numpy as jnp
 
-            if self.kv_quant is not None:
-                def _scatter_q(k, v, ks, vs, kw, vw, ksw, vsw, ids):
-                    k = k.at[:, ids].set(jnp.moveaxis(kw, 0, 1))
-                    v = v.at[:, ids].set(jnp.moveaxis(vw, 0, 1))
-                    ks = ks.at[:, ids].set(jnp.moveaxis(ksw, 0, 1))
-                    vs = vs.at[:, ids].set(jnp.moveaxis(vsw, 0, 1))
-                    return k, v, ks, vs
+            def put(arena, wire, ids):
+                # wire (w, L, H, B, ..) -> whole blocks (L, w, B, H, ..)
+                rows = jnp.moveaxis(jnp.moveaxis(wire, 0, 1), 2, 3)
+                return write_rows(arena, slice(None), ids, None, rows)
 
-                exe = jax.jit(_scatter_q, donate_argnums=(0, 1, 2, 3))
-            else:
-                def _scatter(k, v, kw, vw, ids):
-                    k = k.at[:, ids].set(jnp.moveaxis(kw, 0, 1))
-                    v = v.at[:, ids].set(jnp.moveaxis(vw, 0, 1))
-                    return k, v
+            n = len(self.arenas)
 
-                exe = jax.jit(_scatter, donate_argnums=(0, 1))
+            def _scatter(*ops):     # the arenas, their wire legs, the ids
+                return tuple(put(a, w, ops[-1])
+                             for a, w in zip(ops[:n], ops[n:2 * n]))
+
+            exe = jax.jit(_scatter, donate_argnums=tuple(range(n)))
             self._adopt_jits[width] = exe
         return exe
 
@@ -332,24 +459,20 @@ class BlockPool:
             w = int(w)
             if w < 1:
                 continue
-            kw = jnp.zeros((w, self.shape[0]) + self.shape[2:],
-                           self.dtype)
+            kw = jnp.zeros((w,) + self.wire_shape, self.dtype)
             if getattr(self.k, "sharding", None) is not None:
                 import jax
                 kw = jax.device_put(kw, self.k.sharding)
             idx = np.full((w,), SCRATCH_BLOCK, np.int32)
             if self.kv_quant is not None:
-                sw = jnp.zeros((w,) + self.shape[:1] + self.shape[2:4],
-                               jnp.float32)
+                sw = jnp.zeros((w,) + self.wire_shape[:3], jnp.float32)
                 if getattr(self.ks, "sharding", None) is not None:
                     import jax
                     sw = jax.device_put(sw, self.ks.sharding)
-                (self.k, self.v, self.ks,
-                 self.vs) = self._adopt_scatter(w)(
-                    self.k, self.v, self.ks, self.vs, kw, kw, sw, sw, idx)
+                wire = (kw, kw, sw, sw)
             else:
-                self.k, self.v = self._adopt_scatter(w)(
-                    self.k, self.v, kw, kw, idx)
+                wire = (kw, kw)
+            self.arenas = self._adopt_scatter(w)(*self.arenas, *wire, idx)
             n += 1
         return n
 
@@ -401,7 +524,7 @@ class BlockPool:
         if quant and n:
             ks_wire = np.asarray(ks_wire, np.float32)
             vs_wire = np.asarray(vs_wire, np.float32)
-            want = (n,) + self.shape[:1] + self.shape[2:4]
+            want = (n,) + self.wire_shape[:3]
             if ks_wire.shape != want or vs_wire.shape != want:
                 raise ValueError(
                     f"scale wire shapes {ks_wire.shape} / "
@@ -451,14 +574,8 @@ class BlockPool:
                     vsw = jnp.concatenate([vsw, spad], axis=0)
             idx = np.full((width,), SCRATCH_BLOCK, np.int32)
             idx[:n] = ids[:n]
-            if quant:
-                (self.k, self.v, self.ks,
-                 self.vs) = self._adopt_scatter(width)(
-                    self.k, self.v, self.ks, self.vs, kw, vw, ksw, vsw,
-                    idx)
-            else:
-                self.k, self.v = self._adopt_scatter(width)(
-                    self.k, self.v, kw, vw, idx)
+            wire = (kw, vw, ksw, vsw) if quant else (kw, vw)
+            self.arenas = self._adopt_scatter(width)(*self.arenas, *wire, idx)
         except BaseException:
             self.release(ids)
             raise

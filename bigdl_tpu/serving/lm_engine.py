@@ -18,8 +18,9 @@ paged block arena of :mod:`bigdl_tpu.serving.kvcache`:
   largest bucket prefill in block-aligned CHUNKS — over-length requests
   are admitted, not rejected.
 - **insert** — scatter of each chunk's k/v rows into its allocated
-  blocks of the resident (L, num_blocks, H, block_len, D) arenas,
-  donated so insert rewrites the resident buffers in place.
+  blocks of the resident arenas (their layout is the pool's:
+  :mod:`bigdl_tpu.serving.kvcache.blocks`), donated so insert rewrites
+  the resident buffers in place.
 - **decode** — ONE fixed-shape executable stepping all S slots, each at
   its own position, taking a padded int32 **block-table** operand
   (S, M) (padded entries name the scratch block) — paging changes the
@@ -842,11 +843,8 @@ class LMServingEngine:
             # projections, not the cache) and the donated insert/decode
             # executables keep the committed layout
             _rep = placement.replicated()
-            self.pool.k = jax.device_put(self.pool.k, _rep)
-            self.pool.v = jax.device_put(self.pool.v, _rep)
-            if _kvq:
-                self.pool.ks = jax.device_put(self.pool.ks, _rep)
-                self.pool.vs = jax.device_put(self.pool.vs, _rep)
+            self.pool.arenas = [jax.device_put(a, _rep)
+                                for a in self.pool.arenas]
         self.radix = RadixCache(self.pool) if enable_prefix_cache else None
         #: router-published prefix summary (see attach_radix_summary)
         self.radix_summary = None
@@ -885,14 +883,9 @@ class LMServingEngine:
 
         def _prefix_prefill_fn(params, buffers, x):
             del buffers
-            if _kvq:
-                return _constrain(_prefill_suffix_parts(
-                    model, dequantize_entry(params), x["ids"],
-                    x["len"] - 1, x["prefix_len"], x["blocks"],
-                    x["k"], x["v"], x["ks"], x["vs"]))
             return _constrain(_prefill_suffix_parts(
                 model, dequantize_entry(params), x["ids"], x["len"] - 1,
-                x["prefix_len"], x["blocks"], x["k"], x["v"]))
+                x["prefix_len"], x["blocks"], *x["kv"]))
 
         self.prefix_prefill_cache = CompileCache(
             _prefix_prefill_fn, max_entries=max_cache_entries,
@@ -927,21 +920,20 @@ class LMServingEngine:
                 check_paged_kernel_shapes(self.block_len, dt)
         self.decode_attn = decode_attn
 
-        if _kvq:
-            def _decode_fn(params, token, pos, tables, kc, vc, ks, vs):
-                return _constrain(_decode_step_paged(
-                    model, dequantize_entry(params), token, pos, tables,
-                    kc, vc, ks, vs, attn_impl=decode_attn))
+        # every step program takes the pool's arenas last, (k, v) or
+        # (k, v, ks, vs), donated, and hands them back after its result
+        _n_kv = len(self.pool.arenas)
 
-            donate = (4, 5, 6, 7) if donate_cache else ()
-        else:
-            def _decode_fn(params, token, pos, tables, kc, vc):
-                return _constrain(_decode_step_paged(
-                    model, dequantize_entry(params), token, pos, tables,
-                    kc, vc, attn_impl=decode_attn))
+        def _donated(first):
+            return (tuple(range(first, first + _n_kv))
+                    if donate_cache else ())
 
-            donate = (4, 5) if donate_cache else ()
-        self._decode_jit = jax.jit(_decode_fn, donate_argnums=donate)
+        def _decode_fn(params, token, pos, tables, *kv):
+            return _constrain(_decode_step_paged(
+                model, dequantize_entry(params), token, pos, tables,
+                *kv, attn_impl=decode_attn))
+
+        self._decode_jit = jax.jit(_decode_fn, donate_argnums=_donated(4))
         self._decode_exec = None
 
         _insert_donate = ((0, 1, 5, 6) if _kvq else (0, 1))
@@ -1009,25 +1001,13 @@ class LMServingEngine:
             self.spec_metrics.overflow_risk = float(
                 _drep.get("overflow_risk") or 0.0)
 
-            if _kvq:
-                def _verify_fn(params, tokens, pos, n_cand, tables, kc,
-                               vc, ks, vs):
-                    return _constrain(_verify_step_paged(
-                        model, dequantize_entry(params), tokens, pos,
-                        n_cand, tables, kc, vc, ks, vs))
+            def _verify_fn(params, tokens, pos, n_cand, tables, *kv):
+                return _constrain(_verify_step_paged(
+                    model, dequantize_entry(params), tokens, pos,
+                    n_cand, tables, *kv))
 
-                _vdonate = (5, 6, 7, 8)
-            else:
-                def _verify_fn(params, tokens, pos, n_cand, tables, kc,
-                               vc):
-                    return _constrain(_verify_step_paged(
-                        model, dequantize_entry(params), tokens, pos,
-                        n_cand, tables, kc, vc))
-
-                _vdonate = (5, 6)
-            self._verify_jit = jax.jit(
-                _verify_fn,
-                donate_argnums=_vdonate if donate_cache else ())
+            self._verify_jit = jax.jit(_verify_fn,
+                                       donate_argnums=_donated(5))
 
             if spec.tree:
                 # one donated verify executable per ladder rung: the
@@ -1040,23 +1020,14 @@ class LMServingEngine:
                 def _mk_tree_verify(shp):
                     _depths = np.asarray(shp.depths, np.int32)
                     _anc = np.ascontiguousarray(shp.anc)
-                    if _kvq:
-                        def _fn(params, tokens, pos, n_cand, tables, kc,
-                                vc, ks, vs):
-                            return _constrain(_tree_verify_step_paged(
-                                model, dequantize_entry(params), tokens,
-                                pos, n_cand, tables, kc, vc, ks, vs,
-                                depths=_depths, anc=_anc))
-                    else:
-                        def _fn(params, tokens, pos, n_cand, tables, kc,
-                                vc):
-                            return _constrain(_tree_verify_step_paged(
-                                model, dequantize_entry(params), tokens,
-                                pos, n_cand, tables, kc, vc,
-                                depths=_depths, anc=_anc))
-                    return jax.jit(
-                        _fn,
-                        donate_argnums=_vdonate if donate_cache else ())
+
+                    def _fn(params, tokens, pos, n_cand, tables, *kv):
+                        return _constrain(_tree_verify_step_paged(
+                            model, dequantize_entry(params), tokens,
+                            pos, n_cand, tables, *kv,
+                            depths=_depths, anc=_anc))
+
+                    return jax.jit(_fn, donate_argnums=_donated(5))
 
                 self._verify_tree_jits = [
                     _mk_tree_verify(s) for s in self._tree_shapes]
@@ -1066,21 +1037,12 @@ class LMServingEngine:
                 self._commit_dmax = max(
                     (s.max_depth for s in self._tree_shapes
                      if not s.is_chain), default=0)
-                if _kvq:
-                    def _commit_fn(src, pos, tables, kc, vc, ks, vs):
-                        return _constrain(_tree_commit_paged(
-                            src, pos, tables, kc, vc, ks, vs))
+                def _commit_fn(src, pos, tables, *kv):
+                    return _constrain(_tree_commit_paged(
+                        src, pos, tables, *kv, n_heads=H))
 
-                    _cdonate = (3, 4, 5, 6)
-                else:
-                    def _commit_fn(src, pos, tables, kc, vc):
-                        return _constrain(_tree_commit_paged(
-                            src, pos, tables, kc, vc))
-
-                    _cdonate = (3, 4)
-                self._commit_jit = jax.jit(
-                    _commit_fn,
-                    donate_argnums=_cdonate if donate_cache else ())
+                self._commit_jit = jax.jit(_commit_fn,
+                                           donate_argnums=_donated(3))
                 self._commit_exec = None
                 self._commit_compiles = 0
 
@@ -1120,7 +1082,7 @@ class LMServingEngine:
 
                 self._ledger_keys.append(led.register(
                     "kvcache", f"{name}/scale_arena", _scale_bytes,
-                    shape=self.pool.shape[:4], dtype="float32",
+                    shape=self.pool.scale_shape, dtype="float32",
                     device=_dev))
             self._ledger_keys.append(led.register(
                 "params", f"{name}/staged",
@@ -1334,9 +1296,7 @@ class LMServingEngine:
                      "len": _np.int32(b),
                      "prefix_len": _np.int32(pb * self.block_len),
                      "blocks": _np.zeros((pb,), _np.int32),
-                     "k": self.pool.k, "v": self.pool.v}
-                if self.kv_quant is not None:
-                    x["ks"], x["vs"] = self.pool.ks, self.pool.vs
+                     "kv": self.pool.arenas}
                 inputs.append(x)
         return self.prefix_prefill_cache.warmup_inputs(
             self._params, self._buffers, inputs)
@@ -1354,11 +1314,8 @@ class LMServingEngine:
             tok = sds((self.slots,), np.int32, **sh)
             pos = sds((self.slots,), np.int32, **sh)
             tables = sds((self.slots, self.table_width), np.int32, **sh)
-            args = [self._params, tok, pos, tables,
-                    self.pool.k, self.pool.v]
-            if self.kv_quant is not None:
-                args += [self.pool.ks, self.pool.vs]
-            self._decode_exec = self._decode_jit.lower(*args).compile()
+            self._decode_exec = self._decode_jit.lower(
+                self._params, tok, pos, tables, *self.pool.arenas).compile()
             self._ledger_exec("decode", f"slots={self.slots}",
                               self._decode_exec)
         return self._decode_exec
@@ -1377,11 +1334,9 @@ class LMServingEngine:
             pos = sds((self.slots,), np.int32, **sh)
             ncand = sds((self.slots,), np.int32, **sh)
             tables = sds((self.slots, self.table_width), np.int32, **sh)
-            args = [self._params, tok, pos, ncand, tables,
-                    self.pool.k, self.pool.v]
-            if self.kv_quant is not None:
-                args += [self.pool.ks, self.pool.vs]
-            self._verify_exec = self._verify_jit.lower(*args).compile()
+            self._verify_exec = self._verify_jit.lower(
+                self._params, tok, pos, ncand, tables,
+                *self.pool.arenas).compile()
             self._verify_compiles += 1
             self._ledger_exec("verify", f"slots={self.slots}",
                               self._verify_exec)
@@ -1404,11 +1359,9 @@ class LMServingEngine:
             pos = sds((self.slots,), np.int32, **sh)
             ncand = sds((self.slots,), np.int32, **sh)
             tables = sds((self.slots, self.table_width), np.int32, **sh)
-            args = [self._params, tok, pos, ncand, tables,
-                    self.pool.k, self.pool.v]
-            if self.kv_quant is not None:
-                args += [self.pool.ks, self.pool.vs]
-            exe = self._verify_tree_jits[rung].lower(*args).compile()
+            exe = self._verify_tree_jits[rung].lower(
+                self._params, tok, pos, ncand, tables,
+                *self.pool.arenas).compile()
             self._verify_tree_execs[rung] = exe
             self._verify_compiles += 1
             self._ledger_exec(
@@ -1429,10 +1382,8 @@ class LMServingEngine:
             src = sds((self.slots, self._commit_dmax), np.int32, **sh)
             pos = sds((self.slots,), np.int32, **sh)
             tables = sds((self.slots, self.table_width), np.int32, **sh)
-            args = [src, pos, tables, self.pool.k, self.pool.v]
-            if self.kv_quant is not None:
-                args += [self.pool.ks, self.pool.vs]
-            self._commit_exec = self._commit_jit.lower(*args).compile()
+            self._commit_exec = self._commit_jit.lower(
+                src, pos, tables, *self.pool.arenas).compile()
             self._commit_compiles += 1
             self._ledger_exec(
                 "verify", f"slots={self.slots}/tree_commit",
@@ -1443,7 +1394,7 @@ class LMServingEngine:
         exe = self._insert_execs.get(bucket)
         if exe is None:
             import jax
-            L, N, H, B, D = self.pool.shape
+            L, H, B, D = self.pool.wire_shape
             nb = -(-bucket // B)
             sds = jax.ShapeDtypeStruct
             sh = (dict(sharding=self.placement.replicated())
@@ -1451,13 +1402,10 @@ class LMServingEngine:
             # fresh chunk rows arrive in the model's compute dtype even
             # when the pool stores int8 (_insert_blocks quantizes them)
             new = sds((L, 1, H, bucket, D), self._cache_dtype, **sh)
-            args = [sds(self.pool.shape, self.pool.dtype, **sh),
-                    sds(self.pool.shape, self.pool.dtype, **sh),
-                    new, new, sds((nb,), np.int32, **sh)]
-            if self.kv_quant is not None:
-                scale = sds(self.pool.shape[:4], np.float32, **sh)
-                args += [scale, scale]
-            exe = self._insert_jit.lower(*args).compile()
+            kv = self.pool.arenas
+            exe = self._insert_jit.lower(
+                *kv[:2], new, new, sds((nb,), np.int32, **sh),
+                *kv[2:]).compile()
             self._insert_execs[bucket] = exe
             self._ledger_exec("insert", f"bucket={bucket}", exe)
         return exe
@@ -2312,8 +2260,7 @@ class LMServingEngine:
         if not payloads:
             return matched
         quant = self.kv_quant is not None
-        L, _, H, Bl, D = self.pool.shape
-        if (payloads[0]["k"].shape[1:] != (L, H, Bl, D)
+        if (payloads[0]["k"].shape[1:] != self.pool.wire_shape
                 or (quant and "ks" not in payloads[0])):
             # stale entries from a different geometry/precision under
             # the same store name: not promotable into this pool
@@ -2653,22 +2600,15 @@ class LMServingEngine:
             pblocks[:nbp] = blocks[:nbp]
             x = {"ids": ids, "len": np.int32(ts),
                  "prefix_len": np.int32(p), "blocks": pblocks,
-                 "k": self.pool.k, "v": self.pool.v}
-            if self.kv_quant is not None:
-                x["ks"], x["vs"] = self.pool.ks, self.pool.vs
+                 "kv": self.pool.arenas}
             logits, k, v = self.prefix_prefill_cache(
                 self._params, self._buffers, x)
         self._stamp(P_INSERT)
         if _tracer.enabled:
             self._ph_args = {"slot": pf.slot, "bucket": bucket}
-        if self.kv_quant is not None:
-            (self.pool.k, self.pool.v, self.pool.ks,
-             self.pool.vs) = self._insert_compiled(bucket)(
-                self.pool.k, self.pool.v, k, v, ids_w,
-                self.pool.ks, self.pool.vs)
-        else:
-            self.pool.k, self.pool.v = self._insert_compiled(bucket)(
-                self.pool.k, self.pool.v, k, v, ids_w)
+        kv = self.pool.arenas
+        self.pool.arenas = self._insert_compiled(bucket)(
+            *kv[:2], k, v, ids_w, *kv[2:])
         self._stamp(P_ADMIT_HOST)
         self._prefill_since_step = True
         pf.logits = logits
@@ -2765,15 +2705,8 @@ class LMServingEngine:
         if not active:
             return
         self._rd_active = len(active)
-        if self.kv_quant is not None:
-            (logits, self.pool.k, self.pool.v, self.pool.ks,
-             self.pool.vs) = self._decode_compiled()(
-                self._params, token, pos, tables, self.pool.k,
-                self.pool.v, self.pool.ks, self.pool.vs)
-        else:
-            logits, self.pool.k, self.pool.v = self._decode_compiled()(
-                self._params, token, pos, tables, self.pool.k,
-                self.pool.v)
+        logits, *self.pool.arenas = self._decode_compiled()(
+            self._params, token, pos, tables, *self.pool.arenas)
         self._stamp(P_WAIT)
         logits = np.asarray(logits)  # sync; (S, V) f32
         now = self._stamp(P_EMIT)
@@ -2925,15 +2858,8 @@ class LMServingEngine:
         if not active:
             return
         self._rd_active = len(active)
-        if self.kv_quant is not None:
-            (logits, self.pool.k, self.pool.v, self.pool.ks,
-             self.pool.vs) = self._verify_compiled()(
-                self._params, tokens, pos, ncand, tables,
-                self.pool.k, self.pool.v, self.pool.ks, self.pool.vs)
-        else:
-            logits, self.pool.k, self.pool.v = self._verify_compiled()(
-                self._params, tokens, pos, ncand, tables,
-                self.pool.k, self.pool.v)
+        logits, *self.pool.arenas = self._verify_compiled()(
+            self._params, tokens, pos, ncand, tables, *self.pool.arenas)
         self._stamp(P_WAIT)
         logits = np.asarray(logits)  # sync; (S, W, V) f32
         now = self._stamp(P_EMIT)
@@ -3160,16 +3086,8 @@ class LMServingEngine:
         if not active:
             return
         self._rd_active = len(active)
-        if self.kv_quant is not None:
-            (logits, self.pool.k, self.pool.v, self.pool.ks,
-             self.pool.vs) = self._verify_tree_compiled(round_rung)(
-                self._params, tokens, pos, ncand, tables,
-                self.pool.k, self.pool.v, self.pool.ks, self.pool.vs)
-        else:
-            (logits, self.pool.k,
-             self.pool.v) = self._verify_tree_compiled(round_rung)(
-                self._params, tokens, pos, ncand, tables,
-                self.pool.k, self.pool.v)
+        logits, *self.pool.arenas = self._verify_tree_compiled(round_rung)(
+            self._params, tokens, pos, ncand, tables, *self.pool.arenas)
         self._stamp(P_WAIT)
         logits = np.asarray(logits)  # sync; (S, W, V) f32
         now = self._stamp(P_EMIT)
@@ -3283,16 +3201,8 @@ class LMServingEngine:
                     self.draft.push(i, emitted[0])
         if commit_src is not None:
             self._stamp(P_TREE_COMMIT)
-            if self.kv_quant is not None:
-                (self.pool.k, self.pool.v, self.pool.ks,
-                 self.pool.vs) = self._commit_compiled()(
-                    commit_src, pos, tables,
-                    self.pool.k, self.pool.v,
-                    self.pool.ks, self.pool.vs)
-            else:
-                self.pool.k, self.pool.v = self._commit_compiled()(
-                    commit_src, pos, tables,
-                    self.pool.k, self.pool.v)
+            self.pool.arenas = self._commit_compiled()(
+                commit_src, pos, tables, *self.pool.arenas)
             self._stamp(P_EMIT)
         self.spec_metrics.record_verify_round(
             bool(jobs), n_emitted, self.draft.steps - steps_before)

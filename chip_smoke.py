@@ -54,6 +54,7 @@ from bigdl_tpu.ops.flash_attention import (_xla_fallback, flash_attention,
                                            use_flash_auto)
 from bigdl_tpu.ops.paged_attention import (paged_decode_attention,
                                            paged_decode_attention_reference)
+from bigdl_tpu.serving.kvcache.blocks import pack_rows
 from bigdl_tpu.optim import SGD, Optimizer, Trigger
 from bigdl_tpu.serving import LMServingEngine
 from bigdl_tpu.utils.engine import configure_compile_cache
@@ -488,8 +489,11 @@ def _paged_case(case: tuple, seed: int) -> dict:
     n = s * m + 1
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
     q = jax.random.normal(ks[0], (s, h, 1, d), jnp.float32).astype(dt)
-    ka = jax.random.normal(ks[1], (n, h, blk, d), jnp.float32).astype(dt)
-    va = jax.random.normal(ks[2], (n, h, blk, d), jnp.float32).astype(dt)
+    # one layer's arena in the pool's layout (serving.kvcache.blocks)
+    ka = pack_rows(jax.random.normal(ks[1], (n, blk, h, d),
+                                     jnp.float32).astype(dt))
+    va = pack_rows(jax.random.normal(ks[2], (n, blk, h, d),
+                                     jnp.float32).astype(dt))
     rs = np.random.RandomState(seed)
     tables = jnp.asarray(
         1 + rs.permutation(s * m).reshape(s, m).astype(np.int32))

@@ -16,6 +16,9 @@ of a benchmark cell.  Not part of the benchmark: it calls
                         lm/decode_dispatch .. lm/decode_wait annotations of
                         the same round, on the profiler's one clock), and how
                         much of the window the worker's leaf spans cover
+    ... --blocks <n>    the pool probe: the cell with a KV pool of n blocks
+                        laid over its configuration (does a round grow with
+                        the pool?)
     ... --stamp-cost    no cell: times the stamps of one plain round on an
                         idle toy engine, tracer off and on
 
@@ -236,6 +239,7 @@ def main(argv) -> int:
     ap.add_argument("--seeds", type=int, nargs="*", default=[])
     ap.add_argument("--trace", action="store_true")
     ap.add_argument("--tracer-on", action="store_true")
+    ap.add_argument("--blocks", type=int)
     ap.add_argument("--stamp-cost", action="store_true")
     ap.add_argument("--cpu", action="store_true",
                     help="a rehearsal: do not insist on an accelerator")
@@ -268,9 +272,11 @@ def main(argv) -> int:
             tracer.enable()
         stalls_before = _stalls()
         t0 = time.perf_counter()
-        line = run.run_cell(a.root, a.cell, seed, a.seconds, a.trace,
-                            require_accelerator=not a.cpu)
-        row = {"tag": a.tag, "cell": a.cell, "seed": seed,
+        line = run.run_cell(
+            a.root, a.cell, seed, a.seconds, a.trace,
+            require_accelerator=not a.cpu,
+            config_update=(a.blocks and {"engine": {"num_blocks": a.blocks}}))
+        row = {"tag": a.tag, "cell": a.cell, "seed": seed, "blocks": a.blocks,
                "trace": int(a.trace), "tracer_on": a.tracer_on,
                "run_s": time.perf_counter() - t0, "line": line,
                "rounds": seen.get("rounds")}

@@ -70,13 +70,17 @@ def _fill(pool, ids, seed=0):
     """Write distinct recognisable rows into ``ids`` and return the
     host copies."""
     import jax.numpy as jnp
+    from bigdl_tpu.serving.kvcache.blocks import write_rows
     rng = np.random.default_rng(seed)
-    L, _, H, B, D = pool.shape
+    L, H, B, D = pool.wire_shape
     k = rng.standard_normal((L, len(ids), H, B, D)).astype(pool.dtype)
     v = rng.standard_normal((L, len(ids), H, B, D)).astype(pool.dtype)
     idx = jnp.asarray(ids, jnp.int32)
-    pool.k = pool.k.at[:, idx].set(k)
-    pool.v = pool.v.at[:, idx].set(v)
+    # whole blocks through the pool's own writer: (L, n, B, H, D) rows
+    pool.k = write_rows(pool.k, slice(None), idx, None,
+                        jnp.asarray(k).swapaxes(2, 3))
+    pool.v = write_rows(pool.v, slice(None), idx, None,
+                        jnp.asarray(v).swapaxes(2, 3))
     return k, v
 
 
@@ -88,7 +92,7 @@ def test_export_adopt_roundtrip_exact_and_refcounts():
     k, v = _fill(src, ids)
     wire = src.export_chain(ids)
     assert wire["blocks"] == 3
-    assert wire["k"].shape == (3,) + (src.shape[0],) + src.shape[2:]
+    assert wire["k"].shape == (3,) + src.wire_shape == (3, 2, 2, 4, 3)
     np.testing.assert_array_equal(wire["k"],
                                   np.moveaxis(k, 0, 1))
     assert all(src.refcount(b) == 1 for b in ids)  # export never refs
@@ -158,8 +162,7 @@ def test_adopt_empty_wire_reserves_tail_only():
     """A fully radix-matched migration wires zero blocks but still
     atomically reserves the generation tail."""
     dst = _pool()
-    L, _, H, B, D = dst.shape
-    empty = np.zeros((0, L, H, B, D), dst.dtype)
+    empty = np.zeros((0,) + dst.wire_shape, dst.dtype)
     ids = dst.adopt_chain(empty, empty, extra_blocks=2)
     assert len(ids) == 2 and all(dst.refcount(b) == 1 for b in ids)
 

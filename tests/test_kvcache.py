@@ -89,11 +89,154 @@ def test_block_pool_stats_and_sizing():
     st = pool.stats()
     assert st["used_blocks"] == 3 and st["free_blocks"] == 4
     assert st["utilization"] == pytest.approx(3 / 7)
-    # (L, N, H, B, D) f32 k + v arenas
-    assert st["arena_bytes"] == 2 * (1 * 8 * 1 * 4 * 2) * 4
+    # (L, N, B, W) f32 k + v arenas; one head of 2 values pads to 128 lanes
+    assert pool.shape == (1, 8, 4, 128)
+    assert st["arena_bytes"] == 2 * (1 * 8 * 4 * 128) * 4
     with pytest.raises(ValueError):
         BlockPool(n_layers=1, n_heads=1, head_dim=2, block_len=2,
                   num_blocks=1)  # no room for scratch + data
+
+
+# --------------------------------------------------------------------------- #
+# the arena layout (PR 25): block-contiguous, lane-padded rows                 #
+# --------------------------------------------------------------------------- #
+
+#: (heads, head_dim): H * D = 320 needs lane padding (-> 384), 256 does not
+LAYOUT_GEOMETRIES = [(5, 64), (4, 64)]
+LAYOUT_KINDS = ["bfloat16", "float32", "int8"]
+
+
+def _layout_pool(heads, head_dim, kind, layers=2, block_len=4, num_blocks=12):
+    import jax.numpy as jnp
+    return BlockPool(n_layers=layers, n_heads=heads, head_dim=head_dim,
+                     block_len=block_len, num_blocks=num_blocks,
+                     dtype=None if kind == "int8" else jnp.dtype(kind),
+                     kv_quant="int8" if kind == "int8" else None)
+
+
+def _arenas(pool):
+    return ((pool.k, pool.v) if pool.kv_quant is None
+            else (pool.k, pool.v, pool.ks, pool.vs))
+
+
+@pytest.mark.parametrize("kind", LAYOUT_KINDS)
+@pytest.mark.parametrize("geom", LAYOUT_GEOMETRIES, ids=["hd320", "hd256"])
+def test_layout_shape_follows_geometry(geom, kind):
+    """The lane padding comes from the shape the pool is built with: 320
+    -> 384 lanes, 256 stays 256; the scale arena holds a block's B * H
+    scales in one padded row; the wire shape never pads."""
+    h, d = geom
+    pool = _layout_pool(h, d, kind)
+    assert pool.shape == (2, 12, 4, 384 if h * d == 320 else 256)
+    assert pool.k.shape == pool.v.shape == pool.shape
+    assert pool.wire_shape == (2, h, 4, d)
+    assert pool.block_bytes == 2 * h * 4 * d * pool.dtype.itemsize
+    if kind == "int8":
+        assert pool.ks.shape == pool.vs.shape == pool.scale_shape == (2, 12, 128)
+    else:
+        assert pool.ks is None and pool.scale_arena_bytes == 0
+
+
+@pytest.mark.parametrize("kind", LAYOUT_KINDS)
+@pytest.mark.parametrize("geom", LAYOUT_GEOMETRIES, ids=["hd320", "hd256"])
+def test_layout_insert_then_read_chain_bit_exact(geom, kind):
+    """``_insert_blocks`` then ``read_chain`` gives back the chunk's rows
+    bit for bit (a quantized pool: its own int8 rows and scales), the
+    padding lanes stay zero and no other block is touched."""
+    import jax
+    import jax.numpy as jnp
+    from bigdl_tpu.models.transformer.generate import (_insert_blocks,
+                                                       _kv_quantize_rows)
+    from bigdl_tpu.serving.kvcache.blocks import read_chain
+    h, d = geom
+    pool = _layout_pool(h, d, kind)
+    L, _, B, W = pool.shape
+    tb, ids = 10, jnp.asarray([7, 2, 9], jnp.int32)   # 10 rows: 3 blocks of 4
+    dt = jnp.float32 if kind == "int8" else pool.dtype
+    keys = jax.random.split(jax.random.PRNGKey(h), 2)
+    k_new = jax.random.normal(keys[0], (L, 1, h, tb, d), jnp.float32).astype(dt)
+    v_new = jax.random.normal(keys[1], (L, 1, h, tb, d), jnp.float32).astype(dt)
+    out = _insert_blocks(pool.k, pool.v, k_new, v_new, ids, pool.ks, pool.vs)
+    block = (B, h, d)
+    for arena, new, scale in ((out[0], k_new, out[2:] and out[2]),
+                              (out[1], v_new, out[2:] and out[3])):
+        got = read_chain(arena, slice(None), ids, block)[:, :tb]  # (L, tb, H, D)
+        want = new[:, 0].transpose(0, 2, 1, 3)
+        if kind == "int8":
+            want, want_s = _kv_quantize_rows(want)
+            got_s = read_chain(scale, slice(None), ids, block[:2])[:, :tb]
+            np.testing.assert_array_equal(np.asarray(got_s), np.asarray(want_s))
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        a = np.asarray(arena)
+        assert not a[..., h * d:].any()                 # padding lanes
+        untouched = [b for b in range(pool.num_blocks) if b not in (7, 2, 9)]
+        assert not a[:, untouched].any()
+
+
+@pytest.mark.parametrize("kind", LAYOUT_KINDS)
+@pytest.mark.parametrize("geom", LAYOUT_GEOMETRIES, ids=["hd320", "hd256"])
+def test_layout_write_rows_lands_at_block_and_offset(geom, kind):
+    """``write_rows`` at (layer, block, offset), the decode step's write:
+    the row reads back at position ``blk_index * B + offset`` of the chain
+    and its neighbours stay as they were."""
+    import jax.numpy as jnp
+    from bigdl_tpu.serving.kvcache.blocks import read_chain, write_rows
+    h, d = geom
+    pool = _layout_pool(h, d, kind)
+    B = pool.block_len
+    blk, off = jnp.asarray([[3], [5]]), jnp.asarray([[1], [3]])    # (S, 1)
+    tables = jnp.asarray([[3, 4], [6, 5]], jnp.int32)
+    rows = (jnp.arange(2 * h * d).reshape(2, 1, h, d) % 100 + 1).astype(pool.dtype)
+    k = write_rows(pool.k, 1, blk, off, rows)
+    got = np.asarray(read_chain(k, 1, tables, (B, h, d)))          # (S, 2B, H, D)
+    want = np.zeros_like(got)
+    want[0, 0 * B + 1], want[1, 1 * B + 3] = rows[0, 0], rows[1, 0]
+    np.testing.assert_array_equal(got, want)
+    assert not np.asarray(k[0]).any()                              # other layer
+    if kind == "int8":
+        srows = jnp.arange(2 * h, dtype=jnp.float32).reshape(2, 1, h) + 0.5
+        ks = write_rows(pool.ks, 1, blk, off, srows)
+        got = np.asarray(read_chain(ks, 1, tables, (B, h)))        # (S, 2B, H)
+        want = np.zeros_like(got)
+        want[0, 1], want[1, B + 3] = srows[0, 0], srows[1, 0]
+        np.testing.assert_array_equal(got, want)
+        assert not np.asarray(ks[0]).any()
+
+
+@pytest.mark.parametrize("kind", LAYOUT_KINDS)
+@pytest.mark.parametrize("geom", LAYOUT_GEOMETRIES, ids=["hd320", "hd256"])
+def test_layout_export_adopt_round_trip_keeps_the_wire(geom, kind):
+    """The wire and the host tier keep the per-block (L, H, B, D) payload:
+    what ``export_chain`` gives is the rows as written, in that shape, and
+    a chain adopted into another pool exports the same bytes."""
+    import jax.numpy as jnp
+    from bigdl_tpu.serving.kvcache.blocks import write_rows
+    h, d = geom
+    src, dst = _layout_pool(h, d, kind), _layout_pool(h, d, kind)
+    L, _, B, _ = src.shape
+    ids = src.alloc(3)
+    idx = jnp.asarray(ids, jnp.int32)
+    rng = np.random.default_rng(h)
+    rows = (rng.integers(-100, 100, (L, 3, B, h, d))).astype(src.dtype)
+    src.k = write_rows(src.k, slice(None), idx, None, jnp.asarray(rows))
+    src.v = write_rows(src.v, slice(None), idx, None, jnp.asarray(-rows))
+    if kind == "int8":
+        srows = rng.random((L, 3, B, h)).astype(np.float32)
+        src.ks = write_rows(src.ks, slice(None), idx, None, jnp.asarray(srows))
+        src.vs = write_rows(src.vs, slice(None), idx, None, jnp.asarray(2 * srows))
+    wire = src.export_chain(ids)
+    assert wire["k"].shape == (3, L, h, B, d) and wire["k"].dtype == src.dtype
+    np.testing.assert_array_equal(wire["k"], rows.transpose(1, 0, 3, 2, 4))
+    np.testing.assert_array_equal(wire["v"], -rows.transpose(1, 0, 3, 2, 4))
+    if kind == "int8":
+        assert wire["ks"].shape == (3, L, h, B)
+        np.testing.assert_array_equal(wire["ks"], srows.transpose(1, 0, 3, 2))
+    dst.alloc(2)                                    # land on other block ids
+    fresh = dst.adopt_chain(wire["k"], wire["v"], wire.get("ks"), wire.get("vs"))
+    assert fresh != ids
+    back = dst.export_chain(fresh)
+    for key in wire:
+        np.testing.assert_array_equal(back[key], wire[key])
 
 
 # --------------------------------------------------------------------------- #

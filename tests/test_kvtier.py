@@ -157,19 +157,30 @@ def test_export_tier_adopt_roundtrip_bit_identical(quant):
     geom = dict(n_layers=2, n_heads=2, head_dim=4, block_len=4,
                 num_blocks=8, kv_quant=quant)
     src, dst = BlockPool(**geom), BlockPool(**geom)
+    from bigdl_tpu.serving.kvcache.blocks import write_rows
     ids = src.alloc(3)
-    shape = (2, 3, 2, 4, 4)
+    shape = (2, 3, 4, 2, 4)                 # whole blocks: (L, n, B, H, D)
     fill = jnp.arange(np.prod(shape)).reshape(shape)
+    idx = jnp.asarray(ids, jnp.int32)
+
+    def put(arena, rows):
+        return write_rows(arena, slice(None), idx, None, rows)
+
     if quant:
-        src.k = src.k.at[:, ids].set((fill % 127).astype(jnp.int8))
-        src.v = src.v.at[:, ids].set((-fill % 127).astype(jnp.int8))
+        src.k = put(src.k, (fill % 127).astype(jnp.int8))
+        src.v = put(src.v, (-fill % 127).astype(jnp.int8))
         sfill = jnp.arange(np.prod(shape[:4]), dtype=jnp.float32)
-        src.ks = src.ks.at[:, ids].set(sfill.reshape(shape[:4]) * 0.25)
-        src.vs = src.vs.at[:, ids].set(sfill.reshape(shape[:4]) * 0.5)
+        src.ks = put(src.ks, sfill.reshape(shape[:4]) * 0.25)
+        src.vs = put(src.vs, sfill.reshape(shape[:4]) * 0.5)
     else:
-        src.k = src.k.at[:, ids].set(fill.astype(jnp.float32))
-        src.v = src.v.at[:, ids].set(-fill.astype(jnp.float32))
+        src.k = put(src.k, fill.astype(jnp.float32))
+        src.v = put(src.v, -fill.astype(jnp.float32))
     wire = src.export_chain(ids)
+    # the wire keeps its per-block (L, H, B, D) payload whatever the arena
+    assert wire["k"].shape == (3, 2, 2, 4, 4)
+    assert np.array_equal(
+        wire["k"], np.asarray(src.k[:, idx, :, :8]).reshape(shape)
+        .transpose(1, 0, 3, 2, 4))
     if quant:                               # scales rode the payload
         assert wire["ks"].shape == (3, 2, 2, 4)
         assert wire["vs"].dtype == np.float32

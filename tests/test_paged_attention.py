@@ -24,6 +24,7 @@ from bigdl_tpu.models.transformer.generate import _decode_step_paged, generate
 from bigdl_tpu.ops import (autotune, paged_decode_attention,
                            paged_decode_attention_reference)
 from bigdl_tpu.serving import LMServingEngine
+from bigdl_tpu.serving.kvcache.blocks import pack_rows
 
 
 @pytest.fixture(autouse=True)
@@ -45,9 +46,11 @@ def _arena(slots=3, heads=2, head_dim=8, cache_len=24, block_len=4,
     num_blocks = slots * width + 1  # block 0 is the scratch block
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
     q = jax.random.normal(ks[0], (slots, heads, head_dim), dtype)
-    ka = jax.random.normal(ks[1], (num_blocks, heads, block_len, head_dim),
-                           dtype)
-    va = jax.random.normal(ks[2], ka.shape, dtype)
+    # one layer's arena in the pool's layout: (N, B, W), a position row
+    # holding its heads side by side, lane-padded
+    shape = (num_blocks, block_len, heads, head_dim)
+    ka = pack_rows(jax.random.normal(ks[1], shape, dtype))
+    va = pack_rows(jax.random.normal(ks[2], shape, dtype))
     ids = np.arange(1, slots * width + 1)
     if shuffle:
         np.random.RandomState(seed).shuffle(ids)
@@ -117,8 +120,8 @@ def test_decode_step_rejects_unknown_impl():
         _decode_step_paged(m, m.params, jnp.zeros((1,), jnp.int32),
                            jnp.zeros((1,), jnp.int32),
                            jnp.zeros((1, 2), jnp.int32),
-                           jnp.zeros((1, 3, 2, 4, 8)),
-                           jnp.zeros((1, 3, 2, 4, 8)),
+                           jnp.zeros((1, 3, 4, 128)),
+                           jnp.zeros((1, 3, 4, 128)),
                            attn_impl="nope")
 
 

@@ -270,7 +270,8 @@ def test_blockpool_int8_arenas_and_migration_gate():
     pool = BlockPool(n_layers=1, n_heads=2, head_dim=8, block_len=4,
                      num_blocks=6, dtype=np.float32, kv_quant="int8")
     assert pool.k.dtype == jnp.int8 and pool.ks.dtype == jnp.float32
-    assert pool.ks.shape == pool.shape[:4]
+    # a block's 4 x 2 scales in one lane-padded row
+    assert pool.ks.shape == pool.scale_shape == (1, 6, 128)
     assert pool.stats()["kv_quant"] == "int8"
     # scale arenas are accounted, and the int8 arenas beat the f32
     # pool's footprint despite them

@@ -174,6 +174,71 @@ def test_tree_int8_target_with_radix_sharing(lm_model):
         eng.close()
 
 
+@pytest.mark.parametrize("quant", [None, "int8"])
+def test_tree_commit_moves_rows_through_the_pool_layout(quant):
+    """``_tree_commit_paged`` in the block-contiguous layout: an accepted
+    off-spine node's row moves from its store offset to its position
+    offset (across a block boundary too), data and, quantized, its
+    scales; identity rows and every other row stay as they were."""
+    import jax.numpy as jnp
+    from bigdl_tpu.models.transformer.generate import _tree_commit_paged
+    from bigdl_tpu.serving.kvcache import BlockPool
+    from bigdl_tpu.serving.kvcache.blocks import read_chain, write_rows
+    L, H, D, B = 2, 5, 64, 4                 # H * D = 320: lane-padded rows
+    pool = BlockPool(n_layers=L, n_heads=H, head_dim=D, block_len=B,
+                     num_blocks=8, dtype=jnp.float32, kv_quant=quant)
+    tables = jnp.asarray([[3, 5, 1], [2, 6, 4]], jnp.int32)
+    idx = tables.reshape(-1)
+    rng = np.random.default_rng(0)
+    rows = rng.integers(-90, 90, (L, 6, B, H, D)).astype(pool.dtype)
+    every = slice(None)
+    arenas = [write_rows(a, every, idx, None, jnp.asarray(s * rows))
+              for a, s in ((pool.k, 1), (pool.v, -1))]
+    if quant:
+        srows = rng.random((L, 6, B, H)).astype(np.float32)
+        arenas += [write_rows(a, every, idx, None, jnp.asarray(s * srows))
+                   for a, s in ((pool.ks, 1), (pool.vs, 2))]
+    pos = jnp.asarray([2, 5], jnp.int32)
+    # slot 0 accepted node 3 at depth 1 and node 2 (spine) at depth 2;
+    # slot 1 stays on the spine (identity rows)
+    src = jnp.asarray([[3, 2], [1, 2]], jnp.int32)
+    out = _tree_commit_paged(src, pos, tables, *arenas, n_heads=H)
+    blocks = [(B, H, D)] * 2 + [(B, H)] * 2
+    for before, after, block in zip(arenas, out, blocks):
+        b = np.asarray(read_chain(before, every, tables, block))  # (L, S, 3B, ..)
+        a = np.asarray(read_chain(after, every, tables, block))
+        want = b.copy()
+        want[:, 0, 2 + 1] = b[:, 0, 2 + 3]      # position 3 <- offset 5 (next block)
+        want[:, 0, 2 + 2] = b[:, 0, 2 + 2]
+        np.testing.assert_array_equal(a, want)
+        assert a.shape[2] == 3 * B
+
+
+def test_tree_int8_kv_pool_matches_its_plain_decode(lm_model):
+    """An int8 KV pool under tree verify: candidate rows are quantized
+    into the data and scale arenas, accepted alternates are committed
+    through both, and greedy streams equal the same int8 pool's plain
+    decode engine token for token."""
+    kw = dict(slots=4, cache_len=48, block_len=4, max_new_tokens=10,
+              prefill_buckets=(8, 16), kv_quant="int8")
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(1, 32, size=n).astype(np.int32)
+               for n in (5, 8, 11)]
+    outs = []
+    for spec in (None, SpecConfig(k=3, tree=True)):
+        eng = LMServingEngine(lm_model, spec=spec, **kw)
+        eng.warmup()
+        try:
+            streams = [eng.submit(p, max_new_tokens=10) for p in prompts]
+            outs.append([np.asarray(s.result(timeout=60)) for s in streams])
+            if spec is not None:
+                assert eng.stats()["spec"]["tree_rounds"] > 0
+        finally:
+            eng.close()
+    for plain, tree in zip(*outs):
+        np.testing.assert_array_equal(plain, tree)
+
+
 # --------------------------------------------------------------------------- #
 # bounded executables + donation                                              #
 # --------------------------------------------------------------------------- #
