@@ -23,10 +23,11 @@ TPU-first structure instead of a stack of OO layers:
 from __future__ import annotations
 
 import math
-from typing import Optional
+from typing import NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from bigdl_tpu import nn
 from bigdl_tpu.nn.module import Module
@@ -50,6 +51,75 @@ def apply_rope(x, positions, base: float = 10000.0):
                             (x2 * cos + x1 * sin).astype(x.dtype)], -1)
 
 
+class RopeSpec(NamedTuple):
+    """One layer kind's rotary embedding: ``theta``, how many leading dims
+    of each head rotate (``rotary_dim``; ``None``: the whole head, the
+    rest pass through), and YaRN's ``(factor, original_max_positions,
+    beta_fast, beta_slow)`` with the ``attention_factor`` that multiplies
+    cos and sin (Peng et al. 2023; the frequencies are computed over the
+    ROTATED dims, as the published configs of partial-rotary models do)."""
+    theta: float = 10000.0
+    rotary_dim: Optional[int] = None
+    yarn: Optional[Tuple[float, int, float, float]] = None
+    attention_factor: float = 1.0
+
+    def inv_freq(self, head_dim: int) -> np.ndarray:
+        dim = int(self.rotary_dim or head_dim)
+        pos_freqs = float(self.theta) ** (np.arange(0, dim, 2,
+                                                    dtype=np.float64) / dim)
+        if self.yarn is None:
+            return (1.0 / pos_freqs).astype(np.float32)
+        factor, original, beta_fast, beta_slow = self.yarn
+
+        def correction_dim(rotations):
+            return (dim * math.log(original / (rotations * 2 * math.pi))
+                    / (2 * math.log(self.theta)))
+
+        low = max(math.floor(correction_dim(beta_fast)), 0)
+        high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
+        ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                       / max(high - low, 1e-3), 0.0, 1.0)
+        # ramp 0: the high frequencies keep their own (extrapolation);
+        # ramp 1: the low ones are divided by the factor (interpolation)
+        return ((1.0 / pos_freqs) * (1.0 - ramp)
+                + (1.0 / (factor * pos_freqs)) * ramp).astype(np.float32)
+
+
+def apply_rotary(x, positions, inv_freq, scale: float = 1.0):
+    """Rotate-half rotary over the first ``2 * len(inv_freq)`` dims of
+    each head, cos and sin multiplied by ``scale``; the other dims pass
+    through.  ``positions`` broadcasts as in :func:`apply_rope`."""
+    half = int(inv_freq.shape[0])
+    ang = positions.astype(jnp.float32)[..., :, None] * jnp.asarray(inv_freq)
+    cos, sin = jnp.cos(ang) * scale, jnp.sin(ang) * scale
+    x1 = x[..., :half].astype(jnp.float32)
+    x2 = x[..., half:2 * half].astype(jnp.float32)
+    parts = [(x1 * cos - x2 * sin).astype(x.dtype),
+             (x2 * cos + x1 * sin).astype(x.dtype)]
+    if 2 * half < x.shape[-1]:
+        parts.append(x[..., 2 * half:])
+    return jnp.concatenate(parts, -1)
+
+
+class LayerSpec(NamedTuple):
+    """What one layer of a plan differs by: its query heads, its window
+    (``None``: every earlier key; ``w``: key j for query i iff
+    ``i - j < w``), its rotary embedding (``None``: the model's
+    ``pos_encoding``) and its feed-forward half (``"dense"`` or
+    ``"moe"``, the model's ``moe`` spec)."""
+    n_head: int
+    window: Optional[int] = None
+    rope: Optional[RopeSpec] = None
+    mlp: str = "dense"
+
+
+def window_mask(q_pos, k_pos, window: Optional[int]):
+    """Causal (and windowed) visibility of key positions ``k_pos`` (..., Tk)
+    to query positions ``q_pos`` (..., Tq): -> (..., Tq, Tk) bool."""
+    d = q_pos[..., :, None] - k_pos[..., None, :]
+    return (d >= 0) if window is None else (d >= 0) & (d < window)
+
+
 class TransformerLM(Module):
     """Causal transformer LM over 1-based token ids.
 
@@ -62,6 +132,12 @@ class TransformerLM(Module):
     # __new__ + saved __dict__ (file_io.build_module), so a model saved
     # before this attribute existed must still forward cleanly
     doc_start_id: Optional[int] = None
+    # the same for the block parameters a layer plan brought: an older
+    # checkpoint is GPT-2's block, a plan of one uniform group
+    layer_plan = None
+    n_kv_head = None
+    norm, norm_eps, mlp_act, bias, attn_gate, moe = (
+        "layernorm", 1e-5, "gelu", True, False, None)
 
     def __init__(self, vocab_size: int, hidden_size: int = 128,
                  n_head: int = 4, n_layers: int = 2,
@@ -74,9 +150,21 @@ class TransformerLM(Module):
                  moe_experts: int = 0,
                  moe_capacity_factor: Optional[float] = 1.25,
                  moe_aux_weight: float = 0.01,
-                 doc_start_id: Optional[int] = None):
+                 doc_start_id: Optional[int] = None,
+                 n_kv_head: Optional[int] = None,
+                 head_dim: Optional[int] = None,
+                 norm: str = "layernorm", norm_eps: float = 1e-5,
+                 mlp_act: str = "gelu", bias: bool = True,
+                 attn_gate: bool = False, moe=None,
+                 layer_plan: Optional[Sequence] = None):
         super().__init__()
-        assert hidden_size % n_head == 0
+        assert head_dim is not None or hidden_size % n_head == 0
+        if norm not in ("layernorm", "rmsnorm"):
+            raise ValueError(f"norm must be 'layernorm' or 'rmsnorm', "
+                             f"got {norm!r}")
+        if mlp_act not in ("gelu", "swiglu"):
+            raise ValueError(f"mlp_act must be 'gelu' or 'swiglu', "
+                             f"got {mlp_act!r}")
         if pos_encoding not in ("learned", "rope"):
             raise ValueError(f"pos_encoding must be 'learned' or 'rope', "
                              f"got {pos_encoding!r}")
@@ -118,8 +206,68 @@ class TransformerLM(Module):
         # attention plumbing (projections + kernel choice) is shared with
         # the standalone nn.MultiHeadAttention so there is one hot path
         self._mha = nn.MultiHeadAttention(
-            hidden_size, n_head, causal=True, with_bias=True,
-            attention_impl=attention_impl, block_size=block_size)
+            hidden_size, n_head, head_dim=head_dim, causal=True,
+            with_bias=bias, attention_impl=attention_impl,
+            block_size=block_size)
+        # -- the block's parameters beyond GPT-2's (all default to it) -- #
+        # RMSNorm or LayerNorm; a gated (SwiGLU) or a GELU MLP; biases or
+        # none; K/V heads shared by groups of query heads; a sigmoid gate
+        # per head on the attention output; a routed expert layer
+        # (parallel.expert.MoESpec) on the plan's "moe" layers
+        self.n_kv_head = int(n_kv_head or n_head)
+        self.norm, self.norm_eps = norm, float(norm_eps)
+        self.mlp_act, self.bias, self.attn_gate = mlp_act, bool(bias), attn_gate
+        self.moe = moe
+        # the LAYER PLAN: a list of groups ``(repeat, period)``, a period a
+        # tuple of LayerSpec.  A group is ``repeat`` copies of its period
+        # stacked on a leading axis and scanned; the body runs the period's
+        # layers in turn, each with its own shapes (a period of one layer
+        # is a stack of identical layers).  ``None`` is GPT-2: one group of
+        # ``n_layers`` identical layers, parameters under "blocks" as ever.
+        if layer_plan is not None:
+            layer_plan = tuple((int(r), tuple(LayerSpec(*s) for s in period))
+                               for r, period in layer_plan)
+            depth = sum(r * len(period) for r, period in layer_plan)
+            if depth != n_layers:
+                raise ValueError(f"layer_plan holds {depth} layers, "
+                                 f"n_layers is {n_layers}")
+            for _, period in layer_plan:
+                for spec in period:
+                    if spec.n_head % self.n_kv_head:
+                        raise ValueError(
+                            f"{spec.n_head} query heads do not divide "
+                            f"over {self.n_kv_head} K/V heads")
+                    if spec.mlp == "moe" and moe is None:
+                        raise ValueError("a 'moe' layer needs moe=MoESpec")
+        elif self.n_kv_head != n_head or attn_gate or moe is not None:
+            raise ValueError("grouped K/V heads, the output gate and "
+                             "routed experts need a layer_plan")
+        self.layer_plan = layer_plan
+
+    # -------------------------------------------------------------- #
+    @property
+    def head_dim(self) -> int:
+        return self._mha.head_dim
+
+    @property
+    def plan(self):
+        """The layer plan, GPT-2's included (one uniform group)."""
+        if self.layer_plan is not None:
+            return self.layer_plan
+        return ((self.n_layers, (LayerSpec(self.n_head),)),)
+
+    @property
+    def moe_layers(self) -> int:
+        """How many of the plan's layers are routed expert layers."""
+        return sum(r * sum(s.mlp == "moe" for s in period)
+                   for r, period in self.plan)
+
+    def group_params(self, params):
+        """``params`` by the plan: a list (groups) of lists (the period's
+        positions) of layer-stacked parameter dicts."""
+        if self.layer_plan is None:
+            return [[params["blocks"]]]
+        return params["groups"]
 
     # -------------------------------------------------------------- #
     def _init_block(self, rng):
@@ -143,6 +291,48 @@ class TransformerLM(Module):
             p["b2"] = jnp.zeros((h,))
         return p
 
+    def _init_norm(self):
+        h = self.hidden_size
+        p = {"weight": jnp.ones((h,))}
+        if self.norm == "layernorm":
+            p["bias"] = jnp.zeros((h,))
+        return p
+
+    def _init_layer(self, spec: LayerSpec, rng):
+        """One planned layer: attention of ``spec.n_head`` query heads
+        over the model's K/V heads, then its feed-forward half."""
+        ks = jax.random.split(rng, 8)
+        h, f, d = self.hidden_size, self.ffn_size, self.head_dim
+        std_h = 1.0 / math.sqrt(h)
+        inner, kv = spec.n_head * d, self.n_kv_head * d
+
+        def mat(k, shape, std):
+            return jax.random.normal(k, shape, jnp.float32) * std
+
+        attn = {"wq": mat(ks[0], (h, inner), std_h),
+                "wk": mat(ks[1], (h, kv), std_h),
+                "wv": mat(ks[2], (h, kv), std_h),
+                "wo": mat(ks[3], (inner, h), 1.0 / math.sqrt(inner))}
+        if self.attn_gate:
+            attn["wg"] = mat(ks[4], (h, spec.n_head), std_h)
+        if self.bias:
+            attn.update(bq=jnp.zeros((inner,)), bk=jnp.zeros((kv,)),
+                        bv=jnp.zeros((kv,)), bo=jnp.zeros((h,)))
+        p = {"ln1": self._init_norm(), "attn": attn,
+             "ln2": self._init_norm()}
+        if spec.mlp == "moe":
+            from bigdl_tpu.parallel.expert import init_routed_params
+            p["moe"] = init_routed_params(ks[5], self.moe, h)
+        elif self.mlp_act == "swiglu":
+            p["mlp"] = {"w_gate": mat(ks[5], (h, f), std_h),
+                        "w_up": mat(ks[6], (h, f), std_h),
+                        "w_down": mat(ks[7], (f, h), 1.0 / math.sqrt(f))}
+        else:
+            p.update(w1=mat(ks[5], (h, f), std_h), b1=jnp.zeros((f,)),
+                     w2=mat(ks[6], (f, h), 1.0 / math.sqrt(f)),
+                     b2=jnp.zeros((h,)))
+        return p
+
     def _mlp(self, bp, m):
         """The block's feed-forward half: dense GELU MLP or switch MoE.
         Shared by the single-device block, the sequence-parallel body
@@ -163,13 +353,19 @@ class TransformerLM(Module):
         std = 1.0 / math.sqrt(h)
         # one vmapped init -> parameters already stacked on a leading
         # layer axis, the exact layout lax.scan consumes
-        blocks = jax.vmap(self._init_block)(
-            jax.random.split(k_blocks, self.n_layers))
-        p = {
-            "embed": jax.random.normal(k_emb, (v, h)) * std,
-            "blocks": blocks,
-            "ln_f": {"weight": jnp.ones((h,)), "bias": jnp.zeros((h,))},
-        }
+        p = {"embed": jax.random.normal(k_emb, (v, h)) * std,
+             "ln_f": self._init_norm()}
+        if self.layer_plan is None:
+            p["blocks"] = jax.vmap(self._init_block)(
+                jax.random.split(k_blocks, self.n_layers))
+        else:
+            import functools
+            keys = iter(jax.random.split(k_blocks, self.n_layers))
+            p["groups"] = [
+                [jax.vmap(functools.partial(self._init_layer, spec))(
+                    jnp.stack([next(keys) for _ in range(repeat)]))
+                 for spec in period]
+                for repeat, period in self.layer_plan]
         if self.pos_encoding == "learned":
             p["pos"] = jax.random.normal(k_pos, (self.max_len, h)) * std
         if not self.tie_embeddings:
@@ -183,35 +379,141 @@ class TransformerLM(Module):
         from bigdl_tpu.nn.normalization import layer_norm
         return layer_norm(x, p["weight"], p["bias"])
 
-    def _rope(self, q, k, positions):
+    def _norm(self, p, x):
+        """The block's normalisation: LayerNorm, or RMSNorm (f32 inside,
+        a learned weight, no offset)."""
+        if self.norm == "layernorm":
+            return self._layer_norm(p, x)
+        x32 = x.astype(jnp.float32)
+        y = x32 * jax.lax.rsqrt(
+            jnp.mean(x32 * x32, axis=-1, keepdims=True) + self.norm_eps)
+        return (y * p["weight"].astype(jnp.float32)).astype(x.dtype)
+
+    def _rope(self, q, k, positions, spec: Optional[LayerSpec] = None):
+        """Rotate q and k at ``positions``: by the layer's own rotary
+        embedding where its spec has one, else by ``pos_encoding``."""
+        if spec is not None and spec.rope is not None:
+            inv_freq = spec.rope.inv_freq(self.head_dim)
+            scale = spec.rope.attention_factor
+            return (apply_rotary(q, positions, inv_freq, scale),
+                    apply_rotary(k, positions, inv_freq, scale))
         if self.pos_encoding != "rope":
             return q, k
         return (apply_rope(q, positions, self.rope_base),
                 apply_rope(k, positions, self.rope_base))
 
-    def _block(self, bp, x, training: bool, rng, positions=None,
-               segment_ids=None):
-        mha = self._mha
-        a = self._layer_norm(bp["ln1"], x)
-        q, k, v = mha.project_qkv(bp["attn"], a, a, a)
+    # -- one layer in three parts, shared by the training forward, the
+    # -- prefills and every cached step (models/transformer/generate.py):
+    # -- what differs between them is only how attention reads its keys
+    def layer_qkv(self, spec: LayerSpec, bp, x, positions=None):
+        """Pre-attention: norm, projections, rotary.  -> q (B, H, T, D),
+        k, v (B, H_kv, T, D), gate (B, T, H) or None."""
+        from bigdl_tpu.nn._util import match_compute_dtype
+        from bigdl_tpu.quant.kernels import qmatmul
+        ap = bp["attn"]
+        a = match_compute_dtype(self._norm(bp["ln1"], x), ap["wq"])
+        q, k, v = (qmatmul(a, ap[n]) for n in ("wq", "wk", "wv"))
+        if self.bias:
+            q, k, v = q + ap["bq"], k + ap["bk"], v + ap["bv"]
+        b, t = a.shape[:2]
+
+        def heads(y, n):    # (B, T, n * D) -> (B, n, T, D)
+            return y.reshape(b, t, n, self.head_dim).transpose(0, 2, 1, 3)
+
+        q, k, v = (heads(q, spec.n_head), heads(k, self.n_kv_head),
+                   heads(v, self.n_kv_head))
         if positions is not None:
-            q, k = self._rope(q, k, positions)
-        # one shared dispatch (nn.MultiHeadAttention.attend); the block
-        # keeps mha.block_size as flash TILES, never the blockwise core
-        o = mha.attend(q, k, v, segment_ids=segment_ids,
-                       allow_blockwise=False)
-        o = mha.project_out(bp["attn"], o)
-        if training and self.dropout > 0.0:
-            rng, sub = jax.random.split(rng)
-            keep = 1.0 - self.dropout
-            o = o * jax.random.bernoulli(sub, keep, o.shape) / keep
-        x = x + o
-        m = self._layer_norm(bp["ln2"], x)
+            q, k = self._rope(q, k, positions, spec)
+        gate = (jax.nn.sigmoid(qmatmul(a, ap["wg"]).astype(jnp.float32))
+                if self.attn_gate else None)
+        return q, k, v, gate
+
+    def layer_attn_out(self, bp, o, gate=None):
+        """Post-attention, before the residual: the per-head gate and the
+        output projection.  ``o`` (B, H, T, D)."""
+        from bigdl_tpu.quant.kernels import qmatmul
+        if gate is not None:
+            o = (o.astype(jnp.float32)
+                 * gate.transpose(0, 2, 1)[..., None]).astype(o.dtype)
+        b, h, t, d = o.shape
+        y = qmatmul(o.transpose(0, 2, 1, 3).reshape(b, t, h * d),
+                    bp["attn"]["wo"])
+        if self.bias:
+            y = y + bp["attn"]["bo"]
+        return y
+
+    def layer_ffn(self, spec: LayerSpec, bp, x, *, dense_routing=False,
+                  token_mask=None):
+        """The feed-forward half before its residual: -> (m, aux, counts).
+        ``aux`` is the switch MoE's balance term (0 otherwise), ``counts``
+        the routed layer's two integers (``parallel.expert.routed_experts``;
+        zeros otherwise).  ``dense_routing``: the cached steps run the
+        legacy switch MoE without its capacity window."""
+        m = self._norm(bp["ln2"], x)
+        zero = jnp.zeros((), jnp.float32)
+        counts = jnp.zeros((2,), jnp.int32)
+        if spec.mlp == "moe":
+            from bigdl_tpu.parallel.expert import routed_mlp
+            m, counts = routed_mlp(bp["moe"], m, self.moe,
+                                   token_mask=token_mask)
+            return m, zero, counts
+        if self.mlp_act == "swiglu":
+            from bigdl_tpu.parallel.expert import swiglu
+            return swiglu(bp["mlp"], m), zero, counts
+        if self.moe_experts and dense_routing:
+            from bigdl_tpu.parallel.expert import switch_mlp
+            m, _ = switch_mlp(bp["moe"], m, capacity_factor=None)
+            return m, zero, counts
         m, aux = self._mlp(bp, m)
-        if training and self.dropout > 0.0:
+        return m, aux, counts
+
+    def attend_full(self, spec: LayerSpec, q, k, v, segment_ids=None):
+        """Self-attention of a whole sequence (training forward, prefill):
+        causal, within ``spec.window`` where the layer has one, K/V heads
+        shared by groups of query heads.  The Pallas flash kernel where
+        the model's ``attention_impl`` resolves to it (the mask terms are
+        applied inside its tiles and K/V tiles are read once a group: no
+        (T, T) matrix and no repeated heads in HBM), else one XLA fusion
+        under an explicit mask."""
+        mha = self._mha
+        if spec.window is None and q.shape[1] == k.shape[1]:
+            # one shared dispatch (nn.MultiHeadAttention.attend); the block
+            # keeps mha.block_size as flash TILES, never the blockwise core
+            return mha.attend(q, k, v, segment_ids=segment_ids,
+                              allow_blockwise=False)
+        if mha.resolve_use_flash(q.shape[-2], dtype=q.dtype):
+            from bigdl_tpu.ops import flash_attention
+            bs = mha.block_size or 128
+            return flash_attention(q, k, v, causal=True, window=spec.window,
+                                   segment_ids=segment_ids, block_q=bs,
+                                   block_k=bs)
+        from bigdl_tpu.nn.attention import (dot_product_attention,
+                                            segment_mask)
+        group = q.shape[1] // k.shape[1]
+        if group > 1:
+            k, v = (jnp.repeat(x, group, axis=1) for x in (k, v))
+        pos = jnp.arange(q.shape[-2])
+        mask = window_mask(pos, pos, spec.window)
+        if segment_ids is not None:
+            mask = mask & segment_mask(segment_ids, segment_ids)
+        return dot_product_attention(q, k, v, mask=mask)
+
+    def _block(self, spec, bp, x, training: bool, rng, positions=None,
+               segment_ids=None):
+        q, k, v, gate = self.layer_qkv(spec, bp, x, positions)
+        o = self.attend_full(spec, q, k, v, segment_ids)
+
+        def drop(y, rng):
+            if not (training and self.dropout > 0.0):
+                return y, rng
             rng, sub = jax.random.split(rng)
             keep = 1.0 - self.dropout
-            m = m * jax.random.bernoulli(sub, keep, m.shape) / keep
+            return y * jax.random.bernoulli(sub, keep, y.shape) / keep, rng
+
+        o, rng = drop(self.layer_attn_out(bp, o, gate), rng)
+        x = x + o
+        m, aux, _ = self.layer_ffn(spec, bp, x)
+        m, rng = drop(m, rng)
         return x + m, aux
 
     def _forward(self, params, x, training: bool, rng):
@@ -238,14 +540,28 @@ class TransformerLM(Module):
             segment_ids = jnp.cumsum(
                 (ids == self.doc_start_id - 1).astype(jnp.int32), axis=-1)
 
-        block = (jax.checkpoint(self._block, static_argnums=(2,))
+        block = (jax.checkpoint(self._block, static_argnums=(0, 3))
                  if self.remat else self._block)
         keys = jax.random.split(rng, self.n_layers)
-        h, auxes = jax.lax.scan(
-            lambda carry, layer: block(layer[0], carry, training, layer[1],
-                                       positions, segment_ids),
-            h, (params["blocks"], keys))
-        h = self._layer_norm(params["ln_f"], h)
+        aux, done = jnp.zeros((), jnp.float32), 0
+        for (repeat, period), stacks in zip(self.plan,
+                                            self.group_params(params)):
+            n = repeat * len(period)
+
+            def body(carry, xs, period=period):
+                h, aux = carry
+                for i, (spec, bp) in enumerate(zip(period, xs[0])):
+                    h, a = block(spec, bp, h, training, xs[1][i], positions,
+                                 segment_ids)
+                    aux = aux + a
+                return (h, aux), None
+
+            # one key a layer, in the plan's order: (repeat, period, ...)
+            group_keys = keys[done:done + n].reshape(
+                (repeat, len(period)) + keys.shape[1:])
+            (h, aux), _ = jax.lax.scan(body, (h, aux), (stacks, group_keys))
+            done += n
+        h = self._norm(params["ln_f"], h)
         if self.tie_embeddings:
             logits = h @ params["embed"].T.astype(h.dtype)
         else:
@@ -255,7 +571,7 @@ class TransformerLM(Module):
             logits = (qmatmul(h, head) if is_qtensor(head)
                       else h @ head.astype(h.dtype))
         logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-        return logp, jnp.sum(auxes)
+        return logp, aux
 
     def f(self, params, x, *, training: bool = False, rng=None):
         return self._forward(params, x, training, rng)[0]

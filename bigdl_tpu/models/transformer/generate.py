@@ -29,14 +29,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from bigdl_tpu.models.transformer import TransformerLM
+from bigdl_tpu.models.transformer import TransformerLM, window_mask
 from bigdl_tpu.serving.kvcache.blocks import read_chain, write_rows
-
-
-def _block_qkv(model, bp, h):
-    """One block's q/k/v for a (B, T, hidden) slice, pre-attention."""
-    a = model._layer_norm(bp["ln1"], h)
-    return model._mha.project_qkv(bp["attn"], a, a, a)
 
 
 def _head_logits(model, params, h):
@@ -54,20 +48,60 @@ def _head_logits(model, params, h):
     return h @ head.astype(h.dtype)
 
 
-def _finish_block(model, bp, h, o):
-    h = h + model._mha.project_out(bp["attn"], o)
-    m = model._layer_norm(bp["ln2"], h)
-    if model.moe_experts:
-        from bigdl_tpu.parallel.expert import switch_mlp
-        # DENSE routing during decode: the capacity window is a
-        # batch-level training construct — under it, a sequence's tokens
-        # would drop depending on which unrelated prompts share the
-        # dispatch, coupling batch rows.  Dense per-token routing is
-        # batch-independent and exact (aux is a training term; dropped).
-        m, _ = switch_mlp(bp["moe"], m, capacity_factor=None)
-    else:
-        m, _ = model._mlp(bp, m)
-    return h + m
+def _finish_block(model, spec, bp, h, o, gate, token_mask=None):
+    """After attention: gate, output projection and residual, then the
+    feed-forward half.  The legacy switch MoE routes DENSELY here: its
+    capacity window is a batch-level training construct -- under it a
+    sequence's tokens would drop depending on which unrelated prompts
+    share the dispatch, coupling batch rows.  -> (h, counts), ``counts``
+    the routed expert layer's two integers (zeros on a dense layer)."""
+    h = h + model.layer_attn_out(bp, o, gate)
+    m, _, counts = model.layer_ffn(spec, bp, h, dense_routing=True,
+                                   token_mask=token_mask)
+    return h + m, counts
+
+
+def _windows(model):
+    """The distinct windows of the model's layers (``None``: full)."""
+    return {s.window for _, period in model.plan for s in period}
+
+
+def _scan_prefill(model, params, h, layer_fn):
+    """A prefill's layer loop over the plan: ``layer_fn(spec, h, bp,
+    layer) -> (h, k, v, counts)``; -> (h, k, v, counts) with k/v (L, B,
+    H_kv, T, D) stacked by absolute layer and the routed expert layers'
+    two integers summed."""
+    ks, vs, base = [], [], 0
+    counts = jnp.zeros((2,), jnp.int32)
+    for (repeat, period), stacks in zip(model.plan,
+                                        model.group_params(params)):
+        n = len(period)
+
+        def body(carry, x, period=period, base=base, n=n):
+            h, counts = carry
+            bps, r = x
+            kv = []
+            for i, (spec, bp) in enumerate(zip(period, bps)):
+                h, k, v, c = layer_fn(spec, h, bp, base + r * n + i)
+                kv.append((k, v))
+                counts = counts + c
+            return (h, counts), tuple(jnp.stack(c) for c in zip(*kv))
+
+        (h, counts), (k, v) = lax.scan(body, (h, counts),
+                                       (stacks, jnp.arange(repeat)))
+        ks.append(k.reshape((repeat * n,) + k.shape[2:]))
+        vs.append(v.reshape((repeat * n,) + v.shape[2:]))
+        base += repeat * n
+    if len(ks) == 1:
+        return h, ks[0], vs[0], counts
+    return h, jnp.concatenate(ks), jnp.concatenate(vs), counts
+
+
+def _prefill_result(model, logits, k, v, counts):
+    """What a prefill hands back: with routed expert layers in the model,
+    their two integers ride out behind the k/v."""
+    out = (logits.astype(jnp.float32), k, v)
+    return out + (counts,) if model.moe_layers else out
 
 
 def _prefill_parts(model, params, ids0, last_index):
@@ -85,33 +119,22 @@ def _prefill_parts(model, params, ids0, last_index):
         h = h + params["pos"][:t]
     positions = jnp.arange(t)
 
-    def body(h, bp):
-        q, k, v = _block_qkv(model, bp, h)
-        q, k = model._rope(q, k, positions)
-        # honor the model's configured attention core via the shared
-        # resolver (flash keeps the (T, T) matrix out of HBM for long
-        # prompts, exactly as in TransformerLM._block — including the
-        # "auto" crossover rule)
-        if model._mha.resolve_use_flash(q.shape[-2], dtype=q.dtype):
-            from bigdl_tpu.ops import flash_attention
-            if model._mha.attention_impl == "flash" or model._mha.block_size:
-                bs = model._mha.block_size or 128
-                o = flash_attention(q, k, v, causal=True, block_q=bs,
-                                    block_k=bs)
-            else:
-                # "auto": blocks stay None -> tuned-crossover plan
-                o = flash_attention(q, k, v, causal=True)
-        else:
-            from bigdl_tpu.nn.attention import dot_product_attention
-            o = dot_product_attention(q, k, v, causal=True)
-        h = _finish_block(model, bp, h, o)
-        return h, (k, v)
+    def layer_fn(spec, h, bp, layer):
+        q, k, v, gate = model.layer_qkv(spec, bp, h, positions)
+        # the model's configured attention core via the shared dispatch
+        # (flash keeps the (T, T) matrix out of HBM for long prompts,
+        # exactly as in TransformerLM._block -- including the "auto"
+        # crossover rule)
+        with jax.named_scope("attn/sliding" if spec.window else "attn/full"):
+            o = model.attend_full(spec, q, k, v)
+        h, c = _finish_block(model, spec, bp, h, o, gate)
+        return h, k, v, c
 
-    h, (k, v) = lax.scan(body, h, params["blocks"])
+    h, k, v, counts = _scan_prefill(model, params, h, layer_fn)
     h = lax.dynamic_slice_in_dim(h, last_index, 1, axis=1)
-    h = model._layer_norm(params["ln_f"], h)
+    h = model._norm(params["ln_f"], h)
     logits = _head_logits(model, params, h)[:, 0]
-    return logits.astype(jnp.float32), k, v
+    return _prefill_result(model, logits, k, v, counts)
 
 
 def _prefill(model, params, ids0, cache_len):
@@ -121,7 +144,7 @@ def _prefill(model, params, ids0, cache_len):
     from bigdl_tpu.quant import dequantize_entry
     params = dequantize_entry(params)  # int8 clones generate too
     t = ids0.shape[1]
-    logits, k, v = _prefill_parts(model, params, ids0, t - 1)
+    logits, k, v = _prefill_parts(model, params, ids0, t - 1)[:3]
     pad = ((0, 0), (0, 0), (0, 0), (0, cache_len - t), (0, 0))
     return logits, jnp.pad(k, pad), jnp.pad(v, pad)
 
@@ -133,7 +156,12 @@ def _decode_step_slots(model, params, token, pos, k_cache, v_cache):
     Caches (L, S, H, cache_len, D).  Returns (next logits (S, V) f32,
     caches').  The serving engine jits this with the caches donated so
     the decode loop never copies HBM-resident state."""
-    mha = model._mha
+    if model.layer_plan is not None:
+        raise NotImplementedError(
+            "the slot caches hold one uniform stack of layers; a model with "
+            "a layer plan decodes through the paged engine "
+            "(serving.LMServingEngine)")
+    spec = model.plan[0][1][0]
     h = params["embed"][token][:, None, :]
     if model.pos_encoding == "learned":
         h = h + params["pos"][pos][:, None, :]
@@ -150,22 +178,22 @@ def _decode_step_slots(model, params, token, pos, k_cache, v_cache):
     def body(carry, layer):
         h = carry
         bp, kc, vc = layer
-        q, k, v = _block_qkv(model, bp, h)  # q,k,v: (S, H, 1, D)
-        q, k = model._rope(q, k, positions)  # keys rotate at THEIR position
+        # q, k, v: (S, H, 1, D); keys rotate at THEIR position
+        q, k, v, gate = model.layer_qkv(spec, bp, h, positions)
         kc = upd(kc, k, pos)
         vc = upd(vc, v, pos)
         scores = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
                             kc.astype(jnp.float32))
-        scores = scores / jnp.sqrt(jnp.float32(mha.head_dim))
+        scores = scores / jnp.sqrt(jnp.float32(model.head_dim))
         scores = jnp.where(mask, scores, -1e30)
         w = jax.nn.softmax(scores, axis=-1)
         o = jnp.einsum("bhqk,bhkd->bhqd", w, vc.astype(jnp.float32))
-        h = _finish_block(model, bp, h, o.astype(h.dtype))
+        h, _ = _finish_block(model, spec, bp, h, o.astype(h.dtype), gate)
         return h, (kc, vc)
 
     h, (k_cache, v_cache) = lax.scan(body, h,
                                      (params["blocks"], k_cache, v_cache))
-    h = model._layer_norm(params["ln_f"], h)
+    h = model._norm(params["ln_f"], h)
     logits = _head_logits(model, params, h)[:, 0]
     return logits.astype(jnp.float32), k_cache, v_cache
 
@@ -182,18 +210,20 @@ def _kv_quantize_rows(x):
     return q, s
 
 
-def _paged_attention(mha, q, k, v, arenas, layer, blk, off, tables, mask):
+def _paged_attention(q, k, v, arenas, layer, blk, off, tables, mask):
     """One layer's cached attention over PAGED arenas, shared by the
     decode, verify and tree-verify steps: write the W new rows of each
-    slot (``k``/``v`` (S, H, W, D), row j at ``(blk, off)[s, j]``) into
-    ``arenas[..][layer]``, then attend ``q`` (S, H, W, D) over each
-    slot's chain under ``mask`` (S, 1, W, ctx).  ``arenas`` is ``(k, v)``
+    slot (``k``/``v`` (S, H_kv, W, D), row j at ``(blk, off)[s, j]``)
+    into ``arenas[..][layer]``, then attend ``q`` (S, H, W, D) over each
+    slot's chain under ``mask`` (S, 1, W, ctx); query head i reads K/V
+    head ``i // (H / H_kv)``.  ``arenas`` is ``(k, v)``
     or, for an int8 pool, ``(k, v, k_scale, v_scale)``: rows are
     quantized per (position, head) on the way in and the gather
     dequantizes in flight.  The layout is the pool's
     (``serving.kvcache.blocks``); scores and softmax are f32.  Returns
     (o (S, H, W, D) f32, arenas')."""
-    block = (arenas[0].shape[2], mha.n_head, mha.head_dim)    # (B, H, D)
+    n_kv, d = k.shape[1], k.shape[3]
+    block = (arenas[0].shape[2], n_kv, d)                     # (B, H_kv, D)
     k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)   # (S, W, H, D)
     if len(arenas) == 4:
         ka, va, ksa, vsa = arenas
@@ -214,28 +244,52 @@ def _paged_attention(mha, q, k, v, arenas, layer, blk, off, tables, mask):
         arenas = (ka, va, ksa, vsa)
     else:
         arenas = (ka, va)
+    if q.shape[1] != n_kv:
+        # grouped heads: (S, H_kv, G, W, D) against the K/V heads as stored
+        s_, h_, w_ = q.shape[:3]
+        qg = q.astype(jnp.float32).reshape(s_, n_kv, h_ // n_kv, w_, d)
+        scores = jnp.einsum("bngqd,bknd->bngqk", qg, kg)
+        scores = scores / jnp.sqrt(jnp.float32(d))
+        scores = jnp.where(mask[:, :, None], scores, -1e30)
+        w = jax.nn.softmax(scores, axis=-1)
+        o = jnp.einsum("bngqk,bknd->bngqd", w, vg)
+        return o.reshape(s_, h_, w_, d), arenas
     scores = jnp.einsum("bhqd,bkhd->bhqk", q.astype(jnp.float32), kg)
-    scores = scores / jnp.sqrt(jnp.float32(mha.head_dim))
+    scores = scores / jnp.sqrt(jnp.float32(d))
     scores = jnp.where(mask, scores, -1e30)
     w = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum("bhqk,bkhd->bhqd", w, vg), arenas
 
 
-def _scan_layers(params, h, arenas, layer_fn):
-    """The paged steps' layer loop: the arenas ride the CARRY whole and
-    each layer indexes them itself (``layer_fn(h, bp, layer, arenas) ->
-    (h, arenas)``), so the compiled loop updates the donated buffers in
+def _scan_layers(model, params, h, arenas, layer_fn):
+    """The paged steps' layer loop over the model's plan: the arenas ride
+    the CARRY whole and each layer indexes them itself by its absolute
+    layer (``layer_fn(spec, h, bp, layer, arenas) -> (h, arenas,
+    counts)``), so the compiled loop updates the donated buffers in
     place; threaded as ``xs`` and stacked ``ys`` they were re-laid out
-    and copied every layer (PERF.md, PR 25)."""
-    n = jax.tree_util.tree_leaves(params["blocks"])[0].shape[0]
+    and copied every layer (PERF.md, PR 25).  A group of the plan is one
+    scan over its stacked periods, the body running the period's layers
+    in turn.  -> (h, arenas, counts): ``counts`` the routed expert
+    layers' two integers summed over the layers."""
+    counts, base = jnp.zeros((2,), jnp.int32), 0
+    arenas = tuple(arenas)
+    for (repeat, period), stacks in zip(model.plan,
+                                        model.group_params(params)):
+        n = len(period)
 
-    def body(carry, x):
-        bp, layer = x
-        return layer_fn(carry[0], bp, layer, carry[1]), None
+        def body(carry, x, period=period, base=base, n=n):
+            h, arenas, counts = carry
+            bps, r = x
+            for i, (spec, bp) in enumerate(zip(period, bps)):
+                h, arenas, c = layer_fn(spec, h, bp, base + r * n + i,
+                                        arenas)
+                counts = counts + c
+            return (h, arenas, counts), None
 
-    (h, arenas), _ = lax.scan(
-        body, (h, tuple(arenas)), (params["blocks"], jnp.arange(n)))
-    return h, arenas
+        (h, arenas, counts), _ = lax.scan(
+            body, (h, arenas, counts), (stacks, jnp.arange(repeat)))
+        base += repeat * n
+    return h, arenas, counts
 
 
 def _arenas(k_arena, v_arena, k_scale, v_scale):
@@ -252,7 +306,7 @@ def _prefill_suffix_parts(model, params, ids0, last_index, prefix_len,
     the padded block chain holding the prefix k/v in the pool's arenas
     — padded entries point at the scratch block and are masked via
     ``prefix_len``.  Returns (logits at suffix index ``last_index``, k,
-    v) with k/v (L, 1, H, Ts, D), exactly like :func:`_prefill_parts`
+    v) with k/v (L, 1, H_kv, Ts, D), exactly like :func:`_prefill_parts`
     for the suffix rows.
 
     Numerics are the offline prefill's: suffix queries attend the SAME
@@ -269,7 +323,7 @@ def _prefill_suffix_parts(model, params, ids0, last_index, prefix_len,
 
     b, ts = ids0.shape
     B = k_arena.shape[2]
-    block = (B, model._mha.n_head, model._mha.head_dim)
+    block = (B, model.n_kv_head, model.head_dim)
     pb = blocks.shape[0]
     h = params["embed"][ids0]
     positions = prefix_len + jnp.arange(ts)
@@ -280,10 +334,14 @@ def _prefill_suffix_parts(model, params, ids0, last_index, prefix_len,
     # key validity over the concatenated [prefix | suffix] axis: prefix
     # entries are valid below prefix_len (padded chain entries and the
     # block-padding gap are garbage), suffix entries are causal
-    jq = jnp.arange(ts)[:, None]
-    jk = jnp.arange(pb * B + ts)[None, :]
-    mask = ((jk < prefix_len)
-            | ((jk >= pb * B) & (jk - pb * B <= jq)))[None, None]
+    # ... and, on a windowed layer, within the window of the query's
+    # ABSOLUTE position (a cached prefix key lives at its index, a suffix
+    # key at prefix_len + its index)
+    jk = jnp.arange(pb * B + ts)
+    kpos = jnp.where(jk < pb * B, jk, prefix_len + jk - pb * B)
+    valid = (jk < prefix_len) | (jk >= pb * B)
+    masks = {w: (valid[None, :] & window_mask(positions, kpos, w))[None, None]
+             for w in _windows(model)}
 
     def prefix(arena, scale, layer, dtype):
         # the prefix chain (Pb*B, H, D) -> (1, H, Pb*B, D)
@@ -293,24 +351,22 @@ def _prefill_suffix_parts(model, params, ids0, last_index, prefix_len,
                  * read_chain(scale, layer, blocks, block[:2])[..., None])
         return g.transpose(1, 0, 2)[None].astype(dtype)
 
-    def body(h, x):
-        bp, layer = x
-        q, k, v = _block_qkv(model, bp, h)
-        q, k = model._rope(q, k, positions)
-        kp = prefix(k_arena, k_scale, layer, k.dtype)
-        vp = prefix(v_arena, v_scale, layer, v.dtype)
-        o = dot_product_attention(q, jnp.concatenate([kp, k], axis=2),
-                                  jnp.concatenate([vp, v], axis=2),
-                                  mask=mask)
-        h = _finish_block(model, bp, h, o)
-        return h, (k, v)
+    def layer_fn(spec, h, bp, layer):
+        q, k, v, gate = model.layer_qkv(spec, bp, h, positions)
+        kc = jnp.concatenate([prefix(k_arena, k_scale, layer, k.dtype), k], 2)
+        vc = jnp.concatenate([prefix(v_arena, v_scale, layer, v.dtype), v], 2)
+        group = q.shape[1] // k.shape[1]
+        if group > 1:       # K/V heads repeated to the query heads
+            kc, vc = (jnp.repeat(x, group, axis=1) for x in (kc, vc))
+        o = dot_product_attention(q, kc, vc, mask=masks[spec.window])
+        h, c = _finish_block(model, spec, bp, h, o, gate)
+        return h, k, v, c
 
-    h, (k, v) = lax.scan(
-        body, h, (params["blocks"], jnp.arange(k_arena.shape[0])))
+    h, k, v, counts = _scan_prefill(model, params, h, layer_fn)
     h = lax.dynamic_slice_in_dim(h, last_index, 1, axis=1)
-    h = model._layer_norm(params["ln_f"], h)
+    h = model._norm(params["ln_f"], h)
     logits = _head_logits(model, params, h)[:, 0]
-    return logits.astype(jnp.float32), k, v
+    return _prefill_result(model, logits, k, v, counts)
 
 
 def _insert_blocks(k_arena, v_arena, k_new, v_new, block_ids,
@@ -377,7 +433,6 @@ def _decode_step_paged(model, params, token, pos, tables, k_arena,
     if k_scale is not None and attn_impl == "paged_kernel":
         raise ValueError("kv_quant='int8' requires decode_attn='gather' "
                          "(the Pallas paged kernel reads raw blocks)")
-    mha = model._mha
     s, m = tables.shape
     B = k_arena.shape[2]
     ctx = m * B
@@ -385,16 +440,27 @@ def _decode_step_paged(model, params, token, pos, tables, k_arena,
     if model.pos_encoding == "learned":
         h = h + params["pos"][pos][:, None, :]
     positions = pos[:, None, None]
-    mask = (jnp.arange(ctx)[None, :] <= pos[:, None])[:, None, None, :]
+    # (S, 1, 1, ctx) by window: a sliding layer reads its whole chain and
+    # sees the last ``window`` positions of it
+    masks = {w: window_mask(pos[:, None], jnp.arange(ctx)[None, :],
+                            w)[:, None]
+             for w in _windows(model)}
+    if attn_impl == "paged_kernel" and (
+            set(masks) != {None} or model.n_kv_head != model.n_head):
+        raise ValueError("the Pallas block-table kernel reads one K/V head a "
+                         "query head under a causal mask; windows and grouped "
+                         "heads need decode_attn='gather'")
+    # an idle slot carries an all-scratch table: its token is routed to no
+    # expert (its other rows are garbage that nothing reads)
+    active = tables[:, 0] != 0
     # the block holding each slot's write position (idle slots carry an
     # all-scratch table: their garbage write lands in block 0 and is
     # never attended); one new row a slot: (S, 1)
     blk = tables[jnp.arange(s), pos // B][:, None]
     off = (pos % B)[:, None]
 
-    def layer_fn(h, bp, layer, arenas):
-        q, k, v = _block_qkv(model, bp, h)  # (S, H, 1, D)
-        q, k = model._rope(q, k, positions)
+    def layer_fn(spec, h, bp, layer, arenas):
+        q, k, v, gate = model.layer_qkv(spec, bp, h, positions)  # (S, H, 1, D)
         if attn_impl == "paged_kernel":
             # in-place block reads via the table (no dense gather);
             # numerics identical to the gather
@@ -404,15 +470,23 @@ def _decode_step_paged(model, params, token, pos, tables, k_arena,
                 for a, x in zip(arenas, (k, v)))
             o = paged_decode_attention(q, *arenas, tables, pos, layer=layer)
         else:
-            o, arenas = _paged_attention(mha, q, k, v, arenas, layer, blk,
-                                         off, tables, mask)
-        return _finish_block(model, bp, h, o.astype(h.dtype)), arenas
+            with jax.named_scope("attn/sliding" if spec.window
+                                 else "attn/full"):
+                o, arenas = _paged_attention(q, k, v, arenas, layer, blk,
+                                             off, tables, masks[spec.window])
+        h, counts = _finish_block(model, spec, bp, h, o.astype(h.dtype), gate,
+                                  token_mask=active[:, None])
+        return h, arenas, counts
 
-    h, arenas = _scan_layers(
-        params, h, _arenas(k_arena, v_arena, k_scale, v_scale), layer_fn)
-    h = model._layer_norm(params["ln_f"], h)
-    logits = _head_logits(model, params, h)[:, 0]
-    return (logits.astype(jnp.float32),) + arenas
+    h, arenas, counts = _scan_layers(
+        model, params, h, _arenas(k_arena, v_arena, k_scale, v_scale),
+        layer_fn)
+    h = model._norm(params["ln_f"], h)
+    logits = _head_logits(model, params, h)[:, 0].astype(jnp.float32)
+    if model.moe_layers:
+        # the routed layers' two integers ride out beside the logits
+        return (logits, counts) + arenas
+    return (logits,) + arenas
 
 
 def _verify_step_paged(model, params, tokens, pos, n_cand, tables,
@@ -442,18 +516,18 @@ def _verify_step_paged(model, params, tokens, pos, n_cand, tables,
     abspos = pos[:, None] + jnp.arange(w)[None, :]   # (S, W)
     ctx = tables.shape[1] * k_arena.shape[2]
     # row j attends positions <= pos + j: (S, 1, W, ctx)
-    mask = (jnp.arange(ctx)[None, None, :] <= abspos[:, :, None])[:, None]
+    masks = {w_: window_mask(abspos, jnp.arange(ctx)[None, :], w_)[:, None]
+             for w_ in _windows(model)}
     return _verify_rows(model, params, tokens, n_cand, tables,
                         _arenas(k_arena, v_arena, k_scale, v_scale),
-                        store=abspos, rope=abspos, mask=mask)
+                        store=abspos, rope=abspos, masks=masks)
 
 
 def _verify_rows(model, params, tokens, n_cand, tables, arenas, *, store,
-                 rope, mask):
+                 rope, masks):
     """The body linear and tree verify share: row j of slot s is stored
     at arena offset ``store[s, j]``, rotated at position ``rope[s, j]``
-    and attends under ``mask`` (S, 1, W, ctx)."""
-    mha = model._mha
+    and attends under ``masks[window of the layer]`` (S, 1, W, ctx)."""
     s, w = tokens.shape
     m = tables.shape[1]
     B = arenas[0].shape[2]
@@ -473,15 +547,18 @@ def _verify_rows(model, params, tokens, n_cand, tables, arenas, *, store,
                     tables[jnp.arange(s)[:, None], blkcol], 0)   # (S, W)
     off = store % B
 
-    def layer_fn(h, bp, layer, arenas):
-        q, k, v = _block_qkv(model, bp, h)  # (S, H, W, D)
-        q, k = model._rope(q, k, positions)
-        o, arenas = _paged_attention(mha, q, k, v, arenas, layer, blk, off,
-                                     tables, mask)
-        return _finish_block(model, bp, h, o.astype(h.dtype)), arenas
+    valid = jnp.arange(w)[None, :] < n_cand[:, None]
 
-    h, arenas = _scan_layers(params, h, arenas, layer_fn)
-    h = model._layer_norm(params["ln_f"], h)
+    def layer_fn(spec, h, bp, layer, arenas):
+        q, k, v, gate = model.layer_qkv(spec, bp, h, positions)  # (S, H, W, D)
+        o, arenas = _paged_attention(q, k, v, arenas, layer, blk, off,
+                                     tables, masks[spec.window])
+        h, counts = _finish_block(model, spec, bp, h, o.astype(h.dtype), gate,
+                                  token_mask=valid)
+        return h, arenas, counts
+
+    h, arenas, _ = _scan_layers(model, params, h, arenas, layer_fn)
+    h = model._norm(params["ln_f"], h)
     logits = _head_logits(model, params, h)      # (S, W, V)
     return (logits.astype(jnp.float32),) + arenas
 
@@ -524,9 +601,13 @@ def _tree_verify_step_paged(model, params, tokens, pos, n_cand, tables,
     anc_cols = ancm[:, jnp.clip(rel, 0, w - 1)]      # (W, S, ctx)
     mask = ((rel < 0)[:, None, :]
             | (in_tree[:, None, :] & jnp.moveaxis(anc_cols, 0, 1)))
+    if _windows(model) != {None}:
+        raise NotImplementedError(
+            "tree verify stores a node away from its position; a windowed "
+            "layer's mask over such offsets is not written")
     return _verify_rows(model, params, tokens, n_cand, tables,
                         _arenas(k_arena, v_arena, k_scale, v_scale),
-                        store=store, rope=rope, mask=mask[:, None])
+                        store=store, rope=rope, masks={None: mask[:, None]})
 
 
 def _tree_commit_paged(src, pos, tables, k_arena, v_arena,

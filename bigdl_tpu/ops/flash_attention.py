@@ -37,7 +37,8 @@ _LANES = 128  # m/l scratch is kept lane-replicated for TPU-friendly tiles
 
 
 def _fwd_kernel(*refs, scale: float, causal: bool, segmented: bool,
-                tq_real: int, tk_real: int, block_q: int, block_k: int):
+                tq_real: int, tk_real: int, block_q: int, block_k: int,
+                window: Optional[int] = None):
     if segmented:
         (q_ref, k_ref, v_ref, sq_ref, sk_ref,
          o_ref, lse_ref, acc_ref, m_ref, l_ref) = refs
@@ -61,6 +62,12 @@ def _fwd_kernel(*refs, scale: float, causal: bool, segmented: bool,
     block_live = jnp.logical_and(
         j * block_k < tk_real,                      # not pure key padding
         jnp.logical_or(not causal, j * block_k <= q_end))
+    if window is not None:
+        # the tile's last key is still inside the FIRST query row's window
+        # (the tile is fetched either way; its arithmetic is skipped)
+        block_live = jnp.logical_and(
+            block_live,
+            j * block_k + block_k - 1 > q_end - block_q + 1 - window)
 
     @pl.when(block_live)
     def _():
@@ -78,6 +85,8 @@ def _fwd_kernel(*refs, scale: float, causal: bool, segmented: bool,
             q_pos = iq * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0) + (tk_real - tq_real)
             mask = jnp.logical_and(mask, q_pos >= k_pos)
+            if window is not None:      # sliding: i - j < window
+                mask = jnp.logical_and(mask, q_pos - k_pos < window)
         if segmented:
             # packed-document isolation: a query attends only within its
             # own segment (pad fills -1/-2 can never match)
@@ -161,13 +170,17 @@ def _check_compiled_blocks(block_q: int, block_k: int) -> None:
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "causal", "scale", "block_q", "block_k", "interpret"))
+    "causal", "scale", "block_q", "block_k", "interpret", "window"))
 def _flash_fwd(q, k, v, seg_q, seg_k, causal, scale, block_q, block_k,
-               interpret):
+               interpret, window=None):
+    """``k``/``v`` may hold fewer heads than ``q`` (grouped-query
+    attention): query head i reads K/V head ``i // (H / H_kv)``, through
+    the tiles' index map -- no repeated heads in HBM."""
     if not interpret:
         _check_compiled_blocks(block_q, block_k)
     b, h, tq, d = q.shape
     tk = k.shape[2]
+    group = h // k.shape[1]
     segmented = seg_q is not None
     qp = _pad_t(q, block_q)
     kp = _pad_t(k, block_k)
@@ -177,14 +190,15 @@ def _flash_fwd(q, k, v, seg_q, seg_k, causal, scale, block_q, block_k,
 
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, segmented=segmented,
-        tq_real=tq, tk_real=tk, block_q=block_q, block_k=block_k)
+        tq_real=tq, tk_real=tk, block_q=block_q, block_k=block_k,
+        window=window)
     in_specs = [
         pl.BlockSpec((1, 1, block_q, d),
                      lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
         pl.BlockSpec((1, 1, block_k, d),
-                     lambda bi, hi, qi, ki: (bi, hi, ki, 0)),
+                     lambda bi, hi, qi, ki: (bi, hi // group, ki, 0)),
         pl.BlockSpec((1, 1, block_k, d),
-                     lambda bi, hi, qi, ki: (bi, hi, ki, 0)),
+                     lambda bi, hi, qi, ki: (bi, hi // group, ki, 0)),
     ]
     operands = [qp, kp, vp]
     if segmented:
@@ -600,7 +614,8 @@ def flash_attention(q, k, v, *, causal: bool = False,
                     scale: Optional[float] = None,
                     segment_ids=None,
                     block_q: Optional[int] = None,
-                    block_k: Optional[int] = None):
+                    block_k: Optional[int] = None,
+                    window: Optional[int] = None):
     """Tiled flash attention.  q: (B, H, Tq, D); k, v: (B, H, Tk, D) — D
     should be a multiple of 128 for MXU-aligned tiles (smaller D works at
     reduced efficiency).  Runs the Pallas kernel on TPU, interpreter mode
@@ -617,11 +632,29 @@ def flash_attention(q, k, v, *, causal: bool = False,
     match (on top of causality), so documents packed into one window
     (dataset.text.DocumentPacker) never attend across boundaries.  The
     mask is applied inside the existing tiles: no (T, T) materialization,
-    same VMEM footprint.  Self-attention only (requires Tq == Tk)."""
+    same VMEM footprint.  Self-attention only (requires Tq == Tk).
+
+    ``window`` (with ``causal``): a sliding window, query i sees key j
+    iff ``0 <= i - j < window``, one more term of the tile mask.  ``k``
+    and ``v`` may hold fewer heads than ``q`` (a divisor: grouped-query
+    attention).  Either makes this the FORWARD kernel alone (a prefill;
+    the backward kernels know neither): always the Pallas kernel, and
+    not differentiable."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     if segment_ids is not None and q.shape[-2] != k.shape[-2]:
         raise ValueError("segment_ids requires self-attention (Tq == Tk)")
+    if window is not None or k.shape[1] != q.shape[1]:
+        if window is not None and not causal:
+            raise ValueError("window requires causal=True")
+        if q.shape[1] % k.shape[1]:
+            raise ValueError(f"{q.shape[1]} query heads do not divide over "
+                             f"{k.shape[1]} K/V heads")
+        o, _ = _flash_fwd(q, k, v, segment_ids, segment_ids, causal,
+                          float(scale), int(block_q or 128),
+                          int(block_k or 128), _use_interpret(),
+                          None if window is None else int(window))
+        return o
     plan = resolve_attention_plan(k.shape[-2], q.shape[-1], q.dtype,
                                   causal, block_q=block_q, block_k=block_k)
     if plan.impl == "xla":

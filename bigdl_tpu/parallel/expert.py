@@ -16,12 +16,21 @@ auxiliary loss, two dispatch modes:
   output is zero, the standard Switch behavior).  Per-token expert-FFN
   FLOPs are then independent of the expert count — the scaling story
   expert parallelism exists for.
+
+Serving's routed layer is the second half of this file
+(:func:`routed_experts`, :class:`MoESpec`): top-k dropless routing over
+all experts, the assignments that land on the experts HELD here sorted by
+expert and run as one grouped matmul, a shared expert beside them.  A
+holder is told which experts it has and computes their part of the
+result: the same function is one chip's share of an expert-parallel
+deployment and, under the ``expert`` mesh axis, the per-device body
+before a ``psum``.
 """
 from __future__ import annotations
 
 import math
 from functools import partial
-from typing import Optional
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -182,3 +191,146 @@ def moe_apply(params, x, mesh: Mesh, *, axis: str = EXPERT_AXIS,
     if len(shape) == 3:
         y = y.reshape(shape)
     return y, jnp.mean(aux)
+
+
+# ---------------------------------------------------------------------- #
+# top-k dropless routing over a SHARE of the experts (serving MoE)
+# ---------------------------------------------------------------------- #
+
+class MoESpec(NamedTuple):
+    """A routed expert layer as a model states it: ``n_experts`` routed
+    over (the router's width), ``top_k`` a token, SwiGLU experts of
+    ``width``, an always-on shared expert of ``shared_width`` (0: none),
+    the chosen weights renormalised (``norm_topk``) and multiplied by
+    ``routed_scale``.  ``held = (first, count)`` names the experts THIS
+    holder has (expert parallelism's share; ``None``: all of them): the
+    router still scores all ``n_experts`` and the layer computes the part
+    of the result its own experts give."""
+    n_experts: int
+    top_k: int
+    width: int
+    shared_width: int = 0
+    routed_scale: float = 1.0
+    norm_topk: bool = True
+    held: Optional[Tuple[int, int]] = None
+
+    @property
+    def n_held(self) -> int:
+        return self.n_experts if self.held is None else int(self.held[1])
+
+
+def init_routed_params(rng, spec: MoESpec, d_model: int):
+    """Router over all experts, the held experts' SwiGLU matrices stacked
+    on a leading expert axis, and the shared expert's."""
+    ks = jax.random.split(rng, 7)
+    s_in, s_mid = 1.0 / math.sqrt(d_model), 1.0 / math.sqrt(spec.width)
+    e, f = spec.n_held, spec.width
+    p = {"router": jax.random.normal(ks[0], (d_model, spec.n_experts)) * s_in,
+         "w_gate": jax.random.normal(ks[1], (e, d_model, f)) * s_in,
+         "w_up": jax.random.normal(ks[2], (e, d_model, f)) * s_in,
+         "w_down": jax.random.normal(ks[3], (e, f, d_model)) * s_mid}
+    if spec.shared_width:
+        g = spec.shared_width
+        p["shared"] = {
+            "w_gate": jax.random.normal(ks[4], (d_model, g)) * s_in,
+            "w_up": jax.random.normal(ks[5], (d_model, g)) * s_in,
+            "w_down": jax.random.normal(ks[6], (g, d_model))
+            / math.sqrt(g)}
+    return p
+
+
+def route_top_k(router, x2, spec: MoESpec):
+    """Softmax over ALL experts in f32, the ``top_k`` largest, their
+    weights renormalised and scaled: -> (idx (T, k) int32, w (T, k) f32)."""
+    logits = jnp.dot(x2, router.astype(x2.dtype),
+                     preferred_element_type=jnp.float32)
+    probs = jax.nn.softmax(logits, axis=-1)
+    w, idx = lax.top_k(probs, spec.top_k)
+    if spec.norm_topk:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    return idx, w * spec.routed_scale
+
+
+def swiglu(p, x):
+    """``(silu(x W_gate) * (x W_up)) W_down``: the dense gated MLP, the
+    shared expert, and one expert's function."""
+    from bigdl_tpu.quant.kernels import qmatmul
+    return qmatmul(jax.nn.silu(qmatmul(x, p["w_gate"]))
+                   * qmatmul(x, p["w_up"]), p["w_down"])
+
+
+def grouped_swiglu(params, xs, sizes):
+    """SwiGLU over rows SORTED by expert: rows ``[sum(sizes[:e]),
+    sum(sizes[:e+1]))`` go through expert ``e``'s matrices
+    (``lax.ragged_dot``: one grouped matmul, work proportional to the
+    rows; a native kernel on the TPU).  Rows past ``sum(sizes)`` come
+    back unspecified."""
+    def dot(a, w):
+        return lax.ragged_dot(a, w, sizes,
+                              preferred_element_type=jnp.float32
+                              ).astype(xs.dtype)
+
+    hidden = jax.nn.silu(dot(xs, params["w_gate"])) * dot(xs, params["w_up"])
+    return dot(hidden, params["w_down"])
+
+
+def routed_experts(params, x, spec: MoESpec, *, token_mask=None,
+                   axis: Optional[str] = None):
+    """Top-k dropless routed experts over tokens ``x`` (..., D): route
+    over all ``spec.n_experts``, keep the (token, expert) assignments
+    whose expert is HELD here, sort them by expert, one grouped matmul
+    (:func:`grouped_swiglu`), and add each result back to its token under
+    its routing weight.  No capacity and no dropped token; the work is
+    proportional to the assignments that land here.  What the absent
+    experts would add is left out: the sum over every holder's result is
+    the uncut layer's.  Under ``axis`` (inside ``shard_map`` over the
+    expert mesh axis, ``params`` holding the local slice) the holder is
+    ``axis_index`` and a ``psum`` completes the sum; on one chip the same
+    body runs without that exchange.  ``token_mask`` (...,) bool leaves
+    tokens out of the routing (a decode step's idle slots).
+
+    Returns ``(y, counts)``: ``counts`` int32 (2,) = assignments that
+    landed here, distinct held experts hit."""
+    shape = x.shape
+    x2 = x.reshape(-1, shape[-1])
+    t, k = x2.shape[0], spec.top_k
+    count = params["w_gate"].shape[0]
+    if axis is not None:
+        first = lax.axis_index(axis) * count
+    else:
+        first = 0 if spec.held is None else spec.held[0]
+    with jax.named_scope("moe/route"):
+        idx, w = route_top_k(params["router"], x2, spec)
+        local = idx - first
+        here = (local >= 0) & (local < count)
+        if token_mask is not None:
+            here = here & token_mask.reshape(-1)[:, None]
+        # assignments sorted by held expert; the rest sort behind them
+        key = jnp.where(here, local, count).reshape(-1)        # (T*k,)
+        order = jnp.argsort(key, stable=True)
+        sizes = jnp.zeros((count + 1,), jnp.int32).at[key].add(1)[:count]
+        back = jnp.zeros_like(order).at[order].set(jnp.arange(t * k))
+    with jax.named_scope("moe/experts"):
+        out = grouped_swiglu(params, x2[order // k], sizes)
+        # back to (token, choice) order; an assignment that is not here
+        # carries whatever the grouped matmul left in its row: weight 0
+        out = out[back].reshape(t, k, -1).astype(jnp.float32)
+        y = jnp.sum(jnp.where(here[..., None], out * w[..., None], 0.0),
+                    axis=1).astype(x.dtype)
+    if axis is not None:
+        y = lax.psum(y, axis)
+    counts = jnp.stack([jnp.sum(here), jnp.sum(sizes > 0)]).astype(jnp.int32)
+    return y.reshape(shape), counts
+
+
+def routed_mlp(params, x, spec: MoESpec, *, token_mask=None,
+               axis: Optional[str] = None):
+    """The sparse block's feed-forward half: the held experts' part of the
+    routed sum plus the shared expert (which every holder computes alike
+    and which counts once).  -> (y, counts)."""
+    y, counts = routed_experts(params, x, spec, token_mask=token_mask,
+                               axis=axis)
+    if spec.shared_width:
+        with jax.named_scope("moe/shared"):
+            y = y + swiglu(params["shared"], x)
+    return y, counts

@@ -340,6 +340,13 @@ class LMMetrics:
         self.slot_steps = 0
         self.active_slot_steps = 0
         self.peak_active = 0
+        # routed expert layers (zero for a dense model): (token, expert)
+        # assignments that landed on held experts, distinct held experts
+        # hit summed over layers and rounds, and expert-layer rounds run
+        self.moe_assignments = 0
+        self.moe_experts_hit = 0
+        self.moe_expert_layer_rounds = 0
+        self.moe_prefill_assignments = 0
         self.started_at = time.perf_counter()
         self._window_s = float(throughput_window_s)
         self._recent: deque = deque()  # (t, n_tokens) per decode step
@@ -359,6 +366,12 @@ class LMMetrics:
             registry.register(prefix + key,
                               FnGauge(lambda k=key: getattr(self, k)),
                               replace=True)
+        for key in ("assignments", "experts_hit", "expert_layer_rounds",
+                    "prefill_assignments"):
+            registry.register(
+                prefix + "moe/" + key,
+                FnGauge(lambda k="moe_" + key: getattr(self, k)),
+                replace=True)
         registry.register(prefix + "tokens_per_s",
                           FnGauge(lambda: self.snapshot()["tokens_per_s"]),
                           replace=True)
@@ -410,6 +423,19 @@ class LMMetrics:
     def record_complete(self) -> None:
         with self._lock:
             self.completed += 1
+
+    def record_moe(self, counts, layers: int) -> None:
+        """One decode step's routed-layer integers (summed over its
+        ``layers`` expert layers by the step program)."""
+        with self._lock:
+            self.moe_assignments += int(counts[0])
+            self.moe_experts_hit += int(counts[1])
+            self.moe_expert_layer_rounds += int(layers)
+
+    def record_moe_prefill(self, assignments: int) -> None:
+        """A prompt's assignments that landed on held experts."""
+        with self._lock:
+            self.moe_prefill_assignments += int(assignments)
 
     def reset_rounds(self) -> None:
         """Start the round record afresh (a measured window opens)."""
@@ -484,6 +510,10 @@ class LMMetrics:
                 "tokens": self.tokens,
                 "prefills": self.prefills,
                 "decode_steps": self.decode_steps,
+                "moe": {"assignments": self.moe_assignments,
+                        "experts_hit": self.moe_experts_hit,
+                        "expert_layer_rounds": self.moe_expert_layer_rounds,
+                        "prefill_assignments": self.moe_prefill_assignments},
                 "tokens_per_s": (windowed / span) if span > 0 else 0.0,
                 "slot_occupancy":
                     (self.active_slot_steps / self.slot_steps)
@@ -617,7 +647,8 @@ class _Prefill:
     """An admitted request's in-progress (possibly chunk-interleaved)
     prefill: blocks are allocated, ``p`` tokens are in the arena."""
 
-    __slots__ = ("req", "blocks", "slot", "p", "t", "logits", "handoff")
+    __slots__ = ("req", "blocks", "slot", "p", "t", "logits", "handoff",
+                 "moe")
 
     def __init__(self, req: _Request, blocks: List[int], slot: int,
                  matched_len: int, handoff: Optional[KVHandoff] = None):
@@ -627,6 +658,7 @@ class _Prefill:
         self.p = matched_len            # tokens already in the arena
         self.t = req.prompt0.shape[0]
         self.logits = None
+        self.moe = None                 # routed layers' counts, on the device
         self.handoff = handoff          # set: re-prefill, don't re-emit
 
 
@@ -829,7 +861,9 @@ class LMServingEngine:
         if num_blocks is None:
             # slots worst-case chains + headroom for radix-held prefixes
             num_blocks = 1 + (self.slots + 4) * self.table_width
-        L, H, D = model.n_layers, model._mha.n_head, model._mha.head_dim
+        # the pool's geometry is the K/V heads': a model whose query heads
+        # share them in groups stores (and moves) the shared heads only
+        L, H, D = model.n_layers, model.n_kv_head, model.head_dim
         dt = self._params["embed"].dtype
         self.pool = BlockPool(n_layers=L, n_heads=H, head_dim=D,
                               block_len=self.block_len,
@@ -902,6 +936,12 @@ class LMServingEngine:
                     "kv_quant='int8' requires decode_attn='gather' (the "
                     "Pallas paged kernel reads raw blocks)")
             decode_attn = "gather"
+        elif decode_attn == "auto" and (
+                model.n_kv_head != model.n_head
+                or any(s.window for _, period in model.plan for s in period)):
+            # the Pallas block-table kernel knows neither grouped heads
+            # nor windows yet (ROADMAP M3)
+            decode_attn = "gather"
         elif decode_attn == "auto":
             # the same crossover discipline as flash_attention: the
             # kernel only on tuned evidence for this device kind, the
@@ -935,6 +975,9 @@ class LMServingEngine:
 
         self._decode_jit = jax.jit(_decode_fn, donate_argnums=_donated(4))
         self._decode_exec = None
+        #: routed expert layers of the model: with any, the decode step
+        #: hands their two integers out beside the logits
+        self._moe_layers = model.moe_layers
 
         _insert_donate = ((0, 1, 5, 6) if _kvq else (0, 1))
         self._insert_jit = jax.jit(
@@ -2590,7 +2633,7 @@ class LMServingEngine:
             self._ph_args = {"bucket": bucket, "prompt_len": t,
                              "prefix_len": p}
         if p == 0:
-            logits, k, v = self.prefill_cache(
+            logits, k, v, *moe = self.prefill_cache(
                 self._params, self._buffers,
                 {"ids": ids, "len": np.int32(ts)})
         else:
@@ -2601,8 +2644,10 @@ class LMServingEngine:
             x = {"ids": ids, "len": np.int32(ts),
                  "prefix_len": np.int32(p), "blocks": pblocks,
                  "kv": self.pool.arenas}
-            logits, k, v = self.prefix_prefill_cache(
+            logits, k, v, *moe = self.prefix_prefill_cache(
                 self._params, self._buffers, x)
+        if moe:     # a model with routed expert layers: summed over chunks
+            pf.moe = moe[0] if pf.moe is None else pf.moe + moe[0]
         self._stamp(P_INSERT)
         if _tracer.enabled:
             self._ph_args = {"slot": pf.slot, "bucket": bucket}
@@ -2634,6 +2679,11 @@ class LMServingEngine:
         # where the device wait for the prefill and the insert lands
         self._stamp(P_FIRST_TOKEN)
         logits = np.asarray(pf.logits)  # sync; (1, V) f32
+        if pf.moe is not None:
+            landed = int(np.asarray(pf.moe)[0])
+            self.metrics.record_moe_prefill(landed)
+            if _tracer.enabled:
+                self._ph_args = {"moe_assignments": landed}
         first0 = self._pick(logits[0], req.temperature, req.first_key,
                             clamp=False)
         req.stream._emit(first0 + 1)
@@ -2705,15 +2755,26 @@ class LMServingEngine:
         if not active:
             return
         self._rd_active = len(active)
-        logits, *self.pool.arenas = self._decode_compiled()(
+        logits, *out = self._decode_compiled()(
             self._params, token, pos, tables, *self.pool.arenas)
+        moe = None
+        if self._moe_layers:
+            moe, *out = out
+            moe.copy_to_host_async()    # lands with the logits: one wait
+        self.pool.arenas = out
         self._stamp(P_WAIT)
         logits = np.asarray(logits)  # sync; (S, V) f32
+        if moe is not None:
+            moe = np.asarray(moe)
+            self.metrics.record_moe(moe, self._moe_layers)
         now = self._stamp(P_EMIT)
         if _tracer.enabled:
+            step_args = {"active": len(active), "round": self._rd_index}
+            if moe is not None:
+                step_args.update(moe_assignments=int(moe[0]),
+                                 moe_experts_hit=int(moe[1]))
             _tracer.add_complete("lm/decode_step", t0, now - t0, cat="serve",
-                                 args={"active": len(active),
-                                       "round": self._rd_index})
+                                 args=step_args)
             # per-request view of the shared batched step: one
             # retroactive span per sampled slot, all spanning [t0, now]
             for i, st in active:

@@ -308,6 +308,94 @@ def test_lm_paged_programs_keep_the_arenas_in_place_on_v5e(sds, monkeypatch,
         assert not moved or geom is DEPTH2, moved
 
 
+@pytest.mark.parametrize("program", ["decode", "prefill2048"])
+def test_laguna_cell_compiles_for_v5e_and_keeps_the_arenas_in_place(
+        sds, monkeypatch, program, capsys):
+    """``laguna-cell``, beside ``decode-cell``: the programs of the benchmark's
+    ``laguna_s.steady`` cell at its own geometry (``benchmarks/configs/
+    laguna-s-2.1.json``: 5 layers in two groups of the plan, 128 held experts,
+    32 slots, table width 160, 5,136 blocks of 16 rows of 8 x 128 lanes,
+    bf16), compiled for the described v5e: the decode step with the arenas
+    donated (aliased, no arena-shaped copy, grouped matmuls as the TPU's own
+    custom call) and the 2,048-token prefill through the windowed,
+    grouped-head flash kernel (no (T, T) score tensor).  Prints what the
+    configuration's ``memory_arithmetic`` quotes."""
+    import json
+    from benchmarks.drivers import serve_laguna as D
+    from bigdl_tpu.models.transformer import generate as G
+    from bigdl_tpu.serving.kvcache.blocks import BlockPool
+
+    monkeypatch.setattr(fa, "_use_interpret", lambda: False)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "laguna-s-2.1.json")) as f:
+        c = json.load(f)
+    eng = c["engine"]
+    model = D.build_model(c)
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda: D.program_params(model, 0, c, "bfloat16")))
+    weight_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                       for a in jax.tree_util.tree_leaves(params))
+    # the table of ISSUE 26 (5,572,042,752) and the 11 norm vectors
+    assert weight_bytes == 2 * (5_572_042_752 + 11 * 3072), weight_bytes
+    layers, heads, d = model.n_layers, model.n_kv_head, model.head_dim
+    i32 = lambda *shape: sds(shape, jnp.int32)              # noqa: E731
+    if program == "decode":
+        def arenas_of():
+            pool = BlockPool(n_layers=layers, n_heads=heads, head_dim=d,
+                             block_len=eng["block_len"],
+                             num_blocks=eng["num_blocks"], dtype=jnp.bfloat16)
+            return [pool.k, pool.v]
+
+        arenas = [sds(a.shape, a.dtype) for a in jax.eval_shape(arenas_of)]
+        assert arenas[0].shape == (5, 5136, 16, 1024)       # no lane padding
+        slots, width = eng["slots"], eng["cache_len"] // eng["block_len"]
+
+        def step(p, tok, pos, tables, *kv):
+            return G._decode_step_paged(model, p, tok, pos, tables, *kv,
+                                        attn_impl="gather")
+
+        compiled, text = _compile(
+            step, params, i32(slots), i32(slots), i32(slots, width), *arenas,
+            donate_argnums=(4, 5))
+        logits, counts = compiled.out_info[:2]
+        assert logits.shape == (slots, 50176) and counts.shape == (2,)
+        arena_bytes = 2 * int(np.prod(arenas[0].shape)) * 2
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes >= 0.99 * arena_bytes
+        dims = "bf16[5,5136,16,1024]"
+        assert set(re.findall(re.escape(dims) + r"\{([\d,]+)", text)) == {"3,2,1,0"}
+        moved = [ln.strip()[:160] for ln in text.splitlines()
+                 if dims in ln and re.search(r" copy(-start)?\(|AllocateBuffer", ln)]
+        assert not moved, moved
+    else:
+        def step(p, ids, n):
+            return G._prefill_parts(model, p, ids, n - 1)
+
+        compiled, text = _compile(step, params, i32(1, 2048), i32())
+        logits, k, v, counts = compiled.out_info
+        assert logits.shape == (1, 50176) and counts.shape == (2,)
+        assert k.shape == v.shape == (5, 1, 8, 2048, 128)
+        mem = compiled.memory_analysis()
+        arena_bytes = 0
+        assert "flash_attention_fwd" in text
+        # 72 heads x 2,048 x 2,048 scores: in no dtype, in no layout
+        assert not re.search(r"\[(1,)?(72|48|8,9|8,6),2048,2048\]", text)
+    assert "ragged" in text.lower() or "custom-call" in text
+    total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    with capsys.disabled():
+        print(f"\nlaguna-cell {program}: weights {weight_bytes / 1e9:.3f} GB, "
+              f"arenas {arena_bytes / 1e9:.3f} GB, "
+              f"arguments {mem.argument_size_in_bytes / 1e9:.3f} GB, "
+              f"temporaries {mem.temp_size_in_bytes / 1e9:.3f} GB, "
+              f"outputs {mem.output_size_in_bytes / 1e9:.3f} GB, "
+              f"aliased {mem.alias_size_in_bytes / 1e9:.3f} GB, "
+              f"total {total / 1e9:.3f} GB")
+    assert total < 14.5e9, total
+
+
 def test_lm_flash_remat_train_step_compiles_for_v5e(sds, monkeypatch):
     """A TransformerLM training step with ``attention_impl="flash"``, RoPE
     and remat: the flash forward and both backward kernels inside the
