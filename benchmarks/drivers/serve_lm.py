@@ -1,17 +1,19 @@
 """Driver ``serve_lm``: a GPT-2-shaped ``TransformerLM`` behind
-``LMServingEngine``, under an open-loop arrival schedule.
+``LMServingEngine``, under the mix's load: an open-loop arrival schedule, or a
+closed loop's pool of clients (``"kind": "closed"``).
 
 The calls are the ones ``chip_smoke.py`` proved on the chip (build, engine,
 ``warmup()``, ``submit()``); the sizes come from the configuration file and the
 traffic from the cell's mix.  Token stamps are the client's: one consumer
-thread per stream in flight on ``LMStream.tokens()``.
+thread per stream in flight on ``LMStream.tokens()``; in a closed loop the
+generator's one thread polls every stream in flight (``_Client.poll``).
 
 Mix keys beyond the generator's: ``"follow_s"``: how long after the window
 closes the requests due inside it are still followed; what runs on then is
 cancelled (it was attempted, it has not failed, and its tokens up to then
 count).  A latency cell follows until every first token has come and the
 longest requests are done or nearly; a throughput cell follows for 0 s.
-``"preroll_s"`` (default 0): arrivals start that long before the window
+``"preroll_s"`` (default 0): the load starts that long before the window
 opens, so that a throughput cell's window finds every slot busy and a queue
 behind them; the pre-roll is set-up, its tokens are not counted.
 """
@@ -70,7 +72,7 @@ def build_engine(config: dict, seed: int):
 class _Client:
     """One request as its client sees it."""
 
-    def __init__(self, fired: loadgen.Fired):
+    def __init__(self, fired: loadgen.Fired, polled: bool = False):
         self.arrival = fired.arrival
         self.due_at = fired.due_at
         self.late_s = fired.fired_at - fired.due_at
@@ -80,7 +82,7 @@ class _Client:
         self.generated = None       # 1-based served tokens, set by release()
         self.truncated = False
         self.thread = None
-        if self.stream is not None:
+        if self.stream is not None and not polled:
             self.thread = threading.Thread(target=self._consume, daemon=True)
             self.thread.start()
 
@@ -90,6 +92,27 @@ class _Client:
                 self.stamps.append(time.perf_counter())
         except Exception as e:  # noqa: BLE001 -- the engine's refusal or error
             self.error = repr(e)
+
+    def poll(self, now: float) -> bool:
+        """A closed loop's client: stamp the tokens that have arrived since
+        the last poll; True once the stream has ended.  ``done()`` is read
+        first, so an ended stream's last tokens are stamped; the count is the
+        length of ``LMStream._tokens`` (``generated`` would copy the answer
+        so far under the stream's lock, for every stream, every poll)."""
+        if self.stream is None:
+            return True
+        ended = self.stream.done()
+        self.stamps.extend([now] * (len(self.stream._tokens) - len(self.stamps)))
+        if ended:
+            try:
+                self.stream.result(timeout=0)
+            except Exception as e:  # noqa: BLE001 -- the engine's error
+                self.error = repr(e)
+        return ended
+
+    def cancel(self) -> None:
+        if self.stream is not None and not self.stream.done():
+            self.stream.cancel()
 
     def release(self) -> None:
         """Keep what the client received and let go of the stream (it holds
@@ -176,12 +199,16 @@ def run(bench) -> dict:
     engine = build_engine(config, seed)
     t1 = time.perf_counter()
     _warm(engine, config, np.random.RandomState((seed + 1) % (2 ** 32)))
+    # what imports and set-up left on the heap leaves the collector's sight: a
+    # full collection over it stops every thread for 110-160 ms once a run
+    # (440 ms with the tracer's events), in whatever phase the worker is in
+    gc.collect()
+    gc.freeze()
     bench.out({"setup_phases_s": {"weights_and_engine": t1 - t0,
                                   "compile_or_load_and_warm": time.perf_counter() - t1,
                                   "preroll": float(mix.get("preroll_s", 0.0))}})
     preroll = float(mix.get("preroll_s", 0.0))
-    arrivals = [a._replace(due_s=a.due_s - preroll) for a in loadgen.schedule(
-        mix, seed, bench.seconds + preroll, config["vocab_size"])]
+    closed = mix["kind"] == "closed"
     tracer = get_tracer()
     clients, stop = [], threading.Event()
     if bench.trace:
@@ -192,10 +219,22 @@ def run(bench) -> dict:
         return engine.submit(a.prompt, max_new_tokens=a.max_new, temperature=0.0)
 
     t_open = time.perf_counter() + preroll
-    firing = threading.Thread(
-        target=loadgen.fire, daemon=True,
-        args=(arrivals, submit, t_open, lambda f: clients.append(_Client(f)),
-              stop))
+    if closed:
+        def polled(fired):
+            clients.append(_Client(fired, polled=True))
+            return clients[-1]
+
+        load = (loadgen.closed_loop, mix,
+                loadgen.sequence(mix, seed, config["vocab_size"]))
+        on_fired = polled
+    else:
+        arrivals = [a._replace(due_s=a.due_s - preroll)
+                    for a in loadgen.schedule(mix, seed, bench.seconds + preroll,
+                                              config["vocab_size"])]
+        load = (loadgen.fire, arrivals)
+        on_fired = lambda f: clients.append(_Client(f))     # noqa: E731
+    firing = threading.Thread(target=load[0], daemon=True,
+                              args=load[1:] + (submit, t_open, on_fired, stop))
     firing.start()
     bench.sleep_until(t_open)
     before = _lm_counters(engine)
@@ -204,13 +243,14 @@ def run(bench) -> dict:
     # the window closes where --seconds says, however late this thread woke
     t_close = bench.close_window(at=t_open + bench.seconds)
     after = _lm_counters(engine)
-    firing.join(timeout=30)
+    if closed:
+        stop.set()                      # the loop cancels what is in flight
+    firing.join(timeout=30)             # an open loop's late firings
     stop.set()
     bench.sleep_until(t_close + float(mix["follow_s"]), until=lambda: all(
         c.stream is None or c.stream.done() for c in clients))
     for c in clients:
-        if c.stream is not None and not c.stream.done():
-            c.stream.cancel()
+        c.cancel()
     for c in clients:
         if c.thread is not None:
             c.thread.join(timeout=300)
@@ -228,6 +268,7 @@ def run(bench) -> dict:
     peak = bench.memory_peak_bytes(max(temps, default=0))
     engine.close()
     del engine, firing, submit
+    gc.unfreeze()           # or the engine's cycles would keep its arrays
     gc.collect()
     bench.out({"device_bytes_in_use_after_close": [
         (d.memory_stats() or {}).get("bytes_in_use") for d in bench.devices]})
@@ -257,7 +298,8 @@ def run(bench) -> dict:
                "due_in_first_four_fifths": len(early),
                "of_those_finished_by_close": sum(
                    c.complete and c.stamps[-1] < t_close for c in early)})
-    bench.out({"offered": len(arrivals), "fired": len(everyone),
+    bench.out({"offered": len(everyone) if closed else len(arrivals),
+               "fired": len(everyone),
                "due_in_window": len(clients),
                "finished": sum(c.complete for c in clients),
                "failed": len(failed),
@@ -271,8 +313,21 @@ def run(bench) -> dict:
     # the rate is taken from emission to emission (stats.emission_rate), so
     # that it does not move in steps of one round's 16 tokens; the plain count
     # over --seconds is printed beside it
-    end_to_end = {"out_tokens_per_s": stats.emission_rate(
-        [t for c in everyone for t in c.stamps], t_open, t_close)}
+    stamps = [t for c in everyone for t in c.stamps]
+    end_to_end = {"out_tokens_per_s": stats.emission_rate(stamps, t_open,
+                                                          t_close)}
+    # what the estimator's settle rests on: how long one round's tokens take
+    # to reach their clients, against the gap to the next round's
+    xs, ends, settle = stats.emission_groups(
+        [t for t in stamps if in_window(t)])
+    if len(ends) > 2:
+        first = np.append(0, ends[:-1] + 1)
+        bench.out({"emission_groups": {
+            "n": len(ends), "settle_ms": settle * 1e3,
+            "tokens_median": stats.median(ends + 1 - first),
+            "width_ms": stats.describe((xs[ends] - xs[first]) * 1e3),
+            "gap_to_next_ms": stats.describe(
+                (xs[first[1:]] - xs[ends[:-1]]) * 1e3, q=5.0)}})
     if ttft and itl:
         end_to_end.update(ttft_p95_ms=stats.percentile(ttft, 95),
                           itl_p95_ms=stats.percentile(itl, 95))

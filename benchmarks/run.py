@@ -10,8 +10,9 @@ holds each per-layer metric's reader).  A later PR adds files and one
 ``workloads`` entry; nothing here is edited.
 
 The last line of standard output is the result; earlier lines are JSON notes
-(sample counts and medians, compilations inside the window, every number the
-check compared beside its limit).
+(sample counts and medians, compilations inside the window).  Every number the
+check compared stands beside its limit under the result's last key,
+``compared``, and on the last lines of standard error.
 """
 _T0 = __import__("time").perf_counter()     # process start, for setup_s
 
@@ -256,6 +257,9 @@ def run_cell(root: str, workload: str, seed: int, seconds: float, trace: bool,
     line["metrics"] = {m["name"]: {"value": values[m["name"]], "unit": m["unit"]}
                        for m in wanted if values.get(m["name"]) is not None}
     line["device"] = device
+    # every number the check compared beside its limit: the line's last key
+    line["compared"] = {c["name"]: {"value": c["value"], "limit": c["limit"]}
+                        for c in checks}
     return line
 
 
@@ -270,6 +274,11 @@ def main(argv=None) -> int:
                     bool(args.trace))
     sys.stdout.flush()
     print(json.dumps(line), flush=True)
+    # and as the last lines of standard error
+    for name, c in line["compared"].items():
+        print(f"compared {name}: {c['value']!r} (limit {c['limit']!r})",
+              file=sys.stderr)
+    sys.stderr.flush()
     return 0
 
 

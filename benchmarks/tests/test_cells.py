@@ -25,6 +25,9 @@ def _line(root, cell, seed=2 ** 31 + 17, seconds=2.0, **kw):
                         require_accelerator=False, **kw)
     json.dumps(line)                    # the last line has to serialise
     assert set(line) >= {"correct", "attempted", "failed", "metrics", "device"}
+    assert list(line)[-1] == "compared"
+    assert set(line["compared"]) == {"served_gap_max", "served_gap_mean",
+                                     "compiles_in_window"}
     return line
 
 
@@ -33,6 +36,18 @@ def test_the_command_refuses_to_run_without_an_accelerator(root):
         run.run_cell(root, "toy.steady", 1, 1.0, False)
     with pytest.raises(SystemExit, match="no workload"):
         run.run_cell(root, "toy.absent", 1, 1.0, False)
+
+
+def test_the_command_ends_both_streams_with_the_numbers_compared(
+        monkeypatch, capsys):
+    line = {"correct": False, "attempted": 1, "failed": 0, "metrics": {},
+            "device": {}, "compared": {"served_gap_max": {"value": 0.5,
+                                                          "limit": 0.15}}}
+    monkeypatch.setattr(run, "run_cell", lambda *a, **kw: dict(line))
+    assert run.main(["--workload", "x", "--seed", "1", "--seconds", "1"]) == 0
+    out, err = capsys.readouterr()
+    assert json.loads(out.splitlines()[-1]) == line
+    assert err.splitlines()[-1] == "compared served_gap_max: 0.5 (limit 0.15)"
 
 
 def test_steady_cell_reports_tails_from_the_due_time(root, capsys):
@@ -57,20 +72,23 @@ def test_steady_cell_reports_tails_from_the_due_time(root, capsys):
     assert sizes["ttft_ms"]["median"] > sizes["fire_late_ms"]["median"]
 
 
-def test_saturated_cell_counts_window_tokens_and_cancels_the_rest(root):
-    line = _line(root, "toy.saturated")
+def test_backlog_cell_counts_window_tokens_and_cancels_the_rest(root):
+    line = _line(root, "toy.backlog")
     assert line["correct"] and line["failed"] == 0
     assert set(line["metrics"]) == {"out_tokens_per_s", "setup_s"}
     assert line["metrics"]["out_tokens_per_s"]["value"] > 0
 
 
-@pytest.mark.parametrize("control", sorted(readings.CONTROLS))
-def test_int8_control_comes_out_not_correct(root, control):
-    assert _line(root, "toy.steady",
+@pytest.mark.parametrize("cell,control", [
+    *(("toy.steady", c) for c in sorted(readings.CONTROLS)),
+    ("toy.backlog", "kv8")])
+def test_int8_control_comes_out_not_correct(root, cell, control):
+    assert _line(root, cell,
                  config_update=readings.CONTROLS[control])["correct"] is False
 
 
-def test_altered_token_comes_out_not_correct(root, monkeypatch):
+@pytest.mark.parametrize("cell", ["toy.steady", "toy.backlog"])
+def test_altered_token_comes_out_not_correct(root, monkeypatch, cell):
     """The timed path broken underneath: every eighth token is altered where
     the engine emits it."""
     from bigdl_tpu.serving import lm_engine
@@ -81,7 +99,7 @@ def test_altered_token_comes_out_not_correct(root, monkeypatch):
         real(self, token_1b % 500 + 1 if n["n"] % 8 == 0 else token_1b)
 
     monkeypatch.setattr(lm_engine.LMStream, "_emit", emit)
-    assert _line(root, "toy.steady")["correct"] is False
+    assert _line(root, cell)["correct"] is False
 
 
 def test_a_later_pr_adds_a_metric_by_adding_files(root):

@@ -8,7 +8,7 @@ import json
 import pytest
 
 from benchmarks import run
-from benchmarks.tests import toy_laguna
+from benchmarks.tests import toy, toy_laguna
 
 
 @pytest.fixture(scope="module")
@@ -55,10 +55,7 @@ def test_toy_traced_run_reports_every_metric_the_cpu_can_read(root, monkeypatch)
     device plane, so the profiler is left out and its reduction canned: the
     two device-trace shares find nothing to read and are left out of the
     line; the span and counter metrics are the program's real ones."""
-    monkeypatch.setattr(run.Run, "trace_tick", lambda self: None)
-    monkeypatch.setattr(run.Run, "reduce_trace", lambda self, spans: {
-        "chips": 1, "window_s": 1.0, "busy_s": 0.5, "modules": {},
-        "device_ops": [], "idle_gaps": []})
+    toy.without_profiler(monkeypatch)
     line = run.run_cell(root, "toy_laguna.steady", 2 ** 31 + 31, 2.0, True,
                         require_accelerator=False)
     json.dumps(line)

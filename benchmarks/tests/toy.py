@@ -24,9 +24,25 @@ TOY_GPT2 = {
 TOY_STEADY = {"kind": "poisson", "rate_rps": 10.0, "follow_s": 5,
               "prompt_lens": [8, 16, 24], "prompt_weights": [0.3, 0.4, 0.3],
               "output_lens": [4, 8], "output_weights": [0.5, 0.5]}
-TOY_SATURATED = dict(TOY_STEADY, kind="bursty", rate_rps=60.0, order_seed=7,
-                     follow_s=0, preroll_s=0.5, burst_factor=2, burst_period_s=1.0,
-                     burst_duty=0.4)
+TOY_BACKLOG = {"kind": "closed", "clients": 12, "poll_s": 0.0005,
+               "sequence_len": 60,
+               "order_seed": 7, "follow_s": 0, "preroll_s": 0.5,
+               **{k: TOY_STEADY[k] for k in ("prompt_lens", "prompt_weights",
+                                             "output_lens", "output_weights")}}
+#: the real cells the toy ones stand for; a metric that lists none of them
+#: (a later configuration's) is left out of the toy tree
+RENAME = {"gpt2xl.steady": "toy.steady", "gpt2xl.backlog": "toy.backlog"}
+
+
+def without_profiler(monkeypatch) -> None:
+    """``--trace 1`` on the CPU, which has no device plane: the profiler is
+    left out and its reduction canned; the program's spans are the real ones."""
+    from benchmarks import run
+    monkeypatch.setattr(run.Run, "trace_tick", lambda self: None)
+    monkeypatch.setattr(run.Run, "reduce_trace", lambda self, spans: {
+        "chips": 1, "window_s": 1.0, "busy_s": 0.5, "modules": {},
+        "device_ops": [], "idle_gaps": []})
+
 
 def make_root(tmp: str) -> str:
     """``tmp/BENCHMARK.json`` + ``tmp/benchmarks/`` with the toy cells added."""
@@ -42,19 +58,21 @@ def make_root(tmp: str) -> str:
 
     write("configs/toy-gpt2.json", TOY_GPT2)
     write("traffic/toy.steady.json", TOY_STEADY)
-    write("traffic/toy.saturated.json", TOY_SATURATED)
+    write("traffic/toy.backlog.json", TOY_BACKLOG)
     bench["configs"] = [
         {"name": "toy-gpt2", "source": "toy", "reduced": [], "why": "toy",
          "file": "benchmarks/configs/toy-gpt2.json"}]
     bench["workloads"] = [
         {"name": "toy.steady", "config": "toy-gpt2", "traffic": "steady",
          "chips": 1, "why": "toy"},
-        {"name": "toy.saturated", "config": "toy-gpt2", "traffic": "saturated",
+        {"name": "toy.backlog", "config": "toy-gpt2", "traffic": "backlog",
          "chips": 1, "why": "toy"}]
-    rename = {"gpt2xl.steady": "toy.steady", "gpt2xl.saturated": "toy.saturated"}
-    for m in bench["end_to_end"] + bench["per_layer"]:
-        if "workloads" in m:
-            m["workloads"] = [rename[w] for w in m["workloads"]]
+    for key in ("end_to_end", "per_layer"):
+        for m in bench[key]:
+            if "workloads" in m:
+                m["workloads"] = [RENAME[w] for w in m["workloads"]
+                                  if w in RENAME]
+        bench[key] = [m for m in bench[key] if m.get("workloads", True)]
     with open(os.path.join(tmp, "BENCHMARK.json"), "w") as f:
         json.dump(bench, f)
     return tmp
