@@ -30,7 +30,10 @@ import numpy as np
 from jax import lax
 
 from bigdl_tpu.models.transformer import TransformerLM, window_mask
-from bigdl_tpu.serving.kvcache.blocks import read_chain, write_rows
+from bigdl_tpu.serving.kvcache.blocks import (SCRATCH_BLOCK, head_columns,
+                                              head_lanes, list_chunk,
+                                              read_chain, read_rows,
+                                              table_list, write_rows)
 
 
 def _head_logits(model, params, h):
@@ -210,22 +213,171 @@ def _kv_quantize_rows(x):
     return q, s
 
 
-def _paged_attention(q, k, v, arenas, layer, blk, off, tables, mask):
+def _list_masks(model, live, q_pos, block_len):
+    """The live list's position masks by window: entry ``i`` holds its
+    owner's positions ``where * B + b``, seen by that owner's query rows
+    at ``q_pos`` (S, W) -> {window: (n, W, B) bool}.  A padded entry is
+    owned by nobody (owner S) and seen by nobody."""
+    _, owner, where = live
+    s = q_pos.shape[0]
+    k_pos = where[:, None] * block_len + jnp.arange(block_len)[None, :]
+    q_pos = q_pos[jnp.minimum(owner, s - 1)]                  # (n, W)
+    owned = (owner < s)[:, None, None]
+    return {w: window_mask(q_pos, k_pos, w) & owned for w in _windows(model)}
+
+
+def _pieces(x):
+    """f32 ``x`` as three bfloat16 pieces on a new LEADING axis, summing
+    back to ``x`` to f32 round-off (8 + 8 + 8 mantissa bits): a matmul
+    of bfloat16 pool rows against the pieces has exact products and f32
+    sums at one pass of the matrix unit."""
+    out, rest = [], x
+    for _ in range(3):
+        piece = rest.astype(jnp.bfloat16)
+        out.append(piece)
+        rest = rest - piece.astype(jnp.float32)
+    return jnp.stack(out)
+
+
+def _pool_dot(dot, x, rows, fold=None):
+    """``dot(x, rows)`` of f32 ``x`` against pool ``rows`` with exact
+    products and f32 sums.  f32 rows run at the highest precision;
+    bfloat16 rows (and int8 ones, which bfloat16 holds exactly) meet
+    ``x`` in three bfloat16 pieces, on a leading axis or where ``fold``
+    puts them for ``dot`` (more columns); -> (out, pieces), the pieces
+    still to be added up by the caller, where they were put."""
+    if rows.dtype == jnp.float32:
+        return dot(x, rows, precision=lax.Precision.HIGHEST), 1
+    split = _pieces(x)
+    return dot(split if fold is None else fold(split),
+               rows.astype(jnp.bfloat16),
+               preferred_element_type=jnp.float32), 3
+
+
+def _attend_all_pairs(q, kg, vg, mask, scales):
+    """Attention over a live list when a K/V head has ONE query vector a
+    slot (plain decode, heads not grouped): a slot's rows against its
+    query would be a matrix-vector product a head, which fills nothing
+    of the matrix unit, so every slot's query meets EVERY listed
+    position, a head at a time (``(S, D) x (D, P)``), and the mask keeps
+    each slot its own positions: today's softmax over a ``(S, H, P)``
+    score tensor, P the list's positions and not slots x the table.
+    ``q`` (S, H, D) f32, ``kg``/``vg`` (P, H, D) pool rows, ``mask`` (S,
+    P), ``scales`` None or the int8 rows' (P, H) pair.  -> the softmax's
+    three parts over these positions: the maximum (S, H), the sum of
+    ``exp(score - maximum)`` (S, H) and the rows weighted by it (S, H,
+    D)."""
+    scores, _ = _pool_dot(
+        lambda x, rows, **kw: jnp.einsum("...shd,phd->...shp", x, rows, **kw),
+        q, kg)
+    scores = scores.reshape((-1,) + scores.shape[-3:]).sum(0)
+    if scales is not None:
+        scores = scores * scales[0].T[None]
+    scores = scores / jnp.sqrt(jnp.float32(q.shape[-1]))
+    seen = mask[:, None, :]
+    top = jnp.max(jnp.where(seen, scores, -1e30), axis=-1)
+    e = jnp.where(seen, jnp.exp(scores - top[..., None]), 0.0)
+    den = jnp.sum(e, axis=-1)
+    if scales is not None:
+        e = e * scales[1].T[None]
+    o, _ = _pool_dot(
+        lambda x, rows, **kw: jnp.einsum("...shp,phd->...shd", x, rows, **kw),
+        e, vg)
+    return top, den, o.reshape((-1,) + o.shape[-3:]).sum(0)
+
+
+#: a slot's weights against its run of rows: contract the row axis of
+#: (P, C) with that of (P, lanes), ragged by slot -> (S, C, lanes)
+_BY_SLOT = lax.RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(((0,), (0,)), ((), ())),
+    lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
+
+
+def _attend_by_owner(q, k_rows, v_rows, owner, mask, scales):
+    """Attention over a live list when a K/V head has SEVERAL query
+    vectors a slot (grouped heads, or the candidate rows of a verify
+    step): two grouped matmuls (``lax.ragged_dot``: the groups are the
+    owners' runs of rows, the list being sorted by owner) of the rows as
+    they lie.  A slot's queries are columns that are zero outside their
+    K/V head's lanes, so ``rows @ columns`` is every position's score
+    under each of its owner's queries; the softmax is the slot's own
+    over every position its blocks hold (its maximum and its sum taken
+    block by block, then by owner); the weighted V rows come back as
+    ``(columns, lanes)`` a slot, of which each column keeps its head.
+    ``q`` (S, H_kv, G, W, D) f32, ``k_rows``/``v_rows`` (P, lanes),
+    ``mask`` (n, W, B), ``scales`` None or the int8 rows' (P, H_kv) pair.
+    -> the softmax's three parts over these blocks: the maximum (S, H_kv,
+    G, W), the sum of ``exp(score - maximum)`` and the rows weighted by it
+    (.., D)."""
+    s_, n_kv, g, w_, d = q.shape
+    n, B, c = owner.shape[0], mask.shape[2], g * w_
+    q = q.reshape(s_, n_kv, c, d)
+    own = jnp.minimum(owner, s_ - 1)
+    mine = owner[None, :] == jnp.arange(s_)[:, None]          # (S, n)
+    sizes = B * jnp.sum(mine, axis=1, dtype=jnp.int32)        # rows a slot
+    columns = lambda p: jnp.concatenate(list(p), axis=-1)    # noqa: E731
+    scores, pieces = _pool_dot(
+        lambda x, rows, **kw: lax.ragged_dot(rows, x, sizes, **kw),
+        head_columns(q, k_rows.shape[1]), k_rows, columns)
+    # the pieces added up BEFORE the rows are split into blocks: summed as
+    # (n, B, pieces, H_kv, C) the TPU compiler lays the scores out with B
+    # in the lanes and the step reads a third longer (PERF.md, PR 29)
+    scores = scores.reshape(n * B, pieces, n_kv * c).sum(1).reshape(
+        n, B, n_kv, c)
+    if scales is not None:          # an int8 row's scale, per (position, head)
+        scores = scores * scales[0].reshape(n, B, n_kv, 1)
+    scores = scores / jnp.sqrt(jnp.float32(d))
+    seen = jnp.broadcast_to(mask.transpose(0, 2, 1)[:, :, None, None, :],
+                            (n, B, n_kv, g, w_)).reshape(scores.shape)
+    scores = jnp.where(seen, scores, -1e30)
+    top = jnp.max(jnp.where(mine[..., None, None],
+                            jnp.max(scores, axis=1)[None], -1e30), axis=1)
+    e = jnp.where(seen, jnp.exp(scores - top[own][:, None]), 0.0)
+    den = jnp.einsum("sn,nkc->skc", mine.astype(jnp.float32),
+                     jnp.sum(e, axis=1), precision=lax.Precision.HIGHEST)
+    if scales is not None:
+        e = e * scales[1].reshape(n, B, n_kv, 1)
+    o, pieces = _pool_dot(
+        lambda x, rows, **kw: lax.ragged_dot_general(x, rows, sizes, _BY_SLOT,
+                                                     **kw),
+        e.reshape(n * B, n_kv * c), v_rows, columns)  # (S, pieces * H_kv * C, lanes)
+    # each column's own head first (an eighth, a 25th of the lanes), then
+    # the pieces added up
+    o = head_lanes(o.reshape(s_ * pieces, n_kv * c, -1), n_kv, d)
+    o = jnp.sum(o.reshape((s_, pieces) + o.shape[1:]), axis=1)
+    part = (s_, n_kv, g, w_)
+    return top.reshape(part), den.reshape(part), o.reshape(part + (d,))
+
+
+def _paged_attention(q, k, v, arenas, layer, blk, off, live, mask):
     """One layer's cached attention over PAGED arenas, shared by the
     decode, verify and tree-verify steps: write the W new rows of each
     slot (``k``/``v`` (S, H_kv, W, D), row j at ``(blk, off)[s, j]``)
-    into ``arenas[..][layer]``, then attend ``q`` (S, H, W, D) over each
-    slot's chain under ``mask`` (S, 1, W, ctx); query head i reads K/V
-    head ``i // (H / H_kv)``.  ``arenas`` is ``(k, v)``
+    into ``arenas[..][layer]``, then attend ``q`` (S, H, W, D) over the
+    blocks of the live list ``live`` (3, n) -- which block, whose, where
+    in the chain, sorted by owner (``serving.kvcache.blocks``) -- under
+    ``mask`` (n, W, B): the owner's query rows against the block's
+    positions; query head i reads K/V head ``i // (H / H_kv)``.  The list
+    is walked a chunk at a time (``list_chunk``) by a loop that stops
+    after the last chunk holding a listed block, so ONLY listed blocks
+    (to within a chunk) are gathered, block index major, and nothing of
+    them is copied to f32 or re-laid out.  How a chunk's rows meet the
+    queries follows from how many query vectors a slot has for one K/V
+    head: one (:func:`_attend_all_pairs`) or several
+    (:func:`_attend_by_owner`); either hands back the three parts of a
+    softmax over the chunk, and the chunks' parts are one softmax once
+    each is rescaled to the larger maximum.  ``arenas`` is ``(k, v)``
     or, for an int8 pool, ``(k, v, k_scale, v_scale)``: rows are
-    quantized per (position, head) on the way in and the gather
-    dequantizes in flight.  The layout is the pool's
-    (``serving.kvcache.blocks``); scores and softmax are f32.  Returns
-    (o (S, H, W, D) f32, arenas')."""
+    quantized per (position, head) on the way in and rescaled in
+    flight.  Products are exact and scores, softmax and sums f32
+    (:func:`_pool_dot`), so what a whole table gave differs by the order
+    of the f32 sums only.
+    Returns (o (S, H, W, D) f32, arenas')."""
     n_kv, d = k.shape[1], k.shape[3]
-    block = (arenas[0].shape[2], n_kv, d)                     # (B, H_kv, D)
+    B = arenas[0].shape[2]
     k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)   # (S, W, H, D)
-    if len(arenas) == 4:
+    quant = len(arenas) == 4
+    if quant:
         ka, va, ksa, vsa = arenas
         k, ksr = _kv_quantize_rows(k)
         v, vsr = _kv_quantize_rows(v)
@@ -235,30 +387,51 @@ def _paged_attention(q, k, v, arenas, layer, blk, off, tables, mask):
         ka, va = arenas
     ka = write_rows(ka, layer, blk, off, k)
     va = write_rows(va, layer, blk, off, v)
-    # gather-by-table: the gathered axis IS the position, (S, ctx, H, D)
-    kg = read_chain(ka, layer, tables, block).astype(jnp.float32)
-    vg = read_chain(va, layer, tables, block).astype(jnp.float32)
-    if len(arenas) == 4:            # dequant inside the gather
-        kg = kg * read_chain(ksa, layer, tables, block[:2])[..., None]
-        vg = vg * read_chain(vsa, layer, tables, block[:2])[..., None]
-        arenas = (ka, va, ksa, vsa)
-    else:
-        arenas = (ka, va)
-    if q.shape[1] != n_kv:
-        # grouped heads: (S, H_kv, G, W, D) against the K/V heads as stored
-        s_, h_, w_ = q.shape[:3]
-        qg = q.astype(jnp.float32).reshape(s_, n_kv, h_ // n_kv, w_, d)
-        scores = jnp.einsum("bngqd,bknd->bngqk", qg, kg)
-        scores = scores / jnp.sqrt(jnp.float32(d))
-        scores = jnp.where(mask[:, :, None], scores, -1e30)
-        w = jax.nn.softmax(scores, axis=-1)
-        o = jnp.einsum("bngqk,bknd->bngqd", w, vg)
-        return o.reshape(s_, h_, w_, d), arenas
-    scores = jnp.einsum("bhqd,bkhd->bhqk", q.astype(jnp.float32), kg)
-    scores = scores / jnp.sqrt(jnp.float32(d))
-    scores = jnp.where(mask, scores, -1e30)
-    w = jax.nn.softmax(scores, axis=-1)
-    return jnp.einsum("bhqk,bkhd->bhqd", w, vg), arenas
+    arenas = (ka, va, ksa, vsa) if quant else (ka, va)
+    s_, h_, w_ = q.shape[:3]
+    g = h_ // n_kv
+    q = q.astype(jnp.float32).reshape(s_, n_kv, g, w_, d)
+    # the list a chunk at a time: as many chunks as hold a listed block
+    chunk = min(list_chunk(s_, g * w_ > 1), live.shape[1])
+    pad = -live.shape[1] % chunk
+    live = jnp.pad(live, ((0, 0), (0, pad)), constant_values=s_)
+    mask = jnp.pad(mask, ((0, pad), (0, 0), (0, 0)))
+    chunks = (jnp.sum(live[1] < s_) + chunk - 1) // chunk
+
+    def attend(i, parts):
+        ids, owner, _ = lax.dynamic_slice_in_dim(live, i * chunk, chunk, axis=1)
+        seen = lax.dynamic_slice_in_dim(mask, i * chunk, chunk, axis=0)
+        # (P, H_kv): an int8 row's scale a head
+        scales = (tuple(read_chain(a, layer, ids, (B, n_kv))
+                        for a in (ksa, vsa)) if quant else None)
+        if g * w_ == 1:             # one query vector a slot and K/V head
+            block = (B, n_kv, d)
+            mine = jnp.repeat(owner, B)[None, :] == jnp.arange(s_)[:, None]
+            new = _attend_all_pairs(
+                q[:, :, 0, 0], read_chain(ka, layer, ids, block),
+                read_chain(va, layer, ids, block),
+                mine & seen[:, 0].reshape(-1)[None, :], scales)
+            new = tuple(x.reshape(x.shape[:2] + (1, 1) + x.shape[2:])
+                        for x in new)
+        else:
+            new = _attend_by_owner(q, read_rows(ka, layer, ids),
+                                   read_rows(va, layer, ids), owner, seen,
+                                   scales)
+        # one softmax over every chunk: each part rescaled to the larger
+        # maximum (a slot with nothing in a chunk adds exp(-1e30 - ..) = 0)
+        top = jnp.maximum(parts[0], new[0])
+        old, add = jnp.exp(parts[0] - top), jnp.exp(new[0] - top)
+        return (top, parts[1] * old + new[1] * add,
+                parts[2] * old[..., None] + new[2] * add[..., None])
+
+    part = (s_, n_kv, g, w_)
+    _, den, o = lax.fori_loop(
+        0, chunks, attend,
+        (jnp.full(part, -1e30, jnp.float32), jnp.zeros(part, jnp.float32),
+         jnp.zeros(part + (d,), jnp.float32)))
+    # (a slot that owns nothing, an idle one, sums to 0 over 0: unread)
+    o = o / jnp.maximum(den, 1e-30)[..., None]
+    return o.reshape(s_, h_, w_, d), arenas
 
 
 def _scan_layers(model, params, h, arenas, layer_fn):
@@ -404,20 +577,28 @@ def _insert_blocks(k_arena, v_arena, k_new, v_new, block_ids,
     return k_arena, v_arena
 
 
-def _decode_step_paged(model, params, token, pos, tables, k_arena,
+def _decode_step_paged(model, params, token, pos, live, k_arena,
                        v_arena, k_scale=None, v_scale=None, *,
+                       table_width: Optional[int] = None,
                        attn_impl: str = "gather"):
     """One cached decode step over S slots against PAGED caches: same
     contract as :func:`_decode_step_slots`, but each slot's KV lives in
-    pool blocks named by its row of ``tables`` (S, M) int32 — a
-    fixed-shape operand (padded with the scratch block), so this stays
-    ONE AOT executable regardless of sequence lengths.  The new k/v
-    scatter by (block, offset) derived from ``pos``; attention reads
-    each slot's chain under the identical position mask / score math as
-    the slot engine — either by gathering it into a dense
-    (S, M*B, H, D) view (``attn_impl="gather"``, the XLA baseline) or in
-    place via the Pallas block-table kernel (``attn_impl="paged_kernel"``,
-    ``ops.paged_attention`` — same f32 softmax formulation, so streams
+    pool blocks, and the step reads the blocks the round's **live list**
+    names: ``live`` (3, n) int32 -- which block, whose, where in its
+    chain (``serving.kvcache.blocks.live_list``) -- holds what the
+    active slots hold up to their write positions, padded with nobody's
+    entries to a fixed length (the engine's: every entry of every
+    ``table_width``-wide table), so this stays ONE AOT executable
+    regardless of sequence lengths, and attention walks the list a chunk
+    at a time as far as it holds blocks (:func:`_paged_attention`): a
+    full pool reads every chunk, a round of short chains one.  The new
+    k/v scatter by (block, offset) derived from ``pos`` and the list;
+    attention reads each slot's blocks under the identical position mask
+    / score math as the slot engine -- either by gathering the listed
+    blocks (``attn_impl="gather"``, the XLA path) or in place via the
+    Pallas block-table kernel over the tables the list spells
+    (``attn_impl="paged_kernel"``, ``ops.paged_attention``, which needs
+    their ``table_width`` -- same f32 softmax formulation, so streams
     stay token-exact across the two).  The arenas (the pool's layout,
     ``serving.kvcache.blocks``) are donated by the serving engine and
     carried whole through the layer loop (:func:`_scan_layers`).
@@ -433,31 +614,38 @@ def _decode_step_paged(model, params, token, pos, tables, k_arena,
     if k_scale is not None and attn_impl == "paged_kernel":
         raise ValueError("kv_quant='int8' requires decode_attn='gather' "
                          "(the Pallas paged kernel reads raw blocks)")
-    s, m = tables.shape
+    s = token.shape[0]
     B = k_arena.shape[2]
-    ctx = m * B
     h = params["embed"][token][:, None, :]
     if model.pos_encoding == "learned":
         h = h + params["pos"][pos][:, None, :]
     positions = pos[:, None, None]
-    # (S, 1, 1, ctx) by window: a sliding layer reads its whole chain and
-    # sees the last ``window`` positions of it
-    masks = {w: window_mask(pos[:, None], jnp.arange(ctx)[None, :],
-                            w)[:, None]
-             for w in _windows(model)}
+    # (n, 1, B) by window: a sliding layer reads its whole chain and sees
+    # the last ``window`` positions of it
+    masks = _list_masks(model, live, pos[:, None], B)
     if attn_impl == "paged_kernel" and (
             set(masks) != {None} or model.n_kv_head != model.n_head):
         raise ValueError("the Pallas block-table kernel reads one K/V head a "
                          "query head under a causal mask; windows and grouped "
                          "heads need decode_attn='gather'")
-    # an idle slot carries an all-scratch table: its token is routed to no
-    # expert (its other rows are garbage that nothing reads)
-    active = tables[:, 0] != 0
-    # the block holding each slot's write position (idle slots carry an
-    # all-scratch table: their garbage write lands in block 0 and is
-    # never attended); one new row a slot: (S, 1)
-    blk = tables[jnp.arange(s), pos // B][:, None]
+    ids, owner, where = live
+    held = ((owner[None, :] == jnp.arange(s)[:, None])
+            & (ids != SCRATCH_BLOCK)[None, :])
+    # a slot that holds no listed block but scratch padding is idle: its
+    # token is routed to no expert (its other rows are garbage that nothing
+    # reads)
+    active = jnp.any(held, axis=1)
+    # the block holding each slot's write position (an idle slot's garbage
+    # write lands in the scratch block 0 and is never attended); one new
+    # row a slot: (S, 1)
+    blk = jnp.max(jnp.where(held & (where[None, :] == (pos // B)[:, None]),
+                            ids[None, :], 0), axis=1)[:, None]
     off = (pos % B)[:, None]
+    if attn_impl == "paged_kernel":
+        # the kernel walks (S, M) tables: the list spelled out (padded
+        # entries, owned by nobody, drop)
+        tables = jnp.zeros((s, table_width), jnp.int32).at[owner, where].set(
+            ids, mode="drop")
 
     def layer_fn(spec, h, bp, layer, arenas):
         q, k, v, gate = model.layer_qkv(spec, bp, h, positions)  # (S, H, 1, D)
@@ -473,7 +661,7 @@ def _decode_step_paged(model, params, token, pos, tables, k_arena,
             with jax.named_scope("attn/sliding" if spec.window
                                  else "attn/full"):
                 o, arenas = _paged_attention(q, k, v, arenas, layer, blk,
-                                             off, tables, masks[spec.window])
+                                             off, live, masks[spec.window])
         h, counts = _finish_block(model, spec, bp, h, o.astype(h.dtype), gate,
                                   token_mask=active[:, None])
         return h, arenas, counts
@@ -514,20 +702,20 @@ def _verify_step_paged(model, params, tokens, pos, n_cand, tables,
     setting."""
     w = tokens.shape[1]
     abspos = pos[:, None] + jnp.arange(w)[None, :]   # (S, W)
-    ctx = tables.shape[1] * k_arena.shape[2]
-    # row j attends positions <= pos + j: (S, 1, W, ctx)
-    masks = {w_: window_mask(abspos, jnp.arange(ctx)[None, :], w_)[:, None]
-             for w_ in _windows(model)}
-    return _verify_rows(model, params, tokens, n_cand, tables,
+    live = table_list(tables)
+    # row j attends positions <= pos + j: (n, W, B) over the tables' entries
+    masks = _list_masks(model, live, abspos, k_arena.shape[2])
+    return _verify_rows(model, params, tokens, n_cand, tables, live,
                         _arenas(k_arena, v_arena, k_scale, v_scale),
                         store=abspos, rope=abspos, masks=masks)
 
 
-def _verify_rows(model, params, tokens, n_cand, tables, arenas, *, store,
-                 rope, masks):
+def _verify_rows(model, params, tokens, n_cand, tables, live, arenas, *,
+                 store, rope, masks):
     """The body linear and tree verify share: row j of slot s is stored
     at arena offset ``store[s, j]``, rotated at position ``rope[s, j]``
-    and attends under ``masks[window of the layer]`` (S, 1, W, ctx)."""
+    and attends the entries of ``live`` (the tables as a list) under
+    ``masks[window of the layer]`` (n, W, B)."""
     s, w = tokens.shape
     m = tables.shape[1]
     B = arenas[0].shape[2]
@@ -552,7 +740,7 @@ def _verify_rows(model, params, tokens, n_cand, tables, arenas, *, store,
     def layer_fn(spec, h, bp, layer, arenas):
         q, k, v, gate = model.layer_qkv(spec, bp, h, positions)  # (S, H, W, D)
         o, arenas = _paged_attention(q, k, v, arenas, layer, blk, off,
-                                     tables, masks[spec.window])
+                                     live, masks[spec.window])
         h, counts = _finish_block(model, spec, bp, h, o.astype(h.dtype), gate,
                                   token_mask=valid)
         return h, arenas, counts
@@ -589,25 +777,28 @@ def _tree_verify_step_paged(model, params, tokens, pos, n_cand, tables,
     (lower-rung or plain slots riding a wider executable) scatter to the
     scratch block."""
     w = tokens.shape[1]
-    ctx = tables.shape[1] * k_arena.shape[2]
+    B = k_arena.shape[2]
     depths = jnp.asarray(depths, jnp.int32)          # (W,) static
     ancm = jnp.asarray(np.asarray(anc), bool)        # (W, W) static
     store = pos[:, None] + jnp.arange(w)[None, :]    # (S, W) arena offsets
     rope = pos[:, None] + depths[None, :]            # (S, W) true positions
+    live = table_list(tables)
     # node j attends the committed prefix (col < pos) plus the offsets of
-    # its ancestors-or-self (col == pos + i with anc[j, i]): (S, 1, W, ctx)
-    rel = jnp.arange(ctx)[None, :] - pos[:, None]    # (S, ctx)
+    # its ancestors-or-self (col == pos + i with anc[j, i]): (n, W, B), an
+    # entry's columns against its owner's position
+    col = live[2][:, None] * B + jnp.arange(B)[None, :]
+    rel = col - pos[live[1]][:, None]                # (n, B)
     in_tree = (rel >= 0) & (rel < w)
-    anc_cols = ancm[:, jnp.clip(rel, 0, w - 1)]      # (W, S, ctx)
+    anc_cols = ancm[:, jnp.clip(rel, 0, w - 1)]      # (W, n, B)
     mask = ((rel < 0)[:, None, :]
             | (in_tree[:, None, :] & jnp.moveaxis(anc_cols, 0, 1)))
     if _windows(model) != {None}:
         raise NotImplementedError(
             "tree verify stores a node away from its position; a windowed "
             "layer's mask over such offsets is not written")
-    return _verify_rows(model, params, tokens, n_cand, tables,
+    return _verify_rows(model, params, tokens, n_cand, tables, live,
                         _arenas(k_arena, v_arena, k_scale, v_scale),
-                        store=store, rope=rope, masks={None: mask[:, None]})
+                        store=store, rope=rope, masks={None: mask})
 
 
 def _tree_commit_paged(src, pos, tables, k_arena, v_arena,

@@ -32,11 +32,42 @@ the arena every layer; H padded to 128 lanes stays in place at five
 times the bytes).
 
 The device arrays never change shape — prefill scatters rows into
-blocks, decode gathers by a padded int32 block-table operand — so the
-AOT executables of the serving engine survive untouched and donation
-keeps the arena resident.  Everything dynamic (allocation, refcounts,
+blocks, the steps gather blocks by an int32 **live list** — so the AOT
+executables of the serving engine survive untouched and donation keeps
+the arena resident.  Everything dynamic (allocation, refcounts,
 sharing) lives on the host in this class, where it costs nothing per
 token.
+
+**The live list** is what a step reads by: a flat ``(3, n)`` int32
+operand naming, entry by entry, WHICH block, WHOSE (the owning slot) and
+WHERE in that slot's chain (so entry ``i`` holds its owner's positions
+``where * block_len ..``).  A decode round lists only the blocks its
+active slots hold up to their write positions (:func:`live_list`, built
+on the host) and pads the list to the entries of whole tables; the step
+walks it a chunk at a time (:func:`list_chunk`) and stops after the
+last chunk that holds a listed block -- one executable for every length
+-- so the bytes a round gathers follow the SUM of
+the live blocks, not slots x the table width.  Entries are sorted by
+owner; a padded entry comes last, names the scratch block and is owned
+by NOBODY (owner ``S``, one past the slots), so no slot's softmax sees
+it.  The steps that still carry whole ``(S, M)`` tables (verify, tree
+verify) read through the same function with the degenerate list of
+every table entry (:func:`table_list`).
+
+A step attends a list without an f32 copy or a re-layout of what it
+gathered (``generate._paged_attention``).  Where a slot has ONE query
+vector for a K/V head (plain decode, heads not grouped) the listed
+chains are read as one chain (:func:`read_chain`) and every slot's
+query meets every listed position, a head at a time, the mask keeping
+each slot its own.  Where it has several (grouped heads, a verify
+step's candidate rows) the blocks' rows as they lie, ``(n * block_len,
+W)`` (:func:`read_rows`), meet the queries in two grouped matmuls whose
+groups are the owners' runs of rows: a slot's queries become COLUMNS
+that are zero outside their K/V head's lanes (:func:`head_columns`), so
+``rows @ columns`` is every position's score under every head; the
+weighted V rows of a slot come back as ``(columns, W)`` and
+:func:`head_lanes` keeps each column's own head.  Only these functions
+know where a head lies in a row.
 
 Block 0 is reserved as a **scratch** block: padded table entries and
 padded scatter targets point at it, so fixed-shape gathers/scatters
@@ -157,6 +188,79 @@ def read_chain(arena, layer, tables, block):
     # (the same decode round read 103 ms, not 33: PERF.md, PR 25)
     g = g.reshape(g.shape[:-3] + (g.shape[-3] * g.shape[-2], g.shape[-1]))
     return g[..., :int(np.prod(block[1:]))].reshape(g.shape[:-1] + block[1:])
+
+
+def list_chunk(slots: int, grouped: bool = False) -> int:
+    """Blocks of a live list a step attends at a time: four a slot, or
+    sixteen where a chunk costs two ``grouped`` matmuls (each a custom
+    call of some 0.1 ms whatever it multiplies: PERF.md, PR 29).  A step
+    loops over as many chunks as hold a listed block, so what a round
+    gathers follows its live blocks to within a chunk, in ONE executable
+    for every length a list can have."""
+    return (16 if grouped else 4) * int(slots)
+
+
+def live_list(chains, length: int, slots: int):
+    """The ``(3, length)`` int32 live list of one round: ``chains`` is
+    ``[(slot, block ids)]`` by ascending slot, each slot's blocks in
+    chain order and only the ones the round reads; padded with scratch
+    entries that nobody owns (module docstring)."""
+    import numpy as np
+
+    out = np.zeros((3, length), np.int32)
+    out[1] = slots
+    at = 0
+    for slot, blocks in chains:
+        n = len(blocks)
+        out[0, at:at + n] = blocks
+        out[1, at:at + n] = slot
+        out[2, at:at + n] = np.arange(n)
+        at += n
+    return out
+
+
+def table_list(tables):
+    """Whole tables ``(S, M)`` as the degenerate live list ``(3, S * M)``:
+    every entry of every slot, scratch padding and all (the position
+    mask hides what a slot does not hold, as it always did)."""
+    import jax.numpy as jnp
+
+    s, m = tables.shape
+    return jnp.stack([tables.reshape(-1),
+                      jnp.repeat(jnp.arange(s, dtype=tables.dtype), m),
+                      jnp.tile(jnp.arange(m, dtype=tables.dtype), s)])
+
+
+def read_rows(arena, layer, ids):
+    """The listed blocks' position rows as they lie in a data arena,
+    block index major: ``ids`` (n,) -> ``(n * block_len, W)``."""
+    g = arena[layer, ids]
+    return g.reshape((g.shape[0] * g.shape[1], g.shape[2]))
+
+
+def head_columns(q, width: int):
+    """Queries as columns over a position row's lanes: ``q`` (S, H_kv, C,
+    D), the C query vectors that read K/V head ``k`` -> ``(S, width,
+    H_kv * C)``, column ``(k, c)`` holding ``q[s, k, c]`` at head k's D
+    lanes and zeros elsewhere (the lane padding too)."""
+    import jax.numpy as jnp
+
+    s, k, c, d = q.shape
+    own = jnp.eye(k, dtype=q.dtype)[None, :, None, :, None]
+    cols = q.transpose(0, 1, 3, 2)[:, :, :, None, :] * own   # (S, k, D, k', C)
+    return jnp.pad(cols.reshape(s, k * d, k * c),
+                   ((0, 0), (0, width - k * d), (0, 0)))
+
+
+def head_lanes(x, n_kv: int, head_dim: int):
+    """The inverse read: ``x`` (S, H_kv * C, W), one row of lanes a
+    column -> ``(S, H_kv, C, D)``, column ``(k, c)``'s head-k lanes."""
+    import jax.numpy as jnp
+
+    s, kc, _ = x.shape
+    x = x[..., :n_kv * head_dim].reshape(s, n_kv, kc // n_kv, n_kv, head_dim)
+    own = jnp.eye(n_kv, dtype=x.dtype)[None, :, None, :, None]
+    return jnp.sum(x * own, axis=3)
 
 
 class PoolExhausted(RuntimeError):

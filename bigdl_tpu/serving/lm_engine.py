@@ -22,10 +22,19 @@ paged block arena of :mod:`bigdl_tpu.serving.kvcache`:
   :mod:`bigdl_tpu.serving.kvcache.blocks`), donated so insert rewrites
   the resident buffers in place.
 - **decode** — ONE fixed-shape executable stepping all S slots, each at
-  its own position, taking a padded int32 **block-table** operand
-  (S, M) (padded entries name the scratch block) — paging changes the
-  operand, not the executable count — with ``donate_argnums`` on both
-  arenas so the decode loop never copies HBM-resident state.
+  its own position, reading the blocks a **live list** names: each
+  round ``_step`` lists, on the host, the blocks its active slots hold
+  up to their write positions (which block, whose, where in the chain:
+  :func:`~bigdl_tpu.serving.kvcache.blocks.live_list`), padded to the
+  ``S x M`` entries of whole tables; the step attends the list a chunk
+  at a time (:func:`~bigdl_tpu.serving.kvcache.blocks.list_chunk`) and
+  stops after the last chunk that holds a listed block, so the bytes a
+  round gathers follow what the slots hold, not slots x the table
+  width — paging and chain lengths change the operand, not the
+  executable count — with ``donate_argnums`` on both arenas so the
+  decode loop never copies HBM-resident state.
+  ``LMMetrics.live_blocks`` / ``gathered_blocks`` count the blocks
+  listed and the chunks' blocks gathered.
 
 Sharing: the radix cache maps token prefixes to refcounted block
 chains, so concurrent requests with a common head attend the SAME
@@ -85,6 +94,7 @@ from bigdl_tpu.serving.batcher import (ServingClosed, ServingQueueFull,
 from bigdl_tpu.serving.compile_cache import CompileCache
 from bigdl_tpu.serving.kvcache import (BlockPool, PoolExhausted, RadixCache,
                                        RequestExceedsPool)
+from bigdl_tpu.serving.kvcache.blocks import list_chunk, live_list
 from bigdl_tpu.utils.engine import configure_compile_cache
 
 _tracer = get_tracer()
@@ -337,6 +347,11 @@ class LMMetrics:
         self.tokens = 0
         self.prefills = 0
         self.decode_steps = 0
+        # the decode rounds' live lists: blocks the active slots held, and
+        # blocks of the chunks that held them (what a round gathers);
+        # ``decode_steps`` x slots x table width is what whole tables hold
+        self.live_blocks = 0
+        self.gathered_blocks = 0
         self.slot_steps = 0
         self.active_slot_steps = 0
         self.peak_active = 0
@@ -362,7 +377,8 @@ class LMMetrics:
         registry.register(prefix + "itl_prefill_gap", self.itl_prefill_gap,
                           replace=True)
         for key in ("requests", "rejected", "completed", "tokens",
-                    "prefills", "decode_steps"):
+                    "prefills", "decode_steps", "live_blocks",
+                    "gathered_blocks"):
             registry.register(prefix + key,
                               FnGauge(lambda k=key: getattr(self, k)),
                               replace=True)
@@ -402,10 +418,13 @@ class LMMetrics:
             self._recent.append((time.perf_counter(), 1))
 
     def record_step(self, n_active: int, itls_s: Sequence[float],
-                    prefill_interrupted: bool = False) -> None:
+                    prefill_interrupted: bool = False, *,
+                    live_blocks: int = 0, gathered_blocks: int = 0) -> None:
         with self._lock:
             now = time.perf_counter()
             self.decode_steps += 1
+            self.live_blocks += live_blocks
+            self.gathered_blocks += gathered_blocks
             self.slot_steps += self.slots
             self.active_slot_steps += n_active
             self.peak_active = max(self.peak_active, n_active)
@@ -510,6 +529,8 @@ class LMMetrics:
                 "tokens": self.tokens,
                 "prefills": self.prefills,
                 "decode_steps": self.decode_steps,
+                "live_blocks": self.live_blocks,
+                "gathered_blocks": self.gathered_blocks,
                 "moe": {"assignments": self.moe_assignments,
                         "experts_hit": self.moe_experts_hit,
                         "expert_layer_rounds": self.moe_expert_layer_rounds,
@@ -693,8 +714,11 @@ class LMServingEngine:
         donate_cache: donate k/v arenas into decode/insert (the no-copy
             hot path); disable only for debugging.
         decode_attn: decode attention over the paged cache —
-            "gather" (dense kc[tables] materialization, the XLA
-            baseline), "paged_kernel" (the in-place Pallas block-table
+            "gather" (the round's live blocks gathered a chunk at a
+            time as they lie and attended by
+            ``generate._paged_attention``, the XLA path; how many
+            chunks follows each round from what the slots hold, never
+            set), "paged_kernel" (the in-place Pallas block-table
             kernel, ``ops.paged_attention``), or "auto" (default): the
             kernel only when the autotune cache has measured it faster
             than the gather ON THIS device kind, the gather otherwise.
@@ -968,10 +992,10 @@ class LMServingEngine:
             return (tuple(range(first, first + _n_kv))
                     if donate_cache else ())
 
-        def _decode_fn(params, token, pos, tables, *kv):
+        def _decode_fn(params, token, pos, live, *kv):
             return _constrain(_decode_step_paged(
-                model, dequantize_entry(params), token, pos, tables,
-                *kv, attn_impl=decode_attn))
+                model, dequantize_entry(params), token, pos, live, *kv,
+                table_width=self.table_width, attn_impl=decode_attn))
 
         self._decode_jit = jax.jit(_decode_fn, donate_argnums=_donated(4))
         self._decode_exec = None
@@ -1278,9 +1302,11 @@ class LMServingEngine:
     # ------------------------------------------------------------------ #
     def warmup(self) -> int:
         """AOT-compile every prefill bucket plus the decode and insert
-        executables before traffic; returns the number of prefill
-        executables compiled.  Warmup never executes on the resident
-        arenas (it lowers against shapes), so it is safe mid-traffic."""
+        executables before traffic (ONE decode executable: it reads a
+        live list of any length up to whole tables); returns the number
+        of prefill executables compiled.  Warmup never executes on the
+        resident arenas (it lowers against shapes), so it is safe
+        mid-traffic."""
         import numpy as _np
 
         inputs = [{"ids": _np.zeros((1, b), _np.int32),
@@ -1356,9 +1382,10 @@ class LMServingEngine:
             sds = jax.ShapeDtypeStruct
             tok = sds((self.slots,), np.int32, **sh)
             pos = sds((self.slots,), np.int32, **sh)
-            tables = sds((self.slots, self.table_width), np.int32, **sh)
+            # the live list at the one length that holds any round's
+            live = sds((3, self.slots * self.table_width), np.int32, **sh)
             self._decode_exec = self._decode_jit.lower(
-                self._params, tok, pos, tables, *self.pool.arenas).compile()
+                self._params, tok, pos, live, *self.pool.arenas).compile()
             self._ledger_exec("decode", f"slots={self.slots}",
                               self._decode_exec)
         return self._decode_exec
@@ -2744,19 +2771,27 @@ class LMServingEngine:
         t0 = self._stamp(P_DISPATCH)
         token = np.zeros((self.slots,), np.int32)
         pos = np.zeros((self.slots,), np.int32)
-        tables = np.zeros((self.slots, self.table_width), np.int32)
-        active = []
+        active, chains, n_live = [], [], 0
         for i, st in enumerate(self._slots):
             if st is not None:
                 active.append((i, st))
                 token[i] = st.last0
                 pos[i] = st.pos_next
-                tables[i] = st.table
+                # what the round reads of the slot's chain: the blocks up
+                # to the one its new row is written to
+                held = st.table[:st.pos_next // self.block_len + 1]
+                chains.append((i, held))
+                n_live += len(held)
         if not active:
             return
         self._rd_active = len(active)
+        live = live_list(chains, self.slots * self.table_width, self.slots)
+        # what the step gathers: the chunks that hold a listed block
+        chunk = list_chunk(self.slots,
+                           self.model.n_head != self.model.n_kv_head)
+        gathered = -(-n_live // chunk) * chunk
         logits, *out = self._decode_compiled()(
-            self._params, token, pos, tables, *self.pool.arenas)
+            self._params, token, pos, live, *self.pool.arenas)
         moe = None
         if self._moe_layers:
             moe, *out = out
@@ -2769,7 +2804,8 @@ class LMServingEngine:
             self.metrics.record_moe(moe, self._moe_layers)
         now = self._stamp(P_EMIT)
         if _tracer.enabled:
-            step_args = {"active": len(active), "round": self._rd_index}
+            step_args = {"active": len(active), "round": self._rd_index,
+                         "live_blocks": n_live, "gather_blocks": gathered}
             if moe is not None:
                 step_args.update(moe_assignments=int(moe[0]),
                                  moe_experts_hit=int(moe[1]))
@@ -2818,7 +2854,8 @@ class LMServingEngine:
                 self.metrics.record_complete()
                 freed.append(i)
         self.metrics.record_step(len(active), itls,
-                                 prefill_interrupted=self._prefill_since_step)
+                                 prefill_interrupted=self._prefill_since_step,
+                                 live_blocks=n_live, gathered_blocks=gathered)
         self._prefill_since_step = False
         if freed:
             with self._cv:

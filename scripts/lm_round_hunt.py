@@ -2,12 +2,14 @@
 of a benchmark cell.  Not part of the benchmark: it calls
 ``benchmarks.run.run_cell`` and reads the engine from outside.
 
-    python3 scripts/lm_round_hunt.py --cell gpt2xl.saturated --seeds <n> [<n> ...]
+    python3 scripts/lm_round_hunt.py --cell gpt2xl.backlog --seeds <n> [<n> ...]
         one untraced run a seed, in this process; after each, one JSON line
         with the result line's metrics and the engine's stats()["rounds"]
         over the measured window (reset where the window opens, read where it
         closes): seconds by phase, the running median, the longest round with
-        its own phase split -- the stall hunt
+        its own phase split -- the stall hunt; and, under "lists", what the
+        decode rounds' live lists held over the window (blocks listed,
+        blocks gathered, the entries of whole tables)
     ... --tracer-on     the same with the span tracer enabled for the whole run
                         (not the profiler): what tracing costs end to end
     ... --trace         runs with --trace 1 instead; beside each result line,
@@ -53,14 +55,31 @@ def _watch_engines():
         return [e for e in (r() for r in engines)
                 if e is not None and hasattr(e, "rounds_stats")]
 
+    def lists(e):
+        m = e.metrics
+        return [m.decode_steps, getattr(m, "live_blocks", None),
+                getattr(m, "gathered_blocks", None)]
+
     def watched_open(self, at=None):
         for e in live():
             e.metrics.reset_rounds()
+        seen["_lists"] = [lists(e) for e in live()]
         return open_window(self, at)
 
     def watched_close(self, at=None):
         t = close_window(self, at)
         seen["rounds"] = [e.rounds_stats() for e in live()]
+        # the decode rounds' live lists over the window: blocks listed,
+        # blocks gathered (the rungs), and what whole tables would hold
+        seen["lists"] = []
+        for e, before in zip(live(), seen.pop("_lists", [])):
+            steps, listed, gathered = (
+                None if b is None else a - b
+                for a, b in zip(lists(e), before))
+            seen["lists"].append({
+                "decode_steps": steps, "live_blocks": listed,
+                "gathered_blocks": gathered,
+                "table_entries": steps * e.slots * e.table_width})
         del engines[:]
         return t
 
@@ -279,7 +298,7 @@ def main(argv) -> int:
         row = {"tag": a.tag, "cell": a.cell, "seed": seed, "blocks": a.blocks,
                "trace": int(a.trace), "tracer_on": a.tracer_on,
                "run_s": time.perf_counter() - t0, "line": line,
-               "rounds": seen.get("rounds")}
+               "rounds": seen.get("rounds"), "lists": seen.get("lists")}
         stall = _new_stall(stalls_before)
         if stall is not None:
             row["watchdog_event"] = stall

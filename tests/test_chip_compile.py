@@ -234,9 +234,13 @@ def test_lm_paged_programs_keep_the_arenas_in_place_on_v5e(sds, monkeypatch,
     temporaries stay under 0.5 GB (5.70 GB before PR 25, when every layer
     re-laid the pool out), the compiler keeps the arenas' block index MAJOR
     (row-major ``{3,2,1,0}``: a block is contiguous), and the compiled text
-    holds no ``copy`` and no ``AllocateBuffer`` of an arena's shape."""
+    holds no ``copy`` and no ``AllocateBuffer`` of an arena's shape.  The
+    decode step takes a live list as long as whole tables (16 x 64 entries)
+    and walks it a chunk of 64 blocks at a time: the gathered chunk stays as
+    it lies, and no tensor of the whole tables' chain shape exists in any
+    dtype or layout."""
     from bigdl_tpu.models.transformer import generate as G
-    from bigdl_tpu.serving.kvcache.blocks import BlockPool
+    from bigdl_tpu.serving.kvcache.blocks import BlockPool, list_chunk
 
     # the step asks jax.default_backend(), which is the CPU here: steer
     # it from the test, not through an option of the program
@@ -261,11 +265,11 @@ def test_lm_paged_programs_keep_the_arenas_in_place_on_v5e(sds, monkeypatch,
     if program == "decode":
         impl = "paged_kernel" if arg == "paged_kernel" else "gather"
 
-        def step(p, tok, pos, tables, *kv):
-            return G._decode_step_paged(model, p, tok, pos, tables, *kv,
-                                        attn_impl=impl)
+        def step(p, tok, pos, live, *kv):
+            return G._decode_step_paged(model, p, tok, pos, live, *kv,
+                                        table_width=width, attn_impl=impl)
 
-        args = (params, i32(slots), i32(slots), i32(slots, width))
+        args = (params, i32(slots), i32(slots), i32(3, slots * width))
     elif program == "verify":
         def step(p, tok, pos, n_cand, tables, *kv):
             return G._verify_step_paged(model, p, tok, pos, n_cand, tables,
@@ -281,18 +285,35 @@ def test_lm_paged_programs_keep_the_arenas_in_place_on_v5e(sds, monkeypatch,
         args = (chunk, chunk, i32(arg // blk))
     donate = tuple(range(len(args), len(args) + len(arenas)))
     compiled, text = _compile(step, *args, *arenas, donate_argnums=donate)
-    assert ("tpu_custom_call" in text) == (arg == "paged_kernel")
+    # the Pallas kernel is a custom call of its own (the grouped matmuls
+    # of a verify step are the compiler's: "ragged-dot")
+    pallas = [ln for ln in text.splitlines()
+              if 'custom_call_target="tpu_custom_call"' in ln
+              and "ragged" not in ln]
+    assert bool(pallas) == (arg == "paged_kernel")
     mem = compiled.memory_analysis()
     arena_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in arenas)
     assert mem.alias_size_in_bytes >= 0.99 * arena_bytes
     assert mem.temp_size_in_bytes < 0.5e9, mem.temp_size_in_bytes
-    if case == "decode-cell":
-        # the cells' program as measured (33 ms a round): the gathered
-        # chains reach the f32 score math with the POSITIONS in the lanes.
-        # ``read_chain`` cutting the lane padding before it merges (M, B)
-        # compiles to an f32 copy with D = 64 in the lanes: 103 ms
-        chains = set(re.findall(r"f32\[16,1024,25,64\]\{([\d,]+)", text))
-        assert chains == {"1,3,2,0"}, chains
+    if program == "decode" and arg != "paged_kernel":
+        # a chunk of the list (four blocks a slot) is gathered as it lies,
+        # block index major, and reaches the matrix unit in the pool's own
+        # dtype: no f32 copy of it, whole or cut to (.., H, D) (what the
+        # gathered chains cost before the list: 33 ms a round; with D = 64
+        # in the lanes, 103 ms) ...
+        chunk = list_chunk(slots)
+        rows = r"\[%d,16,1664\]" % chunk
+        assert set(re.findall(r"\w+" + rows + r"\{([\d,]+)", text)) == {
+            "2,1,0"}, "gathered blocks re-laid"
+        if geom["dtype"] != "float32":
+            assert not re.search(
+                r"f32(%s|\[%d,(1600|1664|25,64)\])" % (rows, 16 * chunk), text)
+        # ... and nothing has the whole tables' chain shape, slots x 64
+        # blocks x 16 positions, in any dtype, merged or not
+        entries = slots * width
+        assert not re.search(
+            r"\[(%d,%d|%d,%d,16|%d,16|%d),(25,64|1600|1664)\]" % (
+                slots, width * 16, slots, width, entries, entries * 16), text)
     for a in arenas:
         dims = "%s[%s]" % ({"bfloat16": "bf16", "float32": "f32",
                             "int8": "s8"}[a.dtype.name],
@@ -316,8 +337,11 @@ def test_laguna_cell_compiles_for_v5e_and_keeps_the_arenas_in_place(
     laguna-s-2.1.json``: 5 layers in two groups of the plan, 128 held experts,
     32 slots, table width 160, 5,136 blocks of 16 rows of 8 x 128 lanes,
     bf16), compiled for the described v5e: the decode step with the arenas
-    donated (aliased, no arena-shaped copy, grouped matmuls as the TPU's own
-    custom call) and the 2,048-token prefill through the windowed,
+    donated, its live list as long as whole tables and walked a chunk of 512
+    blocks at a time (aliased, no arena-shaped copy, temporaries under 0.5 GB,
+    the gathered chunk as it lies, no tensor of the whole tables' chain
+    shape, grouped matmuls as the TPU's own custom call) and the 2,048-token
+    prefill through the windowed,
     grouped-head flash kernel (no (T, T) score tensor).  Prints what the
     configuration's ``memory_arithmetic`` quotes."""
     import json
@@ -352,13 +376,13 @@ def test_laguna_cell_compiles_for_v5e_and_keeps_the_arenas_in_place(
         assert arenas[0].shape == (5, 5136, 16, 1024)       # no lane padding
         slots, width = eng["slots"], eng["cache_len"] // eng["block_len"]
 
-        def step(p, tok, pos, tables, *kv):
-            return G._decode_step_paged(model, p, tok, pos, tables, *kv,
-                                        attn_impl="gather")
+        def step(p, tok, pos, live, *kv):
+            return G._decode_step_paged(model, p, tok, pos, live, *kv,
+                                        table_width=width, attn_impl="gather")
 
         compiled, text = _compile(
-            step, params, i32(slots), i32(slots), i32(slots, width), *arenas,
-            donate_argnums=(4, 5))
+            step, params, i32(slots), i32(slots), i32(3, slots * width),
+            *arenas, donate_argnums=(4, 5))
         logits, counts = compiled.out_info[:2]
         assert logits.shape == (slots, 50176) and counts.shape == (2,)
         arena_bytes = 2 * int(np.prod(arenas[0].shape)) * 2
@@ -369,6 +393,12 @@ def test_laguna_cell_compiles_for_v5e_and_keeps_the_arenas_in_place(
         moved = [ln.strip()[:160] for ln in text.splitlines()
                  if dims in ln and re.search(r" copy(-start)?\(|AllocateBuffer", ln)]
         assert not moved, moved
+        assert mem.temp_size_in_bytes < 0.5e9, mem.temp_size_in_bytes
+        assert set(re.findall(r"bf16\[512,16,1024\]\{([\d,]+)", text)) == {
+            "2,1,0"}
+        # 32 slots x 160 blocks x 16 positions: in no dtype, merged or not
+        assert not re.search(
+            r"\[(32,2560|32,160,16|5120,16|81920),(8,128|1024)\]", text)
     else:
         def step(p, ids, n):
             return G._prefill_parts(model, p, ids, n - 1)

@@ -119,10 +119,10 @@ def test_decode_step_rejects_unknown_impl():
     with pytest.raises(ValueError, match="attn_impl"):
         _decode_step_paged(m, m.params, jnp.zeros((1,), jnp.int32),
                            jnp.zeros((1,), jnp.int32),
-                           jnp.zeros((1, 2), jnp.int32),
+                           jnp.zeros((3, 2), jnp.int32),
                            jnp.zeros((1, 3, 4, 128)),
                            jnp.zeros((1, 3, 4, 128)),
-                           attn_impl="nope")
+                           table_width=2, attn_impl="nope")
 
 
 # --------------------------------------------------------------------------- #
