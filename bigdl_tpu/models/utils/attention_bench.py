@@ -3,7 +3,9 @@ measured (prove the Pallas kernels on hardware).
 
     python -m bigdl_tpu.models.utils.attention_bench -t 16384
     python -m bigdl_tpu.models.utils.attention_bench \
-        --sweep 2048,8192,16384,32768 --naive --json BENCH_ATTN.json
+        --sweep 2048,8192,16384,32768 --naive --json attn.json
+    python -m bigdl_tpu.models.utils.attention_bench --autotune \
+        --sweep 2048 --naive --useTuned --json attn.json
 
 Prints one JSON line per (impl, T): causal train-step time (fwd+bwd) at
 (B, H, T, D); ``--naive`` also times the O(T^2) XLA attention so the
@@ -12,6 +14,10 @@ flash/XLA speedup into one JSON document for committing.  A config that
 OOMs or fails to compile reports {"error": ...} instead of killing the
 sweep — on a TPU the naive path runs out of HBM orders of magnitude
 before the flash path does; both paths share the bf16 qkv inputs.
+``--autotune`` first runs ``ops.autotune``'s resumable (block_q,
+block_k) sweep over the same lengths into the tuning cache the
+crossover reads (``TUNE_ATTN.json`` or ``BIGDL_TPU_TUNE_CACHE``);
+``--paged`` adds the paged-decode kernel/gather duel.
 """
 from __future__ import annotations
 
@@ -93,18 +99,19 @@ def main(argv=None) -> None:
                    help="also time flash with packed-document segment "
                         "masking (the isolation-overhead arm)")
     p.add_argument("--autotune", action="store_true",
-                   help="sweep flash (block_q, block_k) tiles at -t and "
-                        "report the fastest; grid via --tuneGrid")
-    p.add_argument("--tuneGrid", default="128:128,128:256,128:512,"
-                                          "256:256,256:512,512:512,"
-                                          "128:1024,256:1024",
-                   help="comma list of blockQ:blockK pairs for --autotune")
+                   help="run the (block_q, block_k) sweep into the "
+                        "tuning cache before the sweep below")
+    p.add_argument("--grid", default=None,
+                   help="candidate tiles as 'bq:bk,bq:bk,...' "
+                        "(default: autotune.DEFAULT_GRID)")
+    p.add_argument("--paged", action="store_true",
+                   help="with --autotune, also duel the paged-decode "
+                        "kernel against the dense gather")
     p.add_argument("--useTuned", action="store_true",
                    help="resolve per-T flash blocks from the autotune "
                         "cache (TUNE_ATTN.json winners) instead of "
-                        "--blockQ/--blockK — the BENCH_ATTN regeneration "
-                        "mode, so the headline rows measure the TUNED "
-                        "kernel")
+                        "--blockQ/--blockK, so the rows measure the "
+                        "TUNED kernel")
     p.add_argument("--json", default=None,
                    help="write the full sweep to this path")
     p.add_argument("--require-lens", default=None,
@@ -122,15 +129,20 @@ def main(argv=None) -> None:
 
     Engine.init()  # the platform is JAX_PLATFORMS' (cpu to rehearse)
 
-    if args.autotune:
-        if args.sweep:
-            p.error("--autotune tunes at a single -t; it does not iterate "
-                    "--sweep (run it once per length instead)")
-        _autotune(args)
-        return
-
     seq_lens = ([int(s) for s in args.sweep.split(",")]
                 if args.sweep else [args.seqLen])
+    if args.autotune:
+        from bigdl_tpu.ops import autotune
+        autotune.autotune_attention(
+            seq_lens, head_dim=args.headDim, dtype=args.dtype,
+            causal=True, batch=args.batch, heads=args.heads,
+            iters=args.iters,
+            grid=(autotune.parse_grid(args.grid) if args.grid
+                  else autotune.DEFAULT_GRID),
+            finalize=not args.paged)
+        if args.paged:
+            autotune.autotune_paged_decode(
+                heads=args.heads, head_dim=args.headDim, dtype=args.dtype)
     plat = jax.devices()[0].platform
     # per-T flash tile plan: the CLI blocks, or the autotuned winners
     # (--useTuned; unknown configs fall back to the CLI blocks)
@@ -235,76 +247,6 @@ def _is_capacity_error(row: dict) -> bool:
 
 
 from bigdl_tpu.utils.artifacts import write_artifact as _flush_artifact
-
-
-def _autotune(args) -> None:
-    """Tile-size sweep for the flash kernels at one sequence length.
-
-    The shipped defaults (128, 128) were chosen for VMEM safety, not
-    measured speed; the right tiles are a hardware property (VMEM size,
-    MXU shape) this one command measures the moment a chip answers:
-
-        python -m bigdl_tpu.models.utils.attention_bench --autotune \\
-            -t 16384 --json TUNE_ATTN.json
-
-    Incremental + resumable like the main sweep: killed mid-grid keeps
-    every measured pair; OOM-class pairs record error rows (a too-big
-    tile failing IS the measurement)."""
-    import jax
-
-    plat = jax.devices()[0].platform
-    grid = []
-    for pair in args.tuneGrid.split(","):
-        bq, bk = pair.split(":")
-        grid.append((int(bq), int(bk)))
-    from bigdl_tpu.utils.artifacts import load_resumable_rows
-    prev = load_resumable_rows(
-        args.json,
-        # a tile that OOMs/fails VMEM IS a measurement — reuse it;
-        # transient-looking errors (backend died mid-compile) retry
-        match=lambda old, r: (
-            old.get("platform") == plat
-            and old.get("seq_len") == args.seqLen
-            and old.get("config") == [args.batch, args.heads,
-                                      args.headDim, args.dtype,
-                                      args.iters, bool(args.segmented)]
-            and ("step_s" in r or _is_capacity_error(r))),
-        key=lambda r: (r["block_q"], r["block_k"]))
-    rows = []
-    result = {"metric": "flash_attention_tile_autotune",
-              "platform": plat, "seq_len": args.seqLen,
-              "config": [args.batch, args.heads, args.headDim, args.dtype,
-                         args.iters, bool(args.segmented)],
-              "rows": rows, "complete": False}
-
-    def flush():
-        good = [r for r in rows if "step_s" in r]
-        if good:
-            best = min(good, key=lambda r: r["step_s"])
-            result["best"] = {"block_q": best["block_q"],
-                              "block_k": best["block_k"],
-                              "step_s": best["step_s"]}
-            base = next((r["step_s"] for r in good
-                         if (r["block_q"], r["block_k"]) == (128, 128)),
-                        None)
-            if base is not None:  # no fabricated 1.0 when unmeasured
-                result["best"]["speedup_vs_128x128"] = round(
-                    base / best["step_s"], 3)
-        _flush_artifact(args.json, result)
-
-    for bq, bk in grid:
-        if (bq, bk) in prev:
-            row = dict(prev[(bq, bk)], reused_from_previous_run=True)
-        else:
-            row = bench_one("flash", args.seqLen, args.batch, args.heads,
-                            args.headDim, args.dtype, iters=args.iters,
-                            block_q=bq, block_k=bk,
-                            segmented=args.segmented)
-        rows.append(row)
-        flush()
-        print(json.dumps(row), flush=True)
-    result["complete"] = True
-    flush()
 
 
 def _summarize(rows) -> list:

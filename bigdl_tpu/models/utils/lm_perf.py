@@ -8,7 +8,8 @@ ships for its conv nets).
 
 Prints ONE JSON line: steady-state step time and tokens/sec for a full
 train step (forward + backward + SGD/Adam update) at the given shape,
-with the bf16-compute / f32-master recipe bench.py uses.
+with the bf16-compute / f32-master recipe
+(``Optimizer.set_compute_dtype``).
 """
 from __future__ import annotations
 

@@ -153,7 +153,7 @@ def run_scaling_sweep(model_name: str, per_chip_batch: int, iterations: int,
     size-independent (full params) and reduce-scatter input bytes likewise,
     so wire(N) follows from any compiled footprint.
     ``assume_compute_s`` substitutes a measured real-chip step time for the
-    compute term (e.g. from bench.py) instead of the sweep's own base step.
+    compute term instead of the sweep's own base step.
 
     ``real_devices=True`` (the ``--real-devices`` CLI flag) initialises the
     default accelerator backend and sweeps over the actual chips — the pod
@@ -312,7 +312,7 @@ def main(argv=None) -> None:
                         "compute term instead of the sweep's own base step")
     p.add_argument("--compute-source", default=None,
                    help="provenance label for --assume-compute-s, e.g. "
-                        "'measured (real v5e chip, bench.py r4)'")
+                        "'measured (TPU v5e, PERF.md)'")
     p.add_argument("--json", default=None,
                    help="write the result as JSON to this path")
     args = p.parse_args(argv)

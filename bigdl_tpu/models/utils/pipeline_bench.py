@@ -4,7 +4,7 @@ ImageNet path + MTLabeledBGRImgToBatch.scala:52-80 threaded host decode).
 
     python -m bigdl_tpu.models.utils.pipeline_bench --batch 256 --iters 20
 
-Measures the SAME training step as bench.py twice: (a) synthetic
+Measures the SAME bf16/NHWC ResNet-50 training step twice: (a) synthetic
 device-resident data, (b) fed by the real path — record shards on disk ->
 threaded decode/augment -> bounded Prefetcher -> host->device transfer.
 Emits one JSON line with both numbers and their ratio.

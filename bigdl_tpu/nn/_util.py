@@ -30,9 +30,8 @@ def fold_rng(rng, i: int):
 def cast_f32_leaves(tree, dtype):
     """The mixed-precision param cast (f32 leaves -> compute dtype,
     everything else untouched) — ONE definition shared by
-    ``Optimizer.set_compute_dtype``, ``bench.py`` and the perf
-    harnesses, so the benchmarks measure exactly the recipe training
-    uses."""
+    ``Optimizer.set_compute_dtype`` and the perf harnesses, so they
+    measure exactly the recipe training uses."""
     return jax.tree_util.tree_map(
         lambda a: a.astype(dtype) if a.dtype == jnp.float32 else a, tree)
 

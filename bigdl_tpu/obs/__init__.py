@@ -21,7 +21,7 @@ Six pieces, one spine:
 - :mod:`~bigdl_tpu.obs.timeseries` — TimeSeriesSampler: a background
   thread snapshotting the registry at a fixed interval into bounded
   rings — gauge values, counter deltas, windowed histogram p50/p99 —
-  the time axis the SLO controller, bench.py, and post-mortems read.
+  the time axis the SLO controller and post-mortems read.
 - :mod:`~bigdl_tpu.obs.flight` — FlightRecorder: on a watchdog stall,
   a classified backend-lost, a fault-injector fire, or a shed burst,
   atomically dump ONE correlated bundle (last spans + time-series

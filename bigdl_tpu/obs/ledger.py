@@ -40,7 +40,7 @@ Gauges land in the metric registry under ``obs/ledger/*`` (totals,
 per-subsystem bytes, drift, headroom) and ``obs/xcost/*`` (executable
 count, flops/bytes-accessed/code/temp totals); the full per-entry and
 per-executable tables ride flight bundles (state provider
-``memledger``) and ``bench.py --memprofile``'s ``PROFILE_MEM.json``.
+``memledger``).
 
 The process-wide instance (:func:`get_ledger`) is what the engines
 register into; :func:`set_ledger` swaps it (test injection — a fake

@@ -15,9 +15,9 @@ time axis:
   the interval's observation count.
 
 Consumers: the flight recorder embeds ``window()`` in every incident
-bundle (the time axis around the incident), ``bench.py`` can record a
-load test's trajectory instead of one end-state snapshot, and
-post-mortems read the ring directly.  The ring is bounded
+bundle (the time axis around the incident), a load test can record
+its trajectory instead of one end-state snapshot, and post-mortems
+read the ring directly.  The ring is bounded
 (``capacity`` rows), so a week-long serving process pays a fixed
 memory cost.
 
@@ -186,7 +186,7 @@ class TimeSeriesSampler:
 
 
 #: process-wide sampler slot — None until something (an engine opting
-#: in, bench.py, the flight recorder CLI) installs one; the flight
+#: in, the flight recorder CLI) installs one; the flight
 #: recorder embeds its window when present and degrades to [] when not
 _GLOBAL: Optional[TimeSeriesSampler] = None
 _global_lock = threading.Lock()

@@ -550,8 +550,8 @@ _flash_lse.defvjp(_flash_lse_vjp_fwd, _flash_lse_vjp_bwd)
 #: the crossover XLA's single fused kernel wins (no pallas_call launch
 #: framing, and the (T,T) scores still fit VMEM-friendly fusions); above
 #: it the flash tiles win on HBM traffic and, past ~8-16k, are the only
-#: thing that fits at all.  Override with BIGDL_TPU_FLASH_MIN_T; pin from
-#: BENCH_ATTN.json measurements on the target chip generation.
+#: thing that fits at all.  Override with BIGDL_TPU_FLASH_MIN_T; a tuned
+#: verdict in the autotune cache for this device kind overrides both.
 FLASH_AUTO_MIN_T = int(os.environ.get("BIGDL_TPU_FLASH_MIN_T", "4096"))
 
 

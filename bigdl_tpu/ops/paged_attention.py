@@ -3,8 +3,8 @@
 ``LMServingEngine``'s decode step originally gathered every slot's KV
 blocks into a dense (S, ctx, H, D) view (``read_chain``) before a plain
 einsum attention — correct and fixed-shape, but it materializes and
-copies the whole context window per token step (the ~2x decode tax in
-BENCH_LM_SERVE.json).  This kernel reads the KV blocks IN PLACE: the
+copies the whole context window per token step.  This kernel reads the
+KV blocks IN PLACE: the
 block table is a scalar-prefetch operand, so the BlockSpec index maps
 name the arena block to stream into VMEM per grid step (the vLLM
 paged-attention shape) and nothing dense is ever built.

@@ -21,7 +21,6 @@ Entry points:
 - ``model.quantize("int8", compute="int8")`` — quantized clone (nn.Module)
 - :func:`quantize_params`          — the pytree-level transform + policy
 - ``SpecConfig(drafter_compute="int8")`` — the int8-compute drafter
-- ``bench.py --serve-lm --spec --qcompute`` — resumable BENCH_QCOMPUTE.json
 """
 from bigdl_tpu.quant.qtensor import (QMAX, QTensor, dequantize_array,
                                      is_qtensor, quantize_array)

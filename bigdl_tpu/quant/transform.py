@@ -191,8 +191,7 @@ def quantize_params(params, dtype: str = "int8", *,
     ``module`` (optional) enables owner-aware decisions: native scale
     layouts for Linear/Conv and automatic embedding exclusion.
     ``report`` (optional dict) is filled with byte counts and per-layer
-    max abs dequantization error — the numbers obs gauges and
-    BENCH_QUANT.json publish.
+    max abs dequantization error — the numbers the obs gauges publish.
     """
     policy = policy or QuantPolicy(dtype)
     if policy.dtype != dtype:

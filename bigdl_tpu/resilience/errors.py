@@ -47,8 +47,7 @@ class ServingDeadlineExceeded(ServingOverloaded):
     refused with a typed receipt."""
 
 
-#: Substrings that mark a retryable wobble (same set the bench.py
-#: supervisor restarts a sweep on).  RESOURCE_EXHAUSTED is here on
+#: Substrings that mark a retryable wobble.  RESOURCE_EXHAUSTED is here on
 #: purpose: for transfers the remedy is the chunk-size downshift that
 #: rides the retry path.
 TRANSIENT_MARKERS = (

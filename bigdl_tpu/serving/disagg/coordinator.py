@@ -5,7 +5,7 @@ Prefill and decode have opposite hardware appetites — prefill is one
 big compute-bound matmul per request, decode is a memory-bound gather
 over the KV arena per token — yet a co-located engine interleaves them
 on the same slots, so every large prompt stalls every in-flight decode
-(the ITL spike BENCH_LM_SERVE shows under prefill-heavy load).
+(``serving/lm/itl_prefill_gap`` counts those gaps).
 Disaggregation (DistServe, OSDI'24; Splitwise, ISCA'24) runs the two
 phases on *separate replicas* so the SLOs decouple: TTFT is the
 prefill pool's problem, ITL the decode pool's.
@@ -77,7 +77,7 @@ class DisaggCoordinator:
             when given, every replica acquires a phase-tagged mesh
             slot (``acquire(phase=...)``) and scale-up is refused once
             the device set is full.  Without it replicas share the
-            default device (the CPU test/bench posture).
+            default device (the CPU test posture).
         max_replicas_per_phase: scale-up ceiling per phase when no
             placement policy bounds it.
         migrate_retries / migrate_base_delay_s: ``with_backoff``
@@ -321,8 +321,8 @@ class DisaggCoordinator:
 
     @property
     def metrics(self) -> LMMetrics:
-        """Engine-compat alias (bench stage helpers read
-        ``eng.metrics``): the DECODE pool's metrics — the client-visible
+        """Engine-compat alias (callers written against one engine
+        read ``eng.metrics``): the DECODE pool's metrics — the client-visible
         token cadence (ITL, tokens/sec, completions) lives where decode
         runs; TTFT is client-measured and ``prefill_metrics`` holds the
         server-side view."""

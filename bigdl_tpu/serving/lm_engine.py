@@ -320,7 +320,7 @@ class LMMetrics:
     engine pays for every slot every step regardless).
 
     ITL is split per phase: ``itl`` stays the combined histogram every
-    existing consumer (SLO controller, bench rows) reads, while
+    existing consumer (SLO controller, the benchmark) reads, while
     ``itl_decode`` holds only gaps between back-to-back decode rounds
     and ``itl_prefill_gap`` the gaps a prefill (or a KV-chain adoption)
     interrupted — the head-of-line blocking disaggregation exists to
@@ -711,8 +711,6 @@ class LMServingEngine:
         eos_id: default 1-based stop token; generation also stops at
             ``max_new``.
         max_queue: admission queue bound (``ServingQueueFull`` beyond).
-        donate_cache: donate k/v arenas into decode/insert (the no-copy
-            hot path); disable only for debugging.
         decode_attn: decode attention over the paged cache —
             "gather" (the round's live blocks gathered a chunk at a
             time as they lie and attended by
@@ -782,7 +780,6 @@ class LMServingEngine:
                  eos_id: Optional[int] = None,
                  max_queue: int = 256,
                  max_cache_entries: int = 16,
-                 donate_cache: bool = True,
                  decode_attn: str = "auto",
                  kv_quant: Optional[str] = None,
                  name: str = "lm",
@@ -792,7 +789,6 @@ class LMServingEngine:
                  max_prefill_chunk_tokens: Optional[int] = None,
                  migrate=None,
                  kvtier=None,
-                 honor_lifecycle: bool = True,
                  metrics: Optional[LMMetrics] = None,
                  metrics_prefix: str = "serving/lm/"):
         configure_compile_cache()
@@ -989,8 +985,7 @@ class LMServingEngine:
         _n_kv = len(self.pool.arenas)
 
         def _donated(first):
-            return (tuple(range(first, first + _n_kv))
-                    if donate_cache else ())
+            return tuple(range(first, first + _n_kv))
 
         def _decode_fn(params, token, pos, live, *kv):
             return _constrain(_decode_step_paged(
@@ -1003,10 +998,9 @@ class LMServingEngine:
         #: hands their two integers out beside the logits
         self._moe_layers = model.moe_layers
 
-        _insert_donate = ((0, 1, 5, 6) if _kvq else (0, 1))
         self._insert_jit = jax.jit(
             _insert_blocks,
-            donate_argnums=_insert_donate if donate_cache else ())
+            donate_argnums=(0, 1, 5, 6) if _kvq else (0, 1))
         self._insert_execs: dict = {}
 
         # -- speculation (draft-verify) --------------------------------- #
@@ -1199,10 +1193,6 @@ class LMServingEngine:
         self._abort = False
         self._lc_nudge = False    # a cancel/deadline wants a sweep
         # -- request lifecycle (deadlines / cooperative cancel) ---------- #
-        # honor_lifecycle=False is the bench's ignore-everything
-        # baseline: deadlines and cancels are RECORDED (so wasted
-        # decode work is measurable) but never acted on.
-        self.honor_lifecycle = bool(honor_lifecycle)
         self._lc_lock = threading.Lock()
         self.lifecycle = {
             "expired_preadmission": 0,   # shed before prefill
@@ -1584,8 +1574,7 @@ class LMServingEngine:
         stream = LMStream(prompt, max_new, request_id=rid,
                           deadline_s=deadline_s)
         stream._wake_cb = self._lc_wake
-        if (self.honor_lifecycle and deadline_s is not None
-                and float(deadline_s) <= 0.0):
+        if deadline_s is not None and float(deadline_s) <= 0.0:
             # already dead on arrival: shed synchronously, typed
             self.metrics.record_reject()
             count_rejection()
@@ -1947,8 +1936,6 @@ class LMServingEngine:
         (queued / adoption / resume / hibernated), as a cv-wait bound —
         an idle engine must still wake to shed an expiring hibernated
         stream.  Caller holds ``_cv``; None = no deadline pending."""
-        if not self.honor_lifecycle:
-            return None
         dls = [r.stream.deadline_at for r in self._queue]
         dls += [h.stream.deadline_at for h in self._adopt_q]
         dls += [h.stream.deadline_at for h in self._resume_q]
@@ -2009,8 +1996,6 @@ class LMServingEngine:
         drops straight out of the host tier, no promote transfer.
         Caller holds ``_cv``."""
         self._lc_nudge = False
-        if not self.honor_lifecycle:
-            return
         now = time.monotonic()
 
         def _dead(stream):
@@ -2066,10 +2051,7 @@ class LMServingEngine:
         makes a disconnect storm); then cancelled/expired streams are
         honored same-iteration: slot recycled, blocks released,
         drafter state dropped, stream finished with the typed
-        truncation marker.  With ``honor_lifecycle=False`` (the bench's
-        ignore-everything baseline) nothing is freed — instead every
-        dead seated slot counts one wasted decode slot-step per round,
-        the work this layer exists to shed."""
+        truncation marker."""
         from bigdl_tpu.resilience.faults import fault_point
         with self._cv:
             seated = [st.stream for st in self._slots if st is not None]
@@ -2085,12 +2067,6 @@ class LMServingEngine:
         def _dead(stream):
             return stream.cancel_requested or stream.expired(now)
 
-        if not self.honor_lifecycle:
-            with self._cv:
-                n_dead = sum(1 for st in self._slots
-                             if st is not None and _dead(st.stream))
-            self._lc_count("wasted_decode_steps", n_dead)
-            return
         with self._cv:
             if any(_dead(pf.req.stream) for pf in self._prefilling):
                 live = []
@@ -2110,8 +2086,7 @@ class LMServingEngine:
                     continue
                 s = st.stream
                 # decode steps spent between the cancel landing and
-                # this round honoring it were wasted: count the
-                # residual so the honored arm stays honest too
+                # this round honoring it were wasted: count them
                 if s.cancel_requested:
                     self._lc_count(
                         "wasted_decode_steps",
@@ -3411,7 +3386,6 @@ class LMServingEngine:
             "hibernations": self.hibernations,
             "resumes": self.resumes,
             "resume_re_prefills": self.resume_re_prefills,
-            "honor_lifecycle": self.honor_lifecycle,
             "lifecycle": self.lifecycle_stats(),
             "metrics": self.metrics.snapshot(),
             "rounds": self.rounds_stats(),

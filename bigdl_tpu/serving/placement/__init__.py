@@ -22,10 +22,10 @@ Three layers, smallest first:
 - :class:`PlacementPolicy` — pack replicas onto slots (acquire/release
   with headroom accounting), publish ``serving/placement/*`` gauges.
 
-Everything is proven on CPU with the 8-virtual-device fake mesh
-(``XLA_FLAGS=--xla_force_host_platform_device_count=8``): ``bench.py --serve --mesh`` writes the
-resumable BENCH_MESH.json comparing single-device vs 2-slot x TP2 vs
-1-slot x TP4 against the unsharded oracle.
+Agreement is held on CPU with the 8-virtual-device fake mesh
+(``XLA_FLAGS=--xla_force_host_platform_device_count=8``):
+``tests/test_placement.py`` compares 2-slot x TP2 and 1-slot x TP4
+against the unsharded oracle.  No chip has run a sharded slot.
 """
 from bigdl_tpu.serving.placement.topology import DeviceTopology
 from bigdl_tpu.serving.placement.slicer import (MeshSlice, MeshSlicer,

@@ -63,7 +63,7 @@ class DeviceTopology:
         return len(self.devices)
 
     def describe(self) -> dict:
-        """One serializable snapshot (BENCH_MESH.json embeds it)."""
+        """One serializable snapshot."""
         return {
             "platform": self.platform,
             "device_kind": self.device_kind,

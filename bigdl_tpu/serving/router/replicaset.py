@@ -99,8 +99,7 @@ class LMReplicaSet(ReplicaSetCore):
             output: the bit-exact replay failover depends on this.
         n_replicas: member count (default 2).
         router: a :class:`RadixRouter` for prefix-affinity dispatch, or
-            None for the radix-blind least-loaded baseline (the bench's
-            control arm).  Each member's RadixCache publishes a
+            None for the radix-blind least-loaded baseline.  Each member's RadixCache publishes a
             :class:`RadixSummary` into the router.
         sessions: a :class:`SessionTable` (default: private table) —
             session stickiness runs ahead of affinity scoring.
@@ -593,9 +592,9 @@ class LMReplicaSet(ReplicaSetCore):
 
     # -- introspection / lifecycle ---------------------------------------- #
     def prefix_cache_stats(self) -> dict:
-        """Set-wide radix accounting: the bench's prefix-hit-rate gate
-        reads the SUM over members (per-replica hit rates reward
-        imbalance; the set-level rate is what routing improves)."""
+        """Set-wide radix accounting: the SUM over members (per-replica
+        hit rates reward imbalance; the set-level rate is what routing
+        improves)."""
         lookups = hits = saved = 0
         with self._lock:
             engines = [r.engine for r in self._replicas]
@@ -629,8 +628,8 @@ class LMReplicaSet(ReplicaSetCore):
 
     def lifecycle_stats(self) -> dict:
         """Set-wide lifecycle accounting: the SUM of every member's
-        expired/cancelled/wasted counters (the bench's goodput and
-        zero-loss gates read the set, not a replica)."""
+        expired/cancelled/wasted counters (zero accepted loss is a
+        property of the set, not of a replica)."""
         with self._lock:
             engines = [r.engine for r in self._replicas]
         total: dict = {}

@@ -41,8 +41,8 @@ class RadixRouter:
 
     Args:
         affinity_weight: ``w`` above, in [0, 1] (default 0.7 — affinity
-            dominates until load skew is severe, matching the bench's
-            returning-session regime).
+            dominates until load skew is severe: the returning-session
+            regime).
         min_match_blocks: smallest prefix match (whole blocks) that
             counts as affinity; prompts matching less everywhere are
             cold dispatches (least-loaded fallback).

@@ -12,9 +12,6 @@ Three pieces that close the serving loop the way production does:
 - :mod:`~bigdl_tpu.traffic.chaos` — replay of an incident list (a
   seeded synthetic one, or a recorded ledger) as a seeded fault schedule
   through the existing ``fault_point`` sites, mid-load.
-
-Entry point: ``python bench.py --slo`` sweeps offered load, runs the
-chaos row, and writes the resumable ``BENCH_SLO.json`` goodput curve.
 """
 from bigdl_tpu.traffic.chaos import ChaosReplayer, build_schedule
 from bigdl_tpu.traffic.incidents import (append_incident,

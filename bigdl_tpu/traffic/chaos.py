@@ -22,8 +22,8 @@ Two halves:
   e`` or log line shows precisely what chaos is active) and refuses to
   clobber an operator's explicit spec.
 
-The harness contract asserted on top of this (tests/test_traffic.py,
-bench --slo chaos row): ZERO ACCEPTED-REQUEST LOSS — every request the
+The harness contract asserted on top of this (tests/test_traffic.py):
+ZERO ACCEPTED-REQUEST LOSS — every request the
 server accepted before or during the chaos window completes with exact
 results; only typed sheds at admission are allowed to increase.
 """

@@ -21,7 +21,7 @@ Traces are deterministic given (kind, rate, duration, seed):
 
 Every arrival also carries a prompt (seeded ids) and a generation
 budget drawn from the configured menus — mixed prompt/output lengths
-are what make continuous batching earn its keep (see bench --serve-lm).
+are what continuous batching is for.
 Non-LM callers (ReplicaSet vector serving) just ignore the prompt and
 build their payload from ``arrival.index``.
 """

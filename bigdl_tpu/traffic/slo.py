@@ -37,7 +37,7 @@ convert typed sheds into queue delay for everyone.
 Deliberately sans thread in the core: :meth:`tick` is a pure
 read-decide-actuate step, so tests drive it with a fake clock and
 hand-fed histograms.  :meth:`start`/:meth:`stop` wrap it in a daemon
-thread for bench/production use.
+thread for production use.
 """
 from __future__ import annotations
 

@@ -165,8 +165,8 @@ def configure_compile_cache() -> str:
     touched.  Unset: ``<checkout>/.jax_cache`` — a FIXED, git-ignored
     path (the directory is part of the cache key's lookup, so a path
     with a pid, a time or a temp dir in it never hits).  Every entry
-    point that compiles calls this once (Engine.init, bench.py,
-    chip_smoke.py, the serving engines); it is idempotent."""
+    point that compiles calls this once (Engine.init, chip_smoke.py,
+    the serving engines); it is idempotent."""
     env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env_dir:
         return env_dir

@@ -37,11 +37,10 @@ if [ "${FAULTS_GATE:-1}" = "1" ]; then
     -q -m faults || exit 1
 fi
 
-# Artifact schema lint: committed BENCH_*/TUNE_*/PROFILE_*/TRACE_*/
-# FLIGHT_* files are the evidence chain — a truncated or key-drifted
-# one fails silently downstream (resume identity never matches, regen
-# skips rows, a forensic bundle reads as empty), so it should fail
-# loudly here, in seconds.
+# Artifact schema lint: TUNE_*/TRACE_*/FLIGHT_* files — a truncated or
+# key-drifted one fails silently downstream (resume identity never
+# matches, a forensic bundle reads as empty), so it should fail loudly
+# here, in seconds.
 python scripts/validate_artifact.py || exit 1
 
 # Kernel correctness gate: the attention crossover + paged-decode

@@ -211,26 +211,33 @@ def test_repo_cache_has_cpu_crossover_verdict(monkeypatch):
 
 
 # --------------------------------------------------------------------------- #
-# CLI: bench.py --attn --autotune (subprocess, resumable)                     #
+# CLI: attention_bench --autotune (subprocess, resumable)                     #
 # --------------------------------------------------------------------------- #
 
-@pytest.mark.slow
 def test_bench_attn_cli_resume(tmp_path):
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                BIGDL_TPU_TUNE_CACHE=str(tmp_path / "tune.json"))
     bench_json = str(tmp_path / "attn.json")
-    argv = [sys.executable, os.path.join(REPO, "bench.py"), "--attn",
+    argv = [sys.executable, "-m", "bigdl_tpu.models.utils.attention_bench",
             "--autotune", "--sweep", "32", "--headDim", "8", "--dtype",
             "float32", "--heads", "2", "--iters", "1",
-            "--grid", "8:8,8:16", "--json", bench_json]
+            "--grid", "8:8,8:16", "--naive", "--useTuned",
+            "--json", bench_json]
+    passes = []
     for _ in range(2):
         r = subprocess.run(argv, env=env, cwd=REPO, capture_output=True,
                            text=True, timeout=560)
         assert r.returncode == 0, r.stderr[-2000:]
-    tune = json.load(open(tmp_path / "tune.json"))
+        passes.append((r.stdout, json.load(open(tmp_path / "tune.json"))))
+    tune = passes[1][1]
     assert tune["complete"] is True
-    # second pass re-used every tuning measurement
-    assert all(r.get("reused_from_previous_run") for r in tune["rows"])
+    # second pass re-used every tuning measurement: it logs each row as
+    # reused and leaves the certified cache as the first pass wrote it
+    logged = [ln for ln in passes[1][0].splitlines()
+              if ln.startswith("tune: ")]
+    assert len(logged) == len(tune["rows"]) == 3
+    assert all("'reused_from_previous_run': True" in ln for ln in logged)
+    assert tune == passes[0][1]
     bench = json.load(open(bench_json))
     assert bench["complete"] is True
     impls = {r["impl"] for r in bench["rows"]}

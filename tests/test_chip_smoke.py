@@ -200,7 +200,7 @@ def test_no_private_cache_knob_or_tmp_cache_path_remains():
     """Acceptance grep: no cache path under /tmp, no private knob."""
     import re
     hits = []
-    for root in ("bench.py", "chip_smoke.py", "bigdl_tpu"):
+    for root in ("chip_smoke.py", "bigdl_tpu"):
         path = os.path.join(REPO, root)
         files = ([path] if os.path.isfile(path) else
                  [os.path.join(d, f) for d, _, fs in os.walk(path)
@@ -265,24 +265,22 @@ def test_roofline_attribution_needs_a_known_device():
 
 
 # --------------------------------------------------------------------- #
-# bench.py: no chip, no result                                          #
+# the benchmark: no chip, no result                                     #
 # --------------------------------------------------------------------- #
 
-def test_bench_default_mode_fails_without_a_chip(tmp_path):
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
-    # an earlier measurement lying around must never be printed
-    (tmp_path / "BENCH_LAST.json").write_text(json.dumps(
-        {"metric": "resnet50_imagenet_train_images_per_sec_per_chip",
-         "value": 2000.0, "measured_at_unix": 0}))
+def test_benchmark_command_fails_without_a_chip(tmp_path):
+    """No fallback may hide the device: the one command the ledger's
+    numbers come from refuses a CPU and prints no result line."""
     proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py")], cwd=str(tmp_path),
-        env=dict(env, BIGDL_TPU_BENCH_LAST_PATH=str(
-            tmp_path / "BENCH_LAST.json")),
+        [sys.executable, os.path.join(REPO, "benchmarks", "run.py"),
+         "--workload", "gpt2xl.steady", "--seed", "1", "--seconds", "1",
+         "--trace", "0"], cwd=str(tmp_path),
+        env=dict(os.environ, JAX_PLATFORMS="cpu",
+                 JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache")),
         capture_output=True, text=True, timeout=300)
     assert proc.returncode != 0
-    assert '"value"' not in proc.stdout
-    assert "no result" in proc.stderr
+    assert proc.stdout.strip() == ""
+    assert "needs 1 accelerator chip" in proc.stderr
 
 
 def test_ensure_virtual_devices_hands_back_cpu_devices_only():

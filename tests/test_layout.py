@@ -194,7 +194,7 @@ def test_bench_recipe_lock_tpu_hlo():
     the TPU-lowered StableHLO of the ResNet-50 NHWC bf16 train step must
     keep every convolution's inputs in bf16 (MXU operands) and contain
     NO rank-4 activation transposes (layout churn around convs is the
-    classic NCHW tax bench.py's recipe exists to avoid; the only
+    classic NCHW tax the bf16/NHWC recipe exists to avoid; the only
     transposes allowed are 2-D weight transposes from the classifier
     head's matmul grad).  Runs the real TPU lowering via jax.export on
     the CPU host — no chip needed, so the recipe cannot silently rot

@@ -8,10 +8,6 @@ small-scale token-exactness vs offline ``generate``.  The slow soak
 replays a staggered-arrival, mixed-length workload and asserts
 bit-exact agreement for EVERY request.
 """
-import json
-import os
-import subprocess
-import sys
 import time
 
 import numpy as np
@@ -23,8 +19,6 @@ from bigdl_tpu.serving import (CompileCache, LMServingEngine,
                                ServingClosed, ServingQueueFull,
                                prefill_bucket_lengths)
 from bigdl_tpu.serving.lm_engine import LMMetrics
-
-REPO = os.path.join(os.path.dirname(__file__), os.pardir)
 
 
 def _wait(pred, timeout=30.0):
@@ -373,29 +367,6 @@ def test_soak_continuous_batching_token_exact():
         assert st["metrics"]["slot_occupancy"] > 0.3
     finally:
         eng.close()
-
-
-@pytest.mark.slow
-def test_serve_lm_bench_cli(tmp_path):
-    """bench.py --serve-lm end to end on CPU: resumable artifact with
-    both continuous and static numbers and a final summary."""
-    out = tmp_path / "BENCH_LM_SERVE.json"
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    proc = subprocess.run(
-        [sys.executable, "bench.py", "--serve-lm", "--json", str(out),
-         "--requests", "8", "--slots", "2", "--cache-len", "128",
-         "--mean-gap-ms", "4", "--probes", "1"],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=560)
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    doc = json.loads(out.read_text())
-    assert doc["complete"] is True
-    stages = {r["stage"] for r in doc["rows"]}
-    assert {"warmup", "continuous", "static_baseline"} <= stages
-    s = doc["summary"]
-    assert s["agreement"] == 1.0
-    assert s["tokens_per_s"] > 0 and s["static_tokens_per_s"] > 0
-    last = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert last["metric"] == "lm_serving_continuous_tokens_per_sec"
 
 
 def test_int8_lm_serves_and_generates_exactly(lm_model):

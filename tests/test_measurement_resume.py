@@ -114,54 +114,6 @@ def test_lm_sweep_resumes_and_error_rows_retry(tmp_path):
     assert all("tokens_per_s" in r for r in d["rows"])
 
 
-@pytest.mark.slow
-def test_profile_resume_skips_measured_batches(tmp_path):
-    """Seeded artifact rows short-circuit the expensive subprocess
-    measurements entirely (every batch and every flag preset already
-    has a successful row, so the run must finish without launching a
-    single inner bench — only the CPU attribution pass runs).  slow:
-    the attribution compiles every ResNet-50 layer on CPU; and should
-    resume matching ever regress, the pinned-cpu inner bench fails via
-    the subprocess timeout rather than touching a real backend."""
-    art = tmp_path / "prof.json"
-    sys.path.insert(0, os.path.join(REPO, "scripts"))
-    from tpu_profile_bench import FLAG_PRESETS
-    seed = {
-        "metric": "resnet50_tpu_profile", "complete": False,
-        "inner_platform": "cpu",
-        "measurements": [
-            {"batch": 256, "iters": 20, "images_per_s": 1900.0,
-             "step_s": 0.1347, "mfu": 0.12},
-            {"batch": 512, "iters": 20, "images_per_s": 2100.0,
-             "step_s": 0.2438, "mfu": 0.13}],
-        # resume requires the recorded flag string to match the preset's
-        # CURRENT definition — an edited preset must be re-measured
-        "flag_sweep": [
-            {"preset": p, "batch": 512, "iters": 20,
-             "images_per_s": 2100.0 + i, "step_s": 0.24, "xla_flags": fl}
-            for i, (p, fl) in enumerate(FLAG_PRESETS.items())],
-    }
-    art.write_text(json.dumps(seed))
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts", "tpu_profile_bench.py"),
-         "--batches", "256,512", "--flag-sweep", "--deadline", "60",
-         "--json", art, "--assume-step-s", "0.24",
-         "--device-kind", "TPU v5 lite"],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=300)
-    assert proc.returncode == 0, proc.stderr[-800:]
-    d = json.loads(art.read_text())
-    assert d["complete"]
-    assert all(r.get("reused_from_previous_run")
-               for r in d["measurements"])
-    assert all(r.get("reused_from_previous_run")
-               for r in d["flag_sweep"])
-    # best_preset computed from the reused rows, with its provenance
-    assert d["best_preset"]["preset"] == "scoped_vmem_32m"
-    assert d["best_preset"]["baseline_source"] == "flag_sweep_baseline"
-
-
 # --------------------------------------------------------------------------- #
 # corrupted resumable artifacts (resilience): treated as absent, loudly       #
 # --------------------------------------------------------------------------- #

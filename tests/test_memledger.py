@@ -213,6 +213,43 @@ def test_compile_cache_eviction_releases_ledger_rows(ledger):
     assert cache.stats()["evictions"] == 1
 
 
+def test_serving_stack_attributes_every_subsystem(ledger):
+    """A batch engine plus an LM engine with a model drafter and a host
+    KV tier report in under five subsystems, and their AOT executables
+    file cost rows: the whole stack is attributed, not just the part a
+    unit test registers by hand."""
+    from bigdl_tpu import nn
+    from bigdl_tpu.models.transformer import TransformerLM
+    from bigdl_tpu.serving import (HostBlockStore, LMServingEngine,
+                                   ServingEngine, SpecConfig)
+
+    lm_model = TransformerLM(vocab_size=31, hidden_size=16, n_head=2,
+                             n_layers=1, max_len=32,
+                             pos_encoding="rope").build(seed=0)
+    eng = ServingEngine(
+        nn.Sequential(nn.Linear(8, 4), nn.LogSoftMax()).build(seed=0),
+        input_shape=(8,), max_batch_size=4, name="stack")
+    lm = LMServingEngine(lm_model, slots=2, cache_len=24,
+                         prefill_buckets=(8,), spec=SpecConfig(k=2),
+                         kvtier=HostBlockStore(host_bytes=1 << 20,
+                                               name="stack"),
+                         name="stack-lm")
+    try:
+        eng.warmup()
+        lm.warmup()
+        attr = ledger.attribution()
+        assert {"params", "host_stager", "kvcache", "spec",
+                "kvtier"} <= set(attr)
+        assert attr["params"] > 0 and attr["kvcache"] > 0 \
+            and attr["spec"] > 0
+        rows = ledger.executables()
+        assert rows and all(r["memory"]["code_bytes"] >= 0 for r in rows)
+    finally:
+        lm.close()
+        eng.close()
+    assert ledger.attribution().get("kvcache", 0) == 0
+
+
 # --------------------------------------------------------------------- #
 # reconciliation: CPU degrade path
 # --------------------------------------------------------------------- #

@@ -512,7 +512,7 @@ class TestPreemption:
 
 class TestMixedPrecision:
     """set_compute_dtype: bf16 forward/backward, f32 master weights (the
-    TPU mixed-precision recipe bench.py uses, now first-class API)."""
+    TPU mixed-precision recipe, first-class API)."""
 
     def _job(self, cls, dtype=None, mesh=None):
         import jax.numpy as jnp

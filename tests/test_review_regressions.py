@@ -246,3 +246,30 @@ def test_validator_jit_is_cached_across_test_calls():
         fwd1 = val._fwd
         val.test([Top1Accuracy()])
         assert val._fwd is fwd1  # same jitted callable, no rebuild
+
+
+def test_one_instrument():
+    """PR 30: numbers come from benchmarks/run.py on a chip and nothing
+    else.  The pre-chip instrument, its CPU artifacts and every pointer
+    to them stay gone (``attention_bench.py`` and its like are other
+    files and stay)."""
+    import glob
+    import re
+    repo = os.path.join(os.path.dirname(__file__), os.pardir)
+    assert not os.path.exists(os.path.join(repo, "bench.py"))
+    for pat in ("BENCH_*.json", "MULTICHIP_*.json", "PROFILE_MEM.json"):
+        assert not glob.glob(os.path.join(repo, pat)), pat
+    word = re.compile(r"(?<![\w/])bench\.py|BENCH_")
+    files = [os.path.join(repo, f) for f in (
+        "chip_smoke.py", "CLAUDE.md", "README.md",
+        os.path.join(".claude", "skills", "verify", "SKILL.md"))]
+    for root in ("bigdl_tpu", "scripts"):
+        files += [os.path.join(d, f)
+                  for d, _, fs in os.walk(os.path.join(repo, root))
+                  for f in fs if f.endswith((".py", ".sh", ".md"))]
+    hits = []
+    for fn in files:
+        with open(fn, errors="replace") as f:
+            hits += [f"{os.path.relpath(fn, repo)}:{i}: {line.strip()}"
+                     for i, line in enumerate(f, 1) if word.search(line)]
+    assert not hits, hits

@@ -1,13 +1,10 @@
 """bigdl_tpu.serving: dynamic batcher, compile cache, engine, transfer.
 
 Fast tests run in tier-1 (the smoke test pushes a single request
-through the FULL engine on CPU); the soak/latency tests and the
-bench.py --serve subprocess test are marked slow.
+through the FULL engine on CPU); the soak/latency tests are marked
+slow.
 """
-import json
 import os
-import subprocess
-import sys
 import threading
 import time
 from concurrent.futures import Future
@@ -20,8 +17,6 @@ from bigdl_tpu.serving import (CompileCache, DynamicBatcher, ServingEngine,
                                ServingClosed, ServingQueueFull,
                                power_of_two_buckets)
 from bigdl_tpu.serving.metrics import LatencyHistogram, ServingMetrics
-
-REPO = os.path.join(os.path.dirname(__file__), os.pardir)
 
 
 def _tiny_model():
@@ -408,34 +403,6 @@ def test_serving_soak_concurrent_clients():
         assert snap["metrics"]["examples"] >= 8 * 40
         assert snap["compile_cache"]["hit_rate"] > 0.9
         assert snap["metrics"]["throughput_eps"] > 0
-
-
-@pytest.mark.slow
-def test_bench_serve_cli_artifact_and_resume(tmp_path):
-    art = tmp_path / "BENCH_SERVE.json"
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    cmd = [sys.executable, "bench.py", "--serve", "--json", str(art),
-           "--requests", "48"]
-    p = subprocess.run(cmd, cwd=REPO, env=env, capture_output=True,
-                       text=True, timeout=600)
-    assert p.returncode == 0, p.stderr[-800:]
-    d = json.loads(art.read_text())
-    assert d["complete"] and d["platform"] == "cpu"
-    assert d["summary"]["cache_hit_rate"] > 0.9
-    assert d["summary"]["latency_p50_ms"] > 0
-    assert d["summary"]["latency_p99_ms"] >= d["summary"]["latency_p50_ms"]
-    assert d["summary"]["throughput_eps"] > 0
-    last = json.loads(p.stdout.strip().splitlines()[-1])
-    assert last["unit"] == "examples/sec" and last["value"] > 0
-    # resume: same config reuses every measured stage
-    p = subprocess.run(cmd, cwd=REPO, env=env, capture_output=True,
-                       text=True, timeout=600)
-    assert p.returncode == 0, p.stderr[-800:]
-    d = json.loads(art.read_text())
-    reused = {r["stage"]: r.get("reused_from_previous_run")
-              for r in d["rows"] if r.get("stage") != "warmup"}
-    assert all(reused.values()), reused
 
 
 # --------------------------------------------------------------------------- #

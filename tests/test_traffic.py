@@ -394,7 +394,6 @@ def test_injector_stats_aggregate_identical_specs(inject):
 # actuators: LM slot limit, ReplicaSet scale_to                               #
 # --------------------------------------------------------------------------- #
 
-@pytest.mark.slow
 def test_lm_slot_limit_caps_concurrency_token_exact():
     from bigdl_tpu.models.transformer import TransformerLM
     from bigdl_tpu.models.transformer.generate import generate
