@@ -607,7 +607,13 @@ def _decode_step_paged(model, params, token, pos, live, k_arena,
     (``BlockPool(kv_quant="int8")``): the new k/v row is quantized per
     (slot, head) on write and the gather dequantizes in-flight.  The
     Pallas paged kernel reads raw blocks, so quantized pools require
-    the gather path."""
+    the gather path.
+
+    -> (logits (S, V) float32, [the routed layers' two integers, when the
+    model has any], *arenas).  The serving engine's decode program is
+    :func:`_decode_pick_paged`, which picks from these logits on the
+    device and hands out ids; the logits stay this function's result for
+    what reads them (the tests, the pick's twin on the host)."""
     if attn_impl not in ("gather", "paged_kernel"):
         raise ValueError(f"attn_impl must be 'gather' or 'paged_kernel', "
                          f"got {attn_impl!r}")
@@ -675,6 +681,48 @@ def _decode_step_paged(model, params, token, pos, live, k_arena,
         # the routed layers' two integers ride out beside the logits
         return (logits, counts) + arenas
     return (logits,) + arenas
+
+
+def pick_next(logits, key, temperature):
+    """THE sampling rule of a decode step, on the device: ``logits``
+    (n, V) float32, one key, one temperature -> (n,) ids, 0-based.
+    Greedy argmax (the first index on ties, as ``np.argmax``) at
+    temperature 0, else the key's categorical draw over the rows at
+    shape (n, V).  The offline scan draws a whole batch under one key;
+    the serving step (:func:`pick_rows`) one slot at a time at (1, V),
+    which is what ``serving.spec.verify.pick_token`` draws on the host."""
+    greedy = jnp.argmax(logits, axis=-1)
+    sampled = jax.random.categorical(key, logits / jnp.maximum(
+        temperature, 1e-6), axis=-1)
+    return jnp.where(temperature > 0.0, sampled, greedy)
+
+
+def pick_rows(logits, temperature, keys):
+    """:func:`pick_next` a slot: ``logits`` (S, V) float32, ``temperature``
+    (S,) float32 and ``keys`` (S, 2) uint32 (zeros for a greedy slot)
+    -> (S,) int32.  The noise is drawn only in a round that samples: the
+    step sees that in the temperatures it is handed."""
+    def drawn(_):
+        return jax.vmap(lambda row, key, t: pick_next(row[None, :], key, t)[0])(
+            logits, keys, temperature)
+
+    def greedy(_):
+        return jnp.argmax(logits, axis=-1)
+
+    return lax.cond(jnp.any(temperature > 0.0), drawn, greedy,
+                    None).astype(jnp.int32)
+
+
+def _decode_pick_paged(model, params, token, pos, live, temperature, keys,
+                       *kv, **kw):
+    """:func:`_decode_step_paged` with the pick applied on the device: the
+    serving engine's decode program.  -> (ids (S,) int32, [the routed
+    layers' counts], *arenas): no output has the vocabulary's width, so a
+    round hands the host S integers (and the ids stay on the device for a
+    later step to take)."""
+    logits, *rest = _decode_step_paged(model, params, token, pos, live, *kv,
+                                       **kw)
+    return (pick_rows(logits, temperature, keys), *rest)
 
 
 def _verify_step_paged(model, params, tokens, pos, n_cand, tables,
@@ -864,10 +912,7 @@ def _decode_scan(model, params, max_new, first_token, pos0,
     def step(carry, key):
         token, pos, kc, vc = carry
         logits, kc, vc = _decode_step(model, params, token, pos, kc, vc)
-        greedy = jnp.argmax(logits, axis=-1)
-        sampled = jax.random.categorical(key, logits / jnp.maximum(
-            temperature, 1e-6), axis=-1)
-        nxt = jnp.where(temperature > 0.0, sampled, greedy)
+        nxt = pick_next(logits, key, temperature)
         return (nxt, pos + 1, kc, vc), nxt
 
     keys = jax.random.split(rng, max_new)

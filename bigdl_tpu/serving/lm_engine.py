@@ -34,7 +34,15 @@ paged block arena of :mod:`bigdl_tpu.serving.kvcache`:
   executable count — with ``donate_argnums`` on both arenas so the
   decode loop never copies HBM-resident state.
   ``LMMetrics.live_blocks`` / ``gathered_blocks`` count the blocks
-  listed and the chunks' blocks gathered.
+  listed and the chunks' blocks gathered.  The step PICKS ITS TOKENS
+  (``generate.pick_rows``: argmax, or the slot's key-chain draw at its
+  temperature): a round hands the host ``(S,)`` ids, never ``(S, V)``
+  logits (``LMMetrics.logit_rows_to_host`` counts the rows that do
+  cross: an admission's first token, the verify rounds), and the host
+  hands the round ONE operand vector -- tokens, positions,
+  temperatures, keys and the live list
+  (:func:`decode_operands`) -- because what a round pays around its
+  device module is a fixed cost a transfer, not bytes.
 
 Sharing: the radix cache maps token prefixes to refcounted block
 chains, so concurrent requests with a common head attend the SAME
@@ -114,6 +122,35 @@ _PHASE_SPANS = tuple("lm/" + p for p in ROUND_PHASES)
 #: running medians of the plain (decode-only) rounds
 SLOW_ROUND_S = 1.0
 SLOW_ROUND_MEDIANS = 8.0
+
+
+def decode_operands(slots: int, entries: int):
+    """What the host hands a decode round, as ONE int32 vector and the
+    views a round fills: ``-> (operands, token (S,), pos (S,),
+    temperature (S,) float32, keys (S, 2) uint32, live (3, entries))``.
+    One vector because every host operand of a step costs its dispatch a
+    transfer of its own (0.13-0.24 ms each on a v5e, PERF.md PR 31),
+    whatever its size; a fresh one a round because the transfer may still
+    read it when the call returns.  :func:`split_decode_operands` is the
+    same layout on the device."""
+    s = int(slots)
+    ops = np.zeros((5 * s + 3 * int(entries),), np.int32)
+    return (ops, ops[:s], ops[s:2 * s], ops[2 * s:3 * s].view(np.float32),
+            ops[3 * s:5 * s].view(np.uint32).reshape(s, 2),
+            ops[5 * s:].reshape(3, -1))
+
+
+def split_decode_operands(ops, slots: int):
+    """:func:`decode_operands`' vector inside the step program ->
+    ``(token, pos, temperature, keys, live)``."""
+    import jax.numpy as jnp
+    from jax import lax
+    s = int(slots)
+    return (ops[:s], ops[s:2 * s],
+            lax.bitcast_convert_type(ops[2 * s:3 * s], jnp.float32),
+            lax.bitcast_convert_type(ops[3 * s:5 * s],
+                                     jnp.uint32).reshape(s, 2),
+            ops[5 * s:].reshape(3, -1))
 
 
 def prefill_bucket_lengths(max_len: int, min_bucket: int = 8) -> tuple:
@@ -352,6 +389,10 @@ class LMMetrics:
         # ``decode_steps`` x slots x table width is what whole tables hold
         self.live_blocks = 0
         self.gathered_blocks = 0
+        # rows of V float32 logits copied to the host: 1 an admission's
+        # first token, S x W a verify round, none for a plain decode
+        # round (its step picks on the device and hands out S ids)
+        self.logit_rows_to_host = 0
         self.slot_steps = 0
         self.active_slot_steps = 0
         self.peak_active = 0
@@ -378,7 +419,7 @@ class LMMetrics:
                           replace=True)
         for key in ("requests", "rejected", "completed", "tokens",
                     "prefills", "decode_steps", "live_blocks",
-                    "gathered_blocks"):
+                    "gathered_blocks", "logit_rows_to_host"):
             registry.register(prefix + key,
                               FnGauge(lambda k=key: getattr(self, k)),
                               replace=True)
@@ -442,6 +483,11 @@ class LMMetrics:
     def record_complete(self) -> None:
         with self._lock:
             self.completed += 1
+
+    def record_logit_rows(self, rows: int) -> None:
+        """``rows`` rows of V float32 logits were copied to the host."""
+        with self._lock:
+            self.logit_rows_to_host += int(rows)
 
     def record_moe(self, counts, layers: int) -> None:
         """One decode step's routed-layer integers (summed over its
@@ -531,6 +577,7 @@ class LMMetrics:
                 "decode_steps": self.decode_steps,
                 "live_blocks": self.live_blocks,
                 "gathered_blocks": self.gathered_blocks,
+                "logit_rows_to_host": self.logit_rows_to_host,
                 "moe": {"assignments": self.moe_assignments,
                         "experts_hit": self.moe_experts_hit,
                         "expert_layer_rounds": self.moe_expert_layer_rounds,
@@ -794,7 +841,7 @@ class LMServingEngine:
         configure_compile_cache()
         import jax
         from bigdl_tpu.models.transformer.generate import (
-            _decode_step_paged, _insert_blocks, _prefill_parts,
+            _decode_pick_paged, _insert_blocks, _prefill_parts,
             _prefill_suffix_parts, _tree_commit_paged,
             _tree_verify_step_paged, _verify_step_paged)
         from bigdl_tpu.quant import dequantize_entry
@@ -987,15 +1034,19 @@ class LMServingEngine:
         def _donated(first):
             return tuple(range(first, first + _n_kv))
 
-        def _decode_fn(params, token, pos, live, *kv):
-            return _constrain(_decode_step_paged(
-                model, dequantize_entry(params), token, pos, live, *kv,
+        def _decode_fn(params, operands, *kv):
+            # the step picks its tokens: (S,) ids leave, never (S, V) logits
+            token, pos, temperature, keys, live = split_decode_operands(
+                operands, self.slots)
+            return _constrain(_decode_pick_paged(
+                model, dequantize_entry(params), token, pos, live,
+                temperature, keys, *kv,
                 table_width=self.table_width, attn_impl=decode_attn))
 
-        self._decode_jit = jax.jit(_decode_fn, donate_argnums=_donated(4))
+        self._decode_jit = jax.jit(_decode_fn, donate_argnums=_donated(2))
         self._decode_exec = None
         #: routed expert layers of the model: with any, the decode step
-        #: hands their two integers out beside the logits
+        #: hands their two integers out beside the ids
         self._moe_layers = model.moe_layers
 
         self._insert_jit = jax.jit(
@@ -1369,13 +1420,13 @@ class LMServingEngine:
             # Compiled.__call__ auto-places the uncommitted np arrays
             sh = (dict(sharding=self.placement.replicated())
                   if self.placement is not None else {})
-            sds = jax.ShapeDtypeStruct
-            tok = sds((self.slots,), np.int32, **sh)
-            pos = sds((self.slots,), np.int32, **sh)
-            # the live list at the one length that holds any round's
-            live = sds((3, self.slots * self.table_width), np.int32, **sh)
+            # one operand a round, the live list in it at the one length
+            # that holds any round's
+            ops = decode_operands(self.slots,
+                                  self.slots * self.table_width)[0]
             self._decode_exec = self._decode_jit.lower(
-                self._params, tok, pos, live, *self.pool.arenas).compile()
+                self._params, jax.ShapeDtypeStruct(ops.shape, ops.dtype, **sh),
+                *self.pool.arenas).compile()
             self._ledger_exec("decode", f"slots={self.slots}",
                               self._decode_exec)
         return self._decode_exec
@@ -1662,12 +1713,16 @@ class LMServingEngine:
         1-based ids for one prompt."""
         return self.submit(prompt_ids, **kw).result(timeout=timeout)
 
-    # -- sampling (host-side, replicating offline generate exactly) ---- #
+    # -- sampling (replicating offline generate exactly) -------------- #
+    # A plain decode round picks on the device (``generate.pick_rows``,
+    # inside the one decode executable); the host's rule below is its
+    # twin, for the rows that do reach the host: an admission's first
+    # token and the verify rows.
     @staticmethod
     def _pick(logits_row: np.ndarray, temperature: float, key,
               clamp: bool) -> int:
         # one shared implementation with the speculative acceptance
-        # path (spec/verify.py), so plain decode, verify rows, and the
+        # path (spec/verify.py), so the first token, verify rows, and the
         # Gumbel-coupled drafter can never drift apart
         from bigdl_tpu.serving.spec.verify import pick_token
         return pick_token(logits_row, temperature, key, clamp)
@@ -2681,6 +2736,7 @@ class LMServingEngine:
         # where the device wait for the prefill and the insert lands
         self._stamp(P_FIRST_TOKEN)
         logits = np.asarray(pf.logits)  # sync; (1, V) f32
+        self.metrics.record_logit_rows(logits.shape[0])
         if pf.moe is not None:
             landed = int(np.asarray(pf.moe)[0])
             self.metrics.record_moe_prefill(landed)
@@ -2744,14 +2800,19 @@ class LMServingEngine:
 
     def _step(self):
         t0 = self._stamp(P_DISPATCH)
-        token = np.zeros((self.slots,), np.int32)
-        pos = np.zeros((self.slots,), np.int32)
+        # one operand vector a round; what stays zero in it: an idle
+        # slot, a greedy pick (no temperature, no key), nobody's blocks
+        operands, token, pos, temperature, keys, live = decode_operands(
+            self.slots, self.slots * self.table_width)
         active, chains, n_live = [], [], 0
         for i, st in enumerate(self._slots):
             if st is not None:
                 active.append((i, st))
                 token[i] = st.last0
                 pos[i] = st.pos_next
+                if st.temperature > 0.0 and st.step_keys is not None:
+                    temperature[i] = st.temperature
+                    keys[i] = st.step_keys[st.step_idx]
                 # what the round reads of the slot's chain: the blocks up
                 # to the one its new row is written to
                 held = st.table[:st.pos_next // self.block_len + 1]
@@ -2760,20 +2821,20 @@ class LMServingEngine:
         if not active:
             return
         self._rd_active = len(active)
-        live = live_list(chains, self.slots * self.table_width, self.slots)
+        live[:] = live_list(chains, live.shape[1], self.slots)
         # what the step gathers: the chunks that hold a listed block
         chunk = list_chunk(self.slots,
                            self.model.n_head != self.model.n_kv_head)
         gathered = -(-n_live // chunk) * chunk
-        logits, *out = self._decode_compiled()(
-            self._params, token, pos, live, *self.pool.arenas)
+        ids, *out = self._decode_compiled()(
+            self._params, operands, *self.pool.arenas)
         moe = None
         if self._moe_layers:
             moe, *out = out
-            moe.copy_to_host_async()    # lands with the logits: one wait
+            moe.copy_to_host_async()    # lands with the ids: one wait
         self.pool.arenas = out
         self._stamp(P_WAIT)
-        logits = np.asarray(logits)  # sync; (S, V) f32
+        ids = np.asarray(ids)  # sync; (S,) int32
         if moe is not None:
             moe = np.asarray(moe)
             self.metrics.record_moe(moe, self._moe_layers)
@@ -2803,7 +2864,7 @@ class LMServingEngine:
                 # payload-less resume: this step just rebuilt last0's
                 # KV row; the next token was already emitted before
                 # hibernation — take it from the replay queue instead
-                # of the logits (no re-emit, no ITL sample).  The
+                # of the step's id (no re-emit, no ITL sample).  The
                 # queue preserves the original step_keys alignment, so
                 # post-replay sampling is bit-exact.
                 st.last0 = st.replay.popleft()
@@ -2811,11 +2872,7 @@ class LMServingEngine:
                 st.step_idx += 1
                 st.remaining -= 1
                 continue
-            nxt0 = self._pick(
-                logits[i], st.temperature,
-                st.step_keys[st.step_idx]
-                if st.step_keys is not None else None,
-                clamp=True)
+            nxt0 = int(ids[i])
             st.stream._emit(nxt0 + 1)
             itls.append(now - st.last_emit_at)
             st.last_emit_at = now
@@ -2935,6 +2992,7 @@ class LMServingEngine:
             self._params, tokens, pos, ncand, tables, *self.pool.arenas)
         self._stamp(P_WAIT)
         logits = np.asarray(logits)  # sync; (S, W, V) f32
+        self.metrics.record_logit_rows(logits.shape[0] * logits.shape[1])
         now = self._stamp(P_EMIT)
         if _tracer.enabled:
             _tracer.add_complete(
@@ -3163,6 +3221,7 @@ class LMServingEngine:
             self._params, tokens, pos, ncand, tables, *self.pool.arenas)
         self._stamp(P_WAIT)
         logits = np.asarray(logits)  # sync; (S, W, V) f32
+        self.metrics.record_logit_rows(logits.shape[0] * logits.shape[1])
         now = self._stamp(P_EMIT)
         if _tracer.enabled:
             _tracer.add_complete(

@@ -315,7 +315,11 @@ def pick_token(logits_row: np.ndarray, temperature: float, key,
     """The offline sampling rule for one logits row: argmax at
     temperature 0 (or without a key), else the key-chain categorical
     over (1, V) — shapes and clamping replicate ``generate()`` exactly,
-    which is what makes serving streams bit-exact against it."""
+    which is what makes serving streams bit-exact against it.  The
+    host's rule, for the rows that reach the host (an admission's first
+    token, the verify rows); a plain decode round picks on the device by
+    its twin, ``generate.pick_rows`` (``tests/test_decode_pick.py`` holds
+    the two together)."""
     if temperature <= 0.0 or key is None:
         return int(np.argmax(logits_row))
     import jax

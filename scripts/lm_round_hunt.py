@@ -9,7 +9,10 @@ of a benchmark cell.  Not part of the benchmark: it calls
         closes): seconds by phase, the running median, the longest round with
         its own phase split -- the stall hunt; and, under "lists", what the
         decode rounds' live lists held over the window (blocks listed,
-        blocks gathered, the entries of whole tables)
+        blocks gathered, the entries of whole tables) and, beside them,
+        "logit_rows_to_host": the rows of V float32 logits the window
+        copied to the host, against "prefills", its admissions' first
+        tokens (equal when no plain decode round moves a row)
     ... --tracer-on     the same with the span tracer enabled for the whole run
                         (not the profiler): what tracing costs end to end
     ... --trace         runs with --trace 1 instead; beside each result line,
@@ -57,8 +60,10 @@ def _watch_engines():
 
     def lists(e):
         m = e.metrics
+        # (the parent's tree has no logit_rows_to_host: null there)
         return [m.decode_steps, getattr(m, "live_blocks", None),
-                getattr(m, "gathered_blocks", None)]
+                getattr(m, "gathered_blocks", None), m.prefills,
+                getattr(m, "logit_rows_to_host", None)]
 
     def watched_open(self, at=None):
         for e in live():
@@ -73,13 +78,14 @@ def _watch_engines():
         # blocks gathered (the rungs), and what whole tables would hold
         seen["lists"] = []
         for e, before in zip(live(), seen.pop("_lists", [])):
-            steps, listed, gathered = (
+            steps, listed, gathered, prefills, logit_rows = (
                 None if b is None else a - b
                 for a, b in zip(lists(e), before))
             seen["lists"].append({
                 "decode_steps": steps, "live_blocks": listed,
                 "gathered_blocks": gathered,
-                "table_entries": steps * e.slots * e.table_width})
+                "table_entries": steps * e.slots * e.table_width,
+                "prefills": prefills, "logit_rows_to_host": logit_rows})
         del engines[:]
         return t
 

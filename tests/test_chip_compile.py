@@ -265,11 +265,15 @@ def test_lm_paged_programs_keep_the_arenas_in_place_on_v5e(sds, monkeypatch,
     if program == "decode":
         impl = "paged_kernel" if arg == "paged_kernel" else "gather"
 
-        def step(p, tok, pos, live, *kv):
-            return G._decode_step_paged(model, p, tok, pos, live, *kv,
-                                        table_width=width, attn_impl=impl)
+        # the engine's program: the step picks its tokens, handed each
+        # slot's temperature and key beside its token and position
+        def step(p, tok, pos, live, temperature, keys, *kv):
+            return G._decode_pick_paged(model, p, tok, pos, live, temperature,
+                                        keys, *kv, table_width=width,
+                                        attn_impl=impl)
 
-        args = (params, i32(slots), i32(slots), i32(3, slots * width))
+        args = (params, i32(slots), i32(slots), i32(3, slots * width),
+                sds((slots,), jnp.float32), sds((slots, 2), jnp.uint32))
     elif program == "verify":
         def step(p, tok, pos, n_cand, tables, *kv):
             return G._verify_step_paged(model, p, tok, pos, n_cand, tables,
@@ -291,6 +295,12 @@ def test_lm_paged_programs_keep_the_arenas_in_place_on_v5e(sds, monkeypatch,
               if 'custom_call_target="tpu_custom_call"' in ln
               and "ragged" not in ln]
     assert bool(pallas) == (arg == "paged_kernel")
+    if program == "decode":
+        # (S,) ids leave, and beside them only the arenas: no output of the
+        # vocabulary's 50,257 columns
+        ids, *back = compiled.out_info
+        assert ids.shape == (slots,) and ids.dtype == jnp.int32
+        assert [o.shape for o in back] == [a.shape for a in arenas]
     mem = compiled.memory_analysis()
     arena_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in arenas)
     assert mem.alias_size_in_bytes >= 0.99 * arena_bytes
@@ -376,15 +386,20 @@ def test_laguna_cell_compiles_for_v5e_and_keeps_the_arenas_in_place(
         assert arenas[0].shape == (5, 5136, 16, 1024)       # no lane padding
         slots, width = eng["slots"], eng["cache_len"] // eng["block_len"]
 
-        def step(p, tok, pos, live, *kv):
-            return G._decode_step_paged(model, p, tok, pos, live, *kv,
-                                        table_width=width, attn_impl="gather")
+        def step(p, tok, pos, live, temperature, keys, *kv):
+            return G._decode_pick_paged(model, p, tok, pos, live, temperature,
+                                        keys, *kv, table_width=width,
+                                        attn_impl="gather")
 
         compiled, text = _compile(
             step, params, i32(slots), i32(slots), i32(3, slots * width),
-            *arenas, donate_argnums=(4, 5))
-        logits, counts = compiled.out_info[:2]
-        assert logits.shape == (slots, 50176) and counts.shape == (2,)
+            sds((slots,), jnp.float32), sds((slots, 2), jnp.uint32),
+            *arenas, donate_argnums=(6, 7))
+        # the picked ids and the routed layers' two integers: nothing of
+        # the vocabulary's 50,176 columns leaves the step
+        ids, counts = compiled.out_info[:2]
+        assert ids.shape == (slots,) and ids.dtype == jnp.int32
+        assert counts.shape == (2,) and len(compiled.out_info) == 4
         arena_bytes = 2 * int(np.prod(arenas[0].shape)) * 2
         mem = compiled.memory_analysis()
         assert mem.alias_size_in_bytes >= 0.99 * arena_bytes

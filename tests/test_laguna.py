@@ -75,15 +75,36 @@ def test_full_forward_matches_the_reference(reference_weights, impl):
 
 def _served_logits(monkeypatch, engine, prompt, forced):
     """Serve ``prompt`` teacher-forced on ``forced`` (0-based): every logits
-    row the engine picks a token from, in order."""
+    row the engine picks a token from, in order.  The first token's row
+    reaches the host's ``_pick``; a decode round picks on the device, so the
+    engine's decode executable is stood in for by the same step handing out
+    its logits, and the forced token as every slot's id."""
+    from bigdl_tpu.models.transformer import generate as G
     from bigdl_tpu.serving import lm_engine
+    from bigdl_tpu.serving.kvcache.blocks import SCRATCH_BLOCK
     rows, queue = [], list(forced)
 
     def pick(logits_row, temperature, key, clamp):
         rows.append(np.array(logits_row))
         return int(queue.pop(0))
 
+    step = jax.jit(
+        lambda p, token, pos, live, *kv: G._decode_step_paged(
+            engine.model, p, token, pos, live, *kv,
+            table_width=engine.table_width, attn_impl=engine.decode_attn),
+        donate_argnums=(4, 5))
+
+    def decode(params, operands, *kv):
+        token, pos, _, _, live = lm_engine.split_decode_operands(
+            jnp.asarray(operands), engine.slots)
+        logits, *rest = step(params, token, pos, live, *kv)
+        block, owner, _ = np.asarray(live)
+        slot, = set(owner[block != SCRATCH_BLOCK].tolist())     # one request
+        rows.append(np.array(logits[slot]))
+        return (jnp.full((engine.slots,), queue.pop(0), jnp.int32), *rest)
+
     monkeypatch.setattr(lm_engine.LMServingEngine, "_pick", staticmethod(pick))
+    monkeypatch.setattr(engine, "_decode_exec", decode)
     engine.submit(prompt + 1, max_new_tokens=len(forced)).result(timeout=300)
     return np.stack(rows)
 

@@ -211,7 +211,7 @@ def test_tracer_off_leaves_the_ring_empty_and_fills_the_round_record(
     assert eng.metrics.rounds == rounds["count"]
 
 
-def test_slow_pick_is_named_by_the_longest_round_and_logged_once(
+def test_slow_emission_is_named_by_the_longest_round_and_logged_once(
         model, tracer, monkeypatch, caplog):
     tracer.disable()
     monkeypatch.setattr(lm_engine, "SLOW_ROUND_S", 0.2)
@@ -220,16 +220,18 @@ def test_slow_pick_is_named_by_the_longest_round_and_logged_once(
     assert eng.metrics.plain_rounds >= 3
     eng.metrics.reset_rounds()
     _serve(eng, n=2, seed=3)
-    real, calls = lm_engine.LMServingEngine._pick, {"n": 0}
+    real, calls = lm_engine.LMStream._emit, {"n": 0}
 
-    def slow_pick(*a, **kw):
-        calls["n"] += bool(kw.get("clamp"))     # a decode round's pick
-        if kw.get("clamp") and calls["n"] == 5:
+    def slow_emit(self, token_1b):
+        # a decode round's emission (the step picked the token on the device;
+        # a first token is emitted in lm/first_token)
+        in_round = self.first_token_at is not None
+        calls["n"] += in_round
+        if in_round and calls["n"] == 5:
             time.sleep(0.3)
-        return real(*a, **kw)
+        return real(self, token_1b)
 
-    monkeypatch.setattr(lm_engine.LMServingEngine, "_pick",
-                        staticmethod(slow_pick))
+    monkeypatch.setattr(lm_engine.LMStream, "_emit", slow_emit)
     with caplog.at_level(logging.WARNING, logger="bigdl_tpu.serving"):
         _serve(eng, n=3, max_new=8, seed=5)
         _close(eng)
@@ -256,9 +258,9 @@ def test_held_round_fires_the_lm_round_watchdog(model, tracer, monkeypatch):
     assert eng.watchdog is wd
     _serve(eng, n=3)                    # completed rounds arm the median rule
     assert len(wd._durations) >= wd.min_samples
-    real, calls, seen = lm_engine.LMServingEngine._pick, {"n": 0}, {}
+    real, calls, seen = lm_engine.LMStream._emit, {"n": 0}, {}
 
-    def held_pick(*a, **kw):
+    def held_emit(self, token_1b):
         calls["n"] += 1
         if calls["n"] == 8:     # in a plain round, past both admissions
             # the round hangs until it is seen (a toy's admission round can
@@ -267,16 +269,15 @@ def test_held_round_fires_the_lm_round_watchdog(model, tracer, monkeypatch):
             while wd.last_event is before and time.perf_counter() < deadline:
                 time.sleep(0.01)
             seen["event"] = wd.last_event
-        return real(*a, **kw)
+        return real(self, token_1b)
 
-    monkeypatch.setattr(lm_engine.LMServingEngine, "_pick",
-                        staticmethod(held_pick))
+    monkeypatch.setattr(lm_engine.LMStream, "_emit", held_emit)
     _serve(eng, n=2, seed=7)
     event = seen["event"]
     assert event["watchdog"] == "lm_round"
     assert event["inflight_s"] >= event["threshold_s"]
     # the dump names where the worker hung, while it hung
-    assert "held_pick" in event["thread_stacks"][eng._worker.name]
+    assert "held_emit" in event["thread_stacks"][eng._worker.name]
     assert eng.stats()["rounds"]["watchdog"]["stalls"] == wd.stall_count >= 1
     _close(eng)
     # never across lm/idle, and disarmed at close()
