@@ -105,12 +105,16 @@ class LayerSpec(NamedTuple):
     """What one layer of a plan differs by: its query heads, its window
     (``None``: every earlier key; ``w``: key j for query i iff
     ``i - j < w``), its rotary embedding (``None``: the model's
-    ``pos_encoding``) and its feed-forward half (``"dense"`` or
-    ``"moe"``, the model's ``moe`` spec)."""
+    ``pos_encoding``), its feed-forward half (``"dense"`` or ``"moe"``,
+    the model's ``moe`` spec) and its token mixer: ``"attention"`` (softmax
+    over cached keys and values) or ``"kda"`` (gated delta-rule linear
+    attention, :mod:`bigdl_tpu.nn.kda`: ``n_head`` heads of ``head_dim``
+    keys and values, NO keys and values kept, a state a head instead)."""
     n_head: int
     window: Optional[int] = None
     rope: Optional[RopeSpec] = None
     mlp: str = "dense"
+    mixer: str = "attention"
 
 
 def window_mask(q_pos, k_pos, window: Optional[int]):
@@ -138,6 +142,7 @@ class TransformerLM(Module):
     n_kv_head = None
     norm, norm_eps, mlp_act, bias, attn_gate, moe = (
         "layernorm", 1e-5, "gelu", True, False, None)
+    kda_conv = 4
 
     def __init__(self, vocab_size: int, hidden_size: int = 128,
                  n_head: int = 4, n_layers: int = 2,
@@ -155,8 +160,9 @@ class TransformerLM(Module):
                  head_dim: Optional[int] = None,
                  norm: str = "layernorm", norm_eps: float = 1e-5,
                  mlp_act: str = "gelu", bias: bool = True,
-                 attn_gate: bool = False, moe=None,
-                 layer_plan: Optional[Sequence] = None):
+                 attn_gate=False, moe=None,
+                 layer_plan: Optional[Sequence] = None,
+                 kda_conv: int = 4):
         super().__init__()
         assert head_dim is not None or hidden_size % n_head == 0
         if norm not in ("layernorm", "rmsnorm"):
@@ -165,9 +171,14 @@ class TransformerLM(Module):
         if mlp_act not in ("gelu", "swiglu"):
             raise ValueError(f"mlp_act must be 'gelu' or 'swiglu', "
                              f"got {mlp_act!r}")
-        if pos_encoding not in ("learned", "rope"):
-            raise ValueError(f"pos_encoding must be 'learned' or 'rope', "
-                             f"got {pos_encoding!r}")
+        if pos_encoding not in ("learned", "rope", "none"):
+            raise ValueError(f"pos_encoding must be 'learned', 'rope' or "
+                             f"'none', got {pos_encoding!r}")
+        if attn_gate is True:       # the gate as it was first written
+            attn_gate = "per-head"
+        if attn_gate not in (False, "per-head", "elementwise"):
+            raise ValueError(f"attn_gate must be False, 'per-head' or "
+                             f"'elementwise', got {attn_gate!r}")
         if pos_encoding == "rope" and (hidden_size // n_head) % 2 != 0:
             raise ValueError("rope needs an even head_dim")
         self.vocab_size = vocab_size
@@ -212,12 +223,14 @@ class TransformerLM(Module):
         # -- the block's parameters beyond GPT-2's (all default to it) -- #
         # RMSNorm or LayerNorm; a gated (SwiGLU) or a GELU MLP; biases or
         # none; K/V heads shared by groups of query heads; a sigmoid gate
-        # per head on the attention output; a routed expert layer
-        # (parallel.expert.MoESpec) on the plan's "moe" layers
+        # on the attention output, a scalar a head or elementwise; a routed
+        # expert layer (parallel.expert.MoESpec) on the plan's "moe" layers;
+        # the taps of a "kda" layer's depthwise convolution
         self.n_kv_head = int(n_kv_head or n_head)
         self.norm, self.norm_eps = norm, float(norm_eps)
         self.mlp_act, self.bias, self.attn_gate = mlp_act, bool(bias), attn_gate
         self.moe = moe
+        self.kda_conv = int(kda_conv)
         # the LAYER PLAN: a list of groups ``(repeat, period)``, a period a
         # tuple of LayerSpec.  A group is ``repeat`` copies of its period
         # stacked on a leading axis and scanned; the body runs the period's
@@ -233,7 +246,11 @@ class TransformerLM(Module):
                                  f"n_layers is {n_layers}")
             for _, period in layer_plan:
                 for spec in period:
-                    if spec.n_head % self.n_kv_head:
+                    if spec.mixer not in ("attention", "kda"):
+                        raise ValueError(f"a layer's mixer is 'attention' or "
+                                         f"'kda', got {spec.mixer!r}")
+                    if spec.mixer == "attention" and (
+                            spec.n_head % self.n_kv_head):
                         raise ValueError(
                             f"{spec.n_head} query heads do not divide "
                             f"over {self.n_kv_head} K/V heads")
@@ -261,6 +278,31 @@ class TransformerLM(Module):
         """How many of the plan's layers are routed expert layers."""
         return sum(r * sum(s.mlp == "moe" for s in period)
                    for r, period in self.plan)
+
+    def _layers_mixing(self, mixer: str) -> tuple:
+        specs = [s for r, period in self.plan for s in period * r]
+        return tuple(l for l, s in enumerate(specs) if s.mixer == mixer)
+
+    @property
+    def kv_layers(self) -> tuple:
+        """The (absolute) layers that keep keys and values: a paged pool
+        holds one arena layer for each, in this order."""
+        return self._layers_mixing("attention")
+
+    @property
+    def state_layers(self) -> tuple:
+        """The layers that keep a recurrent state (``"kda"``) instead."""
+        return self._layers_mixing("kda")
+
+    @property
+    def state_shapes(self) -> tuple:
+        """What one sequence holds a recurrent layer: the state's shape (H,
+        d_k, d_v) and the convolution tail's (taps - 1, q, k and v channels);
+        the heads are the first recurrent layer's (one shape a model)."""
+        spec = next(s for _, period in self.plan for s in period
+                    if s.mixer == "kda")
+        d = self.head_dim
+        return ((spec.n_head, d, d), (self.kda_conv - 1, 3 * spec.n_head * d))
 
     def group_params(self, params):
         """``params`` by the plan: a list (groups) of lists (the period's
@@ -309,17 +351,39 @@ class TransformerLM(Module):
         def mat(k, shape, std):
             return jax.random.normal(k, shape, jnp.float32) * std
 
-        attn = {"wq": mat(ks[0], (h, inner), std_h),
-                "wk": mat(ks[1], (h, kv), std_h),
-                "wv": mat(ks[2], (h, kv), std_h),
-                "wo": mat(ks[3], (inner, h), 1.0 / math.sqrt(inner))}
-        if self.attn_gate:
-            attn["wg"] = mat(ks[4], (h, spec.n_head), std_h)
-        if self.bias:
-            attn.update(bq=jnp.zeros((inner,)), bk=jnp.zeros((kv,)),
-                        bv=jnp.zeros((kv,)), bo=jnp.zeros((h,)))
-        p = {"ln1": self._init_norm(), "attn": attn,
-             "ln2": self._init_norm()}
+        p = {"ln1": self._init_norm(), "ln2": self._init_norm()}
+        if spec.mixer == "kda":
+            kk = jax.random.split(ks[0], 8)
+            proj = lambda k: mat(k, (h, inner), std_h)      # noqa: E731
+            p["kda"] = {
+                "wq": proj(kk[0]), "wk": proj(kk[1]), "wv": proj(kk[2]),
+                "wo": mat(ks[3], (inner, h), 1.0 / math.sqrt(inner)),
+                "conv": mat(kk[3], (self.kda_conv, 3 * inner),
+                            1.0 / math.sqrt(self.kda_conv)),
+                # the decay's low-rank pair, its rate a head and offset a
+                # channel; beta a head; the output gate's low-rank pair and
+                # the head norm's weight
+                "wf1": mat(kk[4], (h, d), std_h),
+                "wf2": mat(kk[5], (d, inner), 1.0 / math.sqrt(d)),
+                "a_log": jnp.log(jnp.linspace(1.0, 16.0, spec.n_head)),
+                "dt_bias": jnp.zeros((inner,)),
+                "wb": mat(ks[1], (h, spec.n_head), std_h),
+                "wg1": mat(kk[6], (h, d), std_h),
+                "wg2": mat(kk[7], (d, inner), 1.0 / math.sqrt(d)),
+                "norm": jnp.ones((d,))}
+        else:
+            attn = {"wq": mat(ks[0], (h, inner), std_h),
+                    "wk": mat(ks[1], (h, kv), std_h),
+                    "wv": mat(ks[2], (h, kv), std_h),
+                    "wo": mat(ks[3], (inner, h), 1.0 / math.sqrt(inner))}
+            if self.attn_gate:
+                attn["wg"] = mat(ks[4], (h, inner if self.attn_gate
+                                         == "elementwise" else spec.n_head),
+                                 std_h)
+            if self.bias:
+                attn.update(bq=jnp.zeros((inner,)), bk=jnp.zeros((kv,)),
+                            bv=jnp.zeros((kv,)), bo=jnp.zeros((h,)))
+            p["attn"] = attn
         if spec.mlp == "moe":
             from bigdl_tpu.parallel.expert import init_routed_params
             p["moe"] = init_routed_params(ks[5], self.moe, h)
@@ -407,7 +471,8 @@ class TransformerLM(Module):
     # -- what differs between them is only how attention reads its keys
     def layer_qkv(self, spec: LayerSpec, bp, x, positions=None):
         """Pre-attention: norm, projections, rotary.  -> q (B, H, T, D),
-        k, v (B, H_kv, T, D), gate (B, T, H) or None."""
+        k, v (B, H_kv, T, D), gate (B, T, H), (B, T, H * D) where it is
+        elementwise, or None."""
         from bigdl_tpu.nn._util import match_compute_dtype
         from bigdl_tpu.quant.kernels import qmatmul
         ap = bp["attn"]
@@ -429,18 +494,95 @@ class TransformerLM(Module):
         return q, k, v, gate
 
     def layer_attn_out(self, bp, o, gate=None):
-        """Post-attention, before the residual: the per-head gate and the
-        output projection.  ``o`` (B, H, T, D)."""
+        """Post-attention, before the residual: the gate (a scalar a head,
+        or elementwise) and the output projection.  ``o`` (B, H, T, D)."""
         from bigdl_tpu.quant.kernels import qmatmul
-        if gate is not None:
+        b, h, t, d = o.shape
+        if gate is not None and self.attn_gate == "elementwise":
+            gate = gate.reshape(b, t, h, d).transpose(0, 2, 1, 3)
+            o = (o.astype(jnp.float32) * gate).astype(o.dtype)
+        elif gate is not None:
             o = (o.astype(jnp.float32)
                  * gate.transpose(0, 2, 1)[..., None]).astype(o.dtype)
-        b, h, t, d = o.shape
         y = qmatmul(o.transpose(0, 2, 1, 3).reshape(b, t, h * d),
                     bp["attn"]["wo"])
         if self.bias:
             y = y + bp["attn"]["bo"]
         return y
+
+    # -- a "kda" layer's mixer in three parts: what comes before the
+    # -- recurrence, the heads it reads, and what follows it
+    def kda_inputs(self, bp, x):
+        """Norm and projections of a recurrent layer: -> (qkv (B, T, 3 * H *
+        D) before the convolution, g (B, T, H, D) float32 the log of the
+        decay a channel, beta (B, T, H) float32 in (0, 2), gate (B, T, H *
+        D) float32)."""
+        from bigdl_tpu.nn._util import match_compute_dtype
+        from bigdl_tpu.quant.kernels import qmatmul
+        kp = bp["kda"]
+        a = match_compute_dtype(self._norm(bp["ln1"], x), kp["wq"])
+        qkv = jnp.concatenate([qmatmul(a, kp[n]) for n in ("wq", "wk", "wv")],
+                              axis=-1)
+        d = self.head_dim
+        f32 = lambda y: y.astype(jnp.float32)               # noqa: E731
+        rate = jnp.exp(f32(kp["a_log"]))[:, None]
+        fgt = f32(qmatmul(qmatmul(a, kp["wf1"]), kp["wf2"])) + f32(kp["dt_bias"])
+        g = -rate * jax.nn.softplus(fgt.reshape(fgt.shape[:-1] + (-1, d)))
+        beta = 2.0 * jax.nn.sigmoid(f32(qmatmul(a, kp["wb"])))
+        gate = jax.nn.sigmoid(f32(qmatmul(qmatmul(a, kp["wg1"]), kp["wg2"])))
+        return qkv, g, beta, gate
+
+    def kda_heads(self, y):
+        """After the convolution: ``y`` (..., 3 * H * D) float32 -> q, k
+        (l2-normalised a head) and v, each (..., H, D) float32."""
+        from bigdl_tpu.nn.kda import l2norm
+        q, k, v = (z.reshape(z.shape[:-1] + (-1, self.head_dim))
+                   for z in jnp.split(jax.nn.silu(y), 3, axis=-1))
+        return l2norm(q), l2norm(k), v
+
+    def kda_out(self, bp, o, gate, dtype):
+        """After the recurrence, before the residual: RMSNorm a head (one
+        weight vector), the elementwise gate, the output projection.  ``o``
+        (..., H, D) float32."""
+        from bigdl_tpu.quant.kernels import qmatmul
+        kp = bp["kda"]
+        o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
+                              + self.norm_eps) * kp["norm"].astype(jnp.float32)
+        o = o.reshape(gate.shape) * gate
+        return qmatmul(o.astype(dtype), kp["wo"])
+
+    def layer_kda(self, bp, x, state=None, tail=None, length=None):
+        """A recurrent layer's mixer over whole sequences ``x`` (B, T,
+        hidden), from ``state`` (B, H, D, D) float32 and the convolution's
+        ``tail`` (B, taps - 1, 3 * H * D) (``None``: a sequence's start).
+        ``length`` (traced) says how many leading positions are real: what
+        lies past them is bucket padding, which neither the state nor the
+        tail handed out has seen.  -> (y before the residual, state, tail)."""
+        from bigdl_tpu.nn.kda import kda_chunked, short_conv
+        qkv, g, beta, gate = self.kda_inputs(bp, x)
+        y, tail = short_conv(qkv, bp["kda"]["conv"], tail, length)
+        q, k, v = self.kda_heads(y)
+        valid = (None if length is None else jnp.broadcast_to(
+            jnp.arange(x.shape[1])[None, :] < jnp.reshape(length, (-1, 1)),
+            x.shape[:2]))
+        o, state = kda_chunked(q, k, v, g, beta, state, valid)
+        return self.kda_out(bp, o, gate, x.dtype), state, tail
+
+    def layer_kda_step(self, bp, x, state, tail, active=None):
+        """One new position a sequence: ``x`` (S, 1, hidden), ``state`` (S,
+        H, D, D), ``tail`` (S, taps - 1, 3 * H * D); a row that is not
+        ``active`` (S,) keeps its state and tail.  -> (y (S, 1, hidden),
+        state, tail)."""
+        from bigdl_tpu.nn.kda import kda_step, short_conv_step
+        qkv, g, beta, gate = self.kda_inputs(bp, x)
+        y, new_tail = short_conv_step(qkv[:, 0], bp["kda"]["conv"], tail)
+        q, k, v = self.kda_heads(y)
+        o, new_state = kda_step(q, k, v, g[:, 0], beta[:, 0], state)
+        if active is not None:
+            new_state = jnp.where(active[:, None, None, None], new_state, state)
+            new_tail = jnp.where(active[:, None, None], new_tail, tail)
+        y = self.kda_out(bp, o[:, None], gate, x.dtype)
+        return y, new_state, new_tail.astype(tail.dtype)
 
     def layer_ffn(self, spec: LayerSpec, bp, x, *, dense_routing=False,
                   token_mask=None):
@@ -500,8 +642,16 @@ class TransformerLM(Module):
 
     def _block(self, spec, bp, x, training: bool, rng, positions=None,
                segment_ids=None):
-        q, k, v, gate = self.layer_qkv(spec, bp, x, positions)
-        o = self.attend_full(spec, q, k, v, segment_ids)
+        if spec.mixer == "kda":
+            if segment_ids is not None:
+                raise NotImplementedError(
+                    "a recurrent layer does not reset its state at a packed "
+                    "document's start")
+            mixed = self.layer_kda(bp, x)[0]
+        else:
+            q, k, v, gate = self.layer_qkv(spec, bp, x, positions)
+            o = self.attend_full(spec, q, k, v, segment_ids)
+            mixed = self.layer_attn_out(bp, o, gate)
 
         def drop(y, rng):
             if not (training and self.dropout > 0.0):
@@ -510,7 +660,7 @@ class TransformerLM(Module):
             keep = 1.0 - self.dropout
             return y * jax.random.bernoulli(sub, keep, y.shape) / keep, rng
 
-        o, rng = drop(self.layer_attn_out(bp, o, gate), rng)
+        o, rng = drop(mixed, rng)
         x = x + o
         m, aux, _ = self.layer_ffn(spec, bp, x)
         m, rng = drop(m, rng)

@@ -58,53 +58,103 @@ def _finish_block(model, spec, bp, h, o, gate, token_mask=None):
     sequence's tokens would drop depending on which unrelated prompts
     share the dispatch, coupling batch rows.  -> (h, counts), ``counts``
     the routed expert layer's two integers (zeros on a dense layer)."""
-    h = h + model.layer_attn_out(bp, o, gate)
+    return _ffn(model, spec, bp, h + model.layer_attn_out(bp, o, gate),
+                token_mask)
+
+
+def _ffn(model, spec, bp, h, token_mask=None):
+    """A block's second half on the stream ``h`` its mixer has been added
+    to: -> (h, counts)."""
     m, _, counts = model.layer_ffn(spec, bp, h, dense_routing=True,
                                    token_mask=token_mask)
     return h + m, counts
 
 
 def _windows(model):
-    """The distinct windows of the model's layers (``None``: full)."""
+    """The distinct windows of the model's layers (``None``: full; a
+    recurrent layer has none)."""
     return {s.window for _, period in model.plan for s in period}
+
+
+def _attn_scope(model, spec):
+    """The named scope of a layer's attention in the device trace."""
+    if spec.window:
+        return "attn/sliding"
+    rotated = spec.rope is not None or model.pos_encoding != "none"
+    return "attn/full" if rotated else "attn/nope"
+
+
+def _kind_indices(period):
+    """For each layer of a period: (how many of its kind the period holds,
+    which of them it is).  A layer's caches are indexed by KIND: an
+    attention layer's K/V arena, a recurrent layer's state row, each
+    counted over the layers of its own kind alone."""
+    total = {m: sum(s.mixer == m for s in period) for m in ("attention", "kda")}
+    return [(total[s.mixer], sum(x.mixer == s.mixer for x in period[:i]))
+            for i, s in enumerate(period)]
+
+
+def _split_arenas(model, arenas):
+    """A step's arenas -> (the paged pool's ``(k, v)`` or ``(k, v, ks, vs)``,
+    the recurrent layers' ``(state, tail)`` or ``()``): the state arenas
+    ride last (``serving.kvcache.state``)."""
+    n = 2 if model.state_layers else 0
+    return tuple(arenas[:len(arenas) - n]), tuple(arenas[len(arenas) - n:])
 
 
 def _scan_prefill(model, params, h, layer_fn):
     """A prefill's layer loop over the plan: ``layer_fn(spec, h, bp,
-    layer) -> (h, k, v, counts)``; -> (h, k, v, counts) with k/v (L, B,
-    H_kv, T, D) stacked by absolute layer and the routed expert layers'
-    two integers summed."""
-    ks, vs, base = [], [], 0
+    index) -> (h, (a, b), counts)``, ``index`` the layer's place among the
+    layers of its kind and ``(a, b)`` what it caches: an attention layer's
+    k and v, a recurrent layer's state and convolution tail.  -> (h, (k,
+    v), (state, tail), counts): k/v (L_kv, B, H_kv, T, D) stacked by
+    attention layer, state/tail stacked by recurrent layer (``()`` for a
+    model with none), the routed expert layers' two integers summed."""
+    kinds = ("attention", "kda")
+    kept = {m: [] for m in kinds}
+    base = {m: 0 for m in kinds}
     counts = jnp.zeros((2,), jnp.int32)
     for (repeat, period), stacks in zip(model.plan,
                                         model.group_params(params)):
-        n = len(period)
+        where = _kind_indices(period)
 
-        def body(carry, x, period=period, base=base, n=n):
+        def body(carry, x, period=period, base=dict(base), where=where):
             h, counts = carry
             bps, r = x
-            kv = []
-            for i, (spec, bp) in enumerate(zip(period, bps)):
-                h, k, v, c = layer_fn(spec, h, bp, base + r * n + i)
-                kv.append((k, v))
+            made = {m: [] for m in kinds}
+            for spec, bp, (n, j) in zip(period, bps, where):
+                h, pair, c = layer_fn(spec, h, bp, base[spec.mixer] + r * n + j)
+                made[spec.mixer].append(pair)
                 counts = counts + c
-            return (h, counts), tuple(jnp.stack(c) for c in zip(*kv))
+            return (h, counts), tuple(
+                tuple(jnp.stack(c) for c in zip(*made[m])) for m in kinds)
 
-        (h, counts), (k, v) = lax.scan(body, (h, counts),
-                                       (stacks, jnp.arange(repeat)))
-        ks.append(k.reshape((repeat * n,) + k.shape[2:]))
-        vs.append(v.reshape((repeat * n,) + v.shape[2:]))
-        base += repeat * n
-    if len(ks) == 1:
-        return h, ks[0], vs[0], counts
-    return h, jnp.concatenate(ks), jnp.concatenate(vs), counts
+        (h, counts), out = lax.scan(body, (h, counts),
+                                    (stacks, jnp.arange(repeat)))
+        for m, pair in zip(kinds, out):
+            if pair:        # (repeat, n of the kind, ..) -> by layer of the kind
+                kept[m].append(tuple(x.reshape((-1,) + x.shape[2:])
+                                     for x in pair))
+        for spec in period:
+            base[spec.mixer] += repeat
+
+    def whole(parts):
+        if len(parts) <= 1:
+            return parts[0] if parts else ()
+        return tuple(jnp.concatenate(x) for x in zip(*parts))
+
+    return h, whole(kept["attention"]), whole(kept["kda"]), counts
 
 
-def _prefill_result(model, logits, k, v, counts):
-    """What a prefill hands back: with routed expert layers in the model,
-    their two integers ride out behind the k/v."""
-    out = (logits.astype(jnp.float32), k, v)
-    return out + (counts,) if model.moe_layers else out
+def _prefill_result(model, logits, kv, state, counts):
+    """What a prefill hands back: (logits, k, v); with routed expert layers
+    in the model their two integers ride out behind the k/v, with recurrent
+    layers each one's state and convolution tail at the prompt's true end
+    behind those."""
+    out = (logits.astype(jnp.float32),) + tuple(kv)
+    if model.moe_layers:
+        out += (counts,)
+    return out + tuple(state)
 
 
 def _prefill_parts(model, params, ids0, last_index):
@@ -122,22 +172,28 @@ def _prefill_parts(model, params, ids0, last_index):
         h = h + params["pos"][:t]
     positions = jnp.arange(t)
 
-    def layer_fn(spec, h, bp, layer):
+    def layer_fn(spec, h, bp, index):
+        if spec.mixer == "kda":
+            # from a sequence's start; the padded tail touches neither the
+            # state nor the convolution tail that are handed out
+            y, state, tail = model.layer_kda(bp, h, length=last_index + 1)
+            h, c = _ffn(model, spec, bp, h + y)
+            return h, (state, tail), c
         q, k, v, gate = model.layer_qkv(spec, bp, h, positions)
         # the model's configured attention core via the shared dispatch
         # (flash keeps the (T, T) matrix out of HBM for long prompts,
         # exactly as in TransformerLM._block -- including the "auto"
         # crossover rule)
-        with jax.named_scope("attn/sliding" if spec.window else "attn/full"):
+        with jax.named_scope(_attn_scope(model, spec)):
             o = model.attend_full(spec, q, k, v)
         h, c = _finish_block(model, spec, bp, h, o, gate)
-        return h, k, v, c
+        return h, (k, v), c
 
-    h, k, v, counts = _scan_prefill(model, params, h, layer_fn)
+    h, kv, state, counts = _scan_prefill(model, params, h, layer_fn)
     h = lax.dynamic_slice_in_dim(h, last_index, 1, axis=1)
     h = model._norm(params["ln_f"], h)
     logits = _head_logits(model, params, h)[:, 0]
-    return _prefill_result(model, logits, k, v, counts)
+    return _prefill_result(model, logits, kv, state, counts)
 
 
 def _prefill(model, params, ids0, cache_len):
@@ -436,32 +492,36 @@ def _paged_attention(q, k, v, arenas, layer, blk, off, live, mask):
 
 def _scan_layers(model, params, h, arenas, layer_fn):
     """The paged steps' layer loop over the model's plan: the arenas ride
-    the CARRY whole and each layer indexes them itself by its absolute
-    layer (``layer_fn(spec, h, bp, layer, arenas) -> (h, arenas,
-    counts)``), so the compiled loop updates the donated buffers in
+    the CARRY whole and each layer indexes them itself by its place among
+    the layers of its kind (``layer_fn(spec, h, bp, index, arenas) -> (h,
+    arenas, counts)``; :func:`_kind_indices`: a model of attention layers
+    alone indexes by absolute layer), so the compiled loop updates the
+    donated buffers in
     place; threaded as ``xs`` and stacked ``ys`` they were re-laid out
     and copied every layer (PERF.md, PR 25).  A group of the plan is one
     scan over its stacked periods, the body running the period's layers
     in turn.  -> (h, arenas, counts): ``counts`` the routed expert
     layers' two integers summed over the layers."""
-    counts, base = jnp.zeros((2,), jnp.int32), 0
+    counts = jnp.zeros((2,), jnp.int32)
+    base = {"attention": 0, "kda": 0}
     arenas = tuple(arenas)
     for (repeat, period), stacks in zip(model.plan,
                                         model.group_params(params)):
-        n = len(period)
+        where = _kind_indices(period)
 
-        def body(carry, x, period=period, base=base, n=n):
+        def body(carry, x, period=period, base=dict(base), where=where):
             h, arenas, counts = carry
             bps, r = x
-            for i, (spec, bp) in enumerate(zip(period, bps)):
-                h, arenas, c = layer_fn(spec, h, bp, base + r * n + i,
-                                        arenas)
+            for spec, bp, (n, j) in zip(period, bps, where):
+                h, arenas, c = layer_fn(spec, h, bp,
+                                        base[spec.mixer] + r * n + j, arenas)
                 counts = counts + c
             return (h, arenas, counts), None
 
         (h, arenas, counts), _ = lax.scan(
             body, (h, arenas, counts), (stacks, jnp.arange(repeat)))
-        base += repeat * n
+        for spec in period:
+            base[spec.mixer] += repeat
     return h, arenas, counts
 
 
@@ -472,7 +532,7 @@ def _arenas(k_arena, v_arena, k_scale, v_scale):
 
 def _prefill_suffix_parts(model, params, ids0, last_index, prefix_len,
                           blocks, k_arena, v_arena,
-                          k_scale=None, v_scale=None):
+                          k_scale=None, v_scale=None, *, carried=()):
     """Prefill a prompt SUFFIX against a cached prefix held in paged KV
     blocks: ``ids0`` (1, Ts) is the (bucket-padded) suffix, whose tokens
     live at absolute positions ``prefix_len + i``; ``blocks`` (Pb,) is
@@ -491,7 +551,13 @@ def _prefill_suffix_parts(model, params, ids0, last_index, prefix_len,
     ``k_scale``/``v_scale`` mark int8-quantized arenas
     (``BlockPool(kv_quant="int8")``): the prefix gather dequantizes
     in-flight (int8 block x per-row scale); the returned suffix k/v stay
-    full precision — the engine quantizes them at ``_insert_blocks``."""
+    full precision — the engine quantizes them at ``_insert_blocks``.
+
+    ``carried`` is, for a model with recurrent layers, what they hold at
+    the prefix's end: ``(state (R, 1, H, D, D), tail (R, 1, taps - 1,
+    channels))`` by recurrent layer; the suffix starts from it and the
+    result carries, as :func:`_prefill_parts`' does, what they hold at the
+    suffix's true end."""
     from bigdl_tpu.nn.attention import dot_product_attention
 
     b, ts = ids0.shape
@@ -525,6 +591,11 @@ def _prefill_suffix_parts(model, params, ids0, last_index, prefix_len,
         return g.transpose(1, 0, 2)[None].astype(dtype)
 
     def layer_fn(spec, h, bp, layer):
+        if spec.mixer == "kda":
+            y, state, tail = model.layer_kda(
+                bp, h, carried[0][layer], carried[1][layer], last_index + 1)
+            h, c = _ffn(model, spec, bp, h + y)
+            return h, (state, tail), c
         q, k, v, gate = model.layer_qkv(spec, bp, h, positions)
         kc = jnp.concatenate([prefix(k_arena, k_scale, layer, k.dtype), k], 2)
         vc = jnp.concatenate([prefix(v_arena, v_scale, layer, v.dtype), v], 2)
@@ -533,13 +604,13 @@ def _prefill_suffix_parts(model, params, ids0, last_index, prefix_len,
             kc, vc = (jnp.repeat(x, group, axis=1) for x in (kc, vc))
         o = dot_product_attention(q, kc, vc, mask=masks[spec.window])
         h, c = _finish_block(model, spec, bp, h, o, gate)
-        return h, k, v, c
+        return h, (k, v), c
 
-    h, k, v, counts = _scan_prefill(model, params, h, layer_fn)
+    h, kv, state, counts = _scan_prefill(model, params, h, layer_fn)
     h = lax.dynamic_slice_in_dim(h, last_index, 1, axis=1)
     h = model._norm(params["ln_f"], h)
     logits = _head_logits(model, params, h)[:, 0]
-    return _prefill_result(model, logits, k, v, counts)
+    return _prefill_result(model, logits, kv, state, counts)
 
 
 def _insert_blocks(k_arena, v_arena, k_new, v_new, block_ids,
@@ -577,8 +648,7 @@ def _insert_blocks(k_arena, v_arena, k_new, v_new, block_ids,
     return k_arena, v_arena
 
 
-def _decode_step_paged(model, params, token, pos, live, k_arena,
-                       v_arena, k_scale=None, v_scale=None, *,
+def _decode_step_paged(model, params, token, pos, live, *arenas,
                        table_width: Optional[int] = None,
                        attn_impl: str = "gather"):
     """One cached decode step over S slots against PAGED caches: same
@@ -603,11 +673,15 @@ def _decode_step_paged(model, params, token, pos, live, k_arena,
     ``serving.kvcache.blocks``) are donated by the serving engine and
     carried whole through the layer loop (:func:`_scan_layers`).
 
-    ``k_scale``/``v_scale`` mark int8 arenas
-    (``BlockPool(kv_quant="int8")``): the new k/v row is quantized per
-    (slot, head) on write and the gather dequantizes in-flight.  The
-    Pallas paged kernel reads raw blocks, so quantized pools require
-    the gather path.
+    ``arenas`` are the pool's ``(k, v)`` or, int8
+    (``BlockPool(kv_quant="int8")``), ``(k, v, k_scale, v_scale)``: the new
+    k/v row is quantized per (slot, head) on write and the gather
+    dequantizes in-flight.  The Pallas paged kernel reads raw blocks, so
+    quantized pools require the gather path.  Behind them, for a model
+    with recurrent layers, ride the state arenas ``(state, tail)``
+    (``serving.kvcache.state``: a row a recurrent layer and slot): a
+    recurrent layer reads and writes its slots' rows (``kda/step``) and an
+    idle slot's row stays as it was.
 
     -> (logits (S, V) float32, [the routed layers' two integers, when the
     model has any], *arenas).  The serving engine's decode program is
@@ -617,11 +691,12 @@ def _decode_step_paged(model, params, token, pos, live, k_arena,
     if attn_impl not in ("gather", "paged_kernel"):
         raise ValueError(f"attn_impl must be 'gather' or 'paged_kernel', "
                          f"got {attn_impl!r}")
-    if k_scale is not None and attn_impl == "paged_kernel":
+    kv = _split_arenas(model, arenas)[0]
+    if len(kv) == 4 and attn_impl == "paged_kernel":
         raise ValueError("kv_quant='int8' requires decode_attn='gather' "
                          "(the Pallas paged kernel reads raw blocks)")
     s = token.shape[0]
-    B = k_arena.shape[2]
+    B = kv[0].shape[2]
     h = params["embed"][token][:, None, :]
     if model.pos_encoding == "learned":
         h = h + params["pos"][pos][:, None, :]
@@ -654,6 +729,18 @@ def _decode_step_paged(model, params, token, pos, live, k_arena,
             ids, mode="drop")
 
     def layer_fn(spec, h, bp, layer, arenas):
+        kv, recurrent = _split_arenas(model, arenas)
+        if spec.mixer == "kda":
+            state, tail = recurrent
+            y, row, tail_row = model.layer_kda_step(
+                bp, h, state[layer], tail[layer], active)
+            recurrent = (state.at[layer].set(row), tail.at[layer].set(tail_row))
+            h, counts = _ffn(model, spec, bp, h + y, active[:, None])
+        else:
+            h, kv, counts = attention_layer(spec, h, bp, layer, kv)
+        return h, kv + recurrent, counts
+
+    def attention_layer(spec, h, bp, layer, arenas):
         q, k, v, gate = model.layer_qkv(spec, bp, h, positions)  # (S, H, 1, D)
         if attn_impl == "paged_kernel":
             # in-place block reads via the table (no dense gather);
@@ -664,17 +751,14 @@ def _decode_step_paged(model, params, token, pos, live, k_arena,
                 for a, x in zip(arenas, (k, v)))
             o = paged_decode_attention(q, *arenas, tables, pos, layer=layer)
         else:
-            with jax.named_scope("attn/sliding" if spec.window
-                                 else "attn/full"):
+            with jax.named_scope(_attn_scope(model, spec)):
                 o, arenas = _paged_attention(q, k, v, arenas, layer, blk,
                                              off, live, masks[spec.window])
         h, counts = _finish_block(model, spec, bp, h, o.astype(h.dtype), gate,
                                   token_mask=active[:, None])
         return h, arenas, counts
 
-    h, arenas, counts = _scan_layers(
-        model, params, h, _arenas(k_arena, v_arena, k_scale, v_scale),
-        layer_fn)
+    h, arenas, counts = _scan_layers(model, params, h, arenas, layer_fn)
     h = model._norm(params["ln_f"], h)
     logits = _head_logits(model, params, h)[:, 0].astype(jnp.float32)
     if model.moe_layers:

@@ -205,7 +205,10 @@ class MoESpec(NamedTuple):
     ``routed_scale``.  ``held = (first, count)`` names the experts THIS
     holder has (expert parallelism's share; ``None``: all of them): the
     router still scores all ``n_experts`` and the layer computes the part
-    of the result its own experts give."""
+    of the result its own experts give.  ``score`` is how a router logit
+    becomes a weight: ``"softmax"`` over all experts, or ``"sigmoid"`` an
+    expert, chosen by the score plus a ``select_bias`` parameter
+    (n_experts,) beside the router and weighed by the score alone."""
     n_experts: int
     top_k: int
     width: int
@@ -213,6 +216,7 @@ class MoESpec(NamedTuple):
     routed_scale: float = 1.0
     norm_topk: bool = True
     held: Optional[Tuple[int, int]] = None
+    score: str = "softmax"
 
     @property
     def n_held(self) -> int:
@@ -229,6 +233,8 @@ def init_routed_params(rng, spec: MoESpec, d_model: int):
          "w_gate": jax.random.normal(ks[1], (e, d_model, f)) * s_in,
          "w_up": jax.random.normal(ks[2], (e, d_model, f)) * s_in,
          "w_down": jax.random.normal(ks[3], (e, f, d_model)) * s_mid}
+    if spec.score == "sigmoid":
+        p["select_bias"] = jnp.zeros((spec.n_experts,), jnp.float32)
     if spec.shared_width:
         g = spec.shared_width
         p["shared"] = {
@@ -239,13 +245,23 @@ def init_routed_params(rng, spec: MoESpec, d_model: int):
     return p
 
 
-def route_top_k(router, x2, spec: MoESpec):
-    """Softmax over ALL experts in f32, the ``top_k`` largest, their
-    weights renormalised and scaled: -> (idx (T, k) int32, w (T, k) f32)."""
+def route_top_k(router, x2, spec: MoESpec, select_bias=None):
+    """Scores over ALL experts in f32 (``spec.score``), the ``top_k``
+    largest, their weights renormalised and scaled: -> (idx (T, k) int32,
+    w (T, k) f32).  A sigmoid router selects on ``score + select_bias``
+    and weighs by the unbiased score."""
     logits = jnp.dot(x2, router.astype(x2.dtype),
                      preferred_element_type=jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    w, idx = lax.top_k(probs, spec.top_k)
+    if spec.score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        _, idx = lax.top_k(scores + select_bias.astype(jnp.float32),
+                           spec.top_k)
+        w = jnp.take_along_axis(scores, idx, axis=-1)
+    elif spec.score == "softmax":
+        w, idx = lax.top_k(jax.nn.softmax(logits, axis=-1), spec.top_k)
+    else:
+        raise ValueError(f"MoESpec.score must be 'softmax' or 'sigmoid', "
+                         f"got {spec.score!r}")
     if spec.norm_topk:
         w = w / jnp.sum(w, axis=-1, keepdims=True)
     return idx, w * spec.routed_scale
@@ -300,7 +316,8 @@ def routed_experts(params, x, spec: MoESpec, *, token_mask=None,
     else:
         first = 0 if spec.held is None else spec.held[0]
     with jax.named_scope("moe/route"):
-        idx, w = route_top_k(params["router"], x2, spec)
+        idx, w = route_top_k(params["router"], x2, spec,
+                             params.get("select_bias"))
         local = idx - first
         here = (local >= 0) & (local < count)
         if token_mask is not None:

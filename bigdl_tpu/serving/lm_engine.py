@@ -44,6 +44,14 @@ paged block arena of :mod:`bigdl_tpu.serving.kvcache`:
   (:func:`decode_operands`) -- because what a round pays around its
   device module is a fixed cost a transfer, not bytes.
 
+Recurrent layers: a model whose plan has ``mixer="kda"`` layers keeps,
+beside the pool (built for its ATTENTION layers only), a **state arena**
+(:mod:`bigdl_tpu.serving.kvcache.state`: one fixed-size row a slot and
+recurrent layer).  Its arenas ride behind the pool's through the decode
+step, donated; a prefill writes its slot's rows (``lm/state_insert``), a
+chunk's continuation starts from them, and the radix prefix cache is off
+(no state exists at a shared prefix's boundary).
+
 Sharing: the radix cache maps token prefixes to refcounted block
 chains, so concurrent requests with a common head attend the SAME
 blocks copy-free; decode always writes into a sequence's private tail
@@ -114,9 +122,10 @@ log = logging.getLogger("bigdl_tpu.serving")
 #: inside one ``lm/round``.
 ROUND_PHASES = ("idle", "sched", "admit_host", "prefill", "insert",
                 "first_token", "draft", "decode_dispatch", "decode_wait",
-                "emit", "tree_commit")
+                "emit", "tree_commit", "state_insert")
 (P_IDLE, P_SCHED, P_ADMIT_HOST, P_PREFILL, P_INSERT, P_FIRST_TOKEN, P_DRAFT,
- P_DISPATCH, P_WAIT, P_EMIT, P_TREE_COMMIT) = range(len(ROUND_PHASES))
+ P_DISPATCH, P_WAIT, P_EMIT, P_TREE_COMMIT,
+ P_STATE_INSERT) = range(len(ROUND_PHASES))
 _PHASE_SPANS = tuple("lm/" + p for p in ROUND_PHASES)
 #: a round is logged as slow when it took this long AND this many
 #: running medians of the plain (decode-only) rounds
@@ -403,6 +412,12 @@ class LMMetrics:
         self.moe_experts_hit = 0
         self.moe_expert_layer_rounds = 0
         self.moe_prefill_assignments = 0
+        # recurrent layers (zero for a model without): (slot, recurrent
+        # layer) rows the decode rounds read and wrote, the rows that hold a
+        # seated request's state now, and the state arena's bytes
+        self.state_row_steps = 0
+        self.state_rows_in_use = 0
+        self.state_bytes = 0
         self.started_at = time.perf_counter()
         self._window_s = float(throughput_window_s)
         self._recent: deque = deque()  # (t, n_tokens) per decode step
@@ -428,6 +443,11 @@ class LMMetrics:
             registry.register(
                 prefix + "moe/" + key,
                 FnGauge(lambda k="moe_" + key: getattr(self, k)),
+                replace=True)
+        for key in ("row_steps", "rows_in_use", "bytes"):
+            registry.register(
+                prefix + "state/" + key,
+                FnGauge(lambda k="state_" + key: getattr(self, k)),
                 replace=True)
         registry.register(prefix + "tokens_per_s",
                           FnGauge(lambda: self.snapshot()["tokens_per_s"]),
@@ -460,12 +480,14 @@ class LMMetrics:
 
     def record_step(self, n_active: int, itls_s: Sequence[float],
                     prefill_interrupted: bool = False, *,
-                    live_blocks: int = 0, gathered_blocks: int = 0) -> None:
+                    live_blocks: int = 0, gathered_blocks: int = 0,
+                    state_rows: int = 0) -> None:
         with self._lock:
             now = time.perf_counter()
             self.decode_steps += 1
             self.live_blocks += live_blocks
             self.gathered_blocks += gathered_blocks
+            self.state_row_steps += state_rows
             self.slot_steps += self.slots
             self.active_slot_steps += n_active
             self.peak_active = max(self.peak_active, n_active)
@@ -582,6 +604,9 @@ class LMMetrics:
                         "experts_hit": self.moe_experts_hit,
                         "expert_layer_rounds": self.moe_expert_layer_rounds,
                         "prefill_assignments": self.moe_prefill_assignments},
+                "state": {"row_steps": self.state_row_steps,
+                          "rows_in_use": self.state_rows_in_use,
+                          "bytes": self.state_bytes},
                 "tokens_per_s": (windowed / span) if span > 0 else 0.0,
                 "slot_occupancy":
                     (self.active_slot_steps / self.slot_steps)
@@ -845,9 +870,37 @@ class LMServingEngine:
             _prefill_suffix_parts, _tree_commit_paged,
             _tree_verify_step_paged, _verify_step_paged)
         from bigdl_tpu.quant import dequantize_entry
+        from bigdl_tpu.serving.kvcache import state as kvstate
 
         model._built()
         self.model = model
+        #: the model's recurrent layers (``mixer="kda"``): with any, a state
+        #: arena rides beside the pool and what carries (k, v) pairs only is
+        #: refused HERE, each with its one message
+        self._state_layers = len(model.state_layers)
+        if self._state_layers:
+            for given, what in (
+                    (spec, "spec: a rejected draft would need the recurrent "
+                           "state rolled back, and only the K/V pointer "
+                           "rewinds"),
+                    (migrate, "migrate: the handoff carries (k, v) chains, "
+                              "not a recurrent layer's state"),
+                    (kvtier, "kvtier: demotion, promotion and hibernation "
+                             "carry (k, v) blocks, not a recurrent layer's "
+                             "state"),
+                    (decode_attn == "paged_kernel" or None,
+                     "decode_attn='paged_kernel': the Pallas block-table "
+                     "kernel reads one K/V head a query head, and this "
+                     "model's attention layers share theirs")):
+                if given is not None:
+                    raise ValueError(
+                        f"a model with recurrent layers cannot serve with "
+                        f"{what} (ROADMAP M6)")
+            if placement is not None and placement.tp > 1:
+                raise ValueError(
+                    "a model with recurrent layers cannot serve with "
+                    "tensor-parallel placement: no rule places a recurrent "
+                    "layer's heads and state (ROADMAP M6)")
         self.name = name
         self.placement = placement
         self._params = model.params
@@ -929,8 +982,12 @@ class LMServingEngine:
             # slots worst-case chains + headroom for radix-held prefixes
             num_blocks = 1 + (self.slots + 4) * self.table_width
         # the pool's geometry is the K/V heads': a model whose query heads
-        # share them in groups stores (and moves) the shared heads only
-        L, H, D = model.n_layers, model.n_kv_head, model.head_dim
+        # share them in groups stores (and moves) the shared heads only,
+        # and only its attention layers have an arena layer
+        L, H, D = len(model.kv_layers), model.n_kv_head, model.head_dim
+        if not L:
+            raise ValueError("the paged engine needs at least one attention "
+                             "layer in the model's plan")
         dt = self._params["embed"].dtype
         self.pool = BlockPool(n_layers=L, n_heads=H, head_dim=D,
                               block_len=self.block_len,
@@ -946,7 +1003,29 @@ class LMServingEngine:
             _rep = placement.replicated()
             self.pool.arenas = [jax.device_put(a, _rep)
                                 for a in self.pool.arenas]
-        self.radix = RadixCache(self.pool) if enable_prefix_cache else None
+        #: one row a slot and recurrent layer, beside the pool (None: the
+        #: model keeps K/V alone)
+        self.state = None
+        if self._state_layers:
+            shapes = model.state_shapes
+            self.state = kvstate.StateArena(
+                n_layers=self._state_layers, slots=self.slots,
+                state_shape=shapes[0], tail_shape=shapes[1], tail_dtype=dt)
+            if placement is not None:
+                self.state.arenas = [jax.device_put(a, placement.replicated())
+                                     for a in self.state.arenas]
+        # a radix hit hands a request K/V blocks it did not compute; no
+        # recurrent state exists at that boundary, so such a model shares
+        # no prefix (matched tokens 0 always)
+        self.radix = (RadixCache(self.pool)
+                      if enable_prefix_cache and not self._state_layers
+                      else None)
+        self._prefix_cache_note = (
+            "on" if self.radix is not None else
+            "off: the model has recurrent layers, and a shared prefix's K/V "
+            "blocks come without the state at their boundary (snapshots of "
+            "state at block boundaries: ROADMAP M6)" if self._state_layers
+            else "off: enable_prefix_cache=False")
         #: router-published prefix summary (see attach_radix_summary)
         self.radix_summary = None
         self.kvtier = kvtier
@@ -984,9 +1063,12 @@ class LMServingEngine:
 
         def _prefix_prefill_fn(params, buffers, x):
             del buffers
+            # a recurrent model's suffix starts from its slot's rows
+            carried = (kvstate.read_slot(*x["state"], x["slot"])
+                       if "state" in x else ())
             return _constrain(_prefill_suffix_parts(
                 model, dequantize_entry(params), x["ids"], x["len"] - 1,
-                x["prefix_len"], x["blocks"], *x["kv"]))
+                x["prefix_len"], x["blocks"], *x["kv"], carried=carried))
 
         self.prefix_prefill_cache = CompileCache(
             _prefix_prefill_fn, max_entries=max_cache_entries,
@@ -1028,7 +1110,8 @@ class LMServingEngine:
         self.decode_attn = decode_attn
 
         # every step program takes the pool's arenas last, (k, v) or
-        # (k, v, ks, vs), donated, and hands them back after its result
+        # (k, v, ks, vs), donated, and hands them back after its result;
+        # the decode step takes the state arenas (state, tail) behind them
         _n_kv = len(self.pool.arenas)
 
         def _donated(first):
@@ -1043,8 +1126,13 @@ class LMServingEngine:
                 temperature, keys, *kv,
                 table_width=self.table_width, attn_impl=decode_attn))
 
-        self._decode_jit = jax.jit(_decode_fn, donate_argnums=_donated(2))
+        self._decode_jit = jax.jit(_decode_fn, donate_argnums=tuple(
+            range(2, 2 + len(self._arenas()))))
         self._decode_exec = None
+        if self.state is not None:
+            self._state_insert_jit = jax.jit(kvstate.write_slot,
+                                             donate_argnums=(0, 1))
+            self._state_insert_exec = None
         #: routed expert layers of the model: with any, the decode step
         #: hands their two integers out beside the ids
         self._moe_layers = model.moe_layers
@@ -1162,6 +1250,8 @@ class LMServingEngine:
                         else LMMetrics(self.slots)).publish_to(
             get_registry(), prefix=metrics_prefix)
         self.metrics.spec = self.spec_metrics
+        if self.state is not None:
+            self.metrics.state_bytes = self.state.arena_bytes
         self._publish_kv_metrics(get_registry())
 
         # memory-ledger attribution: KV arenas (+ int8 scale arenas),
@@ -1195,6 +1285,17 @@ class LMServingEngine:
                 self._ledger_keys.append(led.register(
                     "kvcache", f"{name}/scale_arena", _scale_bytes,
                     shape=self.pool.scale_shape, dtype="float32",
+                    device=_dev))
+            if self.state is not None:
+                _state_ref = _weakref.ref(self.state)
+
+                def _state_bytes():
+                    a = _state_ref()
+                    return a.arena_bytes if a is not None else None
+
+                self._ledger_keys.append(led.register(
+                    "kvcache", f"{name}/state_arena", _state_bytes,
+                    shape=self.state.state.shape, dtype="float32",
                     device=_dev))
             self._ledger_keys.append(led.register(
                 "params", f"{name}/staged",
@@ -1374,6 +1475,8 @@ class LMServingEngine:
             self._decode_compiled()
         for b in self.prefill_buckets:
             self._insert_compiled(b)
+        if self.state is not None:
+            self._state_insert_compiled()
         return n
 
     def warmup_prefix(self, suffix_lens: Optional[Sequence[int]] = None,
@@ -1406,10 +1509,41 @@ class LMServingEngine:
                      "len": _np.int32(b),
                      "prefix_len": _np.int32(pb * self.block_len),
                      "blocks": _np.zeros((pb,), _np.int32),
-                     "kv": self.pool.arenas}
+                     "kv": self.pool.arenas, **self._carried_operands(0)}
                 inputs.append(x)
         return self.prefix_prefill_cache.warmup_inputs(
             self._params, self._buffers, inputs)
+
+    def _arenas(self) -> tuple:
+        """What the decode step takes last, donated, and hands back: the
+        pool's arenas and, behind them, the state arenas."""
+        return tuple(self.pool.arenas) + (
+            self.state.arenas if self.state is not None else ())
+
+    def _carried_operands(self, slot: int) -> dict:
+        """What a suffix prefill of a recurrent model takes beside the
+        pool: the state arenas and the slot whose rows it starts from."""
+        if self.state is None:
+            return {}
+        return {"state": self.state.arenas, "slot": np.int32(slot)}
+
+    def _state_insert_compiled(self):
+        """The program that writes one slot's rows of the state arenas
+        (``kvcache.state.write_slot``, both donated)."""
+        if self._state_insert_exec is None:
+            import jax
+            sh = (dict(sharding=self.placement.replicated())
+                  if self.placement is not None else {})
+            sds = jax.ShapeDtypeStruct
+            state, tail = self.state.arenas
+            one = lambda a: sds(a.shape[:1] + (1,) + a.shape[2:],   # noqa: E731
+                                a.dtype, **sh)
+            self._state_insert_exec = self._state_insert_jit.lower(
+                state, tail, one(state), one(tail),
+                sds((), np.int32, **sh)).compile()
+            self._ledger_exec("state_insert", f"slots={self.slots}",
+                              self._state_insert_exec)
+        return self._state_insert_exec
 
     def _decode_compiled(self):
         if self._decode_exec is None:
@@ -1426,7 +1560,7 @@ class LMServingEngine:
                                   self.slots * self.table_width)[0]
             self._decode_exec = self._decode_jit.lower(
                 self._params, jax.ShapeDtypeStruct(ops.shape, ops.dtype, **sh),
-                *self.pool.arenas).compile()
+                *self._arenas()).compile()
             self._ledger_exec("decode", f"slots={self.slots}",
                               self._decode_exec)
         return self._decode_exec
@@ -1660,6 +1794,11 @@ class LMServingEngine:
         emitted.  Adoptions outrank queued submissions (they are
         further along: TTFT is already paid) and defer under pool
         pressure exactly like admissions."""
+        if self.state is not None:
+            raise ValueError(
+                "a model with recurrent layers cannot adopt a migrated "
+                "request: the handoff carries (k, v) chains, not a recurrent "
+                "layer's state (ROADMAP M6)")
         # the deadline rides the handoff on the stream itself; rebind
         # the cancel nudge so a disconnect now wakes THIS worker
         handoff.stream._wake_cb = self._lc_wake
@@ -1778,6 +1917,8 @@ class LMServingEngine:
         self._rd_t0, self._rd_index, self._rd_active = now, index + 1, 0
         if self.watchdog is not None:
             self.watchdog.step_finished()
+        # the rows of the state arena that hold a seated request's state
+        self.metrics.state_rows_in_use = self._n_active * self._state_layers
         dur = now - t0
         if _tracer.enabled:
             _tracer.add_complete(
@@ -2689,8 +2830,11 @@ class LMServingEngine:
         if _tracer.enabled:
             self._ph_args = {"bucket": bucket, "prompt_len": t,
                              "prefix_len": p}
+            if self.state is not None:
+                self._ph_args.update(carried_state=p > 0,
+                                     state_layers=self._state_layers)
         if p == 0:
-            logits, k, v, *moe = self.prefill_cache(
+            logits, k, v, *rest = self.prefill_cache(
                 self._params, self._buffers,
                 {"ids": ids, "len": np.int32(ts)})
         else:
@@ -2700,17 +2844,26 @@ class LMServingEngine:
             pblocks[:nbp] = blocks[:nbp]
             x = {"ids": ids, "len": np.int32(ts),
                  "prefix_len": np.int32(p), "blocks": pblocks,
-                 "kv": self.pool.arenas}
-            logits, k, v, *moe = self.prefix_prefill_cache(
+                 "kv": self.pool.arenas, **self._carried_operands(pf.slot)}
+            logits, k, v, *rest = self.prefix_prefill_cache(
                 self._params, self._buffers, x)
-        if moe:     # a model with routed expert layers: summed over chunks
-            pf.moe = moe[0] if pf.moe is None else pf.moe + moe[0]
+        if self._moe_layers:    # summed over a prompt's chunks
+            moe, *rest = rest
+            pf.moe = moe if pf.moe is None else pf.moe + moe
         self._stamp(P_INSERT)
         if _tracer.enabled:
             self._ph_args = {"slot": pf.slot, "bucket": bucket}
         kv = self.pool.arenas
         self.pool.arenas = self._insert_compiled(bucket)(
             *kv[:2], k, v, ids_w, *kv[2:])
+        if self.state is not None:
+            # the slot's rows at this chunk's true end: what the next chunk
+            # starts from, and after the last what the slot decodes from
+            self._stamp(P_STATE_INSERT)
+            if _tracer.enabled:
+                self._ph_args = {"slot": pf.slot}
+            self.state.arenas = self._state_insert_compiled()(
+                *self.state.arenas, *rest, np.int32(pf.slot))
         self._stamp(P_ADMIT_HOST)
         self._prefill_since_step = True
         pf.logits = logits
@@ -2827,12 +2980,16 @@ class LMServingEngine:
                            self.model.n_head != self.model.n_kv_head)
         gathered = -(-n_live // chunk) * chunk
         ids, *out = self._decode_compiled()(
-            self._params, operands, *self.pool.arenas)
+            self._params, operands, *self._arenas())
         moe = None
         if self._moe_layers:
             moe, *out = out
             moe.copy_to_host_async()    # lands with the ids: one wait
+        if self.state is not None:
+            *out, state, tail = out
+            self.state.arenas = (state, tail)
         self.pool.arenas = out
+        state_rows = len(active) * self._state_layers
         self._stamp(P_WAIT)
         ids = np.asarray(ids)  # sync; (S,) int32
         if moe is not None:
@@ -2845,6 +3002,8 @@ class LMServingEngine:
             if moe is not None:
                 step_args.update(moe_assignments=int(moe[0]),
                                  moe_experts_hit=int(moe[1]))
+            if self.state is not None:
+                step_args["state_rows"] = state_rows
             _tracer.add_complete("lm/decode_step", t0, now - t0, cat="serve",
                                  args=step_args)
             # per-request view of the shared batched step: one
@@ -2887,7 +3046,8 @@ class LMServingEngine:
                 freed.append(i)
         self.metrics.record_step(len(active), itls,
                                  prefill_interrupted=self._prefill_since_step,
-                                 live_blocks=n_live, gathered_blocks=gathered)
+                                 live_blocks=n_live, gathered_blocks=gathered,
+                                 state_rows=state_rows)
         self._prefill_since_step = False
         if freed:
             with self._cv:
@@ -2898,6 +3058,8 @@ class LMServingEngine:
                     self._slots[i] = None
                     self._free.append(i)
                     self._n_active -= 1
+                # (a freed slot's rows of the state arena stay as they
+                # are: the next admission into it overwrites them whole)
                 self._cv.notify_all()
 
     def _step_spec(self):
@@ -3414,6 +3576,7 @@ class LMServingEngine:
             prefilling = len(self._prefilling)
             adopt_q = len(self._adopt_q)
             hibernated = len(self._hibernated)
+        metrics = self.metrics.snapshot()
         return {
             "name": self.name,
             "slots": self.slots,
@@ -3437,6 +3600,10 @@ class LMServingEngine:
             "prefill_cache": self.prefill_cache.stats(),
             "prefix_prefill_cache": self.prefix_prefill_cache.stats(),
             "kvcache": self.kvcache_stats(),
+            "prefix_cache": self._prefix_cache_note,
+            "state": ({"layers": self._state_layers,
+                       "row_bytes": self.state.row_bytes, **metrics["state"]}
+                      if self.state is not None else None),
             "kvtier": (self.kvtier.stats()
                        if self.kvtier is not None else None),
             "radix_summary": (self.radix_summary.stats()
@@ -3446,7 +3613,7 @@ class LMServingEngine:
             "resumes": self.resumes,
             "resume_re_prefills": self.resume_re_prefills,
             "lifecycle": self.lifecycle_stats(),
-            "metrics": self.metrics.snapshot(),
+            "metrics": metrics,
             "rounds": self.rounds_stats(),
             "spec": self._spec_stats(),
         }

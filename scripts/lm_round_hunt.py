@@ -12,7 +12,10 @@ of a benchmark cell.  Not part of the benchmark: it calls
         blocks gathered, the entries of whole tables) and, beside them,
         "logit_rows_to_host": the rows of V float32 logits the window
         copied to the host, against "prefills", its admissions' first
-        tokens (equal when no plain decode round moves a row)
+        tokens (equal when no plain decode round moves a row); for a model
+        with recurrent layers "state_row_steps" (active slots x recurrent
+        layers, summed over the rounds), "state_rows_in_use" and the state's
+        read-and-write bytes a round
     ... --tracer-on     the same with the span tracer enabled for the whole run
                         (not the profiler): what tracing costs end to end
     ... --trace         runs with --trace 1 instead; beside each result line,
@@ -63,7 +66,8 @@ def _watch_engines():
         # (the parent's tree has no logit_rows_to_host: null there)
         return [m.decode_steps, getattr(m, "live_blocks", None),
                 getattr(m, "gathered_blocks", None), m.prefills,
-                getattr(m, "logit_rows_to_host", None)]
+                getattr(m, "logit_rows_to_host", None),
+                getattr(m, "state_row_steps", None)]
 
     def watched_open(self, at=None):
         for e in live():
@@ -78,14 +82,25 @@ def _watch_engines():
         # blocks gathered (the rungs), and what whole tables would hold
         seen["lists"] = []
         for e, before in zip(live(), seen.pop("_lists", [])):
-            steps, listed, gathered, prefills, logit_rows = (
+            steps, listed, gathered, prefills, logit_rows, state_rows = (
                 None if b is None else a - b
                 for a, b in zip(lists(e), before))
+            # a recurrent model's state arena (null on a model without, and
+            # on the parent's tree): (slot, layer) rows the rounds moved, the
+            # rows that hold a request's state now, and the bytes a round
+            # reads and writes of them on average
+            arena = getattr(e, "state", None)
             seen["lists"].append({
                 "decode_steps": steps, "live_blocks": listed,
                 "gathered_blocks": gathered,
                 "table_entries": steps * e.slots * e.table_width,
-                "prefills": prefills, "logit_rows_to_host": logit_rows})
+                "prefills": prefills, "logit_rows_to_host": logit_rows,
+                "state_row_steps": state_rows,
+                "state_rows_in_use": getattr(e.metrics, "state_rows_in_use",
+                                             None),
+                "state_bytes_a_round": (
+                    None if arena is None or not steps
+                    else 2 * arena.row_bytes * state_rows / steps)})
         del engines[:]
         return t
 

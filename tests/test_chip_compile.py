@@ -441,6 +441,130 @@ def test_laguna_cell_compiles_for_v5e_and_keeps_the_arenas_in_place(
     assert total < 14.5e9, total
 
 
+@pytest.mark.parametrize("program", ["decode", "prefill1024", "suffix1024"])
+def test_solar2_cell_compiles_for_v5e_and_keeps_the_state_in_place(
+        sds, monkeypatch, program, capsys):
+    """``solar2-cell``, beside ``laguna-cell``: the programs of the benchmark's
+    ``solar2.backlog`` cell at its own geometry (``benchmarks/configs/
+    solar-open2-250b.json``: one period of a softmax and three KDA layers, 40
+    held experts, 128 slots, table width 128, ONE K/V arena layer of 16,400
+    blocks, a state arena of 3 x 128 rows of 64 x 128 x 128 float32),
+    compiled for the described v5e: the decode step with the pool's AND the
+    state's arenas donated (aliased in place: no copy of the 1.6-GB state, no
+    K/V-arena-shaped copy), the 1,024-token prefill (flash kernel on the
+    softmax layer, the chunked scan on the others, state and tail handed out
+    beside k and v of the one attention layer) and the suffix prefill that
+    starts from a slot's rows.  Prints what the configuration's
+    ``memory_arithmetic`` quotes; memory before any run."""
+    import json
+    from benchmarks.drivers import serve_solar2 as D
+    from bigdl_tpu.models.transformer import generate as G
+    from bigdl_tpu.serving.kvcache import state as kvstate
+    from bigdl_tpu.serving.kvcache.blocks import BlockPool
+
+    monkeypatch.setattr(fa, "_use_interpret", lambda: False)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "solar-open2-250b.json")) as f:
+        c = json.load(f)
+    eng = c["engine"]
+    model = D.build_model(c)
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda: D.program_params(model, 0, c, "bfloat16")))
+    weight_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                       for a in jax.tree_util.tree_leaves(params))
+    # ISSUE 33's table: 4 x 40 experts of 15,728,640; a softmax layer's
+    # 109,051,904 and three KDA layers' 137,732,288 outside them; router,
+    # shared expert and two norms a layer; embedding and head; the final norm
+    values = (160 * 15_728_640 + 109_051_904 + 3 * 137_732_288
+              + 4 * 17_047_552 + 2 * 24_576 * 4096 + 4096)
+    assert values == 3_308_352_064
+    # (bf16 but for A_log and dt_bias, float32: 2 B more each; the router's
+    # selection bias, 320 float32 a layer, is not in the table)
+    assert weight_bytes == 2 * values + 2 * 3 * (64 + 8192) + 4 * 4 * 320, weight_bytes
+    assert model.kv_layers == (0,) and model.state_layers == (1, 2, 3)
+    heads, d = model.n_kv_head, model.head_dim
+    slots, width = eng["slots"], eng["cache_len"] // eng["block_len"]
+    i32 = lambda *shape: sds(shape, jnp.int32)              # noqa: E731
+
+    def arenas_of():
+        pool = BlockPool(n_layers=len(model.kv_layers), n_heads=heads,
+                         head_dim=d, block_len=eng["block_len"],
+                         num_blocks=eng["num_blocks"], dtype=jnp.bfloat16)
+        arena = kvstate.StateArena(
+            n_layers=len(model.state_layers), slots=slots,
+            state_shape=model.state_shapes[0], tail_shape=model.state_shapes[1],
+            tail_dtype=jnp.bfloat16)
+        return [pool.k, pool.v, arena.state, arena.tail]
+
+    arenas = [sds(a.shape, a.dtype) for a in jax.eval_shape(arenas_of)]
+    assert arenas[0].shape == (1, 16400, 16, 1024)          # ONE layer, not four
+    assert arenas[2].shape == (3, 128, 64, 128, 128) and arenas[2].dtype == jnp.float32
+    assert arenas[3].shape == (3, 128, 3, 24576)
+    arena_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in arenas)
+    state_dims = "f32[3,128,64,128,128]"
+    if program == "decode":
+        def step(p, tok, pos, live, temperature, keys, *kv):
+            return G._decode_pick_paged(model, p, tok, pos, live, temperature,
+                                        keys, *kv, table_width=width,
+                                        attn_impl="gather")
+
+        compiled, text = _compile(
+            step, params, i32(slots), i32(slots), i32(3, slots * width),
+            sds((slots,), jnp.float32), sds((slots, 2), jnp.uint32),
+            *arenas, donate_argnums=(6, 7, 8, 9))
+        ids, counts = compiled.out_info[:2]
+        assert ids.shape == (slots,) and ids.dtype == jnp.int32
+        assert counts.shape == (2,) and len(compiled.out_info) == 6
+        mem = compiled.memory_analysis()
+        # pool and state alike are updated where they lie
+        assert mem.alias_size_in_bytes >= 0.99 * arena_bytes
+        for dims in ("bf16[1,16400,16,1024]", state_dims):
+            moved = [ln.strip()[:160] for ln in text.splitlines()
+                     if dims in ln and re.search(
+                         r" copy(-start)?\(|AllocateBuffer", ln)]
+            assert not moved, moved
+        assert mem.temp_size_in_bytes < 3.0e9, mem.temp_size_in_bytes
+    elif program == "prefill1024":
+        def step(p, ids, n):
+            return G._prefill_parts(model, p, ids, n - 1)
+
+        compiled, text = _compile(step, params, i32(1, 1024), i32())
+        logits, k, v, counts, state, tail = compiled.out_info
+        assert logits.shape == (1, 24576) and counts.shape == (2,)
+        assert k.shape == v.shape == (1, 1, 8, 1024, 128)   # the one K/V layer
+        assert state.shape == (3, 1, 64, 128, 128) and state.dtype == jnp.float32
+        assert tail.shape == (3, 1, 3, 24576) and tail.dtype == jnp.bfloat16
+        mem = compiled.memory_analysis()
+        arena_bytes = 0
+        assert "flash_attention_fwd" in text
+        assert not re.search(r"\[(1,)?(64|8,8),1024,1024\]", text)   # no (T, T) scores
+        assert "triangular" in text.lower() or "custom-call" in text
+    else:
+        def step(p, ids, n, prefix_len, blocks, slot, k, v, state, tail):
+            return G._prefill_suffix_parts(
+                model, p, ids, n - 1, prefix_len, blocks, k, v,
+                carried=kvstate.read_slot(state, tail, slot))
+
+        compiled, text = _compile(step, params, i32(1, 1024), i32(), i32(),
+                                  i32(64), i32(), *arenas)
+        assert compiled.out_info[4].shape == (3, 1, 64, 128, 128)
+        mem = compiled.memory_analysis()
+    assert "ragged" in text.lower() or "custom-call" in text
+    total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    with capsys.disabled():
+        print(f"\nsolar2-cell {program}: weights {weight_bytes / 1e9:.3f} GB, "
+              f"arenas {arena_bytes / 1e9:.3f} GB, "
+              f"arguments {mem.argument_size_in_bytes / 1e9:.3f} GB, "
+              f"temporaries {mem.temp_size_in_bytes / 1e9:.3f} GB, "
+              f"outputs {mem.output_size_in_bytes / 1e9:.3f} GB, "
+              f"aliased {mem.alias_size_in_bytes / 1e9:.3f} GB, "
+              f"total {total / 1e9:.3f} GB")
+    assert total < 14.5e9, total
+
+
 def test_lm_flash_remat_train_step_compiles_for_v5e(sds, monkeypatch):
     """A TransformerLM training step with ``attention_impl="flash"``, RoPE
     and remat: the flash forward and both backward kernels inside the

@@ -221,10 +221,12 @@ def test_one_bundle_per_distinct_incident(tmp_path, recorder):
 
 
 def test_dedup_window_expiry_rearms(tmp_path, recorder):
-    recorder.dedup_window_s = 0.05
+    # (a window of 0.05 s was shorter than the first bundle's write under six
+    # loaded test workers: the second record then re-armed and the test failed)
+    recorder.dedup_window_s = 0.5
     assert recorder.record("stall", key="w") is not None
     assert recorder.record("stall", key="w") is None
-    time.sleep(0.06)
+    time.sleep(0.6)
     assert recorder.record("stall", key="w") is not None
 
 
