@@ -798,12 +798,15 @@ def pick_rows(logits, temperature, keys):
 
 
 def _decode_pick_paged(model, params, token, pos, live, temperature, keys,
-                       *kv, **kw):
+                       prev_ids, *kv, **kw):
     """:func:`_decode_step_paged` with the pick applied on the device: the
     serving engine's decode program.  -> (ids (S,) int32, [the routed
     layers' counts], *arenas): no output has the vocabulary's width, so a
-    round hands the host S integers (and the ids stay on the device for a
-    later step to take)."""
+    round hands the host S integers.  ``prev_ids`` (S,) int32 is what the
+    previous call returned, still on the device: a slot whose ``token`` is
+    negative takes its entry of it, so a round can be enqueued before its
+    predecessor's ids have reached the host."""
+    token = jnp.where(token < 0, prev_ids, token)
     logits, *rest = _decode_step_paged(model, params, token, pos, live, *kv,
                                        **kw)
     return (pick_rows(logits, temperature, keys), *rest)

@@ -23,7 +23,7 @@ paged block arena of :mod:`bigdl_tpu.serving.kvcache`:
   the resident buffers in place.
 - **decode** — ONE fixed-shape executable stepping all S slots, each at
   its own position, reading the blocks a **live list** names: each
-  round ``_step`` lists, on the host, the blocks its active slots hold
+  round ``_dispatch`` lists, on the host, the blocks its active slots hold
   up to their write positions (which block, whose, where in the chain:
   :func:`~bigdl_tpu.serving.kvcache.blocks.live_list`), padded to the
   ``S x M`` entries of whole tables; the step attends the list a chunk
@@ -42,7 +42,16 @@ paged block arena of :mod:`bigdl_tpu.serving.kvcache`:
   hands the round ONE operand vector -- tokens, positions,
   temperatures, keys and the live list
   (:func:`decode_operands`) -- because what a round pays around its
-  device module is a fixed cost a transfer, not bytes.
+  device module is a fixed cost a transfer, not bytes.  A round RUNS ONE
+  AHEAD: the step takes a slot's token from the previous step's ids on
+  the device (``TAKE_PREV`` in the token's place), so the worker
+  enqueues round n+1 (``_dispatch``) before it reads round n's ids
+  (``_collect``) whenever nothing needs the host in between
+  (``_runs_ahead``: nobody to seat, no chunk to prefill, nothing to
+  hibernate, cancel or close), and collects first -- a drain --
+  otherwise.  ``LMMetrics.rounds_ahead`` counts the rounds enqueued
+  behind another, ``rows_discarded`` the rows of a stream that had ended
+  on its ``eos`` a round before.
 
 Recurrent layers: a model whose plan has ``mixer="kda"`` layers keeps,
 beside the pool (built for its ATTENTION layers only), a **state arena**
@@ -133,10 +142,17 @@ SLOW_ROUND_S = 1.0
 SLOW_ROUND_MEDIANS = 8.0
 
 
+#: in a slot's place of ``token``: the step takes the slot's entry of the
+#: previous step's ids, which are still on the device
+TAKE_PREV = -1
+
+
 def decode_operands(slots: int, entries: int):
     """What the host hands a decode round, as ONE int32 vector and the
     views a round fills: ``-> (operands, token (S,), pos (S,),
     temperature (S,) float32, keys (S, 2) uint32, live (3, entries))``.
+    A slot's ``token`` is its last token, or ``TAKE_PREV`` where that
+    token is the previous round's pick and has not reached the host.
     One vector because every host operand of a step costs its dispatch a
     transfer of its own (0.13-0.24 ms each on a v5e, PERF.md PR 31),
     whatever its size; a fresh one a round because the transfer may still
@@ -402,6 +418,11 @@ class LMMetrics:
         # first token, S x W a verify round, none for a plain decode
         # round (its step picks on the device and hands out S ids)
         self.logit_rows_to_host = 0
+        # decode rounds enqueued while their predecessor was still on the
+        # device (of ``decode_steps``), and rows of such a round thrown
+        # away because their stream had ended on its eos a round before
+        self.rounds_ahead = 0
+        self.rows_discarded = 0
         self.slot_steps = 0
         self.active_slot_steps = 0
         self.peak_active = 0
@@ -434,7 +455,8 @@ class LMMetrics:
                           replace=True)
         for key in ("requests", "rejected", "completed", "tokens",
                     "prefills", "decode_steps", "live_blocks",
-                    "gathered_blocks", "logit_rows_to_host"):
+                    "gathered_blocks", "logit_rows_to_host",
+                    "rounds_ahead", "rows_discarded"):
             registry.register(prefix + key,
                               FnGauge(lambda k=key: getattr(self, k)),
                               replace=True)
@@ -481,10 +503,13 @@ class LMMetrics:
     def record_step(self, n_active: int, itls_s: Sequence[float],
                     prefill_interrupted: bool = False, *,
                     live_blocks: int = 0, gathered_blocks: int = 0,
-                    state_rows: int = 0) -> None:
+                    state_rows: int = 0, ahead: bool = False,
+                    discarded: int = 0) -> None:
         with self._lock:
             now = time.perf_counter()
             self.decode_steps += 1
+            self.rounds_ahead += ahead
+            self.rows_discarded += discarded
             self.live_blocks += live_blocks
             self.gathered_blocks += gathered_blocks
             self.state_row_steps += state_rows
@@ -600,6 +625,8 @@ class LMMetrics:
                 "live_blocks": self.live_blocks,
                 "gathered_blocks": self.gathered_blocks,
                 "logit_rows_to_host": self.logit_rows_to_host,
+                "rounds_ahead": self.rounds_ahead,
+                "rows_discarded": self.rows_discarded,
                 "moe": {"assignments": self.moe_assignments,
                         "experts_hit": self.moe_experts_hit,
                         "expert_layer_rounds": self.moe_expert_layer_rounds,
@@ -672,6 +699,24 @@ class _Slot:
         self.spec_rounds = 0            # rounds of EMA evidence
         self.probe_in = 0               # plain rounds until re-probe
         self.tree_rung = 0              # shape-ladder rung (tree mode)
+
+
+class _Round:
+    """A decode round on the device: what ``_dispatch`` leaves for
+    ``_collect``.  ``rows`` are ``(slot, its _Slot, emits, last)``: a
+    replayed row emits nothing, ``last`` is the row of a stream that
+    finishes by its count."""
+
+    __slots__ = ("ids", "moe", "rows", "t0", "ahead", "n_live", "gathered",
+                 "n_last", "sampled")
+
+    def __init__(self, t0: float, ahead: bool):
+        self.ids = self.moe = None      # device arrays, on their way
+        self.rows: list = []
+        self.t0 = t0                    # when its dispatch began
+        self.ahead = ahead              # enqueued behind a round in flight
+        self.n_live = self.gathered = self.n_last = 0
+        self.sampled: list = []         # (rid, slot, step) of traced requests
 
 
 class KVHandoff:
@@ -1117,18 +1162,26 @@ class LMServingEngine:
         def _donated(first):
             return tuple(range(first, first + _n_kv))
 
-        def _decode_fn(params, operands, *kv):
-            # the step picks its tokens: (S,) ids leave, never (S, V) logits
+        def _decode_fn(params, operands, prev_ids, *kv):
+            # the step picks its tokens: (S,) ids leave, never (S, V) logits,
+            # and come back as the next call's prev_ids without a transfer
             token, pos, temperature, keys, live = split_decode_operands(
                 operands, self.slots)
             return _constrain(_decode_pick_paged(
                 model, dequantize_entry(params), token, pos, live,
-                temperature, keys, *kv,
+                temperature, keys, prev_ids, *kv,
                 table_width=self.table_width, attn_impl=decode_attn))
 
         self._decode_jit = jax.jit(_decode_fn, donate_argnums=tuple(
-            range(2, 2 + len(self._arenas()))))
+            range(3, 3 + len(self._arenas()))))
         self._decode_exec = None
+        #: the last decode step's ids, on the device (zeros before the first)
+        _ids = np.zeros((self.slots,), np.int32)
+        self._ids = (jax.device_put(_ids, placement.replicated())
+                     if placement is not None else jax.device_put(_ids))
+        #: the decode round on the device that has not been collected
+        self._flying: Optional[_Round] = None
+        self._step_end = 0.0    # where the last lm/decode_step span ended
         if self.state is not None:
             self._state_insert_jit = jax.jit(kvstate.write_slot,
                                              donate_argnums=(0, 1))
@@ -1560,6 +1613,7 @@ class LMServingEngine:
                                   self.slots * self.table_width)[0]
             self._decode_exec = self._decode_jit.lower(
                 self._params, jax.ShapeDtypeStruct(ops.shape, ops.dtype, **sh),
+                jax.ShapeDtypeStruct(self._ids.shape, self._ids.dtype, **sh),
                 *self._arenas()).compile()
             self._ledger_exec("decode", f"slots={self.slots}",
                               self._decode_exec)
@@ -1905,6 +1959,7 @@ class LMServingEngine:
         return (not self._queue and not self._adopt_q
                 and not self._resume_q
                 and not self._n_active and not self._prefilling
+                and self._flying is None
                 and not self._closing and not self._abort
                 and not self._lc_nudge)
 
@@ -1979,6 +2034,29 @@ class LMServingEngine:
             self._adm_rid = self._adm_note = None
         return deferred
 
+    def _runs_ahead(self) -> bool:
+        """Whether the next decode round may be enqueued before the one
+        on the device is collected: nothing needs the host in between.
+        Nobody waits who could be seated (on a free slot, or on one that
+        the round in flight vacates BY ITS COUNT; a stream's eos cannot
+        be known, so its slot runs ahead and the row is discarded if it
+        had ended), no chunk is left to prefill, nothing is to hibernate,
+        no cancel has nudged, the engine is not closing, and some slot
+        goes on into the next round.  What this cannot see, a seated
+        stream cancelled or past its deadline, ``_lifecycle_dead`` says.
+        Caller holds ``_cv``."""
+        rnd = self._flying
+        if (rnd is None or self._closing or self._abort or self._lc_nudge
+                or self._prefilling or self._hibernate_req):
+            return False
+        # the seated slots that decode in the next round
+        going_on = self._n_active - rnd.n_last
+        if going_on <= 0:
+            return False
+        return not ((self._queue or self._adopt_q or self._resume_q)
+                    and (self._free or rnd.n_last)
+                    and going_on < self._slot_limit)
+
     def _run(self):
         try:
             while True:
@@ -2008,69 +2086,29 @@ class LMServingEngine:
                         break
                     if self.watchdog is not None:
                         self.watchdog.step_started()
+                    ahead = self._runs_ahead()
                     # cancelled/expired requests leave their holding
                     # stations BEFORE this round admits anything
                     self._lifecycle_sweep_locked()
-                    # in-flight = decoding + mid-prefill: both hold slots
-                    inflight = self._n_active + len(self._prefilling)
-                    adopts = []
-                    # adoptions outrank submissions: their TTFT is paid
-                    while (self._free and self._adopt_q
-                           and (inflight + len(adopts)) < self._slot_limit):
-                        adopts.append((self._free.pop(),
-                                       self._adopt_q.popleft()))
-                    # resumes rank with adoptions (same reason) but
-                    # after them: a migrated chain in transit is hotter
-                    # than a hibernated one at rest
-                    resumes = []
-                    while (self._free and self._resume_q
-                           and (inflight + len(adopts) + len(resumes))
-                           < self._slot_limit):
-                        resumes.append((self._free.pop(),
-                                        self._resume_q.popleft()))
-                    admits = []
-                    while (self._free and self._queue
-                           and (inflight + len(adopts) + len(resumes)
-                                + len(admits)) < self._slot_limit):
-                        admits.append((self._free.pop(),
-                                       self._queue.popleft()))
-                if self.migrate is not None:
-                    # prefill-phase occupancy: one sample per scheduler
-                    # round (a prefill replica has no decode steps, so
-                    # this is the phase's slot-utilization signal; its
-                    # decode_steps gauge reads as scheduler rounds)
-                    self.metrics.record_step(
-                        min(self.slots,
-                            inflight + len(adopts) + len(admits)), [])
-                deferred_adopts = self._admit_each(
-                    "adopt", self._adopt_into, adopts)
-                deferred_resumes = self._admit_each(
-                    "resume", self._resume_into, resumes)
-                deferred = self._admit_each("submit", self._admit, admits)
-                admitted = (len(adopts) + len(resumes) + len(admits)
-                            - len(deferred_adopts) - len(deferred_resumes)
-                            - len(deferred))
-                if deferred or deferred_adopts or deferred_resumes:
-                    # pool pressure: requeue at the FRONT (FIFO order
-                    # preserved) and return the slots — blocks free as
-                    # active streams finish, then admission retries
-                    with self._cv:
-                        for slot, req in reversed(deferred):
-                            self._free.append(slot)
-                            self._queue.appendleft(req)
-                        for slot, h in reversed(deferred_adopts):
-                            self._free.append(slot)
-                            self._adopt_q.appendleft(h)
-                        for slot, hib in reversed(deferred_resumes):
-                            self._free.append(slot)
-                            self._resume_q.appendleft(hib)
-                        if not self._n_active and not self._prefilling:
-                            # nothing in flight to free capacity (a
-                            # ledger-watermark deferral with idle
-                            # slots): wait briefly instead of spinning
-                            # on the retry
-                            self._cv.wait(0.05)
-                self._lifecycle_round()
+                # a seated stream to honour needs the host as well
+                dead = self._lifecycle_dead() if ahead else None
+                if ahead and not dead:
+                    # round n+1 goes to the device, then round n's ids are
+                    # read: completion, the copy, emission and this
+                    # dispatch hide behind a module
+                    nxt = self._dispatch(ahead=True)
+                    self._collect(self._flying)
+                    self._flying = nxt
+                    self._round_end(0)
+                    continue
+                if self._flying is not None:
+                    # a drain: what follows needs the slots as the round
+                    # in flight leaves them
+                    self._collect(self._flying)
+                    self._flying = None
+                    self._stamp(P_SCHED)
+                admitted = self._seat_waiting()
+                self._lifecycle_round(dead)
                 if self._hibernate_req:
                     self._service_hibernations()
                 if self._chunk_cap is not None and self._prefilling:
@@ -2098,7 +2136,9 @@ class LMServingEngine:
                     if self.draft is not None:
                         self._step_spec()
                     else:
-                        self._step()
+                        # left on the device: the next round of the loop
+                        # runs ahead of it or collects it first
+                        self._flying = self._dispatch(ahead=False)
                 self._round_end(admitted)
         except BaseException as e:  # noqa: BLE001
             self._fail_all(e)
@@ -2110,6 +2150,71 @@ class LMServingEngine:
             if self.watchdog is not None:
                 self.watchdog.step_finished()
         self._fail_all(ServingClosed("engine closed before completion"))
+
+    def _seat_waiting(self) -> int:
+        """Hand the free slots to who waits -- adoptions, resumes, then
+        submissions -- and run their admissions; returns how many were
+        seated.  No decode round is on the device."""
+        with self._cv:
+            # in-flight = decoding + mid-prefill: both hold slots
+            inflight = self._n_active + len(self._prefilling)
+            adopts = []
+            # adoptions outrank submissions: their TTFT is paid
+            while (self._free and self._adopt_q
+                   and (inflight + len(adopts)) < self._slot_limit):
+                adopts.append((self._free.pop(),
+                               self._adopt_q.popleft()))
+            # resumes rank with adoptions (same reason) but
+            # after them: a migrated chain in transit is hotter
+            # than a hibernated one at rest
+            resumes = []
+            while (self._free and self._resume_q
+                   and (inflight + len(adopts) + len(resumes))
+                   < self._slot_limit):
+                resumes.append((self._free.pop(),
+                                self._resume_q.popleft()))
+            admits = []
+            while (self._free and self._queue
+                   and (inflight + len(adopts) + len(resumes)
+                        + len(admits)) < self._slot_limit):
+                admits.append((self._free.pop(),
+                               self._queue.popleft()))
+        if self.migrate is not None:
+            # prefill-phase occupancy: one sample per scheduler
+            # round (a prefill replica has no decode steps, so
+            # this is the phase's slot-utilization signal; its
+            # decode_steps gauge reads as scheduler rounds)
+            self.metrics.record_step(
+                min(self.slots,
+                    inflight + len(adopts) + len(admits)), [])
+        deferred_adopts = self._admit_each(
+            "adopt", self._adopt_into, adopts)
+        deferred_resumes = self._admit_each(
+            "resume", self._resume_into, resumes)
+        deferred = self._admit_each("submit", self._admit, admits)
+        if deferred or deferred_adopts or deferred_resumes:
+            # pool pressure: requeue at the FRONT (FIFO order
+            # preserved) and return the slots — blocks free as
+            # active streams finish, then admission retries
+            with self._cv:
+                for slot, req in reversed(deferred):
+                    self._free.append(slot)
+                    self._queue.appendleft(req)
+                for slot, h in reversed(deferred_adopts):
+                    self._free.append(slot)
+                    self._adopt_q.appendleft(h)
+                for slot, hib in reversed(deferred_resumes):
+                    self._free.append(slot)
+                    self._resume_q.appendleft(hib)
+                if not self._n_active and not self._prefilling:
+                    # nothing in flight to free capacity (a
+                    # ledger-watermark deferral with idle
+                    # slots): wait briefly instead of spinning
+                    # on the retry
+                    self._cv.wait(0.05)
+        return (len(adopts) + len(resumes) + len(admits)
+                - len(deferred_adopts) - len(deferred_resumes)
+                - len(deferred))
 
     # -- request lifecycle (deadlines / cooperative cancel) ------------- #
     def _lc_wake(self):
@@ -2239,25 +2344,35 @@ class LMServingEngine:
                 pass
             self._lc_truncate(hib.stream, rid, station="hibernated")
 
-    def _lifecycle_round(self) -> None:
-        """Per-round lifecycle pass over the stations that DO hold a
-        decode slot.  The ``serving.cancel`` fault site crosses here —
+    def _lifecycle_dead(self) -> bool:
+        """Whether a stream that holds a decode slot is cancelled or past
+        its deadline.  The ``serving.cancel`` fault site crosses here —
         one crossing per seated stream per round, and an injected
         fault IS that client disconnecting (how the chaos replayer
-        makes a disconnect storm); then cancelled/expired streams are
-        honored same-iteration: slot recycled, blocks released,
-        drafter state dropped, stream finished with the typed
-        truncation marker."""
+        makes a disconnect storm)."""
         from bigdl_tpu.resilience.faults import fault_point
-        with self._cv:
-            seated = [st.stream for st in self._slots if st is not None]
-            seated += [pf.req.stream for pf in self._prefilling]
+        seated = [st.stream for st in self._slots if st is not None]
+        seated += [pf.req.stream for pf in self._prefilling]
         for s in seated:
             try:
                 fault_point("serving.cancel", name=self.name,
                             rid=s.request_id)
             except (TransientBackendError, BackendLostError):
                 s.cancel()
+        now = time.monotonic()
+        return any(s.cancel_requested or s.expired(now) for s in seated)
+
+    def _lifecycle_round(self, dead: Optional[bool] = None) -> None:
+        """Per-round lifecycle pass over the stations that DO hold a
+        decode slot (``dead``: what ``_lifecycle_dead`` said, where this
+        round of the loop has asked already): cancelled/expired streams
+        are honored same-iteration: slot recycled, blocks released,
+        drafter state dropped, stream finished with the typed
+        truncation marker.  No decode round is on the device."""
+        if dead is None:
+            dead = self._lifecycle_dead()
+        if not dead:
+            return
         now = time.monotonic()
 
         def _dead(stream):
@@ -2951,54 +3066,103 @@ class LMServingEngine:
             self._slots[slot] = st
             self._n_active += 1
 
-    def _step(self):
-        t0 = self._stamp(P_DISPATCH)
+    def _dispatch(self, ahead: bool) -> _Round:
+        """The first half of a plain decode round: build its operands
+        from what the host knows WITHOUT the previous round's ids --
+        positions, sampling keys, counts, tables (blocks are allotted
+        whole at admission) -- advance those books and enqueue the step.
+        ``ahead``: the previous round is still on the device (it is
+        ``self._flying``), so a slot of it takes its token from that
+        round's ids there (``TAKE_PREV``), and a slot whose count ends
+        with it is not in this round.  The device runs the calls in
+        dispatch order, which is all the ordering relied on.  At least
+        one slot decodes (``_n_active``, ``_runs_ahead``)."""
+        rnd = _Round(self._stamp(P_DISPATCH), ahead)
         # one operand vector a round; what stays zero in it: an idle
         # slot, a greedy pick (no temperature, no key), nobody's blocks
         operands, token, pos, temperature, keys, live = decode_operands(
             self.slots, self.slots * self.table_width)
-        active, chains, n_live = [], [], 0
+        rows, chains = rnd.rows, []
+        # the slots whose last token is a pick of the round on the device
+        # (``last0`` is one behind until that round is collected)
+        taken = ({i for i, _, emits, _ in self._flying.rows if emits}
+                 if ahead else ())
         for i, st in enumerate(self._slots):
-            if st is not None:
-                active.append((i, st))
-                token[i] = st.last0
-                pos[i] = st.pos_next
-                if st.temperature > 0.0 and st.step_keys is not None:
-                    temperature[i] = st.temperature
-                    keys[i] = st.step_keys[st.step_idx]
-                # what the round reads of the slot's chain: the blocks up
-                # to the one its new row is written to
-                held = st.table[:st.pos_next // self.block_len + 1]
-                chains.append((i, held))
-                n_live += len(held)
-        if not active:
-            return
-        self._rd_active = len(active)
+            if st is None or st.remaining <= 0:
+                continue        # idle, or its last row is in flight
+            token[i] = TAKE_PREV if i in taken else st.last0
+            pos[i] = st.pos_next
+            if st.temperature > 0.0 and st.step_keys is not None:
+                temperature[i] = st.temperature
+                keys[i] = st.step_keys[st.step_idx]
+            # what the round reads of the slot's chain: the blocks up
+            # to the one its new row is written to
+            held = st.table[:st.pos_next // self.block_len + 1]
+            chains.append((i, held))
+            rnd.n_live += len(held)
+            if _tracer.enabled and _tracer.sampled(st.rid):
+                rnd.sampled.append((st.rid, i, st.step_idx))
+            emits = not st.replay
+            if not emits:
+                # payload-less resume: this step rebuilds last0's KV
+                # row; the next token was already emitted before
+                # hibernation — take it from the replay queue instead
+                # of the step's id (no re-emit, no ITL sample).  The
+                # queue preserves the original step_keys alignment, so
+                # post-replay sampling is bit-exact.
+                st.last0 = st.replay.popleft()
+            st.pos_next += 1
+            st.step_idx += 1
+            st.remaining -= 1
+            last = emits and st.remaining <= 0
+            rnd.n_last += last
+            rows.append((i, st, emits, last))
+        if not self._rd_active:     # (a round collected here names it)
+            self._rd_active = len(rows)
         live[:] = live_list(chains, live.shape[1], self.slots)
         # what the step gathers: the chunks that hold a listed block
         chunk = list_chunk(self.slots,
                            self.model.n_head != self.model.n_kv_head)
-        gathered = -(-n_live // chunk) * chunk
+        rnd.gathered = -(-rnd.n_live // chunk) * chunk
         ids, *out = self._decode_compiled()(
-            self._params, operands, *self._arenas())
-        moe = None
+            self._params, operands, self._ids, *self._arenas())
+        rnd.ids = self._ids = ids
+        ids.copy_to_host_async()
         if self._moe_layers:
-            moe, *out = out
-            moe.copy_to_host_async()    # lands with the ids: one wait
+            rnd.moe, *out = out
+            rnd.moe.copy_to_host_async()    # lands with the ids: one wait
         if self.state is not None:
             *out, state, tail = out
             self.state.arenas = (state, tail)
         self.pool.arenas = out
-        state_rows = len(active) * self._state_layers
+        return rnd
+
+    def _collect(self, rnd: _Round) -> None:
+        """The second half: wait for the round's ids, emit them, take
+        the ITL samples, finish and free.  A row whose slot no longer
+        holds its stream is thrown away: the stream ended on its eos in
+        the round before, while this one was on the device (the row
+        wrote one position further into a block the stream still held
+        when the step was enqueued; whatever reuses the block is
+        enqueued behind that step)."""
         self._stamp(P_WAIT)
-        ids = np.asarray(ids)  # sync; (S,) int32
+        ids = np.asarray(rnd.ids)  # sync; (S,) int32
+        moe = rnd.moe
         if moe is not None:
             moe = np.asarray(moe)
             self.metrics.record_moe(moe, self._moe_layers)
         now = self._stamp(P_EMIT)
+        n_rows = self._rd_active = len(rnd.rows)
+        state_rows = n_rows * self._state_layers
+        # a round enqueued behind another was the device's from the
+        # instant its predecessor's ids were out: consecutive spans abut
+        t0 = max(rnd.t0, self._step_end)
+        self._step_end = now
         if _tracer.enabled:
-            step_args = {"active": len(active), "round": self._rd_index,
-                         "live_blocks": n_live, "gather_blocks": gathered}
+            step_args = {"active": n_rows, "round": self._rd_index,
+                         "ahead": int(rnd.ahead),
+                         "live_blocks": rnd.n_live,
+                         "gather_blocks": rnd.gathered}
             if moe is not None:
                 step_args.update(moe_assignments=int(moe[0]),
                                  moe_experts_hit=int(moe[1]))
@@ -3008,46 +3172,34 @@ class LMServingEngine:
                                  args=step_args)
             # per-request view of the shared batched step: one
             # retroactive span per sampled slot, all spanning [t0, now]
-            for i, st in active:
-                if _tracer.sampled(st.rid):
-                    _tracer.add_complete(
-                        "lm/decode_round", t0, now - t0, cat="request",
-                        args={"request_id": st.rid, "slot": i,
-                              "step": st.step_idx})
+            for rid, i, step in rnd.sampled:
+                _tracer.add_complete(
+                    "lm/decode_round", t0, now - t0, cat="request",
+                    args={"request_id": rid, "slot": i, "step": step})
         itls = []
         freed = []
-        for i, st in enumerate(self._slots):
-            if st is None:
+        discarded = 0
+        for i, st, emits, last in rnd.rows:
+            if self._slots[i] is not st:
+                discarded += 1
                 continue
-            if st.replay:
-                # payload-less resume: this step just rebuilt last0's
-                # KV row; the next token was already emitted before
-                # hibernation — take it from the replay queue instead
-                # of the step's id (no re-emit, no ITL sample).  The
-                # queue preserves the original step_keys alignment, so
-                # post-replay sampling is bit-exact.
-                st.last0 = st.replay.popleft()
-                st.pos_next += 1
-                st.step_idx += 1
-                st.remaining -= 1
+            if not emits:
                 continue
             nxt0 = int(ids[i])
             st.stream._emit(nxt0 + 1)
             itls.append(now - st.last_emit_at)
             st.last_emit_at = now
             st.last0 = nxt0
-            st.pos_next += 1
-            st.step_idx += 1
-            st.remaining -= 1
-            if st.remaining <= 0 or (st.eos0 is not None
-                                     and nxt0 == st.eos0):
+            if last or (st.eos0 is not None and nxt0 == st.eos0):
                 st.stream._finish()
                 self.metrics.record_complete()
                 freed.append(i)
-        self.metrics.record_step(len(active), itls,
+        self.metrics.record_step(n_rows, itls,
                                  prefill_interrupted=self._prefill_since_step,
-                                 live_blocks=n_live, gathered_blocks=gathered,
-                                 state_rows=state_rows)
+                                 live_blocks=rnd.n_live,
+                                 gathered_blocks=rnd.gathered,
+                                 state_rows=state_rows, ahead=rnd.ahead,
+                                 discarded=discarded)
         self._prefill_since_step = False
         if freed:
             with self._cv:
@@ -3537,6 +3689,7 @@ class LMServingEngine:
                     self._slots[i] = None
                     self._free.append(i)
             self._n_active = 0
+            self._flying = None     # a round on the device is dropped
             if self.draft is not None:
                 self.draft.release_all()
             # hibernated / resuming streams hold no pool blocks (their

@@ -12,7 +12,11 @@ of a benchmark cell.  Not part of the benchmark: it calls
         blocks gathered, the entries of whole tables) and, beside them,
         "logit_rows_to_host": the rows of V float32 logits the window
         copied to the host, against "prefills", its admissions' first
-        tokens (equal when no plain decode round moves a row); for a model
+        tokens (equal when no plain decode round moves a row), and
+        "rounds_ahead", the decode rounds enqueued while their predecessor
+        was still on the device, with "ahead_share", their share of
+        "decode_steps", and "rows_discarded", the rows of streams that had
+        ended on their eos a round before; for a model
         with recurrent layers "state_row_steps" (active slots x recurrent
         layers, summed over the rounds), "state_rows_in_use" and the state's
         read-and-write bytes a round
@@ -63,11 +67,14 @@ def _watch_engines():
 
     def lists(e):
         m = e.metrics
-        # (the parent's tree has no logit_rows_to_host: null there)
+        # (the parent's tree has no logit_rows_to_host, and the one before
+        # the run-ahead round no rounds_ahead: null there)
         return [m.decode_steps, getattr(m, "live_blocks", None),
                 getattr(m, "gathered_blocks", None), m.prefills,
                 getattr(m, "logit_rows_to_host", None),
-                getattr(m, "state_row_steps", None)]
+                getattr(m, "state_row_steps", None),
+                getattr(m, "rounds_ahead", None),
+                getattr(m, "rows_discarded", None)]
 
     def watched_open(self, at=None):
         for e in live():
@@ -82,9 +89,9 @@ def _watch_engines():
         # blocks gathered (the rungs), and what whole tables would hold
         seen["lists"] = []
         for e, before in zip(live(), seen.pop("_lists", [])):
-            steps, listed, gathered, prefills, logit_rows, state_rows = (
-                None if b is None else a - b
-                for a, b in zip(lists(e), before))
+            (steps, listed, gathered, prefills, logit_rows, state_rows,
+             ahead, discarded) = (None if b is None else a - b
+                                  for a, b in zip(lists(e), before))
             # a recurrent model's state arena (null on a model without, and
             # on the parent's tree): (slot, layer) rows the rounds moved, the
             # rows that hold a request's state now, and the bytes a round
@@ -95,6 +102,10 @@ def _watch_engines():
                 "gathered_blocks": gathered,
                 "table_entries": steps * e.slots * e.table_width,
                 "prefills": prefills, "logit_rows_to_host": logit_rows,
+                "rounds_ahead": ahead,
+                "ahead_share": (None if ahead is None or not steps
+                                else ahead / steps),
+                "rows_discarded": discarded,
                 "state_row_steps": state_rows,
                 "state_rows_in_use": getattr(e.metrics, "state_rows_in_use",
                                              None),
@@ -110,9 +121,15 @@ def _watch_engines():
 
 
 def _clock_offset(xplane: str):
-    """[lo, hi] ms for (device clock - host clock) in one profile: in every
-    round the decode module starts after lm/decode_dispatch was entered and
-    ends before lm/decode_wait was left."""
+    """[lo, hi] ms for (device clock - host clock) in one profile: a round's
+    decode module starts after its lm/decode_dispatch was entered and ends
+    before its lm/decode_wait was left.  Since the round runs one ahead the
+    NEXT round's dispatch is entered while a module runs and the previous
+    round's wait is left just after it starts, so a module is paired with the
+    last dispatch entered before it STARTED and the first wait left after it
+    ENDED: the wait's side stays tight (a module's end to its ids on the
+    host); the dispatch's side is tight only for a round after a drain (one
+    enqueued ahead waits for the device, not for the host)."""
     from jax.profiler import ProfileData
     dispatch, wait, modules = [], [], []
     for plane in ProfileData.from_file(xplane).planes:
@@ -129,12 +146,10 @@ def _clock_offset(xplane: str):
     dispatch.sort(), wait.sort(), modules.sort()
     lows, highs = [], []
     for m0, m1 in modules:
-        # the round of this module: the last dispatch entered before it ended,
-        # and the first wait left after it started (offsets are ~ms, rounds
-        # a quarter of a second)
-        d = [t for t in dispatch if t < m1]
-        w = [b for a, b in wait if b > m0]
-        if d and w and w[0] - d[-1] < 2 * (m1 - m0):
+        # (offsets are under a millisecond or two, rounds 7 to 34 ms)
+        d = [t for t in dispatch if t < m0]
+        w = [b for a, b in wait if b > m1]
+        if d and w and w[0] - d[-1] < 3 * (m1 - m0):
             highs.append((m0 - d[-1]) * 1e-6)
             lows.append((m1 - w[0]) * 1e-6)
     if not lows:

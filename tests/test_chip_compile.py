@@ -267,13 +267,16 @@ def test_lm_paged_programs_keep_the_arenas_in_place_on_v5e(sds, monkeypatch,
 
         # the engine's program: the step picks its tokens, handed each
         # slot's temperature and key beside its token and position
-        def step(p, tok, pos, live, temperature, keys, *kv):
+        # and, not donated, the previous step's ids: a slot's token is its
+        # entry of them where the host has not read it yet (the round ahead)
+        def step(p, tok, pos, live, temperature, keys, prev_ids, *kv):
             return G._decode_pick_paged(model, p, tok, pos, live, temperature,
-                                        keys, *kv, table_width=width,
-                                        attn_impl=impl)
+                                        keys, prev_ids, *kv,
+                                        table_width=width, attn_impl=impl)
 
         args = (params, i32(slots), i32(slots), i32(3, slots * width),
-                sds((slots,), jnp.float32), sds((slots, 2), jnp.uint32))
+                sds((slots,), jnp.float32), sds((slots, 2), jnp.uint32),
+                i32(slots))
     elif program == "verify":
         def step(p, tok, pos, n_cand, tables, *kv):
             return G._verify_step_paged(model, p, tok, pos, n_cand, tables,
@@ -386,15 +389,15 @@ def test_laguna_cell_compiles_for_v5e_and_keeps_the_arenas_in_place(
         assert arenas[0].shape == (5, 5136, 16, 1024)       # no lane padding
         slots, width = eng["slots"], eng["cache_len"] // eng["block_len"]
 
-        def step(p, tok, pos, live, temperature, keys, *kv):
+        def step(p, tok, pos, live, temperature, keys, prev_ids, *kv):
             return G._decode_pick_paged(model, p, tok, pos, live, temperature,
-                                        keys, *kv, table_width=width,
-                                        attn_impl="gather")
+                                        keys, prev_ids, *kv,
+                                        table_width=width, attn_impl="gather")
 
         compiled, text = _compile(
             step, params, i32(slots), i32(slots), i32(3, slots * width),
             sds((slots,), jnp.float32), sds((slots, 2), jnp.uint32),
-            *arenas, donate_argnums=(6, 7))
+            i32(slots), *arenas, donate_argnums=(7, 8))
         # the picked ids and the routed layers' two integers: nothing of
         # the vocabulary's 50,176 columns leaves the step
         ids, counts = compiled.out_info[:2]
@@ -505,15 +508,15 @@ def test_solar2_cell_compiles_for_v5e_and_keeps_the_state_in_place(
     arena_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in arenas)
     state_dims = "f32[3,128,64,128,128]"
     if program == "decode":
-        def step(p, tok, pos, live, temperature, keys, *kv):
+        def step(p, tok, pos, live, temperature, keys, prev_ids, *kv):
             return G._decode_pick_paged(model, p, tok, pos, live, temperature,
-                                        keys, *kv, table_width=width,
-                                        attn_impl="gather")
+                                        keys, prev_ids, *kv,
+                                        table_width=width, attn_impl="gather")
 
         compiled, text = _compile(
             step, params, i32(slots), i32(slots), i32(3, slots * width),
             sds((slots,), jnp.float32), sds((slots, 2), jnp.uint32),
-            *arenas, donate_argnums=(6, 7, 8, 9))
+            i32(slots), *arenas, donate_argnums=(7, 8, 9, 10))
         ids, counts = compiled.out_info[:2]
         assert ids.shape == (slots,) and ids.dtype == jnp.int32
         assert counts.shape == (2,) and len(compiled.out_info) == 6

@@ -1,6 +1,6 @@
 """The decode step picks its tokens: a round hands the host ``(S,)`` ids, not
 ``(S, V)`` float32 logits (``generate.pick_next`` / ``pick_rows`` /
-``_decode_pick_paged``, ``LMServingEngine._decode_fn`` and ``_step``).
+``_decode_pick_paged``, ``LMServingEngine._decode_fn`` and ``_dispatch`` / ``_collect``).
 
 Toy sizes, the CPU, the GPT-2-shaped toy and the toy Laguna (grouped heads,
 windows, routed experts).  The step: its ids are ``spec.verify.pick_token`` --
@@ -82,6 +82,11 @@ def test_one_operand_vector_holds_what_a_round_hands_its_step():
     got = operands_of(ops, 4)
     for a, b in zip(got, (token, pos, temperature, keys, live)):
         assert a.dtype == b.dtype and np.array_equal(a, b)
+    # a token the host has not seen yet: the sentinel in its place is no
+    # token of any vocabulary, and rides the same vector
+    assert lm_engine.TAKE_PREV < 0
+    token[2] = lm_engine.TAKE_PREV
+    assert operands_of(ops, 4)[0].tolist() == [3, 0, lm_engine.TAKE_PREV, 8]
 
 
 # -- the step ----------------------------------------------------------------------
@@ -96,7 +101,8 @@ def test_step_ids_are_pick_token_of_the_steps_logits(case, round_):
     two temperatures (``mixed``), or the same slots all greedy (the branch
     that draws no noise): the ids are ``pick_token`` of the logits' rows, the
     routed layers' two integers and the arenas those of the step that hands
-    out logits."""
+    out logits.  Two of the slots take their token from ``prev_ids`` (the
+    run-ahead round's ``TAKE_PREV``): the step is the one fed those tokens."""
     model = _model(case)
     where = [p for p, _ in ROUND]
     temps = np.asarray([t if round_ == "mixed" else 0.0 for _, t in ROUND],
@@ -109,13 +115,18 @@ def test_step_ids_are_pick_token_of_the_steps_logits(case, round_):
     arenas = _pool(model.n_kv_head, model.head_dim, None, seed=4,
                    layers=model.n_layers)
     token = jnp.asarray([3, 0, 17, 8, 40, 21], jnp.int32)
+    # two slots' tokens are the previous step's picks, still on the device:
+    # the sentinel in their place of the operand, the value in prev_ids
+    taken = np.asarray([0, 0, 1, 0, 1, 0], bool)
+    operand = jnp.where(taken, lm_engine.TAKE_PREV, token)
+    prev_ids = jnp.where(taken, token, 59 - token)      # the others': ignored
     kw = dict(table_width=M)
 
     logits, *rest = jax.jit(lambda *a: G._decode_step_paged(
         model, model.params, *a, **kw))(token, pos, live, *arenas)
     ids, *picked = jax.jit(lambda *a: G._decode_pick_paged(
-        model, model.params, *a, **kw))(token, pos, live, jnp.asarray(temps),
-                                        jnp.asarray(keys), *arenas)
+        model, model.params, *a, **kw))(operand, pos, live, jnp.asarray(temps),
+                                        jnp.asarray(keys), prev_ids, *arenas)
     assert ids.shape == (len(ROUND),) and ids.dtype == jnp.int32
     logits = np.asarray(logits)
     want = [pick_token(logits[i], float(temps[i]), keys[i], clamp=True)
@@ -160,8 +171,10 @@ def _host_pick_exec(eng):
         donate_argnums=tuple(range(4, 4 + len(eng.pool.arenas))))
     rounds = {"n": 0, "mixed": 0}
 
-    def call(params, operands, *kv):
+    def call(params, operands, prev_ids, *kv):
         token, pos, temperature, keys, live = operands_of(operands, eng.slots)
+        token = np.where(token == lm_engine.TAKE_PREV, np.asarray(prev_ids),
+                         token)
         logits, *rest = step(params, token, pos, live, *kv)
         logits = np.asarray(logits)
         ids = np.asarray([pick_token(logits[i], float(temperature[i]),
@@ -231,7 +244,9 @@ def test_streams_are_those_of_logits_picked_on_the_host(case):
 @pytest.mark.parametrize("case", MODELS + ["int8"])
 def test_decode_executable_has_no_output_of_the_vocabularys_width(case):
     """Its first output is (S,) int32; a routed model's two integers and the
-    donated arenas follow; nothing has V columns."""
+    donated arenas follow; nothing has V columns.  It takes the operand
+    vector, then the previous step's ids -- its own first output's shape, not
+    donated: the host still reads them -- then the donated arenas."""
     eng = (_engine("gpt2", kv_quant="int8") if case == "int8"
            else _engine(case))
     try:
@@ -245,6 +260,12 @@ def test_decode_executable_has_no_output_of_the_vocabularys_width(case):
             a.shape for a in eng.pool.arenas]
         assert all(vocab not in o.shape
                    for o in out[:-len(eng.pool.arenas)])
+        (_, operands, prev_ids, *kv), _ = eng._decode_compiled().in_avals
+        assert operands.shape == (5 * eng.slots + 3 * eng.slots
+                                  * eng.table_width,)
+        assert (prev_ids.shape, prev_ids.dtype) == (out[0].shape, jnp.int32)
+        assert [a.shape for a in kv] == [a.shape for a in eng.pool.arenas]
+        assert eng._ids.shape == prev_ids.shape         # zeros before a round
     finally:
         eng.close()
 

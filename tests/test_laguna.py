@@ -94,9 +94,10 @@ def _served_logits(monkeypatch, engine, prompt, forced):
             table_width=engine.table_width, attn_impl=engine.decode_attn),
         donate_argnums=(4, 5))
 
-    def decode(params, operands, *kv):
+    def decode(params, operands, prev_ids, *kv):
         token, pos, _, _, live = lm_engine.split_decode_operands(
             jnp.asarray(operands), engine.slots)
+        token = jnp.where(token < 0, prev_ids, token)   # lm_engine.TAKE_PREV
         logits, *rest = step(params, token, pos, live, *kv)
         block, owner, _ = np.asarray(live)
         slot, = set(owner[block != SCRATCH_BLOCK].tolist())     # one request

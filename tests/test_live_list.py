@@ -1,7 +1,7 @@
 """The decode round reads the blocks its slots hold: a live list in place of
 the slots x table-width gather (``serving.kvcache.blocks.live_list`` /
 ``list_chunk`` / ``table_list``, ``generate._paged_attention``,
-``LMServingEngine._step``).
+``LMServingEngine._dispatch``).
 
 Three levels, toy sizes, the CPU: the attention over a list against a dense
 softmax written out here (grouped heads, a window, an int8 pool, the edges

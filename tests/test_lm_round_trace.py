@@ -120,31 +120,71 @@ def test_leaf_spans_tile_every_round(model, tracer, kind):
 @pytest.mark.parametrize("kind", ["plain", "spec"])
 def test_decoding_round_holds_one_step_with_one_dispatch_and_one_wait(
         model, tracer, kind):
+    """A round of the loop collects ONE decode step: one ``lm/decode_wait``,
+    ending where the step's span ends.  A speculating engine's round is
+    synchronous: its dispatch and its wait fill the step.  A plain engine's
+    round runs one ahead: it holds at most one dispatch, which enqueues the
+    NEXT step before the wait (``ahead`` 1 on that step's span) or, after a
+    drain, behind it; consecutive ``lm/decode_step`` spans never overlap, a
+    step that ran ahead starts where its predecessor's span ended, a step
+    after a drain where its own dispatch began."""
     tracer.enable()
     eng = _engine(model, **ENGINES[kind])
     _serve(eng)
+    # one request alone: nobody to seat, so its rounds run ahead
+    eng.submit(np.arange(1, 8), max_new_tokens=8).result(timeout=120)
     _close(eng)
     evs = _worker_events(tracer, eng)
     step_name = "lm/decode_step" if kind == "plain" else "lm/verify_step"
-    decoded = 0
+    decoded, all_steps = 0, []
     for r in (e for e in evs if e["name"] == "lm/round"):
         mine = [e for e in evs if e is not r and e.get("args", {}).get("round")
                 == r["args"]["round"]]
         steps = [e for e in mine if e["name"] == step_name]
-        assert len(steps) == (1 if r["args"]["active"] else 0)
+        found = {leaf: [e for e in mine if e["name"] == leaf]
+                 for leaf in ("lm/decode_dispatch", "lm/decode_wait")}
+        assert len(steps) == len(found["lm/decode_wait"]) <= 1
+        assert len(found["lm/decode_dispatch"]) <= 1
         if not steps:
             continue
         decoded += 1
-        assert steps[0]["args"]["active"] == r["args"]["active"]
-        for leaf in ("lm/decode_dispatch", "lm/decode_wait"):
-            found = [e for e in mine if e["name"] == leaf]
-            assert len(found) == 1 and _inside(found[0], steps[0]), (leaf, r)
-        # dispatch then wait fill the step: nothing else happens inside it
-        assert sum(e["dur"] for e in mine if e["name"] in
-                   ("lm/decode_dispatch", "lm/decode_wait")) \
-            == pytest.approx(steps[0]["dur"], abs=2 * EPS_US)
+        step, wait = steps[0], found["lm/decode_wait"][0]
+        all_steps.append(step)
+        assert step["args"]["active"] == r["args"]["active"] > 0
+        assert _inside(wait, step)
+        assert wait["ts"] + wait["dur"] == pytest.approx(
+            step["ts"] + step["dur"], abs=EPS_US)
+        if kind == "spec":
+            # dispatch then wait fill the step: nothing else happens inside
+            assert "ahead" not in step["args"]
+            assert _inside(found["lm/decode_dispatch"][0], step)
+            assert sum(e["dur"] for e in mine if e["name"] in
+                       ("lm/decode_dispatch", "lm/decode_wait")) \
+                == pytest.approx(step["dur"], abs=2 * EPS_US)
     assert decoded >= 5
     assert decoded == eng.metrics.decode_steps
+    if kind == "spec":
+        assert eng.metrics.rounds_ahead == 0
+        return
+    all_steps.sort(key=lambda e: e["ts"])
+    dispatches = sorted((e for e in evs if e["name"] == "lm/decode_dispatch"),
+                        key=lambda e: e["ts"])
+    assert len(dispatches) == len(all_steps)        # every step was enqueued once
+    for i, (step, dispatch) in enumerate(zip(all_steps, dispatches)):
+        assert step["args"]["ahead"] in (0, 1)
+        if i:
+            before = all_steps[i - 1]
+            assert before["ts"] + before["dur"] <= step["ts"] + EPS_US
+        if step["args"]["ahead"]:
+            # enqueued while its predecessor was on the device: the device
+            # could turn to it when that one's ids were out
+            assert dispatch["ts"] < before["ts"] + before["dur"]
+            assert step["ts"] == pytest.approx(before["ts"] + before["dur"],
+                                               abs=EPS_US)
+        else:
+            assert step["ts"] == pytest.approx(dispatch["ts"], abs=EPS_US)
+    ahead = sum(e["args"]["ahead"] for e in all_steps)
+    assert ahead == eng.metrics.rounds_ahead >= 5
 
 
 def test_admit_spans_count_the_prefills(model, tracer):

@@ -189,6 +189,7 @@ class _Served:
     def __init__(self, monkeypatch, engine):
         from bigdl_tpu.models.transformer import generate as G
         from bigdl_tpu.serving import lm_engine
+        from bigdl_tpu.serving.kvcache.blocks import SCRATCH_BLOCK
         self.engine, self.rows, self.queue, self.order = engine, {}, {}, []
         self.rounds = []        # the active slots of every decode round
         n = len(engine._arenas())
@@ -203,12 +204,16 @@ class _Served:
             self.rows[who].append(np.array(logits_row))
             return int(self.queue[who].pop(0))
 
-        def decode(params, operands, *kv):
+        def decode(params, operands, prev_ids, *kv):
             token, pos, _, _, live = lm_engine.split_decode_operands(
                 jnp.asarray(operands), engine.slots)
+            token = jnp.where(token < 0, prev_ids, token)   # lm_engine.TAKE_PREV
             logits, *rest = step(params, token, pos, live, *kv)
             ids = np.zeros((engine.slots,), np.int32)
-            active = [i for i, st in enumerate(engine._slots) if st is not None]
+            # the round's slots are those its live list names (a slot whose
+            # count ended with the round before is seated, and not in it)
+            block, owner, _ = np.asarray(live)
+            active = sorted(set(owner[block != SCRATCH_BLOCK].tolist()))
             self.rounds.append(active)
             for i in active:
                 who = int(engine._slots[i].stream.prompt[0])
