@@ -107,14 +107,64 @@ class LayerSpec(NamedTuple):
     ``i - j < w``), its rotary embedding (``None``: the model's
     ``pos_encoding``), its feed-forward half (``"dense"`` or ``"moe"``,
     the model's ``moe`` spec) and its token mixer: ``"attention"`` (softmax
-    over cached keys and values) or ``"kda"`` (gated delta-rule linear
+    over cached keys and values), ``"kda"`` (gated delta-rule linear
     attention, :mod:`bigdl_tpu.nn.kda`: ``n_head`` heads of ``head_dim``
-    keys and values, NO keys and values kept, a state a head instead)."""
+    keys and values, NO keys and values kept, a state a head instead) or
+    ``"mla"`` (latent attention of the model's :class:`MLASpec`: softmax
+    over ONE cached row a position, nothing a head; ``rope`` rotates its
+    ``MLASpec.rope`` lanes)."""
     n_head: int
     window: Optional[int] = None
     rope: Optional[RopeSpec] = None
     mlp: str = "dense"
     mixer: str = "attention"
+
+
+class MLASpec(NamedTuple):
+    """Latent attention's shape, stated once (DeepSeek-V2's multi-head
+    latent attention, no query compression).  A position's keys and values
+    are up-projections of ONE latent ``c`` of ``kv_rank`` lanes (RMS-normed),
+    beside ``rope`` rotated lanes shared by every head::
+
+        q_h = [q_n (nope) ; q_r (rope)]        [c' ; k_r'] = a W_dkv
+        c = rmsnorm(c')     q_r, k_r = rotary(q_r', k_r')
+        [k_n,h ; v_h] = c W_ukv,h              (nope + v lanes a head)
+        s_h(t, j) = (q_n,h(t) . k_n,h(j) + q_r,h(t) . k_r(j)) / sqrt(nope + rope)
+
+    What a position caches is the ``row`` ``[c ; k_r]``.  Two forms of the
+    one function: EXPANDED (keys and values up-projected: whole sequences)
+    and ABSORBED (``W_uk`` folded into the query, ``W_uv`` applied after the
+    softmax: the cached step, whose values are the row's first ``kv_rank``
+    lanes)."""
+    kv_rank: int
+    nope: int
+    rope: int
+    v: int
+
+    @property
+    def row(self) -> int:
+        """Lanes of the one row a position caches."""
+        return self.kv_rank + self.rope
+
+    @property
+    def score_dim(self) -> int:
+        return self.nope + self.rope
+
+
+class KDASpec(NamedTuple):
+    """The variants of a ``"kda"`` layer a model states.  The decay's log
+    ``g`` from its logit ``f = (a Wf) + dt_bias`` and rate ``r = exp(A_log)``
+    a head: ``"softplus"``: ``-r * softplus(f)`` in (-inf, 0);
+    ``"bounded"``: ``lower_bound * sigmoid(r * f)`` in (lower_bound, 0).
+    ``full_rank``: the decay's and the output gate's projections are one
+    hidden x (H * D) matrix each (``wf``, ``wg``), else a low-rank pair
+    through ``head_dim`` (``wf1`` ``wf2``, ``wg1`` ``wg2``).  ``beta_scale``:
+    beta = ``beta_scale * sigmoid(.)``, in (0, 2) where the transition may
+    have a negative eigenvalue, in (0, 1) where not."""
+    gate: str = "softplus"
+    lower_bound: float = -5.0
+    full_rank: bool = False
+    beta_scale: float = 2.0
 
 
 def window_mask(q_pos, k_pos, window: Optional[int]):
@@ -143,6 +193,7 @@ class TransformerLM(Module):
     norm, norm_eps, mlp_act, bias, attn_gate, moe = (
         "layernorm", 1e-5, "gelu", True, False, None)
     kda_conv = 4
+    kda, mla = KDASpec(), None
 
     def __init__(self, vocab_size: int, hidden_size: int = 128,
                  n_head: int = 4, n_layers: int = 2,
@@ -162,7 +213,8 @@ class TransformerLM(Module):
                  mlp_act: str = "gelu", bias: bool = True,
                  attn_gate=False, moe=None,
                  layer_plan: Optional[Sequence] = None,
-                 kda_conv: int = 4):
+                 kda_conv: int = 4, kda: Optional[KDASpec] = None,
+                 mla: Optional[MLASpec] = None):
         super().__init__()
         assert head_dim is not None or hidden_size % n_head == 0
         if norm not in ("layernorm", "rmsnorm"):
@@ -225,12 +277,18 @@ class TransformerLM(Module):
         # none; K/V heads shared by groups of query heads; a sigmoid gate
         # on the attention output, a scalar a head or elementwise; a routed
         # expert layer (parallel.expert.MoESpec) on the plan's "moe" layers;
-        # the taps of a "kda" layer's depthwise convolution
+        # the taps of a "kda" layer's depthwise convolution and its variants;
+        # the shape of an "mla" layer
         self.n_kv_head = int(n_kv_head or n_head)
         self.norm, self.norm_eps = norm, float(norm_eps)
         self.mlp_act, self.bias, self.attn_gate = mlp_act, bool(bias), attn_gate
         self.moe = moe
         self.kda_conv = int(kda_conv)
+        self.kda = KDASpec(*kda) if kda is not None else KDASpec()
+        if self.kda.gate not in ("softplus", "bounded"):
+            raise ValueError(f"KDASpec.gate must be 'softplus' or 'bounded', "
+                             f"got {self.kda.gate!r}")
+        self.mla = MLASpec(*mla) if mla is not None else None
         # the LAYER PLAN: a list of groups ``(repeat, period)``, a period a
         # tuple of LayerSpec.  A group is ``repeat`` copies of its period
         # stacked on a leading axis and scanned; the body runs the period's
@@ -246,9 +304,13 @@ class TransformerLM(Module):
                                  f"n_layers is {n_layers}")
             for _, period in layer_plan:
                 for spec in period:
-                    if spec.mixer not in ("attention", "kda"):
-                        raise ValueError(f"a layer's mixer is 'attention' or "
-                                         f"'kda', got {spec.mixer!r}")
+                    if spec.mixer not in ("attention", "kda", "mla"):
+                        raise ValueError(f"a layer's mixer is 'attention', "
+                                         f"'kda' or 'mla', got {spec.mixer!r}")
+                    if spec.mixer == "mla" and (self.mla is None or self.bias
+                                                or spec.window):
+                        raise ValueError("an 'mla' layer needs mla=MLASpec, "
+                                         "has no biases and no window")
                     if spec.mixer == "attention" and (
                             spec.n_head % self.n_kv_head):
                         raise ValueError(
@@ -256,9 +318,10 @@ class TransformerLM(Module):
                             f"over {self.n_kv_head} K/V heads")
                     if spec.mlp == "moe" and moe is None:
                         raise ValueError("a 'moe' layer needs moe=MoESpec")
-        elif self.n_kv_head != n_head or attn_gate or moe is not None:
-            raise ValueError("grouped K/V heads, the output gate and "
-                             "routed experts need a layer_plan")
+        elif (self.n_kv_head != n_head or attn_gate or moe is not None
+              or mla is not None):
+            raise ValueError("grouped K/V heads, the output gate, routed "
+                             "experts and latent attention need a layer_plan")
         self.layer_plan = layer_plan
 
     # -------------------------------------------------------------- #
@@ -293,6 +356,17 @@ class TransformerLM(Module):
     def state_layers(self) -> tuple:
         """The layers that keep a recurrent state (``"kda"``) instead."""
         return self._layers_mixing("kda")
+
+    @property
+    def latent_layers(self) -> tuple:
+        """The layers that keep one latent row a position (``"mla"``): a
+        paged pool of such rows holds one arena layer for each."""
+        return self._layers_mixing("mla")
+
+    @property
+    def n_counts(self) -> int:
+        """Integers a routed layer counts (``MoESpec.n_counts``)."""
+        return 2 if self.moe is None else self.moe.n_counts
 
     @property
     def state_shapes(self) -> tuple:
@@ -360,17 +434,36 @@ class TransformerLM(Module):
                 "wo": mat(ks[3], (inner, h), 1.0 / math.sqrt(inner)),
                 "conv": mat(kk[3], (self.kda_conv, 3 * inner),
                             1.0 / math.sqrt(self.kda_conv)),
-                # the decay's low-rank pair, its rate a head and offset a
-                # channel; beta a head; the output gate's low-rank pair and
-                # the head norm's weight
-                "wf1": mat(kk[4], (h, d), std_h),
-                "wf2": mat(kk[5], (d, inner), 1.0 / math.sqrt(d)),
+                # the decay's rate a head and offset a channel; beta a
+                # head; the head norm's weight
                 "a_log": jnp.log(jnp.linspace(1.0, 16.0, spec.n_head)),
                 "dt_bias": jnp.zeros((inner,)),
                 "wb": mat(ks[1], (h, spec.n_head), std_h),
-                "wg1": mat(kk[6], (h, d), std_h),
-                "wg2": mat(kk[7], (d, inner), 1.0 / math.sqrt(d)),
                 "norm": jnp.ones((d,))}
+            # the decay's and the output gate's projections (KDASpec)
+            if self.kda.full_rank:
+                p["kda"].update(wf=proj(kk[4]), wg=proj(kk[6]))
+            else:
+                p["kda"].update(
+                    wf1=mat(kk[4], (h, d), std_h),
+                    wf2=mat(kk[5], (d, inner), 1.0 / math.sqrt(d)),
+                    wg1=mat(kk[6], (h, d), std_h),
+                    wg2=mat(kk[7], (d, inner), 1.0 / math.sqrt(d)))
+        elif spec.mixer == "mla":
+            m, kk = self.mla, jax.random.split(ks[0], 3)
+            p["mla"] = {
+                "wq": mat(kk[0], (h, spec.n_head * m.score_dim), std_h),
+                "w_dkv": mat(kk[1], (h, m.row), std_h),
+                "kv_norm": jnp.ones((m.kv_rank,)),
+                # a head's [k_n ; v] side by side
+                "w_ukv": mat(kk[2], (m.kv_rank, spec.n_head * (m.nope + m.v)),
+                             1.0 / math.sqrt(m.kv_rank)),
+                "wo": mat(ks[3], (spec.n_head * m.v, h),
+                          1.0 / math.sqrt(spec.n_head * m.v))}
+            if self.attn_gate:
+                p["mla"]["wg"] = mat(ks[4], (h, spec.n_head * m.v
+                                             if self.attn_gate == "elementwise"
+                                             else spec.n_head), std_h)
         else:
             attn = {"wq": mat(ks[0], (h, inner), std_h),
                     "wk": mat(ks[1], (h, kv), std_h),
@@ -448,16 +541,22 @@ class TransformerLM(Module):
         a learned weight, no offset)."""
         if self.norm == "layernorm":
             return self._layer_norm(p, x)
+        return self._rms_norm(p["weight"], x)
+
+    def _rms_norm(self, weight, x):
         x32 = x.astype(jnp.float32)
         y = x32 * jax.lax.rsqrt(
             jnp.mean(x32 * x32, axis=-1, keepdims=True) + self.norm_eps)
-        return (y * p["weight"].astype(jnp.float32)).astype(x.dtype)
+        return (y * weight.astype(jnp.float32)).astype(x.dtype)
 
-    def _rope(self, q, k, positions, spec: Optional[LayerSpec] = None):
+    def _rope(self, q, k, positions, spec: Optional[LayerSpec] = None,
+              dim: Optional[int] = None):
         """Rotate q and k at ``positions``: by the layer's own rotary
-        embedding where its spec has one, else by ``pos_encoding``."""
+        embedding where its spec has one, else by ``pos_encoding``; ``dim``:
+        the lanes handed in where they are not a whole head (a latent
+        layer's rotated lanes)."""
         if spec is not None and spec.rope is not None:
-            inv_freq = spec.rope.inv_freq(self.head_dim)
+            inv_freq = spec.rope.inv_freq(dim or self.head_dim)
             scale = spec.rope.attention_factor
             return (apply_rotary(q, positions, inv_freq, scale),
                     apply_rotary(k, positions, inv_freq, scale))
@@ -495,8 +594,10 @@ class TransformerLM(Module):
 
     def layer_attn_out(self, bp, o, gate=None):
         """Post-attention, before the residual: the gate (a scalar a head,
-        or elementwise) and the output projection.  ``o`` (B, H, T, D)."""
+        or elementwise) and the output projection (an ``"mla"`` layer's
+        where ``bp`` is one's).  ``o`` (B, H, T, D)."""
         from bigdl_tpu.quant.kernels import qmatmul
+        ap = bp["mla"] if "mla" in bp else bp["attn"]
         b, h, t, d = o.shape
         if gate is not None and self.attn_gate == "elementwise":
             gate = gate.reshape(b, t, h, d).transpose(0, 2, 1, 3)
@@ -504,19 +605,113 @@ class TransformerLM(Module):
         elif gate is not None:
             o = (o.astype(jnp.float32)
                  * gate.transpose(0, 2, 1)[..., None]).astype(o.dtype)
-        y = qmatmul(o.transpose(0, 2, 1, 3).reshape(b, t, h * d),
-                    bp["attn"]["wo"])
+        y = qmatmul(o.transpose(0, 2, 1, 3).reshape(b, t, h * d), ap["wo"])
         if self.bias:
-            y = y + bp["attn"]["bo"]
+            y = y + ap["bo"]
         return y
+
+    # -- an "mla" layer's mixer: what comes before attention, and the two
+    # -- forms' own steps (MLASpec); after it, layer_attn_out
+    def mla_inputs(self, spec: LayerSpec, bp, x, positions=None):
+        """Norm, projections, the latent's norm, rotary.  -> q (B, H, T,
+        nope + rope) with its last ``rope`` lanes rotated, row (B, T,
+        kv_rank + rope) = ``[c ; k_r]`` -- what a position caches, in the
+        compute dtype -- and the gate as :meth:`layer_qkv` hands it."""
+        from bigdl_tpu.nn._util import match_compute_dtype
+        from bigdl_tpu.quant.kernels import qmatmul
+        mp, m = bp["mla"], self.mla
+        a = match_compute_dtype(self._norm(bp["ln1"], x), mp["wq"])
+        b, t = a.shape[:2]
+        q = qmatmul(a, mp["wq"]).reshape(b, t, spec.n_head,
+                                         m.score_dim).transpose(0, 2, 1, 3)
+        down = qmatmul(a, mp["w_dkv"])
+        c = self._rms_norm(mp["kv_norm"], down[..., :m.kv_rank])
+        k_r = down[..., None, :, m.kv_rank:]        # one "head": (B, 1, T, rope)
+        if positions is not None:
+            q_r, k_r = self._rope(q[..., m.nope:], k_r, positions, spec,
+                                  m.rope)
+            q = jnp.concatenate([q[..., :m.nope], q_r], -1)
+        row = jnp.concatenate([c, k_r[..., 0, :, :]], -1)
+        gate = (jax.nn.sigmoid(qmatmul(a, mp["wg"]).astype(jnp.float32))
+                if self.attn_gate else None)
+        return q, row, gate
+
+    def _mla_up(self, bp):
+        """``W_ukv`` as stored, by head: -> (W_uk (kv_rank, H, nope), W_uv
+        (kv_rank, H, v))."""
+        m = self.mla
+        w = bp["mla"]["w_ukv"]
+        w = w.reshape(m.kv_rank, -1, m.nope + m.v)
+        return w[..., :m.nope], w[..., m.nope:]
+
+    def mla_expand(self, bp, row):
+        """EXPANDED: cached rows (B, T, kv_rank + rope) -> k (B, H, T, nope +
+        rope), the rotated lanes shared by every head, and v (B, H, T, v)."""
+        from bigdl_tpu.quant.kernels import qmatmul
+        m = self.mla
+        with jax.named_scope("mla/expand"):
+            b, t = row.shape[:2]
+            kv = qmatmul(row[..., :m.kv_rank], bp["mla"]["w_ukv"]).reshape(
+                b, t, -1, m.nope + m.v).transpose(0, 2, 1, 3)
+            k_r = jnp.broadcast_to(row[:, None, :, m.kv_rank:],
+                                   kv.shape[:3] + (m.rope,))
+            return (jnp.concatenate([kv[..., :m.nope], k_r.astype(kv.dtype)],
+                                    -1), kv[..., m.nope:])
+
+    def mla_absorb(self, bp, q):
+        """ABSORBED, before the softmax: ``q`` (..., H, W, nope + rope) ->
+        float32 (..., H, W, kv_rank + rope): ``W_uk`` folded into the first
+        lanes, so that a query meets the cached ROW itself."""
+        m = self.mla
+        with jax.named_scope("mla/absorb"):
+            q = q.astype(jnp.float32)
+            folded = jnp.einsum("...hwn,rhn->...hwr", q[..., :m.nope],
+                                self._mla_up(bp)[0].astype(jnp.float32),
+                                precision=jax.lax.Precision.HIGHEST)
+            return jnp.concatenate([folded, q[..., m.nope:]], -1)
+
+    def mla_values(self, bp, u):
+        """ABSORBED, after it: the weighted latents ``u`` (..., H, W, kv_rank)
+        float32 -> o (..., H, W, v) float32 through ``W_uv``."""
+        with jax.named_scope("mla/absorb"):
+            return jnp.einsum("...hwr,rhv->...hwv", u,
+                              self._mla_up(bp)[1].astype(jnp.float32),
+                              precision=jax.lax.Precision.HIGHEST)
+
+    def latent_parts(self, q, k, v, mask):
+        """A softmax's three parts over one block of keys, float32 scores
+        whatever the operands' dtype: ``q`` (..., Tq, D), ``k`` (..., Tk, D),
+        ``v`` (..., Tk, Dv) (a latent layer's score and value widths differ),
+        ``mask`` broadcastable to (..., Tq, Tk) -> (maximum (..., Tq), sum of
+        ``exp(score - maximum)``, the values weighted by it (..., Tq, Dv)), the
+        shapes :func:`~bigdl_tpu.nn.attention.online_softmax_update` merges."""
+        from bigdl_tpu.nn.attention import _block_scores
+        with jax.named_scope("mla/attend"):
+            return _block_scores(q, k, v, mask, 1.0 / math.sqrt(q.shape[-1]),
+                                 acc=jnp.float32)
+
+    def attend_latent(self, bp, q, row, segment_ids=None):
+        """An ``"mla"`` layer's self-attention of whole sequences, EXPANDED,
+        on the XLA path: one masked (T, T) float32 score matrix a head (the
+        flash kernel takes one width for scores and values).  ``q`` and
+        ``row`` as :meth:`mla_inputs` hands them -> o (B, H, T, v)."""
+        from bigdl_tpu.nn.attention import _finalize, segment_mask
+        k, v = self.mla_expand(bp, row)
+        pos = jnp.arange(q.shape[-2])
+        mask = window_mask(pos, pos, None)
+        if segment_ids is not None:
+            mask = mask & segment_mask(segment_ids, segment_ids)
+        _, den, o = self.latent_parts(q, k, v, mask)
+        return _finalize(o, den).astype(q.dtype)
 
     # -- a "kda" layer's mixer in three parts: what comes before the
     # -- recurrence, the heads it reads, and what follows it
     def kda_inputs(self, bp, x):
         """Norm and projections of a recurrent layer: -> (qkv (B, T, 3 * H *
         D) before the convolution, g (B, T, H, D) float32 the log of the
-        decay a channel, beta (B, T, H) float32 in (0, 2), gate (B, T, H *
-        D) float32)."""
+        decay a channel, beta (B, T, H) float32 in (0, ``kda.beta_scale``),
+        gate (B, T, H * D) float32); the decay's form and the projections'
+        rank are the model's :class:`KDASpec`."""
         from bigdl_tpu.nn._util import match_compute_dtype
         from bigdl_tpu.quant.kernels import qmatmul
         kp = bp["kda"]
@@ -526,10 +721,17 @@ class TransformerLM(Module):
         d = self.head_dim
         f32 = lambda y: y.astype(jnp.float32)               # noqa: E731
         rate = jnp.exp(f32(kp["a_log"]))[:, None]
-        fgt = f32(qmatmul(qmatmul(a, kp["wf1"]), kp["wf2"])) + f32(kp["dt_bias"])
-        g = -rate * jax.nn.softplus(fgt.reshape(fgt.shape[:-1] + (-1, d)))
-        beta = 2.0 * jax.nn.sigmoid(f32(qmatmul(a, kp["wb"])))
-        gate = jax.nn.sigmoid(f32(qmatmul(qmatmul(a, kp["wg1"]), kp["wg2"])))
+        full = self.kda.full_rank
+        project = lambda n: (qmatmul(a, kp[n]) if full else         # noqa: E731
+                             qmatmul(qmatmul(a, kp[n + "1"]), kp[n + "2"]))
+        fgt = f32(project("wf")) + f32(kp["dt_bias"])
+        by_head = fgt.shape[:-1] + (-1, d)
+        if self.kda.gate == "bounded":
+            g = self.kda.lower_bound * jax.nn.sigmoid(rate * fgt.reshape(by_head))
+        else:
+            g = -rate * jax.nn.softplus(fgt.reshape(by_head))
+        beta = self.kda.beta_scale * jax.nn.sigmoid(f32(qmatmul(a, kp["wb"])))
+        gate = jax.nn.sigmoid(f32(project("wg")))
         return qkv, g, beta, gate
 
     def kda_heads(self, y):
@@ -588,12 +790,12 @@ class TransformerLM(Module):
                   token_mask=None):
         """The feed-forward half before its residual: -> (m, aux, counts).
         ``aux`` is the switch MoE's balance term (0 otherwise), ``counts``
-        the routed layer's two integers (``parallel.expert.routed_experts``;
+        the routed layer's integers (``parallel.expert.routed_experts``;
         zeros otherwise).  ``dense_routing``: the cached steps run the
         legacy switch MoE without its capacity window."""
         m = self._norm(bp["ln2"], x)
         zero = jnp.zeros((), jnp.float32)
-        counts = jnp.zeros((2,), jnp.int32)
+        counts = jnp.zeros((self.n_counts,), jnp.int32)
         if spec.mlp == "moe":
             from bigdl_tpu.parallel.expert import routed_mlp
             m, counts = routed_mlp(bp["moe"], m, self.moe,
@@ -648,6 +850,10 @@ class TransformerLM(Module):
                     "a recurrent layer does not reset its state at a packed "
                     "document's start")
             mixed = self.layer_kda(bp, x)[0]
+        elif spec.mixer == "mla":
+            q, row, gate = self.mla_inputs(spec, bp, x, positions)
+            mixed = self.layer_attn_out(
+                bp, self.attend_latent(bp, q, row, segment_ids), gate)
         else:
             q, k, v, gate = self.layer_qkv(spec, bp, x, positions)
             o = self.attend_full(spec, q, k, v, segment_ids)

@@ -84,20 +84,25 @@ def _attn_scope(model, spec):
     return "attn/full" if rotated else "attn/nope"
 
 
+#: the kinds of token mixer, each with a cache of its own
+_KINDS = ("attention", "kda", "mla")
+
+
 def _kind_indices(period):
     """For each layer of a period: (how many of its kind the period holds,
     which of them it is).  A layer's caches are indexed by KIND: an
-    attention layer's K/V arena, a recurrent layer's state row, each
-    counted over the layers of its own kind alone."""
-    total = {m: sum(s.mixer == m for s in period) for m in ("attention", "kda")}
+    attention layer's K/V arena, a recurrent layer's state row, a latent
+    layer's arena of rows, each counted over the layers of its own kind
+    alone."""
+    total = {m: sum(s.mixer == m for s in period) for m in _KINDS}
     return [(total[s.mixer], sum(x.mixer == s.mixer for x in period[:i]))
             for i, s in enumerate(period)]
 
 
 def _split_arenas(model, arenas):
-    """A step's arenas -> (the paged pool's ``(k, v)`` or ``(k, v, ks, vs)``,
-    the recurrent layers' ``(state, tail)`` or ``()``): the state arenas
-    ride last (``serving.kvcache.state``)."""
+    """A step's arenas -> (the paged pool's ``(k, v)``, ``(k, v, ks, vs)``
+    or, latent, ``(rows,)``, the recurrent layers' ``(state, tail)`` or
+    ``()``): the state arenas ride last (``serving.kvcache.state``)."""
     n = 2 if model.state_layers else 0
     return tuple(arenas[:len(arenas) - n]), tuple(arenas[len(arenas) - n:])
 
@@ -106,14 +111,15 @@ def _scan_prefill(model, params, h, layer_fn):
     """A prefill's layer loop over the plan: ``layer_fn(spec, h, bp,
     index) -> (h, (a, b), counts)``, ``index`` the layer's place among the
     layers of its kind and ``(a, b)`` what it caches: an attention layer's
-    k and v, a recurrent layer's state and convolution tail.  -> (h, (k,
-    v), (state, tail), counts): k/v (L_kv, B, H_kv, T, D) stacked by
-    attention layer, state/tail stacked by recurrent layer (``()`` for a
-    model with none), the routed expert layers' two integers summed."""
-    kinds = ("attention", "kda")
+    k and v, a recurrent layer's state and convolution tail, a latent
+    layer's ``(rows,)``.  -> (h, (k, v) or (rows,), (state, tail), counts):
+    k/v (L_kv, B, H_kv, T, D) stacked by attention layer, rows (L_mla, B, T,
+    lanes) by latent layer, state/tail stacked by recurrent layer (``()``
+    for a model with none), the routed expert layers' integers summed."""
+    kinds = _KINDS
     kept = {m: [] for m in kinds}
     base = {m: 0 for m in kinds}
-    counts = jnp.zeros((2,), jnp.int32)
+    counts = jnp.zeros((model.n_counts,), jnp.int32)
     for (repeat, period), stacks in zip(model.plan,
                                         model.group_params(params)):
         where = _kind_indices(period)
@@ -143,14 +149,15 @@ def _scan_prefill(model, params, h, layer_fn):
             return parts[0] if parts else ()
         return tuple(jnp.concatenate(x) for x in zip(*parts))
 
-    return h, whole(kept["attention"]), whole(kept["kda"]), counts
+    return (h, whole(kept["attention"]) + whole(kept["mla"]),
+            whole(kept["kda"]), counts)
 
 
 def _prefill_result(model, logits, kv, state, counts):
-    """What a prefill hands back: (logits, k, v); with routed expert layers
-    in the model their two integers ride out behind the k/v, with recurrent
-    layers each one's state and convolution tail at the prompt's true end
-    behind those."""
+    """What a prefill hands back: (logits, k, v), or (logits, rows) of a
+    model whose pool is latent; with routed expert layers in the model their
+    integers ride out behind those, with recurrent layers each one's state
+    and convolution tail at the prompt's true end behind those."""
     out = (logits.astype(jnp.float32),) + tuple(kv)
     if model.moe_layers:
         out += (counts,)
@@ -179,6 +186,11 @@ def _prefill_parts(model, params, ids0, last_index):
             y, state, tail = model.layer_kda(bp, h, length=last_index + 1)
             h, c = _ffn(model, spec, bp, h + y)
             return h, (state, tail), c
+        if spec.mixer == "mla":
+            q, row, gate = model.mla_inputs(spec, bp, h, positions)
+            h, c = _finish_block(model, spec, bp, h,
+                                 model.attend_latent(bp, q, row), gate)
+            return h, (row,), c
         q, k, v, gate = model.layer_qkv(spec, bp, h, positions)
         # the model's configured attention core via the shared dispatch
         # (flash keeps the (T, T) matrix out of HBM for long prompts,
@@ -349,7 +361,7 @@ _BY_SLOT = lax.RaggedDotDimensionNumbers(
     lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
 
 
-def _attend_by_owner(q, k_rows, v_rows, owner, mask, scales):
+def _attend_by_owner(q, k_rows, v_rows, owner, mask, scales, score_dim=None):
     """Attention over a live list when a K/V head has SEVERAL query
     vectors a slot (grouped heads, or the candidate rows of a verify
     step): two grouped matmuls (``lax.ragged_dot``: the groups are the
@@ -361,7 +373,9 @@ def _attend_by_owner(q, k_rows, v_rows, owner, mask, scales):
     block by block, then by owner); the weighted V rows come back as
     ``(columns, lanes)`` a slot, of which each column keeps its head.
     ``q`` (S, H_kv, G, W, D) f32, ``k_rows``/``v_rows`` (P, lanes),
-    ``mask`` (n, W, B), ``scales`` None or the int8 rows' (P, H_kv) pair.
+    ``mask`` (n, W, B), ``scales`` None or the int8 rows' (P, H_kv) pair;
+    ``score_dim``: the width a score is scaled by where it is not D (an
+    absorbed latent query is longer than the head it stands for).
     -> the softmax's three parts over these blocks: the maximum (S, H_kv,
     G, W), the sum of ``exp(score - maximum)`` and the rows weighted by it
     (.., D)."""
@@ -382,7 +396,7 @@ def _attend_by_owner(q, k_rows, v_rows, owner, mask, scales):
         n, B, n_kv, c)
     if scales is not None:          # an int8 row's scale, per (position, head)
         scores = scores * scales[0].reshape(n, B, n_kv, 1)
-    scores = scores / jnp.sqrt(jnp.float32(d))
+    scores = scores / jnp.sqrt(jnp.float32(score_dim or d))
     seen = jnp.broadcast_to(mask.transpose(0, 2, 1)[:, :, None, None, :],
                             (n, B, n_kv, g, w_)).reshape(scores.shape)
     scores = jnp.where(seen, scores, -1e30)
@@ -405,7 +419,8 @@ def _attend_by_owner(q, k_rows, v_rows, owner, mask, scales):
     return top.reshape(part), den.reshape(part), o.reshape(part + (d,))
 
 
-def _paged_attention(q, k, v, arenas, layer, blk, off, live, mask):
+def _paged_attention(q, k, v, arenas, layer, blk, off, live, mask,
+                     score_dim=None):
     """One layer's cached attention over PAGED arenas, shared by the
     decode, verify and tree-verify steps: write the W new rows of each
     slot (``k``/``v`` (S, H_kv, W, D), row j at ``(blk, off)[s, j]``)
@@ -428,27 +443,40 @@ def _paged_attention(q, k, v, arenas, layer, blk, off, live, mask):
     flight.  Products are exact and scores, softmax and sums f32
     (:func:`_pool_dot`), so what a whole table gave differs by the order
     of the f32 sums only.
+    A LATENT pool (``v=None``, ``arenas`` its one ``(rows,)``): ``k`` (S, 1,
+    W, lanes) are the new rows, ``q`` the ABSORBED queries of every head (S,
+    H, W, lanes) -- H query vectors a slot against the one row a position,
+    the grouped case with one K/V head -- scaled by ``score_dim``, and the
+    values are the SAME gathered rows (no second gather): ``o``'s leading
+    ``kv_rank`` lanes are the weighted latents.
     Returns (o (S, H, W, D) f32, arenas')."""
     n_kv, d = k.shape[1], k.shape[3]
     B = arenas[0].shape[2]
-    k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)   # (S, W, H, D)
+    latent = v is None
+    k = k.transpose(0, 2, 1, 3)                                # (S, W, H, D)
     quant = len(arenas) == 4
-    if quant:
-        ka, va, ksa, vsa = arenas
-        k, ksr = _kv_quantize_rows(k)
-        v, vsr = _kv_quantize_rows(v)
-        ksa = write_rows(ksa, layer, blk, off, ksr)
-        vsa = write_rows(vsa, layer, blk, off, vsr)
+    if latent:
+        ka = va = write_rows(arenas[0], layer, blk, off, k)
+        arenas = (ka,)
     else:
-        ka, va = arenas
-    ka = write_rows(ka, layer, blk, off, k)
-    va = write_rows(va, layer, blk, off, v)
-    arenas = (ka, va, ksa, vsa) if quant else (ka, va)
+        v = v.transpose(0, 2, 1, 3)
+        if quant:
+            ka, va, ksa, vsa = arenas
+            k, ksr = _kv_quantize_rows(k)
+            v, vsr = _kv_quantize_rows(v)
+            ksa = write_rows(ksa, layer, blk, off, ksr)
+            vsa = write_rows(vsa, layer, blk, off, vsr)
+        else:
+            ka, va = arenas
+        ka = write_rows(ka, layer, blk, off, k)
+        va = write_rows(va, layer, blk, off, v)
+        arenas = (ka, va, ksa, vsa) if quant else (ka, va)
     s_, h_, w_ = q.shape[:3]
     g = h_ // n_kv
     q = q.astype(jnp.float32).reshape(s_, n_kv, g, w_, d)
     # the list a chunk at a time: as many chunks as hold a listed block
-    chunk = min(list_chunk(s_, g * w_ > 1), live.shape[1])
+    grouped = latent or g * w_ > 1
+    chunk = min(list_chunk(s_, grouped, latent), live.shape[1])
     pad = -live.shape[1] % chunk
     live = jnp.pad(live, ((0, 0), (0, pad)), constant_values=s_)
     mask = jnp.pad(mask, ((0, pad), (0, 0), (0, 0)))
@@ -460,7 +488,7 @@ def _paged_attention(q, k, v, arenas, layer, blk, off, live, mask):
         # (P, H_kv): an int8 row's scale a head
         scales = (tuple(read_chain(a, layer, ids, (B, n_kv))
                         for a in (ksa, vsa)) if quant else None)
-        if g * w_ == 1:             # one query vector a slot and K/V head
+        if not grouped:             # one query vector a slot and K/V head
             block = (B, n_kv, d)
             mine = jnp.repeat(owner, B)[None, :] == jnp.arange(s_)[:, None]
             new = _attend_all_pairs(
@@ -470,9 +498,10 @@ def _paged_attention(q, k, v, arenas, layer, blk, off, live, mask):
             new = tuple(x.reshape(x.shape[:2] + (1, 1) + x.shape[2:])
                         for x in new)
         else:
-            new = _attend_by_owner(q, read_rows(ka, layer, ids),
-                                   read_rows(va, layer, ids), owner, seen,
-                                   scales)
+            k_rows = read_rows(ka, layer, ids)
+            new = _attend_by_owner(
+                q, k_rows, k_rows if latent else read_rows(va, layer, ids),
+                owner, seen, scales, score_dim)
         # one softmax over every chunk: each part rescaled to the larger
         # maximum (a slot with nothing in a chunk adds exp(-1e30 - ..) = 0)
         top = jnp.maximum(parts[0], new[0])
@@ -501,9 +530,9 @@ def _scan_layers(model, params, h, arenas, layer_fn):
     and copied every layer (PERF.md, PR 25).  A group of the plan is one
     scan over its stacked periods, the body running the period's layers
     in turn.  -> (h, arenas, counts): ``counts`` the routed expert
-    layers' two integers summed over the layers."""
-    counts = jnp.zeros((2,), jnp.int32)
-    base = {"attention": 0, "kda": 0}
+    layers' integers summed over the layers."""
+    counts = jnp.zeros((model.n_counts,), jnp.int32)
+    base = {m: 0 for m in _KINDS}
     arenas = tuple(arenas)
     for (repeat, period), stacks in zip(model.plan,
                                         model.group_params(params)):
@@ -530,8 +559,43 @@ def _arenas(k_arena, v_arena, k_scale, v_scale):
             else (k_arena, v_arena, k_scale, v_scale))
 
 
+#: positions of a cached prefix that a latent layer's suffix prefill expands
+#: and attends at a time (its float32 scores are H x suffix x this many)
+LATENT_PREFIX_STEP = 2048
+
+
+def _latent_prefix_parts(model, bp, q, arena, layer, blocks, prefix_len):
+    """A latent layer's suffix queries ``q`` (1, H, Ts, nope + rope) against
+    the CACHED PREFIX, read from the latent ``arena`` through the padded
+    chain ``blocks`` and EXPANDED a step of :data:`LATENT_PREFIX_STEP`
+    positions at a time, as far as ``prefix_len`` reaches (a loop of that
+    many steps: a bucket's padding is not walked).  Every suffix query sees
+    every prefix position.  -> the softmax's parts over the prefix, in the
+    order :func:`~bigdl_tpu.nn.attention.online_softmax_update` carries them:
+    (o (1, H, Ts, v), the sum, the maximum)."""
+    from bigdl_tpu.nn.attention import NEG_INF, online_softmax_update
+    B = arena.shape[2]
+    step = min(max(LATENT_PREFIX_STEP // B, 1), blocks.shape[0])
+    blocks = jnp.pad(blocks, (0, -blocks.shape[0] % step),
+                     constant_values=SCRATCH_BLOCK)
+    part = q.shape[:-1]
+
+    def attend(i, carry):
+        ids = lax.dynamic_slice_in_dim(blocks, i * step, step)
+        rows = read_rows(arena, layer, ids)[None, :, :model.mla.row]
+        k, v = model.mla_expand(bp, rows)
+        seen = (i * step * B + jnp.arange(step * B)) < prefix_len
+        top, den, o = model.latent_parts(q, k, v, seen)
+        return online_softmax_update(carry, (top, den, o))
+
+    return lax.fori_loop(
+        0, (prefix_len + step * B - 1) // (step * B), attend,
+        (jnp.zeros(part + (model.mla.v,), jnp.float32),
+         jnp.zeros(part, jnp.float32), jnp.full(part, NEG_INF, jnp.float32)))
+
+
 def _prefill_suffix_parts(model, params, ids0, last_index, prefix_len,
-                          blocks, k_arena, v_arena,
+                          blocks, k_arena, v_arena=None,
                           k_scale=None, v_scale=None, *, carried=()):
     """Prefill a prompt SUFFIX against a cached prefix held in paged KV
     blocks: ``ids0`` (1, Ts) is the (bucket-padded) suffix, whose tokens
@@ -557,8 +621,13 @@ def _prefill_suffix_parts(model, params, ids0, last_index, prefix_len,
     the prefix's end: ``(state (R, 1, H, D, D), tail (R, 1, taps - 1,
     channels))`` by recurrent layer; the suffix starts from it and the
     result carries, as :func:`_prefill_parts`' does, what they hold at the
-    suffix's true end."""
-    from bigdl_tpu.nn.attention import dot_product_attention
+    suffix's true end.
+
+    A latent pool's one arena comes as ``k_arena`` (``v_arena`` None): a
+    latent layer attends its prefix through :func:`_latent_prefix_parts` and
+    its own rows causally, one softmax, and hands out its suffix ROWS."""
+    from bigdl_tpu.nn.attention import (_finalize, dot_product_attention,
+                                        online_softmax_update)
 
     b, ts = ids0.shape
     B = k_arena.shape[2]
@@ -579,8 +648,8 @@ def _prefill_suffix_parts(model, params, ids0, last_index, prefix_len,
     jk = jnp.arange(pb * B + ts)
     kpos = jnp.where(jk < pb * B, jk, prefix_len + jk - pb * B)
     valid = (jk < prefix_len) | (jk >= pb * B)
-    masks = {w: (valid[None, :] & window_mask(positions, kpos, w))[None, None]
-             for w in _windows(model)}
+    masks = ({w: (valid[None, :] & window_mask(positions, kpos, w))[None, None]
+              for w in _windows(model)} if model.kv_layers else {})
 
     def prefix(arena, scale, layer, dtype):
         # the prefix chain (Pb*B, H, D) -> (1, H, Pb*B, D)
@@ -596,6 +665,17 @@ def _prefill_suffix_parts(model, params, ids0, last_index, prefix_len,
                 bp, h, carried[0][layer], carried[1][layer], last_index + 1)
             h, c = _ffn(model, spec, bp, h + y)
             return h, (state, tail), c
+        if spec.mixer == "mla":
+            q, row, gate = model.mla_inputs(spec, bp, h, positions)
+            k, v = model.mla_expand(bp, row)
+            own = jnp.arange(ts)
+            o, den, _ = online_softmax_update(
+                _latent_prefix_parts(model, bp, q, k_arena, layer, blocks,
+                                     prefix_len),
+                model.latent_parts(q, k, v, window_mask(own, own, None)))
+            h, c = _finish_block(model, spec, bp, h,
+                                 _finalize(o, den).astype(h.dtype), gate)
+            return h, (row,), c
         q, k, v, gate = model.layer_qkv(spec, bp, h, positions)
         kc = jnp.concatenate([prefix(k_arena, k_scale, layer, k.dtype), k], 2)
         vc = jnp.concatenate([prefix(v_arena, v_scale, layer, v.dtype), v], 2)
@@ -648,6 +728,16 @@ def _insert_blocks(k_arena, v_arena, k_new, v_new, block_ids,
     return k_arena, v_arena
 
 
+def _insert_rows(arena, new, block_ids):
+    """:func:`_insert_blocks` for a LATENT pool's one arena: a prefilled
+    chunk's rows ``new`` (L, 1, Tb, lanes) into the blocks ``block_ids``,
+    row i at offset ``i % B`` of block ``block_ids[i // B]``."""
+    B, nb = arena.shape[2], block_ids.shape[0]
+    x = jnp.pad(new[:, 0], ((0, 0), (0, nb * B - new.shape[2]), (0, 0)))
+    return (write_rows(arena, slice(None), block_ids, None,
+                       x.reshape(x.shape[0], nb, B, x.shape[2])),)
+
+
 def _decode_step_paged(model, params, token, pos, live, *arenas,
                        table_width: Optional[int] = None,
                        attn_impl: str = "gather"):
@@ -681,9 +771,12 @@ def _decode_step_paged(model, params, token, pos, live, *arenas,
     with recurrent layers, ride the state arenas ``(state, tail)``
     (``serving.kvcache.state``: a row a recurrent layer and slot): a
     recurrent layer reads and writes its slots' rows (``kda/step``) and an
-    idle slot's row stays as it was.
+    idle slot's row stays as it was.  A LATENT pool's arenas are its one
+    ``(rows,)``: a latent layer writes the new row and attends ABSORBED
+    (``TransformerLM.mla_absorb`` / ``mla_values`` around
+    :func:`_paged_attention`, ``mla/attend``).
 
-    -> (logits (S, V) float32, [the routed layers' two integers, when the
+    -> (logits (S, V) float32, [the routed layers' integers, when the
     model has any], *arenas).  The serving engine's decode program is
     :func:`_decode_pick_paged`, which picks from these logits on the
     device and hands out ids; the logits stay this function's result for
@@ -736,9 +829,26 @@ def _decode_step_paged(model, params, token, pos, live, *arenas,
                 bp, h, state[layer], tail[layer], active)
             recurrent = (state.at[layer].set(row), tail.at[layer].set(tail_row))
             h, counts = _ffn(model, spec, bp, h + y, active[:, None])
+        elif spec.mixer == "mla":
+            h, kv, counts = latent_layer(spec, h, bp, layer, kv)
         else:
             h, kv, counts = attention_layer(spec, h, bp, layer, kv)
         return h, kv + recurrent, counts
+
+    def latent_layer(spec, h, bp, layer, arenas):
+        # ABSORBED: every head's query folded through W_uk meets the one
+        # cached row a position; W_uv after the softmax
+        m = model.mla
+        q, row, gate = model.mla_inputs(spec, bp, h, positions)  # (S, H, 1, ..)
+        q = model.mla_absorb(bp, q)
+        with jax.named_scope("mla/attend"):
+            u, arenas = _paged_attention(q, row[:, None], None, arenas, layer,
+                                         blk, off, live, masks[None],
+                                         score_dim=m.score_dim)
+        o = model.mla_values(bp, u[..., :m.kv_rank])
+        h, counts = _finish_block(model, spec, bp, h, o.astype(h.dtype), gate,
+                                  token_mask=active[:, None])
+        return h, arenas, counts
 
     def attention_layer(spec, h, bp, layer, arenas):
         q, k, v, gate = model.layer_qkv(spec, bp, h, positions)  # (S, H, 1, D)

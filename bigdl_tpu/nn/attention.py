@@ -49,17 +49,23 @@ def segment_mask(seg_q, seg_k):
     return seg_q[:, None, :, None] == seg_k[:, None, None, :]
 
 
-def _block_scores(q, k, v, mask, scale):
+def _block_scores(q, k, v, mask, scale, acc=None):
     """Partial attention of q against one k/v block.
-    q: (..., Tq, D); k, v: (..., Tk, D); mask: broadcastable (..., Tq, Tk)
-    or None.  Returns (m_blk (...,Tq), l_blk (...,Tq), o_blk (...,Tq,D))."""
-    s = jnp.einsum("...qd,...kd->...qk", q, k) * scale
+    q: (..., Tq, D); k: (..., Tk, D); v: (..., Tk, Dv); mask: broadcastable
+    (..., Tq, Tk) or None.  ``acc`` (a dtype): scores, softmax and sums in
+    it whatever the operands' (the weights meet v in v's dtype); None: the
+    operands' own.  Returns (m_blk (...,Tq), l_blk (...,Tq), o_blk
+    (...,Tq,Dv))."""
+    s = jnp.einsum("...qd,...kd->...qk", q, k,
+                   preferred_element_type=acc) * scale
     if mask is not None:
         s = jnp.where(mask, s, NEG_INF)
     m_blk = jnp.max(s, axis=-1)
     p = _safe_exp(s, m_blk[..., None])
     l_blk = jnp.sum(p, axis=-1)
-    o_blk = jnp.einsum("...qk,...kd->...qd", p, v)
+    o_blk = jnp.einsum("...qk,...kd->...qd",
+                       p if acc is None else p.astype(v.dtype), v,
+                       preferred_element_type=acc)
     return m_blk, l_blk, o_blk
 
 

@@ -208,7 +208,13 @@ class MoESpec(NamedTuple):
     of the result its own experts give.  ``score`` is how a router logit
     becomes a weight: ``"softmax"`` over all experts, or ``"sigmoid"`` an
     expert, chosen by the score plus a ``select_bias`` parameter
-    (n_experts,) beside the router and weighed by the score alone."""
+    (n_experts,) beside the router and weighed by the score alone.
+    ``n_group`` > 1 is GROUP-LIMITED selection (a sigmoid router's): the
+    experts form ``n_group`` groups of consecutive ones, a group scores the
+    sum of its two largest ``score + select_bias``, and the ``top_k`` are
+    chosen inside the ``topk_group`` best groups alone -- with a group a
+    chip, a token's experts lie on at most ``topk_group`` chips.  1 and 1:
+    no groups."""
     n_experts: int
     top_k: int
     width: int
@@ -217,10 +223,18 @@ class MoESpec(NamedTuple):
     norm_topk: bool = True
     held: Optional[Tuple[int, int]] = None
     score: str = "softmax"
+    n_group: int = 1
+    topk_group: int = 1
 
     @property
     def n_held(self) -> int:
         return self.n_experts if self.held is None else int(self.held[1])
+
+    @property
+    def n_counts(self) -> int:
+        """How many integers a routed layer counts (:func:`routed_experts`):
+        the groups hit ride behind the two where the router has groups."""
+        return 3 if self.n_group > 1 else 2
 
 
 def init_routed_params(rng, spec: MoESpec, d_model: int):
@@ -249,13 +263,26 @@ def route_top_k(router, x2, spec: MoESpec, select_bias=None):
     """Scores over ALL experts in f32 (``spec.score``), the ``top_k``
     largest, their weights renormalised and scaled: -> (idx (T, k) int32,
     w (T, k) f32).  A sigmoid router selects on ``score + select_bias``
-    and weighs by the unbiased score."""
+    (inside its best groups, where it has groups: :class:`MoESpec`) and
+    weighs by the unbiased score."""
     logits = jnp.dot(x2, router.astype(x2.dtype),
                      preferred_element_type=jnp.float32)
+    if spec.n_group > 1 and spec.score != "sigmoid":
+        raise ValueError("group-limited selection (MoESpec.n_group > 1) is a "
+                         "sigmoid router's")
     if spec.score == "sigmoid":
         scores = jax.nn.sigmoid(logits)
-        _, idx = lax.top_k(scores + select_bias.astype(jnp.float32),
-                           spec.top_k)
+        biased = scores + select_bias.astype(jnp.float32)
+        if spec.n_group > 1:
+            with jax.named_scope("moe/group_select"):
+                by_group = biased.reshape(biased.shape[0], spec.n_group, -1)
+                best = jnp.sum(lax.top_k(by_group, 2)[0], axis=-1)
+                _, kept = lax.top_k(best, spec.topk_group)  # (T, topk_group)
+                stays = jnp.any(kept[:, :, None] == jnp.arange(spec.n_group),
+                                axis=1)                      # (T, n_group)
+                biased = jnp.where(stays[:, :, None], by_group,
+                                   -jnp.inf).reshape(biased.shape)
+        _, idx = lax.top_k(biased, spec.top_k)
         w = jnp.take_along_axis(scores, idx, axis=-1)
     elif spec.score == "softmax":
         w, idx = lax.top_k(jax.nn.softmax(logits, axis=-1), spec.top_k)
@@ -306,7 +333,9 @@ def routed_experts(params, x, spec: MoESpec, *, token_mask=None,
     tokens out of the routing (a decode step's idle slots).
 
     Returns ``(y, counts)``: ``counts`` int32 (2,) = assignments that
-    landed here, distinct held experts hit."""
+    landed here, distinct held experts hit; behind them, where the router
+    has groups, the groups (of ALL the experts) in which the routed tokens
+    have a chosen expert, summed over the tokens (``spec.n_counts``)."""
     shape = x.shape
     x2 = x.reshape(-1, shape[-1])
     t, k = x2.shape[0], spec.top_k
@@ -336,7 +365,14 @@ def routed_experts(params, x, spec: MoESpec, *, token_mask=None,
                     axis=1).astype(x.dtype)
     if axis is not None:
         y = lax.psum(y, axis)
-    counts = jnp.stack([jnp.sum(here), jnp.sum(sizes > 0)]).astype(jnp.int32)
+    counts = [jnp.sum(here), jnp.sum(sizes > 0)]
+    if spec.n_group > 1:
+        group = idx // (spec.n_experts // spec.n_group)        # (T, k)
+        hit = jnp.any(group[:, :, None] == jnp.arange(spec.n_group), axis=1)
+        if token_mask is not None:
+            hit = hit & token_mask.reshape(-1)[:, None]
+        counts.append(jnp.sum(hit))
+    counts = jnp.stack(counts).astype(jnp.int32)
     return y.reshape(shape), counts
 
 

@@ -3,7 +3,9 @@
 - :class:`BlockPool` — one fixed-shape HBM k/v arena of contiguous,
   lane-padded blocks (the layout is stated once, in
   :mod:`~bigdl_tpu.serving.kvcache.blocks`), host-side free list,
-  refcounted so block chains are shared copy-free.
+  refcounted so block chains are shared copy-free.  What a cached row is,
+  the model says: a ``(k, v)`` pair a K/V head, or ONE latent row a
+  position (``latent=True``: one arena, no second).
 - :class:`RadixCache` — token-prefix trie over block chains with LRU
   eviction of unreferenced tails; admission reuses the longest cached
   prefix and prefills only the suffix.

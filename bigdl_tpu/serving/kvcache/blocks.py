@@ -10,6 +10,13 @@ fixed-size blocks
     k, v : (L, num_blocks, block_len, W),   W = H * D rounded up to 128
 
 and a *sequence* is a host-side list of block ids (its block table).
+**What a cached row is, the model says**: a ``(k, v)`` PAIR a K/V head
+(softmax attention over cached keys and values: two arenas, a row the
+position's H heads of D values side by side), or ONE LATENT ROW (latent
+attention, ``BlockPool(latent=True)``: a position's ``[c ; k_r]``, with no
+head axis -- H = 1, D the row's lanes, 576 -> 640 -- and NO SECOND ARENA:
+the step's values are the row's own first lanes).  ``row_width``,
+``block_bytes``, ``wire_shape``, ``arenas`` and ``stats()`` follow from it.
 **This is the one place the arena layout is written down.**  A block is
 ``block_len`` position rows, a row is the position's H heads of D values
 side by side, zero-padded to whole 128-lane tiles (1600 -> 1664 at GPT-2
@@ -190,13 +197,22 @@ def read_chain(arena, layer, tables, block):
     return g[..., :int(np.prod(block[1:]))].reshape(g.shape[:-1] + block[1:])
 
 
-def list_chunk(slots: int, grouped: bool = False) -> int:
+def list_chunk(slots: int, grouped: bool = False, latent: bool = False) -> int:
     """Blocks of a live list a step attends at a time: four a slot, or
     sixteen where a chunk costs two ``grouped`` matmuls (each a custom
-    call of some 0.1 ms whatever it multiplies: PERF.md, PR 29).  A step
-    loops over as many chunks as hold a listed block, so what a round
-    gathers follows its live blocks to within a chunk, in ONE executable
-    for every length a list can have."""
+    call of some 0.1 ms whatever it multiplies: PERF.md, PR 29), or forty
+    for a ``latent`` walk (one row a position, every head's query against
+    it).  That one is the best of a sweep of the walk alone on the chip, at
+    32 slots and 51,000 live blocks (PERF.md, PR 35: 16 / 40 / 80 / 160 /
+    320 blocks a slot walk in 20.5 / 14.8 / 15.7 / 22.7 / 22.0 ms); inside
+    the whole decode step it gave a twentieth, not a third (160 -> 40
+    blocks a slot: 970-980 -> 1,011-1,014 tokens/s; why the loop inside the
+    step is not the loop alone is open there).  A step loops over as many
+    chunks as hold a listed block, so what a round gathers follows its live
+    blocks to within a chunk, in ONE executable for every length a list can
+    have."""
+    if latent:
+        return 40 * int(slots)
     return (16 if grouped else 4) * int(slots)
 
 
@@ -275,10 +291,18 @@ class RequestExceedsPool(ValueError):
 
 
 class BlockPool:
-    """Refcounted free-list allocator over one paged k/v arena.
+    """Refcounted free-list allocator over one paged k/v arena, or over
+    one arena of latent rows.
 
     Args:
-        n_layers / n_heads / head_dim: model geometry (L, H, D).
+        n_layers / n_heads / head_dim: model geometry (L, H, D): the layers
+            that cache a row, and what a row is -- H K/V heads of D, or, with
+            ``latent``, one head of the latent row's lanes.
+        latent: the cached row is ONE latent row a position (module
+            docstring): one arena ``self.k``, ``self.v`` is None, and what
+            carries ``(k, v)`` pairs -- ``kv_quant`` (a scale a head),
+            ``export_chain`` / ``adopt_chain`` (the wire format) -- is
+            refused.
         block_len: tokens per block (the page size).
         num_blocks: total blocks INCLUDING the reserved scratch block 0;
             usable capacity is ``num_blocks - 1``.
@@ -303,8 +327,13 @@ class BlockPool:
 
     def __init__(self, *, n_layers: int, n_heads: int, head_dim: int,
                  block_len: int, num_blocks: int, dtype=None,
-                 kv_quant: Optional[str] = None):
+                 kv_quant: Optional[str] = None, latent: bool = False):
         import jax.numpy as jnp
+
+        self.latent = bool(latent)
+        if self.latent and (kv_quant is not None or n_heads != 1):
+            raise ValueError("a latent pool holds one full-precision row a "
+                             "position: n_heads 1, no kv_quant")
 
         if block_len < 1:
             raise ValueError(f"block_len must be >= 1, got {block_len}")
@@ -338,7 +367,7 @@ class BlockPool:
         else:
             dt = dtype if dtype is not None else jnp.float32
             self.k = jnp.zeros(self.shape, dt)
-            self.v = jnp.zeros(self.shape, dt)
+            self.v = None if self.latent else jnp.zeros(self.shape, dt)
             self.ks = self.vs = None
         self.dtype = self.k.dtype
         self._lock = threading.Lock()
@@ -349,14 +378,19 @@ class BlockPool:
 
     @property
     def arenas(self) -> tuple:
-        """``(k, v)`` or, quantized, ``(k, v, ks, vs)``: what the donated
-        executables take and hand back (assign their outputs here)."""
+        """``(k, v)``, quantized ``(k, v, ks, vs)``, or a latent pool's one
+        ``(rows,)``: what the donated executables take and hand back
+        (assign their outputs here)."""
+        if self.latent:
+            return (self.k,)
         return ((self.k, self.v) if self.ks is None
                 else (self.k, self.v, self.ks, self.vs))
 
     @arenas.setter
     def arenas(self, new) -> None:
-        if self.ks is None:
+        if self.latent:
+            (self.k,) = new
+        elif self.ks is None:
             self.k, self.v = new
         else:
             self.k, self.v, self.ks, self.vs = new
@@ -377,9 +411,21 @@ class BlockPool:
         return self.capacity - self.free_count
 
     @property
+    def data_arenas(self) -> int:
+        """How many arenas hold rows: k and v, or the one of latent rows."""
+        return 1 if self.latent else 2
+
+    @property
+    def row_bytes(self) -> int:
+        """One position's row in one layer as the arenas hold it, lane
+        padding included (both of a pair)."""
+        return self.data_arenas * self.shape[3] * self.dtype.itemsize
+
+    @property
     def kv_arena_bytes(self) -> int:
-        """HBM footprint of the k + v data arenas alone."""
-        return 2 * self.k.size * self.k.dtype.itemsize
+        """HBM footprint of the data arenas alone (k + v, or the latent
+        rows')."""
+        return self.data_arenas * self.k.size * self.k.dtype.itemsize
 
     @property
     def scale_arena_bytes(self) -> int:
@@ -402,6 +448,20 @@ class BlockPool:
     def blocks_for(self, n_tokens: int) -> int:
         """Blocks needed to hold ``n_tokens`` cache positions."""
         return -(-int(n_tokens) // self.block_len)
+
+    def rows_at(self, chain, positions) -> tuple:
+        """The cached rows of one ``chain`` (block ids in chain order) at
+        ``positions`` as the data arenas hold them, the lane padding cut: one
+        host array an arena -- k and v, or the latent rows -- of (layers,
+        positions, H * D).  For a check that compares what was cached with a
+        reference's rows; an int8 pool's come without their scales."""
+        import numpy as np
+        chain = np.asarray(chain, np.int32)
+        positions = np.asarray(positions, np.int64)
+        blk, off = chain[positions // self.block_len], positions % self.block_len
+        lanes = self.n_heads * self.head_dim
+        return tuple(np.asarray(a[:, blk, off, :lanes])
+                     for a in self.arenas[:self.data_arenas])
 
     # -- alloc / refcount ------------------------------------------------ #
     def alloc(self, n: int) -> List[int]:
@@ -489,6 +549,7 @@ class BlockPool:
         import numpy as np
 
         from bigdl_tpu.utils.transfer import DEFAULT_CHUNK_BYTES
+        self._pairs_only("export_chain")
         cb = int(chunk_bytes) if chunk_bytes else DEFAULT_CHUNK_BYTES
         n = len(blocks)
         L, H, B, D = self.wire_shape
@@ -522,6 +583,12 @@ class BlockPool:
             out["ks"] = host_ks
             out["vs"] = host_vs
         return out
+
+    def _pairs_only(self, what: str) -> None:
+        if self.latent:
+            raise NotImplementedError(
+                f"{what}: the wire format is a (k, v) pair, and this pool "
+                f"holds one latent row a position (ROADMAP M4)")
 
     def _adopt_scatter(self, width: int):
         """Donated scatter of a ``width``-block wire payload into the
@@ -558,6 +625,7 @@ class BlockPool:
         through the donation."""
         import jax.numpy as jnp
         import numpy as np
+        self._pairs_only("warmup_adopt")
         n = 0
         for w in widths:
             w = int(w)
@@ -610,6 +678,7 @@ class BlockPool:
 
         from bigdl_tpu.utils.transfer import (DEFAULT_CHUNK_BYTES,
                                               chunked_device_put)
+        self._pairs_only("adopt_chain")
         k_wire = np.asarray(k_wire)
         v_wire = np.asarray(v_wire)
         n = int(k_wire.shape[0]) if k_wire.ndim else 0
@@ -699,4 +768,10 @@ class BlockPool:
                             if self.capacity else 0.0),
             "arena_bytes": self.arena_bytes,
             "kv_quant": self.kv_quant or "none",
+            # what a cached row is for the model at hand, and its bytes in
+            # one layer as the arenas hold it
+            "row": ("one latent row a position" if self.latent
+                    else "a (k, v) pair a K/V head"),
+            "row_lanes": self.n_heads * self.head_dim,
+            "row_bytes": self.row_bytes,
         }

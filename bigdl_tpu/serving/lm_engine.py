@@ -61,6 +61,15 @@ step, donated; a prefill writes its slot's rows (``lm/state_insert``), a
 chunk's continuation starts from them, and the radix prefix cache is off
 (no state exists at a shared prefix's boundary).
 
+Latent layers: a model whose plan has ``mixer="mla"`` layers caches ONE
+LATENT ROW a position and layer, nothing a head: its pool is
+``BlockPool(latent=True)``, one arena and no second (a model with no
+``(k, v)`` layer gets no ``(k, v)`` arena at all), the step programs' donated
+tuple is ``(rows,)`` + the state arenas, a prefill hands out rows
+(``generate._insert_rows``) and reads a chunk's prefix from the arena, and
+the decode step attends ABSORBED through the same live list.  What assumes a
+``(k, v)`` pair is refused at construction (:func:`refuse_unsupported`).
+
 Sharing: the radix cache maps token prefixes to refcounted block
 chains, so concurrent requests with a common head attend the SAME
 blocks copy-free; decode always writes into a sequence's private tail
@@ -176,6 +185,66 @@ def split_decode_operands(ops, slots: int):
             lax.bitcast_convert_type(ops[3 * s:5 * s],
                                      jnp.uint32).reshape(s, 2),
             ops[5 * s:].reshape(3, -1))
+
+
+#: what a cache kind other than a paged ``(k, v)`` pair cannot do: a
+#: row a (kind, what is asked, why); the next cache kind adds rows, not branches
+_KIND_NAMES = {"recurrent": ("recurrent layers", "M6"),
+               "latent": ("latent attention layers", "M4")}
+_REFUSALS = (
+    ("recurrent", "serve with spec", "a rejected draft would need the "
+     "recurrent state rolled back, and only the K/V pointer rewinds"),
+    ("recurrent", "serve with migrate", "the handoff carries (k, v) chains, "
+     "not a recurrent layer's state"),
+    ("recurrent", "serve with kvtier", "demotion, promotion and hibernation "
+     "carry (k, v) blocks, not a recurrent layer's state"),
+    ("recurrent", "serve with decode_attn='paged_kernel'", "the Pallas "
+     "block-table kernel reads one K/V head a query head, and this model's "
+     "attention layers share theirs"),
+    ("recurrent", "serve with tensor-parallel placement", "no rule places a "
+     "recurrent layer's heads and state"),
+    ("recurrent", "adopt a migrated request", "the handoff carries (k, v) "
+     "chains, not a recurrent layer's state"),
+    ("latent", "serve with kv_quant='int8'", "the int8 pool keeps a scale a "
+     "(position, head), and a latent row has no head"),
+    ("latent", "serve with spec", "the verify steps attend (k, v) rows; no "
+     "absorbed verify step is written"),
+    ("latent", "serve with migrate", "the handoff's wire format is a (k, v) "
+     "pair, and the pool holds one latent row a position"),
+    ("latent", "serve with kvtier", "the host tier's wire format is a (k, v) "
+     "pair, and the pool holds one latent row a position"),
+    ("latent", "serve with decode_attn='paged_kernel'", "the Pallas "
+     "block-table kernel reads (k, v) blocks of one head width"),
+    ("latent", "serve with tensor-parallel placement", "no rule places the "
+     "up-projections' heads"),
+    ("latent", "adopt a migrated request", "the handoff carries (k, v) "
+     "chains, and the pool holds one latent row a position"),
+)
+
+
+def refuse_unsupported(model, *, spec=None, migrate=None, kvtier=None,
+                       decode_attn="auto", kv_quant=None, placement=None,
+                       adopt=False):
+    """Raise, with its one message, for the first thing asked that the
+    model's cache kinds cannot do (:data:`_REFUSALS`): THE place where a
+    recurrent state and a latent pool refuse what assumes a paged ``(k, v)``
+    pair, at construction and where a handoff arrives (``adopt``)."""
+    has = {"recurrent": bool(model.state_layers),
+           "latent": bool(model.latent_layers)}
+    asked = {"serve with spec": spec is not None,
+             "serve with migrate": migrate is not None,
+             "serve with kvtier": kvtier is not None,
+             "serve with decode_attn='paged_kernel'":
+                 decode_attn == "paged_kernel",
+             "serve with kv_quant='int8'": kv_quant == "int8",
+             "serve with tensor-parallel placement":
+                 placement is not None and placement.tp > 1,
+             "adopt a migrated request": adopt}
+    for kind, what, why in _REFUSALS:
+        if has[kind] and asked[what]:
+            name, milestone = _KIND_NAMES[kind]
+            raise ValueError(f"a model with {name} cannot {what}: {why} "
+                             f"(ROADMAP {milestone})")
 
 
 def prefill_bucket_lengths(max_len: int, min_bucket: int = 8) -> tuple:
@@ -433,6 +502,15 @@ class LMMetrics:
         self.moe_experts_hit = 0
         self.moe_expert_layer_rounds = 0
         self.moe_prefill_assignments = 0
+        # a router with groups (zero otherwise): the groups in which a
+        # decoded token has a chosen expert, summed over tokens, layers, rounds
+        self.moe_groups_hit = 0
+        # latent layers (zero for a model without): (position, latent layer)
+        # rows the decode rounds' live slots held -- what their attention
+        # read -- and those rows' bytes as the arena holds them
+        self.latent_rows_read = 0
+        self.latent_bytes_read = 0
+        self.latent_row_bytes = 0
         # recurrent layers (zero for a model without): (slot, recurrent
         # layer) rows the decode rounds read and wrote, the rows that hold a
         # seated request's state now, and the state arena's bytes
@@ -460,8 +538,12 @@ class LMMetrics:
             registry.register(prefix + key,
                               FnGauge(lambda k=key: getattr(self, k)),
                               replace=True)
+        for key in ("latent_rows_read", "latent_bytes_read"):
+            registry.register(prefix + key,
+                              FnGauge(lambda k=key: getattr(self, k)),
+                              replace=True)
         for key in ("assignments", "experts_hit", "expert_layer_rounds",
-                    "prefill_assignments"):
+                    "prefill_assignments", "groups_hit"):
             registry.register(
                 prefix + "moe/" + key,
                 FnGauge(lambda k="moe_" + key: getattr(self, k)),
@@ -503,8 +585,8 @@ class LMMetrics:
     def record_step(self, n_active: int, itls_s: Sequence[float],
                     prefill_interrupted: bool = False, *,
                     live_blocks: int = 0, gathered_blocks: int = 0,
-                    state_rows: int = 0, ahead: bool = False,
-                    discarded: int = 0) -> None:
+                    state_rows: int = 0, latent_rows: int = 0,
+                    ahead: bool = False, discarded: int = 0) -> None:
         with self._lock:
             now = time.perf_counter()
             self.decode_steps += 1
@@ -513,6 +595,8 @@ class LMMetrics:
             self.live_blocks += live_blocks
             self.gathered_blocks += gathered_blocks
             self.state_row_steps += state_rows
+            self.latent_rows_read += latent_rows
+            self.latent_bytes_read += latent_rows * self.latent_row_bytes
             self.slot_steps += self.slots
             self.active_slot_steps += n_active
             self.peak_active = max(self.peak_active, n_active)
@@ -538,10 +622,13 @@ class LMMetrics:
 
     def record_moe(self, counts, layers: int) -> None:
         """One decode step's routed-layer integers (summed over its
-        ``layers`` expert layers by the step program)."""
+        ``layers`` expert layers by the step program; the groups hit ride
+        third where the router has groups)."""
         with self._lock:
             self.moe_assignments += int(counts[0])
             self.moe_experts_hit += int(counts[1])
+            if len(counts) > 2:
+                self.moe_groups_hit += int(counts[2])
             self.moe_expert_layer_rounds += int(layers)
 
     def record_moe_prefill(self, assignments: int) -> None:
@@ -630,7 +717,11 @@ class LMMetrics:
                 "moe": {"assignments": self.moe_assignments,
                         "experts_hit": self.moe_experts_hit,
                         "expert_layer_rounds": self.moe_expert_layer_rounds,
-                        "prefill_assignments": self.moe_prefill_assignments},
+                        "prefill_assignments": self.moe_prefill_assignments,
+                        "groups_hit": self.moe_groups_hit},
+                "latent": {"rows_read": self.latent_rows_read,
+                           "bytes_read": self.latent_bytes_read,
+                           "row_bytes": self.latent_row_bytes},
                 "state": {"row_steps": self.state_row_steps,
                           "rows_in_use": self.state_rows_in_use,
                           "bytes": self.state_bytes},
@@ -708,7 +799,7 @@ class _Round:
     finishes by its count."""
 
     __slots__ = ("ids", "moe", "rows", "t0", "ahead", "n_live", "gathered",
-                 "n_last", "sampled")
+                 "n_last", "n_positions", "sampled")
 
     def __init__(self, t0: float, ahead: bool):
         self.ids = self.moe = None      # device arrays, on their way
@@ -716,6 +807,7 @@ class _Round:
         self.t0 = t0                    # when its dispatch began
         self.ahead = ahead              # enqueued behind a round in flight
         self.n_live = self.gathered = self.n_last = 0
+        self.n_positions = 0            # live positions its slots hold
         self.sampled: list = []         # (rid, slot, step) of traced requests
 
 
@@ -911,7 +1003,7 @@ class LMServingEngine:
         configure_compile_cache()
         import jax
         from bigdl_tpu.models.transformer.generate import (
-            _decode_pick_paged, _insert_blocks, _prefill_parts,
+            _decode_pick_paged, _insert_blocks, _insert_rows, _prefill_parts,
             _prefill_suffix_parts, _tree_commit_paged,
             _tree_verify_step_paged, _verify_step_paged)
         from bigdl_tpu.quant import dequantize_entry
@@ -919,33 +1011,19 @@ class LMServingEngine:
 
         model._built()
         self.model = model
-        #: the model's recurrent layers (``mixer="kda"``): with any, a state
-        #: arena rides beside the pool and what carries (k, v) pairs only is
-        #: refused HERE, each with its one message
+        #: the model's recurrent layers (``mixer="kda"``: with any, a state
+        #: arena rides beside the pool) and its latent ones (``mixer="mla"``:
+        #: with any, the pool holds one latent row a position); what carries
+        #: (k, v) pairs only is refused HERE, each with its one message
         self._state_layers = len(model.state_layers)
-        if self._state_layers:
-            for given, what in (
-                    (spec, "spec: a rejected draft would need the recurrent "
-                           "state rolled back, and only the K/V pointer "
-                           "rewinds"),
-                    (migrate, "migrate: the handoff carries (k, v) chains, "
-                              "not a recurrent layer's state"),
-                    (kvtier, "kvtier: demotion, promotion and hibernation "
-                             "carry (k, v) blocks, not a recurrent layer's "
-                             "state"),
-                    (decode_attn == "paged_kernel" or None,
-                     "decode_attn='paged_kernel': the Pallas block-table "
-                     "kernel reads one K/V head a query head, and this "
-                     "model's attention layers share theirs")):
-                if given is not None:
-                    raise ValueError(
-                        f"a model with recurrent layers cannot serve with "
-                        f"{what} (ROADMAP M6)")
-            if placement is not None and placement.tp > 1:
-                raise ValueError(
-                    "a model with recurrent layers cannot serve with "
-                    "tensor-parallel placement: no rule places a recurrent "
-                    "layer's heads and state (ROADMAP M6)")
+        self._latent_layers = len(model.latent_layers)
+        refuse_unsupported(model, spec=spec, migrate=migrate, kvtier=kvtier,
+                           decode_attn=decode_attn, kv_quant=kv_quant,
+                           placement=placement)
+        if self._latent_layers and model.kv_layers:
+            raise ValueError(
+                "a plan that mixes 'attention' and 'mla' layers cannot be "
+                "served: one pool holds one kind of row")
         self.name = name
         self.placement = placement
         self._params = model.params
@@ -1029,15 +1107,21 @@ class LMServingEngine:
         # the pool's geometry is the K/V heads': a model whose query heads
         # share them in groups stores (and moves) the shared heads only,
         # and only its attention layers have an arena layer
-        L, H, D = len(model.kv_layers), model.n_kv_head, model.head_dim
+        # -- or the latent rows': a model none of whose layers keeps a
+        # (k, v) pair gets no (k, v) arena at all
+        if self._latent_layers:
+            L, H, D = self._latent_layers, 1, model.mla.row
+        else:
+            L, H, D = len(model.kv_layers), model.n_kv_head, model.head_dim
         if not L:
             raise ValueError("the paged engine needs at least one attention "
-                             "layer in the model's plan")
+                             "or latent attention layer in the model's plan")
         dt = self._params["embed"].dtype
         self.pool = BlockPool(n_layers=L, n_heads=H, head_dim=D,
                               block_len=self.block_len,
                               num_blocks=num_blocks, dtype=dt,
-                              kv_quant=kv_quant)
+                              kv_quant=kv_quant,
+                              latent=bool(self._latent_layers))
         self.kv_quant = self.pool.kv_quant
         _kvq = self.kv_quant is not None
         if placement is not None:
@@ -1080,9 +1164,12 @@ class LMServingEngine:
             # instead of dropping it
             self.radix.on_evict = self._demote_block
         self._cache_dtype = dt
-        # prefix-chain pad buckets (powers of two up to the table width)
-        self._prefix_block_buckets = prefill_bucket_lengths(
-            self.table_width, min_bucket=1)
+        # prefix-chain pad buckets (powers of two up to the table width; a
+        # latent layer walks its prefix as far as it reaches, whatever the
+        # padding behind it: one bucket, one suffix executable a chunk bucket)
+        self._prefix_block_buckets = (
+            (self.table_width,) if self._latent_layers else
+            prefill_bucket_lengths(self.table_width, min_bucket=1))
 
         # -- the device programs ---------------------------------------- #
         _ptag = placement.tag if placement is not None else ""
@@ -1131,7 +1218,7 @@ class LMServingEngine:
                     "Pallas paged kernel reads raw blocks)")
             decode_attn = "gather"
         elif decode_attn == "auto" and (
-                model.n_kv_head != model.n_head
+                model.n_kv_head != model.n_head or self._latent_layers
                 or any(s.window for _, period in model.plan for s in period)):
             # the Pallas block-table kernel knows neither grouped heads
             # nor windows yet (ROADMAP M3)
@@ -1190,9 +1277,16 @@ class LMServingEngine:
         #: hands their two integers out beside the ids
         self._moe_layers = model.moe_layers
 
-        self._insert_jit = jax.jit(
-            _insert_blocks,
-            donate_argnums=(0, 1, 5, 6) if _kvq else (0, 1))
+        #: a round's live blocks are attended this many at a time
+        self._list_chunk = list_chunk(
+            self.slots, model.n_head != model.n_kv_head,
+            bool(self._latent_layers))
+        if self._latent_layers:
+            self._insert_jit = jax.jit(_insert_rows, donate_argnums=(0,))
+        else:
+            self._insert_jit = jax.jit(
+                _insert_blocks,
+                donate_argnums=(0, 1, 5, 6) if _kvq else (0, 1))
         self._insert_execs: dict = {}
 
         # -- speculation (draft-verify) --------------------------------- #
@@ -1305,6 +1399,8 @@ class LMServingEngine:
         self.metrics.spec = self.spec_metrics
         if self.state is not None:
             self.metrics.state_bytes = self.state.arena_bytes
+        if self.pool.latent:
+            self.metrics.latent_row_bytes = self.pool.row_bytes
         self._publish_kv_metrics(get_registry())
 
         # memory-ledger attribution: KV arenas (+ int8 scale arenas),
@@ -1699,12 +1795,14 @@ class LMServingEngine:
             sh = (dict(sharding=self.placement.replicated())
                   if self.placement is not None else {})
             # fresh chunk rows arrive in the model's compute dtype even
-            # when the pool stores int8 (_insert_blocks quantizes them)
-            new = sds((L, 1, H, bucket, D), self._cache_dtype, **sh)
-            kv = self.pool.arenas
+            # when the pool stores int8 (_insert_blocks quantizes them); a
+            # latent pool's have no head axis
+            new = sds((L, 1, bucket, D) if self.pool.latent
+                      else (L, 1, H, bucket, D), self._cache_dtype, **sh)
+            kv, n = self.pool.arenas, self.pool.data_arenas
             exe = self._insert_jit.lower(
-                *kv[:2], new, new, sds((nb,), np.int32, **sh),
-                *kv[2:]).compile()
+                *kv[:n], *[new] * n, sds((nb,), np.int32, **sh),
+                *kv[n:]).compile()
             self._insert_execs[bucket] = exe
             self._ledger_exec("insert", f"bucket={bucket}", exe)
         return exe
@@ -1848,11 +1946,7 @@ class LMServingEngine:
         emitted.  Adoptions outrank queued submissions (they are
         further along: TTFT is already paid) and defer under pool
         pressure exactly like admissions."""
-        if self.state is not None:
-            raise ValueError(
-                "a model with recurrent layers cannot adopt a migrated "
-                "request: the handoff carries (k, v) chains, not a recurrent "
-                "layer's state (ROADMAP M6)")
+        refuse_unsupported(self.model, adopt=True)
         # the deadline rides the handoff on the stream itself; rebind
         # the cancel nudge so a disconnect now wakes THIS worker
         handoff.stream._wake_cb = self._lc_wake
@@ -2949,7 +3043,7 @@ class LMServingEngine:
                 self._ph_args.update(carried_state=p > 0,
                                      state_layers=self._state_layers)
         if p == 0:
-            logits, k, v, *rest = self.prefill_cache(
+            logits, *rest = self.prefill_cache(
                 self._params, self._buffers,
                 {"ids": ids, "len": np.int32(ts)})
         else:
@@ -2960,8 +3054,11 @@ class LMServingEngine:
             x = {"ids": ids, "len": np.int32(ts),
                  "prefix_len": np.int32(p), "blocks": pblocks,
                  "kv": self.pool.arenas, **self._carried_operands(pf.slot)}
-            logits, k, v, *rest = self.prefix_prefill_cache(
+            logits, *rest = self.prefix_prefill_cache(
                 self._params, self._buffers, x)
+        # what the chunk caches: (k, v), or a latent pool's rows
+        n = self.pool.data_arenas
+        new, rest = rest[:n], rest[n:]
         if self._moe_layers:    # summed over a prompt's chunks
             moe, *rest = rest
             pf.moe = moe if pf.moe is None else pf.moe + moe
@@ -2970,7 +3067,7 @@ class LMServingEngine:
             self._ph_args = {"slot": pf.slot, "bucket": bucket}
         kv = self.pool.arenas
         self.pool.arenas = self._insert_compiled(bucket)(
-            *kv[:2], k, v, ids_w, *kv[2:])
+            *kv[:n], *new, ids_w, *kv[n:])
         if self.state is not None:
             # the slot's rows at this chunk's true end: what the next chunk
             # starts from, and after the last what the slot decodes from
@@ -3100,6 +3197,7 @@ class LMServingEngine:
             held = st.table[:st.pos_next // self.block_len + 1]
             chains.append((i, held))
             rnd.n_live += len(held)
+            rnd.n_positions += st.pos_next + 1
             if _tracer.enabled and _tracer.sampled(st.rid):
                 rnd.sampled.append((st.rid, i, st.step_idx))
             emits = not st.replay
@@ -3121,8 +3219,7 @@ class LMServingEngine:
             self._rd_active = len(rows)
         live[:] = live_list(chains, live.shape[1], self.slots)
         # what the step gathers: the chunks that hold a listed block
-        chunk = list_chunk(self.slots,
-                           self.model.n_head != self.model.n_kv_head)
+        chunk = self._list_chunk
         rnd.gathered = -(-rnd.n_live // chunk) * chunk
         ids, *out = self._decode_compiled()(
             self._params, operands, self._ids, *self._arenas())
@@ -3154,6 +3251,8 @@ class LMServingEngine:
         now = self._stamp(P_EMIT)
         n_rows = self._rd_active = len(rnd.rows)
         state_rows = n_rows * self._state_layers
+        # live positions the round's latent layers read, the new rows too
+        latent_rows = rnd.n_positions * self._latent_layers
         # a round enqueued behind another was the device's from the
         # instant its predecessor's ids were out: consecutive spans abut
         t0 = max(rnd.t0, self._step_end)
@@ -3168,6 +3267,10 @@ class LMServingEngine:
                                  moe_experts_hit=int(moe[1]))
             if self.state is not None:
                 step_args["state_rows"] = state_rows
+            if moe is not None and len(moe) > 2:
+                step_args["moe_groups_hit"] = int(moe[2])
+            if self._latent_layers:
+                step_args["latent_positions"] = latent_rows
             _tracer.add_complete("lm/decode_step", t0, now - t0, cat="serve",
                                  args=step_args)
             # per-request view of the shared batched step: one
@@ -3198,7 +3301,8 @@ class LMServingEngine:
                                  prefill_interrupted=self._prefill_since_step,
                                  live_blocks=rnd.n_live,
                                  gathered_blocks=rnd.gathered,
-                                 state_rows=state_rows, ahead=rnd.ahead,
+                                 state_rows=state_rows,
+                                 latent_rows=latent_rows, ahead=rnd.ahead,
                                  discarded=discarded)
         self._prefill_since_step = False
         if freed:
@@ -3713,6 +3817,19 @@ class LMServingEngine:
                                if self.radix is not None else None)
         return out
 
+    def chain_of(self, stream: LMStream) -> Optional[List[int]]:
+        """The pool blocks a SEATED stream's cached rows lie in, in chain
+        order (position ``p`` is row ``p % block_len`` of block
+        ``chain[p // block_len]``); None when it holds no decode slot.  What
+        a check reads the arenas by (``BlockPool.rows_at``) once the engine
+        has closed: an ended stream's rows stay where they lay until a later
+        stream's are written over them."""
+        with self._cv:
+            for st in self._slots:
+                if st is not None and st.stream is stream:
+                    return list(st.blocks)
+        return None
+
     def kvcache_headroom(self) -> int:
         """How many additional WORST-CASE requests (a full
         ``cache_len`` context each) the pool can hold right now.  The
@@ -3753,6 +3870,14 @@ class LMServingEngine:
             "prefill_cache": self.prefill_cache.stats(),
             "prefix_prefill_cache": self.prefix_prefill_cache.stats(),
             "kvcache": self.kvcache_stats(),
+            # what a cached row is for the model at hand, and the pool's books
+            "kv_pool": self.pool.stats(),
+            "latent_cache": ({"layers": self._latent_layers,
+                              "row_bytes": self.pool.row_bytes,
+                              "blocks_used": self.pool.used_count,
+                              "blocks_free": self.pool.free_count,
+                              **metrics["latent"]}
+                             if self.pool.latent else None),
             "prefix_cache": self._prefix_cache_note,
             "state": ({"layers": self._state_layers,
                        "row_bytes": self.state.row_bytes, **metrics["state"]}

@@ -568,6 +568,128 @@ def test_solar2_cell_compiles_for_v5e_and_keeps_the_state_in_place(
     assert total < 14.5e9, total
 
 
+@pytest.mark.parametrize("program", ["decode", "prefill2048", "suffix2048"])
+def test_ling3_cell_compiles_for_v5e_and_keeps_latent_and_state_in_place(
+        sds, monkeypatch, program, capsys):
+    """``ling3-cell``, beside ``solar2-cell``: the programs of the benchmark's
+    ``ling3.longdecode`` cell at its own geometry (``benchmarks/configs/
+    ling-3.0-flash-vl.json``: two dense layers and one period of five KDA
+    layers and an MLA layer, 64 held experts, 32 slots, table width 2,560,
+    ONE latent arena layer of 81,921 blocks of 16 rows of 640 lanes and NO
+    (k, v) arena, a state arena of 7 x 32 rows of 32 x 128 x 128 float32),
+    compiled for the described v5e: the decode step with the latent AND the
+    state arenas donated (aliased in place, no arena-shaped copy), the
+    2,048-token prefill (the expanded latent layer on the XLA path, the
+    chunked scan on the others: rows, state and tail handed out) and the
+    suffix prefill that reads its prefix from the latent arena and starts
+    from a slot's rows.  Prints what the configuration's
+    ``memory_arithmetic`` quotes; memory before any run."""
+    import json
+    from benchmarks.drivers import serve_ling3 as D
+    from bigdl_tpu.models.transformer import generate as G
+    from bigdl_tpu.serving.kvcache import state as kvstate
+    from bigdl_tpu.serving.kvcache.blocks import BlockPool
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "ling-3.0-flash-vl.json")) as f:
+        c = json.load(f)
+    eng = c["engine"]
+    model = D.build_model(c)
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda: D.program_params(model, 0, c, "bfloat16")))
+    weight_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                       for a in jax.tree_util.tree_leaves(params))
+    # ISSUE 35's table: a KDA mixer 63,049,888, the MLA mixer 31,965,696, a
+    # dense MLP 47,185,920, a routed layer's 64 experts, shared expert and
+    # router 384,696,320, two norms a layer, embedding and head, the final norm
+    values = (7 * 63_049_888 + 31_965_696 + 2 * 47_185_920 + 6 * 384_696_320
+              + 8 * 2 * 2560 + 2 * 19_648 * 2560 + 2560)
+    assert values == 2_976_505_952
+    # (bf16 but for A_log and dt_bias, float32: 2 B more each; the router's
+    # selection bias, 512 float32 a routed layer, is not in the table)
+    assert weight_bytes == 2 * values + 2 * 7 * (32 + 4096) + 6 * 4 * 512, weight_bytes
+    assert model.kv_layers == () and model.latent_layers == (5,)
+    assert model.state_layers == (0, 1, 2, 3, 4, 6, 7)
+    slots, width = eng["slots"], eng["cache_len"] // eng["block_len"]
+    i32 = lambda *shape: sds(shape, jnp.int32)              # noqa: E731
+
+    def arenas_of():
+        pool = BlockPool(n_layers=1, n_heads=1, head_dim=model.mla.row,
+                         block_len=eng["block_len"], latent=True,
+                         num_blocks=eng["num_blocks"], dtype=jnp.bfloat16)
+        arena = kvstate.StateArena(
+            n_layers=len(model.state_layers), slots=slots,
+            state_shape=model.state_shapes[0], tail_shape=model.state_shapes[1],
+            tail_dtype=jnp.bfloat16)
+        return [*pool.arenas, arena.state, arena.tail]
+
+    arenas = [sds(a.shape, a.dtype) for a in jax.eval_shape(arenas_of)]
+    assert len(arenas) == 3                                 # no (k, v) pair
+    assert arenas[0].shape == (1, 81921, 16, 640)           # ONE layer, 576 -> 640
+    assert arenas[1].shape == (7, 32, 32, 128, 128) and arenas[1].dtype == jnp.float32
+    assert arenas[2].shape == (7, 32, 3, 12288)
+    arena_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in arenas)
+    if program == "decode":
+        def step(p, tok, pos, live, temperature, keys, prev_ids, *kv):
+            return G._decode_pick_paged(model, p, tok, pos, live, temperature,
+                                        keys, prev_ids, *kv,
+                                        table_width=width, attn_impl="gather")
+
+        compiled, text = _compile(
+            step, params, i32(slots), i32(slots), i32(3, slots * width),
+            sds((slots,), jnp.float32), sds((slots, 2), jnp.uint32),
+            i32(slots), *arenas, donate_argnums=(7, 8, 9))
+        ids, counts = compiled.out_info[:2]
+        assert ids.shape == (slots,) and ids.dtype == jnp.int32
+        assert counts.shape == (3,) and len(compiled.out_info) == 5
+        mem = compiled.memory_analysis()
+        # latent rows and state alike are updated where they lie
+        assert mem.alias_size_in_bytes >= 0.99 * arena_bytes
+        for dims in ("bf16[1,81921,16,640]", "f32[7,32,32,128,128]"):
+            moved = [ln.strip()[:160] for ln in text.splitlines()
+                     if dims in ln and re.search(
+                         r" copy(-start)?\(|AllocateBuffer", ln)]
+            assert not moved, moved
+        assert mem.temp_size_in_bytes < 3.0e9, mem.temp_size_in_bytes
+    elif program == "prefill2048":
+        def step(p, ids, n):
+            return G._prefill_parts(model, p, ids, n - 1)
+
+        compiled, text = _compile(step, params, i32(1, 2048), i32())
+        logits, rows, counts, state, tail = compiled.out_info
+        assert logits.shape == (1, 19648) and counts.shape == (3,)
+        assert rows.shape == (1, 1, 2048, 576)              # rows, not (k, v)
+        assert state.shape == (7, 1, 32, 128, 128) and state.dtype == jnp.float32
+        assert tail.shape == (7, 1, 3, 12288) and tail.dtype == jnp.bfloat16
+        mem = compiled.memory_analysis()
+        arena_bytes = 0
+    else:
+        def step(p, ids, n, prefix_len, blocks, slot, rows, state, tail):
+            return G._prefill_suffix_parts(
+                model, p, ids, n - 1, prefix_len, blocks, rows,
+                carried=kvstate.read_slot(state, tail, slot))
+
+        compiled, text = _compile(step, params, i32(1, 2048), i32(), i32(),
+                                  i32(width), i32(), *arenas)
+        assert compiled.out_info[1].shape == (1, 1, 2048, 576)
+        assert compiled.out_info[3].shape == (7, 1, 32, 128, 128)
+        mem = compiled.memory_analysis()
+    assert "ragged" in text.lower() or "custom-call" in text
+    total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    with capsys.disabled():
+        print(f"\nling3-cell {program}: weights {weight_bytes / 1e9:.3f} GB, "
+              f"arenas {arena_bytes / 1e9:.3f} GB, "
+              f"arguments {mem.argument_size_in_bytes / 1e9:.3f} GB, "
+              f"temporaries {mem.temp_size_in_bytes / 1e9:.3f} GB, "
+              f"outputs {mem.output_size_in_bytes / 1e9:.3f} GB, "
+              f"aliased {mem.alias_size_in_bytes / 1e9:.3f} GB, "
+              f"total {total / 1e9:.3f} GB")
+    assert total < 14.5e9, total
+
+
 def test_lm_flash_remat_train_step_compiles_for_v5e(sds, monkeypatch):
     """A TransformerLM training step with ``attention_impl="flash"``, RoPE
     and remat: the flash forward and both backward kernels inside the

@@ -37,7 +37,7 @@ def test_a_chunk_is_four_blocks_a_slot_and_sixteen_for_grouped_matmuls():
 def chunks_of_16(monkeypatch):
     """Four slots: a chunk of 16 entries for either kind of attention, so that
     lists of 24 and 32 entries take two chunks."""
-    monkeypatch.setattr(G, "list_chunk", lambda slots, grouped=False: 16)
+    monkeypatch.setattr(G, "list_chunk", lambda *a, **k: 16)
 
 
 def test_a_list_names_block_owner_and_place_and_pads_with_nobodys_scratch():
