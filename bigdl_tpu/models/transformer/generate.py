@@ -773,8 +773,10 @@ def _decode_step_paged(model, params, token, pos, live, *arenas,
     recurrent layer reads and writes its slots' rows (``kda/step``) and an
     idle slot's row stays as it was.  A LATENT pool's arenas are its one
     ``(rows,)``: a latent layer writes the new row and attends ABSORBED
-    (``TransformerLM.mla_absorb`` / ``mla_values`` around
-    :func:`_paged_attention`, ``mla/attend``).
+    (``TransformerLM.mla_absorb`` / ``mla_values`` around ``mla/attend``:
+    :func:`_paged_attention`'s walk, the CPU path and the oracle, or under
+    ``attn_impl="paged_kernel"`` the Pallas kernel that reads the listed
+    blocks where they lie, ``ops.latent_attention``).
 
     -> (logits (S, V) float32, [the routed layers' integers, when the
     model has any], *arenas).  The serving engine's decode program is
@@ -842,9 +844,19 @@ def _decode_step_paged(model, params, token, pos, live, *arenas,
         q, row, gate = model.mla_inputs(spec, bp, h, positions)  # (S, H, 1, ..)
         q = model.mla_absorb(bp, q)
         with jax.named_scope("mla/attend"):
-            u, arenas = _paged_attention(q, row[:, None], None, arenas, layer,
-                                         blk, off, live, masks[None],
-                                         score_dim=m.score_dim)
+            if attn_impl == "paged_kernel":
+                # the listed blocks read where they lie, the new row first
+                from bigdl_tpu.ops import latent_decode_attention
+                arenas = (write_rows(arenas[0], layer, blk, off,
+                                     row[:, :, None]),)
+                u = latent_decode_attention(
+                    q, arenas[0], tables, jnp.where(active, pos + 1, 0),
+                    score_dim=m.score_dim, layer=layer, value_lanes=m.kv_rank)
+            else:
+                u, arenas = _paged_attention(q, row[:, None], None, arenas,
+                                             layer, blk, off, live,
+                                             masks[None],
+                                             score_dim=m.score_dim)
         o = model.mla_values(bp, u[..., :m.kv_rank])
         h, counts = _finish_block(model, spec, bp, h, o.astype(h.dtype), gate,
                                   token_mask=active[:, None])

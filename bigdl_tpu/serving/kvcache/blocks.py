@@ -74,7 +74,11 @@ that are zero outside their K/V head's lanes (:func:`head_columns`), so
 ``rows @ columns`` is every position's score under every head; the
 weighted V rows of a slot come back as ``(columns, W)`` and
 :func:`head_lanes` keeps each column's own head.  Only these functions
-know where a head lies in a row.
+know where a head lies in a row.  A LATENT pool's walk (every head's
+absorbed query against the one row a position, the grouped case with one
+K/V head) is the CPU path and the oracle: on a TPU the decode step reads
+the listed blocks where they lie, with no chunk gathered
+(``ops.latent_attention``, PERF.md, PR 36).
 
 Block 0 is reserved as a **scratch** block: padded table entries and
 padded scatter targets point at it, so fixed-shape gathers/scatters
@@ -202,7 +206,9 @@ def list_chunk(slots: int, grouped: bool = False, latent: bool = False) -> int:
     sixteen where a chunk costs two ``grouped`` matmuls (each a custom
     call of some 0.1 ms whatever it multiplies: PERF.md, PR 29), or forty
     for a ``latent`` walk (one row a position, every head's query against
-    it).  That one is the best of a sweep of the walk alone on the chip, at
+    it).  Since PR 36 the latent walk is the CPU path and the oracle of the
+    kernel that serves on a TPU (``ops.latent_attention``, which gathers no
+    chunk); its forty are the best of a sweep of the walk alone on the chip, at
     32 slots and 51,000 live blocks (PERF.md, PR 35: 16 / 40 / 80 / 160 /
     320 blocks a slot walk in 20.5 / 14.8 / 15.7 / 22.7 / 22.0 ms); inside
     the whole decode step it gave a twentieth, not a third (160 -> 40

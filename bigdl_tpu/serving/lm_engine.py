@@ -213,8 +213,6 @@ _REFUSALS = (
      "pair, and the pool holds one latent row a position"),
     ("latent", "serve with kvtier", "the host tier's wire format is a (k, v) "
      "pair, and the pool holds one latent row a position"),
-    ("latent", "serve with decode_attn='paged_kernel'", "the Pallas "
-     "block-table kernel reads (k, v) blocks of one head width"),
     ("latent", "serve with tensor-parallel placement", "no rule places the "
      "up-projections' heads"),
     ("latent", "adopt a migrated request", "the handoff carries (k, v) "
@@ -234,8 +232,10 @@ def refuse_unsupported(model, *, spec=None, migrate=None, kvtier=None,
     asked = {"serve with spec": spec is not None,
              "serve with migrate": migrate is not None,
              "serve with kvtier": kvtier is not None,
+             # (the block-table kernel over (k, v) blocks: a latent pool's
+             # kernel is its own, ops.latent_attention)
              "serve with decode_attn='paged_kernel'":
-                 decode_attn == "paged_kernel",
+                 decode_attn == "paged_kernel" and not model.latent_layers,
              "serve with kv_quant='int8'": kv_quant == "int8",
              "serve with tensor-parallel placement":
                  placement is not None and placement.tp > 1,
@@ -926,10 +926,15 @@ class LMServingEngine:
             ``generate._paged_attention``, the XLA path; how many
             chunks follows each round from what the slots hold, never
             set), "paged_kernel" (the in-place Pallas block-table
-            kernel, ``ops.paged_attention``), or "auto" (default): the
+            kernel, ``ops.paged_attention``; for a latent pool the
+            kernel that reads the listed blocks of latent rows where
+            they lie, ``ops.latent_attention``), or "auto" (default): the
             kernel only when the autotune cache has measured it faster
-            than the gather ON THIS device kind, the gather otherwise.
-            Both produce token-identical streams.
+            than the gather ON THIS device kind, the gather otherwise;
+            for a latent pool the kernel on a TPU whenever the compiled
+            kernel can take the pool's geometry, the gather (the CPU
+            path) otherwise.  Both produce token-identical streams (a
+            latent pool's to the order of float32 sums).
         kv_quant: ``None`` (full-precision KV, the default) or
             ``"int8"``: the block pool stores int8 KV blocks with
             per-(position, head) f32 scales, dequantized inside the
@@ -1209,6 +1214,18 @@ class LMServingEngine:
         if decode_attn not in ("auto", "gather", "paged_kernel"):
             raise ValueError(f"decode_attn must be 'auto', 'gather' or "
                              f"'paged_kernel', got {decode_attn!r}")
+
+        def _check_kernel_shapes():
+            # raises for a pool geometry the COMPILED kernel cannot read
+            from bigdl_tpu.ops.latent_attention import (
+                check_latent_kernel_shapes)
+            from bigdl_tpu.ops.paged_attention import check_paged_kernel_shapes
+            if self._latent_layers:
+                check_latent_kernel_shapes(self.block_len,
+                                           self.pool.shape[-1], dt)
+            else:
+                check_paged_kernel_shapes(self.block_len, dt)
+
         if _kvq:
             # the Pallas paged kernel reads raw blocks — a quantized
             # pool's in-gather dequant needs the gather path
@@ -1217,8 +1234,19 @@ class LMServingEngine:
                     "kv_quant='int8' requires decode_attn='gather' (the "
                     "Pallas paged kernel reads raw blocks)")
             decode_attn = "gather"
+        elif decode_attn == "auto" and self._latent_layers:
+            # a latent pool's kernel reads the listed blocks where they lie
+            # (ops.latent_attention): on the chip, where the compiled kernel
+            # can take the pool's geometry; the walk is the CPU path
+            decode_attn = "gather"
+            if jax.default_backend() == "tpu":
+                try:
+                    _check_kernel_shapes()
+                    decode_attn = "paged_kernel"
+                except ValueError:
+                    pass
         elif decode_attn == "auto" and (
-                model.n_kv_head != model.n_head or self._latent_layers
+                model.n_kv_head != model.n_head
                 or any(s.window for _, period in model.plan for s in period)):
             # the Pallas block-table kernel knows neither grouped heads
             # nor windows yet (ROADMAP M3)
@@ -1235,10 +1263,9 @@ class LMServingEngine:
         if decode_attn == "paged_kernel":
             # a pool geometry the COMPILED kernel cannot read is an error
             # here, not a silent gather (the interpreter takes any)
-            from bigdl_tpu.ops.paged_attention import (
-                _use_interpret, check_paged_kernel_shapes)
+            from bigdl_tpu.ops.paged_attention import _use_interpret
             if not _use_interpret():
-                check_paged_kernel_shapes(self.block_len, dt)
+                _check_kernel_shapes()
         self.decode_attn = decode_attn
 
         # every step program takes the pool's arenas last, (k, v) or

@@ -568,7 +568,8 @@ def test_solar2_cell_compiles_for_v5e_and_keeps_the_state_in_place(
     assert total < 14.5e9, total
 
 
-@pytest.mark.parametrize("program", ["decode", "prefill2048", "suffix2048"])
+@pytest.mark.parametrize("program", ["decode", "decode-gather", "prefill2048",
+                                     "suffix2048"])
 def test_ling3_cell_compiles_for_v5e_and_keeps_latent_and_state_in_place(
         sds, monkeypatch, program, capsys):
     """``ling3-cell``, beside ``solar2-cell``: the programs of the benchmark's
@@ -578,7 +579,11 @@ def test_ling3_cell_compiles_for_v5e_and_keeps_latent_and_state_in_place(
     ONE latent arena layer of 81,921 blocks of 16 rows of 640 lanes and NO
     (k, v) arena, a state arena of 7 x 32 rows of 32 x 128 x 128 float32),
     compiled for the described v5e: the decode step with the latent AND the
-    state arenas donated (aliased in place, no arena-shaped copy), the
+    state arenas donated (aliased in place, no arena-shaped copy) -- as
+    ``decode_attn="auto"`` resolves there, through the Pallas kernel that reads
+    the listed blocks where they lie (``ops.latent_attention``: a custom call,
+    its VMEM inside the limit it asks for), and, ``decode-gather``, through the
+    XLA walk that stays the CPU path -- the
     2,048-token prefill (the expanded latent layer on the XLA path, the
     chunked scan on the others: rows, state and tail handed out) and the
     suffix prefill that reads its prefix from the latent arena and starts
@@ -631,11 +636,16 @@ def test_ling3_cell_compiles_for_v5e_and_keeps_latent_and_state_in_place(
     assert arenas[1].shape == (7, 32, 32, 128, 128) and arenas[1].dtype == jnp.float32
     assert arenas[2].shape == (7, 32, 3, 12288)
     arena_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in arenas)
-    if program == "decode":
+    if program.startswith("decode"):
+        impl = "gather" if program == "decode-gather" else "paged_kernel"
+        # (the kernel asks jax.default_backend(), the CPU here: steered from
+        # the test, as the paged programs' above)
+        monkeypatch.setattr(pa, "_use_interpret", lambda: False)
+
         def step(p, tok, pos, live, temperature, keys, prev_ids, *kv):
             return G._decode_pick_paged(model, p, tok, pos, live, temperature,
                                         keys, prev_ids, *kv,
-                                        table_width=width, attn_impl="gather")
+                                        table_width=width, attn_impl=impl)
 
         compiled, text = _compile(
             step, params, i32(slots), i32(slots), i32(3, slots * width),
@@ -653,6 +663,18 @@ def test_ling3_cell_compiles_for_v5e_and_keeps_latent_and_state_in_place(
                          r" copy(-start)?\(|AllocateBuffer", ln)]
             assert not moved, moved
         assert mem.temp_size_in_bytes < 3.0e9, mem.temp_size_in_bytes
+        kernel = [ln for ln in text.splitlines()
+                  if 'custom_call_target="tpu_custom_call"' in ln
+                  and "latent_decode_attention" in ln]
+        assert bool(kernel) == (impl == "paged_kernel")
+        if kernel:
+            # the walk is gone from the step; the compiler took the kernel
+            # inside the VMEM it asks for (two fetch buffers of 128 blocks, a
+            # step's scores and weights): a quarter of a core's 128 MiB at most
+            assert "mla/attend/ragged" not in text
+            asked = [int(n) for ln in kernel for n in re.findall(
+                r'"scoped_memory_configs":\[\{[^\]]*"size":"(\d+)"', ln)]
+            assert asked and max(asked) < 32 << 20, asked
     elif program == "prefill2048":
         def step(p, ids, n):
             return G._prefill_parts(model, p, ids, n - 1)

@@ -540,7 +540,6 @@ def _recurrent():
     ("latent", {"spec": 2}, "spec"),
     ("latent", {"migrate": lambda *a: None}, "migrate"),
     ("latent", {"kvtier": object()}, "kvtier"),
-    ("latent", {"decode_attn": "paged_kernel"}, "paged_kernel"),
     ("both", {"kv_quant": "int8"}, "kv_quant='int8'"),
     ("both", {"spec": 2}, "spec"),
 ])
@@ -566,7 +565,7 @@ def test_refusals_at_construction(kind, kw, says):
 def test_every_refusal_is_a_row_of_the_one_table():
     from bigdl_tpu.serving import lm_engine
     rows = lm_engine._REFUSALS
-    assert len(rows) == len({r[:2] for r in rows}) == 13
+    assert len(rows) == len({r[:2] for r in rows}) == 12
     assert {r[0] for r in rows} == set(lm_engine._KIND_NAMES)
     lm_engine.refuse_unsupported(_latent_alone())           # nothing given: silent
 
