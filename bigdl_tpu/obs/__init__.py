@@ -4,7 +4,7 @@ Six pieces, one spine:
 
 - :mod:`~bigdl_tpu.obs.tracer` — thread-safe span API (context manager
   + decorator) over a ring buffer, exported as Chrome trace-event JSON
-  (Perfetto-loadable) or a structured JSONL log.  Request-scoped:
+  (Perfetto-loadable); there is no other exporter.  Request-scoped:
   every serving submission is minted a ``request_id``
   (:func:`mint_request_id`), propagated through batch assembly,
   prefill, decode/verify rounds, and failover re-dispatch, and

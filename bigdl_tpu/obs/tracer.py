@@ -376,8 +376,11 @@ class Tracer:
         return doc
 
 
-#: process-wide tracer — instrumented modules bind this once at import
-_GLOBAL = Tracer()
+#: process-wide tracer — instrumented modules bind this once at import.
+#: Its ring holds a whole traced run of the benchmark's busiest cell
+#: (`gpt2xl.backlog`: 100,000 events in 56 s with every request sampled;
+#: at 65,536 the window's first seconds were pushed out)
+_GLOBAL = Tracer(capacity=131072)
 
 
 def get_tracer() -> Tracer:

@@ -101,7 +101,10 @@ phase stamps (``ROUND_PHASES``; one ``perf_counter()`` read a boundary,
 split, one WARNING for a slow round — and, with the tracer on, the
 ``lm/round`` / ``lm/admit`` envelopes, one leaf span a phase and a
 profiler annotation of the same name; ``shared_watchdog("lm_round")``
-dumps every thread's stack while a round hangs.
+dumps every thread's stack while a round hangs.  The same stamps keep the
+device's account (``_account``): a wait on the newest enqueued output proves it
+empty, an enqueue ends that, and the phases between (bar ``lm/idle``) are
+STARVED: ``starved_s`` / ``starved_phase_s``; tracer on, one ``lm/starved`` each.
 """
 from __future__ import annotations
 
@@ -145,6 +148,15 @@ ROUND_PHASES = ("idle", "sched", "admit_host", "prefill", "insert",
  P_DISPATCH, P_WAIT, P_EMIT, P_TREE_COMMIT,
  P_STATE_INSERT) = range(len(ROUND_PHASES))
 _PHASE_SPANS = tuple("lm/" + p for p in ROUND_PHASES)
+#: the phases that end with a program enqueued: the device has work from
+#: the stamp that closes one on
+_ENQUEUES = frozenset((P_PREFILL, P_INSERT, P_STATE_INSERT, P_DRAFT,
+                       P_DISPATCH, P_TREE_COMMIT))
+#: what the worker knows of the device (``LMServingEngine._dev``): something
+#: it enqueued may still be there; a wait on the newest enqueued output has
+#: proven it empty; or a wait has proven it empty up to an output with
+#: more enqueued behind it, whose ``is_ready()`` the next stamps poll
+_DEV_BUSY, _DEV_EMPTY, _DEV_POLL = 0, 1, 2
 #: a round is logged as slow when it took this long AND this many
 #: running medians of the plain (decode-only) rounds
 SLOW_ROUND_S = 1.0
@@ -460,9 +472,10 @@ class LMMetrics:
 
     The round record (``record_round`` / ``rounds_snapshot``) is what the
     engine's phase stamps feed whether or not the tracer is on: rounds
-    counted, seconds summed per phase, the last plain rounds for a
-    running median, and the longest round with its own phase split.  It
-    costs a fixed handful of additions a round."""
+    counted, seconds summed per phase and, of them, the seconds that
+    passed with the device proven empty (``starved_phase_s``), the last
+    plain rounds for a running median, and the longest round with its own
+    phase split.  It costs a fixed handful of additions a round."""
 
     def __init__(self, slots: int, throughput_window_s: float = 60.0):
         self._lock = threading.Lock()
@@ -643,14 +656,18 @@ class LMMetrics:
             self.plain_rounds = 0
             self.slow_rounds = 0
             self.phase_s = [0.0] * len(ROUND_PHASES)
+            self.starved_phase_s = [0.0] * len(ROUND_PHASES)
             self._plain: deque = deque(maxlen=64)
             self._longest = None
 
     def record_round(self, t0: float, dur_s: float, split: list,
                      index: int, active: int, admitted: int,
-                     plain: bool) -> Optional[float]:
+                     plain: bool, starved: Optional[list] = None
+                     ) -> Optional[float]:
         """Fold one finished round in: ``split`` is its seconds per
-        phase (indexed as ``ROUND_PHASES``; the record keeps the list).
+        phase (indexed as ``ROUND_PHASES``; the record keeps the list),
+        ``starved`` the part of them that passed with the device proven
+        empty (None: none did).
         Returns the running median of the plain rounds when this round
         is a slow one (``SLOW_ROUND_S`` and ``SLOW_ROUND_MEDIANS``), for
         the engine to log, else None."""
@@ -658,8 +675,12 @@ class LMMetrics:
         with self._lock:
             self.rounds += 1
             self.phase_s = list(map(operator.add, self.phase_s, split))
+            if starved is not None:
+                self.starved_phase_s = list(map(
+                    operator.add, self.starved_phase_s, starved))
             if self._longest is None or dur_s > self._longest[1]:
-                self._longest = (t0, dur_s, split, index, active, admitted)
+                self._longest = (t0, dur_s, split, index, active, admitted,
+                                 starved)
             if dur_s >= SLOW_ROUND_S and self._plain:
                 median = statistics.median(self._plain)
                 if dur_s >= SLOW_ROUND_MEDIANS * median:
@@ -680,13 +701,17 @@ class LMMetrics:
                    "median_plain_s": (statistics.median(self._plain)
                                       if self._plain else None),
                    "phase_s": dict(zip(ROUND_PHASES, self.phase_s)),
+                   "starved_s": sum(self.starved_phase_s),
+                   "starved_phase_s": dict(zip(ROUND_PHASES,
+                                               self.starved_phase_s)),
                    "longest": None}
             longest = self._longest
         if longest is not None:
-            t0, dur_s, split, index, active, admitted = longest
+            t0, dur_s, split, index, active, admitted, starved = longest
             in_round = dict(zip(ROUND_PHASES[1:], split[1:]))
             out["longest"] = {
                 "seconds": dur_s, "round": index,
+                "starved_s": sum(starved) if starved else 0.0,
                 "at_s": t0 - self.started_at,
                 "at_unix": self._started_unix + (t0 - self.started_at),
                 "active": active, "admitted": admitted,
@@ -1543,6 +1568,13 @@ class LMServingEngine:
         self._adm_rid = None                # sampled request being admitted
         self._adm_note = None               # lm/admit args from its callees
         self._rd = [0.0] * len(ROUND_PHASES)    # this round's split
+        # the device's account: nothing is enqueued yet, so the device is
+        # empty; what of a round's phases passes while it is (lm/idle
+        # aside) is the round's starved split, None while nothing did
+        self._dev = _DEV_EMPTY
+        self._sv = None
+        self._sv_t0 = self._ph_t0               # since when proven empty
+        self._sv_after = "start"                # ... and by what
         self._rd_t0 = self._ph_t0
         self._rd_index = 0
         self._rd_active = 0
@@ -2052,9 +2084,65 @@ class LMServingEngine:
         self._rd[self._ph] += now - self._ph_t0
         if _tracer.enabled or self._ph_ann is not None:
             self._trace_phase(now, nxt)
+        if self._dev:           # (not while a round runs ahead)
+            self._account(now, nxt)
         self._ph = nxt
         self._ph_t0 = now
         return now
+
+    def _account(self, now: float, nxt: int) -> None:
+        """The device's account at a stamp, while the device is not known
+        to hold work.  Proven empty: the phase that closes was STARVED
+        unless it was ``lm/idle`` (the engine had work, the device none),
+        and an enqueue phase ends the interval -- one ``lm/starved``
+        envelope, tracer on.  Proven empty only up to an output with more
+        enqueued behind it (an admission's logits, the insert behind
+        them): one ``is_ready()`` on the newest output, no wait."""
+        ph = self._ph
+        if self._dev == _DEV_POLL:
+            # nothing is proven at a stamp that opens an enqueue phase: an
+            # envelope would hold that one leaf
+            if ph in _ENQUEUES:
+                self._dev = _DEV_BUSY
+            elif nxt not in _ENQUEUES and self._newest_output().is_ready():
+                self._proved_empty(
+                    now, "idle" if ph == P_IDLE else "first_token")
+            return
+        if ph == P_IDLE:            # a request woke an idle engine
+            self._trace_starved_tail()
+            self._sv_t0, self._sv_after = now, "idle"
+            return
+        if self._sv is None:
+            self._sv = [0.0] * len(ROUND_PHASES)
+        self._sv[ph] += now - self._ph_t0
+        if ph in _ENQUEUES:
+            self._dev = _DEV_BUSY
+            if _tracer.enabled:
+                self._trace_starved(now, ROUND_PHASES[ph])
+
+    def _proved_empty(self, now: float, after: str) -> None:
+        """In-order execution: the wait that ended at ``now`` was for the
+        newest enqueued output, so nothing is left on the device."""
+        self._dev, self._sv_t0, self._sv_after = _DEV_EMPTY, now, after
+
+    def _newest_output(self):
+        """An output of the program enqueued last: the arenas every step
+        and insert hands back (a recurrent model's state insert follows
+        its block insert)."""
+        return (self.pool if self.state is None else self.state).arenas[-1]
+
+    def _trace_starved_tail(self) -> None:
+        """What the last round left between its drain and ``lm/idle`` (or
+        the worker's end) was starved with no enqueue to end it."""
+        if (self._dev == _DEV_EMPTY and _tracer.enabled
+                and self._ph_t0 > self._sv_t0):
+            self._trace_starved(self._ph_t0, "idle")
+
+    def _trace_starved(self, end: float, until: str) -> None:
+        _tracer.add_complete(
+            "lm/starved", self._sv_t0, end - self._sv_t0, cat="serve",
+            args={"round": self._rd_index, "after": self._sv_after,
+                  "until": until, "admitted": self._adm_note is not None})
 
     def _annotate(self, phase: Optional[int]) -> None:
         """Leave the live profiler annotation and, with the tracer on,
@@ -2090,6 +2178,7 @@ class LMServingEngine:
         now = self._stamp(P_SCHED)      # the next round starts here
         t0, index, active = self._rd_t0, self._rd_index, self._rd_active
         split, self._rd = self._rd, [0.0] * len(ROUND_PHASES)
+        starved, self._sv = self._sv, None
         self._rd_t0, self._rd_index, self._rd_active = now, index + 1, 0
         if self.watchdog is not None:
             self.watchdog.step_finished()
@@ -2106,13 +2195,14 @@ class LMServingEngine:
         plain = (admitted == 0 and split[P_PREFILL] == 0.0
                  and split[P_WAIT] > 0.0)
         median = self.metrics.record_round(t0, dur, split, index, active,
-                                           admitted, plain)
+                                           admitted, plain, starved)
         if median is not None:
             log.warning(
                 "lm engine %s: slow round %d: %.3f s, %.1f x the running "
-                "median of %.4f s (active %d, admitted %d, %.3f s after "
-                "start); seconds by phase: %s", self.name, index, dur,
-                dur / median, median, active, admitted,
+                "median of %.4f s (active %d, admitted %d, starved %.3f s, "
+                "%.3f s after start); seconds by phase: %s", self.name,
+                index, dur, dur / median, median, active, admitted,
+                sum(starved) if starved else 0.0,
                 t0 - self.metrics.started_at,
                 {p: round(v, 4)
                  for p, v in zip(ROUND_PHASES[1:], split[1:]) if v})
@@ -2265,9 +2355,10 @@ class LMServingEngine:
             self._fail_all(e)
             return
         finally:
-            # leave no annotation entered and no round in flight behind
-            # a thread that is gone
+            # leave no annotation entered, no round in flight and no
+            # starved interval open behind a thread that is gone
             self._annotate(None)
+            self._trace_starved_tail()
             if self.watchdog is not None:
                 self.watchdog.step_finished()
         self._fail_all(ServingClosed("engine closed before completion"))
@@ -3128,6 +3219,9 @@ class LMServingEngine:
         # where the device wait for the prefill and the insert lands
         self._stamp(P_FIRST_TOKEN)
         logits = np.asarray(pf.logits)  # sync; (1, V) f32
+        # (the insert was enqueued behind the prefill: the device is empty
+        # once the arenas it returned are ready, which the stamps poll)
+        self._dev = _DEV_POLL
         self.metrics.record_logit_rows(logits.shape[0])
         if pf.moe is not None:
             landed = int(np.asarray(pf.moe)[0])
@@ -3276,6 +3370,8 @@ class LMServingEngine:
             moe = np.asarray(moe)
             self.metrics.record_moe(moe, self._moe_layers)
         now = self._stamp(P_EMIT)
+        if rnd.ids is self._ids:    # no successor was enqueued: a drain
+            self._proved_empty(now, "decode_wait")
         n_rows = self._rd_active = len(rnd.rows)
         state_rows = n_rows * self._state_layers
         # live positions the round's latent layers read, the new rows too
@@ -3439,6 +3535,7 @@ class LMServingEngine:
         logits = np.asarray(logits)  # sync; (S, W, V) f32
         self.metrics.record_logit_rows(logits.shape[0] * logits.shape[1])
         now = self._stamp(P_EMIT)
+        self._proved_empty(now, "decode_wait")  # a verify round is synchronous
         if _tracer.enabled:
             _tracer.add_complete(
                 "lm/verify_step", t0, now - t0, cat="serve",
@@ -3668,6 +3765,7 @@ class LMServingEngine:
         logits = np.asarray(logits)  # sync; (S, W, V) f32
         self.metrics.record_logit_rows(logits.shape[0] * logits.shape[1])
         now = self._stamp(P_EMIT)
+        self._proved_empty(now, "decode_wait")  # a verify round is synchronous
         if _tracer.enabled:
             _tracer.add_complete(
                 "lm/verify_step", t0, now - t0, cat="serve",

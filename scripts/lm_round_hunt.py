@@ -6,8 +6,11 @@ of a benchmark cell.  Not part of the benchmark: it calls
         one untraced run a seed, in this process; after each, one JSON line
         with the result line's metrics and the engine's stats()["rounds"]
         over the measured window (reset where the window opens, read where it
-        closes): seconds by phase, the running median, the longest round with
-        its own phase split -- the stall hunt; and, under "lists", what the
+        closes): seconds by phase and, of them, the seconds that passed
+        with the device proven empty and work to do ("starved_s",
+        "starved_phase_s": the engine's own account, no trace needed), the
+        running median, the longest round with its own phase split and its
+        own starved seconds -- the stall hunt; and, under "lists", what the
         decode rounds' live lists held over the window (blocks listed,
         blocks gathered, the entries of whole tables) and, beside them,
         "logit_rows_to_host": the rows of V float32 logits the window
@@ -26,13 +29,24 @@ of a benchmark cell.  Not part of the benchmark: it calls
                         the interval that bounds the device clock's offset
                         from the host's (device module inside the program's
                         lm/decode_dispatch .. lm/decode_wait annotations of
-                        the same round, on the profiler's one clock), and how
-                        much of the window the worker's leaf spans cover
+                        the same round, on the profiler's one clock), how
+                        much of the window the worker's leaf spans cover,
+                        and, under "starved_gaps", every device idle gap over
+                        0.5 ms of the xplane against the lm/starved / lm/idle
+                        envelope that covers it once that offset is applied
+                        (seconds covered by each; what no envelope covers by
+                        the leaf phase under it: the account's blind side; the
+                        gaps one by one in chiprun_out/starved_gaps.jsonl), and
+                        under "profiler_edges" what start_trace and stop_trace
+                        cost the rounds around the profiled sub-window
     ... --blocks <n>    the pool probe: the cell with a KV pool of n blocks
                         laid over its configuration (does a round grow with
                         the pool?)
     ... --stamp-cost    no cell: times the stamps of one plain round on an
-                        idle toy engine, tracer off and on
+                        idle toy engine, tracer off and on: a round that ran
+                        ahead (the device's account has nothing to do) and a
+                        round after a drain (its emit, sched and dispatch are
+                        starved, one lm/starved envelope with the tracer on)
 
 On a tree without the stamps (the parent) the rounds read null.
 """
@@ -159,19 +173,198 @@ def _clock_offset(xplane: str):
             "wait_end_after_module_end_ms": -statistics.median(lows)}
 
 
+def gap_cover(gaps, envelopes, leaves):
+    """Each device idle gap ``(a, b)`` against the engine's account, all on
+    one clock: the seconds of it under an ``lm/starved`` or ``lm/idle``
+    envelope (``envelopes``: ``(name, a, b)``), and what no envelope covers
+    by the leaf phase under it (``leaves``: ``(name, a, b)``; ``no leaf``
+    where none is).  Returns one dict a gap."""
+    from benchmarks.harness.trace_reduce import merge
+    out = []
+    for a, b in gaps:
+        row = {"at": a, "gap": b - a, "covered": {}, "uncovered": {}}
+        inside = [(n, max(x, a), min(y, b)) for n, x, y in envelopes
+                  if x < b and y > a]
+        for n, x, y in inside:
+            row["covered"][n] = row["covered"].get(n, 0.0) + (y - x)
+        # what is left of the gap, piece by piece
+        edges = [a] + [t for iv in merge((x, y) for _, x, y in inside)
+                       for t in iv] + [b]
+        for lo, hi in zip(edges[0::2], edges[1::2]):
+            left = hi - lo
+            if left <= 0:
+                continue
+            for n, x, y in leaves:
+                part = min(y, hi) - max(x, lo)
+                if part > 0:
+                    row["uncovered"][n] = row["uncovered"].get(n, 0.0) + part
+                    left -= part
+            if left > 1e-9:
+                row["uncovered"]["no leaf"] = (
+                    row["uncovered"].get("no leaf", 0.0) + left)
+        out.append(row)
+    return out
+
+
+def _overlap_s(a, b) -> float:
+    """Seconds two sorted lists of disjoint intervals have in common."""
+    total, i, j = 0.0, 0, 0
+    while i < len(a) and j < len(b):
+        total += max(0.0, min(a[i][1], b[j][1]) - max(a[i][0], b[j][0]))
+        if a[i][1] < b[j][1]:
+            i += 1
+        else:
+            j += 1
+    return total
+
+
+def _starved_gaps(xplane: str, spans, traced_window, sync_perf, offset_ms,
+                  min_gap_s: float = 0.5e-3, dump: str = None):
+    """Gap by gap: every device idle gap over ``min_gap_s`` of the traced
+    window against the ``lm/starved`` / ``lm/idle`` envelope that covers it
+    once the measured clock offset is applied; what no envelope covers is
+    listed by the leaf under it -- the account's blind side.  Returns the
+    summary and the gaps one by one."""
+    from jax.profiler import ProfileData
+    from benchmarks.harness import trace_reduce
+    from bigdl_tpu.serving.lm_engine import ROUND_PHASES
+    busy, sync_ns = [], None
+    for plane in ProfileData.from_file(xplane).planes:
+        for line in plane.lines:
+            if plane.name.startswith("/host:"):
+                for ev in line.events:
+                    if ev.name == trace_reduce.SYNC:
+                        sync_ns = ev.start_ns
+            elif (plane.name.startswith("/device:TPU:")
+                  and line.name == "XLA Ops"):
+                busy += [(ev.start_ns, ev.start_ns + ev.duration_ns)
+                         for ev in line.events]
+    if sync_ns is None or not busy:
+        return None
+    # everything in seconds from the sync marker, on the device's clock
+    on_device = lambda t: t - sync_perf + offset_ms * 1e-3      # noqa: E731
+    lo, hi = (on_device(t) for t in traced_window)
+    busy = trace_reduce.merge(((a - sync_ns) * 1e-9, (b - sync_ns) * 1e-9)
+                              for a, b in busy)
+    edges = [lo] + [t for a, b in busy if b > lo and a < hi
+                    for t in (max(a, lo), min(b, hi))] + [hi]
+    gaps = [(a, b) for a, b in zip(edges[0::2], edges[1::2]) if b > a]
+    long = [g for g in gaps if g[1] - g[0] >= min_gap_s]
+    leaf_names = {"lm/" + p for p in ROUND_PHASES} - {"lm/idle"}
+    envelopes = [(n, on_device(s), on_device(s + d)) for n, s, d in spans
+                 if n in ("lm/starved", "lm/idle") and d > 0]
+    leaves = [(n, on_device(s), on_device(s + d)) for n, s, d in spans
+              if n in leaf_names and d > 0]
+    if dump:    # what the comparison was made of, to make it again by hand
+        with open(dump, "w") as f:
+            json.dump({"offset_ms": offset_ms, "window": [lo, hi],
+                       "gaps": long, "envelopes": envelopes,
+                       "leaves": [l for l in leaves
+                                  if l[2] > lo - 0.05 and l[1] < hi + 0.05]}, f)
+    rows = gap_cover(long, envelopes, leaves)
+    total = lambda key: {                                       # noqa: E731
+        n: sum(r[key].get(n, 0.0) for r in rows)
+        for n in sorted({n for r in rows for n in r[key]})}
+    claimed_busy = _overlap_s(
+        sorted((max(x, lo), min(y, hi)) for _, x, y in envelopes
+               if x < hi and y > lo), busy)
+    worst = sorted(rows, key=lambda r: -sum(r["uncovered"].values()))[:12]
+    return {"offset_ms": offset_ms, "window_s": hi - lo,
+            "idle_s": sum(b - a for a, b in gaps),
+            "gaps_over_half_ms": {"n": len(long),
+                                  "s": sum(b - a for a, b in long)},
+            "gaps_under_half_ms": {"n": len(gaps) - len(long),
+                                   "s": sum(b - a for a, b in gaps
+                                            if b - a < min_gap_s)},
+            "covered_s": total("covered"), "uncovered_s": total("uncovered"),
+            "claimed_while_busy_s": claimed_busy,
+            "worst_uncovered": [
+                {"at_s": r["at"] - lo, "gap_ms": r["gap"] * 1e3,
+                 "uncovered_ms": {n: v * 1e3 for n, v in r["uncovered"].items()}}
+                for r in worst if r["uncovered"]]}, rows
+
+
+def _starved_by_part(spans, window, traced_window):
+    """The starved share of the measured window's parts apart: before the
+    profiled sub-window (what ``device_starved_pct.*`` reads), inside it, and
+    after it from past ``stop_trace``'s 0.45-s stop to the window's close,
+    while the trace is still being written out on its caller's thread."""
+    from benchmarks import run
+    mod = run._load_py(os.path.join(ROOT, "benchmarks", "layer_metrics",
+                                    "device_starved_pct.py"))
+    (lo, hi), (t0, t1) = window, traced_window
+    parts = {"before": (lo, t0 - mod.MARGIN_BEFORE_S), "profiled": (t0, t1),
+             "after": (t1 + 1.5, hi)}
+    return {k: [mod.overlap(spans, ("lm/starved",), [(a, b)]) / (b - a) * 100,
+                b - a] for k, (a, b) in parts.items() if b > a}
+
+
+def _profiler_edges(spans, window, traced_window, calls):
+    """What ``start_trace`` and ``stop_trace`` cost the worker: when each call
+    began and returned (seconds from the profiled sub-window's start and from
+    its end), the starved share of the window's parts, and the median and
+    longest ``lm/round`` by quarter second from 2 s before the sub-window to
+    4 s after it."""
+    lo, hi = traced_window
+    bins = {}
+    for n, s, d in spans:
+        if n == "lm/round" and lo - 2.0 <= s < hi + 4.0:
+            key = ("%+.2f" % ((s - lo) // 0.25 * 0.25) if s < hi
+                   else "end%+.2f" % ((s - hi) // 0.25 * 0.25))
+            bins.setdefault(key, []).append(d)
+    return {"start_trace": [t - lo for t in calls["start"]],
+            "stop_trace": [t - hi for t in calls["stop"]],
+            "starved_pct_by_part": _starved_by_part(spans, window,
+                                                    traced_window),
+            "round_ms_by_quarter_s": {
+                k: [round(statistics.median(v) * 1e3, 3),
+                    round(max(v) * 1e3, 1), len(v)] for k, v in bins.items()}}
+
+
 def _watch_trace(seen: dict):
     """Before the harness reduces (and deletes) a trace: the clock offset
-    from its planes, and the leaf spans' cover of the traced window."""
+    from its planes, the leaf spans' cover of the traced window, the device's
+    idle gaps against the engine's starved account, and what the profiler's
+    start and stop cost."""
+    import jax
     from benchmarks import run
     from benchmarks.harness import trace_reduce
     reduce_trace = run.Run.reduce_trace
+    calls = {}
+
+    def timed(fn, key):
+        def call(*a, **kw):
+            t0 = time.perf_counter()
+            try:
+                return fn(*a, **kw)
+            finally:
+                calls[key] = (t0, time.perf_counter())
+        return call
+
+    jax.profiler.start_trace = timed(jax.profiler.start_trace, "start")
+    jax.profiler.stop_trace = timed(jax.profiler.stop_trace, "stop")
 
     def watched(self, spans):
         try:
-            seen["clock_offset"] = _clock_offset(
-                trace_reduce.find_xplane(self._trace_dir))
+            xplane = trace_reduce.find_xplane(self._trace_dir)
+            seen["clock_offset"] = offset = _clock_offset(xplane)
+            seen["profiler_edges"] = _profiler_edges(
+                spans, (self.t_open, self.t_close), self.traced_window, calls)
+            if offset is not None:
+                # the wait's side of the interval is the tight one (a module's
+                # end to its ids on the host); the dispatch's side is loose
+                # where few rounds follow a drain (7-14 ms at solar2.backlog)
+                lo, hi = offset["device_minus_host_ms"]
+                found = _starved_gaps(
+                    xplane, spans, self.traced_window, self._sync_perf,
+                    lo + min(0.5, (hi - lo) / 2),
+                    dump=os.path.join(ROOT, "chiprun_out", "starved_raw_%s_%d.json"
+                                      % (self.cell["name"], self.seed)))
+                if found:
+                    seen["starved_gaps"], seen["_gap_rows"] = found
         except Exception as e:  # noqa: BLE001 -- a reading, not the run
-            seen["clock_offset"] = repr(e)
+            seen.setdefault("clock_offset", repr(e))
+            seen["starved_gaps"] = repr(e)
         seen["cover"] = _cover(spans, (self.t_open, self.t_close))
         seen["round_shapes"] = _round_shapes(spans, (self.t_open, self.t_close))
         return reduce_trace(self, spans)
@@ -262,23 +455,37 @@ def stamp_cost() -> dict:
     tracer, out = get_tracer(), {}
 
     def one_round():
+        # a round that ran ahead: the device holds work throughout
         eng.watchdog.step_started()
         eng._stamp(le.P_DISPATCH)
         eng._stamp(le.P_WAIT)
         eng._stamp(le.P_EMIT)
         eng._round_end(0)
 
-    for label, on, n in (("off", False, 200000), ("on", True, 50000)):
+    def drained_round():
+        # a round after a drain: its wait proves the device empty, so its
+        # emit, sched and dispatch are starved (one lm/starved, tracer on)
+        eng.watchdog.step_started()
+        eng._stamp(le.P_DISPATCH)
+        eng._stamp(le.P_WAIT)
+        eng._proved_empty(eng._stamp(le.P_EMIT), "decode_wait")
+        eng._round_end(0)
+
+    for label, on, n, a_round in (
+            ("tracer_off", False, 200000, one_round),
+            ("tracer_on", True, 50000, one_round),
+            ("drained_tracer_off", False, 200000, drained_round),
+            ("drained_tracer_on", True, 50000, drained_round)):
         tracer.enabled = on
         for _ in range(2000):
-            one_round()
+            a_round()
         samples = []
         for _ in range(5):
             t0 = time.perf_counter()
             for _ in range(n // 5):
-                one_round()
+                a_round()
             samples.append((time.perf_counter() - t0) / (n // 5))
-        out[f"round_stamps_us_tracer_{label}"] = {
+        out[f"round_stamps_us_{label}"] = {
             "median": statistics.median(samples) * 1e6,
             "min": min(samples) * 1e6, "max": max(samples) * 1e6, "rounds": n}
     tracer.enabled = False
@@ -346,6 +553,13 @@ def main(argv) -> int:
             row["clock_offset"] = seen.get("clock_offset")
             row["cover"] = seen.get("cover")
             row["round_shapes"] = seen.get("round_shapes")
+            row["profiler_edges"] = seen.get("profiler_edges")
+            row["starved_gaps"] = seen.get("starved_gaps")
+            # every gap over 0.5 ms, one line each, beside the summary
+            with open(os.path.join(ROOT, "chiprun_out", "starved_gaps.jsonl"),
+                      "a") as f:
+                for r in seen.get("_gap_rows") or ():
+                    f.write(json.dumps(dict(r, cell=a.cell, seed=seed)) + "\n")
         out(row)
     return 0
 

@@ -104,3 +104,23 @@ def corrupt_variants(good: bytes, n_trials: int, seed: int = 0):
             data = data[: rng.randint(8, len(data))] + bytes(
                 rng.randint(0, 256, size=16, dtype=np.uint8))
         yield trial, bytes(data)
+
+
+@pytest.fixture
+def lm_round_records(monkeypatch):
+    """Every round of every LM engine as ``LMMetrics.record_round`` is handed
+    it, in order: its phase split and its starved split (None: nothing of it
+    passed with the device proven empty), both indexed as ``ROUND_PHASES``."""
+    from bigdl_tpu.serving import lm_engine
+    seen, real = [], lm_engine.LMMetrics.record_round
+
+    def watched(self, t0, dur_s, split, index, active, admitted, plain,
+                starved=None):
+        seen.append({"index": index, "admitted": admitted, "plain": plain,
+                     "split": list(split),
+                     "starved": None if starved is None else list(starved)})
+        return real(self, t0, dur_s, split, index, active, admitted, plain,
+                    starved)
+
+    monkeypatch.setattr(lm_engine.LMMetrics, "record_round", watched)
+    return seen

@@ -9,7 +9,9 @@ the toy Laguna and the toy Solar-Open2 (a recurrent state beside the pool);
 a stream that ends on its eos while the next round is on the device emits
 nothing past it and the row is counted; a finish by count leaves the slot out
 of the round ahead; cancel, deadline, hibernate, adopt and close() each drain
-or drop the round in flight; a speculating engine never runs ahead."""
+or drop the round in flight; a speculating engine never runs ahead.  The
+device's account follows it: a round that ran ahead has nothing starved, a
+round after a drain its emit, sched and dispatch."""
 import time
 
 import numpy as np
@@ -404,3 +406,62 @@ def test_operand_sentinel_is_what_the_round_ahead_hands_its_step(monkeypatch):
                 for _, _, ids, slot in calls] == out[1:]
     finally:
         eng.close()
+
+
+# -- (f) the device's account --------------------------------------------------------
+@pytest.mark.parametrize("traced", [False, True], ids=["tracer_off", "tracer_on"])
+@pytest.mark.parametrize("drains", [False, True], ids=["ahead", "drains"])
+@pytest.mark.parametrize("case", ["gpt2", "solar2"])
+def test_account_of_one_stream_round_by_round(monkeypatch, lm_round_records,
+                                              case, drains, traced):
+    """One request into an idle engine: the admission is starved from the
+    wake-up to the prefill's enqueue and not for its insert (a recurrent
+    model's state insert), its first token or what the host does while they
+    are on the device; then every round runs ahead with nothing starved, until
+    the last is drained and its emission is starved.  Made to drain every
+    round, each round is starved for its emit, sched and dispatch and never for
+    its wait.  The tracer changes none of it."""
+    from bigdl_tpu.obs import get_tracer
+    P = lm_engine
+    if drains:
+        _drains_every_round(monkeypatch)
+    tracer = get_tracer()
+    was, tracer.enabled = tracer.enabled, traced
+    eng = _engine(case)
+    try:
+        eng.warmup()
+        assert len(_gen(eng.submit(np.arange(1, 8), max_new_tokens=10))) == 10
+        _idle(eng)
+    finally:
+        eng.close()
+        tracer.enabled = was
+        tracer.clear()
+    records = [(r["split"], r["starved"], r["admitted"])
+               for r in lm_round_records]
+    assert len(records) == 10 and [a for *_, a in records] == [1] + [0] * 9
+    split, starved, _ = records[0]
+    assert starved[P.P_PREFILL] == split[P.P_PREFILL] > 0
+    assert 0 < starved[P.P_ADMIT_HOST] < split[P.P_ADMIT_HOST]
+    assert 0 < starved[P.P_SCHED] <= split[P.P_SCHED]
+    assert (starved[P.P_IDLE] == starved[P.P_INSERT] == starved[P.P_STATE_INSERT]
+            == starved[P.P_FIRST_TOKEN] == starved[P.P_WAIT] == 0)
+    assert split[P.P_INSERT] > 0 and split[P.P_FIRST_TOKEN] > 0
+    assert (split[P.P_STATE_INSERT] > 0) == (case == "solar2")
+    if not drains:
+        # eight rounds ran ahead; the ninth collects the last step and drains
+        assert [s for _, s, _ in records[1:9]] == [None] * 8
+        assert eng.metrics.rounds_ahead == 8
+    for split, starved, _ in (records[1:] if drains else records[9:]):
+        assert starved[P.P_EMIT] == split[P.P_EMIT] > 0
+        assert starved[P.P_DISPATCH] == split[P.P_DISPATCH]
+        assert 0 < starved[P.P_SCHED] <= split[P.P_SCHED]
+        assert starved[P.P_WAIT] == 0 < split[P.P_WAIT]
+        assert sum(starved) == pytest.approx(
+            starved[P.P_EMIT] + starved[P.P_SCHED] + starved[P.P_DISPATCH])
+    # the last round dispatches nothing; a drained round before it does
+    assert records[-1][0][P.P_DISPATCH] == 0
+    if drains:
+        assert all(split[P.P_DISPATCH] > 0 for split, *_ in records[1:-1])
+    rounds = eng.stats()["rounds"]
+    assert rounds["starved_s"] == pytest.approx(
+        sum(sum(s) for _, s, _ in records if s), rel=1e-9)

@@ -2,7 +2,10 @@
 the worker thread's leaf spans cover every ``lm/round`` without a gap or an
 overlap; with it off, the same stamps fill ``stats()["rounds"]``, name the
 longest round's phase and log a slow round once; a round that hangs fires the
-``lm_round`` watchdog while it hangs."""
+``lm_round`` watchdog while it hangs.  The same stamps keep the device's
+account: what passes between a wait that proved the device empty and the next
+enqueue is starved (``starved_s`` / ``starved_phase_s``, tracer off or on; one
+``lm/starved`` envelope an interval with it on)."""
 import logging
 import time
 
@@ -16,6 +19,8 @@ from bigdl_tpu.serving.lm_engine import ROUND_PHASES
 from bigdl_tpu.serving.spec import SpecConfig
 
 LEAVES = {"lm/" + p for p in ROUND_PHASES}
+WAIT_LEAVES = {"lm/decode_wait", "lm/first_token"}
+ENQUEUE_LEAVES = {"lm/" + ROUND_PHASES[p] for p in lm_engine._ENQUEUES}
 ADMIT_LEAVES = {"lm/admit_host", "lm/prefill", "lm/insert", "lm/first_token"}
 EPS_US = 1e-3       # stamps share one clock read; what is left is rounding
 
@@ -44,8 +49,9 @@ def tracer():
 
 
 def _engine(model, **kw):
+    kw.setdefault("max_new_tokens", 8)
     eng = LMServingEngine(model, slots=3, cache_len=48, block_len=4,
-                          max_new_tokens=8, prefill_buckets=(8, 16), **kw)
+                          prefill_buckets=(8, 16), **kw)
     eng.warmup()
     return eng
 
@@ -339,3 +345,174 @@ def test_tracer_counts_what_a_full_ring_drops():
     assert tr.export_chrome()["otherData"]["dropped"] == 5
     tr.clear()
     assert tr.dropped == 0 and len(tr) == 0
+
+
+# -- the device's account ------------------------------------------------------------
+def _ends(ev):
+    return ev["ts"] + ev["dur"]
+
+
+@pytest.mark.parametrize("traced", [False, True], ids=["tracer_off", "tracer_on"])
+@pytest.mark.parametrize("kind", sorted(ENGINES))
+def test_starved_account_sums_and_envelopes(model, tracer, lm_round_records,
+                                            kind, traced):
+    """Waits and ``lm/idle`` are never starved, no phase is starved for longer
+    than it lasted, the split sums to ``starved_s``; the account reads the same
+    way with the tracer off.  With it on, the ``lm/starved`` envelopes sum to
+    ``starved_s``, overlap no wait, start where a wait or ``lm/idle`` ended (a
+    first token's: where a later leaf did, once the insert behind it was
+    ready), end where an enqueue leaf ends (or where ``lm/idle`` starts or the
+    worker leaves: the last drain), hold two leaves or more and are written
+    after the last."""
+    tracer.enabled = traced
+    records = lm_round_records
+    eng = _engine(model, **ENGINES[kind])
+    _serve(eng)
+    eng.submit(np.arange(1, 8), max_new_tokens=8).result(timeout=120)
+    _close(eng)
+    rounds = eng.stats()["rounds"]
+    phase_s, starved = rounds["phase_s"], rounds["starved_phase_s"]
+    assert set(starved) == set(ROUND_PHASES)
+    assert starved["idle"] == starved["decode_wait"] == starved["first_token"] == 0
+    for p in ROUND_PHASES:
+        assert 0 <= starved[p] <= phase_s[p] + 1e-9, p
+    assert sum(starved.values()) == pytest.approx(rounds["starved_s"], rel=1e-9)
+    assert 0 < rounds["starved_s"] <= (
+        sum(phase_s.values()) - phase_s["idle"] - phase_s["decode_wait"]
+        - phase_s["first_token"] + 1e-9)
+    # an admission into an idle engine, or after a drain: its prefill's enqueue
+    assert starved["prefill"] > 0 and starved["sched"] > 0
+    # round by round: the same account, folded
+    assert sum(sum(r["starved"]) for r in records if r["starved"]) \
+        == pytest.approx(rounds["starved_s"], rel=1e-9)
+    for r in records:
+        for p, v in enumerate(r["starved"] or ()):
+            assert 0 <= v <= r["split"][p] + 1e-12
+            assert not (v and ROUND_PHASES[p] in ("idle", "decode_wait",
+                                                  "first_token"))
+    if not traced:
+        assert len(tracer) == 0
+        return
+    assert tracer.dropped == 0
+    # (the ring's own order: events() sorts by start)
+    evs = list(enumerate(e for e in list(tracer._events)
+                         if e["ph"] == "X" and e["tid"] == eng._worker.ident))
+    leaves = [(i, e) for i, e in evs if e["name"] in LEAVES]
+    envelopes = [(i, e) for i, e in evs if e["name"] == "lm/starved"]
+    assert len(envelopes) >= 3
+    assert sum(e["dur"] for _, e in envelopes) * 1e-6 == pytest.approx(
+        rounds["starved_s"], abs=len(envelopes) * EPS_US * 1e-6)
+    leaf_ends = {}
+    for _, e in leaves:
+        leaf_ends.setdefault(e["name"], []).append(_ends(e))
+    idle_starts = [e["ts"] for _, e in leaves if e["name"] == "lm/idle"]
+    last_round_end = max(_ends(e) for _, e in evs if e["name"] == "lm/round")
+    near = lambda t, ts: any(abs(t - x) <= EPS_US for x in ts)   # noqa: E731
+    for i, env in envelopes:
+        args = env["args"]
+        assert env["cat"] == "serve" and set(args) == {
+            "round", "after", "until", "admitted"}
+        assert args["after"] in ("decode_wait", "first_token", "idle", "start")
+        for _, w in leaves:
+            if w["name"] in WAIT_LEAVES:
+                assert (_ends(w) <= env["ts"] + EPS_US
+                        or _ends(env) <= w["ts"] + EPS_US), (env, w)
+        if args["until"] == "idle":     # the engine ran out of work, or closed
+            assert near(_ends(env), idle_starts + [last_round_end])
+            assert not args["admitted"]
+        else:
+            assert "lm/" + args["until"] in ENQUEUE_LEAVES
+            assert near(_ends(env), leaf_ends["lm/" + args["until"]])
+            # an admission's envelope ends in its prefill's enqueue (a
+            # chunk is prefilled by the loop, outside any lm/admit)
+            assert args["admitted"] == (args["until"] == "prefill"
+                                        and kind != "chunked")
+        if args["after"] in ("decode_wait", "idle"):
+            assert near(env["ts"], leaf_ends["lm/" + args["after"]])
+        elif args["after"] == "first_token":
+            assert env["ts"] >= min(leaf_ends["lm/first_token"]) - EPS_US
+            assert near(env["ts"], [t for ts in leaf_ends.values() for t in ts])
+        inside = [(j, e) for j, e in leaves if _inside(e, env)]
+        # never the shortest cover of an instant: two leaves or more (one
+        # that ends in lm/idle may hold a speculating round's emit alone)
+        assert len(inside) >= (1 if args["until"] == "idle" else 2), env
+        assert all(e["dur"] < env["dur"] for _, e in inside) or len(inside) == 1
+        assert sum(e["dur"] for _, e in inside) == pytest.approx(
+            env["dur"], abs=len(inside) * EPS_US)
+        assert i > max(j for j, _ in inside)        # written after its last leaf
+        assert not {e["name"] for _, e in inside} & (WAIT_LEAVES | {"lm/idle"})
+    # envelopes do not overlap each other
+    ordered = sorted((e for _, e in envelopes), key=lambda e: e["ts"])
+    for a, b in zip(ordered, ordered[1:]):
+        assert _ends(a) <= b["ts"] + EPS_US
+    # a request that woke the idle engine: starved from the wake-up to the
+    # prefill's enqueue, inside its lm/admit
+    woke = [e for _, e in envelopes if e["args"]["after"] == "idle"]
+    assert woke and all(e["args"]["until"] == "prefill" for e in woke)
+
+
+def test_speculating_rounds_are_starved_between_their_wait_and_their_draft(
+        model, tracer, lm_round_records):
+    """A verify round is synchronous: its wait proves the device empty, so its
+    emission, the scheduling after it and the next round's draft (the first
+    enqueue) pass starved; its dispatch and its wait do not."""
+    tracer.disable()
+    records = lm_round_records
+    eng = _engine(model, spec=SpecConfig(k=3), max_new_tokens=24)
+    _serve(eng)
+    # one request alone, four tokens a round at most: six rounds or more
+    eng.submit(np.arange(1, 8), max_new_tokens=24).result(timeout=120)
+    _close(eng)
+    P = lm_engine
+    plain = [r for prev, r in zip(records, records[1:])
+             if r["plain"] and prev["split"][P.P_WAIT] > 0]
+    assert len(plain) >= 3
+    for r in plain:
+        split, starved = r["split"], r["starved"]
+        for p in (P.P_SCHED, P.P_DRAFT, P.P_EMIT):
+            assert starved[p] == pytest.approx(split[p], rel=1e-9) and split[p] > 0
+        assert starved[P.P_DISPATCH] == starved[P.P_WAIT] == 0
+        assert split[P.P_DISPATCH] > 0 and split[P.P_WAIT] > 0
+
+
+@pytest.mark.parametrize("drains", [True, False], ids=["after_a_drain", "ahead"])
+def test_slow_round_line_carries_the_rounds_starved_seconds(
+        model, tracer, monkeypatch, caplog, drains):
+    """A slow emission after a drain is the host's and the device waits for
+    it: the line and ``longest`` say ``starved`` 0.3 s; in a round that ran
+    ahead the device held the next round meanwhile: ``starved 0.000``."""
+    tracer.disable()
+    monkeypatch.setattr(lm_engine, "SLOW_ROUND_S", 0.2)
+    if drains:
+        monkeypatch.setattr(LMServingEngine, "_runs_ahead", lambda self: False)
+    eng = _engine(model)
+    _serve(eng, n=3)
+    eng.metrics.reset_rounds()
+    real, calls = lm_engine.LMStream._emit, {"n": 0}
+
+    def slow_emit(self, token_1b):
+        in_round = self.first_token_at is not None
+        calls["n"] += in_round
+        if in_round and calls["n"] == 4:
+            time.sleep(0.3)
+        return real(self, token_1b)
+
+    monkeypatch.setattr(lm_engine.LMStream, "_emit", slow_emit)
+    with caplog.at_level(logging.WARNING, logger="bigdl_tpu.serving"):
+        # (a prompt no earlier one shares a block with: no suffix prefill)
+        eng.submit(np.arange(20, 27), max_new_tokens=8).result(timeout=120)
+        _close(eng)
+    rounds = eng.stats()["rounds"]
+    longest = rounds["longest"]
+    assert longest["phase"] == "emit" and longest["phase_s"]["emit"] >= 0.3
+    lines = [r.getMessage() for r in caplog.records
+             if "slow round" in r.getMessage()]
+    assert len(lines) == 1
+    assert f"starved {longest['starved_s']:.3f} s" in lines[0]
+    if drains:
+        assert longest["phase_s"]["emit"] <= longest["starved_s"] \
+            <= longest["seconds"]
+        assert rounds["starved_phase_s"]["emit"] >= 0.3
+    else:
+        assert longest["starved_s"] == 0.0
+        assert rounds["starved_phase_s"]["emit"] < 0.3
