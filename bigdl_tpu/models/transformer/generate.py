@@ -755,11 +755,13 @@ def _decode_step_paged(model, params, token, pos, live, *arenas,
     k/v scatter by (block, offset) derived from ``pos`` and the list;
     attention reads each slot's blocks under the identical position mask
     / score math as the slot engine -- either by gathering the listed
-    blocks (``attn_impl="gather"``, the XLA path) or in place via the
+    blocks (``attn_impl="gather"``, the XLA path) or in place via a
     Pallas block-table kernel over the tables the list spells
-    (``attn_impl="paged_kernel"``, ``ops.paged_attention``, which needs
-    their ``table_width`` -- same f32 softmax formulation, so streams
-    stay token-exact across the two).  The arenas (the pool's layout,
+    (``attn_impl="paged_kernel"``, which needs their ``table_width``:
+    ``ops.grouped_attention`` for a layer whose query heads share K/V
+    heads or that has a window, ``ops.paged_attention`` for a head a query
+    head -- same f32 softmax formulation, so streams stay token-exact
+    across the two).  The arenas (the pool's layout,
     ``serving.kvcache.blocks``) are donated by the serving engine and
     carried whole through the layer loop (:func:`_scan_layers`).
 
@@ -799,11 +801,6 @@ def _decode_step_paged(model, params, token, pos, live, *arenas,
     # (n, 1, B) by window: a sliding layer reads its whole chain and sees
     # the last ``window`` positions of it
     masks = _list_masks(model, live, pos[:, None], B)
-    if attn_impl == "paged_kernel" and (
-            set(masks) != {None} or model.n_kv_head != model.n_head):
-        raise ValueError("the Pallas block-table kernel reads one K/V head a "
-                         "query head under a causal mask; windows and grouped "
-                         "heads need decode_attn='gather'")
     ids, owner, where = live
     held = ((owner[None, :] == jnp.arange(s)[:, None])
             & (ids != SCRATCH_BLOCK)[None, :])
@@ -865,13 +862,23 @@ def _decode_step_paged(model, params, token, pos, live, *arenas,
     def attention_layer(spec, h, bp, layer, arenas):
         q, k, v, gate = model.layer_qkv(spec, bp, h, positions)  # (S, H, 1, D)
         if attn_impl == "paged_kernel":
-            # in-place block reads via the table (no dense gather);
-            # numerics identical to the gather
-            from bigdl_tpu.ops import paged_decode_attention
+            # in-place block reads via the table (no dense gather), the new
+            # rows first; numerics identical to the gather
+            from bigdl_tpu.ops import (grouped_decode_attention,
+                                       paged_decode_attention)
             arenas = tuple(
                 write_rows(a, layer, blk, off, x.transpose(0, 2, 1, 3))
                 for a, x in zip(arenas, (k, v)))
-            o = paged_decode_attention(q, *arenas, tables, pos, layer=layer)
+            if spec.n_head != model.n_kv_head or spec.window is not None:
+                # query heads that share K/V heads, a sliding window
+                with jax.named_scope(_attn_scope(model, spec)):
+                    o = grouped_decode_attention(
+                        q, *arenas, tables, jnp.where(active, pos + 1, 0),
+                        layer=layer, n_kv_head=model.n_kv_head,
+                        window=spec.window)
+            else:
+                o = paged_decode_attention(q, *arenas, tables, pos,
+                                           layer=layer)
         else:
             with jax.named_scope(_attn_scope(model, spec)):
                 o, arenas = _paged_attention(q, k, v, arenas, layer, blk,

@@ -10,6 +10,9 @@ from bigdl_tpu.ops.flash_attention import (  # noqa: F401
     AttentionPlan, flash_attention, flash_attention_with_lse,
     resolve_attention_plan,
 )
+from bigdl_tpu.ops.grouped_attention import (  # noqa: F401
+    grouped_decode_attention,
+)
 from bigdl_tpu.ops.latent_attention import (  # noqa: F401
     latent_decode_attention,
 )

@@ -210,9 +210,6 @@ _REFUSALS = (
      "not a recurrent layer's state"),
     ("recurrent", "serve with kvtier", "demotion, promotion and hibernation "
      "carry (k, v) blocks, not a recurrent layer's state"),
-    ("recurrent", "serve with decode_attn='paged_kernel'", "the Pallas "
-     "block-table kernel reads one K/V head a query head, and this model's "
-     "attention layers share theirs"),
     ("recurrent", "serve with tensor-parallel placement", "no rule places a "
      "recurrent layer's heads and state"),
     ("recurrent", "adopt a migrated request", "the handoff carries (k, v) "
@@ -233,8 +230,7 @@ _REFUSALS = (
 
 
 def refuse_unsupported(model, *, spec=None, migrate=None, kvtier=None,
-                       decode_attn="auto", kv_quant=None, placement=None,
-                       adopt=False):
+                       kv_quant=None, placement=None, adopt=False):
     """Raise, with its one message, for the first thing asked that the
     model's cache kinds cannot do (:data:`_REFUSALS`): THE place where a
     recurrent state and a latent pool refuse what assumes a paged ``(k, v)``
@@ -244,10 +240,6 @@ def refuse_unsupported(model, *, spec=None, migrate=None, kvtier=None,
     asked = {"serve with spec": spec is not None,
              "serve with migrate": migrate is not None,
              "serve with kvtier": kvtier is not None,
-             # (the block-table kernel over (k, v) blocks: a latent pool's
-             # kernel is its own, ops.latent_attention)
-             "serve with decode_attn='paged_kernel'":
-                 decode_attn == "paged_kernel" and not model.latent_layers,
              "serve with kv_quant='int8'": kv_quant == "int8",
              "serve with tensor-parallel placement":
                  placement is not None and placement.tp > 1,
@@ -951,15 +943,17 @@ class LMServingEngine:
             ``generate._paged_attention``, the XLA path; how many
             chunks follows each round from what the slots hold, never
             set), "paged_kernel" (the in-place Pallas block-table
-            kernel, ``ops.paged_attention``; for a latent pool the
-            kernel that reads the listed blocks of latent rows where
-            they lie, ``ops.latent_attention``), or "auto" (default): the
-            kernel only when the autotune cache has measured it faster
-            than the gather ON THIS device kind, the gather otherwise;
-            for a latent pool the kernel on a TPU whenever the compiled
-            kernel can take the pool's geometry, the gather (the CPU
-            path) otherwise.  Both produce token-identical streams (a
-            latent pool's to the order of float32 sums).
+            kernel, ``ops.paged_attention``; where query heads share
+            K/V heads or a layer has a window, and for a latent pool, the
+            kernels that read the listed blocks where they lie,
+            ``ops.grouped_attention`` and ``ops.latent_attention``), or
+            "auto" (default): the kernel only when the autotune cache has
+            measured it faster than the gather ON THIS device kind, the
+            gather otherwise; for shared K/V heads and for a latent pool
+            the kernel on a TPU whenever the compiled kernel can take the
+            pool's geometry, the gather (the CPU path) otherwise.  Both
+            produce token-identical streams (those two kernels' to the
+            order of float32 sums).
         kv_quant: ``None`` (full-precision KV, the default) or
             ``"int8"``: the block pool stores int8 KV blocks with
             per-(position, head) f32 scales, dequantized inside the
@@ -1048,8 +1042,7 @@ class LMServingEngine:
         self._state_layers = len(model.state_layers)
         self._latent_layers = len(model.latent_layers)
         refuse_unsupported(model, spec=spec, migrate=migrate, kvtier=kvtier,
-                           decode_attn=decode_attn, kv_quant=kv_quant,
-                           placement=placement)
+                           kv_quant=kv_quant, placement=placement)
         if self._latent_layers and model.kv_layers:
             raise ValueError(
                 "a plan that mixes 'attention' and 'mla' layers cannot be "
@@ -1240,14 +1233,25 @@ class LMServingEngine:
             raise ValueError(f"decode_attn must be 'auto', 'gather' or "
                              f"'paged_kernel', got {decode_attn!r}")
 
+        # query heads that share K/V heads, and layers with a window: what
+        # of a (k, v) pool the grouped kernel reads (ops.grouped_attention)
+        _shared = model.n_kv_head != model.n_head
+        _windows = any(s.window is not None
+                       for _, period in model.plan for s in period)
+
         def _check_kernel_shapes():
             # raises for a pool geometry the COMPILED kernel cannot read
+            from bigdl_tpu.ops.grouped_attention import (
+                check_grouped_kernel_shapes)
             from bigdl_tpu.ops.latent_attention import (
                 check_latent_kernel_shapes)
             from bigdl_tpu.ops.paged_attention import check_paged_kernel_shapes
             if self._latent_layers:
                 check_latent_kernel_shapes(self.block_len,
                                            self.pool.shape[-1], dt)
+            elif _shared or _windows:
+                check_grouped_kernel_shapes(self.block_len,
+                                            self.pool.shape[-1], D, dt)
             else:
                 check_paged_kernel_shapes(self.block_len, dt)
 
@@ -1259,10 +1263,12 @@ class LMServingEngine:
                     "kv_quant='int8' requires decode_attn='gather' (the "
                     "Pallas paged kernel reads raw blocks)")
             decode_attn = "gather"
-        elif decode_attn == "auto" and self._latent_layers:
-            # a latent pool's kernel reads the listed blocks where they lie
-            # (ops.latent_attention): on the chip, where the compiled kernel
-            # can take the pool's geometry; the walk is the CPU path
+        elif decode_attn == "auto" and (self._latent_layers or _shared):
+            # a latent pool's kernel and the one for query heads that share
+            # K/V heads read the listed blocks where they lie
+            # (ops.latent_attention, ops.grouped_attention): on the chip,
+            # where the compiled kernel can take the pool's geometry; the
+            # walk is the CPU path
             decode_attn = "gather"
             if jax.default_backend() == "tpu":
                 try:
@@ -1270,11 +1276,9 @@ class LMServingEngine:
                     decode_attn = "paged_kernel"
                 except ValueError:
                     pass
-        elif decode_attn == "auto" and (
-                model.n_kv_head != model.n_head
-                or any(s.window for _, period in model.plan for s in period)):
-            # the Pallas block-table kernel knows neither grouped heads
-            # nor windows yet (ROADMAP M3)
+        elif decode_attn == "auto" and _windows:
+            # a head a query head under windows: no chip reading says the
+            # kernel beats the walk there
             decode_attn = "gather"
         elif decode_attn == "auto":
             # the same crossover discipline as flash_attention: the

@@ -48,13 +48,16 @@ import jax.numpy as jnp
 from bigdl_tpu import nn
 from bigdl_tpu.dataset import DataSet, MiniBatch
 from bigdl_tpu.models import ResNet
-from bigdl_tpu.models.transformer import TransformerLM
-from bigdl_tpu.models.transformer.generate import generate
+from bigdl_tpu.models.transformer import TransformerLM, window_mask
+from bigdl_tpu.models.transformer.generate import _paged_attention, generate
 from bigdl_tpu.ops.flash_attention import (_xla_fallback, flash_attention,
                                            use_flash_auto)
+from bigdl_tpu.ops.grouped_attention import grouped_decode_attention
+from bigdl_tpu.ops.latent_attention import latent_decode_attention
 from bigdl_tpu.ops.paged_attention import (paged_decode_attention,
                                            paged_decode_attention_reference)
-from bigdl_tpu.serving.kvcache.blocks import pack_rows
+from bigdl_tpu.serving.kvcache.blocks import (SCRATCH_BLOCK, live_list,
+                                              pack_rows, row_width)
 from bigdl_tpu.optim import SGD, Optimizer, Trigger
 from bigdl_tpu.serving import LMServingEngine
 from bigdl_tpu.utils.engine import configure_compile_cache
@@ -100,6 +103,15 @@ class Sizes:
     paged_cases: Tuple[tuple, ...] = (
         (8, 25, 64, 16, 64, "bfloat16"),
         (8, 25, 64, 16, 64, "float32"),
+    )
+    # the kernels that read a round's listed blocks where they lie, at their
+    # cells' rows: (kind, S, H, H_kv, D, blk, M, window, dtype) -- Solar's
+    # softmax layer, Laguna's sliding layers, Ling's latent row (D its lanes,
+    # a third of them the score's width, all but a ninth values)
+    listed_cases: Tuple[tuple, ...] = (
+        ("grouped", 16, 64, 8, 128, 16, 128, None, "bfloat16"),
+        ("grouped", 8, 72, 8, 128, 16, 160, 512, "bfloat16"),
+        ("latent", 8, 32, 1, 576, 16, 256, None, "bfloat16"),
     )
     # ResNet-50 training
     resnet_depth: int = 50
@@ -511,6 +523,108 @@ def _paged_case(case: tuple, seed: int) -> dict:
             "rel_err": round(err, 5)}
 
 
+#: a kernel that multiplies exact products may lie this far from float64
+#: where its oracle lies nearer (the order of the float32 sums)
+LISTED_TOL = 1e-5
+
+
+def _float64_attention(q, k, v, tables, lengths, n_kv, window, score_dim):
+    """A float64 softmax a slot and query head over the chain's own rows: ``q``
+    (S, H, D), ``k`` / ``v`` one layer's arena (N, blk, lanes), query head i on
+    the lanes of K/V head ``i // (H / n_kv)`` -> (S, H, D)."""
+    s_, h, d = q.shape
+    g = h // n_kv
+    out = np.zeros((s_, h, d))
+    for s, length in enumerate(lengths):
+        if not length:
+            continue
+        lo = 0 if window is None else max(length - window, 0)
+        rows = [a[tables[s]].reshape(-1, a.shape[-1])[lo:length].astype(
+            np.float64) for a in (k, v)]
+        for i in range(h):
+            lanes = slice((i // g) * d, (i // g + 1) * d)
+            score = rows[0][:, lanes] @ q[s, i] / np.sqrt(score_dim)
+            e = np.exp(score - score.max())
+            out[s, i] = (e / e.sum()) @ rows[1][:, lanes]
+    return out
+
+
+def _listed_case(case: tuple, seed: int) -> dict:
+    """A kernel that reads a round's listed blocks in place and the XLA walk
+    it replaces on the chip, each against float64 on the same arenas: what
+    no CPU test can see is a piece of a product dropped by the TPU compiler
+    (PERF.md, PR 36)."""
+    kind, s, h, n_kv, d, blk, m, window, dtname = case
+    dt, latent = jnp.dtype(dtname), kind == "latent"
+    rs = np.random.RandomState(seed)
+    n, w = s * m + 1, row_width(n_kv, d)
+    keys = jax.random.split(jax.random.PRNGKey(seed), 5)
+    lanes = jnp.arange(w) < n_kv * d                        # the lane padding
+    arenas = tuple(
+        jnp.where(lanes, jax.random.normal(kk, (1, n, blk, w), jnp.float32),
+                  0).astype(dt) for kk in keys[:1 if latent else 2])
+    q = 2 * jax.random.normal(keys[2], (s, h, 1, d), jnp.float32)
+    new = [jax.random.normal(kk, (s, n_kv, 1, d), jnp.float32).astype(dt)
+           for kk in keys[3:4 if latent else 5]]
+    # ragged chains of scattered blocks: an idle slot, a full table, one block
+    lengths = rs.randint(1, m * blk + 1, size=s)
+    lengths[:3] = (0, m * blk, 1)
+    order = 1 + rs.permutation(n - 1)
+    tables = np.full((s, m), SCRATCH_BLOCK, np.int32)
+    chains = []
+    for i, length in enumerate(lengths):
+        held = -(-int(length) // blk)
+        if held:
+            tables[i, :held] = order[i * m:i * m + held]
+            chains.append((i, tables[i, :held]))
+    live = jnp.asarray(live_list(chains, s * m, s))
+    pos = jnp.asarray(np.maximum(lengths - 1, 0), jnp.int32)
+    # the block a slot's new row lands in (an idle slot's: the scratch block)
+    at = jnp.asarray(tables[np.arange(s), np.maximum(lengths - 1, 0) // blk])
+    score_dim = d // 3 if latent else d
+    values = d - d // 9 if latent else d
+
+    def walk(q, new, arenas):
+        _, owner, where = live
+        k_pos = where[:, None] * blk + jnp.arange(blk)[None, :]
+        mask = (window_mask(pos[jnp.minimum(owner, s - 1)][:, None], k_pos,
+                            window) & (owner < s)[:, None, None])
+        return _paged_attention(q, new[0], None if latent else new[1], arenas,
+                                0, at[:, None], (pos % blk)[:, None], live,
+                                mask,
+                                score_dim=score_dim)
+
+    def kernel(q, arenas):
+        table, lens = jnp.asarray(tables), jnp.asarray(lengths, jnp.int32)
+        if latent:
+            return latent_decode_attention(q, arenas[0], table, lens,
+                                           score_dim=score_dim, layer=0,
+                                           value_lanes=values)
+        return grouped_decode_attention(q, *arenas, table, lens, layer=0,
+                                        n_kv_head=n_kv, window=window)
+
+    walked, arenas = jax.jit(walk)(q, new, arenas)
+    got = jax.jit(kernel)(q, arenas)
+    rows = [np.asarray(a[0].astype(jnp.float32)) for a in arenas]
+    want = _float64_attention(
+        np.asarray(q, np.float64)[:, :, 0], rows[0], rows[-1], tables, lengths,
+        n_kv, window, score_dim)[..., :values]
+    gap = {name: float(np.max(np.abs(np.asarray(o, np.float64)[:, :, 0, :values]
+                                     - want)) / np.max(np.abs(want)))
+           for name, o in (("kernel", got), ("walk", walked))}
+    _check(bool(np.isfinite(np.asarray(got)).all()),
+           f"{kind} decode {case} produced non-finite values")
+    _check(not np.asarray(got)[lengths == 0].any(),
+           f"{kind} decode {case}: an idle slot's rows are not zeros")
+    _check(gap["kernel"] <= max(gap["walk"], LISTED_TOL),
+           f"{kind} decode {case} vs float64: the kernel {gap['kernel']:.3g}, "
+           f"the walk {gap['walk']:.3g}")
+    return {"kernel": f"{kind} decode", "slots": s, "heads": h, "kv_heads": n_kv,
+            "head_dim": d, "block_len": blk, "table_width": m, "window": window,
+            "dtype": dtname, "positions": int(lengths.sum()),
+            "gap_to_float64": {k: float(f"{v:.3g}") for k, v in gap.items()}}
+
+
 def phase_kernels(sz: Sizes = REAL, seed: int = 0, carry=None,
                   require_compiled: bool = True) -> dict:
     mark = PROBE.mark()
@@ -520,6 +634,9 @@ def phase_kernels(sz: Sizes = REAL, seed: int = 0, carry=None,
             cases.append(_flash_case(case, segmented, sz.flash_block, seed))
     for case in sz.paged_cases:
         cases.append(_paged_case(case, seed))
+    for case in sz.listed_cases:
+        cases.append(_listed_case(case, seed))
+        _free_device_memory()
     if carry is None:
         model = _build_lm(sz, seed)
         reqs = _requests(sz, seed)

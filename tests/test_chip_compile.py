@@ -342,7 +342,23 @@ def test_lm_paged_programs_keep_the_arenas_in_place_on_v5e(sds, monkeypatch,
         assert not moved or geom is DEPTH2, moved
 
 
-@pytest.mark.parametrize("program", ["decode", "prefill2048"])
+def _grouped_kernel_calls(text, *scopes):
+    """The step's ``grouped_decode_attention`` custom calls (one a layer of the
+    plan's periods), the walk gone from ``scopes``, the VMEM each asks for: four
+    fetch buffers of 32 blocks of 1,024 bfloat16 lanes and a head's scores."""
+    kernel = [ln for ln in text.splitlines()
+              if 'custom_call_target="tpu_custom_call"' in ln
+              and "grouped_decode_attention" in ln]
+    for scope in scopes:
+        assert re.search(scope + r"/[\w()/]*grouped_decode_attention", text)
+        assert f"{scope}/ragged" not in text and f"{scope}/while" not in text
+    asked = [int(n) for ln in kernel for n in re.findall(
+        r'"scoped_memory_configs":\[\{[^\]]*"size":"(\d+)"', ln)]
+    assert asked and max(asked) < 32 << 20, asked
+    return kernel
+
+
+@pytest.mark.parametrize("program", ["decode", "decode-kernel", "prefill2048"])
 def test_laguna_cell_compiles_for_v5e_and_keeps_the_arenas_in_place(
         sds, monkeypatch, program, capsys):
     """``laguna-cell``, beside ``decode-cell``: the programs of the benchmark's
@@ -353,7 +369,11 @@ def test_laguna_cell_compiles_for_v5e_and_keeps_the_arenas_in_place(
     donated, its live list as long as whole tables and walked a chunk of 512
     blocks at a time (aliased, no arena-shaped copy, temporaries under 0.5 GB,
     the gathered chunk as it lies, no tensor of the whole tables' chain
-    shape, grouped matmuls as the TPU's own custom call) and the 2,048-token
+    shape, grouped matmuls as the TPU's own custom call: the CPU path), the
+    same step as ``decode_attn="auto"`` resolves on the chip
+    (``decode-kernel``: every attention layer through the Pallas kernel that
+    reads the listed blocks where they lie, ``ops.grouped_attention``, the
+    sliding layers under their window) and the 2,048-token
     prefill through the windowed,
     grouped-head flash kernel (no (T, T) score tensor).  Prints what the
     configuration's ``memory_arithmetic`` quotes."""
@@ -378,7 +398,10 @@ def test_laguna_cell_compiles_for_v5e_and_keeps_the_arenas_in_place(
     assert weight_bytes == 2 * (5_572_042_752 + 11 * 3072), weight_bytes
     layers, heads, d = model.n_layers, model.n_kv_head, model.head_dim
     i32 = lambda *shape: sds(shape, jnp.int32)              # noqa: E731
-    if program == "decode":
+    if program.startswith("decode"):
+        impl = "gather" if program == "decode" else "paged_kernel"
+        monkeypatch.setattr(pa, "_use_interpret", lambda: False)
+
         def arenas_of():
             pool = BlockPool(n_layers=layers, n_heads=heads, head_dim=d,
                              block_len=eng["block_len"],
@@ -392,7 +415,7 @@ def test_laguna_cell_compiles_for_v5e_and_keeps_the_arenas_in_place(
         def step(p, tok, pos, live, temperature, keys, prev_ids, *kv):
             return G._decode_pick_paged(model, p, tok, pos, live, temperature,
                                         keys, prev_ids, *kv,
-                                        table_width=width, attn_impl="gather")
+                                        table_width=width, attn_impl=impl)
 
         compiled, text = _compile(
             step, params, i32(slots), i32(slots), i32(3, slots * width),
@@ -412,8 +435,15 @@ def test_laguna_cell_compiles_for_v5e_and_keeps_the_arenas_in_place(
                  if dims in ln and re.search(r" copy(-start)?\(|AllocateBuffer", ln)]
         assert not moved, moved
         assert mem.temp_size_in_bytes < 0.5e9, mem.temp_size_in_bytes
-        assert set(re.findall(r"bf16\[512,16,1024\]\{([\d,]+)", text)) == {
-            "2,1,0"}
+        if impl == "paged_kernel":
+            # the dense layer's call and the period's four, a window on three
+            kernel = _grouped_kernel_calls(text, "attn/full", "attn/sliding")
+            assert len(kernel) == 5, len(kernel)
+            assert not re.search(r"bf16\[512,16,1024\]", text)    # no chunk
+        else:
+            assert "grouped_decode_attention" not in text
+            assert set(re.findall(r"bf16\[512,16,1024\]\{([\d,]+)", text)) == {
+                "2,1,0"}
         # 32 slots x 160 blocks x 16 positions: in no dtype, merged or not
         assert not re.search(
             r"\[(32,2560|32,160,16|5120,16|81920),(8,128|1024)\]", text)
@@ -444,7 +474,8 @@ def test_laguna_cell_compiles_for_v5e_and_keeps_the_arenas_in_place(
     assert total < 14.5e9, total
 
 
-@pytest.mark.parametrize("program", ["decode", "prefill1024", "suffix1024"])
+@pytest.mark.parametrize("program", ["decode", "decode-kernel", "prefill1024",
+                                     "suffix1024"])
 def test_solar2_cell_compiles_for_v5e_and_keeps_the_state_in_place(
         sds, monkeypatch, program, capsys):
     """``solar2-cell``, beside ``laguna-cell``: the programs of the benchmark's
@@ -454,7 +485,11 @@ def test_solar2_cell_compiles_for_v5e_and_keeps_the_state_in_place(
     blocks, a state arena of 3 x 128 rows of 64 x 128 x 128 float32),
     compiled for the described v5e: the decode step with the pool's AND the
     state's arenas donated (aliased in place: no copy of the 1.6-GB state, no
-    K/V-arena-shaped copy), the 1,024-token prefill (flash kernel on the
+    K/V-arena-shaped copy) -- through the XLA walk that stays the CPU path
+    and, ``decode-kernel``, as ``decode_attn="auto"`` resolves on the chip:
+    the softmax layer through the Pallas kernel that reads the listed blocks
+    where they lie (``ops.grouped_attention``: a Mosaic custom call, its VMEM
+    inside the limit it asks for) --, the 1,024-token prefill (flash kernel on the
     softmax layer, the chunked scan on the others, state and tail handed out
     beside k and v of the one attention layer) and the suffix prefill that
     starts from a slot's rows.  Prints what the configuration's
@@ -507,16 +542,25 @@ def test_solar2_cell_compiles_for_v5e_and_keeps_the_state_in_place(
     assert arenas[3].shape == (3, 128, 3, 24576)
     arena_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in arenas)
     state_dims = "f32[3,128,64,128,128]"
-    if program == "decode":
+    if program.startswith("decode"):
+        impl = "gather" if program == "decode" else "paged_kernel"
+        # (the kernel asks jax.default_backend(), the CPU here: steered from
+        # the test, as the paged programs' above)
+        monkeypatch.setattr(pa, "_use_interpret", lambda: False)
+
         def step(p, tok, pos, live, temperature, keys, prev_ids, *kv):
             return G._decode_pick_paged(model, p, tok, pos, live, temperature,
                                         keys, prev_ids, *kv,
-                                        table_width=width, attn_impl="gather")
+                                        table_width=width, attn_impl=impl)
 
         compiled, text = _compile(
             step, params, i32(slots), i32(slots), i32(3, slots * width),
             sds((slots,), jnp.float32), sds((slots, 2), jnp.uint32),
             i32(slots), *arenas, donate_argnums=(7, 8, 9, 10))
+        if impl == "paged_kernel":
+            assert len(_grouped_kernel_calls(text, "attn/nope")) == 1
+        else:
+            assert "grouped_decode_attention" not in text
         ids, counts = compiled.out_info[:2]
         assert ids.shape == (slots,) and ids.dtype == jnp.int32
         assert counts.shape == (2,) and len(compiled.out_info) == 6

@@ -26,6 +26,9 @@ TOY = chip_smoke.Sizes(
     prompt_lens=(5, 20, 5, 20), shared_prefix=16, shared_tail=4, max_new=5,
     flash_cases=((1, 2, 64, 16, "float32"),), flash_block=16,
     paged_cases=((2, 2, 16, 8, 4, "float32"),),
+    listed_cases=(("grouped", 4, 6, 2, 16, 8, 6, 20, "float32"),
+                  ("grouped", 4, 18, 2, 16, 16, 5, None, "bfloat16"),
+                  ("latent", 4, 4, 1, 36, 16, 5, None, "bfloat16")),
     resnet_depth=20, resnet_dataset="cifar10", image=32, classes=10,
     train_batch=8, train_iters=4, multichip_batch=8, multichip_iters=3)
 
@@ -97,6 +100,13 @@ def test_serve_and_kernels_phases_pass_at_toy_size(probe, capsys):
     # which is what makes main() fail such a run on the chip
     assert kernels["pallas_calls_traced"] > 0
     assert "paged_decode_attention" in kernels["pallas_interpreted"]
+    listed = [c for c in kernels["cases"] if "gap_to_float64" in c]
+    assert [c["kernel"] for c in listed] == ["grouped decode"] * 2 + [
+        "latent decode"]
+    for c in listed:    # exact products on the CPU, in the kernel and the walk
+        assert max(c["gap_to_float64"].values()) < 1e-5, c
+    assert {"grouped_decode_attention", "latent_decode_attention"} <= set(
+        kernels["pallas_interpreted"])
     assert any(n.startswith("flash_attention")
                for n in kernels["pallas_interpreted"])
     rows = [json.loads(line) for line in capsys.readouterr().out.splitlines()]

@@ -535,7 +535,6 @@ def _recurrent():
     ("recurrent", {"spec": 2}, "spec"),
     ("recurrent", {"migrate": lambda *a: None}, "migrate"),
     ("recurrent", {"kvtier": object()}, "kvtier"),
-    ("recurrent", {"decode_attn": "paged_kernel"}, "paged_kernel"),
     ("latent", {"kv_quant": "int8"}, "kv_quant='int8'"),
     ("latent", {"spec": 2}, "spec"),
     ("latent", {"migrate": lambda *a: None}, "migrate"),
@@ -565,9 +564,29 @@ def test_refusals_at_construction(kind, kw, says):
 def test_every_refusal_is_a_row_of_the_one_table():
     from bigdl_tpu.serving import lm_engine
     rows = lm_engine._REFUSALS
-    assert len(rows) == len({r[:2] for r in rows}) == 12
+    assert len(rows) == len({r[:2] for r in rows}) == 11
     assert {r[0] for r in rows} == set(lm_engine._KIND_NAMES)
     lm_engine.refuse_unsupported(_latent_alone())           # nothing given: silent
+
+
+def test_a_model_with_recurrent_layers_serves_through_the_block_table_kernel():
+    """What the table refused until the kernel read shared K/V heads
+    (``ops.grouped_attention``): the engine builds, keeps the kernel and
+    decodes what the model scores best."""
+    from bigdl_tpu.serving import LMServingEngine
+    model = _recurrent()
+    eng = LMServingEngine(model, slots=2, block_len=4, cache_len=64,
+                          prefill_buckets=(8,), num_blocks=40,
+                          decode_attn="paged_kernel")
+    try:
+        assert eng.decode_attn == eng.stats()["decode_attn"] == "paged_kernel"
+        assert eng.state is not None and len(eng._arenas()) == 4
+        prompt = _ids(13, 3) % 64 + 1
+        out = eng.submit(prompt, max_new_tokens=6).result(timeout=300)
+        logp = np.asarray(model.f(model.params, jnp.asarray(out[None])))[0]
+        assert (logp[12:-1].argmax(-1) + 1 == out[13:]).all()   # greedy, teacher-forced
+    finally:
+        eng.close()
 
 
 def test_a_model_of_latent_layers_alone_serves_and_refuses_adoption():
