@@ -122,9 +122,12 @@ class LayerSpec(NamedTuple):
 
 class MLASpec(NamedTuple):
     """Latent attention's shape, stated once (DeepSeek-V2's multi-head
-    latent attention, no query compression).  A position's keys and values
-    are up-projections of ONE latent ``c`` of ``kv_rank`` lanes (RMS-normed),
-    beside ``rope`` rotated lanes shared by every head::
+    latent attention).  A position's keys and values are up-projections of
+    ONE latent ``c`` of ``kv_rank`` lanes (RMS-normed), beside ``rope``
+    rotated lanes shared by every head; the query is one matrix a layer
+    (``q_rank`` ``None`` or 0) or, COMPRESSED, a down-projection to
+    ``q_rank`` lanes, an RMSNorm and an up-projection (``q = rmsnorm(a W_qa)
+    W_qb``)::
 
         q_h = [q_n (nope) ; q_r (rope)]        [c' ; k_r'] = a W_dkv
         c = rmsnorm(c')     q_r, k_r = rotary(q_r', k_r')
@@ -140,6 +143,7 @@ class MLASpec(NamedTuple):
     nope: int
     rope: int
     v: int
+    q_rank: Optional[int] = None
 
     @property
     def row(self) -> int:
@@ -194,6 +198,7 @@ class TransformerLM(Module):
         "layernorm", 1e-5, "gelu", True, False, None)
     kda_conv = 4
     kda, mla = KDASpec(), None
+    mtp = None
 
     def __init__(self, vocab_size: int, hidden_size: int = 128,
                  n_head: int = 4, n_layers: int = 2,
@@ -214,7 +219,8 @@ class TransformerLM(Module):
                  attn_gate=False, moe=None,
                  layer_plan: Optional[Sequence] = None,
                  kda_conv: int = 4, kda: Optional[KDASpec] = None,
-                 mla: Optional[MLASpec] = None):
+                 mla: Optional[MLASpec] = None,
+                 mtp: Optional[LayerSpec] = None):
         super().__init__()
         assert head_dim is not None or hidden_size % n_head == 0
         if norm not in ("layernorm", "rmsnorm"):
@@ -323,6 +329,22 @@ class TransformerLM(Module):
             raise ValueError("grouped K/V heads, the output gate, routed "
                              "experts and latent attention need a layer_plan")
         self.layer_plan = layer_plan
+        # the PREDICTION MODULE (DeepSeek-V3's multi-token prediction, one
+        # module): one more block, stated by its LayerSpec, that reads the
+        # main model's last hidden state beside the NEXT token's embedding and
+        # scores the token after that through the main model's embedding and
+        # head.  A latent block: what it caches is one more latent row a
+        # position (``serving``: one more arena layer of the target's pool)
+        self.mtp = LayerSpec(*mtp) if mtp is not None else None
+        if self.mtp is not None:
+            if (layer_plan is None or self.mtp.mixer != "mla" or self.mla is None
+                    or self.bias or self.mtp.window
+                    or pos_encoding == "learned"
+                    or (self.mtp.mlp == "moe" and moe is None)):
+                raise ValueError(
+                    "a prediction module is one 'mla' block of a planned "
+                    "model (mla=MLASpec, no biases, no window, no learned "
+                    "positions; a 'moe' half needs moe=MoESpec)")
 
     # -------------------------------------------------------------- #
     @property
@@ -451,8 +473,19 @@ class TransformerLM(Module):
                     wg2=mat(kk[7], (d, inner), 1.0 / math.sqrt(d)))
         elif spec.mixer == "mla":
             m, kk = self.mla, jax.random.split(ks[0], 3)
+            if m.q_rank:
+                # the compressed query: down, an RMSNorm's weight, up
+                kq = jax.random.split(kk[0])
+                query = {"wq_a": mat(kq[0], (h, m.q_rank), std_h),
+                         "q_norm": jnp.ones((m.q_rank,)),
+                         "wq_b": mat(kq[1], (m.q_rank,
+                                             spec.n_head * m.score_dim),
+                                     1.0 / math.sqrt(m.q_rank))}
+            else:
+                query = {"wq": mat(kk[0], (h, spec.n_head * m.score_dim),
+                                   std_h)}
             p["mla"] = {
-                "wq": mat(kk[0], (h, spec.n_head * m.score_dim), std_h),
+                **query,
                 "w_dkv": mat(kk[1], (h, m.row), std_h),
                 "kv_norm": jnp.ones((m.kv_rank,)),
                 # a head's [k_n ; v] side by side
@@ -528,6 +561,13 @@ class TransformerLM(Module):
         if not self.tie_embeddings:
             p["head"] = jax.random.uniform(k_head, (h, v), jnp.float32,
                                            -std, std)
+        if self.mtp is not None:
+            k_eh, k_blk = jax.random.split(jax.random.fold_in(rng, 7))
+            p["mtp"] = {"enorm": self._init_norm(), "hnorm": self._init_norm(),
+                        "eh_proj": jax.random.normal(k_eh, (2 * h, h))
+                        / math.sqrt(2 * h),
+                        "block": self._init_layer(self.mtp, k_blk),
+                        "norm": self._init_norm()}
         return p
 
     # -------------------------------------------------------------- #
@@ -620,10 +660,16 @@ class TransformerLM(Module):
         from bigdl_tpu.nn._util import match_compute_dtype
         from bigdl_tpu.quant.kernels import qmatmul
         mp, m = bp["mla"], self.mla
-        a = match_compute_dtype(self._norm(bp["ln1"], x), mp["wq"])
+        a = match_compute_dtype(self._norm(bp["ln1"], x),
+                                mp["wq_a" if m.q_rank else "wq"])
         b, t = a.shape[:2]
-        q = qmatmul(a, mp["wq"]).reshape(b, t, spec.n_head,
-                                         m.score_dim).transpose(0, 2, 1, 3)
+        if m.q_rank:
+            with jax.named_scope("mla/q_down"):
+                q = self._rms_norm(mp["q_norm"], qmatmul(a, mp["wq_a"]))
+            q = qmatmul(q, mp["wq_b"])
+        else:
+            q = qmatmul(a, mp["wq"])
+        q = q.reshape(b, t, spec.n_head, m.score_dim).transpose(0, 2, 1, 3)
         down = qmatmul(a, mp["w_dkv"])
         c = self._rms_norm(mp["kv_norm"], down[..., :m.kv_rank])
         k_r = down[..., None, :, m.kv_rank:]        # one "head": (B, 1, T, rope)
@@ -872,7 +918,9 @@ class TransformerLM(Module):
         m, rng = drop(m, rng)
         return x + m, aux
 
-    def _forward(self, params, x, training: bool, rng):
+    def _trunk(self, params, x, training: bool, rng):
+        """Embedding and every block: 1-based ``x`` (B, T) -> (the last
+        block's output BEFORE ``ln_f`` (B, T, hidden), aux)."""
         ids = jnp.asarray(x)
         if jnp.issubdtype(ids.dtype, jnp.floating):
             ids = ids.astype(jnp.int32)
@@ -917,7 +965,10 @@ class TransformerLM(Module):
                 (repeat, len(period)) + keys.shape[1:])
             (h, aux), _ = jax.lax.scan(body, (h, aux), (stacks, group_keys))
             done += n
-        h = self._norm(params["ln_f"], h)
+        return h, aux
+
+    def _head(self, params, h):
+        """``ln_f``'s output -> logits through the (tied or own) head."""
         if self.tie_embeddings:
             logits = h @ params["embed"].T.astype(h.dtype)
         else:
@@ -926,8 +977,47 @@ class TransformerLM(Module):
             head = params["head"]
             logits = (qmatmul(h, head) if is_qtensor(head)
                       else h @ head.astype(h.dtype))
+        return logits
+
+    def _forward(self, params, x, training: bool, rng):
+        h, aux = self._trunk(params, x, training, rng)
+        logits = self._head(params, self._norm(params["ln_f"], h))
         logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
         return logp, aux
+
+    # -- the prediction module: pair t is (the main model's hidden state at
+    # -- t, the token at t + 1); its block's output scores the token at t + 2
+    def mtp_embed(self, params, h, next_ids0):
+        """A pair's input: ``h`` (..., hidden) the main model's last block's
+        output BEFORE ``ln_f``, ``next_ids0`` (...,) 0-based ids of the tokens
+        that follow -> ``[rmsnorm_e(Emb(x)) ; rmsnorm_h(h)] W_eh`` (...,
+        hidden), the embedding's half first."""
+        from bigdl_tpu.quant.kernels import qmatmul
+        mp = params["mtp"]
+        with jax.named_scope("mtp/embed_proj"):
+            e = self._norm(mp["enorm"], params["embed"][next_ids0])
+            z = jnp.concatenate(
+                [e, self._norm(mp["hnorm"], h).astype(e.dtype)], -1)
+            return qmatmul(z, mp["eh_proj"])
+
+    def mtp_logits(self, params, g):
+        """The module's block's output ``g`` -> draft logits, through its own
+        final norm and the MAIN model's head."""
+        with jax.named_scope("mtp/head"):
+            return self._head(params, self._norm(params["mtp"]["norm"], g))
+
+    def mtp_forward(self, params, x):
+        """The module over whole sequences: 1-based ``x`` (B, T) -> logits (B,
+        T - 1, vocab) float32, row t (from the pair of the main model's hidden
+        state at t and the token at t + 1, causal over the pairs before it,
+        rotated at t) scoring the token at t + 2."""
+        ids0 = jnp.asarray(x).astype(jnp.int32) - 1
+        h, _ = self._trunk(params, x, False, None)
+        z = self.mtp_embed(params, h[:, :-1], ids0[:, 1:])
+        with jax.named_scope("mtp/block"):
+            g, _ = self._block(self.mtp, params["mtp"]["block"], z, False, None,
+                               jnp.arange(z.shape[1]))
+        return self.mtp_logits(params, g).astype(jnp.float32)
 
     def f(self, params, x, *, training: bool = False, rng=None):
         return self._forward(params, x, training, rng)[0]

@@ -37,18 +37,11 @@ from bigdl_tpu.serving.kvcache.blocks import (SCRATCH_BLOCK, head_columns,
 
 
 def _head_logits(model, params, h):
-    """LM-head matmul shared by every decode/prefill/verify path —
-    QTensor-aware so an int8-compute drafter's untied head runs on the
-    int8 MXU path (tied heads ride the f32 embedding, which the quant
-    policy never touches)."""
-    from bigdl_tpu.quant import is_qtensor
-    from bigdl_tpu.quant.kernels import qmatmul
-    if model.tie_embeddings:
-        return h @ params["embed"].T.astype(h.dtype)
-    head = params["head"]
-    if is_qtensor(head):
-        return qmatmul(h, head)
-    return h @ head.astype(h.dtype)
+    """LM-head matmul shared by every decode/prefill/verify path
+    (``TransformerLM._head``) -- QTensor-aware so an int8-compute drafter's
+    untied head runs on the int8 MXU path (tied heads ride the f32 embedding,
+    which the quant policy never touches)."""
+    return model._head(params, h)
 
 
 def _finish_block(model, spec, bp, h, o, gate, token_mask=None):
@@ -153,18 +146,43 @@ def _scan_prefill(model, params, h, layer_fn):
             whole(kept["kda"]), counts)
 
 
-def _prefill_result(model, logits, kv, state, counts):
+def _prefill_result(model, logits, kv, state, counts, h_last=None):
     """What a prefill hands back: (logits, k, v), or (logits, rows) of a
     model whose pool is latent; with routed expert layers in the model their
     integers ride out behind those, with recurrent layers each one's state
-    and convolution tail at the prompt's true end behind those."""
+    and convolution tail at the prompt's true end behind those, and last,
+    where the prediction module drafts (``h_last``), the main model's hidden
+    state at the prompt's true end."""
     out = (logits.astype(jnp.float32),) + tuple(kv)
     if model.moe_layers:
         out += (counts,)
-    return out + tuple(state)
+    out += tuple(state)
+    return out if h_last is None else out + (h_last,)
 
 
-def _prefill_parts(model, params, ids0, last_index):
+def _mtp_rows(model, params, h, h_prev, ids0, positions, kv, last_index):
+    """What the prediction module's block caches of a prefilled chunk, ONE
+    MORE LATENT LAYER of rows behind the main layers': the row stored at
+    position j is the pair's of (the main model's hidden state at j - 1, the
+    token at j), rotated at j - 1 -- stored one position on, so that a row
+    depends on the tokens up to its OWN position alone and a shared prefix's
+    rows are every sharer's.  ``h`` (B, T, hidden) the chunk's hidden states
+    before ``ln_f``, ``h_prev`` (B, hidden) the one before the chunk (zeros at
+    a prompt's start: position 0 holds no pair, and no query sees it),
+    ``positions`` (T,) the chunk's own, ``kv`` the main layers' ``(rows,)``.
+    The block's rows need no attention: a row is its input's projection.
+    -> (``(rows (L + 1, B, T, lanes),)``, the hidden state at ``last_index``
+    (B, hidden): the next chunk's ``h_prev``, a slot's first pair's)."""
+    hs = jnp.concatenate([h_prev[:, None].astype(h.dtype), h[:, :-1]], axis=1)
+    z = model.mtp_embed(params, hs, ids0)
+    with jax.named_scope("mtp/block"):
+        row = model.mla_inputs(model.mtp, params["mtp"]["block"], z,
+                               positions - 1)[1]
+    h_last = lax.dynamic_slice_in_dim(h, last_index, 1, axis=1)[:, 0]
+    return (jnp.concatenate([kv[0], row[None].astype(kv[0].dtype)]),), h_last
+
+
+def _prefill_parts(model, params, ids0, last_index, *, mtp: bool = False):
     """Run a (possibly padded) prompt once; return (logits at
     ``last_index``, k, v) with k/v (L, B, H, T, D) — T the prompt width
     as given, NOT padded to any cache length (the caller pads for the
@@ -202,10 +220,15 @@ def _prefill_parts(model, params, ids0, last_index):
         return h, (k, v), c
 
     h, kv, state, counts = _scan_prefill(model, params, h, layer_fn)
+    h_last = None
+    if mtp:     # the prediction module drafts: its rows, one layer more
+        kv, h_last = _mtp_rows(
+            model, params, h, jnp.zeros((b, h.shape[-1]), h.dtype), ids0,
+            positions, kv, last_index)
     h = lax.dynamic_slice_in_dim(h, last_index, 1, axis=1)
     h = model._norm(params["ln_f"], h)
     logits = _head_logits(model, params, h)[:, 0]
-    return _prefill_result(model, logits, kv, state, counts)
+    return _prefill_result(model, logits, kv, state, counts, h_last)
 
 
 def _prefill(model, params, ids0, cache_len):
@@ -555,6 +578,8 @@ def _scan_layers(model, params, h, arenas, layer_fn):
 
 
 def _arenas(k_arena, v_arena, k_scale, v_scale):
+    if v_arena is None:         # a latent pool's one arena
+        return (k_arena,)
     return ((k_arena, v_arena) if k_scale is None
             else (k_arena, v_arena, k_scale, v_scale))
 
@@ -596,7 +621,8 @@ def _latent_prefix_parts(model, bp, q, arena, layer, blocks, prefix_len):
 
 def _prefill_suffix_parts(model, params, ids0, last_index, prefix_len,
                           blocks, k_arena, v_arena=None,
-                          k_scale=None, v_scale=None, *, carried=()):
+                          k_scale=None, v_scale=None, *, carried=(),
+                          h_prev=None):
     """Prefill a prompt SUFFIX against a cached prefix held in paged KV
     blocks: ``ids0`` (1, Ts) is the (bucket-padded) suffix, whose tokens
     live at absolute positions ``prefix_len + i``; ``blocks`` (Pb,) is
@@ -625,7 +651,10 @@ def _prefill_suffix_parts(model, params, ids0, last_index, prefix_len,
 
     A latent pool's one arena comes as ``k_arena`` (``v_arena`` None): a
     latent layer attends its prefix through :func:`_latent_prefix_parts` and
-    its own rows causally, one softmax, and hands out its suffix ROWS."""
+    its own rows causally, one softmax, and hands out its suffix ROWS.
+    ``h_prev`` (1, hidden), where the prediction module drafts: the main
+    model's hidden state at ``prefix_len - 1``, the first pair's
+    (:func:`_mtp_rows`)."""
     from bigdl_tpu.nn.attention import (_finalize, dot_product_attention,
                                         online_softmax_update)
 
@@ -687,10 +716,14 @@ def _prefill_suffix_parts(model, params, ids0, last_index, prefix_len,
         return h, (k, v), c
 
     h, kv, state, counts = _scan_prefill(model, params, h, layer_fn)
+    h_last = None
+    if h_prev is not None:
+        kv, h_last = _mtp_rows(model, params, h, h_prev, ids0, positions,
+                                    kv, last_index)
     h = lax.dynamic_slice_in_dim(h, last_index, 1, axis=1)
     h = model._norm(params["ln_f"], h)
     logits = _head_logits(model, params, h)[:, 0]
-    return _prefill_result(model, logits, kv, state, counts)
+    return _prefill_result(model, logits, kv, state, counts, h_last)
 
 
 def _insert_blocks(k_arena, v_arena, k_new, v_new, block_ids,
@@ -736,6 +769,39 @@ def _insert_rows(arena, new, block_ids):
     x = jnp.pad(new[:, 0], ((0, 0), (0, nb * B - new.shape[2]), (0, 0)))
     return (write_rows(arena, slice(None), block_ids, None,
                        x.reshape(x.shape[0], nb, B, x.shape[2])),)
+
+
+def _latent_rows(model, spec, bp, h, layer, arenas, positions, blk, off, live,
+                 mask, token_mask, kernel=None):
+    """A latent layer over the W new rows a slot of a cached step (1: decode;
+    the candidate rows of a verify step), ABSORBED: every head's query folded
+    through ``W_uk`` meets the one cached row a position, ``W_uv`` after the
+    softmax.  ``h`` (S, W, hidden); ``positions`` (S, 1, W) where the rows
+    rotate; ``(blk, off)`` (S, W) where they are stored.  ``kernel`` ``None``:
+    the live list's walk under ``mask`` (n, W, B) (:func:`_paged_attention`,
+    the CPU path and the oracle); else ``(tables, lengths, first)``: the
+    Pallas kernel that reads the listed blocks where they lie, the new rows
+    written first (``ops.latent_attention``: row i of a slot sees positions
+    ``first <= p < lengths + i``).  -> (h, arenas, counts)."""
+    m = model.mla
+    q, row, gate = model.mla_inputs(spec, bp, h, positions)     # (S, H, W, ..)
+    q = model.mla_absorb(bp, q)
+    with jax.named_scope("mla/attend"):
+        if kernel is not None:
+            from bigdl_tpu.ops import latent_decode_attention
+            tables, lengths, first = kernel
+            arenas = (write_rows(arenas[0], layer, blk, off, row[:, :, None]),)
+            u = latent_decode_attention(
+                q, arenas[0], tables, lengths, score_dim=m.score_dim,
+                layer=layer, value_lanes=m.kv_rank, first=first)
+        else:
+            u, arenas = _paged_attention(q, row[:, None], None, arenas, layer,
+                                         blk, off, live, mask,
+                                         score_dim=m.score_dim)
+    o = model.mla_values(bp, u[..., :m.kv_rank])
+    h, counts = _finish_block(model, spec, bp, h, o.astype(h.dtype), gate,
+                              token_mask=token_mask)
+    return h, arenas, counts
 
 
 def _decode_step_paged(model, params, token, pos, live, *arenas,
@@ -835,29 +901,13 @@ def _decode_step_paged(model, params, token, pos, live, *arenas,
         return h, kv + recurrent, counts
 
     def latent_layer(spec, h, bp, layer, arenas):
-        # ABSORBED: every head's query folded through W_uk meets the one
-        # cached row a position; W_uv after the softmax
-        m = model.mla
-        q, row, gate = model.mla_inputs(spec, bp, h, positions)  # (S, H, 1, ..)
-        q = model.mla_absorb(bp, q)
-        with jax.named_scope("mla/attend"):
-            if attn_impl == "paged_kernel":
-                # the listed blocks read where they lie, the new row first
-                from bigdl_tpu.ops import latent_decode_attention
-                arenas = (write_rows(arenas[0], layer, blk, off,
-                                     row[:, :, None]),)
-                u = latent_decode_attention(
-                    q, arenas[0], tables, jnp.where(active, pos + 1, 0),
-                    score_dim=m.score_dim, layer=layer, value_lanes=m.kv_rank)
-            else:
-                u, arenas = _paged_attention(q, row[:, None], None, arenas,
-                                             layer, blk, off, live,
-                                             masks[None],
-                                             score_dim=m.score_dim)
-        o = model.mla_values(bp, u[..., :m.kv_rank])
-        h, counts = _finish_block(model, spec, bp, h, o.astype(h.dtype), gate,
-                                  token_mask=active[:, None])
-        return h, arenas, counts
+        # ABSORBED, one new row a slot: the listed blocks read where they
+        # lie (the kernel), or the live list's walk
+        return _latent_rows(
+            model, spec, bp, h, layer, arenas, positions, blk, off, live,
+            masks[None], active[:, None],
+            (tables, jnp.where(active, pos + 1, 0), 0)
+            if attn_impl == "paged_kernel" else None)
 
     def attention_layer(spec, h, bp, layer, arenas):
         q, k, v, gate = model.layer_qkv(spec, bp, h, positions)  # (S, H, 1, D)
@@ -942,7 +992,7 @@ def _decode_pick_paged(model, params, token, pos, live, temperature, keys,
 
 
 def _verify_step_paged(model, params, tokens, pos, n_cand, tables,
-                       k_arena, v_arena, k_scale=None, v_scale=None):
+                       k_arena, v_arena=None, k_scale=None, v_scale=None):
     """Speculative VERIFY over paged caches: score all W = k+1 candidate
     rows per slot in one fixed-shape step.  ``tokens`` (S, W) int32
     0-based — row layout ``[last_emitted, draft_1 .. draft_k]`` — and
@@ -1002,6 +1052,9 @@ def _verify_rows(model, params, tokens, n_cand, tables, live, arenas, *,
     valid = jnp.arange(w)[None, :] < n_cand[:, None]
 
     def layer_fn(spec, h, bp, layer, arenas):
+        if spec.mixer == "mla":     # W candidate rows a slot, absorbed
+            return _latent_rows(model, spec, bp, h, layer, arenas, positions,
+                                blk, off, live, masks[None], valid)
         q, k, v, gate = model.layer_qkv(spec, bp, h, positions)  # (S, H, W, D)
         o, arenas = _paged_attention(q, k, v, arenas, layer, blk, off,
                                      live, masks[spec.window])
@@ -1013,6 +1066,115 @@ def _verify_rows(model, params, tokens, n_cand, tables, live, arenas, *,
     h = model._norm(params["ln_f"], h)
     logits = _head_logits(model, params, h)      # (S, W, V)
     return (logits.astype(jnp.float32),) + arenas
+
+
+def _selfdraft_step_paged(model, params, tokens, pos, n_cand, fresh,
+                          temperature, keys, hid, live, *arenas,
+                          table_width: int, attn_impl: str = "gather",
+                          with_logits: bool = False):
+    """One SELF-DRAFTING round over a latent pool: the model's own prediction
+    module (``TransformerLM.mtp``) is the drafter, through the target's pool
+    (its block's rows are the arena's last layer, :func:`_mtp_rows`' layout).
+    ``tokens`` (S, 2) are ``[x, d]``: a slot's last emitted token, at position
+    ``pos``, and the draft for ``pos + 1``; ``n_cand`` (S,) 2 where the draft
+    is to be verified, 1 for a plain row, 0 for an idle slot.
+
+    1. VERIFY: the main model scores both rows (W = 2, absorbed, rows written
+       at ``pos`` and ``pos + 1``), and the step picks ``y0`` and ``y1`` from
+       them (:func:`pick_rows`, the slot's step keys ``keys[:, 0]`` and ``[:,
+       1]``); ``accepted = (y0 == d)``: the slot emits ``y0``, and ``y1`` too
+       if accepted.  A rejected row is a pointer rewind: it stays above the
+       slot's position, masked until overwritten
+       (:func:`_verify_step_paged`'s invariant).
+    2. DRAFT: the module runs over the NEW pairs -- (hidden at ``pos``, y0)
+       and, if accepted, (hidden at ``pos + 1``, y1); for a ``fresh`` slot,
+       seated by a prefill, the pair before them first, (``hid``: the
+       prefill's hidden state at ``pos - 1``, x) -- writes their rows one
+       position on in its arena layer, attends ABSORBED over the pairs before
+       (positions 1 ..), and the next round's draft is picked from the last
+       valid pair's logits under the key that will verify it (``keys[:, 2 +
+       accepted]``).
+
+    ``hid`` (S, hidden) is read for fresh slots alone.  ``live``: the round's
+    live list, reaching the block of ``pos + 2``.  -> (``out`` (S, 4) int32
+    ``[y0, y1, accepted, draft]``, [the routed layers' integers, the module's
+    block's among them], *arenas[, the logits (S, 2, V) and the draft logits
+    (S, V) ``with_logits``: what the tests read]): no output of a serving
+    round has the vocabulary's width."""
+    if attn_impl not in ("gather", "paged_kernel"):
+        raise ValueError(f"attn_impl must be 'gather' or 'paged_kernel', "
+                         f"got {attn_impl!r}")
+    s, w = tokens.shape
+    B = arenas[0].shape[2]
+    mtp_layer = len(model.latent_layers)
+    kernel = attn_impl == "paged_kernel"
+    ids, owner, where = live
+    tables = jnp.zeros((s, table_width), jnp.int32).at[owner, where].set(
+        ids, mode="drop")
+    j = jnp.arange(w)
+    active = n_cand > 0
+    k_pos = where[:, None] * B + jnp.arange(B)[None, :]
+
+    def place(store, ok):
+        # where a row is stored; one that is not to be kept, in scratch
+        blk = tables[jnp.arange(s)[:, None],
+                     jnp.minimum(store // B, table_width - 1)]
+        return jnp.where(ok, blk, SCRATCH_BLOCK), store % B
+
+    def attend(store, first):
+        # (mask of the walk, operands of the kernel): row i of a slot sees
+        # the positions ``first <= p <= store[:, i]``
+        if kernel:
+            return None, (tables, jnp.where(active, store[:, 0] + 1, 0), first)
+        return (_list_masks(model, live, store, B)[None]
+                & (k_pos >= first)[:, None, :]), None
+
+    # -- 1. verify ------------------------------------------------------------
+    abspos = pos[:, None] + j[None, :]
+    valid = j[None, :] < n_cand[:, None]
+    blk, off = place(abspos, valid)
+    mask, operands = attend(abspos, 0)
+    h = params["embed"][tokens]                             # (S, W, hidden)
+
+    def layer_fn(spec, h, bp, layer, arenas):
+        return _latent_rows(model, spec, bp, h, layer, arenas,
+                            abspos[:, None, :], blk, off, live, mask, valid,
+                            operands)
+
+    h, arenas, counts = _scan_layers(model, params, h, arenas, layer_fn)
+    logits = _head_logits(model, params, model._norm(params["ln_f"], h)
+                          ).astype(jnp.float32)             # (S, W, V)
+    y0 = pick_rows(logits[:, 0], temperature, keys[:, 0])
+    y1 = pick_rows(logits[:, 1], temperature, keys[:, 1])
+    accepted = (n_cand == 2) & (y0 == tokens[:, 1])
+    # -- 2. draft -------------------------------------------------------------
+    hs = jnp.where(fresh[:, None, None],
+                   jnp.stack([hid.astype(h.dtype), h[:, 0]], axis=1), h)
+    toks = jnp.where(fresh[:, None], jnp.stack([tokens[:, 0], y0], axis=1),
+                     jnp.stack([y0, y1], axis=1))
+    store = abspos + jnp.where(fresh, 0, 1)[:, None]
+    n_pairs = jnp.where(active, jnp.where(fresh, 2, 1 + accepted), 0)
+    pvalid = j[None, :] < n_pairs[:, None]
+    pblk, poff = place(store, pvalid)
+    pmask, poperands = attend(store, 1)
+    z = model.mtp_embed(params, hs, toks)
+    with jax.named_scope("mtp/block"):
+        g, arenas, c = _latent_rows(
+            model, model.mtp, params["mtp"]["block"], z, mtp_layer, arenas,
+            (store - 1)[:, None, :], pblk, poff, live, pmask, pvalid,
+            poperands)
+    counts = counts + c
+    last = jnp.clip(n_pairs - 1, 0, w - 1)
+    g = jnp.take_along_axis(g, last[:, None, None], axis=1)[:, 0]
+    draft_logits = model.mtp_logits(params, g).astype(jnp.float32)  # (S, V)
+    draft_key = jnp.take_along_axis(
+        keys[:, 2:4], accepted.astype(jnp.int32)[:, None, None], axis=1)[:, 0]
+    draft = pick_rows(draft_logits, temperature, draft_key)
+    out = (jnp.stack([y0, y1, accepted.astype(jnp.int32), draft], axis=1),)
+    if model.moe_layers or model.mtp.mlp == "moe":
+        out += (counts,)
+    out += tuple(arenas)
+    return out + (logits, draft_logits) if with_logits else out
 
 
 def _tree_verify_step_paged(model, params, tokens, pos, n_cand, tables,

@@ -85,11 +85,17 @@ def _summed(x, n: int):
 
 def _latent_kernel(tbl_ref, len_ref, layer_ref, q_ref, arena_ref, o_ref,
                    buf, sem, q_scr, top_scr, den_scr, acc_scr, *, fetch: int,
-                   block_len: int, score_dim: int, pieces: int):
+                   block_len: int, score_dim: int, pieces: int,
+                   n_rows: int = 1, first: int = 0):
     s, j = pl.program_id(0), pl.program_id(1)
     span = fetch * block_len                    # positions a step holds
     length = len_ref[s]
-    steps = (length + span - 1) // span         # of this slot; 0 when idle
+    if n_rows == 1:
+        steps = (length + span - 1) // span     # of this slot; 0 when idle
+    else:
+        # a slot's last query row sees ``n_rows - 1`` positions further
+        steps = jnp.where(length > 0,
+                          (length + n_rows - 1 + span - 1) // span, 0)
     precision = lax.Precision.HIGHEST if pieces == 1 else None
 
     def copies(step, slot):
@@ -130,7 +136,14 @@ def _latent_kernel(tbl_ref, len_ref, layer_ref, q_ref, arena_ref, o_ref,
             preferred_element_type=jnp.float32), pieces)    # (H, span)
         scores = scores / jnp.sqrt(jnp.float32(score_dim))
         k_pos = j * span + lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-        seen = k_pos < length
+        if n_rows == 1:
+            seen = k_pos < length
+        else:
+            # query row r = head * n_rows + i is the slot's i-th position
+            seen = k_pos < length + lax.rem(
+                lax.broadcasted_iota(jnp.int32, scores.shape, 0), n_rows)
+        if first:
+            seen = seen & (k_pos >= first)
         scores = jnp.where(seen, scores, -1e30)
         top = jnp.maximum(top_scr[...], jnp.max(scores, axis=1, keepdims=True))
         old = jnp.exp(top_scr[...] - top)
@@ -163,12 +176,16 @@ def check_latent_kernel_shapes(block_len: int, lanes: int, dtype) -> None:
 def latent_decode_attention(q, arena, tables, lengths, *, score_dim: int,
                             layer=None, value_lanes=None,
                             blocks_per_step: int = BLOCKS_PER_STEP,
-                            interpret=None):
+                            first: int = 0, interpret=None):
     """One decode step of absorbed latent attention, reading the latent rows
     in place.
 
-    q: (S, H, 1, D) or (S, H, D) float32 absorbed queries, D the row's own
-    lanes (``kv_rank + rope``); arena: the latent pool's arena, whole --
+    q: (S, H, W, D) or (S, H, D) float32 absorbed queries, D the row's own
+    lanes (``kv_rank + rope``), W (static) the query POSITIONS a slot: 1 for
+    a decode step, the candidate rows of a verify step, whose row i sees
+    ``lengths + i`` positions (W = 1 is the kernel as it was, operation for
+    operation); ``first`` (static): the first position any query sees (a
+    prediction module's rows start at 1); arena: the latent pool's arena, whole --
     (L, N, block_len, lanes) with ``layer`` the (traced) layer to attend -- or
     one layer's (N, block_len, lanes); tables: (S, M) int32 block ids by slot
     (scratch-padded past the live prefix); lengths: (S,) int32, the positions
@@ -179,7 +196,11 @@ def latent_decode_attention(q, arena, tables, lengths, *, score_dim: int,
     rows, float32, shaped like q but ``value_lanes`` wide.
     """
     squeeze = q.ndim == 4
-    q3 = (q[:, :, 0, :] if squeeze else q).astype(jnp.float32)
+    rows = q.shape[2] if squeeze else 1
+    heads = q.shape[1]
+    # (head, position) pairs as the kernel's query rows, head-major
+    q3 = (q.reshape(q.shape[0], heads * rows, q.shape[3]) if squeeze
+          else q).astype(jnp.float32)
     s, h, d = q3.shape
     arena, layer = _paged._arena_layer(arena, layer)
     blk, w = arena.shape[2:]
@@ -219,7 +240,8 @@ def latent_decode_attention(q, arena, tables, lengths, *, score_dim: int,
             pltpu.VMEM((h, out_w), jnp.float32),
         ])
     kernel = functools.partial(_latent_kernel, fetch=fetch, block_len=blk,
-                               score_dim=score_dim, pieces=pieces)
+                               score_dim=score_dim, pieces=pieces, n_rows=rows,
+                               first=int(first))
     # the two fetch buffers (and, float32 rows, the pieces the highest
     # precision splits a step's rows into), a step's scores, weights and their
     # pieces (pieces * H, span) a few times over, the queries, the accumulators
@@ -236,4 +258,4 @@ def latent_decode_attention(q, arena, tables, lengths, *, score_dim: int,
     )(tables, lengths.astype(jnp.int32),
       jnp.asarray(layer, jnp.int32).reshape(1), q3, arena)
     o = o[:, :, :value_lanes]
-    return o[:, :, None, :] if squeeze else o
+    return o.reshape(s, heads, rows, value_lanes) if squeeze else o

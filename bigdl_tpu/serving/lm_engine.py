@@ -70,6 +70,23 @@ tuple is ``(rows,)`` + the state arenas, a prefill hands out rows
 the decode step attends ABSORBED through the same live list.  What assumes a
 ``(k, v)`` pair is refused at construction (:func:`refuse_unsupported`).
 
+Self-drafting: a model that states a PREDICTION MODULE
+(``TransformerLM(mtp=...)``) and is given ``spec=SpecConfig(k=1)`` drafts
+for itself (``serving.spec.SelfDrafter``): the module's block's rows are
+one more layer of the latent arena (a prefill fills them beside the main
+layers', one position on, so that a radix hit shares them with the
+prefix), and a round is ONE program that verifies two candidate rows a
+slot, picks, runs the module's pairs and drafts
+(``generate._selfdraft_step_paged``): ``(S, 4)`` ids and counts reach the
+host, a slot advances by 1 or 2, a rejected draft is a pointer rewind.
+Such a round runs one ahead as a plain one does (``_dispatch_selfdraft`` /
+``_collect_selfdraft``): a slot of the round on the device is CHAINED, its
+tokens, how far it advanced and its ``n_cand`` taken from that round's
+output on the device (``split_selfdraft_operands``), its live list
+reaching the furthest it can have advanced; a slot that may have ended
+with the round in flight sits the next one out, and a sampled slot keeps
+the rounds synchronous (its keys follow how many tokens it emitted).
+
 Sharing: the radix cache maps token prefixes to refcounted block
 chains, so concurrent requests with a common head attend the SAME
 blocks copy-free; decode always writes into a sequence's private tail
@@ -199,11 +216,71 @@ def split_decode_operands(ops, slots: int):
             ops[5 * s:].reshape(3, -1))
 
 
-#: what a cache kind other than a paged ``(k, v)`` pair cannot do: a
+def selfdraft_operands(slots: int, entries: int):
+    """:func:`decode_operands` for a SELF-DRAFTING round (the model's own
+    prediction module as the drafter), one int32 vector as well: ``->
+    (operands, tokens (S, 2) [last emitted, draft], pos (S,), n_cand (S,),
+    fresh (S,), temperature (S,) float32, keys (S, 4, 2) uint32, live (3,
+    entries), chain (S,), remaining (S,))``.  A slot with ``chain`` set
+    rides a round enqueued BEHIND the one on the device: its ``pos`` and
+    ``remaining`` (tokens left of its count) are as of before that round,
+    and the step takes its tokens, its position and its ``n_cand`` from
+    that round's ``(S, 4)`` output, which has not reached the host
+    (:func:`split_selfdraft_operands` on the device)."""
+    s = int(slots)
+    ops = np.zeros((16 * s + 3 * int(entries),), np.int32)
+    return (ops, ops[:2 * s].reshape(s, 2), ops[2 * s:3 * s],
+            ops[3 * s:4 * s], ops[4 * s:5 * s],
+            ops[5 * s:6 * s].view(np.float32),
+            ops[6 * s:14 * s].view(np.uint32).reshape(s, 4, 2),
+            ops[16 * s:].reshape(3, -1), ops[14 * s:15 * s],
+            ops[15 * s:16 * s])
+
+
+def split_selfdraft_operands(ops, slots: int, prev=None):
+    """-> ``(tokens, pos, n_cand, fresh (bool), temperature, keys, live)``.
+    ``prev`` (S, 4) is the previous round's output ``[y0, y1, accepted,
+    draft]``, still on the device: a ``chain`` slot emitted ``1 +
+    accepted`` tokens in it, so its last token is ``y1`` where the draft was
+    accepted and ``y0`` where not, at that many positions further; it
+    verifies that round's draft where two tokens or more are left of its
+    count (the host's own rule for ``n_cand``), and the host hands such a
+    round only slots with one left at least."""
+    import jax.numpy as jnp
+    from jax import lax
+    s = int(slots)
+    tokens, pos, n_cand = ops[:2 * s].reshape(s, 2), ops[2 * s:3 * s], ops[3 * s:4 * s]
+    if prev is not None:
+        chain, remaining = ops[14 * s:15 * s] > 0, ops[15 * s:16 * s]
+        accepted = prev[:, 2]
+        last = jnp.where(accepted > 0, prev[:, 1], prev[:, 0])
+        tokens = jnp.where(chain[:, None],
+                           jnp.stack([last, prev[:, 3]], axis=1), tokens)
+        pos = pos + jnp.where(chain, 1 + accepted, 0)
+        n_cand = jnp.where(
+            chain, jnp.where(remaining - 1 - accepted >= 2, 2, 1), n_cand)
+    return (tokens, pos, n_cand, ops[4 * s:5 * s] > 0,
+            lax.bitcast_convert_type(ops[5 * s:6 * s], jnp.float32),
+            lax.bitcast_convert_type(ops[6 * s:14 * s],
+                                     jnp.uint32).reshape(s, 4, 2),
+            ops[16 * s:].reshape(3, -1))
+
+
+#: what a cache kind other than a paged ``(k, v)`` pair cannot do, and what
+#: the model's own prediction module cannot do as the drafter: a
 #: row a (kind, what is asked, why); the next cache kind adds rows, not branches
 _KIND_NAMES = {"recurrent": ("recurrent layers", "M6"),
-               "latent": ("latent attention layers", "M4")}
+               "latent": ("latent attention layers", "M4"),
+               "self-drafting": ("its prediction module as the drafter",
+                                 "M5")}
 _REFUSALS = (
+    ("self-drafting", "serve with tree verify", "one module drafts one token "
+     "a round: there is no runner-up to branch on"),
+    ("self-drafting", "serve with rejection sampling", "the round picks on "
+     "the device with the slot's own keys (replay), and hands the host no "
+     "drafter distribution to form p / q from"),
+    ("self-drafting", "serve with k > 1", "one prediction module scores one "
+     "token past the next"),
     ("recurrent", "serve with spec", "a rejected draft would need the "
      "recurrent state rolled back, and only the K/V pointer rewinds"),
     ("recurrent", "serve with migrate", "the handoff carries (k, v) chains, "
@@ -216,8 +293,8 @@ _REFUSALS = (
      "chains, not a recurrent layer's state"),
     ("latent", "serve with kv_quant='int8'", "the int8 pool keeps a scale a "
      "(position, head), and a latent row has no head"),
-    ("latent", "serve with spec", "the verify steps attend (k, v) rows; no "
-     "absorbed verify step is written"),
+    ("latent", "serve with tree verify", "the accepted path's commit moves "
+     "(k, v) rows; none is written for a latent row"),
     ("latent", "serve with migrate", "the handoff's wire format is a (k, v) "
      "pair, and the pool holds one latent row a position"),
     ("latent", "serve with kvtier", "the host tier's wire format is a (k, v) "
@@ -229,15 +306,34 @@ _REFUSALS = (
 )
 
 
+def drafts_for_itself(model, spec) -> bool:
+    """Whether ``spec`` (a ``SpecConfig``, an int k, or None) makes the
+    model's own prediction module the drafter: the model has one, and no
+    other drafter is named (no ``draft`` model, not the n-gram one)."""
+    if spec is None or getattr(model, "mtp", None) is None:
+        return False
+    return (isinstance(spec, int)
+            or (spec.draft is None
+                and getattr(spec, "drafter_compute", None) != "ngram"))
+
+
 def refuse_unsupported(model, *, spec=None, migrate=None, kvtier=None,
                        kv_quant=None, placement=None, adopt=False):
     """Raise, with its one message, for the first thing asked that the
-    model's cache kinds cannot do (:data:`_REFUSALS`): THE place where a
-    recurrent state and a latent pool refuse what assumes a paged ``(k, v)``
-    pair, at construction and where a handoff arrives (``adopt``)."""
+    model's cache kinds, or its own prediction module as the drafter, cannot
+    do (:data:`_REFUSALS`): THE place where a recurrent state and a latent
+    pool refuse what assumes a paged ``(k, v)`` pair, and a self-drafting
+    model what one module cannot draft, at construction and where a handoff
+    arrives (``adopt``)."""
     has = {"recurrent": bool(model.state_layers),
-           "latent": bool(model.latent_layers)}
+           "latent": bool(model.latent_layers),
+           "self-drafting": drafts_for_itself(model, spec)}
+    k = spec if isinstance(spec, int) else getattr(spec, "k", 1)
     asked = {"serve with spec": spec is not None,
+             "serve with tree verify": bool(getattr(spec, "tree", False)),
+             "serve with rejection sampling":
+                 getattr(spec, "sampling", "replay") == "rejection",
+             "serve with k > 1": spec is not None and k > 1,
              "serve with migrate": migrate is not None,
              "serve with kvtier": kvtier is not None,
              "serve with kv_quant='int8'": kv_quant == "int8",
@@ -329,6 +425,10 @@ class LMStream:
         self._cancel_requested = False
         self._cancel_at_gen = 0         # generated length when cancelled
         self._wake_cb = None            # engine nudge, set at enqueue
+        #: a self-drafting engine's record: ``(i, id)`` for every draft it
+        #: verified -- the 1-based id its prediction module drafted for
+        #: generated token ``i`` (accepted iff that token is the id)
+        self.drafts: List[tuple] = []
 
     # lifecycle ---------------------------------------------------------- #
     def cancel(self) -> bool:
@@ -516,6 +616,10 @@ class LMMetrics:
         self.latent_rows_read = 0
         self.latent_bytes_read = 0
         self.latent_row_bytes = 0
+        # admissions: prompt tokens admitted and, of them, those a radix hit
+        # found cached (what the prefills did not compute)
+        self.prompt_tokens = 0
+        self.prefix_matched_tokens = 0
         # recurrent layers (zero for a model without): (slot, recurrent
         # layer) rows the decode rounds read and wrote, the rows that hold a
         # seated request's state now, and the state arena's bytes
@@ -543,7 +647,8 @@ class LMMetrics:
             registry.register(prefix + key,
                               FnGauge(lambda k=key: getattr(self, k)),
                               replace=True)
-        for key in ("latent_rows_read", "latent_bytes_read"):
+        for key in ("latent_rows_read", "latent_bytes_read", "prompt_tokens",
+                    "prefix_matched_tokens"):
             registry.register(prefix + key,
                               FnGauge(lambda k=key: getattr(self, k)),
                               replace=True)
@@ -619,6 +724,13 @@ class LMMetrics:
     def record_complete(self) -> None:
         with self._lock:
             self.completed += 1
+
+    def record_admission(self, prompt_tokens: int, matched: int) -> None:
+        """A request got its blocks: its prompt's length and how much of it
+        the radix cache held."""
+        with self._lock:
+            self.prompt_tokens += int(prompt_tokens)
+            self.prefix_matched_tokens += int(matched)
 
     def record_logit_rows(self, rows: int) -> None:
         """``rows`` rows of V float32 logits were copied to the host."""
@@ -736,6 +848,8 @@ class LMMetrics:
                         "expert_layer_rounds": self.moe_expert_layer_rounds,
                         "prefill_assignments": self.moe_prefill_assignments,
                         "groups_hit": self.moe_groups_hit},
+                "prefix": {"prompt_tokens": self.prompt_tokens,
+                           "matched_tokens": self.prefix_matched_tokens},
                 "latent": {"rows_read": self.latent_rows_read,
                            "bytes_read": self.latent_bytes_read,
                            "row_bytes": self.latent_row_bytes},
@@ -779,7 +893,8 @@ class _Slot:
     __slots__ = ("stream", "pos_next", "last0", "remaining", "step_idx",
                  "temperature", "eos0", "step_keys", "last_emit_at",
                  "blocks", "table", "draft_ok", "demoted", "accept_ema",
-                 "spec_rounds", "probe_in", "tree_rung", "rid", "replay")
+                 "spec_rounds", "probe_in", "tree_rung", "rid", "replay",
+                 "draft", "fresh")
 
     def __init__(self, req: _Request, prompt_len: int, first0: int,
                  blocks: List[int], table: np.ndarray):
@@ -807,13 +922,20 @@ class _Slot:
         self.spec_rounds = 0            # rounds of EMA evidence
         self.probe_in = 0               # plain rounds until re-probe
         self.tree_rung = 0              # shape-ladder rung (tree mode)
+        # self-drafting: the prediction module's draft for the position
+        # after ``last0``'s (None: none yet), and whether the slot comes
+        # straight from its prefill (its first pair is still to run)
+        self.draft = None
+        self.fresh = True
 
 
 class _Round:
     """A decode round on the device: what ``_dispatch`` leaves for
     ``_collect``.  ``rows`` are ``(slot, its _Slot, emits, last)``: a
     replayed row emits nothing, ``last`` is the row of a stream that
-    finishes by its count."""
+    finishes by its count.  A self-drafting round's are ``(slot, its
+    _Slot, n_cand)``, ``None`` for a chained row, whose ``n_cand`` the step
+    worked out on the device."""
 
     __slots__ = ("ids", "moe", "rows", "t0", "ahead", "n_live", "gathered",
                  "n_last", "n_positions", "sampled")
@@ -895,7 +1017,7 @@ class _Prefill:
     prefill: blocks are allocated, ``p`` tokens are in the arena."""
 
     __slots__ = ("req", "blocks", "slot", "p", "t", "logits", "handoff",
-                 "moe")
+                 "moe", "h_last")
 
     def __init__(self, req: _Request, blocks: List[int], slot: int,
                  matched_len: int, handoff: Optional[KVHandoff] = None):
@@ -906,6 +1028,7 @@ class _Prefill:
         self.t = req.prompt0.shape[0]
         self.logits = None
         self.moe = None                 # routed layers' counts, on the device
+        self.h_last = None              # self-drafting: the hidden state at p - 1
         self.handoff = handoff          # set: re-prefill, don't re-emit
 
 
@@ -969,6 +1092,10 @@ class LMServingEngine:
             step.  Streams stay bit-exact vs offline generate under the
             default ``"replay"`` acceptance; a per-slot acceptance EMA
             demotes collapsing slots to plain decode and re-probes.
+            A model with a prediction module (``TransformerLM(mtp=...)``)
+            and no other drafter named drafts for itself through its own
+            latent pool: ``SpecConfig(k=1)``, one program a round, no
+            drafter arena (``serving.spec``).
         max_prefill_chunk_tokens: Sarathi-style chunked-prefill
             interleaving — when set, the worker advances at most ONE
             block-aligned chunk of at most this many prompt tokens
@@ -1028,7 +1155,7 @@ class LMServingEngine:
         import jax
         from bigdl_tpu.models.transformer.generate import (
             _decode_pick_paged, _insert_blocks, _insert_rows, _prefill_parts,
-            _prefill_suffix_parts, _tree_commit_paged,
+            _prefill_suffix_parts, _selfdraft_step_paged, _tree_commit_paged,
             _tree_verify_step_paged, _verify_step_paged)
         from bigdl_tpu.quant import dequantize_entry
         from bigdl_tpu.serving.kvcache import state as kvstate
@@ -1043,6 +1170,13 @@ class LMServingEngine:
         self._latent_layers = len(model.latent_layers)
         refuse_unsupported(model, spec=spec, migrate=migrate, kvtier=kvtier,
                            kv_quant=kv_quant, placement=placement)
+        #: the model's own prediction module drafts (``serving.spec``'s third
+        #: drafter): its block's rows are one more layer of the latent arena
+        self._selfdraft = drafts_for_itself(model, spec)
+        if self._selfdraft and (model.kv_layers or not self._latent_layers):
+            raise ValueError(
+                "a prediction module drafts through a latent pool: the "
+                "model's layers are 'mla' layers")
         if self._latent_layers and model.kv_layers:
             raise ValueError(
                 "a plan that mixes 'attention' and 'mla' layers cannot be "
@@ -1133,7 +1267,8 @@ class LMServingEngine:
         # -- or the latent rows': a model none of whose layers keeps a
         # (k, v) pair gets no (k, v) arena at all
         if self._latent_layers:
-            L, H, D = self._latent_layers, 1, model.mla.row
+            L, H, D = (self._latent_layers + self._selfdraft, 1,
+                       model.mla.row)
         else:
             L, H, D = len(model.kv_layers), model.n_kv_head, model.head_dim
         if not L:
@@ -1210,7 +1345,8 @@ class LMServingEngine:
         def _prefill_fn(params, buffers, x):
             del buffers  # part of the CompileCache signature only
             return _constrain(_prefill_parts(model, dequantize_entry(params),
-                                             x["ids"], x["len"] - 1))
+                                             x["ids"], x["len"] - 1,
+                                             mtp=self._selfdraft))
 
         self.prefill_cache = CompileCache(
             _prefill_fn, max_entries=max_cache_entries, placement_tag=_ptag,
@@ -1223,7 +1359,8 @@ class LMServingEngine:
                        if "state" in x else ())
             return _constrain(_prefill_suffix_parts(
                 model, dequantize_entry(params), x["ids"], x["len"] - 1,
-                x["prefix_len"], x["blocks"], *x["kv"], carried=carried))
+                x["prefix_len"], x["blocks"], *x["kv"], carried=carried,
+                h_prev=x.get("h_prev")))
 
         self.prefix_prefill_cache = CompileCache(
             _prefix_prefill_fn, max_entries=max_cache_entries,
@@ -1332,12 +1469,33 @@ class LMServingEngine:
         #: routed expert layers of the model: with any, the decode step
         #: hands their two integers out beside the ids
         self._moe_layers = model.moe_layers
+        #: ... and a self-drafting round's: the module's block rides it
+        self._round_moe_layers = self._moe_layers + (
+            self._selfdraft and model.mtp.mlp == "moe")
 
         #: a round's live blocks are attended this many at a time
         self._list_chunk = list_chunk(
             self.slots, model.n_head != model.n_kv_head,
             bool(self._latent_layers))
-        if self._latent_layers:
+        #: self-drafting: a slot's hidden state at its prompt's end, kept
+        #: from its prefill for its first round's first pair (S, hidden)
+        self._hid = None
+        #: ... and the last self-drafting round's (S, 4) output, on the device
+        self._prev_out = None
+        if self._selfdraft:
+            self._hid = jax.device_put(
+                np.zeros((self.slots, model.hidden_size), dt))
+            self._prev_out = jax.device_put(np.zeros((self.slots, 4), np.int32))
+
+            def _insert_hid(arena, hid, new, block_ids, h_last, slot):
+                # the chunk's rows (the module's behind the main layers')
+                # and the chunk's last hidden state into its slot's row
+                return _insert_rows(arena, new, block_ids) + (
+                    jax.lax.dynamic_update_slice(
+                        hid, h_last.astype(hid.dtype), (slot, 0)),)
+
+            self._insert_jit = jax.jit(_insert_hid, donate_argnums=(0, 1))
+        elif self._latent_layers:
             self._insert_jit = jax.jit(_insert_rows, donate_argnums=(0,))
         else:
             self._insert_jit = jax.jit(
@@ -1360,7 +1518,13 @@ class LMServingEngine:
                 spec = SpecConfig(k=spec)
             self.spec = spec
             draft_lm = spec.draft
-            if getattr(spec, "drafter_compute", None) == "ngram":
+            if self._selfdraft:
+                # the model's own prediction module, through the target's
+                # pool: no second model, no arena, no programs of its own
+                from bigdl_tpu.serving.spec import SelfDrafter
+                self.draft = SelfDrafter(model,
+                                         arena_layer=self._latent_layers)
+            elif getattr(spec, "drafter_compute", None) == "ngram":
                 # zero-model prompt-lookup drafter: host-side suffix
                 # matching, no device programs, no arena
                 self.draft = NgramDrafter(
@@ -1409,8 +1573,21 @@ class LMServingEngine:
                     model, dequantize_entry(params), tokens, pos,
                     n_cand, tables, *kv))
 
-            self._verify_jit = jax.jit(_verify_fn,
-                                       donate_argnums=_donated(5))
+            def _selfdraft_fn(params, operands, hid, prev, *kv):
+                # verify, pick and draft in one program: (S, 4) ids and
+                # counts leave, never logits, and come back as the next
+                # call's prev without a transfer
+                (tokens, pos, n_cand, fresh, temperature, keys,
+                 live) = split_selfdraft_operands(operands, self.slots, prev)
+                return _constrain(_selfdraft_step_paged(
+                    model, dequantize_entry(params), tokens, pos, n_cand,
+                    fresh, temperature, keys, hid, live, *kv,
+                    table_width=self.table_width, attn_impl=decode_attn))
+
+            self._verify_jit = (
+                jax.jit(_selfdraft_fn, donate_argnums=_donated(4))
+                if self._selfdraft else
+                jax.jit(_verify_fn, donate_argnums=_donated(5)))
 
             if spec.tree:
                 # one donated verify executable per ladder rung: the
@@ -1721,7 +1898,8 @@ class LMServingEngine:
                      "len": _np.int32(b),
                      "prefix_len": _np.int32(pb * self.block_len),
                      "blocks": _np.zeros((pb,), _np.int32),
-                     "kv": self.pool.arenas, **self._carried_operands(0)}
+                     "kv": self.pool.arenas, **self._carried_operands(0),
+                     **self._h_prev_operand(None)}
                 inputs.append(x)
         return self.prefix_prefill_cache.warmup_inputs(
             self._params, self._buffers, inputs)
@@ -1738,6 +1916,16 @@ class LMServingEngine:
         if self.state is None:
             return {}
         return {"state": self.state.arenas, "slot": np.int32(slot)}
+
+    def _h_prev_operand(self, h_prev) -> dict:
+        """What a suffix prefill of a self-drafting engine takes beside the
+        pool: the hidden state before the chunk (zeros where none is known:
+        a warm-up's shapes, the pass that computes one)."""
+        if not self._selfdraft:
+            return {}
+        if h_prev is None:
+            h_prev = np.zeros((1, self.model.hidden_size), self._cache_dtype)
+        return {"h_prev": h_prev}
 
     def _state_insert_compiled(self):
         """The program that writes one slot's rows of the state arenas
@@ -1782,6 +1970,17 @@ class LMServingEngine:
         """The spec engine's single verify executable: all S slots, all
         W = k+1 candidate rows, every round — k is static per engine
         and slots pad with n_cand, so like decode this lowers ONCE."""
+        if self._verify_exec is None and self._selfdraft:
+            import jax
+            # the self-drafting round: one operand vector, the hidden rows
+            ops = selfdraft_operands(self.slots,
+                                     self.slots * self.table_width)[0]
+            self._verify_exec = self._verify_jit.lower(
+                self._params, jax.ShapeDtypeStruct(ops.shape, ops.dtype),
+                self._hid, self._prev_out, *self.pool.arenas).compile()
+            self._verify_compiles += 1
+            self._ledger_exec("verify", f"slots={self.slots}/selfdraft",
+                              self._verify_exec)
         if self._verify_exec is None:
             import jax
             sh = (dict(sharding=self.placement.replicated())
@@ -1863,9 +2062,15 @@ class LMServingEngine:
             new = sds((L, 1, bucket, D) if self.pool.latent
                       else (L, 1, H, bucket, D), self._cache_dtype, **sh)
             kv, n = self.pool.arenas, self.pool.data_arenas
-            exe = self._insert_jit.lower(
-                *kv[:n], *[new] * n, sds((nb,), np.int32, **sh),
-                *kv[n:]).compile()
+            if self._selfdraft:
+                exe = self._insert_jit.lower(
+                    kv[0], self._hid, new, sds((nb,), np.int32),
+                    sds((1, self.model.hidden_size), self._cache_dtype),
+                    sds((), np.int32)).compile()
+            else:
+                exe = self._insert_jit.lower(
+                    *kv[:n], *[new] * n, sds((nb,), np.int32, **sh),
+                    *kv[n:]).compile()
             self._insert_execs[bucket] = exe
             self._ledger_exec("insert", f"bucket={bucket}", exe)
         return exe
@@ -2264,12 +2469,21 @@ class LMServingEngine:
         if (rnd is None or self._closing or self._abort or self._lc_nudge
                 or self._prefilling or self._hibernate_req):
             return False
+        n_last = rnd.n_last
+        if self._selfdraft:
+            # what the round in flight accepted is not known yet: a slot of
+            # it with two tokens or fewer left MAY end with it, and a
+            # sampled slot's next keys follow how many tokens it emitted
+            rows = [st for i, st, _ in rnd.rows if self._slots[i] is st]
+            if any(st.temperature > 0.0 for st in rows):
+                return False
+            n_last = sum(st.remaining <= 2 for st in rows)
         # the seated slots that decode in the next round
-        going_on = self._n_active - rnd.n_last
+        going_on = self._n_active - n_last
         if going_on <= 0:
             return False
         return not ((self._queue or self._adopt_q or self._resume_q)
-                    and (self._free or rnd.n_last)
+                    and (self._free or n_last)
                     and going_on < self._slot_limit)
 
     def _run(self):
@@ -2348,7 +2562,7 @@ class LMServingEngine:
                     self._stamp(P_SCHED)
                     self._adm_rid = self._adm_note = None
                 if self._n_active:
-                    if self.draft is not None:
+                    if self.draft is not None and not self._selfdraft:
                         self._step_spec()
                     else:
                         # left on the device: the next round of the loop
@@ -2690,6 +2904,7 @@ class LMServingEngine:
                     self.pool.release(matched)
                 return False
         blocks = matched + fresh
+        self.metrics.record_admission(t, len(matched) * B)
         if traced:
             # queue wait is known only now, at successful admission —
             # retroactive, the batcher's serve/queue_wait idiom
@@ -3176,8 +3391,25 @@ class LMServingEngine:
             x = {"ids": ids, "len": np.int32(ts),
                  "prefix_len": np.int32(p), "blocks": pblocks,
                  "kv": self.pool.arenas, **self._carried_operands(pf.slot)}
+            if self._selfdraft and pf.h_last is None:
+                # a radix hit: the prefix's rows are cached, the hidden
+                # state the first pair needs -- at the last matched position
+                # -- is not; one token's pass over the prefix before it
+                # computes it (its own rows are not kept)
+                one = np.zeros((1, self.prefill_buckets[0]), np.int32)
+                one[0, 0] = req.prompt0[p - 1]
+                if self._ph_args is not None:
+                    self._ph_args["prepass"] = 1
+                pf.h_last = self.prefix_prefill_cache(
+                    self._params, self._buffers,
+                    dict(x, ids=one, len=np.int32(1),
+                         prefix_len=np.int32(p - 1),
+                         **self._h_prev_operand(None)))[-1]
+            x.update(self._h_prev_operand(pf.h_last))
             logits, *rest = self.prefix_prefill_cache(
                 self._params, self._buffers, x)
+        if self._selfdraft:
+            *rest, pf.h_last = rest
         # what the chunk caches: (k, v), or a latent pool's rows
         n = self.pool.data_arenas
         new, rest = rest[:n], rest[n:]
@@ -3188,8 +3420,12 @@ class LMServingEngine:
         if _tracer.enabled:
             self._ph_args = {"slot": pf.slot, "bucket": bucket}
         kv = self.pool.arenas
-        self.pool.arenas = self._insert_compiled(bucket)(
-            *kv[:n], *new, ids_w, *kv[n:])
+        if self._selfdraft:
+            *self.pool.arenas, self._hid = self._insert_compiled(bucket)(
+                kv[0], self._hid, *new, ids_w, pf.h_last, np.int32(pf.slot))
+        else:
+            self.pool.arenas = self._insert_compiled(bucket)(
+                *kv[:n], *new, ids_w, *kv[n:])
         if self.state is not None:
             # the slot's rows at this chunk's true end: what the next chunk
             # starts from, and after the last what the slot decodes from
@@ -3274,7 +3510,7 @@ class LMServingEngine:
         table = np.zeros((self.table_width,), np.int32)
         table[:len(blocks)] = blocks
         st = _Slot(req, t, first0, blocks, table)
-        if self.draft is not None:
+        if self.draft is not None and not self._selfdraft:
             # drafter admission: full-prompt prefill into its dense
             # per-slot cache, first emitted token queued as pending.
             # Over-length (chunk-admitted) prompts serve plain decode.
@@ -3299,6 +3535,8 @@ class LMServingEngine:
         with it is not in this round.  The device runs the calls in
         dispatch order, which is all the ordering relied on.  At least
         one slot decodes (``_n_active``, ``_runs_ahead``)."""
+        if self._selfdraft:
+            return self._dispatch_selfdraft(ahead)
         rnd = _Round(self._stamp(P_DISPATCH), ahead)
         # one operand vector a round; what stays zero in it: an idle
         # slot, a greedy pick (no temperature, no key), nobody's blocks
@@ -3367,6 +3605,8 @@ class LMServingEngine:
         wrote one position further into a block the stream still held
         when the step was enqueued; whatever reuses the block is
         enqueued behind that step)."""
+        if self._selfdraft:
+            return self._collect_selfdraft(rnd)
         self._stamp(P_WAIT)
         ids = np.asarray(rnd.ids)  # sync; (S,) int32
         moe = rnd.moe
@@ -3637,6 +3877,174 @@ class LMServingEngine:
                     self._slots[i] = None
                     if self.draft is not None:
                         self.draft.release(i)
+                    self._free.append(i)
+                    self._n_active -= 1
+                self._cv.notify_all()
+
+    def _dispatch_selfdraft(self, ahead: bool) -> _Round:
+        """The first half of a SELF-DRAFTING round: the model's own
+        prediction module is the drafter, through the target's pool, and
+        ONE program verifies, picks and drafts
+        (``generate._selfdraft_step_paged``).  A slot hands it its last
+        emitted token and the module's draft for the position after (none
+        in the round that follows its prefill, or where one token is left
+        of its count: a plain row); the program hands back ``(S, 4)`` ids
+        -- the picks of both rows, whether the draft was the first pick,
+        and the next round's draft -- so a slot advances by 1 or 2 and the
+        host sees ids and counts, never logits.  A rejected draft is a
+        pointer rewind, in the main layers' rows and the module's alike.
+
+        ``ahead``: the previous round is still on the device
+        (``self._flying``), and what it accepted has not reached the
+        host.  A slot of it is CHAINED: the host hands over its position
+        and count as of before that round, and the step takes its tokens,
+        how far it advanced and whether it verifies a draft from that
+        round's output on the device (``split_selfdraft_operands``); its
+        live list reaches two positions further, the most it can have
+        advanced.  A slot that may have ended with the round in flight
+        (two tokens or fewer were left of its count) sits this round
+        out."""
+        rnd = _Round(self._stamp(P_DISPATCH), ahead)
+        B = self.block_len
+        (operands, tokens, pos, ncand, fresh, temperature, keys, live, chain,
+         remaining) = selfdraft_operands(self.slots,
+                                         self.slots * self.table_width)
+        flying = {i for i, _, _ in self._flying.rows} if ahead else ()
+        rows, chains = rnd.rows, []
+        for i, st in enumerate(self._slots):
+            if st is None:
+                continue
+            pos[i] = st.pos_next
+            if i in flying:
+                if st.remaining <= 2:
+                    continue
+                chain[i], remaining[i], cand = 1, st.remaining, None
+                reach = st.pos_next + 4
+            else:
+                tokens[i, 0] = st.last0
+                fresh[i] = st.fresh
+                ncand[i] = cand = 1
+                if st.draft is not None and st.remaining >= 2:
+                    tokens[i, 1] = st.draft
+                    ncand[i] = cand = 2
+                if st.temperature > 0.0 and st.step_keys is not None:
+                    temperature[i] = st.temperature
+                    ks = st.step_keys[st.step_idx:st.step_idx + 4]
+                    keys[i, :len(ks)] = ks
+                reach = st.pos_next + 2
+            # what the round reads and writes of the slot's chain: up to the
+            # block of the module's furthest row
+            held = st.table[:min(reach // B + 1, len(st.blocks))]
+            chains.append((i, held))
+            rnd.n_live += len(held)
+            rows.append((i, st, cand))
+        if not self._rd_active:     # (a round collected here names it)
+            self._rd_active = len(rows)
+        live[:] = live_list(chains, live.shape[1], self.slots)
+        chunk = self._list_chunk
+        rnd.gathered = -(-rnd.n_live // chunk) * chunk
+        out, *rest = self._verify_compiled()(
+            self._params, operands, self._hid, self._prev_out,
+            *self.pool.arenas)
+        rnd.ids = self._prev_out = out
+        out.copy_to_host_async()
+        if self._round_moe_layers:
+            rnd.moe, *rest = rest
+            rnd.moe.copy_to_host_async()    # lands with the ids: one wait
+        self.pool.arenas = rest
+        return rnd
+
+    def _collect_selfdraft(self, rnd: _Round) -> None:
+        """The second half: wait for the round's ``(S, 4)`` ids and counts,
+        emit one or two tokens a slot, finish and free.  A chained row's
+        ``n_cand`` is the step's own rule over what the host knows by now;
+        a row whose slot no longer holds its stream is thrown away (the
+        stream ended on its eos in the round before: ``_collect``)."""
+        self._stamp(P_WAIT)
+        out = np.asarray(rnd.ids)       # sync; (S, 4) int32: ids and counts
+        moe = rnd.moe
+        if moe is not None:
+            moe = np.asarray(moe)
+            self.metrics.record_moe(moe, self._round_moe_layers)
+        now = self._stamp(P_EMIT)
+        if rnd.ids is self._prev_out:   # no successor was enqueued: a drain
+            self._proved_empty(now, "decode_wait")
+        self._rd_active = len(rnd.rows)
+        t0 = max(rnd.t0, self._step_end)
+        self._step_end = now
+        itls, freed = [], []
+        n_active = n_positions = drafted = discarded = 0
+        n_emitted = accepted = pairs = 0
+        for i, st, cand in rnd.rows:
+            if self._slots[i] is not st:
+                discarded += 1
+                continue
+            if cand is None:
+                cand = 2 if st.remaining >= 2 else 1
+            y0, y1, acc, nxt = (int(v) for v in out[i])
+            n_active += 1
+            n_positions += st.pos_next + cand
+            if cand == 2:
+                st.stream.drafts.append((len(st.stream._tokens),
+                                         int(st.draft) + 1))
+                self.spec_metrics.record_round(1, acc)
+                drafted += 1
+                accepted += acc
+            pairs += 2 if st.fresh else 1 + acc
+            finished = False
+            for e in (y0, y1)[:1 + acc]:
+                st.stream._emit(e + 1)
+                itls.append(now - st.last_emit_at)
+                st.last_emit_at = now
+                st.last0 = e
+                st.pos_next += 1
+                st.step_idx += 1
+                st.remaining -= 1
+                n_emitted += 1
+                if st.remaining <= 0 or (st.eos0 is not None
+                                         and e == st.eos0):
+                    finished = True
+                    break
+            st.draft, st.fresh = nxt, False
+            if finished:
+                st.stream._finish()
+                self.metrics.record_complete()
+                freed.append(i)
+        self.draft.steps += pairs
+        # (position, layer) rows read: every arena layer's, the module's too
+        layers = self._latent_layers + 1
+        self.spec_metrics.record_verify_round(
+            bool(drafted), n_emitted, pairs, draft_rows=n_positions)
+        self.metrics.record_step(
+            n_active, itls, prefill_interrupted=self._prefill_since_step,
+            live_blocks=rnd.n_live, gathered_blocks=rnd.gathered,
+            latent_rows=n_positions * layers, ahead=rnd.ahead,
+            discarded=discarded)
+        self._prefill_since_step = False
+        if _tracer.enabled:
+            args = {"active": n_active, "round": self._rd_index,
+                    "ahead": int(rnd.ahead),
+                    "speculating": drafted, "live_blocks": rnd.n_live,
+                    "gather_blocks": rnd.gathered,
+                    "latent_positions": n_positions * layers,
+                    "drafted": drafted, "accepted": accepted,
+                    "emitted": n_emitted}
+            if moe is not None:
+                args.update(moe_assignments=int(moe[0]),
+                            moe_experts_hit=int(moe[1]))
+            _tracer.add_complete("lm/verify_step", t0, now - t0,
+                                 cat="serve", args=args)
+            # the drafter ran inside the same program: a marker, no phase
+            _tracer.add_complete("lm/draft", now, 0.0, cat="serve",
+                                 args={"fused": 1, "pairs": pairs,
+                                       "round": self._rd_index})
+        if freed:
+            with self._cv:
+                for i in freed:
+                    st = self._slots[i]
+                    self._trace_done(st.stream, st.rid)
+                    self.pool.release(st.blocks)
+                    self._slots[i] = None
                     self._free.append(i)
                     self._n_active -= 1
                 self._cv.notify_all()
@@ -4008,6 +4416,7 @@ class LMServingEngine:
                               **metrics["latent"]}
                              if self.pool.latent else None),
             "prefix_cache": self._prefix_cache_note,
+            "prefix_tokens": metrics["prefix"],
             "state": ({"layers": self._state_layers,
                        "row_bytes": self.state.row_bytes, **metrics["state"]}
                       if self.state is not None else None),
@@ -4051,6 +4460,12 @@ class LMServingEngine:
         out = self.spec.describe()
         out["demoted_slots"] = demoted
         out["draft"] = self.draft.describe()
+        # which of the three drafters serves, and whether through the
+        # target's own pool
+        out["drafter"] = ("prediction module" if self._selfdraft else
+                          "ngram" if self.draft.compute_mode == "ngram"
+                          else "draft model")
+        out["shares_pool"] = self._selfdraft
         out["verify_compiles"] = self._verify_compiles
         if self.spec.tree:
             out["commit_compiles"] = self._commit_compiles

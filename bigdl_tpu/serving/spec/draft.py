@@ -472,3 +472,47 @@ class NgramDrafter:
                 "lookups": self.lookups,
                 "hit_rate": (self.hits / self.lookups
                              if self.lookups else 0.0)}
+
+
+class SelfDrafter:
+    """The THIRD drafter: the target's own PREDICTION MODULE
+    (``TransformerLM.mtp``, DeepSeek-V3's multi-token prediction), through the
+    target's OWN pool.  Where :class:`DraftModel` is a second model with a
+    dense arena and prefill programs of its own and :class:`NgramDrafter` no
+    model at all, this one is one more block of the model being served: its
+    block's latent rows are one more arena layer of the target's
+    ``BlockPool(latent=True)``, filled by the target's prefills (a radix hit
+    shares them with the prefix), read through the same block tables, and its
+    pairs run INSIDE the round's one step program
+    (``generate._selfdraft_step_paged``: verify, pick, draft), so nothing here
+    holds device state: this object is the drafter's description and its
+    books.  One draft a slot and round (``k`` = 1: one module)."""
+
+    def __init__(self, model, *, arena_layer: int):
+        from bigdl_tpu.quant import params_compute_tag, params_dtype_tag
+        self.model = model
+        self.arena_layer = int(arena_layer)
+        self.dtype_tag = params_dtype_tag(model.params) or "f32"
+        self.compute_mode = params_compute_tag(model.params) or "f32"
+        self.steps = 0             # pairs the module ran (overhead meter)
+        self.arena_bytes = 0       # no arena of its own
+
+    def warmup(self) -> int:
+        return 0
+
+    def can_draft(self, prompt_len: int) -> bool:
+        return True
+
+    def release(self, slot: int) -> None:
+        pass
+
+    def release_all(self) -> None:
+        pass
+
+    def describe(self) -> dict:
+        return {"kind": "prediction module", "shares_pool": True,
+                "arena_layer": self.arena_layer, "dtype_tag": self.dtype_tag,
+                "compute_mode": self.compute_mode,
+                "block": {"mixer": self.model.mtp.mixer,
+                          "mlp": self.model.mtp.mlp},
+                "steps": self.steps}

@@ -25,6 +25,8 @@ class SpecMetrics:
         self.demotions = 0        # EMA-collapse demotions
         self.fault_demotions = 0  # injected-transient demotions
         self.reprobes = 0         # demoted slots re-probed
+        # (position, layer) latent rows a shared-pool drafter's block read
+        self.draft_latent_rows_read = 0
         # drafter kernel regime ("dequant"/"int8"/"auto"/"fp8"/"f32")
         # and its worst-layer int32-accumulator overflow-risk gauge
         # (max |q_w| * 127 * K / 2^31 — see quant.transform); the
@@ -45,10 +47,12 @@ class SpecMetrics:
                    prefix: str = "serving/lm/spec/") -> "SpecMetrics":
         for key in ("drafted", "accepted", "rolled_back", "draft_steps",
                     "verify_rounds", "spec_rounds", "emitted", "demotions",
-                    "fault_demotions", "reprobes"):
+                    "fault_demotions", "reprobes", "draft_latent_rows_read"):
             registry.register(prefix + key,
                               FnGauge(lambda k=key: getattr(self, k)),
                               replace=True)
+        registry.register(prefix + "tokens_emitted",
+                          FnGauge(lambda: self.emitted), replace=True)
         registry.register(
             prefix + "accept_rate",
             FnGauge(lambda: self.snapshot()["acceptance_rate"]),
@@ -91,13 +95,14 @@ class SpecMetrics:
                 self.acceptance.observe(accepted / drafted)
 
     def record_verify_round(self, speculated: bool, emitted: int,
-                            draft_steps: int) -> None:
+                            draft_steps: int, draft_rows: int = 0) -> None:
         with self._lock:
             self.verify_rounds += 1
             if speculated:
                 self.spec_rounds += 1
             self.emitted += emitted
             self.draft_steps += draft_steps
+            self.draft_latent_rows_read += draft_rows
 
     def record_tree_slot(self, depth: int, width: int,
                          emitted: int, alt_accepted: int) -> None:
@@ -132,6 +137,8 @@ class SpecMetrics:
                 "verify_rounds": self.verify_rounds,
                 "spec_rounds": self.spec_rounds,
                 "emitted": self.emitted,
+                "tokens_emitted": self.emitted,
+                "draft_latent_rows_read": self.draft_latent_rows_read,
                 "demotions": self.demotions,
                 "fault_demotions": self.fault_demotions,
                 "reprobes": self.reprobes,

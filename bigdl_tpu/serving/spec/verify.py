@@ -161,6 +161,16 @@ def _validate_ladder(shapes: Sequence[TreeShape]) -> None:
 class SpecConfig:
     """Speculation knobs for :class:`~bigdl_tpu.serving.LMServingEngine`.
 
+    WHO DRAFTS (``serving.spec``'s three drafters): a model that states a
+    prediction module (``TransformerLM(mtp=...)``) drafts FOR ITSELF,
+    through its own latent pool, whenever no other drafter is named here
+    (``draft`` None, ``drafter_compute`` not ``"ngram"``): ``k`` is then 1,
+    ``sampling`` ``"replay"`` and ``tree`` off, or the engine refuses at
+    construction; the adaptive knobs below (EMA, demotion, probing) are
+    the separate drafters'.  ``drafter_compute="ngram"`` names the
+    zero-model prompt-lookup drafter; otherwise a separate model drafts
+    (``draft``, or the target's int8 clone) with a dense arena of its own.
+
     Args:
         k: draft tokens per verify round (static per engine — the verify
             executable's candidate width is ``k + 1``).

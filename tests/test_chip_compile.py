@@ -756,6 +756,159 @@ def test_ling3_cell_compiles_for_v5e_and_keeps_latent_and_state_in_place(
     assert total < 14.5e9, total
 
 
+@pytest.mark.parametrize("program", ["round", "round-gather", "plain-decode",
+                                     "prefill2048", "suffix1024"])
+def test_glm47_cell_compiles_for_v5e_and_keeps_the_latent_arena_in_place(
+        sds, monkeypatch, program, capsys):
+    """``glm47-cell``: the programs of the benchmark's ``glm47.agentloop`` cell
+    at its own geometry (``benchmarks/configs/glm-4.7-flash.json``: a dense
+    layer and four routed ones, every mixer latent attention with a compressed
+    query, 64 experts held whole, the whole vocabulary, the prediction module;
+    64 slots, table width 512, ONE latent arena of SIX layers -- the module's
+    block's behind the five main ones -- of 32,769 blocks of 16 rows of 640
+    lanes, no (k, v) arena, no drafter arena), compiled for the described v5e:
+    the SELF-DRAFTING round (verify at W = 2, pick, draft: one program, the
+    latent arena donated and aliased in place, (S, 4) ids out and no output of
+    the vocabulary's width) through the Pallas kernel with W a static parameter
+    and, ``round-gather``, through the walk; the plain decode step of the same
+    model with the drafter off (five arena layers, the kernel at W = 1); the
+    2,048-token prefill and a 1,024-token suffix prefill over a cached prefix,
+    both handing out the module's rows behind the main layers' and the last
+    hidden state.  Prints what the configuration's ``memory_arithmetic``
+    quotes; memory before any run."""
+    import json
+    from benchmarks.drivers import serve_glm47 as D
+    from bigdl_tpu.models.transformer import generate as G
+    from bigdl_tpu.serving.kvcache.blocks import BlockPool
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "glm-4.7-flash.json")) as f:
+        c = json.load(f)
+    eng = c["engine"]
+    model = D.build_model(c)
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda: D.program_params(model, 0, c, "bfloat16")))
+    weight_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                       for a in jax.tree_util.tree_leaves(params))
+    # ISSUE 40's table: an MLA mixer 21,759,232; a routed layer whole
+    # 635,311,424; layer 0 84,677,888; the prediction layer 643,706,176;
+    # embedding, head and the final norm
+    values = (84_677_888 + 4 * 635_311_424 + 643_706_176
+              + 2 * 154_880 * 2048 + 2048)
+    assert values == 3_904_020_288
+    # (bf16 but for the routers' selection bias, float32: 2 B more each)
+    assert weight_bytes == 2 * values + 2 * 5 * 64, weight_bytes
+    assert model.kv_layers == () and model.state_layers == ()
+    assert model.latent_layers == (0, 1, 2, 3, 4) and model.mtp.mixer == "mla"
+    slots, width = eng["slots"], eng["cache_len"] // eng["block_len"]
+    i32 = lambda *shape: sds(shape, jnp.int32)              # noqa: E731
+    hidden = c["hidden_size"]
+
+    def arena_of(layers):
+        a, = jax.eval_shape(lambda: BlockPool(
+            n_layers=layers, n_heads=1, head_dim=model.mla.row,
+            block_len=eng["block_len"], latent=True,
+            num_blocks=eng["num_blocks"], dtype=jnp.bfloat16).arenas)
+        return sds(a.shape, a.dtype)
+
+    arena = arena_of(5 if program == "plain-decode" else 6)
+    arena_bytes = int(np.prod(arena.shape)) * 2
+    if program != "plain-decode":
+        assert arena.shape == (6, 32769, 16, 640)           # 576 -> 640 lanes
+    if program.startswith("round"):
+        impl = "gather" if program == "round-gather" else "paged_kernel"
+        monkeypatch.setattr(pa, "_use_interpret", lambda: False)
+
+        from bigdl_tpu.serving import lm_engine
+
+        def step(p, operands, hid, prev, rows):
+            # as the engine compiles it: one operand vector, and the round
+            # before's (S, 4) output, which a chained slot's tokens, position
+            # and n_cand are taken from on the device
+            (tokens, pos, n_cand, fresh, temperature, keys,
+             live) = lm_engine.split_selfdraft_operands(operands, slots, prev)
+            return G._selfdraft_step_paged(
+                model, p, tokens, pos, n_cand, fresh, temperature, keys, hid,
+                live, rows, table_width=width, attn_impl=impl)
+
+        compiled, text = _compile(
+            step, params,
+            i32(lm_engine.selfdraft_operands(slots, slots * width)[0].size),
+            sds((slots, hidden), jnp.bfloat16), i32(slots, 4), arena,
+            donate_argnums=(4,))
+        out, counts, rows = compiled.out_info
+        assert out.shape == (slots, 4) and out.dtype == jnp.int32
+        assert counts.shape == (2,) and rows.shape == arena.shape
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes >= 0.99 * arena_bytes
+        moved = [ln.strip()[:160] for ln in text.splitlines()
+                 if "bf16[6,32769,16,640]" in ln and re.search(
+                     r" copy(-start)?\(|AllocateBuffer", ln)]
+        assert not moved, moved
+        assert mem.temp_size_in_bytes < 3.0e9, mem.temp_size_in_bytes
+        kernel = [ln for ln in text.splitlines()
+                  if 'custom_call_target="tpu_custom_call"' in ln
+                  and "latent_decode_attention" in ln]
+        assert bool(kernel) == (impl == "paged_kernel")
+        if kernel:
+            assert "mla/attend/ragged" not in text
+            asked = [int(n) for ln in kernel for n in re.findall(
+                r'"scoped_memory_configs":\[\{[^\]]*"size":"(\d+)"', ln)]
+            assert asked and max(asked) < 48 << 20, asked
+    elif program == "plain-decode":
+        monkeypatch.setattr(pa, "_use_interpret", lambda: False)
+
+        def step(p, tok, pos, live, temperature, keys, prev_ids, rows):
+            return G._decode_pick_paged(model, p, tok, pos, live, temperature,
+                                        keys, prev_ids, rows,
+                                        table_width=width,
+                                        attn_impl="paged_kernel")
+
+        compiled, text = _compile(
+            step, params, i32(slots), i32(slots), i32(3, slots * width),
+            sds((slots,), jnp.float32), sds((slots, 2), jnp.uint32),
+            i32(slots), arena, donate_argnums=(7,))
+        assert compiled.out_info[0].shape == (slots,)
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes >= 0.99 * arena_bytes
+        assert "latent_decode_attention" in text
+    elif program == "prefill2048":
+        def step(p, ids, n):
+            return G._prefill_parts(model, p, ids, n - 1, mtp=True)
+
+        compiled, text = _compile(step, params, i32(1, 2048), i32())
+        logits, rows, counts, h_last = compiled.out_info
+        assert logits.shape == (1, 154880) and counts.shape == (2,)
+        assert rows.shape == (6, 1, 2048, 576)      # the module's rows behind
+        assert h_last.shape == (1, hidden)
+        mem = compiled.memory_analysis()
+        arena_bytes = 0
+    else:
+        def step(p, ids, n, prefix_len, blocks, h_prev, rows):
+            return G._prefill_suffix_parts(model, p, ids, n - 1, prefix_len,
+                                           blocks, rows, h_prev=h_prev)
+
+        compiled, text = _compile(step, params, i32(1, 1024), i32(), i32(),
+                                  i32(width), sds((1, hidden), jnp.bfloat16),
+                                  arena)
+        assert compiled.out_info[1].shape == (6, 1, 1024, 576)
+        assert compiled.out_info[-1].shape == (1, hidden)
+        mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    with capsys.disabled():
+        print(f"\nglm47-cell {program}: weights {weight_bytes / 1e9:.3f} GB, "
+              f"arena {arena_bytes / 1e9:.3f} GB, "
+              f"arguments {mem.argument_size_in_bytes / 1e9:.3f} GB, "
+              f"temporaries {mem.temp_size_in_bytes / 1e9:.3f} GB, "
+              f"outputs {mem.output_size_in_bytes / 1e9:.3f} GB, "
+              f"aliased {mem.alias_size_in_bytes / 1e9:.3f} GB, "
+              f"total {total / 1e9:.3f} GB")
+    assert total < 15.0e9, total
+
+
 def test_lm_flash_remat_train_step_compiles_for_v5e(sds, monkeypatch):
     """A TransformerLM training step with ``attention_impl="flash"``, RoPE
     and remat: the flash forward and both backward kernels inside the

@@ -262,3 +262,96 @@ def test_auto_takes_the_kernel_where_the_chip_can(monkeypatch, backend,
     if (backend, resolved) == ("tpu", "gather"):
         with pytest.raises(ValueError, match="multiple of 8"):
             LMServingEngine(model, decode_attn="paged_kernel", **kw)
+
+
+# -- W query positions a slot: a verify step's candidate rows -------------------------
+def _rows_case(lengths, w, *, first=0, seed=0, **kw):
+    """:func:`_case` with ``w`` new rows a slot: row i is the slot's position
+    ``length - 1 + i`` (an idle slot has none); the chains reach the last
+    row's block.  -> the case, and the walk's reading of it: the rows written,
+    then row i attending positions ``first <= p <= length - 1 + i``."""
+    c = _case([n + w - 1 if n else 0 for n in lengths], seed=seed, **kw)
+    slots, B = len(lengths), c["B"]
+    rng = np.random.default_rng(seed + 100)
+    heads, row = c["q"].shape[1], c["q"].shape[3]
+    q = jnp.asarray(2 * rng.standard_normal((slots, heads, w, row)), jnp.float32)
+    new = jnp.asarray(rng.standard_normal((slots, 1, w, row)), c["arena"].dtype)
+    lengths = jnp.asarray(lengths, jnp.int32)
+    pos = jnp.maximum(lengths - 1, 0)[:, None] + jnp.arange(w)[None, :]
+    blk = jnp.where((lengths > 0)[:, None],
+                    c["tables"][jnp.arange(slots)[:, None], pos // B], 0)
+    ids, owner, where = c["live"]
+    k_pos = where[:, None] * B + jnp.arange(B)[None, :]
+    own = jnp.minimum(owner, slots - 1)
+    mask = ((k_pos[:, None, :] <= pos[own][:, :, None])
+            & (k_pos >= first)[:, None, :] & (owner < slots)[:, None, None])
+    o, (arena,) = G._paged_attention(q, new, None, (c["arena"],), c["layer"],
+                                     blk, pos % B, c["live"], mask, score_dim=24)
+    return dict(c, q=q, lengths=lengths), o, arena
+
+
+@pytest.mark.parametrize("first", [0, 1])
+@pytest.mark.parametrize("w,lengths", [
+    (2, [16, 3, 1]),                    # the second row opens a block
+    (2, [64, 63, 0, 130]),              # ... a grid step; an idle slot
+    (2, [5, 191]),                      # the table's last entry
+    (3, [70, 41, 9, 119]),
+])
+def test_the_kernel_reads_w_rows_a_slot_as_the_walk(w, lengths, first):
+    """W static: row i of a slot sees ``lengths + i`` positions (its mask a
+    row, the softmax's parts a (head, row)), from ``first`` on (a prediction
+    module's rows start at 1)."""
+    c, want, arena = _rows_case(lengths, w, first=first, seed=w)
+    got = la.latent_decode_attention(c["q"], arena, c["tables"], c["lengths"],
+                                     score_dim=24, layer=c["layer"],
+                                     blocks_per_step=FETCH, first=first)
+    active = np.asarray(c["lengths"]) > 0
+    assert got.shape == want.shape == (len(lengths), 4, w, 40)
+    _close(got[active], want[active])
+    assert not np.asarray(got)[~active].any()
+
+
+def test_one_row_a_slot_is_the_kernel_as_it_was():
+    """W = 1 through the (S, H, 1, D) form lowers to the call the (S, H, D)
+    form makes: the same kernel, the same parameters, the same bits."""
+    c = _case([37, 100, 0, 64])
+    _, arena = _walk(c)
+    kw = dict(score_dim=24, layer=1, blocks_per_step=FETCH)
+    four = la.latent_decode_attention(c["q"], arena, c["tables"], c["lengths"], **kw)
+    three = la.latent_decode_attention(c["q"][:, :, 0], arena, c["tables"],
+                                       c["lengths"], **kw)
+    assert (np.asarray(four[:, :, 0]) == np.asarray(three)).all()
+    # ... and the kernel's own program is the same, equation for equation
+    def kernel(q):
+        eqns = jax.make_jaxpr(lambda q, a: la.latent_decode_attention(
+            q, a, c["tables"], c["lengths"], **kw))(q, arena).jaxpr.eqns
+        call, = [e for e in eqns if e.primitive.name == "pallas_call"]
+        return str(call.params["jaxpr"])
+
+    assert kernel(c["q"]) == kernel(c["q"][:, :, 0])
+
+
+def test_a_self_drafting_engine_serves_through_the_kernel_what_the_walk_serves():
+    """GLM-4.7-Flash's toy twin, its prediction module as the drafter: the
+    round's verify rows (W = 2) and the module's pairs (from position 1 on)
+    through the kernel, interpreted, against the walk: the same tokens, the
+    same drafts."""
+    from benchmarks.drivers import serve_glm47
+    from benchmarks.tests import toy_glm47
+    c = toy_glm47.config()
+    jobs = [(_ids(n, 40 + i) + 1, m) for i, (n, m) in enumerate(
+        [(6, 14), (21, 9), (37, 11)])]
+    got = {}
+    for impl in ("paged_kernel", "gather"):
+        eng = serve_glm47.build_engine(c, SEED, decode_attn=impl)
+        try:
+            assert eng.stats()["decode_attn"] == impl
+            streams = [eng.submit(p, max_new_tokens=m) for p, m in jobs]
+            got[impl] = ([s.result(timeout=600) for s in streams],
+                         [s.drafts for s in streams])
+        finally:
+            eng.close()
+    for a, b in zip(*[got[i][0] for i in got]):
+        assert (a == b).all()
+    assert got["paged_kernel"][1] == got["gather"][1]
+    assert sum(len(d) for d in got["gather"][1]) > 10

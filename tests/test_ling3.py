@@ -536,7 +536,7 @@ def _recurrent():
     ("recurrent", {"migrate": lambda *a: None}, "migrate"),
     ("recurrent", {"kvtier": object()}, "kvtier"),
     ("latent", {"kv_quant": "int8"}, "kv_quant='int8'"),
-    ("latent", {"spec": 2}, "spec"),
+    ("latent", {"spec": "tree"}, "tree verify"),
     ("latent", {"migrate": lambda *a: None}, "migrate"),
     ("latent", {"kvtier": object()}, "kvtier"),
     ("both", {"kv_quant": "int8"}, "kv_quant='int8'"),
@@ -548,6 +548,9 @@ def test_refusals_at_construction(kind, kw, says):
     with latent layers, and this configuration, which has both (the recurrent
     rows speak first)."""
     from bigdl_tpu.serving import LMServingEngine
+    from bigdl_tpu.serving.spec import SpecConfig
+    if kw.get("spec") == "tree":    # (a latent pool serves a chain verify)
+        kw = {"spec": SpecConfig(k=2, tree=True, drafter_compute="ngram")}
     model = {"recurrent": _recurrent, "latent": _latent_alone,
              "both": lambda: _model(toy())}[kind]()
     names = {"recurrent": ("recurrent layers", "M6"),
@@ -564,7 +567,7 @@ def test_refusals_at_construction(kind, kw, says):
 def test_every_refusal_is_a_row_of_the_one_table():
     from bigdl_tpu.serving import lm_engine
     rows = lm_engine._REFUSALS
-    assert len(rows) == len({r[:2] for r in rows}) == 11
+    assert len(rows) == len({r[:2] for r in rows}) == 14
     assert {r[0] for r in rows} == set(lm_engine._KIND_NAMES)
     lm_engine.refuse_unsupported(_latent_alone())           # nothing given: silent
 
