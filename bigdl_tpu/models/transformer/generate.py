@@ -50,7 +50,7 @@ def _finish_block(model, spec, bp, h, o, gate, token_mask=None):
     capacity window is a batch-level training construct -- under it a
     sequence's tokens would drop depending on which unrelated prompts
     share the dispatch, coupling batch rows.  -> (h, counts), ``counts``
-    the routed expert layer's two integers (zeros on a dense layer)."""
+    the routed expert layer's integers (zeros on a dense layer)."""
     return _ffn(model, spec, bp, h + model.layer_attn_out(bp, o, gate),
                 token_mask)
 
@@ -941,7 +941,7 @@ def _decode_step_paged(model, params, token, pos, live, *arenas,
     h = model._norm(params["ln_f"], h)
     logits = _head_logits(model, params, h)[:, 0].astype(jnp.float32)
     if model.moe_layers:
-        # the routed layers' two integers ride out beside the logits
+        # the routed layers' integers ride out beside the logits
         return (logits, counts) + arenas
     return (logits,) + arenas
 

@@ -37,6 +37,8 @@ import jax.numpy as jnp
 from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
+from bigdl_tpu.ops import paged_attention as _paged
+from bigdl_tpu.ops.grouped_matmul import grouped_matmul, row_tiles
 from bigdl_tpu.parallel.mesh import EXPERT_AXIS
 
 
@@ -233,8 +235,9 @@ class MoESpec(NamedTuple):
     @property
     def n_counts(self) -> int:
         """How many integers a routed layer counts (:func:`routed_experts`):
-        the groups hit ride behind the two where the router has groups."""
-        return 3 if self.n_group > 1 else 2
+        the groups hit ride behind the two where the router has groups, the
+        row tiles visited last."""
+        return 4 if self.n_group > 1 else 3
 
 
 def init_routed_params(rng, spec: MoESpec, d_model: int):
@@ -302,19 +305,49 @@ def swiglu(p, x):
                    * qmatmul(x, p["w_up"]), p["w_down"])
 
 
+#: the rows (or their products) a call of ``ops.grouped_matmul`` keeps in VMEM
+KERNEL_ROWS_BYTES = 8 << 20
+
+
+def expert_matmul_path(rows: int, d_model: int, width: int, dtype) -> str:
+    """Which grouped matmul a routed layer takes, from what is known when a
+    step program is traced: the platform and the call's static shapes.
+    ``"grouped_kernel"`` (``ops.grouped_matmul``, a weight-streaming Pallas
+    kernel) on a TPU where the rows -- and their products, the wider of the
+    two -- lie in VMEM whole (``KERNEL_ROWS_BYTES``) while the hit experts'
+    matrices stream past them once: a decode, verify or self-drafting round,
+    a bucket of a few hundred tokens.  ``"ragged_dot"`` (``lax.ragged_dot``)
+    for everything else: the CPU, and the prefills whose rows would have to
+    stream too.  The limit is the kernel's own, not a crossover: alone on the
+    chip it reads faster than ``ragged_dot`` at every shape tried, 256 rows
+    to 4,096 (1.02 to 2.7 times: PERF.md, PR 41)."""
+    if _paged._use_interpret():
+        return "ragged_dot"
+    rows_bytes = rows * max(d_model, width) * jnp.dtype(dtype).itemsize
+    return "grouped_kernel" if rows_bytes <= KERNEL_ROWS_BYTES else "ragged_dot"
+
+
 def grouped_swiglu(params, xs, sizes):
     """SwiGLU over rows SORTED by expert: rows ``[sum(sizes[:e]),
-    sum(sizes[:e+1]))`` go through expert ``e``'s matrices
-    (``lax.ragged_dot``: one grouped matmul, work proportional to the
-    rows; a native kernel on the TPU).  Rows past ``sum(sizes)`` come
-    back unspecified."""
+    sum(sizes[:e+1]))`` go through expert ``e``'s matrices, one grouped
+    matmul a product with work proportional to the rows
+    (:func:`expert_matmul_path`: ``lax.ragged_dot``, or on a TPU's
+    decode-sized calls ``ops.grouped_matmul``, gate and up in one call).
+    Rows past ``sum(sizes)`` come back unspecified.  -> (rows, the row
+    tiles the kernel visited: 0 on the other path)."""
+    _, d, f = params["w_gate"].shape
+    if expert_matmul_path(xs.shape[0], d, f, xs.dtype) == "grouped_kernel":
+        hidden = grouped_matmul(xs, (params["w_gate"], params["w_up"]), sizes)
+        return (grouped_matmul(hidden, params["w_down"], sizes),
+                row_tiles(sizes, xs.shape[0], xs.dtype))
+
     def dot(a, w):
         return lax.ragged_dot(a, w, sizes,
                               preferred_element_type=jnp.float32
                               ).astype(xs.dtype)
 
     hidden = jax.nn.silu(dot(xs, params["w_gate"])) * dot(xs, params["w_up"])
-    return dot(hidden, params["w_down"])
+    return dot(hidden, params["w_down"]), jnp.zeros((), jnp.int32)
 
 
 def routed_experts(params, x, spec: MoESpec, *, token_mask=None,
@@ -332,10 +365,11 @@ def routed_experts(params, x, spec: MoESpec, *, token_mask=None,
     body runs without that exchange.  ``token_mask`` (...,) bool leaves
     tokens out of the routing (a decode step's idle slots).
 
-    Returns ``(y, counts)``: ``counts`` int32 (2,) = assignments that
-    landed here, distinct held experts hit; behind them, where the router
-    has groups, the groups (of ALL the experts) in which the routed tokens
-    have a chosen expert, summed over the tokens (``spec.n_counts``)."""
+    Returns ``(y, counts)``: ``counts`` int32 (``spec.n_counts``,) =
+    assignments that landed here, distinct held experts hit; behind them,
+    where the router has groups, the groups (of ALL the experts) in which
+    the routed tokens have a chosen expert, summed over the tokens; LAST the
+    row tiles the grouped matmul's kernel visited (:func:`grouped_swiglu`)."""
     shape = x.shape
     x2 = x.reshape(-1, shape[-1])
     t, k = x2.shape[0], spec.top_k
@@ -357,7 +391,7 @@ def routed_experts(params, x, spec: MoESpec, *, token_mask=None,
         sizes = jnp.zeros((count + 1,), jnp.int32).at[key].add(1)[:count]
         back = jnp.zeros_like(order).at[order].set(jnp.arange(t * k))
     with jax.named_scope("moe/experts"):
-        out = grouped_swiglu(params, x2[order // k], sizes)
+        out, tiles = grouped_swiglu(params, x2[order // k], sizes)
         # back to (token, choice) order; an assignment that is not here
         # carries whatever the grouped matmul left in its row: weight 0
         out = out[back].reshape(t, k, -1).astype(jnp.float32)
@@ -372,7 +406,7 @@ def routed_experts(params, x, spec: MoESpec, *, token_mask=None,
         if token_mask is not None:
             hit = hit & token_mask.reshape(-1)[:, None]
         counts.append(jnp.sum(hit))
-    counts = jnp.stack(counts).astype(jnp.int32)
+    counts = jnp.stack(counts + [tiles]).astype(jnp.int32)
     return y.reshape(shape), counts
 
 

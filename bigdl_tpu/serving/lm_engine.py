@@ -610,6 +610,9 @@ class LMMetrics:
         # a router with groups (zero otherwise): the groups in which a
         # decoded token has a chosen expert, summed over tokens, layers, rounds
         self.moe_groups_hit = 0
+        # row tiles the grouped matmul's kernel visited (zero on the other
+        # path: parallel.expert.grouped_swiglu), over layers and rounds
+        self.moe_row_tiles = 0
         # latent layers (zero for a model without): (position, latent layer)
         # rows the decode rounds' live slots held -- what their attention
         # read -- and those rows' bytes as the arena holds them
@@ -653,7 +656,7 @@ class LMMetrics:
                               FnGauge(lambda k=key: getattr(self, k)),
                               replace=True)
         for key in ("assignments", "experts_hit", "expert_layer_rounds",
-                    "prefill_assignments", "groups_hit"):
+                    "prefill_assignments", "groups_hit", "row_tiles"):
             registry.register(
                 prefix + "moe/" + key,
                 FnGauge(lambda k="moe_" + key: getattr(self, k)),
@@ -740,12 +743,13 @@ class LMMetrics:
     def record_moe(self, counts, layers: int) -> None:
         """One decode step's routed-layer integers (summed over its
         ``layers`` expert layers by the step program; the groups hit ride
-        third where the router has groups)."""
+        third where the router has groups, the row tiles visited last)."""
         with self._lock:
             self.moe_assignments += int(counts[0])
             self.moe_experts_hit += int(counts[1])
-            if len(counts) > 2:
+            if len(counts) > 3:
                 self.moe_groups_hit += int(counts[2])
+            self.moe_row_tiles += int(counts[-1])
             self.moe_expert_layer_rounds += int(layers)
 
     def record_moe_prefill(self, assignments: int) -> None:
@@ -847,7 +851,8 @@ class LMMetrics:
                         "experts_hit": self.moe_experts_hit,
                         "expert_layer_rounds": self.moe_expert_layer_rounds,
                         "prefill_assignments": self.moe_prefill_assignments,
-                        "groups_hit": self.moe_groups_hit},
+                        "groups_hit": self.moe_groups_hit,
+                        "row_tiles": self.moe_row_tiles},
                 "prefix": {"prompt_tokens": self.prompt_tokens,
                            "matched_tokens": self.prefix_matched_tokens},
                 "latent": {"rows_read": self.latent_rows_read,
@@ -1467,7 +1472,7 @@ class LMServingEngine:
                                              donate_argnums=(0, 1))
             self._state_insert_exec = None
         #: routed expert layers of the model: with any, the decode step
-        #: hands their two integers out beside the ids
+        #: hands their integers out beside the ids
         self._moe_layers = model.moe_layers
         #: ... and a self-drafting round's: the module's block rides it
         self._round_moe_layers = self._moe_layers + (
@@ -3631,10 +3636,11 @@ class LMServingEngine:
                          "gather_blocks": rnd.gathered}
             if moe is not None:
                 step_args.update(moe_assignments=int(moe[0]),
-                                 moe_experts_hit=int(moe[1]))
+                                 moe_experts_hit=int(moe[1]),
+                                 moe_row_tiles=int(moe[-1]))
             if self.state is not None:
                 step_args["state_rows"] = state_rows
-            if moe is not None and len(moe) > 2:
+            if moe is not None and len(moe) > 3:
                 step_args["moe_groups_hit"] = int(moe[2])
             if self._latent_layers:
                 step_args["latent_positions"] = latent_rows
@@ -4031,7 +4037,8 @@ class LMServingEngine:
                     "emitted": n_emitted}
             if moe is not None:
                 args.update(moe_assignments=int(moe[0]),
-                            moe_experts_hit=int(moe[1]))
+                            moe_experts_hit=int(moe[1]),
+                            moe_row_tiles=int(moe[-1]))
             _tracer.add_complete("lm/verify_step", t0, now - t0,
                                  cat="serve", args=args)
             # the drafter ran inside the same program: a marker, no phase
@@ -4401,6 +4408,7 @@ class LMServingEngine:
             "cache_len": self.cache_len,
             "block_len": self.block_len,
             "decode_attn": self.decode_attn,
+            "expert_matmul": self._expert_matmul_paths(),
             "placement": (self.placement.describe()
                           if self.placement is not None else None),
             "prefill_buckets": list(self.prefill_buckets),
@@ -4450,6 +4458,26 @@ class LMServingEngine:
     def lifecycle_stats(self) -> dict:
         with self._lc_lock:
             return dict(self.lifecycle)
+
+    def _expert_matmul_paths(self) -> Optional[dict]:
+        """Which grouped matmul the routed layers of each step program take
+        (``parallel.expert.expert_matmul_path``, asked what the program's
+        trace asks: the rows are the program's tokens x top_k)."""
+        moe = self.model.moe
+        if moe is None or not self._round_moe_layers:
+            return None
+        from bigdl_tpu.parallel.expert import expert_matmul_path
+
+        def path(tokens):
+            return expert_matmul_path(
+                tokens * moe.top_k, self.model.hidden_size, moe.width,
+                self._params["embed"].dtype)
+
+        out = {"decode": path(self.slots)}
+        if self.spec is not None:
+            out["verify"] = path(self.slots * (self.spec.k + 1))
+        out.update({f"prefill_{b}": path(b) for b in self.prefill_buckets})
+        return out
 
     def _spec_stats(self) -> Optional[dict]:
         if self.spec is None:

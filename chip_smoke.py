@@ -9,9 +9,11 @@ two main paths through the entry points a user calls:
            sampled, a shared prefix), each stream replayed against offline
            ``generate()`` on the same device.
   kernels  the compiled (not interpreted) Pallas kernels — flash forward
-           and backward, plain and segmented, and paged decode — against
-           the XLA references in the repo; then the same requests through
-           an engine with ``decode_attn="paged_kernel"``.
+           and backward, plain and segmented, paged decode, the kernels that
+           read a round's listed blocks and the routed experts' grouped
+           matmul (beside ``lax.ragged_dot``: gap to float64, time alone) —
+           against the XLA references in the repo; then the same requests
+           through an engine with ``decode_attn="paged_kernel"``.
   train    ResNet-50, ImageNet shapes, bf16 compute / f32 master, NHWC,
            through ``Optimizer.create(...).optimize()``.
   --chips 4   ONLY the multi-chip phase: ``DistriOptimizer`` on a 4-device
@@ -53,6 +55,7 @@ from bigdl_tpu.models.transformer.generate import _paged_attention, generate
 from bigdl_tpu.ops.flash_attention import (_xla_fallback, flash_attention,
                                            use_flash_auto)
 from bigdl_tpu.ops.grouped_attention import grouped_decode_attention
+from bigdl_tpu.ops.grouped_matmul import grouped_matmul
 from bigdl_tpu.ops.latent_attention import latent_decode_attention
 from bigdl_tpu.ops.paged_attention import (paged_decode_attention,
                                            paged_decode_attention_reference)
@@ -112,6 +115,15 @@ class Sizes:
         ("grouped", 16, 64, 8, 128, 16, 128, None, "bfloat16"),
         ("grouped", 8, 72, 8, 128, 16, 160, 512, "bfloat16"),
         ("latent", 8, 32, 1, 576, 16, 256, None, "bfloat16"),
+    )
+    # the routed experts' grouped matmul at the four cells' rows and hit
+    # patterns: (rows, real rows, E, D, F, experts hit, dtype) -- GLM's verify
+    # round, Solar's, Ling's and Laguna's decode rounds
+    expert_cases: Tuple[tuple, ...] = (
+        (512, 512, 64, 2048, 1536, 63, "bfloat16"),
+        (1024, 128, 40, 4096, 1280, 19, "bfloat16"),
+        (256, 32, 64, 2560, 768, 11, "bfloat16"),
+        (320, 160, 128, 3072, 1024, 8, "bfloat16"),
     )
     # ResNet-50 training
     resnet_depth: int = 50
@@ -625,6 +637,59 @@ def _listed_case(case: tuple, seed: int) -> dict:
             "gap_to_float64": {k: float(f"{v:.3g}") for k, v in gap.items()}}
 
 
+def _expert_case(case: tuple, seed: int, reps: int = 20) -> dict:
+    """One product of a routed layer through ``ops.grouped_matmul`` and through
+    ``lax.ragged_dot``, each against a float64 loop over the hit experts on
+    the same operands, and -- compiled, on the chip -- the time of each alone:
+    ``reps`` calls in one program, each reading the one before."""
+    rows, real, e, d, f, hit, dtname = case
+    dt = jnp.dtype(dtname)
+    rs = np.random.RandomState(seed)
+    sizes = np.zeros(e, np.int64)
+    sizes[rs.permutation(e)[:hit]] = 1 + rs.multinomial(
+        real - hit, np.ones(hit) / hit)
+    keys = jax.random.split(jax.random.PRNGKey(seed), 2)
+    x = jax.random.normal(keys[0], (rows, d), jnp.float32).astype(dt)
+    w = (jax.random.normal(keys[1], (e, d, f), jnp.float32)
+         / np.sqrt(d)).astype(dt)
+    s = jnp.asarray(sizes, jnp.int32)
+    paths = {
+        "kernel": lambda a: grouped_matmul(a, w, s),
+        "ragged_dot": lambda a: jax.lax.ragged_dot(
+            a, w, s, preferred_element_type=jnp.float32).astype(dt)}
+    want, lo = np.zeros((real, f)), 0
+    for i in np.flatnonzero(sizes):
+        n = int(sizes[i])
+        want[lo:lo + n] = (np.asarray(x[lo:lo + n], np.float64)
+                           @ np.asarray(w[i], np.float64))
+        lo += n
+    outs = {name: np.asarray(jax.jit(fn)(x), np.float64)
+            for name, fn in paths.items()}
+    gap = {name: float(np.max(np.abs(o[:real] - want)) / np.max(np.abs(want)))
+           for name, o in outs.items()}
+    _check(not outs["kernel"][real:].any(),
+           f"grouped matmul {case}: rows no expert owns are not zeros")
+    # a product is rounded to the rows' dtype: half a unit in its last place
+    _check(gap["kernel"] <= max(gap["ragged_dot"], 2 * float(jnp.finfo(dt).eps)),
+           f"grouped matmul {case} vs float64: the kernel {gap['kernel']:.3g}, "
+           f"ragged_dot {gap['ragged_dot']:.3g}")
+    row = {"kernel": "grouped matmul", "rows": rows, "real_rows": real,
+           "experts": e, "d_model": d, "width": f, "experts_hit": hit,
+           "dtype": dtname, "hit_bytes": int(hit * d * f * dt.itemsize),
+           "gap_to_float64": {k: float(f"{v:.3g}") for k, v in gap.items()}}
+    if jax.default_backend() == "tpu":      # a time is a chip's or nothing
+        row["alone_ms"] = {}
+        for name, fn in paths.items():
+            loop = jax.jit(lambda a, fn=fn: jax.lax.fori_loop(
+                0, reps, lambda _, b: b + (fn(b)[:, :1] * 0).astype(dt), a))
+            loop(x).block_until_ready()
+            t0 = time.perf_counter()
+            loop(x).block_until_ready()
+            row["alone_ms"][name] = float(
+                f"{(time.perf_counter() - t0) / reps * 1e3:.4g}")
+    return row
+
+
 def phase_kernels(sz: Sizes = REAL, seed: int = 0, carry=None,
                   require_compiled: bool = True) -> dict:
     mark = PROBE.mark()
@@ -636,6 +701,9 @@ def phase_kernels(sz: Sizes = REAL, seed: int = 0, carry=None,
         cases.append(_paged_case(case, seed))
     for case in sz.listed_cases:
         cases.append(_listed_case(case, seed))
+        _free_device_memory()
+    for case in sz.expert_cases:
+        cases.append(_expert_case(case, seed))
         _free_device_memory()
     if carry is None:
         model = _build_lm(sz, seed)

@@ -125,7 +125,13 @@ def _watch_engines():
                                              None),
                 "state_bytes_a_round": (
                     None if arena is None or not steps
-                    else 2 * arena.row_bytes * state_rows / steps)})
+                    else 2 * arena.row_bytes * state_rows / steps),
+                # which grouped matmul each step program's routed layers
+                # take, and the row windows the kernel visited so far (null
+                # on a tree before PR 41)
+                "expert_matmul": getattr(e, "_expert_matmul_paths",
+                                         lambda: None)(),
+                "moe_row_tiles": getattr(e.metrics, "moe_row_tiles", None)})
         del engines[:]
         return t
 

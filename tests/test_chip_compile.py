@@ -298,6 +298,8 @@ def test_lm_paged_programs_keep_the_arenas_in_place_on_v5e(sds, monkeypatch,
               if 'custom_call_target="tpu_custom_call"' in ln
               and "ragged" not in ln]
     assert bool(pallas) == (arg == "paged_kernel")
+    # GPT-2 has no routed layer: the experts' kernel is in none of its programs
+    assert "grouped_matmul" not in text
     if program == "decode":
         # (S,) ids leave, and beside them only the arenas: no output of the
         # vocabulary's 50,257 columns
@@ -342,6 +344,71 @@ def test_lm_paged_programs_keep_the_arenas_in_place_on_v5e(sds, monkeypatch,
         assert not moved or geom is DEPTH2, moved
 
 
+#: (rows, E, D, F): the cells' decode-sized routed layers, and the most rows
+#: the rule lets the kernel hold (GLM's 512-token bucket: 8 MiB of rows)
+EXPERT_SHAPES = {
+    "glm47-round": (512, 64, 2048, 1536),
+    "glm47-prefill512": (2048, 64, 2048, 1536),
+    "solar2-decode": (1024, 40, 4096, 1280),
+    "ling3-decode": (256, 64, 2560, 768),
+    "laguna-decode": (320, 128, 3072, 1024),
+}
+
+
+@pytest.mark.parametrize("case", sorted(EXPERT_SHAPES))
+def test_grouped_matmul_compiles_for_v5e(sds, case):
+    """``ops.grouped_matmul`` at real widths, compiled: gate and up in one
+    call and down in its own, the dynamic row windows on the bfloat16 sublane
+    tile, the column copies from the matrices in HBM, the VMEM it asks for."""
+    from bigdl_tpu.ops.grouped_matmul import grouped_matmul
+    from bigdl_tpu.parallel.expert import expert_matmul_path
+    rows, e, d, f = EXPERT_SHAPES[case]
+
+    def swiglu(x, w_gate, w_up, w_down, sizes):
+        hidden = grouped_matmul(x, (w_gate, w_up), sizes, interpret=False)
+        return grouped_matmul(hidden, w_down, sizes, interpret=False)
+
+    up, down = sds((e, d, f), jnp.bfloat16), sds((e, f, d), jnp.bfloat16)
+    compiled, text = _compile(swiglu, sds((rows, d), jnp.bfloat16), up, up,
+                              down, sds((e,), jnp.int32))
+    assert compiled.out_info.shape == (rows, d)
+    assert _expert_matmuls(text, True, (e, d, f)) == 2
+    # (the rule, asked on the CPU, says ragged_dot: its shapes' side is held
+    # by tests/test_grouped_matmul.py)
+    assert expert_matmul_path(rows, d, f, jnp.bfloat16) == "ragged_dot"
+
+
+def _expert_matmuls(text, kernel, experts, temp_bytes=None, parent_temp=None):
+    """The routed layers' products in a compiled program: ``kernel`` -- a
+    decode-sized program, the platform seen as a TPU -- every one a
+    ``grouped_matmul`` custom call (gate and up in one, down in its own: two a
+    routed block of the text) whose VMEM stays inside what it asks for, no
+    ``ragged-dot`` left and the program's temporaries no larger than the
+    parent's (``parent_temp``: its reading at the parent commit, bytes); else
+    ``lax.ragged_dot``'s own custom calls, three a block, as the parent
+    compiles them, and no kernel.  ``experts``: the stacked matrices' (E, D,
+    F), by which a routed layer's ``ragged-dot`` is told from the attention
+    walk's (the compiler keeps no scope on either).  -> how many of the
+    kind."""
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    ours = [ln for ln in calls if "grouped_matmul" in ln]
+    e, d, f = experts
+    ragged = [ln for ln in calls if re.match(r"\s*%ragged-dot-none", ln)
+              and (f"bf16[{e},{d},{f}]" in ln or f"bf16[{e},{f},{d}]" in ln)]
+    if not kernel:
+        assert ragged and not len(ragged) % 3 and not ours, (len(ragged),
+                                                             len(ours))
+        return len(ragged)
+    assert ours and not len(ours) % 2 and not ragged, (len(ours), len(ragged))
+    asked = [int(n) for ln in ours for n in re.findall(
+        r'"scoped_memory_configs":\[\{[^\]]*"size":"(\d+)"', ln)]
+    assert asked and max(asked) < 64 << 20, asked
+    if parent_temp is not None:
+        assert temp_bytes <= parent_temp, (temp_bytes, parent_temp)
+    return len(ours)
+
+
 def _grouped_kernel_calls(text, *scopes):
     """The step's ``grouped_decode_attention`` custom calls (one a layer of the
     plan's periods), the walk gone from ``scopes``, the VMEM each asks for: four
@@ -383,6 +450,8 @@ def test_laguna_cell_compiles_for_v5e_and_keeps_the_arenas_in_place(
     from bigdl_tpu.serving.kvcache.blocks import BlockPool
 
     monkeypatch.setattr(fa, "_use_interpret", lambda: False)
+    # (the platform seen as a TPU: the routed layers' rule asks)
+    monkeypatch.setattr(pa, "_use_interpret", lambda: False)
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "benchmarks", "configs",
                            "laguna-s-2.1.json")) as f:
@@ -425,7 +494,7 @@ def test_laguna_cell_compiles_for_v5e_and_keeps_the_arenas_in_place(
         # the vocabulary's 50,176 columns leaves the step
         ids, counts = compiled.out_info[:2]
         assert ids.shape == (slots,) and ids.dtype == jnp.int32
-        assert counts.shape == (2,) and len(compiled.out_info) == 4
+        assert counts.shape == (3,) and len(compiled.out_info) == 4
         arena_bytes = 2 * int(np.prod(arenas[0].shape)) * 2
         mem = compiled.memory_analysis()
         assert mem.alias_size_in_bytes >= 0.99 * arena_bytes
@@ -453,14 +522,19 @@ def test_laguna_cell_compiles_for_v5e_and_keeps_the_arenas_in_place(
 
         compiled, text = _compile(step, params, i32(1, 2048), i32())
         logits, k, v, counts = compiled.out_info
-        assert logits.shape == (1, 50176) and counts.shape == (2,)
+        assert logits.shape == (1, 50176) and counts.shape == (3,)
         assert k.shape == v.shape == (5, 1, 8, 2048, 128)
         mem = compiled.memory_analysis()
         arena_bytes = 0
         assert "flash_attention_fwd" in text
         # 72 heads x 2,048 x 2,048 scores: in no dtype, in no layout
         assert not re.search(r"\[(1,)?(72|48|8,9|8,6),2048,2048\]", text)
-    assert "ragged" in text.lower() or "custom-call" in text
+    # the period's four routed layers: the decode step's through the kernel,
+    # its temporaries no larger than the parent's (35 / 7 MB at acd3eaf);
+    # the prefill's through lax.ragged_dot as the parent compiles them
+    _expert_matmuls(text, program.startswith("decode"), (128, 3072, 1024),
+                    mem.temp_size_in_bytes,
+                    {"decode": 35.5e6, "decode-kernel": 7.5e6}.get(program))
     total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
              + mem.output_size_in_bytes - mem.alias_size_in_bytes)
     with capsys.disabled():
@@ -501,6 +575,8 @@ def test_solar2_cell_compiles_for_v5e_and_keeps_the_state_in_place(
     from bigdl_tpu.serving.kvcache.blocks import BlockPool
 
     monkeypatch.setattr(fa, "_use_interpret", lambda: False)
+    # (the platform seen as a TPU: the routed layers' rule asks)
+    monkeypatch.setattr(pa, "_use_interpret", lambda: False)
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "benchmarks", "configs",
                            "solar-open2-250b.json")) as f:
@@ -563,7 +639,7 @@ def test_solar2_cell_compiles_for_v5e_and_keeps_the_state_in_place(
             assert "grouped_decode_attention" not in text
         ids, counts = compiled.out_info[:2]
         assert ids.shape == (slots,) and ids.dtype == jnp.int32
-        assert counts.shape == (2,) and len(compiled.out_info) == 6
+        assert counts.shape == (3,) and len(compiled.out_info) == 6
         mem = compiled.memory_analysis()
         # pool and state alike are updated where they lie
         assert mem.alias_size_in_bytes >= 0.99 * arena_bytes
@@ -579,7 +655,7 @@ def test_solar2_cell_compiles_for_v5e_and_keeps_the_state_in_place(
 
         compiled, text = _compile(step, params, i32(1, 1024), i32())
         logits, k, v, counts, state, tail = compiled.out_info
-        assert logits.shape == (1, 24576) and counts.shape == (2,)
+        assert logits.shape == (1, 24576) and counts.shape == (3,)
         assert k.shape == v.shape == (1, 1, 8, 1024, 128)   # the one K/V layer
         assert state.shape == (3, 1, 64, 128, 128) and state.dtype == jnp.float32
         assert tail.shape == (3, 1, 3, 24576) and tail.dtype == jnp.bfloat16
@@ -598,7 +674,10 @@ def test_solar2_cell_compiles_for_v5e_and_keeps_the_state_in_place(
                                   i32(64), i32(), *arenas)
         assert compiled.out_info[4].shape == (3, 1, 64, 128, 128)
         mem = compiled.memory_analysis()
-    assert "ragged" in text.lower() or "custom-call" in text
+    # (the parent's temporaries at acd3eaf: 263 / 77 MB)
+    _expert_matmuls(text, program.startswith("decode"), (40, 4096, 1280),
+                    mem.temp_size_in_bytes,
+                    {"decode": 263.5e6, "decode-kernel": 77.5e6}.get(program))
     total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
              + mem.output_size_in_bytes - mem.alias_size_in_bytes)
     with capsys.disabled():
@@ -639,6 +718,8 @@ def test_ling3_cell_compiles_for_v5e_and_keeps_latent_and_state_in_place(
     from bigdl_tpu.serving.kvcache import state as kvstate
     from bigdl_tpu.serving.kvcache.blocks import BlockPool
 
+    # (the platform seen as a TPU: the routed layers' rule asks)
+    monkeypatch.setattr(pa, "_use_interpret", lambda: False)
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "benchmarks", "configs",
                            "ling-3.0-flash-vl.json")) as f:
@@ -697,7 +778,7 @@ def test_ling3_cell_compiles_for_v5e_and_keeps_latent_and_state_in_place(
             i32(slots), *arenas, donate_argnums=(7, 8, 9))
         ids, counts = compiled.out_info[:2]
         assert ids.shape == (slots,) and ids.dtype == jnp.int32
-        assert counts.shape == (3,) and len(compiled.out_info) == 5
+        assert counts.shape == (4,) and len(compiled.out_info) == 5
         mem = compiled.memory_analysis()
         # latent rows and state alike are updated where they lie
         assert mem.alias_size_in_bytes >= 0.99 * arena_bytes
@@ -725,7 +806,7 @@ def test_ling3_cell_compiles_for_v5e_and_keeps_latent_and_state_in_place(
 
         compiled, text = _compile(step, params, i32(1, 2048), i32())
         logits, rows, counts, state, tail = compiled.out_info
-        assert logits.shape == (1, 19648) and counts.shape == (3,)
+        assert logits.shape == (1, 19648) and counts.shape == (4,)
         assert rows.shape == (1, 1, 2048, 576)              # rows, not (k, v)
         assert state.shape == (7, 1, 32, 128, 128) and state.dtype == jnp.float32
         assert tail.shape == (7, 1, 3, 12288) and tail.dtype == jnp.bfloat16
@@ -742,7 +823,10 @@ def test_ling3_cell_compiles_for_v5e_and_keeps_latent_and_state_in_place(
         assert compiled.out_info[1].shape == (1, 1, 2048, 576)
         assert compiled.out_info[3].shape == (7, 1, 32, 128, 128)
         mem = compiled.memory_analysis()
-    assert "ragged" in text.lower() or "custom-call" in text
+    # (the parent's temporaries at acd3eaf: 50 / 97 MB)
+    _expert_matmuls(text, program.startswith("decode"), (64, 2560, 768),
+                    mem.temp_size_in_bytes,
+                    {"decode": 50.5e6, "decode-gather": 97.5e6}.get(program))
     total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
              + mem.output_size_in_bytes - mem.alias_size_in_bytes)
     with capsys.disabled():
@@ -781,6 +865,8 @@ def test_glm47_cell_compiles_for_v5e_and_keeps_the_latent_arena_in_place(
     from bigdl_tpu.models.transformer import generate as G
     from bigdl_tpu.serving.kvcache.blocks import BlockPool
 
+    # (the platform seen as a TPU: the routed layers' rule asks)
+    monkeypatch.setattr(pa, "_use_interpret", lambda: False)
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "benchmarks", "configs",
                            "glm-4.7-flash.json")) as f:
@@ -840,7 +926,7 @@ def test_glm47_cell_compiles_for_v5e_and_keeps_the_latent_arena_in_place(
             donate_argnums=(4,))
         out, counts, rows = compiled.out_info
         assert out.shape == (slots, 4) and out.dtype == jnp.int32
-        assert counts.shape == (2,) and rows.shape == arena.shape
+        assert counts.shape == (3,) and rows.shape == arena.shape
         mem = compiled.memory_analysis()
         assert mem.alias_size_in_bytes >= 0.99 * arena_bytes
         moved = [ln.strip()[:160] for ln in text.splitlines()
@@ -880,7 +966,7 @@ def test_glm47_cell_compiles_for_v5e_and_keeps_the_latent_arena_in_place(
 
         compiled, text = _compile(step, params, i32(1, 2048), i32())
         logits, rows, counts, h_last = compiled.out_info
-        assert logits.shape == (1, 154880) and counts.shape == (2,)
+        assert logits.shape == (1, 154880) and counts.shape == (3,)
         assert rows.shape == (6, 1, 2048, 576)      # the module's rows behind
         assert h_last.shape == (1, hidden)
         mem = compiled.memory_analysis()
@@ -896,6 +982,13 @@ def test_glm47_cell_compiles_for_v5e_and_keeps_the_latent_arena_in_place(
         assert compiled.out_info[1].shape == (6, 1, 1024, 576)
         assert compiled.out_info[-1].shape == (1, hidden)
         mem = compiled.memory_analysis()
+    # the four routed layers' block and the prediction module's: the round's
+    # and the plain step's through the kernel (the parent's temporaries at
+    # acd3eaf: 92 / 106 / 8 MB), the prefills' through lax.ragged_dot
+    _expert_matmuls(text, program in ("round", "round-gather", "plain-decode"),
+                    (64, 2048, 1536), mem.temp_size_in_bytes,
+                    {"round": 92.5e6, "round-gather": 106.5e6,
+                     "plain-decode": 8.5e6}.get(program))
     total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
              + mem.output_size_in_bytes - mem.alias_size_in_bytes)
     with capsys.disabled():

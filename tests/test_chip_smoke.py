@@ -29,6 +29,8 @@ TOY = chip_smoke.Sizes(
     listed_cases=(("grouped", 4, 6, 2, 16, 8, 6, 20, "float32"),
                   ("grouped", 4, 18, 2, 16, 16, 5, None, "bfloat16"),
                   ("latent", 4, 4, 1, 36, 16, 5, None, "bfloat16")),
+    expert_cases=((48, 40, 8, 64, 128, 5, "float32"),
+                  (64, 9, 16, 32, 256, 3, "bfloat16")),
     resnet_depth=20, resnet_dataset="cifar10", image=32, classes=10,
     train_batch=8, train_iters=4, multichip_batch=8, multichip_iters=3)
 
@@ -101,12 +103,18 @@ def test_serve_and_kernels_phases_pass_at_toy_size(probe, capsys):
     assert kernels["pallas_calls_traced"] > 0
     assert "paged_decode_attention" in kernels["pallas_interpreted"]
     listed = [c for c in kernels["cases"] if "gap_to_float64" in c]
+    experts = [c for c in listed if c["kernel"] == "grouped matmul"]
+    listed = [c for c in listed if c not in experts]
     assert [c["kernel"] for c in listed] == ["grouped decode"] * 2 + [
         "latent decode"]
     for c in listed:    # exact products on the CPU, in the kernel and the walk
         assert max(c["gap_to_float64"].values()) < 1e-5, c
-    assert {"grouped_decode_attention", "latent_decode_attention"} <= set(
-        kernels["pallas_interpreted"])
+    assert [c["dtype"] for c in experts] == ["float32", "bfloat16"]
+    for c in experts:   # a product's rounding, and no time from a CPU
+        assert c["gap_to_float64"]["kernel"] <= max(
+            c["gap_to_float64"]["ragged_dot"], 1e-5) and "alone_ms" not in c
+    assert {"grouped_decode_attention", "latent_decode_attention",
+            "grouped_matmul"} <= set(kernels["pallas_interpreted"])
     assert any(n.startswith("flash_attention")
                for n in kernels["pallas_interpreted"])
     rows = [json.loads(line) for line in capsys.readouterr().out.splitlines()]

@@ -140,7 +140,7 @@ def test_step_ids_are_pick_token_of_the_steps_logits(case, round_):
     for a, b in zip(picked, rest):
         assert np.array_equal(np.asarray(a), np.asarray(b))
     if model.moe_layers:
-        assert np.asarray(picked[0]).shape == (2,) and int(picked[0][0]) > 0
+        assert np.asarray(picked[0]).shape == (3,) and int(picked[0][0]) > 0
 
 
 # -- the engine --------------------------------------------------------------------
@@ -243,7 +243,7 @@ def test_streams_are_those_of_logits_picked_on_the_host(case):
 
 @pytest.mark.parametrize("case", MODELS + ["int8"])
 def test_decode_executable_has_no_output_of_the_vocabularys_width(case):
-    """Its first output is (S,) int32; a routed model's two integers and the
+    """Its first output is (S,) int32; a routed model's integers and the
     donated arenas follow; nothing has V columns.  It takes the operand
     vector, then the previous step's ids -- its own first output's shape, not
     donated: the host still reads them -- then the donated arenas."""
@@ -255,7 +255,7 @@ def test_decode_executable_has_no_output_of_the_vocabularys_width(case):
         assert out[0].shape == (eng.slots,) and out[0].dtype == jnp.int32
         assert len(out) == 1 + bool(eng.model.moe_layers) + len(eng.pool.arenas)
         if eng.model.moe_layers:
-            assert out[1].shape == (2,)
+            assert out[1].shape == (3,)    # the row tiles ride last
         assert [o.shape for o in out[-len(eng.pool.arenas):]] == [
             a.shape for a in eng.pool.arenas]
         assert all(vocab not in o.shape

@@ -388,7 +388,7 @@ def test_a_round_hands_the_host_ids_and_counts_and_no_logits(engine):
     assert after["decode_steps"] > before["decode_steps"]
     exe = engine._verify_compiled()
     shapes = [tuple(a.shape) for a in jax.tree_util.tree_leaves(exe.out_info)]
-    assert shapes[0] == (4, 4) and shapes[1] == (2,)        # ids and counts, MoE integers
+    assert shapes[0] == (4, 4) and shapes[1] == (3,)        # ids and counts, MoE integers
     assert shapes[2:] == [engine.pool.shape] and engine._verify_compiles == 1
 
 

@@ -80,7 +80,7 @@ def test_layer_plan_is_two_dense_layers_and_a_period_of_five_kda_and_one_mla():
     assert model.mla.row == 32 and model.mla.score_dim == 24
     assert model.kda.gate == "bounded" and model.kda.full_rank
     assert model.kda.beta_scale == 1.0 and model.attn_gate == "per-head"
-    assert (model.moe.n_group, model.moe.topk_group, model.n_counts) == (4, 2, 3)
+    assert (model.moe.n_group, model.moe.topk_group, model.n_counts) == (4, 2, 4)
     assert period[3].rope.rotary_dim == 8 and period[0].rope is None
 
 
@@ -440,11 +440,11 @@ def test_the_picks_are_the_references_five_steps_and_the_groups_matter():
 def test_one_group_reproduces_todays_picks_bit_for_bit():
     """``n_group`` 1 and ``topk_group`` 1, the defaults: the selection Laguna
     and Solar compile today (the top-k of score + bias over all experts), to
-    the bit, and their layers count two integers as before."""
+    the bit, and their layers count no group (two integers and the row tiles)."""
     _, _, p = _uncut()
     m = _tokens(300, 3)
     spec = E.MoESpec(n_experts=16, top_k=3, width=32, score="sigmoid")
-    assert (spec.n_group, spec.topk_group, spec.n_counts) == (1, 1, 2)
+    assert (spec.n_group, spec.topk_group, spec.n_counts) == (1, 1, 3)
     idx, weight = E.route_top_k(p["router"], m, spec, p["select_bias"])
     scores = jax.nn.sigmoid(jnp.dot(m, p["router"],
                                     preferred_element_type=jnp.float32))
@@ -453,7 +453,7 @@ def test_one_group_reproduces_todays_picks_bit_for_bit():
     assert bool(jnp.all(idx == want))
     assert bool(jnp.all(weight == picked / jnp.sum(picked, -1, keepdims=True)))
     held = dict(p, **{k: p[k][:4] for k in ("w_gate", "w_up", "w_down")})
-    assert E.routed_experts(held, m, spec._replace(held=(0, 4)))[1].shape == (2,)
+    assert E.routed_experts(held, m, spec._replace(held=(0, 4)))[1].shape == (3,)
     jaxpr = str(jax.make_jaxpr(lambda x: E.route_top_k(
         p["router"], x, spec, p["select_bias"]))(m))
     assert jaxpr.count("top_k") == 1
@@ -468,7 +468,7 @@ def test_the_four_shares_and_the_shared_expert_add_up_to_the_uncut_layer():
         routed, shared = R.routed_half(c, w, m)
     whole, counts = E.routed_mlp(p, m, _spec(None))
     assert float(jnp.max(jnp.abs(whole - (routed + shared)))) < 1e-5
-    assert int(counts[0]) == 37 * 4 and counts.shape == (3,)
+    assert int(counts[0]) == 37 * 4 and counts.shape == (4,)
     assert 37 < int(counts[2]) <= 37 * 2            # a token hits one or two groups
     parts, landed = [], 0
     for share in range(4):
