@@ -112,12 +112,31 @@ class LayerSpec(NamedTuple):
     keys and values, NO keys and values kept, a state a head instead) or
     ``"mla"`` (latent attention of the model's :class:`MLASpec`: softmax
     over ONE cached row a position, nothing a head; ``rope`` rotates its
-    ``MLASpec.rope`` lanes)."""
+    ``MLASpec.rope`` lanes).  An ``"attention"`` layer may have its OWN count
+    of K/V heads (``n_kv_head``; ``None``: the model's) and a learned SINK
+    (``sink``): one float32 logit a query head that joins the softmax's
+    denominator and owns no value (``den += exp(sink_h - m)``)."""
     n_head: int
     window: Optional[int] = None
     rope: Optional[RopeSpec] = None
     mlp: str = "dense"
     mixer: str = "attention"
+    n_kv_head: Optional[int] = None
+    sink: bool = False
+
+
+class CacheClass(NamedTuple):
+    """One KIND of softmax layer as a paged pool sees it: the plan's
+    ``"attention"`` layers that cache the same row -- ``n_kv`` K/V heads of
+    ``k_dim`` key and ``v_dim`` value lanes -- for the same lifetime (a
+    ``window``: what lies behind it is never read again; ``None``: the whole
+    context).  ``layers``: the (absolute) layers of the class, in plan order;
+    a layer's place among them is its layer of the class's arenas."""
+    n_kv: int
+    k_dim: int
+    v_dim: int
+    window: Optional[int]
+    layers: tuple
 
 
 class MLASpec(NamedTuple):
@@ -199,6 +218,7 @@ class TransformerLM(Module):
     kda_conv = 4
     kda, mla = KDASpec(), None
     mtp = None
+    v_head_dim, value_scale = None, 1.0
 
     def __init__(self, vocab_size: int, hidden_size: int = 128,
                  n_head: int = 4, n_layers: int = 2,
@@ -220,7 +240,9 @@ class TransformerLM(Module):
                  layer_plan: Optional[Sequence] = None,
                  kda_conv: int = 4, kda: Optional[KDASpec] = None,
                  mla: Optional[MLASpec] = None,
-                 mtp: Optional[LayerSpec] = None):
+                 mtp: Optional[LayerSpec] = None,
+                 v_head_dim: Optional[int] = None,
+                 value_scale: float = 1.0):
         super().__init__()
         assert head_dim is not None or hidden_size % n_head == 0
         if norm not in ("layernorm", "rmsnorm"):
@@ -295,6 +317,10 @@ class TransformerLM(Module):
             raise ValueError(f"KDASpec.gate must be 'softplus' or 'bounded', "
                              f"got {self.kda.gate!r}")
         self.mla = MLASpec(*mla) if mla is not None else None
+        # a softmax layer's values may be narrower than its keys (``None``:
+        # the head's own width) and scaled before they are cached and read
+        self.v_head_dim = int(v_head_dim) if v_head_dim else None
+        self.value_scale = float(value_scale)
         # the LAYER PLAN: a list of groups ``(repeat, period)``, a period a
         # tuple of LayerSpec.  A group is ``repeat`` copies of its period
         # stacked on a leading axis and scanned; the body runs the period's
@@ -318,16 +344,21 @@ class TransformerLM(Module):
                         raise ValueError("an 'mla' layer needs mla=MLASpec, "
                                          "has no biases and no window")
                     if spec.mixer == "attention" and (
-                            spec.n_head % self.n_kv_head):
+                            spec.n_head % (spec.n_kv_head or self.n_kv_head)):
                         raise ValueError(
-                            f"{spec.n_head} query heads do not divide "
-                            f"over {self.n_kv_head} K/V heads")
+                            f"{spec.n_head} query heads do not divide over "
+                            f"{spec.n_kv_head or self.n_kv_head} K/V heads")
+                    if spec.mixer != "attention" and (spec.n_kv_head
+                                                      or spec.sink):
+                        raise ValueError("K/V heads of its own and a sink "
+                                         "are an 'attention' layer's")
                     if spec.mlp == "moe" and moe is None:
                         raise ValueError("a 'moe' layer needs moe=MoESpec")
         elif (self.n_kv_head != n_head or attn_gate or moe is not None
-              or mla is not None):
+              or mla is not None or v_head_dim or value_scale != 1.0):
             raise ValueError("grouped K/V heads, the output gate, routed "
-                             "experts and latent attention need a layer_plan")
+                             "experts, latent attention and values of their "
+                             "own width or scale need a layer_plan")
         self.layer_plan = layer_plan
         # the PREDICTION MODULE (DeepSeek-V3's multi-token prediction, one
         # module): one more block, stated by its LayerSpec, that reads the
@@ -373,6 +404,38 @@ class TransformerLM(Module):
         """The (absolute) layers that keep keys and values: a paged pool
         holds one arena layer for each, in this order."""
         return self._layers_mixing("attention")
+
+    @property
+    def v_dim(self) -> int:
+        """Lanes of a softmax layer's value head."""
+        return self.v_head_dim or self.head_dim
+
+    def kv_heads(self, spec: LayerSpec) -> int:
+        """A softmax layer's K/V heads: its own, or the model's."""
+        return spec.n_kv_head or self.n_kv_head or self.n_head
+
+    @property
+    def cache_classes(self) -> tuple:
+        """The plan's softmax layers grouped by what they cache and for how
+        long (:class:`CacheClass`), in plan order: a paged ``(k, v)`` pool
+        holds one class of blocks for each.  A model whose softmax layers are
+        all of one kind has one class, of every such layer."""
+        specs = [s for r, period in self.plan for s in period * r]
+        found: dict = {}
+        for l, s in enumerate(specs):
+            if s.mixer == "attention":
+                found.setdefault(self._class_key(s), []).append(l)
+        return tuple(CacheClass(*key, tuple(layers))
+                     for key, layers in found.items())
+
+    def _class_key(self, spec: LayerSpec) -> tuple:
+        return (self.kv_heads(spec), self.head_dim, self.v_dim, spec.window)
+
+    def cache_class(self, spec: LayerSpec) -> int:
+        """Which of :attr:`cache_classes` a softmax layer belongs to."""
+        key = self._class_key(spec)
+        return next(i for i, c in enumerate(self.cache_classes)
+                    if tuple(c[:4]) == key)
 
     @property
     def state_layers(self) -> tuple:
@@ -442,7 +505,7 @@ class TransformerLM(Module):
         ks = jax.random.split(rng, 8)
         h, f, d = self.hidden_size, self.ffn_size, self.head_dim
         std_h = 1.0 / math.sqrt(h)
-        inner, kv = spec.n_head * d, self.n_kv_head * d
+        inner, kv = spec.n_head * d, self.kv_heads(spec) * d
 
         def mat(k, shape, std):
             return jax.random.normal(k, shape, jnp.float32) * std
@@ -498,17 +561,22 @@ class TransformerLM(Module):
                                              if self.attn_gate == "elementwise"
                                              else spec.n_head), std_h)
         else:
+            # values of their own width: wv and wo follow it
+            dv = self.v_dim
+            out, kv_v = spec.n_head * dv, self.kv_heads(spec) * dv
             attn = {"wq": mat(ks[0], (h, inner), std_h),
                     "wk": mat(ks[1], (h, kv), std_h),
-                    "wv": mat(ks[2], (h, kv), std_h),
-                    "wo": mat(ks[3], (inner, h), 1.0 / math.sqrt(inner))}
+                    "wv": mat(ks[2], (h, kv_v), std_h),
+                    "wo": mat(ks[3], (out, h), 1.0 / math.sqrt(out))}
             if self.attn_gate:
-                attn["wg"] = mat(ks[4], (h, inner if self.attn_gate
+                attn["wg"] = mat(ks[4], (h, out if self.attn_gate
                                          == "elementwise" else spec.n_head),
                                  std_h)
             if self.bias:
                 attn.update(bq=jnp.zeros((inner,)), bk=jnp.zeros((kv,)),
-                            bv=jnp.zeros((kv,)), bo=jnp.zeros((h,)))
+                            bv=jnp.zeros((kv_v,)), bo=jnp.zeros((h,)))
+            if spec.sink:       # one logit a query head, float32 always
+                attn["sink"] = jnp.zeros((spec.n_head,), jnp.float32)
             p["attn"] = attn
         if spec.mlp == "moe":
             from bigdl_tpu.parallel.expert import init_routed_params
@@ -610,8 +678,10 @@ class TransformerLM(Module):
     # -- what differs between them is only how attention reads its keys
     def layer_qkv(self, spec: LayerSpec, bp, x, positions=None):
         """Pre-attention: norm, projections, rotary.  -> q (B, H, T, D),
-        k, v (B, H_kv, T, D), gate (B, T, H), (B, T, H * D) where it is
-        elementwise, or None."""
+        k (B, H_kv, T, D), v (B, H_kv, T, D_v) -- the layer's own count of
+        K/V heads, the values at their own width and SCALED
+        (``value_scale``): what a position caches -- gate (B, T, H), (B, T,
+        H * D_v) where it is elementwise, or None."""
         from bigdl_tpu.nn._util import match_compute_dtype
         from bigdl_tpu.quant.kernels import qmatmul
         ap = bp["attn"]
@@ -622,10 +692,12 @@ class TransformerLM(Module):
         b, t = a.shape[:2]
 
         def heads(y, n):    # (B, T, n * D) -> (B, n, T, D)
-            return y.reshape(b, t, n, self.head_dim).transpose(0, 2, 1, 3)
+            return y.reshape(b, t, n, -1).transpose(0, 2, 1, 3)
 
-        q, k, v = (heads(q, spec.n_head), heads(k, self.n_kv_head),
-                   heads(v, self.n_kv_head))
+        n_kv = self.kv_heads(spec)
+        q, k, v = heads(q, spec.n_head), heads(k, n_kv), heads(v, n_kv)
+        if self.value_scale != 1.0:
+            v = (v.astype(jnp.float32) * self.value_scale).astype(v.dtype)
         if positions is not None:
             q, k = self._rope(q, k, positions, spec)
         gate = (jax.nn.sigmoid(qmatmul(a, ap["wg"]).astype(jnp.float32))
@@ -857,15 +929,73 @@ class TransformerLM(Module):
         m, aux = self._mlp(bp, m)
         return m, aux, counts
 
-    def attend_full(self, spec: LayerSpec, q, k, v, segment_ids=None):
+    def layer_sink(self, spec: LayerSpec, bp):
+        """The layer's sink logits (H,) float32, or None where it has none."""
+        return bp["attn"]["sink"].astype(jnp.float32) if spec.sink else None
+
+    def attend_parts(self, q, k, v, mask):
+        """A softmax's three parts over one block of keys whose K/V heads are
+        shared by GROUPS of query heads, float32 scores whatever the operands'
+        dtype, a K/V head at a time (its group's (G, Tq, Tk) scores are all
+        that is alive at once): ``q`` (B, H, Tq, D), ``k`` (B, H_kv, Tk, D),
+        ``v`` (B, H_kv, Tk, D_v), ``mask`` (Tq, Tk) -> (maximum (B, H, Tq),
+        sum, weighted values (B, H, Tq, D_v)), the shapes
+        :func:`~bigdl_tpu.nn.attention.online_softmax_update` merges."""
+        from bigdl_tpu.nn.attention import _block_scores
+        b, h, tq, d = q.shape
+        n_kv = k.shape[1]
+        qg = q.reshape(b, n_kv, h // n_kv, tq, d)
+        scale = 1.0 / math.sqrt(d)
+
+        def head(x):    # (B, G, Tq, D), (B, Tk, D), (B, Tk, D_v)
+            return _block_scores(x[0], x[1][:, None], x[2][:, None], mask,
+                                 scale, acc=jnp.float32)
+
+        top, den, o = jax.lax.map(head, (jnp.moveaxis(qg, 1, 0),
+                                         jnp.moveaxis(k, 1, 0),
+                                         jnp.moveaxis(v, 1, 0)))
+        flat = lambda x: jnp.moveaxis(x, 0, 1).reshape(    # noqa: E731
+            (b, h) + x.shape[3:])
+        return flat(top), flat(den), flat(o)
+
+    @staticmethod
+    def sink_parts(sink, like):
+        """The sink as one more block of a softmax: its logit a head, a
+        probability mass of its own and NO value.  ``like``: a block's parts
+        (maximum (B, H, Tq), .., weighted values)."""
+        top = jnp.broadcast_to(sink.reshape((1, -1) + (1,) * (like[0].ndim - 2)),
+                               like[0].shape)
+        return top, jnp.ones_like(like[1]), jnp.zeros_like(like[2])
+
+    def attend_full(self, spec: LayerSpec, q, k, v, segment_ids=None,
+                    sink=None):
         """Self-attention of a whole sequence (training forward, prefill):
         causal, within ``spec.window`` where the layer has one, K/V heads
         shared by groups of query heads.  The Pallas flash kernel where
         the model's ``attention_impl`` resolves to it (the mask terms are
         applied inside its tiles and K/V tiles are read once a group: no
         (T, T) matrix and no repeated heads in HBM), else one XLA fusion
-        under an explicit mask."""
+        under an explicit mask.  A layer with a ``sink`` (H,) or with values
+        narrower than its keys takes the XLA path, a K/V head at a time,
+        float32 scores (the flash kernel takes one width and no column
+        without a key)."""
         mha = self._mha
+        if sink is not None or v.shape[-1] != q.shape[-1]:
+            from bigdl_tpu.nn.attention import (_finalize,
+                                                online_softmax_update,
+                                                segment_mask)
+            if segment_ids is not None:
+                raise NotImplementedError(
+                    "packed documents under a sink or values of their own "
+                    "width")
+            pos = jnp.arange(q.shape[-2])
+            top, den, o = self.attend_parts(
+                q, k, v, window_mask(pos, pos, spec.window))
+            if sink is not None:
+                with jax.named_scope("attn/sink"):
+                    o, den, _ = online_softmax_update(
+                        (o, den, top), self.sink_parts(sink, (top, den, o)))
+            return _finalize(o, den).astype(q.dtype)
         if spec.window is None and q.shape[1] == k.shape[1]:
             # one shared dispatch (nn.MultiHeadAttention.attend); the block
             # keeps mha.block_size as flash TILES, never the blockwise core
@@ -902,7 +1032,8 @@ class TransformerLM(Module):
                 bp, self.attend_latent(bp, q, row, segment_ids), gate)
         else:
             q, k, v, gate = self.layer_qkv(spec, bp, x, positions)
-            o = self.attend_full(spec, q, k, v, segment_ids)
+            o = self.attend_full(spec, q, k, v, segment_ids,
+                                 self.layer_sink(spec, bp))
             mixed = self.layer_attn_out(bp, o, gate)
 
         def drop(y, rng):

@@ -33,7 +33,8 @@ from bigdl_tpu.models.transformer import TransformerLM, window_mask
 from bigdl_tpu.serving.kvcache.blocks import (SCRATCH_BLOCK, head_columns,
                                               head_lanes, list_chunk,
                                               read_chain, read_rows,
-                                              table_list, write_rows)
+                                              split_live, table_list,
+                                              write_rows)
 
 
 def _head_logits(model, params, h):
@@ -77,19 +78,40 @@ def _attn_scope(model, spec):
     return "attn/full" if rotated else "attn/nope"
 
 
-#: the kinds of token mixer, each with a cache of its own
-_KINDS = ("attention", "kda", "mla")
+def _kinds(model):
+    """The kinds of cache a model's layers keep, each indexed by its own
+    layers alone: a recurrent layer's state row (``"kda"``), a latent layer's
+    arena of rows (``"mla"``), and a CLASS of softmax layers' K/V arenas (its
+    index among ``model.cache_classes``)."""
+    return ("kda", "mla") + tuple(range(len(model.cache_classes)))
 
 
-def _kind_indices(period):
-    """For each layer of a period: (how many of its kind the period holds,
-    which of them it is).  A layer's caches are indexed by KIND: an
-    attention layer's K/V arena, a recurrent layer's state row, a latent
-    layer's arena of rows, each counted over the layers of its own kind
-    alone."""
-    total = {m: sum(s.mixer == m for s in period) for m in _KINDS}
-    return [(total[s.mixer], sum(x.mixer == s.mixer for x in period[:i]))
-            for i, s in enumerate(period)]
+def _kind(model, spec):
+    """The kind of cache ``spec``'s layer keeps (:func:`_kinds`)."""
+    return (model.cache_class(spec) if spec.mixer == "attention"
+            else spec.mixer)
+
+
+def _kind_indices(model, period):
+    """For each layer of a period: (its kind, how many of its kind the period
+    holds, which of them it is).  A layer's caches are indexed by KIND, each
+    counted over the layers of its own kind alone."""
+    kinds = [_kind(model, s) for s in period]
+    return [(k, kinds.count(k), kinds[:i].count(k))
+            for i, k in enumerate(kinds)]
+
+
+def _class_arenas(model, kv, c):
+    """Class ``c``'s arenas of a step's flat ``kv`` (``(k, v)`` a class side
+    by side; one class: all of them, an int8 pool's scales too)."""
+    n = len(kv) // max(len(model.cache_classes), 1)
+    return tuple(kv[c * n:(c + 1) * n])
+
+
+def _with_class(kv, c, new):
+    """``kv`` with class ``c``'s arenas replaced by ``new``."""
+    n = len(new)
+    return tuple(kv[:c * n]) + tuple(new) + tuple(kv[(c + 1) * n:])
 
 
 def _split_arenas(model, arenas):
@@ -109,21 +131,21 @@ def _scan_prefill(model, params, h, layer_fn):
     k/v (L_kv, B, H_kv, T, D) stacked by attention layer, rows (L_mla, B, T,
     lanes) by latent layer, state/tail stacked by recurrent layer (``()``
     for a model with none), the routed expert layers' integers summed."""
-    kinds = _KINDS
+    kinds = _kinds(model)
     kept = {m: [] for m in kinds}
     base = {m: 0 for m in kinds}
     counts = jnp.zeros((model.n_counts,), jnp.int32)
     for (repeat, period), stacks in zip(model.plan,
                                         model.group_params(params)):
-        where = _kind_indices(period)
+        where = _kind_indices(model, period)
 
         def body(carry, x, period=period, base=dict(base), where=where):
             h, counts = carry
             bps, r = x
             made = {m: [] for m in kinds}
-            for spec, bp, (n, j) in zip(period, bps, where):
-                h, pair, c = layer_fn(spec, h, bp, base[spec.mixer] + r * n + j)
-                made[spec.mixer].append(pair)
+            for spec, bp, (kind, n, j) in zip(period, bps, where):
+                h, pair, c = layer_fn(spec, h, bp, base[kind] + r * n + j)
+                made[kind].append(pair)
                 counts = counts + c
             return (h, counts), tuple(
                 tuple(jnp.stack(c) for c in zip(*made[m])) for m in kinds)
@@ -134,16 +156,16 @@ def _scan_prefill(model, params, h, layer_fn):
             if pair:        # (repeat, n of the kind, ..) -> by layer of the kind
                 kept[m].append(tuple(x.reshape((-1,) + x.shape[2:])
                                      for x in pair))
-        for spec in period:
-            base[spec.mixer] += repeat
+        for kind, _, _ in where:
+            base[kind] += repeat
 
     def whole(parts):
         if len(parts) <= 1:
             return parts[0] if parts else ()
         return tuple(jnp.concatenate(x) for x in zip(*parts))
 
-    return (h, whole(kept["attention"]) + whole(kept["mla"]),
-            whole(kept["kda"]), counts)
+    return (h, sum((whole(kept[c]) for c in kinds[2:]), ())
+            + whole(kept["mla"]), whole(kept["kda"]), counts)
 
 
 def _prefill_result(model, logits, kv, state, counts, h_last=None):
@@ -215,7 +237,8 @@ def _prefill_parts(model, params, ids0, last_index, *, mtp: bool = False):
         # exactly as in TransformerLM._block -- including the "auto"
         # crossover rule)
         with jax.named_scope(_attn_scope(model, spec)):
-            o = model.attend_full(spec, q, k, v)
+            o = model.attend_full(spec, q, k, v,
+                                  sink=model.layer_sink(spec, bp))
         h, c = _finish_block(model, spec, bp, h, o, gate)
         return h, (k, v), c
 
@@ -384,7 +407,8 @@ _BY_SLOT = lax.RaggedDotDimensionNumbers(
     lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
 
 
-def _attend_by_owner(q, k_rows, v_rows, owner, mask, scales, score_dim=None):
+def _attend_by_owner(q, k_rows, v_rows, owner, mask, scales, score_dim=None,
+                     v_dim=None):
     """Attention over a live list when a K/V head has SEVERAL query
     vectors a slot (grouped heads, or the candidate rows of a verify
     step): two grouped matmuls (``lax.ragged_dot``: the groups are the
@@ -398,7 +422,8 @@ def _attend_by_owner(q, k_rows, v_rows, owner, mask, scales, score_dim=None):
     ``q`` (S, H_kv, G, W, D) f32, ``k_rows``/``v_rows`` (P, lanes),
     ``mask`` (n, W, B), ``scales`` None or the int8 rows' (P, H_kv) pair;
     ``score_dim``: the width a score is scaled by where it is not D (an
-    absorbed latent query is longer than the head it stands for).
+    absorbed latent query is longer than the head it stands for); ``v_dim``:
+    a value head's lanes where they are not the key's.
     -> the softmax's three parts over these blocks: the maximum (S, H_kv,
     G, W), the sum of ``exp(score - maximum)`` and the rows weighted by it
     (.., D)."""
@@ -436,14 +461,15 @@ def _attend_by_owner(q, k_rows, v_rows, owner, mask, scales, score_dim=None):
         e.reshape(n * B, n_kv * c), v_rows, columns)  # (S, pieces * H_kv * C, lanes)
     # each column's own head first (an eighth, a 25th of the lanes), then
     # the pieces added up
-    o = head_lanes(o.reshape(s_ * pieces, n_kv * c, -1), n_kv, d)
+    o = head_lanes(o.reshape(s_ * pieces, n_kv * c, -1), n_kv, v_dim or d)
     o = jnp.sum(o.reshape((s_, pieces) + o.shape[1:]), axis=1)
     part = (s_, n_kv, g, w_)
-    return top.reshape(part), den.reshape(part), o.reshape(part + (d,))
+    return (top.reshape(part), den.reshape(part),
+            o.reshape(part + (v_dim or d,)))
 
 
 def _paged_attention(q, k, v, arenas, layer, blk, off, live, mask,
-                     score_dim=None):
+                     score_dim=None, sink=None):
     """One layer's cached attention over PAGED arenas, shared by the
     decode, verify and tree-verify steps: write the W new rows of each
     slot (``k``/``v`` (S, H_kv, W, D), row j at ``(blk, off)[s, j]``)
@@ -472,8 +498,13 @@ def _paged_attention(q, k, v, arenas, layer, blk, off, live, mask,
     the grouped case with one K/V head -- scaled by ``score_dim``, and the
     values are the SAME gathered rows (no second gather): ``o``'s leading
     ``kv_rank`` lanes are the weighted latents.
-    Returns (o (S, H, W, D) f32, arenas')."""
+    ``v`` may be narrower than ``k`` (``D_v``: the arenas' rows differ in
+    width); ``sink`` (H,) float32, a layer's learned sink: one more column
+    of every row's softmax, with a probability and no value, added once the
+    list is walked.
+    Returns (o (S, H, W, D_v) f32, arenas')."""
     n_kv, d = k.shape[1], k.shape[3]
+    dv = d if v is None else v.shape[3]
     B = arenas[0].shape[2]
     latent = v is None
     k = k.transpose(0, 2, 1, 3)                                # (S, W, H, D)
@@ -516,7 +547,7 @@ def _paged_attention(q, k, v, arenas, layer, blk, off, live, mask,
             mine = jnp.repeat(owner, B)[None, :] == jnp.arange(s_)[:, None]
             new = _attend_all_pairs(
                 q[:, :, 0, 0], read_chain(ka, layer, ids, block),
-                read_chain(va, layer, ids, block),
+                read_chain(va, layer, ids, (B, n_kv, dv)),
                 mine & seen[:, 0].reshape(-1)[None, :], scales)
             new = tuple(x.reshape(x.shape[:2] + (1, 1) + x.shape[2:])
                         for x in new)
@@ -524,7 +555,7 @@ def _paged_attention(q, k, v, arenas, layer, blk, off, live, mask,
             k_rows = read_rows(ka, layer, ids)
             new = _attend_by_owner(
                 q, k_rows, k_rows if latent else read_rows(va, layer, ids),
-                owner, seen, scales, score_dim)
+                owner, seen, scales, score_dim, dv)
         # one softmax over every chunk: each part rescaled to the larger
         # maximum (a slot with nothing in a chunk adds exp(-1e30 - ..) = 0)
         top = jnp.maximum(parts[0], new[0])
@@ -533,13 +564,20 @@ def _paged_attention(q, k, v, arenas, layer, blk, off, live, mask,
                 parts[2] * old[..., None] + new[2] * add[..., None])
 
     part = (s_, n_kv, g, w_)
-    _, den, o = lax.fori_loop(
+    top, den, o = lax.fori_loop(
         0, chunks, attend,
         (jnp.full(part, -1e30, jnp.float32), jnp.zeros(part, jnp.float32),
-         jnp.zeros(part + (d,), jnp.float32)))
+         jnp.zeros(part + (dv,), jnp.float32)))
+    if sink is not None:
+        # the sink's column: rescale to the larger maximum, add its mass
+        with jax.named_scope("attn/sink"):
+            sk = sink.astype(jnp.float32).reshape(1, n_kv, g, 1)
+            new = jnp.maximum(top, sk)
+            old = jnp.exp(top - new)
+            den, o = den * old + jnp.exp(sk - new), o * old[..., None]
     # (a slot that owns nothing, an idle one, sums to 0 over 0: unread)
     o = o / jnp.maximum(den, 1e-30)[..., None]
-    return o.reshape(s_, h_, w_, d), arenas
+    return o.reshape(s_, h_, w_, dv), arenas
 
 
 def _scan_layers(model, params, h, arenas, layer_fn):
@@ -555,25 +593,25 @@ def _scan_layers(model, params, h, arenas, layer_fn):
     in turn.  -> (h, arenas, counts): ``counts`` the routed expert
     layers' integers summed over the layers."""
     counts = jnp.zeros((model.n_counts,), jnp.int32)
-    base = {m: 0 for m in _KINDS}
+    base = {m: 0 for m in _kinds(model)}
     arenas = tuple(arenas)
     for (repeat, period), stacks in zip(model.plan,
                                         model.group_params(params)):
-        where = _kind_indices(period)
+        where = _kind_indices(model, period)
 
         def body(carry, x, period=period, base=dict(base), where=where):
             h, arenas, counts = carry
             bps, r = x
-            for spec, bp, (n, j) in zip(period, bps, where):
+            for spec, bp, (kind, n, j) in zip(period, bps, where):
                 h, arenas, c = layer_fn(spec, h, bp,
-                                        base[spec.mixer] + r * n + j, arenas)
+                                        base[kind] + r * n + j, arenas)
                 counts = counts + c
             return (h, arenas, counts), None
 
         (h, arenas, counts), _ = lax.scan(
             body, (h, arenas, counts), (stacks, jnp.arange(repeat)))
-        for spec in period:
-            base[spec.mixer] += repeat
+        for kind, _, _ in where:
+            base[kind] += repeat
     return h, arenas, counts
 
 
@@ -619,26 +657,86 @@ def _latent_prefix_parts(model, bp, q, arena, layer, blocks, prefix_len):
          jnp.zeros(part, jnp.float32), jnp.full(part, NEG_INF, jnp.float32)))
 
 
+#: a cached prefix longer than this many positions is WALKED by a softmax
+#: layer's suffix prefill, a step of :data:`LATENT_PREFIX_STEP` positions at a
+#: time as far as it reaches (one executable whatever its length); up to it
+#: the prefix is read whole and meets the suffix in one fusion
+DENSE_PREFIX_MAX = 4096
+
+
+def walks_prefix(table_width: int, block_len: int) -> bool:
+    """Whether a suffix prefill over tables of ``table_width`` blocks walks
+    its prefix (:data:`DENSE_PREFIX_MAX`): by the tables' static width."""
+    return int(table_width) * int(block_len) > DENSE_PREFIX_MAX
+
+
+def _prefix_parts(model, q, arenas, layer, blocks, prefix_len, positions,
+                  window, n_kv):
+    """A softmax layer's suffix queries ``q`` (1, H, Ts, D), at ``positions``
+    (Ts,), against the CACHED PREFIX of its class, read through the padded
+    chain ``blocks`` a step of :data:`LATENT_PREFIX_STEP` positions at a time:
+    from the step that holds the first position a ``window`` lets the first
+    query see (what the class has let go of behind it is never gathered) as
+    far as ``prefix_len`` reaches.  -> the softmax's parts over the prefix,
+    as :func:`~bigdl_tpu.nn.attention.online_softmax_update` carries them."""
+    from bigdl_tpu.nn.attention import NEG_INF, online_softmax_update
+    ka, va = arenas[:2]
+    B, d = ka.shape[2], q.shape[-1]
+    dv = model.v_dim
+    step = min(max(LATENT_PREFIX_STEP // B, 1), blocks.shape[0])
+    blocks = jnp.pad(blocks, (0, -blocks.shape[0] % step),
+                     constant_values=SCRATCH_BLOCK)
+    span = step * B
+    part = q.shape[:-1]
+
+    def rows(arena, scale, ids, width, dtype):
+        g = read_chain(arena, layer, ids, (B, n_kv, width))
+        if scale is not None:       # dequant inside the gather
+            g = (g.astype(jnp.float32)
+                 * read_chain(scale, layer, ids, (B, n_kv))[..., None])
+        return g.transpose(1, 0, 2)[None].astype(dtype)
+
+    def attend(i, carry):
+        ids = lax.dynamic_slice_in_dim(blocks, i * step, step)
+        k_pos = i * span + jnp.arange(span)
+        seen = (window_mask(positions, k_pos, window)
+                & (k_pos < prefix_len)[None, :])
+        scales = arenas[2:] if len(arenas) == 4 else (None, None)
+        return online_softmax_update(carry, model.attend_parts(
+            q, rows(ka, scales[0], ids, d, q.dtype),
+            rows(va, scales[1], ids, dv, q.dtype), seen))
+
+    first = (0 if window is None
+             else jnp.maximum(prefix_len - window + 1, 0) // span)
+    return lax.fori_loop(
+        first, (prefix_len + span - 1) // span, attend,
+        (jnp.zeros(part + (dv,), jnp.float32), jnp.zeros(part, jnp.float32),
+         jnp.full(part, NEG_INF, jnp.float32)))
+
+
 def _prefill_suffix_parts(model, params, ids0, last_index, prefix_len,
-                          blocks, k_arena, v_arena=None,
-                          k_scale=None, v_scale=None, *, carried=(),
-                          h_prev=None):
+                          blocks, *kv, carried=(), h_prev=None):
     """Prefill a prompt SUFFIX against a cached prefix held in paged KV
     blocks: ``ids0`` (1, Ts) is the (bucket-padded) suffix, whose tokens
     live at absolute positions ``prefix_len + i``; ``blocks`` (Pb,) is
     the padded block chain holding the prefix k/v in the pool's arenas
-    — padded entries point at the scratch block and are masked via
-    ``prefix_len``.  Returns (logits at suffix index ``last_index``, k,
-    v) with k/v (L, 1, H_kv, Ts, D), exactly like :func:`_prefill_parts`
-    for the suffix rows.
+    ``kv`` -- (C, Pb), a row a class, where the pool has several
+    (``serving.kvcache.blocks``: a windowed class's row names the scratch
+    block where the sequence has let go) -- padded entries point at the
+    scratch block and are masked via ``prefix_len``.  Returns (logits at
+    suffix index ``last_index``, k, v a class) with k/v (L, 1, H_kv, Ts, D),
+    exactly like :func:`_prefill_parts` for the suffix rows.
 
     Numerics are the offline prefill's: suffix queries attend the SAME
     valid key set (cached prefix keys — stored post-RoPE, so directly
     reusable — plus causal suffix keys) through the same
     ``dot_product_attention`` core, with padded/garbage keys masked to
-    the same NEG_INF before the max-subtracted softmax.
+    the same NEG_INF before the max-subtracted softmax.  Tables wider than
+    :data:`DENSE_PREFIX_MAX` positions are WALKED instead
+    (:func:`_prefix_parts`), as is every layer with a sink or with values
+    of their own width (float32 scores, a K/V head at a time).
 
-    ``k_scale``/``v_scale`` mark int8-quantized arenas
+    Four arenas of ONE class mark an int8-quantized pool
     (``BlockPool(kv_quant="int8")``): the prefix gather dequantizes
     in-flight (int8 block x per-row scale); the returned suffix k/v stay
     full precision — the engine quantizes them at ``_insert_blocks``.
@@ -649,7 +747,7 @@ def _prefill_suffix_parts(model, params, ids0, last_index, prefix_len,
     result carries, as :func:`_prefill_parts`' does, what they hold at the
     suffix's true end.
 
-    A latent pool's one arena comes as ``k_arena`` (``v_arena`` None): a
+    A latent pool's one arena comes alone: a
     latent layer attends its prefix through :func:`_latent_prefix_parts` and
     its own rows causally, one softmax, and hands out its suffix ROWS.
     ``h_prev`` (1, hidden), where the prediction module drafts: the main
@@ -658,10 +756,13 @@ def _prefill_suffix_parts(model, params, ids0, last_index, prefix_len,
     from bigdl_tpu.nn.attention import (_finalize, dot_product_attention,
                                         online_softmax_update)
 
+    kv = tuple(a for a in kv if a is not None)
+    k_arena = kv[0]
     b, ts = ids0.shape
     B = k_arena.shape[2]
-    block = (B, model.n_kv_head, model.head_dim)
-    pb = blocks.shape[0]
+    tabs = blocks if blocks.ndim == 2 else blocks[None]
+    pb = tabs.shape[1]
+    walk = walks_prefix(pb, B)
     h = params["embed"][ids0]
     positions = prefix_len + jnp.arange(ts)
     if model.pos_encoding == "learned":
@@ -678,14 +779,16 @@ def _prefill_suffix_parts(model, params, ids0, last_index, prefix_len,
     kpos = jnp.where(jk < pb * B, jk, prefix_len + jk - pb * B)
     valid = (jk < prefix_len) | (jk >= pb * B)
     masks = ({w: (valid[None, :] & window_mask(positions, kpos, w))[None, None]
-              for w in _windows(model)} if model.kv_layers else {})
+              for w in _windows(model)}
+             if model.kv_layers and not walk else {})
+    own = jnp.arange(ts)
 
-    def prefix(arena, scale, layer, dtype):
+    def prefix(arena, scale, layer, dtype, chain, block):
         # the prefix chain (Pb*B, H, D) -> (1, H, Pb*B, D)
-        g = read_chain(arena, layer, blocks, block)
+        g = read_chain(arena, layer, chain, block)
         if scale is not None:       # dequant inside the gather
             g = (g.astype(jnp.float32)
-                 * read_chain(scale, layer, blocks, block[:2])[..., None])
+                 * read_chain(scale, layer, chain, block[:2])[..., None])
         return g.transpose(1, 0, 2)[None].astype(dtype)
 
     def layer_fn(spec, h, bp, layer):
@@ -697,33 +800,57 @@ def _prefill_suffix_parts(model, params, ids0, last_index, prefix_len,
         if spec.mixer == "mla":
             q, row, gate = model.mla_inputs(spec, bp, h, positions)
             k, v = model.mla_expand(bp, row)
-            own = jnp.arange(ts)
             o, den, _ = online_softmax_update(
-                _latent_prefix_parts(model, bp, q, k_arena, layer, blocks,
+                _latent_prefix_parts(model, bp, q, k_arena, layer, tabs[0],
                                      prefix_len),
                 model.latent_parts(q, k, v, window_mask(own, own, None)))
             h, c = _finish_block(model, spec, bp, h,
                                  _finalize(o, den).astype(h.dtype), gate)
             return h, (row,), c
         q, k, v, gate = model.layer_qkv(spec, bp, h, positions)
-        kc = jnp.concatenate([prefix(k_arena, k_scale, layer, k.dtype), k], 2)
-        vc = jnp.concatenate([prefix(v_arena, v_scale, layer, v.dtype), v], 2)
-        group = q.shape[1] // k.shape[1]
-        if group > 1:       # K/V heads repeated to the query heads
-            kc, vc = (jnp.repeat(x, group, axis=1) for x in (kc, vc))
-        o = dot_product_attention(q, kc, vc, mask=masks[spec.window])
+        cls = model.cache_class(spec)
+        arenas = _class_arenas(model, kv, cls)
+        sink = model.layer_sink(spec, bp)
+        n_kv = k.shape[1]
+        if walk or sink is not None or v.shape[-1] != k.shape[-1]:
+            # the prefix a step at a time, then the suffix's own keys: one
+            # softmax, and the sink's column last
+            with jax.named_scope(_attn_scope(model, spec)):
+                parts = online_softmax_update(
+                    _prefix_parts(model, q, arenas, layer, tabs[cls],
+                                  prefix_len, positions, spec.window, n_kv),
+                    model.attend_parts(q, k, v,
+                                       window_mask(own, own, spec.window)))
+            if sink is not None:
+                with jax.named_scope("attn/sink"):
+                    parts = online_softmax_update(
+                        parts, model.sink_parts(
+                            sink, (parts[2], parts[1], parts[0])))
+            o = _finalize(parts[0], parts[1]).astype(q.dtype)
+        else:
+            ks, vs = arenas[2:] if len(arenas) == 4 else (None, None)
+            kc = jnp.concatenate([prefix(arenas[0], ks, layer, k.dtype,
+                                         tabs[cls], (B, n_kv, k.shape[-1])),
+                                  k], 2)
+            vc = jnp.concatenate([prefix(arenas[1], vs, layer, v.dtype,
+                                         tabs[cls], (B, n_kv, v.shape[-1])),
+                                  v], 2)
+            group = q.shape[1] // k.shape[1]
+            if group > 1:       # K/V heads repeated to the query heads
+                kc, vc = (jnp.repeat(x, group, axis=1) for x in (kc, vc))
+            o = dot_product_attention(q, kc, vc, mask=masks[spec.window])
         h, c = _finish_block(model, spec, bp, h, o, gate)
         return h, (k, v), c
 
-    h, kv, state, counts = _scan_prefill(model, params, h, layer_fn)
+    h, kv_new, state, counts = _scan_prefill(model, params, h, layer_fn)
     h_last = None
     if h_prev is not None:
-        kv, h_last = _mtp_rows(model, params, h, h_prev, ids0, positions,
-                                    kv, last_index)
+        kv_new, h_last = _mtp_rows(model, params, h, h_prev, ids0, positions,
+                                   kv_new, last_index)
     h = lax.dynamic_slice_in_dim(h, last_index, 1, axis=1)
     h = model._norm(params["ln_f"], h)
     logits = _head_logits(model, params, h)[:, 0]
-    return _prefill_result(model, logits, kv, state, counts, h_last)
+    return _prefill_result(model, logits, kv_new, state, counts, h_last)
 
 
 def _insert_blocks(k_arena, v_arena, k_new, v_new, block_ids,
@@ -855,7 +982,9 @@ def _decode_step_paged(model, params, token, pos, live, *arenas,
         raise ValueError(f"attn_impl must be 'gather' or 'paged_kernel', "
                          f"got {attn_impl!r}")
     kv = _split_arenas(model, arenas)[0]
-    if len(kv) == 4 and attn_impl == "paged_kernel":
+    classes = model.cache_classes
+    quant = len(kv) == 4 * max(len(classes), 1)
+    if quant and attn_impl == "paged_kernel":
         raise ValueError("kv_quant='int8' requires decode_attn='gather' "
                          "(the Pallas paged kernel reads raw blocks)")
     s = token.shape[0]
@@ -864,27 +993,39 @@ def _decode_step_paged(model, params, token, pos, live, *arenas,
     if model.pos_encoding == "learned":
         h = h + params["pos"][pos][:, None, :]
     positions = pos[:, None, None]
-    # (n, 1, B) by window: a sliding layer reads its whole chain and sees
-    # the last ``window`` positions of it
-    masks = _list_masks(model, live, pos[:, None], B)
-    ids, owner, where = live
-    held = ((owner[None, :] == jnp.arange(s)[:, None])
-            & (ids != SCRATCH_BLOCK)[None, :])
+    # the list a CLASS of softmax layers (one class, a latent pool: the list
+    # itself): a class with a window lists its window's blocks alone
+    lives = split_live(live, s, table_width,
+                       [c.window for c in classes] or [None], B)
+
+    def reads(live):
+        # what a class's layers read by: (the masks by window (n, 1, B), the
+        # block and offset of each slot's write position (S, 1), the slots
+        # that hold a listed block, the tables the list spells)
+        ids, owner, where = live
+        held = ((owner[None, :] == jnp.arange(s)[:, None])
+                & (ids != SCRATCH_BLOCK)[None, :])
+        # the block holding each slot's write position (an idle slot's
+        # garbage write lands in the scratch block 0 and is never attended);
+        # one new row a slot: (S, 1)
+        blk = jnp.max(jnp.where(held & (where[None, :] == (pos // B)[:, None]),
+                                ids[None, :], 0), axis=1)[:, None]
+        tables = None
+        if attn_impl == "paged_kernel":
+            # the kernel walks (S, M) tables: the list spelled out (padded
+            # entries, owned by nobody, drop; what a windowed class has let
+            # go of stays the scratch block, behind the window's mask)
+            tables = jnp.zeros((s, table_width), jnp.int32).at[
+                owner, where].set(ids, mode="drop")
+        return (_list_masks(model, live, pos[:, None], B), blk,
+                jnp.any(held, axis=1), tables)
+
+    by_class = [reads(x) for x in lives]
+    masks, blk, active, tables = by_class[0]
     # a slot that holds no listed block but scratch padding is idle: its
     # token is routed to no expert (its other rows are garbage that nothing
     # reads)
-    active = jnp.any(held, axis=1)
-    # the block holding each slot's write position (an idle slot's garbage
-    # write lands in the scratch block 0 and is never attended); one new
-    # row a slot: (S, 1)
-    blk = jnp.max(jnp.where(held & (where[None, :] == (pos // B)[:, None]),
-                            ids[None, :], 0), axis=1)[:, None]
     off = (pos % B)[:, None]
-    if attn_impl == "paged_kernel":
-        # the kernel walks (S, M) tables: the list spelled out (padded
-        # entries, owned by nobody, drop)
-        tables = jnp.zeros((s, table_width), jnp.int32).at[owner, where].set(
-            ids, mode="drop")
 
     def layer_fn(spec, h, bp, layer, arenas):
         kv, recurrent = _split_arenas(model, arenas)
@@ -897,7 +1038,11 @@ def _decode_step_paged(model, params, token, pos, live, *arenas,
         elif spec.mixer == "mla":
             h, kv, counts = latent_layer(spec, h, bp, layer, kv)
         else:
-            h, kv, counts = attention_layer(spec, h, bp, layer, kv)
+            c = model.cache_class(spec)
+            h, mine, counts = attention_layer(
+                spec, h, bp, layer, _class_arenas(model, kv, c), lives[c],
+                by_class[c])
+            kv = _with_class(kv, c, mine)
         return h, kv + recurrent, counts
 
     def latent_layer(spec, h, bp, layer, arenas):
@@ -909,8 +1054,11 @@ def _decode_step_paged(model, params, token, pos, live, *arenas,
             (tables, jnp.where(active, pos + 1, 0), 0)
             if attn_impl == "paged_kernel" else None)
 
-    def attention_layer(spec, h, bp, layer, arenas):
+    def attention_layer(spec, h, bp, layer, arenas, live, read):
+        masks, blk, _, tables = read
         q, k, v, gate = model.layer_qkv(spec, bp, h, positions)  # (S, H, 1, D)
+        sink = model.layer_sink(spec, bp)
+        n_kv = model.kv_heads(spec)
         if attn_impl == "paged_kernel":
             # in-place block reads via the table (no dense gather), the new
             # rows first; numerics identical to the gather
@@ -919,20 +1067,23 @@ def _decode_step_paged(model, params, token, pos, live, *arenas,
             arenas = tuple(
                 write_rows(a, layer, blk, off, x.transpose(0, 2, 1, 3))
                 for a, x in zip(arenas, (k, v)))
-            if spec.n_head != model.n_kv_head or spec.window is not None:
-                # query heads that share K/V heads, a sliding window
+            if (spec.n_head != n_kv or spec.window is not None
+                    or sink is not None or v.shape[-1] != k.shape[-1]):
+                # query heads that share K/V heads, a sliding window, a
+                # sink, values of their own width
                 with jax.named_scope(_attn_scope(model, spec)):
                     o = grouped_decode_attention(
                         q, *arenas, tables, jnp.where(active, pos + 1, 0),
-                        layer=layer, n_kv_head=model.n_kv_head,
-                        window=spec.window)
+                        layer=layer, n_kv_head=n_kv, window=spec.window,
+                        sink=sink, v_dim=v.shape[-1])
             else:
                 o = paged_decode_attention(q, *arenas, tables, pos,
                                            layer=layer)
         else:
             with jax.named_scope(_attn_scope(model, spec)):
                 o, arenas = _paged_attention(q, k, v, arenas, layer, blk,
-                                             off, live, masks[spec.window])
+                                             off, live, masks[spec.window],
+                                             sink=sink)
         h, counts = _finish_block(model, spec, bp, h, o.astype(h.dtype), gate,
                                   token_mask=active[:, None])
         return h, arenas, counts
@@ -991,8 +1142,7 @@ def _decode_pick_paged(model, params, token, pos, live, temperature, keys,
     return (pick_rows(logits, temperature, keys), *rest)
 
 
-def _verify_step_paged(model, params, tokens, pos, n_cand, tables,
-                       k_arena, v_arena=None, k_scale=None, v_scale=None):
+def _verify_step_paged(model, params, tokens, pos, n_cand, tables, *kv):
     """Speculative VERIFY over paged caches: score all W = k+1 candidate
     rows per slot in one fixed-shape step.  ``tokens`` (S, W) int32
     0-based — row layout ``[last_emitted, draft_1 .. draft_k]`` — and
@@ -1015,23 +1165,27 @@ def _verify_step_paged(model, params, tokens, pos, n_cand, tables,
     so emitted streams stay token-exact with every decode_attn
     setting."""
     w = tokens.shape[1]
+    kv = tuple(a for a in kv if a is not None)
     abspos = pos[:, None] + jnp.arange(w)[None, :]   # (S, W)
-    live = table_list(tables)
+    # the tables a class (C, S, M) where the pool has several
+    tables = tables if tables.ndim == 3 else tables[None]
+    lives = [table_list(t) for t in tables]
     # row j attends positions <= pos + j: (n, W, B) over the tables' entries
-    masks = _list_masks(model, live, abspos, k_arena.shape[2])
-    return _verify_rows(model, params, tokens, n_cand, tables, live,
-                        _arenas(k_arena, v_arena, k_scale, v_scale),
+    masks = [_list_masks(model, live, abspos, kv[0].shape[2])
+             for live in lives]
+    return _verify_rows(model, params, tokens, n_cand, tables, lives, kv,
                         store=abspos, rope=abspos, masks=masks)
 
 
-def _verify_rows(model, params, tokens, n_cand, tables, live, arenas, *,
+def _verify_rows(model, params, tokens, n_cand, tables, lives, arenas, *,
                  store, rope, masks):
     """The body linear and tree verify share: row j of slot s is stored
     at arena offset ``store[s, j]``, rotated at position ``rope[s, j]``
-    and attends the entries of ``live`` (the tables as a list) under
-    ``masks[window of the layer]`` (n, W, B)."""
+    and attends, in a layer of class ``c``, the entries of ``lives[c]``
+    (``tables[c]`` as a list) under ``masks[c][window of the layer]`` (n, W,
+    B)."""
     s, w = tokens.shape
-    m = tables.shape[1]
+    m = tables.shape[2]
     B = arenas[0].shape[2]
     h = params["embed"][tokens]                      # (S, W, hidden)
     if model.pos_encoding == "learned":
@@ -1045,22 +1199,24 @@ def _verify_rows(model, params, tokens, n_cand, tables, live, arenas, *,
     # gather-clamp onto the LAST real block), and rows >= n_cand go to
     # the scratch block outright.
     blkcol = jnp.minimum(store // B, m - 1)
-    blk = jnp.where(jnp.arange(w)[None, :] < n_cand[:, None],
-                    tables[jnp.arange(s)[:, None], blkcol], 0)   # (S, W)
-    off = store % B
-
     valid = jnp.arange(w)[None, :] < n_cand[:, None]
+    blks = [jnp.where(valid, t[jnp.arange(s)[:, None], blkcol], 0)
+            for t in tables]                                     # (S, W) each
+    off = store % B
 
     def layer_fn(spec, h, bp, layer, arenas):
         if spec.mixer == "mla":     # W candidate rows a slot, absorbed
             return _latent_rows(model, spec, bp, h, layer, arenas, positions,
-                                blk, off, live, masks[None], valid)
+                                blks[0], off, lives[0], masks[0][None], valid)
+        c = model.cache_class(spec)
         q, k, v, gate = model.layer_qkv(spec, bp, h, positions)  # (S, H, W, D)
-        o, arenas = _paged_attention(q, k, v, arenas, layer, blk, off,
-                                     live, masks[spec.window])
+        o, mine = _paged_attention(q, k, v, _class_arenas(model, arenas, c),
+                                   layer, blks[c], off, lives[c],
+                                   masks[c][spec.window],
+                                   sink=model.layer_sink(spec, bp))
         h, counts = _finish_block(model, spec, bp, h, o.astype(h.dtype), gate,
                                   token_mask=valid)
-        return h, arenas, counts
+        return h, _with_class(arenas, c, mine), counts
 
     h, arenas, _ = _scan_layers(model, params, h, arenas, layer_fn)
     h = model._norm(params["ln_f"], h)
@@ -1222,9 +1378,9 @@ def _tree_verify_step_paged(model, params, tokens, pos, n_cand, tables,
         raise NotImplementedError(
             "tree verify stores a node away from its position; a windowed "
             "layer's mask over such offsets is not written")
-    return _verify_rows(model, params, tokens, n_cand, tables, live,
+    return _verify_rows(model, params, tokens, n_cand, tables[None], [live],
                         _arenas(k_arena, v_arena, k_scale, v_scale),
-                        store=store, rope=rope, masks={None: mask})
+                        store=store, rope=rope, masks=[{None: mask}])
 
 
 def _tree_commit_paged(src, pos, tables, k_arena, v_arena,

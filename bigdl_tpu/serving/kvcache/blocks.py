@@ -10,6 +10,27 @@ fixed-size blocks
     k, v : (L, num_blocks, block_len, W),   W = H * D rounded up to 128
 
 and a *sequence* is a host-side list of block ids (its block table).
+**A CLASS of blocks a kind of layer.**  The model's softmax layers are
+grouped by what they cache and for how long -- (K/V heads, key lanes, value
+lanes, window), ``TransformerLM.cache_classes`` -- and the pool holds one
+:class:`KVClass` for each: its own ``(k, v)`` arenas ``(L_class, N_class,
+block_len, W_k)`` / ``(.., W_v)`` (keys and values may differ in width), its
+own free list and refcounts.  A model whose softmax layers are all of one
+kind is a pool of ONE class: today's arenas, today's programs, and a chain
+entry is a block id.  With several classes a chain entry is a TUPLE of ids,
+one a class, all standing for the same ``block_len`` positions: entry ``i``
+of a chain holds positions ``i * block_len ..`` in every class, so every
+class's table keeps the chain's width and index.  A class WITH A WINDOW lets
+go of what lies behind it: as a sequence advances (:meth:`BlockPool.advance`,
+after every prefill chunk and before every round) its reference on every
+block that lies wholly behind ``pos - window + 1`` is dropped -- the entry's
+id of that class becomes the scratch block, which the window's mask already
+hides, so no kernel's index arithmetic changes -- and blocks ahead are
+allotted as the write position reaches them: a decoding sequence holds at
+most ``ceil(window / block_len) + 1`` blocks of such a class whatever its
+context.  The radix cache keeps a node's block in EVERY class (its own
+reference: a live sequence's release drops the sequence's alone) and evicts
+them together.
 **What a cached row is, the model says**: a ``(k, v)`` PAIR a K/V head
 (softmax attention over cached keys and values: two arenas, a row the
 position's H heads of D values side by side), or ONE LATENT ROW (latent
@@ -224,21 +245,51 @@ def list_chunk(slots: int, grouped: bool = False, latent: bool = False) -> int:
 
 def live_list(chains, length: int, slots: int):
     """The ``(3, length)`` int32 live list of one round: ``chains`` is
-    ``[(slot, block ids)]`` by ascending slot, each slot's blocks in
-    chain order and only the ones the round reads; padded with scratch
-    entries that nobody owns (module docstring)."""
+    ``[(slot, block ids[, first])]`` by ascending slot, each slot's blocks in
+    chain order and only the ones the round reads, from chain index
+    ``first`` on (0 where not given; a windowed class lists its window's
+    blocks alone); padded with scratch entries that nobody owns (module
+    docstring)."""
     import numpy as np
 
     out = np.zeros((3, length), np.int32)
     out[1] = slots
     at = 0
-    for slot, blocks in chains:
+    for slot, blocks, *first in chains:
         n = len(blocks)
         out[0, at:at + n] = blocks
         out[1, at:at + n] = slot
-        out[2, at:at + n] = np.arange(n)
+        out[2, at:at + n] = np.arange(n) + (first[0] if first else 0)
         at += n
     return out
+
+
+def window_blocks(window: int, block_len: int) -> int:
+    """Blocks a window's positions can touch: what a decoding sequence
+    holds of a windowed class at most (``ceil(window / block_len) + 1``)."""
+    return -(-int(window) // int(block_len)) + 1
+
+
+def class_entries(slots: int, table_width: int, window, block_len: int) -> int:
+    """Entries of a class's share of a round's live list: whole tables, or,
+    under a ``window``, the blocks a window can touch a slot."""
+    per = table_width if window is None else min(
+        table_width, window_blocks(window, block_len))
+    return int(slots) * int(per)
+
+
+def split_live(live, slots: int, table_width, windows, block_len: int):
+    """A round's live list by class: the classes' lists lie side by side
+    along the entries, each of :func:`class_entries` of them.  One class:
+    the list itself."""
+    if len(windows) == 1:
+        return (live,)
+    out, at = [], 0
+    for w in windows:
+        n = class_entries(slots, table_width, w, block_len)
+        out.append(live[:, at:at + n])
+        at += n
+    return tuple(out)
 
 
 def table_list(tables):
@@ -296,14 +347,102 @@ class RequestExceedsPool(ValueError):
     admitted.  Counted in ``serving/rejected_total``."""
 
 
+class KVClass:
+    """One class of blocks (module docstring): the arenas of the layers that
+    cache the same row for the same lifetime, their free list and their
+    refcounts.  ``k`` (L, N, block_len, W_k) and ``v`` (.., W_v), ``ks`` /
+    ``vs`` the int8 scales' (None otherwise); a latent class has ``v`` None."""
+
+    def __init__(self, *, n_layers: int, n_heads: int, head_dim: int,
+                 v_dim: Optional[int], window: Optional[int], block_len: int,
+                 num_blocks: int, dtype, kv_quant, latent: bool):
+        import jax.numpy as jnp
+
+        if num_blocks < 2:
+            raise ValueError(
+                f"num_blocks must be >= 2 (block 0 is scratch), got "
+                f"{num_blocks}")
+        self.n_layers, self.n_heads, self.head_dim = (
+            int(n_layers), int(n_heads), int(head_dim))
+        self.v_dim = int(v_dim or head_dim)
+        self.window = None if window is None else int(window)
+        self.num_blocks = int(num_blocks)
+        self.block_len = int(block_len)
+        #: the arenas' shapes (module docstring): the lane padding follows
+        #: from the geometry the class is built with, nothing selects it
+        lead = (self.n_layers, self.num_blocks, self.block_len)
+        self.shape = lead + (row_width(self.n_heads, self.head_dim),)
+        self.v_shape = lead + (row_width(self.n_heads, self.v_dim),)
+        self.scale_shape = lead[:2] + (
+            _whole_tiles(self.block_len * self.n_heads),)
+        if kv_quant == "int8":
+            self.k = jnp.zeros(self.shape, jnp.int8)
+            self.v = jnp.zeros(self.v_shape, jnp.int8)
+            # per-(position, head) scales, block-major like the arenas
+            self.ks = jnp.zeros(self.scale_shape, jnp.float32)
+            self.vs = jnp.zeros(self.scale_shape, jnp.float32)
+        else:
+            dt = dtype if dtype is not None else jnp.float32
+            self.k = jnp.zeros(self.shape, dt)
+            self.v = None if latent else jnp.zeros(self.v_shape, dt)
+            self.ks = self.vs = None
+        # pop() from the tail hands out ascending ids first
+        self._free: List[int] = list(range(self.num_blocks - 1, 0, -1))
+        self._ref = [0] * self.num_blocks
+
+    @property
+    def arenas(self) -> tuple:
+        if self.v is None:
+            return (self.k,)
+        return ((self.k, self.v) if self.ks is None
+                else (self.k, self.v, self.ks, self.vs))
+
+    @arenas.setter
+    def arenas(self, new) -> None:
+        if self.v is None:
+            (self.k,) = new
+        elif self.ks is None:
+            self.k, self.v = new
+        else:
+            self.k, self.v, self.ks, self.vs = new
+
+    @property
+    def capacity(self) -> int:
+        return self.num_blocks - 1
+
+    @property
+    def row_lanes(self) -> int:
+        """A position's lanes in one layer, unpadded: keys and values."""
+        return self.n_heads * (self.head_dim
+                               + (0 if self.v is None else self.v_dim))
+
+    @property
+    def kv_arena_bytes(self) -> int:
+        return sum(a.size * a.dtype.itemsize for a in self.arenas[:2])
+
+    def stats(self) -> dict:
+        free = len(self._free)
+        return {"layers": self.n_layers, "kv_heads": self.n_heads,
+                "row_lanes": self.row_lanes, "window": self.window,
+                "num_blocks": self.num_blocks, "free_blocks": free,
+                "used_blocks": self.capacity - free,
+                "bytes": self.kv_arena_bytes}
+
+
 class BlockPool:
-    """Refcounted free-list allocator over one paged k/v arena, or over
-    one arena of latent rows.
+    """Refcounted free-list allocator over the paged k/v arenas of one or
+    several classes of blocks, or over one arena of latent rows.
 
     Args:
-        n_layers / n_heads / head_dim: model geometry (L, H, D): the layers
-            that cache a row, and what a row is -- H K/V heads of D, or, with
-            ``latent``, one head of the latent row's lanes.
+        n_layers / n_heads / head_dim: model geometry (L, H, D) of a pool of
+            ONE class: the layers that cache a row, and what a row is -- H K/V
+            heads of D, or, with ``latent``, one head of the latent row's
+            lanes.
+        classes: the pool's classes where the model's softmax layers are of
+            several kinds (module docstring): a dict a class, ``n_layers``,
+            ``n_heads``, ``head_dim`` and optionally ``v_dim`` (values of
+            their own width), ``window`` and ``num_blocks`` (the pool's
+            where not given).  In place of the three above.
         latent: the cached row is ONE latent row a position (module
             docstring): one arena ``self.k``, ``self.v`` is None, and what
             carries ``(k, v)`` pairs -- ``kv_quant`` (a scale a head),
@@ -323,94 +462,132 @@ class BlockPool:
             vs a full-precision pool.  Chain export/adopt bundles the
             scale arrays atomically with the data (the host KV tier
             and hibernation ride this); disaggregated serving still
-            keeps full-precision pools.
+            keeps full-precision pools.  Scale arenas a class.
 
-    The jnp arenas are held as ``self.k`` / ``self.v`` (plus
-    ``self.ks`` / ``self.vs`` when quantized); callers that run donated
-    executables over them reassign the attributes with the donated
-    outputs (same contract as the slot engine's resident caches).
+    The jnp arenas are held a class (``self.classes[c].k`` ..); ``self.k`` /
+    ``self.v`` (plus ``self.ks`` / ``self.vs`` when quantized) are the
+    PRIMARY class's -- the first without a window, the one ``capacity``,
+    ``free_count`` and ``shape`` speak of -- and ``self.arenas`` every
+    class's side by side; callers that run donated executables over them
+    reassign the attribute with the donated outputs (same contract as the
+    slot engine's resident caches).
     """
 
-    def __init__(self, *, n_layers: int, n_heads: int, head_dim: int,
-                 block_len: int, num_blocks: int, dtype=None,
-                 kv_quant: Optional[str] = None, latent: bool = False):
-        import jax.numpy as jnp
-
+    def __init__(self, *, n_layers: Optional[int] = None,
+                 n_heads: Optional[int] = None,
+                 head_dim: Optional[int] = None,
+                 block_len: int, num_blocks: Optional[int] = None, dtype=None,
+                 kv_quant: Optional[str] = None, latent: bool = False,
+                 classes: Optional[Sequence[dict]] = None):
         self.latent = bool(latent)
-        if self.latent and (kv_quant is not None or n_heads != 1):
+        if classes is None:
+            classes = [dict(n_layers=n_layers, n_heads=n_heads,
+                            head_dim=head_dim)]
+        if self.latent and (kv_quant is not None or len(classes) > 1
+                            or classes[0]["n_heads"] != 1):
             raise ValueError("a latent pool holds one full-precision row a "
                              "position: n_heads 1, no kv_quant")
-
         if block_len < 1:
             raise ValueError(f"block_len must be >= 1, got {block_len}")
-        if num_blocks < 2:
-            raise ValueError(
-                f"num_blocks must be >= 2 (block 0 is scratch), got "
-                f"{num_blocks}")
         if kv_quant not in (None, "int8"):
             raise ValueError(
                 f"kv_quant must be None or 'int8', got {kv_quant!r}")
         self.block_len = int(block_len)
-        self.num_blocks = int(num_blocks)
-        self.n_layers, self.n_heads, self.head_dim = (
-            int(n_layers), int(n_heads), int(head_dim))
-        #: the arenas' shape (module docstring): the lane padding follows
-        #: from the geometry the pool is built with, nothing selects it
-        self.shape = (self.n_layers, self.num_blocks, self.block_len,
-                      row_width(self.n_heads, self.head_dim))
-        self.scale_shape = self.shape[:2] + (
-            _whole_tiles(self.block_len * self.n_heads),)
-        #: one block on the wire / in the host tier: (L, H, block_len, D)
-        self.wire_shape = (self.n_layers, self.n_heads, self.block_len,
-                           self.head_dim)
         self.kv_quant = kv_quant
-        if kv_quant == "int8":
-            self.k = jnp.zeros(self.shape, jnp.int8)
-            self.v = jnp.zeros(self.shape, jnp.int8)
-            # per-(position, head) scales, block-major like the arenas
-            self.ks = jnp.zeros(self.scale_shape, jnp.float32)
-            self.vs = jnp.zeros(self.scale_shape, jnp.float32)
-        else:
-            dt = dtype if dtype is not None else jnp.float32
-            self.k = jnp.zeros(self.shape, dt)
-            self.v = None if self.latent else jnp.zeros(self.shape, dt)
-            self.ks = self.vs = None
-        self.dtype = self.k.dtype
+        self.classes: List[KVClass] = [
+            KVClass(n_layers=c["n_layers"], n_heads=c["n_heads"],
+                    head_dim=c["head_dim"], v_dim=c.get("v_dim"),
+                    window=c.get("window"), block_len=self.block_len,
+                    num_blocks=(c.get("num_blocks") or num_blocks or 0),
+                    dtype=dtype, kv_quant=kv_quant, latent=self.latent)
+            for c in classes]
+        #: a chain entry is a tuple of ids, one a class (else: a block id)
+        self._multi = len(self.classes) > 1
+        #: the classes with a window, which let go of what lies behind it
+        self.windowed = tuple(i for i, c in enumerate(self.classes)
+                              if c.window is not None)
+        full = [i for i, c in enumerate(self.classes) if c.window is None]
+        self._primary = self.classes[full[0] if full else 0]
+        self.dtype = self._primary.k.dtype
         self._lock = threading.Lock()
-        # pop() from the tail hands out ascending ids first
-        self._free: List[int] = list(range(self.num_blocks - 1, 0, -1))
-        self._ref = [0] * self.num_blocks
         self._adopt_jits: dict = {}  # padded wire width -> donated scatter
+        #: blocks of the windowed classes let go behind a window, in all
+        self.window_released = 0
+
+    # -- the primary class's geometry, as a pool of one class states it ---- #
+    num_blocks = property(lambda self: self._primary.num_blocks)
+    n_layers = property(lambda self: self._primary.n_layers)
+    n_heads = property(lambda self: self._primary.n_heads)
+    head_dim = property(lambda self: self._primary.head_dim)
+    shape = property(lambda self: self._primary.shape)
+    scale_shape = property(lambda self: self._primary.scale_shape)
+
+    def _arena(name):      # the primary class's arena, read and assigned
+        return property(lambda self: getattr(self._primary, name),
+                        lambda self, new: setattr(self._primary, name, new))
+
+    k, v, ks, vs = _arena("k"), _arena("v"), _arena("ks"), _arena("vs")
+    del _arena
+
+    @property
+    def wire_shape(self) -> tuple:
+        """One block on the wire / in the host tier: (L, H, block_len, D)."""
+        c = self._primary
+        return (c.n_layers, c.n_heads, self.block_len, c.head_dim)
 
     @property
     def arenas(self) -> tuple:
-        """``(k, v)``, quantized ``(k, v, ks, vs)``, or a latent pool's one
-        ``(rows,)``: what the donated executables take and hand back
-        (assign their outputs here)."""
-        if self.latent:
-            return (self.k,)
-        return ((self.k, self.v) if self.ks is None
-                else (self.k, self.v, self.ks, self.vs))
+        """``(k, v)``, quantized ``(k, v, ks, vs)``, a latent pool's one
+        ``(rows,)``, or several classes' ``(k, v)`` side by side in class
+        order: what the donated executables take and hand back (assign their
+        outputs here)."""
+        return tuple(a for c in self.classes for a in c.arenas)
 
     @arenas.setter
     def arenas(self, new) -> None:
-        if self.latent:
-            (self.k,) = new
-        elif self.ks is None:
-            self.k, self.v = new
-        else:
-            self.k, self.v, self.ks, self.vs = new
+        new, at = tuple(new), 0
+        for c in self.classes:
+            n = len(c.arenas)
+            c.arenas = new[at:at + n]
+            at += n
+
+    def _ids(self, entry) -> tuple:
+        """A chain entry's block id a class."""
+        return tuple(entry) if self._multi else (entry,)
+
+    def entry(self, ids):
+        """The chain entry of one id a class."""
+        return tuple(int(b) for b in ids) if self._multi else int(ids[0])
+
+    def table(self, chain, width: int):
+        """A chain as scratch-padded tables, one row a class: (C, width)
+        int32."""
+        import numpy as np
+        out = np.zeros((len(self.classes), int(width)), np.int32)
+        if len(chain):
+            out[:, :len(chain)] = np.asarray(chain, np.int32).reshape(
+                len(chain), -1).T
+        return out
+
+    def whole(self, entry) -> bool:
+        """Whether the entry still holds a block in every class."""
+        return all(b != SCRATCH_BLOCK for b in self._ids(entry))
 
     # -- capacity -------------------------------------------------------- #
     @property
     def capacity(self) -> int:
-        """Usable blocks (scratch excluded)."""
-        return self.num_blocks - 1
+        """Usable blocks of the primary class (scratch excluded)."""
+        return self._primary.capacity
 
     @property
     def free_count(self) -> int:
         with self._lock:
-            return len(self._free)
+            return len(self._primary._free)
+
+    def free_in(self, c: int) -> int:
+        """Free blocks of class ``c``."""
+        with self._lock:
+            return len(self.classes[c]._free)
 
     @property
     def used_count(self) -> int:
@@ -425,22 +602,30 @@ class BlockPool:
     def row_bytes(self) -> int:
         """One position's row in one layer as the arenas hold it, lane
         padding included (both of a pair)."""
-        return self.data_arenas * self.shape[3] * self.dtype.itemsize
+        c = self._primary
+        return (c.shape[3] + (0 if self.latent else c.v_shape[3])
+                ) * self.dtype.itemsize
 
     @property
     def kv_arena_bytes(self) -> int:
-        """HBM footprint of the data arenas alone (k + v, or the latent
-        rows')."""
-        return self.data_arenas * self.k.size * self.k.dtype.itemsize
+        """HBM footprint of the data arenas alone (k + v of every class, or
+        the latent rows')."""
+        return sum(c.kv_arena_bytes for c in self.classes)
 
     @property
     def scale_arena_bytes(self) -> int:
         """HBM footprint of the int8 per-(position, head) scale arenas
         (0 for a full-precision pool) — ledgered separately from the
         data arenas so quantized capacity planning sees the overhead."""
-        if self.ks is None:
-            return 0
-        return 2 * self.ks.size * self.ks.dtype.itemsize
+        return sum(2 * c.ks.size * c.ks.dtype.itemsize
+                   for c in self.classes if c.ks is not None)
+
+    def _one_class(self, what: str) -> None:
+        if self._multi or self.windowed:
+            raise NotImplementedError(
+                f"{what}: a chain of several classes, or of one that lets go "
+                f"of what lies behind its window, has no wire format "
+                f"(ROADMAP M3)")
 
     @property
     def arena_bytes(self) -> int:
@@ -464,49 +649,127 @@ class BlockPool:
         import numpy as np
         chain = np.asarray(chain, np.int32)
         positions = np.asarray(positions, np.int64)
+        self._one_class("rows_at")
         blk, off = chain[positions // self.block_len], positions % self.block_len
-        lanes = self.n_heads * self.head_dim
-        return tuple(np.asarray(a[:, blk, off, :lanes])
-                     for a in self.arenas[:self.data_arenas])
+        c = self._primary
+        return tuple(np.asarray(a[:, blk, off, :c.n_heads * d])
+                     for a, d in zip(c.arenas[:self.data_arenas],
+                                     (c.head_dim, c.v_dim)))
 
     # -- alloc / refcount ------------------------------------------------ #
     def alloc(self, n: int) -> List[int]:
-        """Take ``n`` blocks at refcount 1; all-or-nothing."""
+        """Take ``n`` chain entries at refcount 1; all-or-nothing.  A class
+        with a window allots NOTHING here (its ids are the scratch block's):
+        its blocks come as the sequence reaches them (:meth:`advance`)."""
         n = int(n)
         if n <= 0:
             return []
         with self._lock:
-            if n > len(self._free):
-                raise PoolExhausted(
-                    f"need {n} blocks, {len(self._free)} free "
-                    f"(capacity {self.capacity})")
-            out = [self._free.pop() for _ in range(n)]
-            for b in out:
-                self._ref[b] = 1
-        return out
+            for i, c in enumerate(self.classes):
+                if c.window is None and n > len(c._free):
+                    raise PoolExhausted(
+                        f"need {n} blocks, {len(c._free)} free "
+                        f"(capacity {c.capacity}"
+                        + (f", class {i})" if self._multi else ")"))
+            ids = []
+            for c in self.classes:
+                if c.window is not None:
+                    ids.append([SCRATCH_BLOCK] * n)
+                    continue
+                ids.append([c._free.pop() for _ in range(n)])
+                for b in ids[-1]:
+                    c._ref[b] = 1
+        return [self.entry(e) for e in zip(*ids)]
+
+    def advance(self, chain: list, marks: list, pos: int, upto: int) -> tuple:
+        """Move a sequence's WINDOWED classes on, in place: a query at
+        ``pos`` or later is all that is still to run and positions below
+        ``upto`` are about to be written.  Of each such class the sequence's
+        reference on every block that lies wholly behind ``pos - window + 1``
+        is dropped (the entry's id becomes the scratch block's; ``marks[c]``,
+        the sequence's own note, is the first entry not yet let go) and the
+        entries from there up to ``upto``'s block that hold none are
+        allotted.  -> (blocks released, blocks allotted); raises
+        :class:`PoolExhausted`, naming the class, with nothing allotted."""
+        released = allotted = 0
+        if not self.windowed:
+            return released, allotted
+        B = self.block_len
+        hi = min(-(-int(upto) // B), len(chain))
+        with self._lock:
+            plan = []
+            for c in self.windowed:
+                cls = self.classes[c]
+                keep = max(0, int(pos) - cls.window + 1) // B
+                want = [i for i in range(max(keep, marks[c]), hi)
+                        if self._ids(chain[i])[c] == SCRATCH_BLOCK]
+                freed = sum(
+                    1 for i in range(marks[c], min(keep, len(chain)))
+                    if self._ids(chain[i])[c] != SCRATCH_BLOCK
+                    and cls._ref[self._ids(chain[i])[c]] == 1)
+                if len(want) > len(cls._free) + freed:
+                    raise PoolExhausted(
+                        f"need {len(want)} blocks of class {c} (window "
+                        f"{cls.window}), {len(cls._free)} free (capacity "
+                        f"{cls.capacity})")
+                plan.append((c, cls, keep, want))
+            for c, cls, keep, want in plan:
+                for i in range(marks[c], min(keep, len(chain))):
+                    ids = list(self._ids(chain[i]))
+                    if ids[c] != SCRATCH_BLOCK:
+                        self._drop(cls, ids[c])
+                        ids[c] = SCRATCH_BLOCK
+                        chain[i] = self.entry(ids)
+                        released += 1
+                marks[c] = max(marks[c], min(keep, len(chain)))
+                for i in want:
+                    ids = list(self._ids(chain[i]))
+                    ids[c] = cls._free.pop()
+                    cls._ref[ids[c]] = 1
+                    chain[i] = self.entry(ids)
+                    allotted += 1
+            self.window_released += released
+        return released, allotted
+
+    def held(self, chain, c: int) -> int:
+        """Blocks of class ``c`` the chain's entries hold."""
+        return sum(self._ids(e)[c] != SCRATCH_BLOCK for e in chain)
+
+    @staticmethod
+    def _drop(cls: KVClass, b: int) -> None:
+        if cls._ref[b] <= 0:
+            raise ValueError(f"release of free block {b}")
+        cls._ref[b] -= 1
+        if cls._ref[b] == 0:
+            cls._free.append(b)
 
     def retain(self, blocks: Sequence[int]) -> None:
-        """Add one reference to each (already-live) block."""
+        """Add one reference to each (already-live) entry, in every class
+        (a windowed class's entry that was let go holds nothing)."""
         with self._lock:
-            for b in blocks:
-                if self._ref[b] <= 0:
-                    raise ValueError(f"retain of free block {b}")
-                self._ref[b] += 1
+            for e in blocks:
+                for cls, b in zip(self.classes, self._ids(e)):
+                    if b == SCRATCH_BLOCK and cls.window is not None:
+                        continue
+                    if cls._ref[b] <= 0:
+                        raise ValueError(f"retain of free block {b}")
+                    cls._ref[b] += 1
 
     def release(self, blocks: Sequence[int]) -> None:
-        """Drop one reference; a block at zero returns to the free
+        """Drop one reference; a block at zero returns to its class's free
         list."""
         with self._lock:
-            for b in blocks:
-                if self._ref[b] <= 0:
-                    raise ValueError(f"release of free block {b}")
-                self._ref[b] -= 1
-                if self._ref[b] == 0:
-                    self._free.append(b)
+            for e in blocks:
+                for cls, b in zip(self.classes, self._ids(e)):
+                    if b == SCRATCH_BLOCK and cls.window is not None:
+                        continue
+                    self._drop(cls, b)
 
-    def refcount(self, block: int) -> int:
+    def refcount(self, block) -> int:
+        """An entry's references: the most any class's block of it has."""
         with self._lock:
-            return self._ref[block]
+            return max(cls._ref[b]
+                       for cls, b in zip(self.classes, self._ids(block)))
 
     # -- migration (disaggregated prefill/decode) ------------------------ #
     @property
@@ -591,6 +854,7 @@ class BlockPool:
         return out
 
     def _pairs_only(self, what: str) -> None:
+        self._one_class(what)
         if self.latent:
             raise NotImplementedError(
                 f"{what}: the wire format is a (k, v) pair, and this pool "
@@ -763,8 +1027,10 @@ class BlockPool:
     # -- introspection --------------------------------------------------- #
     def stats(self) -> dict:
         with self._lock:
-            free = len(self._free)
+            free = len(self._primary._free)
+            classes = [c.stats() for c in self.classes]
         return {
+            "classes": classes,
             "num_blocks": self.num_blocks,
             "block_len": self.block_len,
             "capacity": self.capacity,
@@ -780,4 +1046,5 @@ class BlockPool:
                     else "a (k, v) pair a K/V head"),
             "row_lanes": self.n_heads * self.head_dim,
             "row_bytes": self.row_bytes,
+            "window_blocks_released": self.window_released,
         }

@@ -37,10 +37,19 @@ events.  The block is still allocated while the callback runs (its
 k/v rows are gatherable); a callback that raises is logged and the
 eviction proceeds — a flaky demotion target must not wedge the pool.
 
+A pool of SEVERAL CLASSES of blocks (``kvcache.blocks``): a node's block is
+the chain ENTRY -- one block id a class -- and the trie holds a reference in
+every class, hands all of them out on a match and frees them together.  A
+live sequence that lets go of what lies behind a class's window drops its
+OWN reference alone, so a hit beside such a donor reads what a cold prefill
+would write; an entry the inserting sequence has already let go of (a chunked
+prefill's earlier blocks) cannot be adopted, and ``insert`` stops at the
+first such entry.
+
 Thread model: the serving worker is the only mutator; counters are
 lock-guarded so stats/metrics reads from other threads are consistent.
-Eviction rescans the trie per freed block — fine at serving scale
-(trie size is bounded by the pool's block count).
+Eviction walks the trie once a call (trie size is bounded by the pool's
+block count).
 """
 from __future__ import annotations
 
@@ -186,10 +195,13 @@ class RadixCache:
                 key = self._block_key(tokens0, i)
                 child = node.children.get(key)
                 if child is None:
-                    child = _Node(key, int(blk), node, now,
+                    if not self.pool.whole(blk):
+                        break   # let go behind a window: nothing to adopt
+                    blk = blk if isinstance(blk, tuple) else int(blk)
+                    child = _Node(key, blk, node, now,
                                   sig=_sig_extend(node.sig, key))
                     node.children[key] = child
-                    self.pool.retain([int(blk)])
+                    self.pool.retain([blk])
                     self.nodes += 1
                     self.inserted_blocks += 1
                     added += 1
@@ -230,11 +242,11 @@ class RadixCache:
         hook = self.on_evict
         if hook is not None:
             try:
-                hook(self._path_of(v), int(v.block))
+                hook(self._path_of(v), v.block)
             except Exception:  # noqa: BLE001 — a failing demotion
                 # target degrades the eviction to a plain drop
                 log.exception("radix on_evict hook failed; dropping "
-                              "block %d", v.block)
+                              "block %s", v.block)
         del v.parent.children[v.key]
         self.pool.release([v.block])
         self.nodes -= 1
@@ -245,17 +257,25 @@ class RadixCache:
     def evict(self, n_blocks: int) -> int:
         """Free up to ``n_blocks`` pool blocks by dropping LRU leaf
         nodes whose block has no holder but the trie (refcount 1).
-        Returns how many blocks were actually freed."""
+        Returns how many blocks were actually freed.  ONE walk of the trie a
+        call: the leaves in a heap by age, a parent joining it when its last
+        child goes (an admission that is short of a windowed class's blocks
+        evicts some tens at a time from a trie of thousands of nodes)."""
+        import heapq
         target = max(1, int(n_blocks))
         freed = 0
         with self._lock:
-            while freed < target:
-                victims = [n for n in self._leaves()
-                           if self.pool.refcount(n.block) == 1]
-                if not victims:
-                    break
-                self._evict_node(min(victims, key=lambda n: n.last_used))
+            heap = [(n.last_used, id(n), n) for n in self._leaves()]
+            heapq.heapify(heap)
+            while freed < target and heap:
+                _, _, v = heapq.heappop(heap)
+                if v.children or self.pool.refcount(v.block) != 1:
+                    continue
+                parent = v.parent
+                self._evict_node(v)
                 freed += 1
+                if parent is not self._root and not parent.children:
+                    heapq.heappush(heap, (parent.last_used, id(parent), parent))
         return freed
 
     # -- router summary -------------------------------------------------- #

@@ -130,7 +130,7 @@ import operator
 import statistics
 import threading
 import time
-from collections import deque
+from collections import defaultdict, deque
 from typing import List, Optional, Sequence
 
 import numpy as np
@@ -148,7 +148,8 @@ from bigdl_tpu.serving.batcher import (ServingClosed, ServingQueueFull,
 from bigdl_tpu.serving.compile_cache import CompileCache
 from bigdl_tpu.serving.kvcache import (BlockPool, PoolExhausted, RadixCache,
                                        RequestExceedsPool)
-from bigdl_tpu.serving.kvcache.blocks import list_chunk, live_list
+from bigdl_tpu.serving.kvcache.blocks import (class_entries, list_chunk,
+                                              live_list, window_blocks)
 from bigdl_tpu.utils.engine import configure_compile_cache
 
 _tracer = get_tracer()
@@ -271,6 +272,10 @@ def split_selfdraft_operands(ops, slots: int, prev=None):
 #: row a (kind, what is asked, why); the next cache kind adds rows, not branches
 _KIND_NAMES = {"recurrent": ("recurrent layers", "M6"),
                "latent": ("latent attention layers", "M4"),
+               "windowed": ("layers whose window lets go of what lies "
+                            "behind it", "M3"),
+               "classes": ("softmax layers of several kinds (a class of "
+                           "blocks each)", "M3"),
                "self-drafting": ("its prediction module as the drafter",
                                  "M5")}
 _REFUSALS = (
@@ -303,6 +308,21 @@ _REFUSALS = (
      "up-projections' heads"),
     ("latent", "adopt a migrated request", "the handoff carries (k, v) "
      "chains, and the pool holds one latent row a position"),
+    ("windowed", "serve with migrate", "a windowed class has let go of the "
+     "blocks behind the window: there is no whole chain to hand off"),
+    ("windowed", "serve with kvtier", "demotion, promotion and hibernation "
+     "carry whole chains, and a windowed class has let go of what lies "
+     "behind the window"),
+    ("windowed", "adopt a migrated request", "the handoff carries whole "
+     "chains, and a windowed class holds a window's blocks"),
+    ("classes", "serve with tree verify", "the accepted path's commit moves "
+     "one class's rows"),
+    ("classes", "serve with migrate", "the handoff's wire format is one "
+     "(k, v) pair of one width"),
+    ("classes", "serve with kvtier", "the host tier's wire format is one "
+     "(k, v) pair of one width"),
+    ("classes", "adopt a migrated request", "the handoff carries one (k, v) "
+     "pair of one width"),
 )
 
 
@@ -325,8 +345,11 @@ def refuse_unsupported(model, *, spec=None, migrate=None, kvtier=None,
     pool refuse what assumes a paged ``(k, v)`` pair, and a self-drafting
     model what one module cannot draft, at construction and where a handoff
     arrives (``adopt``)."""
+    classes = model.cache_classes
     has = {"recurrent": bool(model.state_layers),
            "latent": bool(model.latent_layers),
+           "windowed": any(c.window is not None for c in classes),
+           "classes": len(classes) > 1,
            "self-drafting": drafts_for_itself(model, spec)}
     k = spec if isinstance(spec, int) else getattr(spec, "k", 1)
     asked = {"serve with spec": spec is not None,
@@ -629,6 +652,20 @@ class LMMetrics:
         self.state_row_steps = 0
         self.state_rows_in_use = 0
         self.state_bytes = 0
+        # softmax layers by class of blocks: positions a layer of a class
+        # without a window may see in the decode rounds, summed over active
+        # slots and rounds, and the same of a class with one (min(context,
+        # window) a slot); blocks of the windowed classes let go behind a
+        # window and allotted ahead of it; the most a decoding sequence held
+        # of one after a round's release; and what the active slots held of
+        # them against what their contexts span, summed over rounds
+        self.decode_context_tokens = 0
+        self.decode_window_tokens = 0
+        self.window_blocks_released = 0
+        self.window_blocks_allotted = 0
+        self.window_blocks_held_max = 0
+        self.window_blocks_held = 0
+        self.window_blocks_spanned = 0
         self.started_at = time.perf_counter()
         self._window_s = float(throughput_window_s)
         self._recent: deque = deque()  # (t, n_tokens) per decode step
@@ -646,7 +683,9 @@ class LMMetrics:
         for key in ("requests", "rejected", "completed", "tokens",
                     "prefills", "decode_steps", "live_blocks",
                     "gathered_blocks", "logit_rows_to_host",
-                    "rounds_ahead", "rows_discarded"):
+                    "rounds_ahead", "rows_discarded",
+                    "decode_context_tokens", "decode_window_tokens",
+                    "window_blocks_released", "window_blocks_held_max"):
             registry.register(prefix + key,
                               FnGauge(lambda k=key: getattr(self, k)),
                               replace=True)
@@ -699,10 +738,19 @@ class LMMetrics:
                     prefill_interrupted: bool = False, *,
                     live_blocks: int = 0, gathered_blocks: int = 0,
                     state_rows: int = 0, latent_rows: int = 0,
-                    ahead: bool = False, discarded: int = 0) -> None:
+                    ahead: bool = False, discarded: int = 0,
+                    ctx_tokens: int = 0, window_tokens: int = 0,
+                    window_held: int = 0, window_spanned: int = 0,
+                    window_held_max: int = 0) -> None:
         with self._lock:
             now = time.perf_counter()
             self.decode_steps += 1
+            self.decode_context_tokens += ctx_tokens
+            self.decode_window_tokens += window_tokens
+            self.window_blocks_held += window_held
+            self.window_blocks_spanned += window_spanned
+            self.window_blocks_held_max = max(self.window_blocks_held_max,
+                                              window_held_max)
             self.rounds_ahead += ahead
             self.rows_discarded += discarded
             self.live_blocks += live_blocks
@@ -727,6 +775,13 @@ class LMMetrics:
     def record_complete(self) -> None:
         with self._lock:
             self.completed += 1
+
+    def record_window(self, released: int, allotted: int) -> None:
+        """Blocks of the windowed classes a sequence let go of behind its
+        window, and was allotted ahead of it."""
+        with self._lock:
+            self.window_blocks_released += int(released)
+            self.window_blocks_allotted += int(allotted)
 
     def record_admission(self, prompt_tokens: int, matched: int) -> None:
         """A request got its blocks: its prompt's length and how much of it
@@ -861,6 +916,13 @@ class LMMetrics:
                 "state": {"row_steps": self.state_row_steps,
                           "rows_in_use": self.state_rows_in_use,
                           "bytes": self.state_bytes},
+                "decode_context_tokens": self.decode_context_tokens,
+                "decode_window_tokens": self.decode_window_tokens,
+                "window_blocks_released": self.window_blocks_released,
+                "window_blocks_allotted": self.window_blocks_allotted,
+                "window_blocks_held_max": self.window_blocks_held_max,
+                "window_blocks_held": self.window_blocks_held,
+                "window_blocks_spanned": self.window_blocks_spanned,
                 "tokens_per_s": (windowed / span) if span > 0 else 0.0,
                 "slot_occupancy":
                     (self.active_slot_steps / self.slot_steps)
@@ -899,10 +961,10 @@ class _Slot:
                  "temperature", "eos0", "step_keys", "last_emit_at",
                  "blocks", "table", "draft_ok", "demoted", "accept_ema",
                  "spec_rounds", "probe_in", "tree_rung", "rid", "replay",
-                 "draft", "fresh")
+                 "draft", "fresh", "marks")
 
     def __init__(self, req: _Request, prompt_len: int, first0: int,
-                 blocks: List[int], table: np.ndarray):
+                 blocks: List[int], table: np.ndarray, marks=None):
         self.stream = req.stream
         self.rid = req.rid
         self.pos_next = prompt_len      # next cache position to write
@@ -914,7 +976,9 @@ class _Slot:
         self.step_keys = req.step_keys
         self.last_emit_at = time.perf_counter()
         self.blocks = blocks            # one pool ref per block
-        self.table = table              # (M,) int32, scratch-padded
+        self.table = table              # (C, M) int32, scratch-padded
+        # a windowed class's first entry not yet let go (BlockPool.advance)
+        self.marks = marks if marks is not None else defaultdict(int)
         # already-emitted 0-based tokens whose KV the decode loop must
         # rebuild (payload-less resume): forced through decode without
         # re-emitting, so the rebuilt rows ride the exact path that
@@ -943,7 +1007,9 @@ class _Round:
     worked out on the device."""
 
     __slots__ = ("ids", "moe", "rows", "t0", "ahead", "n_live", "gathered",
-                 "n_last", "n_positions", "sampled")
+                 "n_last", "n_positions", "sampled", "ctx_tokens",
+                 "window_tokens", "window_held", "window_spanned",
+                 "window_held_max")
 
     def __init__(self, t0: float, ahead: bool):
         self.ids = self.moe = None      # device arrays, on their way
@@ -952,6 +1018,11 @@ class _Round:
         self.ahead = ahead              # enqueued behind a round in flight
         self.n_live = self.gathered = self.n_last = 0
         self.n_positions = 0            # live positions its slots hold
+        # positions a layer of its slots may see, summed over the slots: of a
+        # class without a window, and of one with (min(context, window))
+        self.ctx_tokens = self.window_tokens = 0
+        # blocks of the windowed classes its slots hold / their contexts span
+        self.window_held = self.window_spanned = self.window_held_max = 0
         self.sampled: list = []         # (rid, slot, step) of traced requests
 
 
@@ -1022,7 +1093,7 @@ class _Prefill:
     prefill: blocks are allocated, ``p`` tokens are in the arena."""
 
     __slots__ = ("req", "blocks", "slot", "p", "t", "logits", "handoff",
-                 "moe", "h_last")
+                 "moe", "h_last", "marks")
 
     def __init__(self, req: _Request, blocks: List[int], slot: int,
                  matched_len: int, handoff: Optional[KVHandoff] = None):
@@ -1035,6 +1106,8 @@ class _Prefill:
         self.moe = None                 # routed layers' counts, on the device
         self.h_last = None              # self-drafting: the hidden state at p - 1
         self.handoff = handoff          # set: re-prefill, don't re-emit
+        # a windowed class's first entry not yet let go (BlockPool.advance)
+        self.marks = defaultdict(int)
 
 
 # ---------------------------------------------------------------------- #
@@ -1263,28 +1336,67 @@ class LMServingEngine:
                 self.block_len,
                 (self.max_prefill_chunk_tokens
                  // self.block_len) * self.block_len)
-        if num_blocks is None:
-            # slots worst-case chains + headroom for radix-held prefixes
-            num_blocks = 1 + (self.slots + 4) * self.table_width
         # the pool's geometry is the K/V heads': a model whose query heads
         # share them in groups stores (and moves) the shared heads only,
-        # and only its attention layers have an arena layer
+        # and only its attention layers have an arena layer, a CLASS of
+        # blocks a kind of softmax layer (``model.cache_classes``)
         # -- or the latent rows': a model none of whose layers keeps a
         # (k, v) pair gets no (k, v) arena at all
+        self._classes = model.cache_classes
         if self._latent_layers:
-            L, H, D = (self._latent_layers + self._selfdraft, 1,
-                       model.mla.row)
+            classes = [dict(n_layers=self._latent_layers + self._selfdraft,
+                            n_heads=1, head_dim=model.mla.row)]
         else:
-            L, H, D = len(model.kv_layers), model.n_kv_head, model.head_dim
-        if not L:
+            classes = [dict(n_layers=len(c.layers), n_heads=c.n_kv,
+                            head_dim=c.k_dim, v_dim=c.v_dim, window=c.window)
+                       for c in self._classes]
+        if not classes:
             raise ValueError("the paged engine needs at least one attention "
                              "or latent attention layer in the model's plan")
+        #: the blocks of the largest piece a prefill runs at once
+        chunk_blocks = -(-min(self.prefill_buckets[-1],
+                              self._chunk_cap or self.prefill_buckets[-1])
+                         // self.block_len)
+        if not isinstance(num_blocks, (list, tuple)):
+            if num_blocks is None:
+                # slots worst-case chains + headroom for radix-held prefixes
+                num_blocks = 1 + (self.slots + 4) * self.table_width
+            # the PRIMARY class's (the first without a window); a class with
+            # a window is sized from the slots and the window: what every
+            # slot holds of it while it decodes, a chunk in flight, and the
+            # prompts' blocks that the prefix cache keeps beside live
+            # sequences -- never more than the primary's
+            primary = next((i for i, c in enumerate(classes)
+                            if c.get("window") is None), 0)
+            num_blocks = [
+                int(num_blocks) if i == primary else min(
+                    int(num_blocks),
+                    1 + chunk_blocks + self.slots * (
+                        window_blocks(c["window"], self.block_len) + 1
+                        + chunk_blocks))
+                for i, c in enumerate(classes)]
+        if len(num_blocks) != len(classes):
+            raise ValueError(f"num_blocks names {len(num_blocks)} classes, "
+                             f"the model's plan has {len(classes)}")
+        for c, n in zip(classes, num_blocks):
+            c["num_blocks"] = int(n)
+        self._chunk_blocks = chunk_blocks
         dt = self._params["embed"].dtype
-        self.pool = BlockPool(n_layers=L, n_heads=H, head_dim=D,
-                              block_len=self.block_len,
-                              num_blocks=num_blocks, dtype=dt,
-                              kv_quant=kv_quant,
+        self.pool = BlockPool(classes=classes, block_len=self.block_len,
+                              dtype=dt, kv_quant=kv_quant,
                               latent=bool(self._latent_layers))
+        #: entries of a round's live list a class, side by side in one list
+        self._live_entries = [
+            class_entries(self.slots, self.table_width, c.window,
+                          self.block_len) for c in self.pool.classes]
+        self._windows = [c.window for c in self.pool.classes]
+        #: what a sequence may hold of each windowed class while it decodes
+        #: (the window's blocks and the one being written)
+        self._window_need = {
+            i: min(self.table_width,
+                   window_blocks(self.pool.classes[i].window,
+                                 self.block_len) + 1)
+            for i in self.pool.windowed}
         self.kv_quant = self.pool.kv_quant
         _kvq = self.kv_quant is not None
         if placement is not None:
@@ -1330,8 +1442,11 @@ class LMServingEngine:
         # prefix-chain pad buckets (powers of two up to the table width; a
         # latent layer walks its prefix as far as it reaches, whatever the
         # padding behind it: one bucket, one suffix executable a chunk bucket)
+        from bigdl_tpu.models.transformer.generate import walks_prefix
         self._prefix_block_buckets = (
-            (self.table_width,) if self._latent_layers else
+            (self.table_width,)
+            if self._latent_layers or walks_prefix(self.table_width,
+                                                   self.block_len) else
             prefill_bucket_lengths(self.table_width, min_bucket=1))
 
         # -- the device programs ---------------------------------------- #
@@ -1377,9 +1492,11 @@ class LMServingEngine:
 
         # query heads that share K/V heads, and layers with a window: what
         # of a (k, v) pool the grouped kernel reads (ops.grouped_attention)
-        _shared = model.n_kv_head != model.n_head
-        _windows = any(s.window is not None
-                       for _, period in model.plan for s in period)
+        _specs = [s for _, period in model.plan for s in period
+                  if s.mixer == "attention"]
+        _shared = any(s.n_head != model.kv_heads(s) or s.sink
+                      for s in _specs) or model.v_dim != model.head_dim
+        _windows = any(s.window is not None for s in _specs)
 
         def _check_kernel_shapes():
             # raises for a pool geometry the COMPILED kernel cannot read
@@ -1392,8 +1509,10 @@ class LMServingEngine:
                 check_latent_kernel_shapes(self.block_len,
                                            self.pool.shape[-1], dt)
             elif _shared or _windows:
-                check_grouped_kernel_shapes(self.block_len,
-                                            self.pool.shape[-1], D, dt)
+                for c in self.pool.classes:
+                    check_grouped_kernel_shapes(
+                        self.block_len, c.shape[-1], c.head_dim, dt,
+                        c.v_dim, c.n_heads, c.v_shape[-1])
             else:
                 check_paged_kernel_shapes(self.block_len, dt)
 
@@ -1427,7 +1546,7 @@ class LMServingEngine:
             # kernel only on tuned evidence for this device kind, the
             # proven XLA gather otherwise
             from bigdl_tpu.ops import autotune
-            tuned = autotune.lookup_paged(D, self.block_len, dt)
+            tuned = autotune.lookup_paged(model.head_dim, self.block_len, dt)
             decode_attn = ("paged_kernel"
                            if tuned is not None and tuned.use_kernel
                            else "gather")
@@ -1480,7 +1599,7 @@ class LMServingEngine:
 
         #: a round's live blocks are attended this many at a time
         self._list_chunk = list_chunk(
-            self.slots, model.n_head != model.n_kv_head,
+            self.slots, any(s.n_head != model.kv_heads(s) for s in _specs),
             bool(self._latent_layers))
         #: self-drafting: a slot's hidden state at its prompt's end, kept
         #: from its prefill for its first round's first pair (S, hidden)
@@ -1502,6 +1621,23 @@ class LMServingEngine:
             self._insert_jit = jax.jit(_insert_hid, donate_argnums=(0, 1))
         elif self._latent_layers:
             self._insert_jit = jax.jit(_insert_rows, donate_argnums=(0,))
+        elif len(self.pool.classes) > 1:
+            _nc, _na = len(self.pool.classes), len(self.pool.classes[0].arenas)
+
+            def _insert_classes(*ops):
+                # every class's chunk rows into its own blocks: the arenas
+                # (k, v -- and an int8 pool's scales -- a class), the rows
+                # (k, v a class), the ids (C, nb)
+                kv, new, ids = ops[:_na * _nc], ops[_na * _nc:-1], ops[-1]
+                out = ()
+                for c in range(_nc):
+                    mine = kv[_na * c:_na * (c + 1)]
+                    out += tuple(_insert_blocks(
+                        *mine[:2], *new[2 * c:2 * c + 2], ids[c], *mine[2:]))
+                return out
+
+            self._insert_jit = jax.jit(
+                _insert_classes, donate_argnums=tuple(range(_na * _nc)))
         else:
             self._insert_jit = jax.jit(
                 _insert_blocks,
@@ -1624,7 +1760,7 @@ class LMServingEngine:
                      if not s.is_chain), default=0)
                 def _commit_fn(src, pos, tables, *kv):
                     return _constrain(_tree_commit_paged(
-                        src, pos, tables, *kv, n_heads=H))
+                        src, pos, tables, *kv, n_heads=self.pool.n_heads))
 
                 self._commit_jit = jax.jit(_commit_fn,
                                            donate_argnums=_donated(3))
@@ -1902,12 +2038,23 @@ class LMServingEngine:
                 x = {"ids": _np.zeros((1, b), _np.int32),
                      "len": _np.int32(b),
                      "prefix_len": _np.int32(pb * self.block_len),
-                     "blocks": _np.zeros((pb,), _np.int32),
+                     "blocks": self._by_class(_np.zeros(
+                         (len(self.pool.classes), pb), _np.int32)),
                      "kv": self.pool.arenas, **self._carried_operands(0),
                      **self._h_prev_operand(None)}
                 inputs.append(x)
         return self.prefix_prefill_cache.warmup_inputs(
             self._params, self._buffers, inputs)
+
+    def _by_class(self, rows):
+        """A class a row ``(C, ..)`` as the step programs take it: the one
+        row itself where the pool has one class."""
+        return rows if len(self.pool.classes) > 1 else rows[0]
+
+    def _tables_shape(self) -> tuple:
+        one = (self.slots, self.table_width)
+        n = len(self.pool.classes)
+        return (n,) + one if n > 1 else one
 
     def _arenas(self) -> tuple:
         """What the decode step takes last, donated, and hands back: the
@@ -1961,8 +2108,7 @@ class LMServingEngine:
                   if self.placement is not None else {})
             # one operand a round, the live list in it at the one length
             # that holds any round's
-            ops = decode_operands(self.slots,
-                                  self.slots * self.table_width)[0]
+            ops = decode_operands(self.slots, sum(self._live_entries))[0]
             self._decode_exec = self._decode_jit.lower(
                 self._params, jax.ShapeDtypeStruct(ops.shape, ops.dtype, **sh),
                 jax.ShapeDtypeStruct(self._ids.shape, self._ids.dtype, **sh),
@@ -1995,7 +2141,7 @@ class LMServingEngine:
             tok = sds((self.slots, w), np.int32, **sh)
             pos = sds((self.slots,), np.int32, **sh)
             ncand = sds((self.slots,), np.int32, **sh)
-            tables = sds((self.slots, self.table_width), np.int32, **sh)
+            tables = sds(self._tables_shape(), np.int32, **sh)
             self._verify_exec = self._verify_jit.lower(
                 self._params, tok, pos, ncand, tables,
                 *self.pool.arenas).compile()
@@ -2066,15 +2212,27 @@ class LMServingEngine:
             # latent pool's have no head axis
             new = sds((L, 1, bucket, D) if self.pool.latent
                       else (L, 1, H, bucket, D), self._cache_dtype, **sh)
+            vnew = new if self.pool.latent else sds(
+                (L, 1, H, bucket, self.pool.classes[0].v_dim),
+                self._cache_dtype, **sh)
             kv, n = self.pool.arenas, self.pool.data_arenas
             if self._selfdraft:
                 exe = self._insert_jit.lower(
                     kv[0], self._hid, new, sds((nb,), np.int32),
                     sds((1, self.model.hidden_size), self._cache_dtype),
                     sds((), np.int32)).compile()
+            elif len(self.pool.classes) > 1:
+                # a class's chunk rows: its layers, K/V heads and widths
+                rows = [sds((c.n_layers, 1, c.n_heads, bucket, d),
+                            self._cache_dtype, **sh)
+                        for c in self.pool.classes
+                        for d in (c.head_dim, c.v_dim)]
+                exe = self._insert_jit.lower(
+                    *kv, *rows, sds((len(self.pool.classes), nb), np.int32,
+                                    **sh)).compile()
             else:
                 exe = self._insert_jit.lower(
-                    *kv[:n], *[new] * n, sds((nb,), np.int32, **sh),
+                    *kv[:n], *[new, vnew][:n], sds((nb,), np.int32, **sh),
                     *kv[n:]).compile()
             self._insert_execs[bucket] = exe
             self._ledger_exec("insert", f"bucket={bucket}", exe)
@@ -2139,13 +2297,19 @@ class LMServingEngine:
         # admission and counted; anything smaller is admissible — pool
         # pressure merely defers it until streams free blocks
         need = self.pool.blocks_for(t + max_new)
-        if need > self.pool.capacity:
-            self.metrics.record_reject()
-            count_rejection()
-            raise RequestExceedsPool(
-                f"request needs {need} KV blocks ({t} prompt + {max_new} "
-                f"new tokens at block_len {self.block_len}); the whole "
-                f"pool holds {self.pool.capacity}")
+        for i, c in enumerate(self.pool.classes):
+            # a class with a window needs a window's blocks and a prefill
+            # chunk's, whatever the prompt's length
+            mine = need if c.window is None else self._window_claim(t, need)[i]
+            if mine > c.capacity:
+                self.metrics.record_reject()
+                count_rejection()
+                raise RequestExceedsPool(
+                    f"request needs {mine} KV blocks ({t} prompt + {max_new} "
+                    f"new tokens at block_len {self.block_len}); the whole "
+                    f"pool holds {c.capacity}"
+                    + (f" (class {i}, window {c.window})"
+                       if len(self.pool.classes) > 1 else ""))
         if self._chunk_full == 0:
             self.bucket_for(t)  # sub-block buckets: no chunked prefill
         temp = float(self.temperature if temperature is None
@@ -2898,11 +3062,13 @@ class LMServingEngine:
                             prompt_len=t)
         n_new = need_total - len(matched)
         try:
+            self._window_room(t - len(matched) * B, need_total)
             fresh = self.pool.alloc(n_new)
         except PoolExhausted:
             if self.radix is not None:
                 self.radix.evict(n_new - self.pool.free_count)
             try:
+                self._window_room(t - len(matched) * B, need_total)
                 fresh = self.pool.alloc(n_new)
             except PoolExhausted:
                 if matched:
@@ -2932,6 +3098,87 @@ class LMServingEngine:
             self.pool.release(blocks)
             raise
         return True
+
+    def _window_claim(self, prefill_tokens: int, total_blocks: int) -> dict:
+        """What a request may hold of each windowed class at once: the
+        window's blocks and the one being written, plus the blocks of the
+        largest piece its prefill runs (a chunk's, or the prompt's where it
+        is shorter) -- never more than its whole chain's ``total_blocks``."""
+        piece = min(self._chunk_blocks,
+                    self.pool.blocks_for(max(int(prefill_tokens), 0)) + 1)
+        return {i: min(int(total_blocks), need + piece)
+                for i, need in self._window_need.items()}
+
+    def _window_owed(self) -> dict:
+        """Blocks of each windowed class that the sequences in flight may
+        still come for: a decoding one the window's less what it holds, a
+        prefilling one its claim's (:meth:`_window_claim`)."""
+        owed = {i: 0 for i in self._window_need}
+        for st in self._slots:
+            if st is not None:
+                for i, need in self._window_need.items():
+                    owed[i] += max(0, need - self.pool.held(
+                        st.blocks[st.marks[i]:st.pos_next // self.block_len
+                                  + 1], i))
+        for pf in self._prefilling:
+            claim = self._window_claim(pf.t - pf.p, len(pf.blocks))
+            for i in owed:
+                owed[i] += max(0, claim[i] - self.pool.held(
+                    pf.blocks[pf.marks[i]:pf.p // self.block_len + 1], i))
+        return owed
+
+    def _window_room(self, prefill_tokens: int, total_blocks: int) -> None:
+        """Admission by the class that is short: a new request's claim on a
+        windowed class has to fit beside what the sequences in flight may
+        still come for (evicting what only the prefix cache holds first),
+        else :class:`PoolExhausted`, which defers it."""
+        if not self._window_need:
+            return
+        claim, owed = (self._window_claim(prefill_tokens, total_blocks),
+                       self._window_owed())
+        for i in claim:
+            short = claim[i] + owed[i] - self.pool.free_in(i)
+            if short > 0 and self.radix is not None:
+                self.radix.evict(short)
+                short = claim[i] + owed[i] - self.pool.free_in(i)
+            if short > 0:
+                raise PoolExhausted(
+                    f"class {i} (window {self.pool.classes[i].window}) is "
+                    f"{short} blocks short of the request's {claim[i]}")
+
+    def _advance(self, holder, pos: int, upto: int) -> None:
+        """Move ``holder``'s (a slot's, a prefill's) windowed classes on
+        (``BlockPool.advance``): let go of what lies behind the window of a
+        query at ``pos``, allot what positions below ``upto`` are written
+        to; a class that is short evicts what only the prefix cache holds
+        and tries once more."""
+        if not self.pool.windowed:
+            return
+        marks = holder.marks
+        # nothing to do until the write position leaves the blocks allotted
+        # or a block falls wholly behind a window (every block_len rounds of
+        # a decoding slot): the sequence's own two notes, beside its marks
+        if upto <= marks["upto"] and pos < marks["pos"]:
+            return
+        lo = min(marks[i] for i in self.pool.windowed)
+        try:
+            out = self.pool.advance(holder.blocks, marks, pos, upto)
+        except PoolExhausted:
+            if self.radix is None:
+                raise
+            self.radix.evict(self._chunk_blocks)
+            out = self.pool.advance(holder.blocks, marks, pos, upto)
+        B = self.block_len
+        marks["upto"] = min(self.pool.blocks_for(upto), len(holder.blocks)) * B
+        marks["pos"] = min((marks[i] + 1) * B + self._windows[i] - 1
+                           for i in self.pool.windowed)
+        if any(out):
+            self.metrics.record_window(*out)
+            table = getattr(holder, "table", None)
+            if table is not None:       # a slot's: the entries that moved
+                hi = min(self.pool.blocks_for(upto), len(holder.blocks))
+                table[:, lo:hi] = self.pool.table(holder.blocks[lo:hi],
+                                                  hi - lo)
 
     def _adopt_into(self, slot: int, h: KVHandoff) -> bool:
         """Seat a migrated request into ``slot``: adopt its wire
@@ -3305,9 +3552,8 @@ class LMServingEngine:
                       blocks: List[int], slot: int, *, pos_next: int,
                       last0: int, remaining: int, step_idx: int,
                       replay) -> None:
-        table = np.zeros((self.table_width,), np.int32)
-        table[:len(blocks)] = blocks
-        st = _Slot(req, pos_next, last0, blocks, table)
+        st = _Slot(req, pos_next, last0, blocks,
+                   self.pool.table(blocks, self.table_width))
         st.remaining = int(remaining)
         st.step_idx = int(step_idx)
         st.replay = deque(replay)
@@ -3370,9 +3616,11 @@ class LMServingEngine:
         # bucket-padding rows land in trailing owned blocks or the
         # scratch block, always masked until overwritten
         nb_w = -(-bucket // B)
-        ids_w = np.zeros((nb_w,), np.int32)
-        owned = blocks[p // B:p // B + nb_w]
-        ids_w[:len(owned)] = owned
+        # the chunk's blocks of a windowed class are allotted now, and what
+        # lies behind the window of its first query goes
+        self._advance(pf, p, p + ts)
+        ids_w = self._by_class(self.pool.table(blocks[p // B:p // B + nb_w],
+                                               nb_w))
         if self._adm_note is not None:
             self._adm_note["bucket"] = bucket
         # lm/prefill and lm/insert time the ENQUEUE of the chunk program
@@ -3391,8 +3639,7 @@ class LMServingEngine:
         else:
             nbp = p // B
             pb = self._prefix_bucket_for(nbp)
-            pblocks = np.zeros((pb,), np.int32)
-            pblocks[:nbp] = blocks[:nbp]
+            pblocks = self._by_class(self.pool.table(blocks[:nbp], pb))
             x = {"ids": ids, "len": np.int32(ts),
                  "prefix_len": np.int32(p), "blocks": pblocks,
                  "kv": self.pool.arenas, **self._carried_operands(pf.slot)}
@@ -3417,6 +3664,8 @@ class LMServingEngine:
             *rest, pf.h_last = rest
         # what the chunk caches: (k, v), or a latent pool's rows
         n = self.pool.data_arenas
+        multi = len(self.pool.classes) > 1
+        n *= len(self.pool.classes)     # (k, v) a class, side by side
         new, rest = rest[:n], rest[n:]
         if self._moe_layers:    # summed over a prompt's chunks
             moe, *rest = rest
@@ -3428,6 +3677,8 @@ class LMServingEngine:
         if self._selfdraft:
             *self.pool.arenas, self._hid = self._insert_compiled(bucket)(
                 kv[0], self._hid, *new, ids_w, pf.h_last, np.int32(pf.slot))
+        elif multi:
+            self.pool.arenas = self._insert_compiled(bucket)(*kv, *new, ids_w)
         else:
             self.pool.arenas = self._insert_compiled(bucket)(
                 *kv[:n], *new, ids_w, *kv[n:])
@@ -3443,6 +3694,8 @@ class LMServingEngine:
         self._prefill_since_step = True
         pf.logits = logits
         pf.p = p + ts
+        if pf.p < t:    # (the last chunk's go once the trie has the prompt)
+            self._advance(pf, pf.p, pf.p)
         return pf.p >= t
 
     def _finish_prefill(self, pf: _Prefill) -> None:
@@ -3454,12 +3707,16 @@ class LMServingEngine:
             nfull = t // B
             if nfull:
                 self.radix.insert(req.prompt0[:nfull * B], blocks[:nfull])
+        # (the trie has its own references now) what lies behind the windows
+        # of the first decode round's query goes
+        self._advance(pf, t, t)
         if pf.handoff is not None:
             # re-prefill of a migrated request whose wire payload was
             # lost: the first token was already emitted on the prefill
             # replica — recompute the KV rows, discard the logits, and
             # seat decode exactly where the handoff says it stands
             self._seat(req, t, pf.handoff.first0, blocks, slot)
+            self._slots[slot].marks = pf.marks
             return
         # where the device wait for the prefill and the insert lands
         self._stamp(P_FIRST_TOKEN)
@@ -3509,12 +3766,13 @@ class LMServingEngine:
                     self._free.append(slot)
             return
         self._seat(req, t, first0, blocks, slot)
+        # (the windowed classes' marks go on with the sequence)
+        self._slots[slot].marks = pf.marks
 
     def _seat(self, req: _Request, t: int, first0: int,
               blocks: List[int], slot: int) -> None:
-        table = np.zeros((self.table_width,), np.int32)
-        table[:len(blocks)] = blocks
-        st = _Slot(req, t, first0, blocks, table)
+        st = _Slot(req, t, first0, blocks,
+                   self.pool.table(blocks, self.table_width))
         if self.draft is not None and not self._selfdraft:
             # drafter admission: full-prompt prefill into its dense
             # per-slot cache, first emitted token queued as pending.
@@ -3546,12 +3804,26 @@ class LMServingEngine:
         # one operand vector a round; what stays zero in it: an idle
         # slot, a greedy pick (no temperature, no key), nobody's blocks
         operands, token, pos, temperature, keys, live = decode_operands(
-            self.slots, self.slots * self.table_width)
-        rows, chains = rnd.rows, []
+            self.slots, sum(self._live_entries))
+        rows, chains = rnd.rows, [[] for _ in self.pool.classes]
         # the slots whose last token is a pick of the round on the device
         # (``last0`` is one behind until that round is collected)
         taken = ({i for i, _, emits, _ in self._flying.rows if emits}
                  if ahead else ())
+        windows = self._windows
+        if self.pool.windowed:
+            # the windowed classes' releases and allotments of the round,
+            # every slot's: host time of the round (round_host_ms)
+            t_rel = time.perf_counter()
+            for st in self._slots:
+                if st is not None and st.remaining > 0 and (
+                        st.pos_next >= st.marks["upto"]
+                        or st.pos_next >= st.marks["pos"]):
+                    self._advance(st, st.pos_next, st.pos_next + 1)
+            if _tracer.enabled:
+                _tracer.add_complete(
+                    "lm/window_release", t_rel, time.perf_counter() - t_rel,
+                    cat="serve", args={"round": self._rd_index})
         for i, st in enumerate(self._slots):
             if st is None or st.remaining <= 0:
                 continue        # idle, or its last row is in flight
@@ -3561,10 +3833,22 @@ class LMServingEngine:
                 temperature[i] = st.temperature
                 keys[i] = st.step_keys[st.step_idx]
             # what the round reads of the slot's chain: the blocks up
-            # to the one its new row is written to
-            held = st.table[:st.pos_next // self.block_len + 1]
-            chains.append((i, held))
-            rnd.n_live += len(held)
+            # to the one its new row is written to -- of a class with a
+            # window, from the first that the window touches
+            last = st.pos_next // self.block_len + 1
+            for c, w in enumerate(windows):
+                first = (0 if w is None else
+                         max(0, st.pos_next - w + 1) // self.block_len)
+                chains[c].append((i, st.table[c, first:last], first))
+                rnd.n_live += last - first
+                if w is None:
+                    rnd.ctx_tokens += st.pos_next + 1
+                else:
+                    rnd.window_tokens += min(st.pos_next + 1, w)
+                    rnd.window_held += last - st.marks[c]
+                    rnd.window_spanned += last
+                    rnd.window_held_max = max(rnd.window_held_max,
+                                              last - st.marks[c])
             rnd.n_positions += st.pos_next + 1
             if _tracer.enabled and _tracer.sampled(st.rid):
                 rnd.sampled.append((st.rid, i, st.step_idx))
@@ -3585,7 +3869,10 @@ class LMServingEngine:
             rows.append((i, st, emits, last))
         if not self._rd_active:     # (a round collected here names it)
             self._rd_active = len(rows)
-        live[:] = live_list(chains, live.shape[1], self.slots)
+        at = 0
+        for c, n in enumerate(self._live_entries):
+            live[:, at:at + n] = live_list(chains[c], n, self.slots)
+            at += n
         # what the step gathers: the chunks that hold a listed block
         chunk = self._list_chunk
         rnd.gathered = -(-rnd.n_live // chunk) * chunk
@@ -3644,6 +3931,9 @@ class LMServingEngine:
                 step_args["moe_groups_hit"] = int(moe[2])
             if self._latent_layers:
                 step_args["latent_positions"] = latent_rows
+            if self._classes:
+                step_args.update(ctx_tokens=rnd.ctx_tokens,
+                                 window_tokens=rnd.window_tokens)
             _tracer.add_complete("lm/decode_step", t0, now - t0, cat="serve",
                                  args=step_args)
             # per-request view of the shared batched step: one
@@ -3676,7 +3966,12 @@ class LMServingEngine:
                                  gathered_blocks=rnd.gathered,
                                  state_rows=state_rows,
                                  latent_rows=latent_rows, ahead=rnd.ahead,
-                                 discarded=discarded)
+                                 discarded=discarded,
+                                 ctx_tokens=rnd.ctx_tokens,
+                                 window_tokens=rnd.window_tokens,
+                                 window_held=rnd.window_held,
+                                 window_spanned=rnd.window_spanned,
+                                 window_held_max=rnd.window_held_max)
         self._prefill_since_step = False
         if freed:
             with self._cv:
@@ -3763,7 +4058,7 @@ class LMServingEngine:
         tokens = np.zeros((self.slots, w), np.int32)
         pos = np.zeros((self.slots,), np.int32)
         ncand = np.zeros((self.slots,), np.int32)
-        tables = np.zeros((self.slots, self.table_width), np.int32)
+        tables = np.zeros(self._tables_shape(), np.int32)
         active = []
         for i, st in enumerate(self._slots):
             if st is None:
@@ -3775,7 +4070,10 @@ class LMServingEngine:
                 tokens[i, 1 + j] = d
             ncand[i] = 1 + len(ds)
             pos[i] = st.pos_next
-            tables[i] = st.table
+            # (a windowed class: the candidate rows' blocks are allotted,
+            # what lies behind the first row's window goes)
+            self._advance(st, st.pos_next, st.pos_next + 1 + len(ds))
+            tables[..., i, :] = self._by_class(st.table)
         if not active:
             return
         self._rd_active = len(active)
@@ -3940,7 +4238,7 @@ class LMServingEngine:
                 reach = st.pos_next + 2
             # what the round reads and writes of the slot's chain: up to the
             # block of the module's furthest row
-            held = st.table[:min(reach // B + 1, len(st.blocks))]
+            held = st.table[0, :min(reach // B + 1, len(st.blocks))]
             chains.append((i, held))
             rnd.n_live += len(held)
             rows.append((i, st, cand))
@@ -4156,7 +4454,7 @@ class LMServingEngine:
             active.append(i)
             tokens[i, 0] = st.last0
             pos[i] = st.pos_next
-            tables[i] = st.table
+            tables[i] = st.table[0]
             if i in jobs:
                 shp = shapes[jobs[i]]
                 ds, _, alts = drafts[i]
@@ -4417,6 +4715,12 @@ class LMServingEngine:
             "kvcache": self.kvcache_stats(),
             # what a cached row is for the model at hand, and the pool's books
             "kv_pool": self.pool.stats(),
+            # ... a class of blocks a kind of softmax layer: its layers (of
+            # the plan), a position's lanes (keys + values, unpadded), its
+            # window, its blocks and bytes
+            "kv_classes": [dict(c, layers=list(k.layers))
+                           for c, k in zip(self.pool.stats()["classes"],
+                                           self._classes)],
             "latent_cache": ({"layers": self._latent_layers,
                               "row_bytes": self.pool.row_bytes,
                               "blocks_used": self.pool.used_count,

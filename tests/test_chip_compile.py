@@ -432,7 +432,10 @@ def test_laguna_cell_compiles_for_v5e_and_keeps_the_arenas_in_place(
     ``laguna_s.steady`` cell at its own geometry (``benchmarks/configs/
     laguna-s-2.1.json``: 5 layers in two groups of the plan, 128 held experts,
     32 slots, table width 160, 5,136 blocks of 16 rows of 8 x 128 lanes,
-    bf16), compiled for the described v5e: the decode step with the arenas
+    bf16 -- since PR 42 in TWO CLASSES of blocks, the two full layers' and the
+    three sliding ones', whose window lets go of what lies behind it and whose
+    share of the live list is the 33 blocks a window touches a slot),
+    compiled for the described v5e: the decode step with the arenas
     donated, its live list as long as whole tables and walked a chunk of 512
     blocks at a time (aliased, no arena-shaped copy, temporaries under 0.5 GB,
     the gathered chunk as it lies, no tensor of the whole tables' chain
@@ -465,21 +468,29 @@ def test_laguna_cell_compiles_for_v5e_and_keeps_the_arenas_in_place(
                        for a in jax.tree_util.tree_leaves(params))
     # the table of ISSUE 26 (5,572,042,752) and the 11 norm vectors
     assert weight_bytes == 2 * (5_572_042_752 + 11 * 3072), weight_bytes
-    layers, heads, d = model.n_layers, model.n_kv_head, model.head_dim
+    from bigdl_tpu.serving.kvcache.blocks import class_entries
+    full, sliding = model.cache_classes
+    assert (full.layers, sliding.layers) == ((0, 4), (1, 2, 3))
+    assert (full.window, sliding.window) == (None, 512)
     i32 = lambda *shape: sds(shape, jnp.int32)              # noqa: E731
     if program.startswith("decode"):
         impl = "gather" if program == "decode" else "paged_kernel"
         monkeypatch.setattr(pa, "_use_interpret", lambda: False)
 
         def arenas_of():
-            pool = BlockPool(n_layers=layers, n_heads=heads, head_dim=d,
-                             block_len=eng["block_len"],
-                             num_blocks=eng["num_blocks"], dtype=jnp.bfloat16)
-            return [pool.k, pool.v]
+            return BlockPool(classes=[
+                dict(n_layers=len(k.layers), n_heads=k.n_kv, head_dim=k.k_dim,
+                     window=k.window) for k in model.cache_classes],
+                block_len=eng["block_len"], num_blocks=eng["num_blocks"],
+                dtype=jnp.bfloat16).arenas
 
         arenas = [sds(a.shape, a.dtype) for a in jax.eval_shape(arenas_of)]
-        assert arenas[0].shape == (5, 5136, 16, 1024)       # no lane padding
+        assert [a.shape for a in arenas] == [       # no lane padding
+            (2, 5136, 16, 1024)] * 2 + [(3, 5136, 16, 1024)] * 2
         slots, width = eng["slots"], eng["cache_len"] // eng["block_len"]
+        entries = sum(class_entries(slots, width, k.window, 16)
+                      for k in model.cache_classes)
+        assert entries == slots * (160 + 33)
 
         def step(p, tok, pos, live, temperature, keys, prev_ids, *kv):
             return G._decode_pick_paged(model, p, tok, pos, live, temperature,
@@ -487,22 +498,24 @@ def test_laguna_cell_compiles_for_v5e_and_keeps_the_arenas_in_place(
                                         table_width=width, attn_impl=impl)
 
         compiled, text = _compile(
-            step, params, i32(slots), i32(slots), i32(3, slots * width),
+            step, params, i32(slots), i32(slots), i32(3, entries),
             sds((slots,), jnp.float32), sds((slots, 2), jnp.uint32),
-            i32(slots), *arenas, donate_argnums=(7, 8))
+            i32(slots), *arenas, donate_argnums=(7, 8, 9, 10))
         # the picked ids and the routed layers' two integers: nothing of
         # the vocabulary's 50,176 columns leaves the step
         ids, counts = compiled.out_info[:2]
         assert ids.shape == (slots,) and ids.dtype == jnp.int32
-        assert counts.shape == (3,) and len(compiled.out_info) == 4
-        arena_bytes = 2 * int(np.prod(arenas[0].shape)) * 2
+        assert counts.shape == (3,) and len(compiled.out_info) == 6
+        arena_bytes = sum(int(np.prod(a.shape)) * 2 for a in arenas)
         mem = compiled.memory_analysis()
         assert mem.alias_size_in_bytes >= 0.99 * arena_bytes
-        dims = "bf16[5,5136,16,1024]"
-        assert set(re.findall(re.escape(dims) + r"\{([\d,]+)", text)) == {"3,2,1,0"}
-        moved = [ln.strip()[:160] for ln in text.splitlines()
-                 if dims in ln and re.search(r" copy(-start)?\(|AllocateBuffer", ln)]
-        assert not moved, moved
+        for dims in ("bf16[2,5136,16,1024]", "bf16[3,5136,16,1024]"):
+            assert set(re.findall(re.escape(dims) + r"\{([\d,]+)", text)) == {
+                "3,2,1,0"}
+            moved = [ln.strip()[:160] for ln in text.splitlines()
+                     if dims in ln and re.search(
+                         r" copy(-start)?\(|AllocateBuffer", ln)]
+            assert not moved, moved
         assert mem.temp_size_in_bytes < 0.5e9, mem.temp_size_in_bytes
         if impl == "paged_kernel":
             # the dense layer's call and the period's four, a window on three
@@ -521,9 +534,10 @@ def test_laguna_cell_compiles_for_v5e_and_keeps_the_arenas_in_place(
             return G._prefill_parts(model, p, ids, n - 1)
 
         compiled, text = _compile(step, params, i32(1, 2048), i32())
-        logits, k, v, counts = compiled.out_info
+        logits, k, v, k_w, v_w, counts = compiled.out_info
         assert logits.shape == (1, 50176) and counts.shape == (3,)
-        assert k.shape == v.shape == (5, 1, 8, 2048, 128)
+        assert k.shape == v.shape == (2, 1, 8, 2048, 128)   # the full class's
+        assert k_w.shape == v_w.shape == (3, 1, 8, 2048, 128)
         mem = compiled.memory_analysis()
         arena_bytes = 0
         assert "flash_attention_fwd" in text
@@ -838,6 +852,133 @@ def test_ling3_cell_compiles_for_v5e_and_keeps_latent_and_state_in_place(
               f"aliased {mem.alias_size_in_bytes / 1e9:.3f} GB, "
               f"total {total / 1e9:.3f} GB")
     assert total < 14.5e9, total
+
+
+@pytest.mark.parametrize("program", ["decode", "decode-gather", "prefill2048",
+                                     "suffix2048"])
+def test_mimo_v2_cell_compiles_for_v5e_and_keeps_both_classes_in_place(
+        sds, monkeypatch, program, capsys):
+    """``mimo-v2-cell``: the programs of the benchmark's ``mimo_v2.mixedqueue``
+    cell at its own geometry (``benchmarks/configs/mimo-v2-flash.json``: layer
+    0 and one period of five sliding layers and a full one, 16 held experts,
+    64 slots, table width 2,560, TWO CLASSES of blocks: the full class's two
+    layers of rows of 768 key and 512 value lanes, the windowed class's five of
+    1,536 and 1,024), compiled for the described v5e: the decode step with all
+    four arenas donated (aliased in place, no arena-shaped copy) -- as
+    ``decode_attn="auto"`` resolves there, through the Pallas kernel that reads
+    two key heads of 192 lanes at a time, values of 128 and the sink
+    (``ops.grouped_attention``), and, ``decode-gather``, through the XLA walk
+    that stays the CPU path -- the 2,048-token prefill (the XLA path: keys and
+    values differ in width, a sink) and the suffix prefill that walks the full
+    class's prefix and reads the windowed class's window.  Prints what the
+    configuration's ``memory_arithmetic`` quotes; memory before any run."""
+    import json
+    from benchmarks.drivers import serve_mimo_v2 as D
+    from bigdl_tpu.models.transformer import generate as G
+    from bigdl_tpu.serving.kvcache.blocks import BlockPool, class_entries
+
+    monkeypatch.setattr(pa, "_use_interpret", lambda: False)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "mimo-v2-flash.json")) as f:
+        c = json.load(f)
+    eng = c["engine"]
+    model = D.build_model(c)
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda: D.program_params(model, 0, c, "bfloat16")))
+    weight_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                       for a in jax.tree_util.tree_leaves(params))
+    # ISSUE 42's table: a sliding layer's attention 94,371,904 (its 64 sinks
+    # among them), a full layer's 89,128,960, the dense MLP 201,326,592, 16
+    # experts of 25,165,824 and a router of 1,048,576 + a bias of 256 a routed
+    # layer, embedding and head, 15 norm vectors
+    values = (290_455_552 + 5 * (94_371_904 + 16 * 25_165_824 + 1_048_832)
+              + 492_830_976 + 2 * 19_072 * 4096 + 15 * 4096)
+    assert values == 3_429_955_392
+    # (bf16 but for the sinks and the selection bias, float32: 2 B more each)
+    assert weight_bytes == 2 * values + 2 * (5 * 64 + 6 * 256), weight_bytes
+    full, sliding = model.cache_classes
+    assert (full.layers, sliding.layers) == ((0, 5), (1, 2, 3, 4, 6))
+    slots, width, B = eng["slots"], eng["cache_len"] // eng["block_len"], 16
+    i32 = lambda *shape: sds(shape, jnp.int32)              # noqa: E731
+
+    def arenas_of():
+        return BlockPool(classes=[
+            dict(n_layers=len(k.layers), n_heads=k.n_kv, head_dim=k.k_dim,
+                 v_dim=k.v_dim, window=k.window, num_blocks=n)
+            for k, n in zip(model.cache_classes, eng["num_blocks"])],
+            block_len=B, dtype=jnp.bfloat16).arenas
+
+    arenas = [sds(a.shape, a.dtype) for a in jax.eval_shape(arenas_of)]
+    assert [a.shape for a in arenas] == [
+        (2, eng["num_blocks"][0], 16, 768), (2, eng["num_blocks"][0], 16, 512),
+        (5, eng["num_blocks"][1], 16, 1536), (5, eng["num_blocks"][1], 16, 1024)]
+    arena_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in arenas)
+    entries = sum(class_entries(slots, width, k.window, B)
+                  for k in model.cache_classes)
+    assert entries == slots * (width + 9)
+    if program.startswith("decode"):
+        impl = "gather" if program == "decode-gather" else "paged_kernel"
+
+        def step(p, tok, pos, live, temperature, keys, prev_ids, *kv):
+            return G._decode_pick_paged(model, p, tok, pos, live, temperature,
+                                        keys, prev_ids, *kv,
+                                        table_width=width, attn_impl=impl)
+
+        compiled, text = _compile(
+            step, params, i32(slots), i32(slots), i32(3, entries),
+            sds((slots,), jnp.float32), sds((slots, 2), jnp.uint32),
+            i32(slots), *arenas, donate_argnums=(7, 8, 9, 10))
+        ids, counts = compiled.out_info[:2]
+        assert ids.shape == (slots,) and ids.dtype == jnp.int32
+        assert counts.shape == (3,) and len(compiled.out_info) == 6
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes >= 0.99 * arena_bytes
+        for a in arenas:
+            dims = "bf16[" + ",".join(map(str, a.shape)) + "]"
+            moved = [ln.strip()[:160] for ln in text.splitlines()
+                     if dims in ln and re.search(
+                         r" copy(-start)?\(|AllocateBuffer", ln)]
+            assert not moved, moved
+        assert mem.temp_size_in_bytes < 3.0e9, mem.temp_size_in_bytes
+        kernel = [ln for ln in text.splitlines()
+                  if 'custom_call_target="tpu_custom_call"' in ln
+                  and "grouped_decode_attention" in ln]
+        assert bool(kernel) == (impl == "paged_kernel")
+    elif program == "prefill2048":
+        def step(p, ids, n):
+            return G._prefill_parts(model, p, ids, n - 1)
+
+        compiled, text = _compile(step, params, i32(1, 2048), i32())
+        logits, k0, v0, k1, v1, counts = compiled.out_info
+        assert logits.shape == (1, 19072) and counts.shape == (3,)
+        assert k0.shape == (2, 1, 4, 2048, 192) and v0.shape == (2, 1, 4, 2048, 128)
+        assert k1.shape == (5, 1, 8, 2048, 192) and v1.shape == (5, 1, 8, 2048, 128)
+        mem = compiled.memory_analysis()
+        arena_bytes = 0
+    else:
+        def step(p, ids, n, prefix_len, blocks, *kv):
+            return G._prefill_suffix_parts(model, p, ids, n - 1, prefix_len,
+                                           blocks, *kv)
+
+        compiled, text = _compile(step, params, i32(1, 2048), i32(), i32(),
+                                  i32(2, width), *arenas)
+        assert compiled.out_info[1].shape == (2, 1, 4, 2048, 192)
+        assert compiled.out_info[4].shape == (5, 1, 8, 2048, 128)
+        mem = compiled.memory_analysis()
+    _expert_matmuls(text, program.startswith("decode"), (16, 4096, 2048))
+    total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    with capsys.disabled():
+        print(f"\nmimo-v2-cell {program}: weights {weight_bytes / 1e9:.3f} GB, "
+              f"arenas {arena_bytes / 1e9:.3f} GB, "
+              f"arguments {mem.argument_size_in_bytes / 1e9:.3f} GB, "
+              f"temporaries {mem.temp_size_in_bytes / 1e9:.3f} GB, "
+              f"outputs {mem.output_size_in_bytes / 1e9:.3f} GB, "
+              f"aliased {mem.alias_size_in_bytes / 1e9:.3f} GB, "
+              f"total {total / 1e9:.3f} GB")
+    assert total < 15.0e9, total
 
 
 @pytest.mark.parametrize("program", ["round", "round-gather", "plain-decode",

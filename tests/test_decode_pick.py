@@ -25,7 +25,8 @@ from bigdl_tpu.serving import LMServingEngine, lm_engine
 from bigdl_tpu.serving.kvcache import blocks as KB
 from bigdl_tpu.serving.spec import SpecConfig
 from bigdl_tpu.serving.spec.verify import pick_token
-from tests.test_live_list import M, _pool, _tables, _toy_gpt2, _toy_laguna
+from tests.test_live_list import (M, _class_arenas, _class_lists, _tables,
+                                  _toy_gpt2, _toy_laguna)
 
 
 def operands_of(operands, slots):
@@ -111,9 +112,9 @@ def test_step_ids_are_pick_token_of_the_steps_logits(case, round_):
     keys[temps == 0.0] = 0              # what the engine hands a greedy slot
     pos = jnp.asarray([p or 0 for p in where], jnp.int32)
     _, chains = _tables(where, seed=3)
-    live = jnp.asarray(KB.live_list(chains, len(ROUND) * M, len(ROUND)))
-    arenas = _pool(model.n_kv_head, model.head_dim, None, seed=4,
-                   layers=model.n_layers)
+    live = _class_lists(model, KB.live_list(chains, len(ROUND) * M, len(ROUND)),
+                        chains, where)
+    arenas = _class_arenas(model, None, seed=4)
     token = jnp.asarray([3, 0, 17, 8, 40, 21], jnp.int32)
     # two slots' tokens are the previous step's picks, still on the device:
     # the sentinel in their place of the operand, the value in prev_ids
@@ -261,8 +262,10 @@ def test_decode_executable_has_no_output_of_the_vocabularys_width(case):
         assert all(vocab not in o.shape
                    for o in out[:-len(eng.pool.arenas)])
         (_, operands, prev_ids, *kv), _ = eng._decode_compiled().in_avals
-        assert operands.shape == (5 * eng.slots + 3 * eng.slots
-                                  * eng.table_width,)
+        # (a live list a class of blocks, side by side: whole tables, or
+        # under a window the blocks a window touches a slot)
+        assert operands.shape == (5 * eng.slots + 3 * sum(eng._live_entries),)
+        assert eng._live_entries[0] == eng.slots * eng.table_width
         assert (prev_ids.shape, prev_ids.dtype) == (out[0].shape, jnp.int32)
         assert [a.shape for a in kv] == [a.shape for a in eng.pool.arenas]
         assert eng._ids.shape == prev_ids.shape         # zeros before a round

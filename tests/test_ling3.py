@@ -567,7 +567,7 @@ def test_refusals_at_construction(kind, kw, says):
 def test_every_refusal_is_a_row_of_the_one_table():
     from bigdl_tpu.serving import lm_engine
     rows = lm_engine._REFUSALS
-    assert len(rows) == len({r[:2] for r in rows}) == 14
+    assert len(rows) == len({r[:2] for r in rows}) == 21
     assert {r[0] for r in rows} == set(lm_engine._KIND_NAMES)
     lm_engine.refuse_unsupported(_latent_alone())           # nothing given: silent
 

@@ -206,6 +206,31 @@ def _toy_laguna():
     return model.evaluate()
 
 
+def _class_arenas(model, kind, seed):
+    """A pool's arenas for ``model``: a CLASS of blocks a kind of softmax
+    layer (``model.cache_classes``), arenas of its own layers, side by side."""
+    return sum((_pool(c.n_kv, c.k_dim, kind, seed=seed + i, layers=len(c.layers))
+                for i, c in enumerate(model.cache_classes)), ())
+
+
+def _class_lists(model, full, chains, where):
+    """A round's live list for ``model``: ``full`` (a list of whole chains, or
+    whole tables) for a class without a window; a class with one lists the
+    blocks its window touches, side by side with the full class's entries."""
+    slots = len(where)
+    parts = []
+    for c in model.cache_classes:
+        if c.window is None:
+            parts.append(np.asarray(full))
+            continue
+        first = {s: max(0, p - c.window + 1) // B
+                 for s, p in enumerate(where) if p is not None}
+        parts.append(KB.live_list(
+            [(s, blocks[first[s]:], first[s]) for s, blocks in chains],
+            KB.class_entries(slots, M, c.window, B), slots))
+    return jnp.asarray(np.concatenate(parts, axis=1))
+
+
 @pytest.mark.parametrize("case", ["gpt2", "laguna", "int8"])
 def test_step_with_a_short_list_is_the_step_with_whole_tables(case,
                                                              chunks_of_16):
@@ -218,18 +243,19 @@ def test_step_with_a_short_list_is_the_step_with_whole_tables(case,
     where = [13, None, 5, 22]
     pos = jnp.asarray([p or 0 for p in where], jnp.int32)
     tables, chains = _tables(where, seed=3)
-    arenas = _pool(model.n_kv_head, model.head_dim,
-                   "int8" if case == "int8" else None, seed=4,
-                   layers=model.n_layers)
+    arenas = _class_arenas(model, "int8" if case == "int8" else None, seed=4)
     token = jnp.asarray([3, 0, 17, 8], jnp.int32)
 
     def step(live):
         return G._decode_step_paged(model, model.params, token, pos, live,
                                     *arenas, table_width=M)
 
+    def listed(full):
+        return _class_lists(model, full, chains, where)
+
     n_live = sum(len(c) for _, c in chains)
-    short = jax.jit(step)(jnp.asarray(KB.live_list(chains, 24, 4)))
-    whole = jax.jit(step)(KB.table_list(jnp.asarray(tables)))
+    short = jax.jit(step)(listed(KB.live_list(chains, 24, 4)))
+    whole = jax.jit(step)(listed(KB.table_list(jnp.asarray(tables))))
     assert n_live == 12 and len(short) == len(whole)
     live_slots = [0, 2, 3]
     a, b = np.asarray(short[0])[live_slots], np.asarray(whole[0])[live_slots]
