@@ -888,21 +888,32 @@ class TransformerLM(Module):
         o, state = kda_chunked(q, k, v, g, beta, state, valid)
         return self.kda_out(bp, o, gate, x.dtype), state, tail
 
-    def layer_kda_step(self, bp, x, state, tail, active=None):
-        """One new position a sequence: ``x`` (S, 1, hidden), ``state`` (S,
-        H, D, D), ``tail`` (S, taps - 1, 3 * H * D); a row that is not
-        ``active`` (S,) keeps its state and tail.  -> (y (S, 1, hidden),
-        state, tail)."""
+    def layer_kda_step(self, bp, x, state, tail, layer, active):
+        """One new position a sequence: ``x`` (S, 1, hidden), ``state`` the
+        state ARENA (R, S, H, D, D) and ``layer`` (traced) this layer's index
+        in it, ``tail`` (S, taps - 1, 3 * H * D) the layer's tail rows; a row
+        that is not ``active`` (S,) keeps its state and tail.  The recurrence
+        by ``ops.kda_step.kda_step_path``: on a TPU the Pallas kernel that
+        reads and writes the active slots' rows where they lie, else
+        ``nn.kda.kda_step`` on the layer's rows.  -> (y (S, 1, hidden), the
+        arena, the layer's tail rows)."""
         from bigdl_tpu.nn.kda import kda_step, short_conv_step
+        from bigdl_tpu.ops.kda_step import kda_step_path, kda_step_rows
         qkv, g, beta, gate = self.kda_inputs(bp, x)
         y, new_tail = short_conv_step(qkv[:, 0], bp["kda"]["conv"], tail)
         q, k, v = self.kda_heads(y)
-        o, new_state = kda_step(q, k, v, g[:, 0], beta[:, 0], state)
-        if active is not None:
-            new_state = jnp.where(active[:, None, None, None], new_state, state)
-            new_tail = jnp.where(active[:, None, None], new_tail, tail)
+        if kda_step_path(*state.shape[2:]) == "kernel":
+            with jax.named_scope("kda/step"):
+                o, state = kda_step_rows(state, layer, active, q, k, v,
+                                         g[:, 0], beta[:, 0])
+        else:
+            row = state[layer]
+            o, new = kda_step(q, k, v, g[:, 0], beta[:, 0], row)
+            state = state.at[layer].set(
+                jnp.where(active[:, None, None, None], new, row))
+        new_tail = jnp.where(active[:, None, None], new_tail, tail)
         y = self.kda_out(bp, o[:, None], gate, x.dtype)
-        return y, new_state, new_tail.astype(tail.dtype)
+        return y, state, new_tail.astype(tail.dtype)
 
     def layer_ffn(self, spec: LayerSpec, bp, x, *, dense_routing=False,
                   token_mask=None):

@@ -1031,9 +1031,9 @@ def _decode_step_paged(model, params, token, pos, live, *arenas,
         kv, recurrent = _split_arenas(model, arenas)
         if spec.mixer == "kda":
             state, tail = recurrent
-            y, row, tail_row = model.layer_kda_step(
-                bp, h, state[layer], tail[layer], active)
-            recurrent = (state.at[layer].set(row), tail.at[layer].set(tail_row))
+            y, state, tail_row = model.layer_kda_step(
+                bp, h, state, tail[layer], layer, active)
+            recurrent = (state, tail.at[layer].set(tail_row))
             h, counts = _ffn(model, spec, bp, h + y, active[:, None])
         elif spec.mixer == "mla":
             h, kv, counts = latent_layer(spec, h, bp, layer, kv)

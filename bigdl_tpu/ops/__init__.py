@@ -13,6 +13,9 @@ from bigdl_tpu.ops.flash_attention import (  # noqa: F401
 from bigdl_tpu.ops.grouped_attention import (  # noqa: F401
     grouped_decode_attention,
 )
+from bigdl_tpu.ops.kda_step import (  # noqa: F401
+    kda_step_path, kda_step_rows,
+)
 from bigdl_tpu.ops.latent_attention import (  # noqa: F401
     latent_decode_attention,
 )

@@ -16,8 +16,10 @@ placed, donated and handed back like the pool's arenas: every step program
 takes ``pool.arenas + state.arenas`` last, donated.  A prefill writes its
 slot's rows (:func:`write_slot`), a chunk's continuation reads them as its
 initial state (:func:`read_slot`), the decode step updates the rows of the
-slots that decode and leaves an idle slot's row as it is.  A row belongs to
-its slot: admission overwrites it whole, so completion frees nothing.
+slots that decode and leaves an idle slot's row as it is (on a TPU through
+``ops.kda_step``: the arena aliased through a Pallas kernel that reads each
+active slot's state once and writes it once, where it lies).  A row belongs
+to its slot: admission overwrites it whole, so completion frees nothing.
 
 There is no sharing: a prefix's state exists only at the position it was
 computed to, so the radix prefix cache (which hands a request K/V blocks it

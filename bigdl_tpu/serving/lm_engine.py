@@ -1418,6 +1418,10 @@ class LMServingEngine:
             if placement is not None:
                 self.state.arenas = [jax.device_put(a, placement.replicated())
                                      for a in self.state.arenas]
+            # which form the decode step's recurrence takes, as its trace
+            # will ask (the platform and the state's shape): "kernel" / "xla"
+            from bigdl_tpu.ops.kda_step import kda_step_path
+            self._state_step_path = kda_step_path(*shapes[0])
         # a radix hit hands a request K/V blocks it did not compute; no
         # recurrent state exists at that boundary, so such a model shares
         # no prefix (matched tokens 0 always)
@@ -3926,7 +3930,8 @@ class LMServingEngine:
                                  moe_experts_hit=int(moe[1]),
                                  moe_row_tiles=int(moe[-1]))
             if self.state is not None:
-                step_args["state_rows"] = state_rows
+                step_args.update(state_rows=state_rows,
+                                 state_step_path=self._state_step_path)
             if moe is not None and len(moe) > 3:
                 step_args["moe_groups_hit"] = int(moe[2])
             if self._latent_layers:
@@ -4730,7 +4735,8 @@ class LMServingEngine:
             "prefix_cache": self._prefix_cache_note,
             "prefix_tokens": metrics["prefix"],
             "state": ({"layers": self._state_layers,
-                       "row_bytes": self.state.row_bytes, **metrics["state"]}
+                       "row_bytes": self.state.row_bytes,
+                       "step_path": self._state_step_path, **metrics["state"]}
                       if self.state is not None else None),
             "kvtier": (self.kvtier.stats()
                        if self.kvtier is not None else None),

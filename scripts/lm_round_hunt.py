@@ -21,8 +21,9 @@ of a benchmark cell.  Not part of the benchmark: it calls
         "decode_steps", and "rows_discarded", the rows of streams that had
         ended on their eos a round before; for a model
         with recurrent layers "state_row_steps" (active slots x recurrent
-        layers, summed over the rounds), "state_rows_in_use" and the state's
-        read-and-write bytes a round
+        layers, summed over the rounds), "state_rows_in_use", the state's
+        read-and-write bytes a round and "state_step_path", the form its
+        decode step takes ("kernel": ops.kda_step, or "xla")
     ... --tracer-on     the same with the span tracer enabled for the whole run
                         (not the profiler): what tracing costs end to end
     ... --trace         runs with --trace 1 instead; beside each result line,
@@ -126,6 +127,9 @@ def _watch_engines():
                 "state_bytes_a_round": (
                     None if arena is None or not steps
                     else 2 * arena.row_bytes * state_rows / steps),
+                # which form the recurrent layers' decode step takes (null
+                # on a model without, and on a tree before PR 43)
+                "state_step_path": getattr(e, "_state_step_path", None),
                 # which grouped matmul each step program's routed layers
                 # take, and the row windows the kernel visited so far (null
                 # on a tree before PR 41)

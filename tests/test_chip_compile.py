@@ -68,6 +68,13 @@ def _compile(fn, *args, **jit_kw):
     return compiled, compiled.as_text()
 
 
+def _scoped_vmem(calls):
+    """The VMEM, in bytes, each Mosaic custom call of ``calls`` (lines of a
+    compiled program's text) was given."""
+    return [int(n) for ln in calls for n in re.findall(
+        r'"scoped_memory_configs":\[\{[^\]]*"size":"(\d+)"', ln)]
+
+
 #: (B, H, T, D, dtype): GPT-2 XL's head geometry in both dtypes, and the
 #: shape at FLASH_AUTO_MIN_T where "auto" selects the flash kernel
 FLASH_SHAPES = [
@@ -378,6 +385,68 @@ def test_grouped_matmul_compiles_for_v5e(sds, case):
     assert expert_matmul_path(rows, d, f, jnp.bfloat16) == "ragged_dot"
 
 
+#: (R, S, H, d_k, d_v): the state arenas of the two cells with recurrent
+#: layers, and the edge of ``kda_step_path``'s rule: one block of heads, a d_k
+#: of one sublane tile (the row scalers' transposes are not whole lane tiles)
+STATE_ARENAS = {"solar2": (3, 128, 64, 128, 128), "ling3": (7, 32, 32, 128, 128),
+                "edge": (2, 4, 8, 8, 128)}
+
+
+@pytest.mark.parametrize("case", sorted(STATE_ARENAS))
+def test_kda_step_compiles_for_v5e(sds, case):
+    """``ops.kda_step`` at the cells' arenas, compiled: a grid step's tile of
+    8 heads in both directions, the in-kernel transposes of the row scalers,
+    the arena aliased input to output (no second arena among the temporaries)
+    and the VMEM the call asks for."""
+    from bigdl_tpu.ops.kda_step import kda_step_path, kda_step_rows
+    r, s, h, dk, dv = STATE_ARENAS[case]
+    f32 = lambda *shape: sds(shape, jnp.float32)            # noqa: E731
+
+    def step(state, layer, active, q, k, v, g, beta):
+        return kda_step_rows(state, layer, active, q, k, v, g, beta,
+                             interpret=False)
+
+    compiled, text = _compile(
+        step, f32(r, s, h, dk, dv), sds((), jnp.int32), sds((s,), jnp.bool_),
+        f32(s, h, dk), f32(s, h, dk), f32(s, h, dv), f32(s, h, dk), f32(s, h),
+        donate_argnums=(0,))
+    o, state = compiled.out_info
+    assert o.shape == (s, h, dv) and state.shape == (r, s, h, dk, dv)
+    arena_bytes = 4 * r * s * h * dk * dv
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == arena_bytes
+    # (the small operands a few times over; never a layer's rows)
+    assert mem.temp_size_in_bytes < (1 << 20) + 16 * s * h * max(dk, dv) * 4
+    assert len(_kda_kernel_calls(text)) == 1
+    # (the rule, asked on the CPU, says kda_step: its shapes' side is held by
+    # tests/test_kda_step_kernel.py)
+    assert kda_step_path(h, dk, dv) == "xla"
+
+
+def _kda_kernel_calls(text, state_dims=None):
+    """The step's ``kda_step`` custom calls (one a recurrent layer of the
+    plan's periods), each inside the VMEM it asks for -- a tile of 8 heads in
+    both directions twice over: a few MiB -- and, given the arena's
+    ``state_dims``, nothing else of the program that holds the whole arena
+    or a layer's rows of it but the kernel: no ``select`` over the state, no
+    ``dynamic-update-slice`` of it, no reduction pass."""
+    kernel = [ln for ln in text.splitlines()
+              if 'custom_call_target="tpu_custom_call"' in ln
+              and "kda_step" in ln]
+    asked = _scoped_vmem(kernel)
+    assert asked and max(asked) < 16 << 20, asked
+    if state_dims is not None:
+        assert all("kda/step" in ln for ln in kernel), kernel
+        arena = "f32[%s]" % ",".join(map(str, state_dims))
+        rows = "f32[%s]" % ",".join(map(str, state_dims[1:]))
+        others = [ln.strip()[:200] for ln in text.splitlines()
+                  if (arena in ln or rows in ln) and re.search(
+                      r" (fusion|select|dynamic-update-slice|dynamic-slice|"
+                      r"reduce|multiply|copy)\(", ln)]
+        assert not others, others
+    return kernel
+
+
 def _expert_matmuls(text, kernel, experts, temp_bytes=None, parent_temp=None):
     """The routed layers' products in a compiled program: ``kernel`` -- a
     decode-sized program, the platform seen as a TPU -- every one a
@@ -401,8 +470,7 @@ def _expert_matmuls(text, kernel, experts, temp_bytes=None, parent_temp=None):
                                                              len(ours))
         return len(ragged)
     assert ours and not len(ours) % 2 and not ragged, (len(ours), len(ragged))
-    asked = [int(n) for ln in ours for n in re.findall(
-        r'"scoped_memory_configs":\[\{[^\]]*"size":"(\d+)"', ln)]
+    asked = _scoped_vmem(ours)
     assert asked and max(asked) < 64 << 20, asked
     if parent_temp is not None:
         assert temp_bytes <= parent_temp, (temp_bytes, parent_temp)
@@ -419,8 +487,7 @@ def _grouped_kernel_calls(text, *scopes):
     for scope in scopes:
         assert re.search(scope + r"/[\w()/]*grouped_decode_attention", text)
         assert f"{scope}/ragged" not in text and f"{scope}/while" not in text
-    asked = [int(n) for ln in kernel for n in re.findall(
-        r'"scoped_memory_configs":\[\{[^\]]*"size":"(\d+)"', ln)]
+    asked = _scoped_vmem(kernel)
     assert asked and max(asked) < 32 << 20, asked
     return kernel
 
@@ -577,7 +644,9 @@ def test_solar2_cell_compiles_for_v5e_and_keeps_the_state_in_place(
     and, ``decode-kernel``, as ``decode_attn="auto"`` resolves on the chip:
     the softmax layer through the Pallas kernel that reads the listed blocks
     where they lie (``ops.grouped_attention``: a Mosaic custom call, its VMEM
-    inside the limit it asks for) --, the 1,024-token prefill (flash kernel on the
+    inside the limit it asks for); in both the three KDA layers' step is
+    ``ops.kda_step``'s kernel over the aliased state arena and nothing else
+    touches the state --, the 1,024-token prefill (flash kernel on the
     softmax layer, the chunked scan on the others, state and tail handed out
     beside k and v of the one attention layer) and the suffix prefill that
     starts from a slot's rows.  Prints what the configuration's
@@ -662,7 +731,10 @@ def test_solar2_cell_compiles_for_v5e_and_keeps_the_state_in_place(
                      if dims in ln and re.search(
                          r" copy(-start)?\(|AllocateBuffer", ln)]
             assert not moved, moved
-        assert mem.temp_size_in_bytes < 3.0e9, mem.temp_size_in_bytes
+        # the three recurrent layers' step: one kernel call each, and nothing
+        # else of the program touches the state (PR 43)
+        assert len(_kda_kernel_calls(text, arenas[2].shape)) == 3
+        assert mem.temp_size_in_bytes < 0.25e9, mem.temp_size_in_bytes
     elif program == "prefill1024":
         def step(p, ids, n):
             return G._prefill_parts(model, p, ids, n - 1)
@@ -688,10 +760,11 @@ def test_solar2_cell_compiles_for_v5e_and_keeps_the_state_in_place(
                                   i32(64), i32(), *arenas)
         assert compiled.out_info[4].shape == (3, 1, 64, 128, 128)
         mem = compiled.memory_analysis()
-    # (the parent's temporaries at acd3eaf: 263 / 77 MB)
+    # (the parent's temporaries at a7748c7: 263 / 77 MB, a layer's new rows
+    # beside the old among them; since PR 43: 187 / 14 MB)
     _expert_matmuls(text, program.startswith("decode"), (40, 4096, 1280),
                     mem.temp_size_in_bytes,
-                    {"decode": 263.5e6, "decode-kernel": 77.5e6}.get(program))
+                    {"decode": 190e6, "decode-kernel": 16e6}.get(program))
     total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
              + mem.output_size_in_bytes - mem.alias_size_in_bytes)
     with capsys.disabled():
@@ -720,7 +793,8 @@ def test_ling3_cell_compiles_for_v5e_and_keeps_latent_and_state_in_place(
     ``decode_attn="auto"`` resolves there, through the Pallas kernel that reads
     the listed blocks where they lie (``ops.latent_attention``: a custom call,
     its VMEM inside the limit it asks for), and, ``decode-gather``, through the
-    XLA walk that stays the CPU path -- the
+    XLA walk that stays the CPU path; in both the seven KDA layers' step is
+    ``ops.kda_step``'s kernel over the aliased state arena -- the
     2,048-token prefill (the expanded latent layer on the XLA path, the
     chunked scan on the others: rows, state and tail handed out) and the
     suffix prefill that reads its prefix from the latent arena and starts
@@ -801,7 +875,11 @@ def test_ling3_cell_compiles_for_v5e_and_keeps_latent_and_state_in_place(
                      if dims in ln and re.search(
                          r" copy(-start)?\(|AllocateBuffer", ln)]
             assert not moved, moved
-        assert mem.temp_size_in_bytes < 3.0e9, mem.temp_size_in_bytes
+        # the seven recurrent layers' step: a kernel call in the body of the
+        # scan over the two leading layers and one each for the period's
+        # five, and nothing else of the program touches the state (PR 43)
+        assert len(_kda_kernel_calls(text, arenas[1].shape)) == 6
+        assert mem.temp_size_in_bytes < 0.25e9, mem.temp_size_in_bytes
         kernel = [ln for ln in text.splitlines()
                   if 'custom_call_target="tpu_custom_call"' in ln
                   and "latent_decode_attention" in ln]
@@ -811,8 +889,7 @@ def test_ling3_cell_compiles_for_v5e_and_keeps_latent_and_state_in_place(
             # inside the VMEM it asks for (two fetch buffers of 128 blocks, a
             # step's scores and weights): a quarter of a core's 128 MiB at most
             assert "mla/attend/ragged" not in text
-            asked = [int(n) for ln in kernel for n in re.findall(
-                r'"scoped_memory_configs":\[\{[^\]]*"size":"(\d+)"', ln)]
+            asked = _scoped_vmem(kernel)
             assert asked and max(asked) < 32 << 20, asked
     elif program == "prefill2048":
         def step(p, ids, n):
@@ -837,10 +914,10 @@ def test_ling3_cell_compiles_for_v5e_and_keeps_latent_and_state_in_place(
         assert compiled.out_info[1].shape == (1, 1, 2048, 576)
         assert compiled.out_info[3].shape == (7, 1, 32, 128, 128)
         mem = compiled.memory_analysis()
-    # (the parent's temporaries at acd3eaf: 50 / 97 MB)
+    # (the parent's temporaries at a7748c7: 50 / 97 MB; since PR 43: 10 / 21)
     _expert_matmuls(text, program.startswith("decode"), (64, 2560, 768),
                     mem.temp_size_in_bytes,
-                    {"decode": 50.5e6, "decode-gather": 97.5e6}.get(program))
+                    {"decode": 12e6, "decode-gather": 23e6}.get(program))
     total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
              + mem.output_size_in_bytes - mem.alias_size_in_bytes)
     with capsys.disabled():
@@ -1081,8 +1158,7 @@ def test_glm47_cell_compiles_for_v5e_and_keeps_the_latent_arena_in_place(
         assert bool(kernel) == (impl == "paged_kernel")
         if kernel:
             assert "mla/attend/ragged" not in text
-            asked = [int(n) for ln in kernel for n in re.findall(
-                r'"scoped_memory_configs":\[\{[^\]]*"size":"(\d+)"', ln)]
+            asked = _scoped_vmem(kernel)
             assert asked and max(asked) < 48 << 20, asked
     elif program == "plain-decode":
         monkeypatch.setattr(pa, "_use_interpret", lambda: False)

@@ -165,6 +165,7 @@ def test_the_pool_is_one_latent_arena_and_no_kv_arena_at_all(engine):
     assert stats["kv_pool"]["row"] == "one latent row a position"
     assert stats["kv_pool"]["row_lanes"] == 32 and stats["kv_pool"]["row_bytes"] == 512
     assert stats["latent_cache"]["layers"] == 1 and stats["state"]["layers"] == 7
+    assert stats["state"]["step_path"] == "xla"     # the CPU: ``kda_step``
     with pytest.raises(NotImplementedError, match="latent row"):
         pool.export_chain([1])
 
@@ -508,6 +509,7 @@ def test_the_groups_hit_reach_the_metrics_and_the_trace(engine):
     assert sum(a["moe_groups_hit"] for a in steps) == hit
     assert [a["latent_positions"] for a in steps] == [9 + i + 1 for i in range(5)]
     assert all(a["state_rows"] == 7 for a in steps)
+    assert all(a["state_step_path"] == "xla" for a in steps)
 
 
 # -- (f) what a cache kind refuses, at construction, one table ------------------------------

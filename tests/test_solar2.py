@@ -319,6 +319,68 @@ def test_a_state_kept_in_bfloat16_fails_the_tolerance(monkeypatch,
     assert np.max(np.abs(got - _want(reference_weights, c, prompt, forced))) > 5 * TOL
 
 
+def _eight_heads(head_dim=16):
+    """The toy with KDA layers of 8 heads: one head block of ``ops.kda_step``."""
+    c = toy()
+    return toy(head_dim=head_dim, linear_attn_config=dict(
+        c["linear_attn_config"], num_heads=8, head_dim=head_dim))
+
+
+def test_the_engine_says_which_form_its_recurrence_takes(monkeypatch, engine):
+    """``stats()["state"]["step_path"]``, resolved once at construction by
+    ``ops.kda_step.kda_step_path`` -- the platform and the state's shape: the
+    CPU takes ``kda_step``; seen as a TPU (``_use_interpret`` steered false,
+    as ``tests/test_chip_compile.py`` steers it) a state of 8 heads of 128 x
+    128 the kernel, and the toy's 4 heads of 16 x 16 still ``kda_step``."""
+    from bigdl_tpu.ops import paged_attention as pa
+    assert engine.stats()["state"]["step_path"] == "xla"
+    monkeypatch.setattr(pa, "_use_interpret", lambda: False)
+    for c, path in ((_eight_heads(128), "kernel"), (toy(), "xla")):
+        eng = D.build_engine(c, SEED)
+        try:
+            assert eng.stats()["state"]["step_path"] == path
+        finally:
+            eng.close()
+
+
+def test_tokens_through_the_interpreted_kernel_are_the_xla_paths(monkeypatch):
+    """A short mixed run -- four streams of 3 to 14 tokens over four slots, so
+    slots go idle mid-way while the others decode on -- served twice: through
+    ``kda_step`` and with the rule stood in for (``kda_step_path`` is the
+    seam; on the CPU the kernel is interpreted).  The same tokens, and the
+    kernel ran a call a recurrent layer a traced step."""
+    from bigdl_tpu.ops import kda_step as K
+    prompts = [_ids(n, 30 + n) + 1 for n in (5, 9, 14, 7)]
+    lengths = (3, 14, 6, 10)
+    calls, real = [], K.kda_step_rows
+
+    def counted(*a, **kw):
+        calls.append(a[0].shape)
+        return real(*a, **kw)
+
+    def serve():
+        eng = D.build_engine(_eight_heads(), SEED)
+        try:
+            streams = [eng.submit(p, max_new_tokens=n)
+                       for p, n in zip(prompts, lengths)]
+            tokens = [list(s.result(timeout=300)) for s in streams]
+            return tokens, np.asarray(eng.state.state)
+        finally:
+            eng.close()
+
+    want, want_state = serve()
+    assert not calls
+    monkeypatch.setattr(K, "kda_step_rows", counted)
+    monkeypatch.setattr(K, "kda_step_path", lambda *shape: "kernel")
+    got, got_state = serve()
+    assert [len(t) - len(p) for t, p in zip(got, prompts)] == list(lengths)
+    assert got == want
+    assert calls and set(calls) == {(6, 4, 8, 16, 16)}
+    # the arenas the two runs leave: the same rows to float32 round-off
+    assert np.max(np.abs(got_state - want_state)) < KDA_TOL * max(
+        1.0, float(np.max(np.abs(want_state))))
+
+
 # -- (d), (e) the routed half ---------------------------------------------------------
 def _uncut():
     """The toy's first layer with all 16 experts here."""
