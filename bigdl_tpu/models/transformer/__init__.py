@@ -1012,7 +1012,7 @@ class TransformerLM(Module):
             # keeps mha.block_size as flash TILES, never the blockwise core
             return mha.attend(q, k, v, segment_ids=segment_ids,
                               allow_blockwise=False)
-        if mha.resolve_use_flash(q.shape[-2], dtype=q.dtype):
+        if mha.resolve_use_flash(q.shape[-2]):
             from bigdl_tpu.ops import flash_attention
             bs = mha.block_size or 128
             return flash_attention(q, k, v, causal=True, window=spec.window,

@@ -931,6 +931,75 @@ def _latent_rows(model, spec, bp, h, layer, arenas, positions, blk, off, live,
     return h, arenas, counts
 
 
+def decode_attention_path(model, pool, requested: str = "auto") -> str:
+    """How the paged step programs read a pool's listed blocks: ``"gather"``
+    -- :func:`_paged_attention`'s walk, the XLA path, every model's CPU path
+    and the kernels' oracle -- or ``"paged_kernel"``, the Pallas kernel of
+    the pool's KIND that reads them where they lie (``ops.latent_attention``
+    for a latent pool, ``ops.grouped_attention`` for a ``(k, v)`` pool).
+    Resolved once, from the platform and the pool's shapes; ``requested`` is
+    the engine's ``decode_attn``.
+
+    An int8 pool is gathered (the kernels read raw blocks).  ``"auto"`` is
+    the kernel on a TPU where the pool is latent, or its softmax layers'
+    query heads share K/V heads, carry a sink or have values of their own
+    width, and the compiled kernel takes the pool's geometry; the walk
+    otherwise (a head a query head: no cell says the kernel beats it
+    there).  ``"paged_kernel"`` is the kernel, and off the interpreter a
+    geometry the compiled kernel cannot take raises."""
+    from bigdl_tpu.ops import _pallas
+    from bigdl_tpu.ops.grouped_attention import check_grouped_kernel_shapes
+    from bigdl_tpu.ops.latent_attention import check_latent_kernel_shapes
+
+    if requested not in ("auto", "gather", "paged_kernel"):
+        raise ValueError(f"decode_attn must be 'auto', 'gather' or "
+                         f"'paged_kernel', got {requested!r}")
+    if pool.kv_quant is not None:
+        if requested == "paged_kernel":
+            raise ValueError(
+                "kv_quant='int8' requires decode_attn='gather' (the "
+                "Pallas paged kernel reads raw blocks)")
+        return "gather"
+    if requested == "gather":
+        return "gather"
+
+    def check_shapes():
+        if pool.latent:
+            return check_latent_kernel_shapes(pool.block_len, pool.shape[-1],
+                                              pool.dtype)
+        for c in pool.classes:
+            check_grouped_kernel_shapes(
+                pool.block_len, c.shape[-1], c.head_dim, pool.dtype, c.v_dim,
+                c.n_heads, c.v_shape[-1])
+
+    compiled = not _pallas.use_interpret()
+    if requested == "paged_kernel":
+        if compiled:
+            check_shapes()
+        return "paged_kernel"
+    specs = [s for _, period in model.plan for s in period
+             if s.mixer == "attention"]
+    shared = any(s.n_head != model.kv_heads(s) or s.sink
+                 for s in specs) or model.v_dim != model.head_dim
+    if not compiled or not (pool.latent or shared):
+        return "gather"
+    try:
+        check_shapes()
+    except ValueError:
+        return "gather"
+    return "paged_kernel"
+
+
+def _reads_in_place(attn_impl: str) -> bool:
+    """Whether a step reads through the pool kind's kernel: what
+    :func:`decode_attention_path` resolved, the step functions' one
+    ``attn_impl``."""
+    if attn_impl not in ("gather", "paged_kernel"):
+        raise ValueError(f"attn_impl must be 'gather' or 'paged_kernel', "
+                         f"got {attn_impl!r}")
+    return attn_impl == "paged_kernel"
+
+
 def _decode_step_paged(model, params, token, pos, live, *arenas,
                        table_width: Optional[int] = None,
                        attn_impl: str = "gather"):
@@ -948,21 +1017,17 @@ def _decode_step_paged(model, params, token, pos, live, *arenas,
     k/v scatter by (block, offset) derived from ``pos`` and the list;
     attention reads each slot's blocks under the identical position mask
     / score math as the slot engine -- either by gathering the listed
-    blocks (``attn_impl="gather"``, the XLA path) or in place via a
-    Pallas block-table kernel over the tables the list spells
-    (``attn_impl="paged_kernel"``, which needs their ``table_width``:
-    ``ops.grouped_attention`` for a layer whose query heads share K/V
-    heads or that has a window, ``ops.paged_attention`` for a head a query
-    head -- same f32 softmax formulation, so streams stay token-exact
-    across the two).  The arenas (the pool's layout,
-    ``serving.kvcache.blocks``) are donated by the serving engine and
-    carried whole through the layer loop (:func:`_scan_layers`).
+    blocks (``attn_impl="gather"``, the XLA path) or in place via the
+    Pallas kernel of the pool's kind over the tables the list spells
+    (``attn_impl="paged_kernel"``, which needs their ``table_width``;
+    :func:`decode_attention_path` settles which).  The arenas (the pool's
+    layout, ``serving.kvcache.blocks``) are donated by the serving engine
+    and carried whole through the layer loop (:func:`_scan_layers`).
 
     ``arenas`` are the pool's ``(k, v)`` or, int8
     (``BlockPool(kv_quant="int8")``), ``(k, v, k_scale, v_scale)``: the new
     k/v row is quantized per (slot, head) on write and the gather
-    dequantizes in-flight.  The Pallas paged kernel reads raw blocks, so
-    quantized pools require the gather path.  Behind them, for a model
+    dequantizes in-flight.  Behind them, for a model
     with recurrent layers, ride the state arenas ``(state, tail)``
     (``serving.kvcache.state``: a row a recurrent layer and slot): a
     recurrent layer reads and writes its slots' rows (``kda/step``) and an
@@ -978,15 +1043,9 @@ def _decode_step_paged(model, params, token, pos, live, *arenas,
     :func:`_decode_pick_paged`, which picks from these logits on the
     device and hands out ids; the logits stay this function's result for
     what reads them (the tests, the pick's twin on the host)."""
-    if attn_impl not in ("gather", "paged_kernel"):
-        raise ValueError(f"attn_impl must be 'gather' or 'paged_kernel', "
-                         f"got {attn_impl!r}")
+    kernel = _reads_in_place(attn_impl)
     kv = _split_arenas(model, arenas)[0]
     classes = model.cache_classes
-    quant = len(kv) == 4 * max(len(classes), 1)
-    if quant and attn_impl == "paged_kernel":
-        raise ValueError("kv_quant='int8' requires decode_attn='gather' "
-                         "(the Pallas paged kernel reads raw blocks)")
     s = token.shape[0]
     B = kv[0].shape[2]
     h = params["embed"][token][:, None, :]
@@ -1011,7 +1070,7 @@ def _decode_step_paged(model, params, token, pos, live, *arenas,
         blk = jnp.max(jnp.where(held & (where[None, :] == (pos // B)[:, None]),
                                 ids[None, :], 0), axis=1)[:, None]
         tables = None
-        if attn_impl == "paged_kernel":
+        if kernel:
             # the kernel walks (S, M) tables: the list spelled out (padded
             # entries, owned by nobody, drop; what a windowed class has let
             # go of stays the scratch block, behind the window's mask)
@@ -1051,34 +1110,24 @@ def _decode_step_paged(model, params, token, pos, live, *arenas,
         return _latent_rows(
             model, spec, bp, h, layer, arenas, positions, blk, off, live,
             masks[None], active[:, None],
-            (tables, jnp.where(active, pos + 1, 0), 0)
-            if attn_impl == "paged_kernel" else None)
+            (tables, jnp.where(active, pos + 1, 0), 0) if kernel else None)
 
     def attention_layer(spec, h, bp, layer, arenas, live, read):
         masks, blk, _, tables = read
         q, k, v, gate = model.layer_qkv(spec, bp, h, positions)  # (S, H, 1, D)
         sink = model.layer_sink(spec, bp)
-        n_kv = model.kv_heads(spec)
-        if attn_impl == "paged_kernel":
+        if kernel:
             # in-place block reads via the table (no dense gather), the new
-            # rows first; numerics identical to the gather
-            from bigdl_tpu.ops import (grouped_decode_attention,
-                                       paged_decode_attention)
+            # rows first
+            from bigdl_tpu.ops import grouped_decode_attention
             arenas = tuple(
                 write_rows(a, layer, blk, off, x.transpose(0, 2, 1, 3))
                 for a, x in zip(arenas, (k, v)))
-            if (spec.n_head != n_kv or spec.window is not None
-                    or sink is not None or v.shape[-1] != k.shape[-1]):
-                # query heads that share K/V heads, a sliding window, a
-                # sink, values of their own width
-                with jax.named_scope(_attn_scope(model, spec)):
-                    o = grouped_decode_attention(
-                        q, *arenas, tables, jnp.where(active, pos + 1, 0),
-                        layer=layer, n_kv_head=n_kv, window=spec.window,
-                        sink=sink, v_dim=v.shape[-1])
-            else:
-                o = paged_decode_attention(q, *arenas, tables, pos,
-                                           layer=layer)
+            with jax.named_scope(_attn_scope(model, spec)):
+                o = grouped_decode_attention(
+                    q, *arenas, tables, jnp.where(active, pos + 1, 0),
+                    layer=layer, n_kv_head=model.kv_heads(spec),
+                    window=spec.window, sink=sink, v_dim=v.shape[-1])
         else:
             with jax.named_scope(_attn_scope(model, spec)):
                 o, arenas = _paged_attention(q, k, v, arenas, layer, blk,
@@ -1257,13 +1306,10 @@ def _selfdraft_step_paged(model, params, tokens, pos, n_cand, fresh,
     block's among them], *arenas[, the logits (S, 2, V) and the draft logits
     (S, V) ``with_logits``: what the tests read]): no output of a serving
     round has the vocabulary's width."""
-    if attn_impl not in ("gather", "paged_kernel"):
-        raise ValueError(f"attn_impl must be 'gather' or 'paged_kernel', "
-                         f"got {attn_impl!r}")
+    kernel = _reads_in_place(attn_impl)
     s, w = tokens.shape
     B = arenas[0].shape[2]
     mtp_layer = len(model.latent_layers)
-    kernel = attn_impl == "paged_kernel"
     ids, owner, where = live
     tables = jnp.zeros((s, table_width), jnp.int32).at[owner, where].set(
         ids, mode="drop")

@@ -175,19 +175,17 @@ class MultiHeadAttention(Module):
                                     if name == "bo" else (inner,))
         return p
 
-    def resolve_use_flash(self, seq_len: int, dtype=None) -> bool:
+    def resolve_use_flash(self, seq_len: int) -> bool:
         """ONE dispatch rule for every call path (module forward,
         TransformerLM block, generation prefill): explicit "flash" always;
-        "xla" never; "auto" by the measured crossover (the autotune cache
-        when this device kind has a verdict for (seq_len, head_dim,
-        dtype), the static TPU heuristic otherwise) — unless a block_size
+        "xla" never; "auto" on a TPU from ``FLASH_AUTO_MIN_T`` positions
+        up (``ops.flash_attention.use_flash_auto``) — unless a block_size
         was set, which pins the blockwise-XLA core."""
         if self.attention_impl == "flash":
             return True
         if self.attention_impl == "auto" and not self.block_size:
             from bigdl_tpu.ops.flash_attention import use_flash_auto
-            return use_flash_auto(seq_len, self.head_dim, dtype,
-                                  self.causal)
+            return use_flash_auto(seq_len)
         return False
 
     def _split_heads(self, x):  # (B, T, H*D) -> (B, H, T, D)
@@ -233,19 +231,12 @@ class MultiHeadAttention(Module):
             # error (and never silently masks k by q's document ids)
             raise ValueError("segment_ids requires self-attention "
                              "(Tq == Tk)")
-        if self.resolve_use_flash(q.shape[-2], dtype=q.dtype):
+        if self.resolve_use_flash(q.shape[-2]):
             from bigdl_tpu.ops import flash_attention
-            if self.attention_impl == "flash" or self.block_size:
-                # an explicit kernel choice (or pinned block size) must
-                # stay on the Pallas kernel regardless of the cache
-                bs = self.block_size or 128
-                return flash_attention(q, k, v, causal=self.causal,
-                                       segment_ids=segment_ids,
-                                       block_q=bs, block_k=bs)
-            # "auto": leave blocks None so the tuned-crossover plan picks
-            # the winning blocks (or reroutes to the XLA fallback)
             return flash_attention(q, k, v, causal=self.causal,
-                                   segment_ids=segment_ids)
+                                   segment_ids=segment_ids,
+                                   block_q=self.block_size,
+                                   block_k=self.block_size)
         if self.block_size and allow_blockwise:
             if segment_ids is not None:
                 raise ValueError(

@@ -436,9 +436,8 @@ class Module:
         ``"dequant"`` dequantizes on the fly inside the MXU kernel
         (bf16 operands, f32 accumulation); ``"int8"`` quantizes
         activations per token and feeds BOTH int8 operands to the MXU
-        with exact int32 accumulation and one f32 rescale; ``"auto"``
-        follows the measured int8-vs-dequant duel in ops/autotune.py;
-        ``"fp8"`` gates on capable device kinds.  ``dtype="bf16"``: a
+        with exact int32 accumulation and one f32 rescale; ``"fp8"``
+        gates on capable device kinds.  ``dtype="bf16"``: a
         plain storage cast.  The include/exclude ``policy`` defaults
         skip norms, biases and embedding tables (see quant.QuantPolicy);
         an explicit ``policy`` wins over ``compute``.

@@ -7,8 +7,7 @@ schedule.  Every op has a pure-XLA fallback; kernels run in interpreter
 mode off-TPU so the test suite exercises them on CPU.
 """
 from bigdl_tpu.ops.flash_attention import (  # noqa: F401
-    AttentionPlan, flash_attention, flash_attention_with_lse,
-    resolve_attention_plan,
+    flash_attention, flash_attention_with_lse,
 )
 from bigdl_tpu.ops.grouped_attention import (  # noqa: F401
     grouped_decode_attention,
@@ -18,7 +17,4 @@ from bigdl_tpu.ops.kda_step import (  # noqa: F401
 )
 from bigdl_tpu.ops.latent_attention import (  # noqa: F401
     latent_decode_attention,
-)
-from bigdl_tpu.ops.paged_attention import (  # noqa: F401
-    paged_decode_attention, paged_decode_attention_reference,
 )

@@ -25,12 +25,14 @@ from __future__ import annotations
 import functools
 import math
 import os
-from typing import NamedTuple, Optional
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from bigdl_tpu.ops import _pallas
 
 _NEG = -1e30  # large-negative mask value: avoids (-inf) - (-inf) NaNs
 _LANES = 128  # m/l scratch is kept lane-replicated for TPU-friendly tiles
@@ -462,20 +464,16 @@ def _flash_bwd(q, k, v, o, lse, do, dlse, seg_q, seg_k, causal, scale,
     return dq[:, :, :tq], dk[:, :, :tk], dv[:, :, :tk]
 
 
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
 def _flash(q, k, v, seg_q, seg_k, causal, scale, block_q, block_k):
     o, _ = _flash_fwd(q, k, v, seg_q, seg_k, causal, scale, block_q,
-                      block_k, _use_interpret())
+                      block_k, _pallas.use_interpret())
     return o
 
 
 def _flash_vjp_fwd(q, k, v, seg_q, seg_k, causal, scale, block_q, block_k):
     o, lse = _flash_fwd(q, k, v, seg_q, seg_k, causal, scale, block_q,
-                        block_k, _use_interpret())
+                        block_k, _pallas.use_interpret())
     return o, (q, k, v, seg_q, seg_k, o, lse)
 
 
@@ -513,7 +511,7 @@ def _flash_vjp_bwd(causal, scale, block_q, block_k, res, do):
     dlse = jnp.zeros(lse.shape, jnp.float32)
     dq, dk, dv = _flash_bwd(q, k, v, o, lse, do, dlse, seg_q, seg_k,
                             causal, scale, block_q, block_k,
-                            _use_interpret())
+                            _pallas.use_interpret())
     return dq, dk, dv, None, None  # int segment ids carry no cotangent
 
 
@@ -523,13 +521,13 @@ _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
 def _flash_lse(q, k, v, seg_q, seg_k, causal, scale, block_q, block_k):
     return _flash_fwd(q, k, v, seg_q, seg_k, causal, scale, block_q,
-                      block_k, _use_interpret())
+                      block_k, _pallas.use_interpret())
 
 
 def _flash_lse_vjp_fwd(q, k, v, seg_q, seg_k, causal, scale, block_q,
                        block_k):
     o, lse = _flash_fwd(q, k, v, seg_q, seg_k, causal, scale, block_q,
-                        block_k, _use_interpret())
+                        block_k, _pallas.use_interpret())
     return (o, lse), (q, k, v, seg_q, seg_k, o, lse)
 
 
@@ -538,7 +536,7 @@ def _flash_lse_vjp_bwd(causal, scale, block_q, block_k, res, cts):
     q, k, v, seg_q, seg_k, o, lse = res
     dq, dk, dv = _flash_bwd(q, k, v, o, lse, do, dlse, seg_q, seg_k,
                             causal, scale, block_q, block_k,
-                            _use_interpret())
+                            _pallas.use_interpret())
     return dq, dk, dv, None, None
 
 
@@ -550,64 +548,15 @@ _flash_lse.defvjp(_flash_lse_vjp_fwd, _flash_lse_vjp_bwd)
 #: the crossover XLA's single fused kernel wins (no pallas_call launch
 #: framing, and the (T,T) scores still fit VMEM-friendly fusions); above
 #: it the flash tiles win on HBM traffic and, past ~8-16k, are the only
-#: thing that fits at all.  Override with BIGDL_TPU_FLASH_MIN_T; a tuned
-#: verdict in the autotune cache for this device kind overrides both.
+#: thing that fits at all.  Override with BIGDL_TPU_FLASH_MIN_T.
 FLASH_AUTO_MIN_T = int(os.environ.get("BIGDL_TPU_FLASH_MIN_T", "4096"))
 
 
-def use_flash_auto(seq_len: int, head_dim: Optional[int] = None,
-                   dtype=None, causal: bool = True) -> bool:
-    """The "auto" dispatch rule.  With a full config, a tuned verdict
-    from the autotune cache (measured ON THIS device kind) overrides
-    everything; otherwise the static heuristic: Pallas flash iff running
-    on a real TPU backend AND the sequence is past the crossover
-    (interpreter-mode flash on CPU is a correctness tool, never a speed
-    win)."""
-    if head_dim is not None and dtype is not None:
-        from bigdl_tpu.ops import autotune
-        entry = autotune.lookup(seq_len, head_dim, dtype, causal)
-        if entry is not None and entry.use_flash is not None:
-            return entry.use_flash
+def use_flash_auto(seq_len: int) -> bool:
+    """The "auto" dispatch rule: Pallas flash iff running on a real TPU
+    backend AND the sequence is past the crossover (interpreter-mode
+    flash on CPU is a correctness tool, never a speed win)."""
     return jax.default_backend() == "tpu" and seq_len >= FLASH_AUTO_MIN_T
-
-
-class AttentionPlan(NamedTuple):
-    """Resolved dispatch for one attention call (observability + tests)."""
-    impl: str           # "flash" | "xla"
-    block_q: Optional[int]
-    block_k: Optional[int]
-    source: str         # "pinned" | "tuned" | "default"
-
-
-def resolve_attention_plan(seq_len_k: int, head_dim: int, dtype,
-                           causal: bool, *,
-                           block_q: Optional[int] = None,
-                           block_k: Optional[int] = None) -> AttentionPlan:
-    """The crossover rule behind ``flash_attention``: explicit blocks pin
-    the kernel (tests, the autotuner itself); otherwise the tuning cache
-    decides — a tuned loss to naive XLA routes to the XLA fallback so
-    callers can never regress below the baseline, a tuned win supplies
-    the winning blocks, and no verdict keeps the 128x128 status quo."""
-    if block_q is not None or block_k is not None:
-        return AttentionPlan("flash", int(block_q or 128),
-                             int(block_k or 128), "pinned")
-    from bigdl_tpu.ops import autotune
-    entry = autotune.lookup(seq_len_k, head_dim, dtype, causal)
-    if entry is not None and entry.use_flash is not None:
-        if not entry.use_flash:
-            return AttentionPlan("xla", None, None, "tuned")
-        return AttentionPlan("flash", int(entry.block_q or 128),
-                             int(entry.block_k or 128), "tuned")
-    return AttentionPlan("flash", 128, 128, "default")
-
-
-def _xla_fallback(q, k, v, causal, scale, segment_ids):
-    from bigdl_tpu.nn.attention import dot_product_attention, segment_mask
-    mask = None
-    if segment_ids is not None:
-        mask = segment_mask(segment_ids, segment_ids)
-    return dot_product_attention(q, k, v, causal=causal, mask=mask,
-                                 scale=scale)
 
 
 def flash_attention(q, k, v, *, causal: bool = False,
@@ -621,11 +570,7 @@ def flash_attention(q, k, v, *, causal: bool = False,
     reduced efficiency).  Runs the Pallas kernel on TPU, interpreter mode
     elsewhere; differentiable via the recomputation backward.
 
-    Block sizes left as None engage the crossover dispatcher
-    (``resolve_attention_plan``): tuned winner blocks from TUNE_ATTN.json
-    when this device kind has been autotuned, the naive-XLA fused path
-    whenever the tuned flash time lost to it, 128x128 otherwise.
-    Passing explicit block sizes pins the Pallas kernel.
+    Block sizes left as None are 128 x 128.
 
     ``segment_ids`` (B, T) int: packed-document isolation for
     self-attention — position i attends position j only when their ids
@@ -652,15 +597,11 @@ def flash_attention(q, k, v, *, causal: bool = False,
                              f"{k.shape[1]} K/V heads")
         o, _ = _flash_fwd(q, k, v, segment_ids, segment_ids, causal,
                           float(scale), int(block_q or 128),
-                          int(block_k or 128), _use_interpret(),
+                          int(block_k or 128), _pallas.use_interpret(),
                           None if window is None else int(window))
         return o
-    plan = resolve_attention_plan(k.shape[-2], q.shape[-1], q.dtype,
-                                  causal, block_q=block_q, block_k=block_k)
-    if plan.impl == "xla":
-        return _xla_fallback(q, k, v, causal, float(scale), segment_ids)
     return _flash(q, k, v, segment_ids, segment_ids, causal, float(scale),
-                  plan.block_q, plan.block_k)
+                  int(block_q or 128), int(block_k or 128))
 
 
 def flash_attention_with_lse(q, k, v, *, causal: bool = False,

@@ -1,7 +1,7 @@
 """Grouped-head decode attention over a ``(k, v)`` pool as a Pallas TPU kernel.
 
-A decode step's softmax layer whose query heads SHARE K/V heads (``G = n_head /
-n_kv_head > 1``; a sliding window or none) reads the round's listed blocks
+A decode step's softmax layer (``G = n_head / n_kv_head`` query heads a K/V
+head, 1 or more; a sliding window or none) reads the round's listed blocks
 WHERE THEY LIE.  The XLA path (``generate._paged_attention`` through
 ``_attend_by_owner``) walks the live list a chunk at a time: a gather of the
 chunk's K and V blocks, two grouped matmuls against the queries spread over a
@@ -59,7 +59,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from bigdl_tpu.ops import paged_attention as _paged
+from bigdl_tpu.ops import _pallas
 from bigdl_tpu.ops.latent_attention import (LANES, _pieces, _summed,
                                             check_latent_kernel_shapes)
 
@@ -219,9 +219,8 @@ def check_grouped_kernel_shapes(block_len: int, lanes: int, head_dim: int,
     if v_dim % LANES or heads_a_read(head_dim, n_kv) is None:
         raise ValueError(
             f"decode_attn='paged_kernel' needs a head of whole {LANES}-lane "
-            f"tiles where query heads share K/V heads or a layer has a window, "
-            f"on TPU (got head_dim={head_dim}, values of {v_dim}, {n_kv} K/V "
-            f"heads)")
+            f"tiles on TPU (got head_dim={head_dim}, values of {v_dim}, "
+            f"{n_kv} K/V heads)")
 
 
 def grouped_decode_attention(q, k_arena, v_arena, tables, lengths, *,
@@ -251,15 +250,15 @@ def grouped_decode_attention(q, k_arena, v_arena, tables, lengths, *,
     q3 = (q[:, :, 0, :] if squeeze else q).astype(jnp.float32)
     s, h, d = q3.shape
     dv = d if v_dim is None else int(v_dim)
-    k_arena, layer = _paged._arena_layer(k_arena, layer)
-    v_arena, _ = _paged._arena_layer(v_arena, layer)
+    k_arena, layer = _pallas.arena_layer(k_arena, layer)
+    v_arena, _ = _pallas.arena_layer(v_arena, layer)
     blk, w = k_arena.shape[2:]
     n_kv = w // d if n_kv_head is None else int(n_kv_head)
     if h % n_kv or n_kv * d > w or n_kv * dv > v_arena.shape[3]:
         raise ValueError(f"{h} query heads of {d} do not divide over {n_kv} "
                          f"K/V heads in a row of {w} lanes")
     if interpret is None:
-        interpret = _paged._use_interpret()
+        interpret = _pallas.use_interpret()
     if not interpret:
         check_grouped_kernel_shapes(blk, w, d, k_arena.dtype, dv, n_kv,
                                     v_arena.shape[3])

@@ -53,7 +53,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from bigdl_tpu.ops import paged_attention as _paged
+from bigdl_tpu.ops import _pallas
+from bigdl_tpu.ops._pallas import sublane_tile
 from bigdl_tpu.ops.latent_attention import LANES
 
 #: rows a window holds: one pass of an expert's columns through the MXU.  The
@@ -67,11 +68,6 @@ WINDOW = 64
 #: SwiGLU 1.69 / 1.63 ms, Laguna's 8 hit experts 0.267 / 0.245, Solar's and
 #: Ling's alike; 1 MB no better where it was tried)
 TILE_BYTES = 4 << 20
-
-
-def sublane_tile(dtype) -> int:
-    """Rows of ``dtype`` a sublane tile holds: 8 of 32 bits, 16 of 16."""
-    return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
 
 
 def column_tile(k: int, n: int, itemsize: int,
@@ -195,7 +191,7 @@ def grouped_matmul(x, w, sizes, *, window: int = WINDOW,
                          f"{[a.dtype for a in ws]} do not meet rows "
                          f"{x.shape} of {x.dtype}")
     if interpret is None:
-        interpret = _paged._use_interpret()
+        interpret = _pallas.use_interpret()
     ids, hit, offs = hit_experts(sizes.astype(jnp.int32))
     xp = jnp.pad(x, ((0, -m % sublane_tile(x.dtype)), (0, 0)))
     out = _call(xp, ws, ids, hit, offs, window=window_rows(m, x.dtype, window),

@@ -48,8 +48,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from bigdl_tpu.ops import paged_attention as _paged
-from bigdl_tpu.ops.grouped_matmul import sublane_tile
+from bigdl_tpu.ops import _pallas
+from bigdl_tpu.ops._pallas import sublane_tile
 from bigdl_tpu.ops.latent_attention import LANES
 
 #: heads of one slot a grid step owns: 8 heads of 128 x 128 float32 are 512
@@ -68,7 +68,7 @@ def kda_step_path(heads: int, d_k: int, d_v: int) -> str:
     :data:`HEADS_BLOCK`; ``"xla"`` (``nn.kda.kda_step``, a ``select`` and a
     ``dynamic-update-slice``) for everything else: the CPU, and a shape the
     compiled kernel cannot tile."""
-    if _paged._use_interpret():
+    if _pallas.use_interpret():
         return "xla"
     tiled = not (d_v % LANES or d_k % sublane_tile(jnp.float32)
                  or heads % HEADS_BLOCK)
@@ -127,7 +127,7 @@ def kda_step_rows(state, layer, active, q, k, v, g, beta, *, interpret=None):
     if h % HEADS_BLOCK:
         raise ValueError(f"{h} heads do not divide into blocks of {HEADS_BLOCK}")
     if interpret is None:
-        interpret = _paged._use_interpret()
+        interpret = _pallas.use_interpret()
     q, k, v, g, beta = (x.astype(jnp.float32) for x in (q, k, v, g, beta))
     ids, count = active_slots(active)
     o, state = _call(

@@ -22,8 +22,7 @@ live list, with ``lengths`` (S,), the positions a slot attends (0 for an idle
 slot).  Tables and not the list's own runs by owner: a step's
 ``blocks_per_step`` entries are then the slot's own or scratch padding by
 construction -- a run would be read into the next owner's -- every index is
-in bounds, and the scatter that spells them is one small XLA operation the
-branch already holds for ``ops.paged_attention``.
+in bounds, and the scatter that spells them is one small XLA operation.
 
 The grid is ``(S, ceil(table_width / blocks_per_step))``, the second axis
 sequential; a step past a slot's last listed block does nothing, so the bytes
@@ -53,7 +52,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from bigdl_tpu.ops import paged_attention as _paged
+from bigdl_tpu.ops import _pallas
 
 #: blocks a grid step fetches at once (PERF.md, PR 36: the cell's reading)
 BLOCKS_PER_STEP = 128
@@ -164,9 +163,9 @@ def _latent_kernel(tbl_ref, len_ref, layer_ref, q_ref, arena_ref, o_ref,
 def check_latent_kernel_shapes(block_len: int, lanes: int, dtype) -> None:
     """Raise where the COMPILED kernel cannot take the pool's geometry: a
     block is copied to a row of the VMEM buffer that has to lie on the dtype's
-    sublane tile (``ops.paged_attention.check_paged_kernel_shapes``), and a
+    sublane tile (``ops._pallas.check_block_rows``), and a
     row is whole 128-lane tiles."""
-    _paged.check_paged_kernel_shapes(block_len, dtype)
+    _pallas.check_block_rows(block_len, dtype)
     if lanes % LANES:
         raise ValueError(
             f"decode_attn='paged_kernel' needs a latent row of whole "
@@ -202,10 +201,10 @@ def latent_decode_attention(q, arena, tables, lengths, *, score_dim: int,
     q3 = (q.reshape(q.shape[0], heads * rows, q.shape[3]) if squeeze
           else q).astype(jnp.float32)
     s, h, d = q3.shape
-    arena, layer = _paged._arena_layer(arena, layer)
+    arena, layer = _pallas.arena_layer(arena, layer)
     blk, w = arena.shape[2:]
     if interpret is None:
-        interpret = _paged._use_interpret()
+        interpret = _pallas.use_interpret()
     if not interpret:
         check_latent_kernel_shapes(blk, w, arena.dtype)
     value_lanes = d if value_lanes is None else int(value_lanes)

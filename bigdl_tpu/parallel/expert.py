@@ -37,7 +37,7 @@ import jax.numpy as jnp
 from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from bigdl_tpu.ops import paged_attention as _paged
+from bigdl_tpu.ops import _pallas
 from bigdl_tpu.ops.grouped_matmul import grouped_matmul, row_tiles
 from bigdl_tpu.parallel.mesh import EXPERT_AXIS
 
@@ -321,7 +321,7 @@ def expert_matmul_path(rows: int, d_model: int, width: int, dtype) -> str:
     stream too.  The limit is the kernel's own, not a crossover: alone on the
     chip it reads faster than ``ragged_dot`` at every shape tried, 256 rows
     to 4,096 (1.02 to 2.7 times: PERF.md, PR 41)."""
-    if _paged._use_interpret():
+    if _pallas.use_interpret():
         return "ragged_dot"
     rows_bytes = rows * max(d_model, width) * jnp.dtype(dtype).itemsize
     return "grouped_kernel" if rows_bytes <= KERNEL_ROWS_BYTES else "ragged_dot"

@@ -9,12 +9,10 @@ arXiv 1804.05839; BigDL 2.0 Nano's inference optimizations, arXiv
 - **storage-only ("dequant")**: activations stay in the compute dtype;
   the MXU contraction runs bf16 operands with f32 accumulation (the
   ops/flash_attention.py recipe) after an in-kernel dequant.
-- **true int8 compute ("int8"/"auto")**: activations are quantized per
+- **true int8 compute ("int8")**: activations are quantized per
   token (:mod:`~bigdl_tpu.quant.activations`, dynamic or calibrated)
   and BOTH int8 operands feed the MXU with exact int32 accumulation,
-  then one f32 rescale.  ``"auto"`` follows the measured
-  int8-vs-dequant duel per (shape, device_kind) in ops/autotune.py.
-  fp8 variants gate on capable device kinds.
+  then one f32 rescale.  fp8 variants gate on capable device kinds.
 
 Entry points:
 
@@ -25,7 +23,7 @@ Entry points:
 from bigdl_tpu.quant.qtensor import (QMAX, QTensor, dequantize_array,
                                      is_qtensor, quantize_array)
 from bigdl_tpu.quant.kernels import (qconv, qconv_i8, qlinear, qlinear_i8,
-                                     qmatmul, qmatmul_i8, resolve_compute)
+                                     qmatmul, qmatmul_i8)
 from bigdl_tpu.quant.activations import (ActCalibrator, attach_act_scales,
                                          fp8_supported, quantize_per_token)
 from bigdl_tpu.quant.transform import (QuantPolicy, dequantize_entry,
@@ -41,6 +39,6 @@ __all__ = [
     "fp8_supported", "is_qtensor", "params_compute_tag", "params_dtype_tag",
     "params_nbytes", "qconv", "qconv_i8", "qlinear", "qlinear_i8",
     "qmatmul", "qmatmul_i8", "quantize_array", "quantize_params",
-    "quantize_per_token", "resolve_compute", "set_compute_mode",
+    "quantize_per_token", "set_compute_mode",
     "stage_quantized_params",
 ]

@@ -20,14 +20,10 @@ Two MXU recipes live here:
   operands, so the error budget is exactly the two quantization
   roundings and nothing else.
 
-``resolve_compute``/``qmatmul`` are the dispatch seam: a QTensor's
-``compute`` aux picks the recipe, and ``"auto"`` consults the measured
-int8-vs-dequant duel persisted per device_kind by ops/autotune.py — the
-same never-lose-to-the-baseline contract flash "auto" honors.
+``qlinear``/``qconv``/``qmatmul`` are the dispatch seam: a QTensor's
+``compute`` aux picks the recipe.
 """
 from __future__ import annotations
-
-import math
 
 import jax.numpy as jnp
 from jax import lax
@@ -44,23 +40,6 @@ def _operand(x):
     return x
 
 
-def resolve_compute(qweight: QTensor, x_shape) -> str:
-    """The effective compute mode for one (activation shape, weight)
-    pair: "int8" or "dequant".  "auto" resolves through the autotuned
-    duel (per device_kind; no verdict -> dequant, so auto can never
-    lose to the path we already had).  Trace-time only — the decision
-    is static per compiled shape, exactly like flash "auto"."""
-    mode = qweight.compute
-    if mode == "auto":
-        from bigdl_tpu.ops import autotune
-        m = int(math.prod(x_shape[:-1])) if len(x_shape) > 1 else 1
-        k = int(x_shape[-1])
-        n = int(qweight.q.shape[0] if qweight.native
-                else qweight.q.shape[-1])
-        mode = autotune.lookup_qcompute(m, k, n) or "dequant"
-    return mode
-
-
 # ---------------------------------------------------------------------- #
 # dequant-on-the-fly (storage-only) recipe                               #
 # ---------------------------------------------------------------------- #
@@ -68,7 +47,7 @@ def qlinear(x, qweight: QTensor, bias=None):
     """Quantized ``y = x @ W.T + b`` (nn.Linear semantics, weight
     ``(out, in)`` with per-out-channel scales ``(out, 1)``); compute
     mode dispatched per the weight's ``compute`` aux."""
-    if resolve_compute(qweight, jnp.shape(x)) == "int8":
+    if qweight.compute == "int8":
         return qlinear_i8(x, qweight, bias)
     w = qweight.dequantize(jnp.bfloat16)
     y = jnp.matmul(_operand(x), w.T,
@@ -88,7 +67,7 @@ def qconv(x, qweight: QTensor, *, window_strides, padding,
               dimension_numbers=dimension_numbers,
               feature_group_count=feature_group_count,
               rhs_dilation=rhs_dilation)
-    if resolve_compute(qweight, jnp.shape(x)) == "int8":
+    if qweight.compute == "int8":
         return qconv_i8(x, qweight, **kw)
     w = qweight.dequantize(jnp.bfloat16)
     y = lax.conv_general_dilated(
@@ -128,10 +107,9 @@ def qmatmul(x, w):
         return x @ w
     x = jnp.asarray(x)
     if w.q.ndim == 2:
-        mode = resolve_compute(w, x.shape)
-        if mode == "int8":
+        if w.compute == "int8":
             return qmatmul_i8(x, w)
-        if mode == "fp8":
+        if w.compute == "fp8":
             return qmatmul_f8(x, w)
     # dequant fallback reproduces the jit-entry-seam numerics exactly:
     # expand to orig dtype, matmul at the activation's precision

@@ -46,9 +46,8 @@ class QTensor:
     ``compute`` selects what the consuming kernel does with the leaf:
     ``"dequant"`` (the storage-only default: expand to bf16/f32 before
     the MXU), ``"int8"`` (feed the int8 values straight to the MXU with
-    int32 accumulation — quant/kernels.py ``*_i8`` paths), or ``"auto"``
-    (per-shape winner of the measured int8-vs-dequant duel in
-    ops/autotune.py).  ``act_scale`` optionally pins a calibrated static
+    int32 accumulation — quant/kernels.py ``*_i8`` paths) or ``"fp8"``.
+    ``act_scale`` optionally pins a calibrated static
     per-tensor activation scale (quant/activations.py) — ``None`` means
     dynamic per-token quantization at trace time.  Both ride the pytree
     aux data, so tree_map/jit/AOT treat differently-configured leaves as
@@ -60,9 +59,9 @@ class QTensor:
     def __init__(self, q, scale, orig_dtype: str = "float32",
                  native: bool = False, compute: str = "dequant",
                  act_scale: Optional[float] = None):
-        if compute not in ("dequant", "int8", "auto", "fp8"):
-            raise ValueError(f"compute must be 'dequant', 'int8', "
-                             f"'auto' or 'fp8', got {compute!r}")
+        if compute not in ("dequant", "int8", "fp8"):
+            raise ValueError(f"compute must be 'dequant', 'int8' or "
+                             f"'fp8', got {compute!r}")
         self.q = q
         self.scale = scale
         self.orig_dtype = str(orig_dtype)

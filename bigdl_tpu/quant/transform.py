@@ -64,9 +64,8 @@ class QuantPolicy:
         compute: what the consuming kernel does with int8 leaves —
             ``"dequant"`` (storage-only, the default), ``"int8"`` (true
             int8×int8 MXU compute with per-token activation
-            quantization), ``"auto"`` (the measured int8-vs-dequant
-            duel per shape/device_kind), or ``"fp8"`` (gated on capable
-            device kinds via activations.fp8_supported()).
+            quantization), or ``"fp8"`` (gated on capable device kinds
+            via activations.fp8_supported()).
         compute_name_re: which NON-native leaf names are allowed to
             carry a non-dequant compute mode (defaults to the
             transformer matmul sites kernels.qmatmul serves); native
@@ -83,9 +82,9 @@ class QuantPolicy:
         if dtype not in ("int8", "bf16"):
             raise ValueError(f"unsupported quant dtype {dtype!r} "
                              "(int8 or bf16)")
-        if compute not in ("dequant", "int8", "auto", "fp8"):
+        if compute not in ("dequant", "int8", "fp8"):
             raise ValueError(f"unsupported compute mode {compute!r} "
-                             "(dequant, int8, auto or fp8)")
+                             "(dequant, int8 or fp8)")
         if compute != "dequant" and dtype != "int8":
             raise ValueError(f"compute={compute!r} needs dtype='int8' "
                              f"(got {dtype!r}): only int8 storage feeds "
@@ -257,7 +256,7 @@ def quantize_params(params, dtype: str = "int8", *,
         if report is not None:
             err = float(jnp.max(jnp.abs(node - qt.dequantize(node.dtype))))
             per_layer_err["/".join(path)] = err
-            if compute in ("int8", "auto"):
+            if compute == "int8":
                 per_layer_risk["/".join(path)] = _overflow_risk(
                     qt, reduce_axes)
         return qt
@@ -329,9 +328,9 @@ def set_compute_mode(params, compute: str, *,
     expanding at the seam regardless of the requested mode.  This is
     how an int8-storage *target* becomes its own int8-*compute* drafter
     without a second copy of the weights."""
-    if compute not in ("dequant", "int8", "auto", "fp8"):
-        raise ValueError(f"compute must be 'dequant', 'int8', 'auto' or "
-                         f"'fp8', got {compute!r}")
+    if compute not in ("dequant", "int8", "fp8"):
+        raise ValueError(f"compute must be 'dequant', 'int8' or 'fp8', "
+                         f"got {compute!r}")
     name_re = (re.compile(compute_name_re)
                if isinstance(compute_name_re, str) else compute_name_re)
 
@@ -352,13 +351,13 @@ def set_compute_mode(params, compute: str, *,
 
 
 def params_compute_tag(params) -> Optional[str]:
-    """The dominant compute mode of a params tree ("int8" > "auto" >
+    """The dominant compute mode of a params tree ("fp8" > "int8" >
     "dequant"; None when nothing is quantized) — surfaced by
     quant_report, DraftModel.describe() and the serving/lm/spec/*
     gauges so a storage-only drafter is never mistaken for a true
     int8-compute one."""
     best = None
-    rank = {"dequant": 0, "auto": 1, "int8": 2, "fp8": 3}
+    rank = {"dequant": 0, "int8": 1, "fp8": 2}
     for leaf in jax.tree_util.tree_leaves(params, is_leaf=is_qtensor):
         if is_qtensor(leaf):
             if best is None or rank[leaf.compute] > rank[best]:

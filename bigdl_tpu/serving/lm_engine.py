@@ -1138,23 +1138,11 @@ class LMServingEngine:
         eos_id: default 1-based stop token; generation also stops at
             ``max_new``.
         max_queue: admission queue bound (``ServingQueueFull`` beyond).
-        decode_attn: decode attention over the paged cache —
-            "gather" (the round's live blocks gathered a chunk at a
-            time as they lie and attended by
-            ``generate._paged_attention``, the XLA path; how many
-            chunks follows each round from what the slots hold, never
-            set), "paged_kernel" (the in-place Pallas block-table
-            kernel, ``ops.paged_attention``; where query heads share
-            K/V heads or a layer has a window, and for a latent pool, the
-            kernels that read the listed blocks where they lie,
-            ``ops.grouped_attention`` and ``ops.latent_attention``), or
-            "auto" (default): the kernel only when the autotune cache has
-            measured it faster than the gather ON THIS device kind, the
-            gather otherwise; for shared K/V heads and for a latent pool
-            the kernel on a TPU whenever the compiled kernel can take the
-            pool's geometry, the gather (the CPU path) otherwise.  Both
-            produce token-identical streams (those two kernels' to the
-            order of float32 sums).
+        decode_attn: how the decode step reads the paged cache:
+            "gather" (the live list's walk, the XLA path),
+            "paged_kernel" (the Pallas kernel of the pool's kind) or
+            "auto" (default): ``generate.decode_attention_path``
+            resolves it once, from the platform and the pool's shapes.
         kv_quant: ``None`` (full-precision KV, the default) or
             ``"int8"``: the block pool stores int8 KV blocks with
             per-(position, head) f32 scales, dequantized inside the
@@ -1234,7 +1222,8 @@ class LMServingEngine:
         from bigdl_tpu.models.transformer.generate import (
             _decode_pick_paged, _insert_blocks, _insert_rows, _prefill_parts,
             _prefill_suffix_parts, _selfdraft_step_paged, _tree_commit_paged,
-            _tree_verify_step_paged, _verify_step_paged)
+            _tree_verify_step_paged, _verify_step_paged,
+            decode_attention_path)
         from bigdl_tpu.quant import dequantize_entry
         from bigdl_tpu.serving.kvcache import state as kvstate
 
@@ -1490,77 +1479,8 @@ class LMServingEngine:
             _prefix_prefill_fn, max_entries=max_cache_entries,
             placement_tag=_ptag, name=f"lm/{name}/prefix_prefill")
 
-        if decode_attn not in ("auto", "gather", "paged_kernel"):
-            raise ValueError(f"decode_attn must be 'auto', 'gather' or "
-                             f"'paged_kernel', got {decode_attn!r}")
-
-        # query heads that share K/V heads, and layers with a window: what
-        # of a (k, v) pool the grouped kernel reads (ops.grouped_attention)
-        _specs = [s for _, period in model.plan for s in period
-                  if s.mixer == "attention"]
-        _shared = any(s.n_head != model.kv_heads(s) or s.sink
-                      for s in _specs) or model.v_dim != model.head_dim
-        _windows = any(s.window is not None for s in _specs)
-
-        def _check_kernel_shapes():
-            # raises for a pool geometry the COMPILED kernel cannot read
-            from bigdl_tpu.ops.grouped_attention import (
-                check_grouped_kernel_shapes)
-            from bigdl_tpu.ops.latent_attention import (
-                check_latent_kernel_shapes)
-            from bigdl_tpu.ops.paged_attention import check_paged_kernel_shapes
-            if self._latent_layers:
-                check_latent_kernel_shapes(self.block_len,
-                                           self.pool.shape[-1], dt)
-            elif _shared or _windows:
-                for c in self.pool.classes:
-                    check_grouped_kernel_shapes(
-                        self.block_len, c.shape[-1], c.head_dim, dt,
-                        c.v_dim, c.n_heads, c.v_shape[-1])
-            else:
-                check_paged_kernel_shapes(self.block_len, dt)
-
-        if _kvq:
-            # the Pallas paged kernel reads raw blocks — a quantized
-            # pool's in-gather dequant needs the gather path
-            if decode_attn == "paged_kernel":
-                raise ValueError(
-                    "kv_quant='int8' requires decode_attn='gather' (the "
-                    "Pallas paged kernel reads raw blocks)")
-            decode_attn = "gather"
-        elif decode_attn == "auto" and (self._latent_layers or _shared):
-            # a latent pool's kernel and the one for query heads that share
-            # K/V heads read the listed blocks where they lie
-            # (ops.latent_attention, ops.grouped_attention): on the chip,
-            # where the compiled kernel can take the pool's geometry; the
-            # walk is the CPU path
-            decode_attn = "gather"
-            if jax.default_backend() == "tpu":
-                try:
-                    _check_kernel_shapes()
-                    decode_attn = "paged_kernel"
-                except ValueError:
-                    pass
-        elif decode_attn == "auto" and _windows:
-            # a head a query head under windows: no chip reading says the
-            # kernel beats the walk there
-            decode_attn = "gather"
-        elif decode_attn == "auto":
-            # the same crossover discipline as flash_attention: the
-            # kernel only on tuned evidence for this device kind, the
-            # proven XLA gather otherwise
-            from bigdl_tpu.ops import autotune
-            tuned = autotune.lookup_paged(model.head_dim, self.block_len, dt)
-            decode_attn = ("paged_kernel"
-                           if tuned is not None and tuned.use_kernel
-                           else "gather")
-        if decode_attn == "paged_kernel":
-            # a pool geometry the COMPILED kernel cannot read is an error
-            # here, not a silent gather (the interpreter takes any)
-            from bigdl_tpu.ops.paged_attention import _use_interpret
-            if not _use_interpret():
-                _check_kernel_shapes()
-        self.decode_attn = decode_attn
+        decode_attn = self.decode_attn = decode_attention_path(
+            model, self.pool, decode_attn)
 
         # every step program takes the pool's arenas last, (k, v) or
         # (k, v, ks, vs), donated, and hands them back after its result;
@@ -1603,7 +1523,9 @@ class LMServingEngine:
 
         #: a round's live blocks are attended this many at a time
         self._list_chunk = list_chunk(
-            self.slots, any(s.n_head != model.kv_heads(s) for s in _specs),
+            self.slots,
+            any(s.n_head != model.kv_heads(s) for _, period in model.plan
+                for s in period if s.mixer == "attention"),
             bool(self._latent_layers))
         #: self-drafting: a slot's hidden state at its prompt's end, kept
         #: from its prefill for its first round's first pair (S, hidden)

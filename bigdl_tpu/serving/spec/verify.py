@@ -43,7 +43,7 @@ FOLD_RESIDUAL = 107
 
 SAMPLING_MODES = ("replay", "rejection")
 
-DRAFTER_COMPUTE_MODES = ("dequant", "int8", "auto", "ngram")
+DRAFTER_COMPUTE_MODES = ("dequant", "int8", "ngram")
 
 
 class TreeShape:
@@ -245,8 +245,7 @@ class SpecConfig:
         self.sampling = sampling
         # kernel regime for the DEFAULT drafter (the target's int8
         # clone): "dequant" keeps weight-only dequant-on-the-fly,
-        # "int8" feeds int8 activations x int8 weights to the MXU,
-        # "auto" follows the measured duel in ops/autotune.py.  Drafter
+        # "int8" feeds int8 activations x int8 weights to the MXU.  Drafter
         # numerics only move acceptance — emitted tokens are the
         # target's under "replay".  Ignored when ``draft`` is given.
         self.drafter_compute = drafter_compute

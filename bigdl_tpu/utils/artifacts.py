@@ -1,7 +1,7 @@
 """Incremental, resumable measurement artifacts — the shared protocol.
 
-Every measurement tool in this package (attention_bench, lm_perf, the
-autotuner) follows one contract, for runs under a time limit:
+Every measurement tool in this package (lm_perf, convergence_bench)
+follows one contract, for runs under a time limit:
 
 - the artifact is rewritten ATOMICALLY after every row, so a sweep
   killed at its limit keeps everything it measured;

@@ -9,11 +9,10 @@ two main paths through the entry points a user calls:
            sampled, a shared prefix), each stream replayed against offline
            ``generate()`` on the same device.
   kernels  the compiled (not interpreted) Pallas kernels — flash forward
-           and backward, plain and segmented, paged decode, the kernels that
-           read a round's listed blocks and the routed experts' grouped
-           matmul (beside ``lax.ragged_dot``: gap to float64, time alone) —
-           against the XLA references in the repo; then the same requests
-           through an engine with ``decode_attn="paged_kernel"``.
+           and backward, plain and segmented, the kernels that read a
+           round's listed blocks and the routed experts' grouped matmul
+           (beside ``lax.ragged_dot``: gap to float64, time alone) —
+           against the XLA references in the repo.
   train    ResNet-50, ImageNet shapes, bf16 compute / f32 master, NHWC,
            through ``Optimizer.create(...).optimize()``.
   --chips 4   ONLY the multi-chip phase: ``DistriOptimizer`` on a 4-device
@@ -52,15 +51,13 @@ from bigdl_tpu.dataset import DataSet, MiniBatch
 from bigdl_tpu.models import ResNet
 from bigdl_tpu.models.transformer import TransformerLM, window_mask
 from bigdl_tpu.models.transformer.generate import _paged_attention, generate
-from bigdl_tpu.ops.flash_attention import (_xla_fallback, flash_attention,
-                                           use_flash_auto)
+from bigdl_tpu.nn.attention import dot_product_attention, segment_mask
+from bigdl_tpu.ops.flash_attention import flash_attention, use_flash_auto
 from bigdl_tpu.ops.grouped_attention import grouped_decode_attention
 from bigdl_tpu.ops.grouped_matmul import grouped_matmul
 from bigdl_tpu.ops.latent_attention import latent_decode_attention
-from bigdl_tpu.ops.paged_attention import (paged_decode_attention,
-                                           paged_decode_attention_reference)
 from bigdl_tpu.serving.kvcache.blocks import (SCRATCH_BLOCK, live_list,
-                                              pack_rows, row_width)
+                                              row_width)
 from bigdl_tpu.optim import SGD, Optimizer, Trigger
 from bigdl_tpu.serving import LMServingEngine
 from bigdl_tpu.utils.engine import configure_compile_cache
@@ -96,17 +93,13 @@ class Sizes:
     shared_prefix: int = 64         # tokens two requests have in common
     shared_tail: int = 8
     max_new: int = 12
-    # kernels: (B, H, T, D, dtype) flash cases and (S, H, D, blk, M, dtype)
+    # kernels: (B, H, T, D, dtype) flash cases
     flash_cases: Tuple[tuple, ...] = (
         (2, 25, 1024, 64, "bfloat16"),
         (2, 25, 1024, 64, "float32"),
         (1, 8, 4096, 128, "bfloat16"),
     )
     flash_block: int = 128
-    paged_cases: Tuple[tuple, ...] = (
-        (8, 25, 64, 16, 64, "bfloat16"),
-        (8, 25, 64, 16, 64, "float32"),
-    )
     # the kernels that read a round's listed blocks where they lie, at their
     # cells' rows: (kind, S, H, H_kv, D, blk, M, window, dtype) -- Solar's
     # softmax layer, Laguna's sliding layers, Ling's latent row (D its lanes,
@@ -364,12 +357,11 @@ def _serve_requests(eng: LMServingEngine, reqs: list, sz: Sizes) -> list:
             for i, r in enumerate(reqs)]
 
 
-def _run_engine(model, reqs, sz: Sizes, decode_attn: str) -> Tuple[list, dict]:
+def _run_engine(model, reqs, sz: Sizes) -> Tuple[list, dict]:
     eng = LMServingEngine(
         model, slots=sz.slots, cache_len=sz.context,
         max_new_tokens=sz.max_new, prefill_buckets=sz.prefill_buckets,
-        block_len=sz.block_len, num_blocks=sz.num_blocks,
-        decode_attn=decode_attn)
+        block_len=sz.block_len, num_blocks=sz.num_blocks)
     try:
         t0 = time.perf_counter()
         eng.warmup()
@@ -383,7 +375,6 @@ def _run_engine(model, reqs, sz: Sizes, decode_attn: str) -> Tuple[list, dict]:
         stats = eng.stats()
         prefix = (stats["kvcache"]["prefix_cache"] or {})
         info = {
-            "decode_attn_requested": decode_attn,
             "decode_attn_resolved": eng.decode_attn,
             "kv_dtype": str(jnp.dtype(eng._cache_dtype)),
             "warmup_seconds": round(warm_s, 2),
@@ -412,24 +403,22 @@ def _run_engine(model, reqs, sz: Sizes, decode_attn: str) -> Tuple[list, dict]:
         eng.close()
 
 
-def _prefill_attention(model, sz: Sizes) -> dict:
+def _prefill_attention(sz: Sizes) -> dict:
     """Which attention implementation each prefill bucket resolves to
     (``attention_impl="auto"``: flash from FLASH_AUTO_MIN_T up on TPU)."""
-    dt = model.params["embed"].dtype
-    return {str(b): ("flash" if use_flash_auto(
-        b, model._mha.head_dim, dt, True) else "xla")
-        for b in sz.prefill_buckets}
+    return {str(b): "flash" if use_flash_auto(b) else "xla"
+            for b in sz.prefill_buckets}
 
 
-def phase_serve(sz: Sizes = REAL, seed: int = 0) -> Tuple[dict, tuple]:
-    """Returns the printed row and ``(model, requests, streams, offline
-    streams)`` for the kernels phase to answer the same requests."""
+def phase_serve(sz: Sizes = REAL, seed: int = 0) -> Tuple[dict, object]:
+    """Returns the printed row and the model (whose memory must come back
+    when the caller lets go of it: tests/test_chip_smoke.py)."""
     mark = PROBE.mark()
     t0 = time.perf_counter()
     model = _build_lm(sz, seed)
     build_s = time.perf_counter() - t0
     reqs = _requests(sz, seed)
-    outs, info = _run_engine(model, reqs, sz, "auto")
+    outs, info = _run_engine(model, reqs, sz)
     _free_device_memory()       # the closed engine's arenas
     t0 = time.perf_counter()
     refs = [_offline(model, r, sz) for r in reqs]
@@ -450,7 +439,7 @@ def phase_serve(sz: Sizes = REAL, seed: int = 0) -> Tuple[dict, tuple]:
         "prompt_lens": [len(r["prompt"]) for r in reqs],
         "temperatures": [r["temperature"] for r in reqs],
         "max_new": sz.max_new,
-        "prefill_attention": _prefill_attention(model, sz),
+        "prefill_attention": _prefill_attention(sz),
         "build_seconds": round(build_s, 2),
         "offline_generate_seconds": round(offline_s, 2),
         "streams": streams,
@@ -459,7 +448,7 @@ def phase_serve(sz: Sizes = REAL, seed: int = 0) -> Tuple[dict, tuple]:
         **info, **PROBE.since(mark), "peak_bytes": _peak_bytes(),
     }
     _emit(row)
-    return row, (model, reqs, outs, refs)
+    return row, model
 
 
 # --------------------------------------------------------------------------
@@ -488,8 +477,9 @@ def _flash_case(case: tuple, segmented: bool, block: int, seed: int) -> dict:
     flash = lambda q, k, v: flash_attention(  # noqa: E731
         q, k, v, causal=True, scale=scale, segment_ids=seg,
         block_q=block, block_k=block)
-    ref = lambda q, k, v: _xla_fallback(  # noqa: E731
-        q, k, v, True, scale, seg)
+    mask = None if seg is None else segment_mask(seg, seg)
+    ref = lambda q, k, v: dot_product_attention(  # noqa: E731
+        q, k, v, causal=True, mask=mask, scale=scale)
     o = jax.jit(flash)(q, k, v)
     o_ref = jax.jit(ref)(q, k, v)
     g = jax.jit(jax.grad(loss(flash), argnums=(0, 1, 2)))(q, k, v)
@@ -505,34 +495,6 @@ def _flash_case(case: tuple, segmented: bool, block: int, seed: int) -> dict:
     return {"kernel": "flash fwd+bwd", "shape": [b, h, t, d],
             "dtype": dtname, "segmented": segmented, "block": block,
             "rel_err": {k: round(e, 5) for k, e in errs.items()}}
-
-
-def _paged_case(case: tuple, seed: int) -> dict:
-    s, h, d, blk, m, dtname = case
-    dt = jnp.dtype(dtname)
-    n = s * m + 1
-    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
-    q = jax.random.normal(ks[0], (s, h, 1, d), jnp.float32).astype(dt)
-    # one layer's arena in the pool's layout (serving.kvcache.blocks)
-    ka = pack_rows(jax.random.normal(ks[1], (n, blk, h, d),
-                                     jnp.float32).astype(dt))
-    va = pack_rows(jax.random.normal(ks[2], (n, blk, h, d),
-                                     jnp.float32).astype(dt))
-    rs = np.random.RandomState(seed)
-    tables = jnp.asarray(
-        1 + rs.permutation(s * m).reshape(s, m).astype(np.int32))
-    pos = jnp.asarray(rs.randint(0, m * blk, size=s).astype(np.int32))
-    o = jax.jit(paged_decode_attention)(q, ka, va, tables, pos)
-    o_ref = jax.jit(paged_decode_attention_reference)(
-        q, ka, va, tables, pos)
-    err = _rel_err(o, o_ref)
-    _check(bool(np.isfinite(np.asarray(o)).all()),
-           f"paged decode {case} produced non-finite values")
-    _check(err <= KERNEL_TOL,
-           f"paged decode {case} vs gather reference: {err} > {KERNEL_TOL}")
-    return {"kernel": "paged decode", "slots": s, "heads": h, "head_dim": d,
-            "block_len": blk, "table_width": m, "dtype": dtname,
-            "rel_err": round(err, 5)}
 
 
 #: a kernel that multiplies exact products may lie this far from float64
@@ -690,49 +652,25 @@ def _expert_case(case: tuple, seed: int, reps: int = 20) -> dict:
     return row
 
 
-def phase_kernels(sz: Sizes = REAL, seed: int = 0, carry=None,
+def phase_kernels(sz: Sizes = REAL, seed: int = 0,
                   require_compiled: bool = True) -> dict:
     mark = PROBE.mark()
     cases = []
     for case in sz.flash_cases:
         for segmented in (False, True):
             cases.append(_flash_case(case, segmented, sz.flash_block, seed))
-    for case in sz.paged_cases:
-        cases.append(_paged_case(case, seed))
     for case in sz.listed_cases:
         cases.append(_listed_case(case, seed))
         _free_device_memory()
     for case in sz.expert_cases:
         cases.append(_expert_case(case, seed))
         _free_device_memory()
-    if carry is None:
-        model = _build_lm(sz, seed)
-        reqs = _requests(sz, seed)
-        outs = refs = None
-    else:
-        model, reqs, outs, refs = carry
-    _free_device_memory()       # the kernel cases' operands
-    k_outs, info = _run_engine(model, reqs, sz, "paged_kernel")
-    _free_device_memory()
-    _check(info["decode_attn_resolved"] == "paged_kernel",
-           "the engine did not keep decode_attn='paged_kernel'")
-    if outs is None:
-        outs = refs = [_offline(model, r, sz) for r in reqs]
-    streams = []
-    for r, got, gather, ref in zip(reqs, k_outs, outs, refs):
-        if np.array_equal(got, gather):
-            streams.append({"exact": True, "against": "gather engine"})
-        else:   # the gather stream may itself have taken a near-tie
-            streams.append({**_compare_stream(model, r, got, ref, sz),
-                            "against": "offline generate()"})
     probe = PROBE.since(mark)
     if require_compiled:
         _check(probe["pallas_calls_traced"] > 0,
                "no Pallas call was traced in the kernels phase")
     row = {"phase": "kernels", "ok": True, "tolerance": KERNEL_TOL,
-           "cases": cases, "engine": info, "streams": streams,
-           "streams_exact": sum(s["exact"] for s in streams),
-           **probe, "peak_bytes": _peak_bytes()}
+           "cases": cases, **probe, "peak_bytes": _peak_bytes()}
     _emit(row)
     return row
 
@@ -925,15 +863,12 @@ def _preflight(chips: int) -> dict:
             f"chip_smoke: --chips {chips} but JAX reports {len(devices)} "
             f"devices")
     from bigdl_tpu import native
-    from bigdl_tpu.ops import autotune
     from bigdl_tpu.serving.placement.topology import DeviceTopology
     from bigdl_tpu.utils.profiling import device_peaks
     topo = DeviceTopology.detect()
     _check(not topo.degraded and topo.n_devices == chips
            and topo.platform == "tpu",
            f"degraded or non-TPU topology: {topo.describe()}")
-    _check(autotune._device_kind() == d0.device_kind,
-           "autotune could not read the device kind")
     peaks = device_peaks(d0.device_kind)     # raises on an unknown kind
     return {"platform": d0.platform, "kind": d0.device_kind,
             "count": len(devices), "peaks_source": peaks.source,
@@ -958,10 +893,9 @@ def main(argv=None) -> int:
     if args.chips == 4:
         phase_multichip(REAL, args.seed)
     else:
-        _, carry = phase_serve(REAL, args.seed)
-        phase_kernels(REAL, args.seed, carry=carry)
-        del carry                   # the LM, before the trainer
+        phase_serve(REAL, args.seed)    # the LM goes with its result
         _free_device_memory()
+        phase_kernels(REAL, args.seed)
         phase_train(REAL, args.seed)
     interpreted = sorted({n for n, i in PROBE.pallas_calls if i})
     _check(not interpreted,

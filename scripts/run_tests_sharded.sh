@@ -37,20 +37,11 @@ if [ "${FAULTS_GATE:-1}" = "1" ]; then
     -q -m faults || exit 1
 fi
 
-# Artifact schema lint: TUNE_*/TRACE_*/FLIGHT_* files — a truncated or
+# Artifact schema lint: TRACE_*/FLIGHT_* files — a truncated or
 # key-drifted one fails silently downstream (resume identity never
 # matches, a forensic bundle reads as empty), so it should fail loudly
 # here, in seconds.
 python scripts/validate_artifact.py || exit 1
-
-# Kernel correctness gate: the attention crossover + paged-decode
-# kernel and the autotune cache are dispatch-critical (a bad verdict
-# silently reroutes every "auto" attention call) — fail fast before
-# the full shards spend their minutes.
-if [ "${ATTN_GATE:-1}" = "1" ]; then
-  python -m pytest tests/test_paged_attention.py \
-    tests/test_autotune_attention.py -q -m "not slow" || exit 1
-fi
 
 # Placement gate: mesh-sliced serving is agreement-critical (a wrong
 # sharding rule serves silently wrong numbers from every TP slot) and

@@ -1,17 +1,13 @@
 #!/usr/bin/env python
 """Schema lint for the measurement artifacts the repo still produces.
 
-Three families, told apart by file name:
+Two families, told apart by file name:
 
   * ``FLIGHT_*`` incident bundles (``bigdl_tpu.obs.flight``) must carry
     every correlated section (spans, timeseries, state, diagnose_tpu,
     ...) and ``complete``;
   * ``TRACE_*`` files must satisfy the Chrome trace-event contract
-    (delegated to scripts/validate_trace.py);
-  * ``TUNE_*`` caches follow the resumable-artifact contract of
-    ``bigdl_tpu/utils/artifacts.py``: a boolean ``complete`` (false
-    until the final flush), a platform tag (rows without one can be
-    mistaken for chip numbers) and a list-of-dicts ``rows`` section.
+    (delegated to scripts/validate_trace.py).
 
 A file of any other family is reported as unknown, not passed: a
 truncated or key-drifted artifact fails SILENTLY downstream (resume
@@ -33,7 +29,7 @@ import sys
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 #: the families' file-name prefixes
-FAMILIES = ("FLIGHT_", "TRACE_", "TUNE_")
+FAMILIES = ("FLIGHT_", "TRACE_")
 
 #: where the repo keeps them
 PATTERNS = tuple(f + "*.json" for f in FAMILIES) + (
@@ -87,24 +83,6 @@ def _trace_problems(path: str) -> list:
     return validate_trace(path)
 
 
-def _resumable_problems(doc) -> list:
-    """The resumable-artifact contract (``utils/artifacts.py``)."""
-    if not isinstance(doc, dict):
-        return ["top level is %s, expected object" % type(doc).__name__]
-    probs = []
-    if not isinstance(doc.get("complete"), bool):
-        probs.append("missing boolean 'complete' "
-                     "(resumable-artifact contract)")
-    if "platform" not in doc:
-        probs.append("missing 'platform' tag")
-    rows = doc.get("rows")
-    if not isinstance(rows, list):
-        probs.append("'rows' is not a list")
-    elif not all(isinstance(r, dict) for r in rows):
-        probs.append("'rows' holds non-object entries")
-    return probs
-
-
 def validate(path: str) -> list:
     """Problems for one file ([] = clean)."""
     base = os.path.basename(path)
@@ -120,9 +98,7 @@ def validate(path: str) -> list:
         return ["unreadable: %s" % e]
     except json.JSONDecodeError as e:
         return ["not JSON: %s" % e]
-    if base.startswith("FLIGHT_"):
-        return _flight_problems(doc)
-    return _resumable_problems(doc)
+    return _flight_problems(doc)
 
 
 def main(argv=None) -> int:

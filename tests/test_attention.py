@@ -201,16 +201,40 @@ class TestRingFlash:
                                        atol=1e-4, rtol=1e-4)
 
 
-def test_auto_dispatch_rule():
-    """"auto" picks flash only on a TPU backend past the crossover length
-    (interpreter-mode flash on CPU is for correctness tests, never speed)."""
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+@pytest.mark.parametrize("past", [False, True])
+def test_auto_dispatch_rule(monkeypatch, backend, past):
+    """"auto" picks flash only on a TPU backend from the crossover length up
+    (interpreter-mode flash on CPU is for correctness tests, never speed):
+    the platform and the length, either side of ``FLASH_AUTO_MIN_T``."""
     from bigdl_tpu.ops.flash_attention import (FLASH_AUTO_MIN_T,
                                                use_flash_auto)
-    # this test process runs on CPU: never flash regardless of length
-    assert use_flash_auto(FLASH_AUTO_MIN_T * 2) is False
-    assert use_flash_auto(16) is False
-    # the rule itself, backend-independent part
-    assert FLASH_AUTO_MIN_T > 0
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    t = FLASH_AUTO_MIN_T if past else FLASH_AUTO_MIN_T - 1
+    assert use_flash_auto(t) is (backend == "tpu" and past)
+    # the module's one rule: "auto" follows it, a pinned block_size or
+    # "xla" never takes the kernel, "flash" always
+    mha = lambda **kw: nn.MultiHeadAttention(64, 2, causal=True, **kw)  # noqa
+    assert mha().resolve_use_flash(t) is use_flash_auto(t)
+    assert mha(block_size=64).resolve_use_flash(t) is False
+    assert mha(attention_impl="xla").resolve_use_flash(t) is False
+    assert mha(attention_impl="flash").resolve_use_flash(t) is True
+
+
+def test_flash_blocks_left_out_are_128():
+    """``flash_attention`` without blocks runs 128 x 128 tiles: the same
+    bits as the blocks pinned, other bits than another tile's order of
+    sums."""
+    from bigdl_tpu.ops import flash_attention
+    rs = np.random.RandomState(3)
+    q, k, v = (jnp.asarray(rs.randn(1, 2, 256, 16), jnp.float32)
+               for _ in range(3))
+    default = flash_attention(q, k, v, causal=True)
+    pinned = flash_attention(q, k, v, causal=True, block_q=128, block_k=128)
+    np.testing.assert_array_equal(np.asarray(default), np.asarray(pinned))
+    ref = dot_product_attention(q, k, v, causal=True)
+    np.testing.assert_allclose(np.asarray(default), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
 
 
 class TestSegmentedSequenceParallel:

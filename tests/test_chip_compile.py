@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 
 fa = importlib.import_module("bigdl_tpu.ops.flash_attention")
-pa = importlib.import_module("bigdl_tpu.ops.paged_attention")
+pallas = importlib.import_module("bigdl_tpu.ops._pallas")
 
 
 @pytest.fixture(scope="module")
@@ -122,42 +122,13 @@ def test_flash_backward_kernels_compile_for_v5e(sds, shape, segmented):
     assert text.count("tpu_custom_call") >= 2
 
 
-#: (S, H, D, block_len, table width M, dtype): GPT-2 XL, GPT-2, 16 x 128
-PAGED_SHAPES = [
-    (8, 25, 64, 16, 64, "bfloat16"),
-    (8, 25, 64, 16, 64, "float32"),
-    (8, 12, 64, 16, 64, "bfloat16"),
-    (8, 16, 128, 16, 64, "bfloat16"),
-]
-
-
-@pytest.mark.parametrize("shape", PAGED_SHAPES,
-                         ids=lambda s: "x".join(map(str, s)))
-def test_paged_decode_compiles_for_v5e(sds, shape):
-    """cache_len 1024 (M x block_len): the whole-context VMEM scratch and
-    its f32 upcast fit the kernel's memory."""
-    from bigdl_tpu.serving.kvcache.blocks import row_width
-    s, h, d, blk, m, dt = shape
-    q = sds((s, h, 1, d), dt)
-    # one layer's arena in the pool's layout: a block is one (B, W) tile
-    arena = sds((s * m + 1, blk, row_width(h, d)), dt)
-
-    def decode(q, ka, va, tables, pos):
-        return pa.paged_decode_attention(q, ka, va, tables, pos,
-                                         interpret=False)
-
-    _, text = _compile(decode, q, arena, arena, sds((s, m), jnp.int32),
-                       sds((s,), jnp.int32))
-    assert "tpu_custom_call" in text
-
-
 def test_kernel_shapes_the_compiler_cannot_take_raise():
     """A geometry the compiled kernels cannot lay out is an error at the
     call that asks for it, never a silent fallback (no compiler needed)."""
     with pytest.raises(ValueError, match="multiple"):
-        pa.check_paged_kernel_shapes(8, jnp.bfloat16)   # 16-row bf16 tile
-    pa.check_paged_kernel_shapes(8, jnp.float32)
-    pa.check_paged_kernel_shapes(16, jnp.bfloat16)
+        pallas.check_block_rows(8, jnp.bfloat16)    # 16-row bf16 tile
+    pallas.check_block_rows(8, jnp.float32)
+    pallas.check_block_rows(16, jnp.bfloat16)
     q = jnp.zeros((1, 1, 64, 64), jnp.float32)
     with pytest.raises(ValueError, match="multiples of 128"):
         fa._flash_fwd(q, q, q, None, None, True, 0.125, 64, 64, False)
@@ -218,14 +189,13 @@ def test_lm_prefix_prefill_compiles_for_v5e(sds):
 CELL = dict(layers=48, slots=16, blocks=896, dtype="bfloat16")
 DEPTH2 = dict(layers=2, slots=8, blocks=96, dtype="float32")
 
-#: name -> (program, its argument, geometry).  The depth-2 cases are the
-#: old smoke (gather and Pallas); the cell's cases hold what the alias size
-#: never could: the step programs leave the arenas where they are.
+#: name -> (program, its argument, geometry).  The depth-2 case is the
+#: old smoke; the cell's cases hold what the alias size never could: the
+#: step programs leave the arenas where they are.  (GPT-2's head of 64
+#: lanes is no geometry a compiled decode kernel takes: the walk alone.)
 PAGED_PROGRAMS = {
     "decode-depth2-gather": ("decode", "gather", DEPTH2),
-    "decode-depth2-kernel": ("decode", "paged_kernel", DEPTH2),
     "decode-cell": ("decode", "gather", CELL),
-    "decode-cell-kernel": ("decode", "paged_kernel", CELL),
     "decode-cell-int8": ("decode", "int8", CELL),
     "insert64-cell": ("insert", 64, CELL),
     "insert512-cell": ("insert", 512, CELL),
@@ -249,9 +219,6 @@ def test_lm_paged_programs_keep_the_arenas_in_place_on_v5e(sds, monkeypatch,
     from bigdl_tpu.models.transformer import generate as G
     from bigdl_tpu.serving.kvcache.blocks import BlockPool, list_chunk
 
-    # the step asks jax.default_backend(), which is the CPU here: steer
-    # it from the test, not through an option of the program
-    monkeypatch.setattr(pa, "_use_interpret", lambda: False)
     program, arg, geom = PAGED_PROGRAMS[case]
     layers, slots, width, blk = geom["layers"], geom["slots"], 64, 16
     model, params = _gpt2_xl(sds, layers)
@@ -270,8 +237,6 @@ def test_lm_paged_programs_keep_the_arenas_in_place_on_v5e(sds, monkeypatch,
     arenas = [sds(a.shape, a.dtype) for a in jax.eval_shape(pool_arenas)]
     i32 = lambda *shape: sds(shape, jnp.int32)              # noqa: E731
     if program == "decode":
-        impl = "paged_kernel" if arg == "paged_kernel" else "gather"
-
         # the engine's program: the step picks its tokens, handed each
         # slot's temperature and key beside its token and position
         # and, not donated, the previous step's ids: a slot's token is its
@@ -279,7 +244,7 @@ def test_lm_paged_programs_keep_the_arenas_in_place_on_v5e(sds, monkeypatch,
         def step(p, tok, pos, live, temperature, keys, prev_ids, *kv):
             return G._decode_pick_paged(model, p, tok, pos, live, temperature,
                                         keys, prev_ids, *kv,
-                                        table_width=width, attn_impl=impl)
+                                        table_width=width, attn_impl="gather")
 
         args = (params, i32(slots), i32(slots), i32(3, slots * width),
                 sds((slots,), jnp.float32), sds((slots, 2), jnp.uint32),
@@ -299,12 +264,11 @@ def test_lm_paged_programs_keep_the_arenas_in_place_on_v5e(sds, monkeypatch,
         args = (chunk, chunk, i32(arg // blk))
     donate = tuple(range(len(args), len(args) + len(arenas)))
     compiled, text = _compile(step, *args, *arenas, donate_argnums=donate)
-    # the Pallas kernel is a custom call of its own (the grouped matmuls
-    # of a verify step are the compiler's: "ragged-dot")
-    pallas = [ln for ln in text.splitlines()
-              if 'custom_call_target="tpu_custom_call"' in ln
-              and "ragged" not in ln]
-    assert bool(pallas) == (arg == "paged_kernel")
+    # no Pallas kernel, which would be a custom call of its own (the
+    # grouped matmuls of a verify step are the compiler's: "ragged-dot")
+    assert not [ln for ln in text.splitlines()
+                if 'custom_call_target="tpu_custom_call"' in ln
+                and "ragged" not in ln]
     # GPT-2 has no routed layer: the experts' kernel is in none of its programs
     assert "grouped_matmul" not in text
     if program == "decode":
@@ -317,7 +281,7 @@ def test_lm_paged_programs_keep_the_arenas_in_place_on_v5e(sds, monkeypatch,
     arena_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in arenas)
     assert mem.alias_size_in_bytes >= 0.99 * arena_bytes
     assert mem.temp_size_in_bytes < 0.5e9, mem.temp_size_in_bytes
-    if program == "decode" and arg != "paged_kernel":
+    if program == "decode":
         # a chunk of the list (four blocks a slot) is gathered as it lies,
         # block index major, and reaches the matrix unit in the pool's own
         # dtype: no f32 copy of it, whole or cut to (.., H, D) (what the
@@ -519,9 +483,7 @@ def test_laguna_cell_compiles_for_v5e_and_keeps_the_arenas_in_place(
     from bigdl_tpu.models.transformer import generate as G
     from bigdl_tpu.serving.kvcache.blocks import BlockPool
 
-    monkeypatch.setattr(fa, "_use_interpret", lambda: False)
-    # (the platform seen as a TPU: the routed layers' rule asks)
-    monkeypatch.setattr(pa, "_use_interpret", lambda: False)
+    monkeypatch.setattr(pallas, "use_interpret", lambda: False)
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "benchmarks", "configs",
                            "laguna-s-2.1.json")) as f:
@@ -542,7 +504,6 @@ def test_laguna_cell_compiles_for_v5e_and_keeps_the_arenas_in_place(
     i32 = lambda *shape: sds(shape, jnp.int32)              # noqa: E731
     if program.startswith("decode"):
         impl = "gather" if program == "decode" else "paged_kernel"
-        monkeypatch.setattr(pa, "_use_interpret", lambda: False)
 
         def arenas_of():
             return BlockPool(classes=[
@@ -657,9 +618,7 @@ def test_solar2_cell_compiles_for_v5e_and_keeps_the_state_in_place(
     from bigdl_tpu.serving.kvcache import state as kvstate
     from bigdl_tpu.serving.kvcache.blocks import BlockPool
 
-    monkeypatch.setattr(fa, "_use_interpret", lambda: False)
-    # (the platform seen as a TPU: the routed layers' rule asks)
-    monkeypatch.setattr(pa, "_use_interpret", lambda: False)
+    monkeypatch.setattr(pallas, "use_interpret", lambda: False)
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "benchmarks", "configs",
                            "solar-open2-250b.json")) as f:
@@ -703,9 +662,6 @@ def test_solar2_cell_compiles_for_v5e_and_keeps_the_state_in_place(
     state_dims = "f32[3,128,64,128,128]"
     if program.startswith("decode"):
         impl = "gather" if program == "decode" else "paged_kernel"
-        # (the kernel asks jax.default_backend(), the CPU here: steered from
-        # the test, as the paged programs' above)
-        monkeypatch.setattr(pa, "_use_interpret", lambda: False)
 
         def step(p, tok, pos, live, temperature, keys, prev_ids, *kv):
             return G._decode_pick_paged(model, p, tok, pos, live, temperature,
@@ -807,7 +763,7 @@ def test_ling3_cell_compiles_for_v5e_and_keeps_latent_and_state_in_place(
     from bigdl_tpu.serving.kvcache.blocks import BlockPool
 
     # (the platform seen as a TPU: the routed layers' rule asks)
-    monkeypatch.setattr(pa, "_use_interpret", lambda: False)
+    monkeypatch.setattr(pallas, "use_interpret", lambda: False)
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "benchmarks", "configs",
                            "ling-3.0-flash-vl.json")) as f:
@@ -851,9 +807,6 @@ def test_ling3_cell_compiles_for_v5e_and_keeps_latent_and_state_in_place(
     arena_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in arenas)
     if program.startswith("decode"):
         impl = "gather" if program == "decode-gather" else "paged_kernel"
-        # (the kernel asks jax.default_backend(), the CPU here: steered from
-        # the test, as the paged programs' above)
-        monkeypatch.setattr(pa, "_use_interpret", lambda: False)
 
         def step(p, tok, pos, live, temperature, keys, prev_ids, *kv):
             return G._decode_pick_paged(model, p, tok, pos, live, temperature,
@@ -954,7 +907,7 @@ def test_mimo_v2_cell_compiles_for_v5e_and_keeps_both_classes_in_place(
     from bigdl_tpu.models.transformer import generate as G
     from bigdl_tpu.serving.kvcache.blocks import BlockPool, class_entries
 
-    monkeypatch.setattr(pa, "_use_interpret", lambda: False)
+    monkeypatch.setattr(pallas, "use_interpret", lambda: False)
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "benchmarks", "configs",
                            "mimo-v2-flash.json")) as f:
@@ -1084,7 +1037,7 @@ def test_glm47_cell_compiles_for_v5e_and_keeps_the_latent_arena_in_place(
     from bigdl_tpu.serving.kvcache.blocks import BlockPool
 
     # (the platform seen as a TPU: the routed layers' rule asks)
-    monkeypatch.setattr(pa, "_use_interpret", lambda: False)
+    monkeypatch.setattr(pallas, "use_interpret", lambda: False)
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "benchmarks", "configs",
                            "glm-4.7-flash.json")) as f:
@@ -1123,8 +1076,6 @@ def test_glm47_cell_compiles_for_v5e_and_keeps_the_latent_arena_in_place(
         assert arena.shape == (6, 32769, 16, 640)           # 576 -> 640 lanes
     if program.startswith("round"):
         impl = "gather" if program == "round-gather" else "paged_kernel"
-        monkeypatch.setattr(pa, "_use_interpret", lambda: False)
-
         from bigdl_tpu.serving import lm_engine
 
         def step(p, operands, hid, prev, rows):
@@ -1161,8 +1112,6 @@ def test_glm47_cell_compiles_for_v5e_and_keeps_the_latent_arena_in_place(
             asked = _scoped_vmem(kernel)
             assert asked and max(asked) < 48 << 20, asked
     elif program == "plain-decode":
-        monkeypatch.setattr(pa, "_use_interpret", lambda: False)
-
         def step(p, tok, pos, live, temperature, keys, prev_ids, rows):
             return G._decode_pick_paged(model, p, tok, pos, live, temperature,
                                         keys, prev_ids, rows,
@@ -1228,7 +1177,7 @@ def test_lm_flash_remat_train_step_compiles_for_v5e(sds, monkeypatch):
     from bigdl_tpu.nn._util import cast_f32_leaves
     from bigdl_tpu.optim import Adam
 
-    monkeypatch.setattr(fa, "_use_interpret", lambda: False)
+    monkeypatch.setattr(pallas, "use_interpret", lambda: False)
     t = 1024
     model = TransformerLM(vocab_size=32000, hidden_size=512, n_head=8,
                           n_layers=2, max_len=t, remat=True,

@@ -305,22 +305,16 @@ def test_cli_dump_writes_bundle_and_ledger_row(tmp_path):
      "unknown artifact family"),
     ("PROFILE_MEM.json", {"rows": [], "complete": True, "platform": "cpu"},
      "unknown artifact family"),
-    # the resumable contract still holds for the tuning cache
     ("TUNE_ATTN.json", {"rows": [{}], "complete": True, "platform": "cpu"},
-     None),
-    ("TUNE_ATTN.json", {"rows": [{}], "platform": "cpu"},
-     "missing boolean 'complete'"),
+     "unknown artifact family"),
     ("FLIGHT_x.json", {"flight": "probe_death"}, "flight bundle lacks"),
 ])
-def test_validate_artifact_knows_three_families(tmp_path, name, doc,
+def test_validate_artifact_knows_two_families(tmp_path, name, doc,
                                                 problem):
     path = tmp_path / name
     path.write_text(json.dumps(doc))
     probs = validate_artifact(str(path))
-    if problem is None:
-        assert probs == []
-    else:
-        assert probs and problem in probs[0]
+    assert probs and problem in probs[0]
 
 
 # --------------------------------------------------------------------- #

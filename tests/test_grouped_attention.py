@@ -148,6 +148,34 @@ def test_block_lengths_and_row_dtypes(block_len, dtype):
     _close(got, _float64(c, arenas))
 
 
+@pytest.mark.parametrize("lengths,dtype,how", [
+    ([24, 10, 15], "float32", "plain"),
+    ([24, 13, 8], "bfloat16", "plain"),
+    ([6, 1, 18], "float32", "plain"),       # ending mid-block, and position 0
+    ([24, 10, 15], "float32", "3-D query"),
+    ([24, 10, 15], "float32", "jit"),
+])
+def test_a_group_of_one_query_head(lengths, dtype, how):
+    """ONE query head a K/V head (``H == n_kv``): what ``"paged_kernel"`` runs
+    on a pool whose heads are not shared -- rows of both dtypes, chains ending
+    mid-block and at position 0, the ``(S, H, D)`` query and under ``jit``."""
+    c = _case(lengths, group=1, n_kv=2, head_dim=8, block_len=4,
+              table_width=6, dtype=jnp.dtype(dtype), seed=len(how))
+    want, arenas = _walk(c)
+    if how == "3-D query":
+        got = ga.grouped_decode_attention(
+            c["q"][:, :, 0], *arenas, c["tables"], c["lengths"],
+            layer=c["layer"], n_kv_head=2, blocks_per_step=FETCH)
+        assert got.shape == (3, 2, 8)
+        got = got[:, :, None]
+    elif how == "jit":
+        got = jax.jit(lambda *a: _kernel(c, a))(*arenas)
+    else:
+        got = _kernel(c, arenas)
+    _close(got, want)
+    _close(got, _float64(c, arenas))
+
+
 @pytest.mark.parametrize("layers,layer", [(3, 0), (3, 2), (1, 0)])
 def test_the_layer_is_an_operand_of_the_whole_arenas(layers, layer):
     """A traced layer index of arenas with several layers (the decode step's:
@@ -291,38 +319,3 @@ def test_greedy_and_sampled_streams_are_the_walks(engines, temperature):
     for got, walked in zip(out["paged_kernel"], out["gather"]):
         assert len(got) > 10
         np.testing.assert_array_equal(got, walked)
-
-
-@pytest.mark.parametrize("backend,block_len,head_dim,n_kv,resolved", [
-    ("tpu", 8, 128, 1, "paged_kernel"),
-    ("tpu", 4, 128, 1, "gather"),       # off float32's sublane tile: the walk
-    ("tpu", 8, 64, 2, "gather"),        # a head is half a lane tile: the walk
-    ("cpu", 8, 128, 1, "gather"),
-])
-def test_auto_takes_the_kernel_where_the_chip_can(monkeypatch, backend,
-                                                  block_len, head_dim, n_kv,
-                                                  resolved):
-    """``auto`` on a pool of shared K/V heads, by what the code can observe:
-    the model's head grouping, the backend and the compiled kernel's shape
-    check; asked for by name, a geometry the chip's kernel cannot take is an
-    error."""
-    from bigdl_tpu.models.transformer import LayerSpec, TransformerLM
-    from bigdl_tpu.serving import LMServingEngine
-    model = TransformerLM(64, hidden_size=32, n_head=2 * n_kv, n_layers=2,
-                          max_len=64, head_dim=head_dim, pos_encoding="none",
-                          bias=False, n_kv_head=n_kv,
-                          layer_plan=[(2, (LayerSpec(2 * n_kv, window=16),))]
-                          ).build(seed=1).evaluate()
-    monkeypatch.setattr(jax, "default_backend", lambda: backend)
-    kw = dict(slots=2, block_len=block_len, cache_len=64, prefill_buckets=(8,),
-              num_blocks=40)
-    eng = LMServingEngine(model, **kw)
-    try:
-        assert eng.decode_attn == eng.stats()["decode_attn"] == resolved
-    finally:
-        eng.close()
-    if (backend, resolved) == ("tpu", "gather"):
-        with pytest.raises(ValueError, match="multiple of 8|whole 128-lane"):
-            LMServingEngine(model, decode_attn="paged_kernel", **kw)
-    with pytest.raises(ValueError, match="requires decode_attn='gather'"):
-        LMServingEngine(model, decode_attn="paged_kernel", kv_quant="int8", **kw)

@@ -18,7 +18,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from bigdl_tpu.ops import grouped_matmul as GM
-from bigdl_tpu.ops import paged_attention as pa
+from bigdl_tpu.ops import _pallas
 from bigdl_tpu.parallel import expert as E
 
 TOL = {"float32": 1e-5, "bfloat16": 2.0 ** -6}
@@ -230,7 +230,7 @@ def test_decode_sized_programs_take_the_kernel_on_a_tpu_and_none_on_the_cpu(
     is its verify round's, Solar's its decode step's)."""
     *shape, kernel = PROGRAMS[program]
     assert E.expert_matmul_path(*shape, jnp.bfloat16) == "ragged_dot"
-    monkeypatch.setattr(pa, "_use_interpret", lambda: False)     # as on a TPU
+    monkeypatch.setattr(_pallas, "use_interpret", lambda: False)     # as on a TPU
     assert E.expert_matmul_path(*shape, jnp.bfloat16) == (
         "grouped_kernel" if kernel else "ragged_dot")
 

@@ -12,7 +12,7 @@ import jax.numpy as jnp
 
 from bigdl_tpu.nn.kda import kda_step
 from bigdl_tpu.ops import kda_step as K
-from bigdl_tpu.ops import paged_attention as pa
+from bigdl_tpu.ops import _pallas
 
 #: (R, S, H, d_k, d_v): a toy, and one slot of each cell's shape
 #: (``solar2.backlog`` 64 heads, ``ling3.longdecode`` 32, of 128 x 128)
@@ -136,11 +136,11 @@ def test_a_state_that_is_not_float32_or_heads_the_block_does_not_divide_raise():
 def test_kda_step_path_is_the_platform_and_the_shape(monkeypatch, case, shape,
                                                      expected):
     """No argument, no environment variable, no model's name: the CPU takes
-    ``kda_step``; a TPU (``_use_interpret`` false, steered here as
+    ``kda_step``; a TPU (``use_interpret`` false, steered here as
     ``tests/test_chip_compile.py`` steers it) the kernel where a head's state
     is whole tiles and the heads divide into blocks."""
     if case != "the cpu":
-        monkeypatch.setattr(pa, "_use_interpret", lambda: False)
+        monkeypatch.setattr(_pallas, "use_interpret", lambda: False)
     assert K.kda_step_path(*shape) == expected
 
 

@@ -233,37 +233,6 @@ def test_an_engine_serves_through_the_kernel_what_the_walk_serves(
         assert np.max(np.abs(got - want)) < TOL
 
 
-@pytest.mark.parametrize("backend,block_len,resolved", [
-    ("tpu", 8, "paged_kernel"),
-    ("tpu", 4, "gather"),       # off float32's sublane tile: the walk
-    ("cpu", 8, "gather"),
-])
-def test_auto_takes_the_kernel_where_the_chip_can(monkeypatch, backend,
-                                                  block_len, resolved):
-    """``auto`` on a latent pool, by what the code can observe: the backend and
-    the compiled kernel's shape check; asked for by name, a geometry the chip's
-    kernel cannot take is an error."""
-    from bigdl_tpu.models.transformer import (LayerSpec, MLASpec, RopeSpec,
-                                              TransformerLM)
-    from bigdl_tpu.serving import LMServingEngine
-    spec = LayerSpec(2, rope=RopeSpec(theta=1e4, rotary_dim=8), mixer="mla")
-    model = TransformerLM(64, hidden_size=32, n_head=2, n_layers=2, max_len=64,
-                          head_dim=16, pos_encoding="none", bias=False,
-                          mla=MLASpec(24, 16, 8, 16),
-                          layer_plan=[(2, (spec,))]).build(seed=1).evaluate()
-    monkeypatch.setattr(jax, "default_backend", lambda: backend)
-    kw = dict(slots=2, block_len=block_len, cache_len=64, prefill_buckets=(8,),
-              num_blocks=40)
-    eng = LMServingEngine(model, **kw)
-    try:
-        assert eng.decode_attn == eng.stats()["decode_attn"] == resolved
-    finally:
-        eng.close()
-    if (backend, resolved) == ("tpu", "gather"):
-        with pytest.raises(ValueError, match="multiple of 8"):
-            LMServingEngine(model, decode_attn="paged_kernel", **kw)
-
-
 # -- W query positions a slot: a verify step's candidate rows -------------------------
 def _rows_case(lengths, w, *, first=0, seed=0, **kw):
     """:func:`_case` with ``w`` new rows a slot: row i is the slot's position

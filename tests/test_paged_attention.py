@@ -1,15 +1,17 @@
-"""Paged-decode attention: the Pallas block-table kernel vs the dense
-``kc[tables]`` gather.
+"""The decode attention over a paged pool: which path a pool resolves to
+(``generate.decode_attention_path``: ONE function, from the platform and the
+pool's shapes), and that the paths are interchangeable mid-stream.
 
-The kernel reads KV blocks in place through the block table (no dense
-gather materialization); its numerics replicate the gather path's exact
-formulation (f32 cast -> scaled dot -> -1e30 position mask -> softmax),
-so the two are interchangeable mid-stream.  Fast tier-1 coverage: op
-equivalence on CPU (interpret mode) across dtypes / scrambled tables /
-mid-block positions, and engine-level token-exactness — greedy AND
-sampled streams through ``decode_attn="paged_kernel"`` must match
-offline ``generate`` bit for bit, with radix sharing on.
+The decision table takes each of the six benchmark configurations
+(``benchmarks/configs/*.json``) at its own widths, block length and dtype,
+in a pool of two blocks a class, on the CPU and seen as a TPU.  The engine
+tests hold greedy AND sampled streams through ``decode_attn="paged_kernel"``
+(a ``(k, v)`` pool at a group of ONE query head a K/V head: the grouped
+kernel, interpreted) to offline ``generate`` token for token, radix sharing
+on.  The kernels against the walk: tests/test_grouped_attention.py,
+tests/test_latent_attention.py.
 """
+import importlib
 import json
 import os
 
@@ -19,99 +21,172 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from bigdl_tpu.models.transformer import TransformerLM
-from bigdl_tpu.models.transformer.generate import _decode_step_paged, generate
-from bigdl_tpu.ops import (autotune, paged_decode_attention,
-                           paged_decode_attention_reference)
+from bigdl_tpu.models.transformer import LayerSpec, TransformerLM
+from bigdl_tpu.models.transformer.generate import (_decode_step_paged,
+                                                   decode_attention_path,
+                                                   generate)
 from bigdl_tpu.serving import LMServingEngine
-from bigdl_tpu.serving.kvcache.blocks import pack_rows
+from bigdl_tpu.serving.kvcache.blocks import BlockPool
 
-
-@pytest.fixture(autouse=True)
-def _hermetic_tune_cache(tmp_path, monkeypatch):
-    """Point the tuning cache at an empty tmp file: the repo-committed
-    TUNE_ATTN.json must never steer these tests' dispatch."""
-    monkeypatch.setenv("BIGDL_TPU_TUNE_CACHE", str(tmp_path / "tune.json"))
-    autotune.clear_cache()
-    yield
-    autotune.clear_cache()
-
-
-def _arena(slots=3, heads=2, head_dim=8, cache_len=24, block_len=4,
-           dtype=jnp.float32, seed=0, shuffle=True):
-    """Random q + paged KV arena.  Block ids are shuffled by default —
-    non-contiguous tables are the whole point of paging, and a kernel
-    that only works on arange tables is wrong."""
-    width = -(-cache_len // block_len)
-    num_blocks = slots * width + 1  # block 0 is the scratch block
-    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
-    q = jax.random.normal(ks[0], (slots, heads, head_dim), dtype)
-    # one layer's arena in the pool's layout: (N, B, W), a position row
-    # holding its heads side by side, lane-padded
-    shape = (num_blocks, block_len, heads, head_dim)
-    ka = pack_rows(jax.random.normal(ks[1], shape, dtype))
-    va = pack_rows(jax.random.normal(ks[2], shape, dtype))
-    ids = np.arange(1, slots * width + 1)
-    if shuffle:
-        np.random.RandomState(seed).shuffle(ids)
-    tables = jnp.asarray(ids.reshape(slots, width), jnp.int32)
-    return q, ka, va, tables
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 # --------------------------------------------------------------------------- #
-# op equivalence (interpret mode on CPU)                                      #
+# the decision table                                                          #
 # --------------------------------------------------------------------------- #
 
-def test_kernel_matches_reference_f32():
-    q, ka, va, tables = _arena()
-    pos = jnp.asarray([23, 9, 14], jnp.int32)
-    out = paged_decode_attention(q, ka, va, tables, pos)
-    ref = paged_decode_attention_reference(q, ka, va, tables, pos)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=1e-6, atol=1e-6)
+def _pool(model, block_len, dtype, kv_quant=None):
+    """The pool ``LMServingEngine`` builds for ``model`` (a class a kind of
+    softmax layer, or one arena of latent rows), two blocks a class."""
+    if model.latent_layers:
+        classes = [dict(n_layers=len(model.latent_layers), n_heads=1,
+                        head_dim=model.mla.row)]
+    else:
+        classes = [dict(n_layers=len(c.layers), n_heads=c.n_kv,
+                        head_dim=c.k_dim, v_dim=c.v_dim, window=c.window)
+                   for c in model.cache_classes]
+    return BlockPool(classes=classes, block_len=block_len, num_blocks=2,
+                     dtype=dtype, kv_quant=kv_quant,
+                     latent=bool(model.latent_layers))
 
 
-def test_kernel_matches_reference_bf16_arena():
-    q, ka, va, tables = _arena(dtype=jnp.bfloat16, seed=3)
-    pos = jnp.asarray([23, 12, 7], jnp.int32)
-    out = paged_decode_attention(q, ka, va, tables, pos)
-    ref = paged_decode_attention_reference(q, ka, va, tables, pos)
-    # both paths cast to f32 BEFORE every matmul; only the bf16 loads
-    # differ, so the f32 outputs agree tightly
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=1e-5, atol=1e-5)
+def _configured(name):
+    """A benchmark configuration's model (unbuilt: the plan and the widths)
+    and its pool's block length and dtype."""
+    with open(os.path.join(ROOT, "benchmarks", "configs", name + ".json")) as f:
+        c = json.load(f)
+    if c["driver"] == "serve_lm":
+        model = TransformerLM(
+            vocab_size=c["vocab_size"], hidden_size=c["n_embd"],
+            n_head=c["n_head"], n_layers=c["n_layer"],
+            max_len=c["n_positions"])
+    else:
+        model = importlib.import_module(
+            "benchmarks.drivers." + c["driver"]).build_model(c)
+    return model, c["engine"]["block_len"], jnp.dtype(
+        c["assumed"]["serve_dtype"])
 
 
-def test_mid_block_and_zero_positions_masked_identically():
-    """pos mid-block (valid prefix ends inside a page) and pos 0 (a
-    single visible token) — the -1e30 mask must hide the same tail."""
-    q, ka, va, tables = _arena(seed=1)
-    pos = jnp.asarray([5, 0, 17], jnp.int32)
-    out = paged_decode_attention(q, ka, va, tables, pos)
-    ref = paged_decode_attention_reference(q, ka, va, tables, pos)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=1e-6, atol=1e-6)
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+@pytest.mark.parametrize("name,on_a_tpu", [
+    ("gpt2-xl", "gather"),                  # a head a query head, 64 lanes
+    ("laguna-s-2.1", "paged_kernel"),       # grouped heads, windows
+    ("solar-open2-250b", "paged_kernel"),   # grouped heads
+    ("mimo-v2-flash", "paged_kernel"),      # sinks, two widths, two classes
+    ("ling-3.0-flash-vl", "paged_kernel"),  # a latent pool
+    ("glm-4.7-flash", "paged_kernel"),      # a latent pool, a drafter's layer
+])
+def test_auto_resolves_every_benchmark_configuration(monkeypatch, name,
+                                                     on_a_tpu, backend):
+    """What ``decode_attn="auto"`` resolves to in every cell: the walk on
+    the CPU, and on a TPU the pool kind's kernel wherever the softmax layers'
+    query heads share K/V heads (or the pool is latent) -- GPT-2 keeps the
+    walk on every device."""
+    model, block_len, dtype = _configured(name)
+    pool = _pool(model, block_len, dtype)
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert decode_attention_path(model, pool) == (
+        on_a_tpu if backend == "tpu" else "gather")
+    assert decode_attention_path(model, pool, "gather") == "gather"
 
 
-def test_kernel_accepts_4d_query_layout():
-    """(S, H, 1, D) — the engine's decode layout — round-trips with the
-    singleton axis preserved."""
-    q, ka, va, tables = _arena(seed=2)
-    pos = jnp.asarray([23, 9, 14], jnp.int32)
-    out4 = paged_decode_attention(q[:, :, None, :], ka, va, tables, pos)
-    out3 = paged_decode_attention(q, ka, va, tables, pos)
-    assert out4.shape == (3, 2, 1, 8)
-    np.testing.assert_allclose(np.asarray(out4[:, :, 0, :]),
-                               np.asarray(out3), rtol=1e-6, atol=1e-6)
+def _toy(kind, head_dim, n_kv):
+    """A toy whose pool is of ``kind``: K/V heads shared by two query heads
+    each under a window, or latent rows."""
+    from bigdl_tpu.models.transformer import MLASpec, RopeSpec
+    if kind == "latent":
+        spec = LayerSpec(2, rope=RopeSpec(theta=1e4, rotary_dim=8),
+                         mixer="mla")
+        return TransformerLM(64, hidden_size=32, n_head=2, n_layers=2,
+                             max_len=64, head_dim=16, pos_encoding="none",
+                             bias=False, mla=MLASpec(24, 16, 8, 16),
+                             layer_plan=[(2, (spec,))])
+    return TransformerLM(64, hidden_size=32, n_head=2 * n_kv, n_layers=2,
+                         max_len=64, head_dim=head_dim, pos_encoding="none",
+                         bias=False, n_kv_head=n_kv,
+                         layer_plan=[(2, (LayerSpec(2 * n_kv, window=16),))])
 
 
-def test_kernel_under_jit():
-    q, ka, va, tables = _arena(seed=4)
-    pos = jnp.asarray([23, 9, 14], jnp.int32)
-    out = jax.jit(paged_decode_attention)(q, ka, va, tables, pos)
-    ref = paged_decode_attention_reference(q, ka, va, tables, pos)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=1e-6, atol=1e-6)
+@pytest.mark.parametrize("kind,backend,block_len,head_dim,n_kv,resolved", [
+    ("grouped", "tpu", 8, 128, 1, "paged_kernel"),
+    ("grouped", "tpu", 4, 128, 1, "gather"),    # off float32's sublane tile
+    ("grouped", "tpu", 8, 64, 2, "gather"),     # a head is half a lane tile
+    ("grouped", "cpu", 8, 128, 1, "gather"),
+    ("latent", "tpu", 8, None, None, "paged_kernel"),
+    ("latent", "tpu", 4, None, None, "gather"),  # off the sublane tile
+    ("latent", "cpu", 8, None, None, "gather"),
+])
+def test_auto_takes_the_kernel_where_the_chip_can(monkeypatch, kind, backend,
+                                                  block_len, head_dim, n_kv,
+                                                  resolved):
+    """``auto`` by what the code can observe: the model's head grouping or
+    the pool's kind, the backend and the compiled kernel's shape check;
+    asked for by name, a geometry the chip's kernel cannot take is an
+    error."""
+    model = _toy(kind, head_dim, n_kv)
+    pool = _pool(model, block_len, jnp.float32)
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert decode_attention_path(model, pool) == resolved
+    if (backend, resolved) == ("tpu", "gather"):
+        with pytest.raises(ValueError, match="multiple of 8|whole 128-lane"):
+            decode_attention_path(model, pool, "paged_kernel")
+    else:
+        assert decode_attention_path(model, pool, "paged_kernel") == (
+            "paged_kernel")
+
+
+def _a_head_a_query_head(head_dim=128, window=None):
+    return TransformerLM(64, hidden_size=2 * head_dim, n_head=2, n_layers=2,
+                         max_len=64, head_dim=head_dim, pos_encoding="none",
+                         layer_plan=[(2, (LayerSpec(2, window=window),))])
+
+
+@pytest.mark.parametrize("window", [None, 16])
+def test_auto_keeps_the_walk_for_a_head_a_query_head(monkeypatch, window):
+    """One K/V head a query head, with or without windows, at a geometry the
+    compiled kernel takes: ``auto`` is the walk on a TPU too (no cell says
+    the kernel beats it there); asked for by name, the grouped kernel."""
+    model = _a_head_a_query_head(window=window)
+    pool = _pool(model, 8, jnp.float32)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert decode_attention_path(model, pool) == "gather"
+    assert decode_attention_path(model, pool, "paged_kernel") == "paged_kernel"
+
+
+@pytest.mark.parametrize("requested", ["auto", "gather", "paged_kernel"])
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+def test_an_int8_pool_is_gathered(monkeypatch, requested, backend):
+    """The kernels read raw blocks: an int8 pool takes the walk, and the
+    kernel asked for by name on it is an error."""
+    model, block_len, _ = _configured("solar-open2-250b")
+    pool = _pool(model, block_len, jnp.bfloat16, kv_quant="int8")
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if requested == "paged_kernel":
+        with pytest.raises(ValueError, match="requires decode_attn='gather'"):
+            decode_attention_path(model, pool, requested)
+    else:
+        assert decode_attention_path(model, pool, requested) == "gather"
+
+
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+def test_the_kernel_by_name_on_a_head_of_64_lanes(monkeypatch, backend):
+    """GPT-2's head is half a lane tile: no compiled kernel takes it, so
+    ``"paged_kernel"`` raises off the interpreter (which takes any size)."""
+    model, block_len, dtype = _configured("gpt2-xl")
+    pool = _pool(model, block_len, dtype)
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if backend == "tpu":
+        with pytest.raises(ValueError, match="whole 128-lane"):
+            decode_attention_path(model, pool, "paged_kernel")
+    else:
+        assert decode_attention_path(model, pool, "paged_kernel") == (
+            "paged_kernel")
+
+
+def test_unknown_request_is_refused():
+    model = _a_head_a_query_head()
+    with pytest.raises(ValueError, match="decode_attn must be"):
+        decode_attention_path(model, _pool(model, 8, jnp.float32), "dense")
 
 
 def test_decode_step_rejects_unknown_impl():
@@ -136,9 +211,10 @@ def _lm(vocab=31, hidden=16, heads=2, layers=1, max_len=32, seed=0):
 
 
 def test_paged_kernel_stream_token_exact_greedy_and_sampled():
-    """ACCEPTANCE: with the Pallas paged-decode kernel live (and radix
-    sharing on), greedy AND sampled streams are bit-exact vs offline
-    generate — the kernel changes memory traffic, never tokens."""
+    """ACCEPTANCE: with the pool kind's kernel live at a group of ONE
+    query head a K/V head (and radix sharing on), greedy AND sampled streams
+    are bit-exact vs offline generate — the kernel changes memory traffic,
+    never tokens."""
     m = _lm()
     eng = LMServingEngine(m, slots=2, cache_len=24, block_len=4,
                           prefill_buckets=(4, 8, 16),
@@ -176,40 +252,6 @@ def test_dense_gather_still_selectable_and_exact():
                                   5))[0]
         np.testing.assert_array_equal(
             eng.generate(p, max_new_tokens=5, timeout=120), ref)
-    finally:
-        eng.close()
-
-
-def test_auto_resolves_gather_without_tuned_verdict():
-    """No cache verdict -> the safe baseline, never the kernel."""
-    m = _lm()
-    eng = LMServingEngine(m, slots=1, cache_len=24, block_len=4,
-                          prefill_buckets=(4,))
-    try:
-        assert eng.stats()["decode_attn"] == "gather"
-    finally:
-        eng.close()
-
-
-def test_auto_resolves_kernel_from_tuned_verdict(tmp_path, monkeypatch):
-    """A matching use_kernel=True winner flips "auto" to the kernel."""
-    cache = tmp_path / "tuned.json"
-    key = autotune.paged_key(8, 4, "float32")  # head_dim 16/2, block 4
-    cache.write_text(json.dumps({
-        "device_kind": jax.devices()[0].device_kind,
-        "winners": {key: {"use_kernel": True}}}))
-    monkeypatch.setenv("BIGDL_TPU_TUNE_CACHE", str(cache))
-    autotune.clear_cache()
-    m = _lm()
-    eng = LMServingEngine(m, slots=1, cache_len=24, block_len=4,
-                          prefill_buckets=(4,))
-    try:
-        assert eng.stats()["decode_attn"] == "paged_kernel"
-        p = np.arange(1, 8)
-        ref = np.asarray(generate(m, m.params, p[None].astype(np.int32),
-                                  4))[0]
-        np.testing.assert_array_equal(
-            eng.generate(p, max_new_tokens=4, timeout=120), ref)
     finally:
         eng.close()
 

@@ -1,5 +1,5 @@
 """True int8-compute acceptance: kernels, activation quantization, the
-autotuned duel, the int8-compute drafter, and int8 KV storage.
+int8-compute drafter, and int8 KV storage.
 
 The subsystem's central claim is split into the two properties it
 actually rests on:
@@ -25,7 +25,7 @@ from bigdl_tpu.quant import (ActCalibrator, QuantPolicy,  # noqa: E402
                              fp8_supported, is_qtensor, params_compute_tag,
                              qconv, qconv_i8, qlinear, qlinear_i8, qmatmul,
                              qmatmul_i8, quantize_array, quantize_per_token,
-                             resolve_compute, set_compute_mode)
+                             set_compute_mode)
 from bigdl_tpu.serving import LMServingEngine, SpecConfig  # noqa: E402
 from bigdl_tpu.serving.kvcache.blocks import BlockPool  # noqa: E402
 
@@ -155,7 +155,7 @@ def test_fp8_gates_on_device_kind():
 def test_quant_policy_validates_compute():
     with pytest.raises(ValueError):
         QuantPolicy("int8", compute="bf16")
-    for mode in ("dequant", "int8", "auto"):
+    for mode in ("dequant", "int8"):
         assert QuantPolicy("int8", compute=mode).compute == mode
 
 
@@ -187,32 +187,24 @@ def test_dequantize_entry_keeps_compute_leaves():
     assert params_compute_tag(retag) == "int8"
 
 
-# --------------------------------------------------------------------------- #
-# the duel: autotuned int8-compute-vs-dequant verdict feeding "auto"          #
-# --------------------------------------------------------------------------- #
-
-def test_qcompute_duel_verdict_drives_auto(tmp_path, monkeypatch):
-    from bigdl_tpu.ops import autotune
-    cache = str(tmp_path / "TUNE_TEST.json")
-    monkeypatch.setenv("BIGDL_TPU_TUNE_CACHE", cache)
-    doc = autotune.autotune_qcompute([(4, 32, 48)], iters=1,
-                                     log=lambda *_: None)
-    assert doc["complete"] is True
-    key = autotune.qcompute_key(4, 32, 48)
-    entry = doc["winners"][key]
-    assert entry["use_int8"] in (True, False)
-    verdict = autotune.lookup_qcompute(4, 32, 48)
-    assert verdict == ("int8" if entry["use_int8"] else "dequant")
-    # m is the token batch: the largest-m same-(k, n) verdict applies
-    assert autotune.lookup_qcompute(999, 32, 48) == verdict
-    assert autotune.lookup_qcompute(4, 32, 49) is None
-    # "auto" resolves through the cache; a cache miss falls to dequant
-    qw = quantize_array(RNG.randn(32, 48).astype(np.float32), (0,),
-                        compute="auto")
-    assert resolve_compute(qw, (4, 32)) == verdict
-    qw_miss = quantize_array(RNG.randn(32, 49).astype(np.float32), (0,),
-                             compute="auto")
-    assert resolve_compute(qw_miss, (4, 32)) == "dequant"
+@pytest.mark.parametrize("site", ["QTensor", "QuantPolicy", "quantize",
+                                  "set_compute_mode", "SpecConfig"])
+def test_compute_auto_is_refused(site):
+    """There is no ``"auto"`` compute mode: a leaf's ``compute`` IS the
+    recipe its kernel runs (``qmatmul`` and the layers' kernels read it), so
+    every place that takes one names the recipes alone."""
+    w = RNG.randn(32, 48).astype(np.float32)
+    with pytest.raises(ValueError, match="compute"):
+        if site == "QTensor":
+            quantize_array(w, (0,), compute="auto")
+        elif site == "QuantPolicy":
+            QuantPolicy("int8", compute="auto")
+        elif site == "quantize":
+            _lm().quantize("int8", compute="auto")
+        elif site == "set_compute_mode":
+            set_compute_mode(_lm().quantize("int8").params, "auto")
+        else:
+            SpecConfig(drafter_compute="auto")
 
 
 # --------------------------------------------------------------------------- #
@@ -258,8 +250,8 @@ def test_spec_int8_compute_drafter_bitexact_with_radix_sharing():
 def test_spec_config_validates_drafter_compute():
     with pytest.raises(ValueError):
         SpecConfig(drafter_compute="bf16")
-    assert SpecConfig(drafter_compute="auto").describe()[
-        "drafter_compute"] == "auto"
+    assert SpecConfig(drafter_compute="int8").describe()[
+        "drafter_compute"] == "int8"
 
 
 # --------------------------------------------------------------------------- #

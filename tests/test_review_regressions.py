@@ -251,7 +251,7 @@ def test_validator_jit_is_cached_across_test_calls():
 def test_one_instrument():
     """PR 30: numbers come from benchmarks/run.py on a chip and nothing
     else.  The pre-chip instrument, its CPU artifacts and every pointer
-    to them stay gone (``attention_bench.py`` and its like are other
+    to them stay gone (``models/utils/lm_perf.py`` and its like are other
     files and stay)."""
     import glob
     import re
@@ -273,3 +273,41 @@ def test_one_instrument():
             hits += [f"{os.path.relpath(fn, repo)}:{i}: {line.strip()}"
                      for i, line in enumerate(f, 1) if word.search(line)]
     assert not hits, hits
+
+
+def test_one_way_to_choose_a_kernel():
+    """PR 46: a kernel is chosen by one function beside the code that
+    branches on it, from the platform and the shapes.  The tuning cache, its
+    command line, its file, its environment variable and the one-head paged
+    kernel stay gone, with every pointer to them; and no Pallas module
+    reaches into a neighbour for the helpers they share
+    (``ops/_pallas.py``)."""
+    import re
+    repo = os.path.join(os.path.dirname(__file__), os.pardir)
+    for gone in ("TUNE_ATTN.json", "bigdl_tpu/ops/autotune.py",
+                 "bigdl_tpu/ops/paged_attention.py",
+                 "bigdl_tpu/models/utils/attention_bench.py",
+                 "tests/test_autotune_attention.py",
+                 "tests/test_measurement_resume.py"):
+        assert not os.path.exists(os.path.join(repo, gone)), gone
+    word = re.compile(r"autotune|TUNE_ATTN|TUNE_CACHE|paged_decode_attention"
+                      r"|lookup_paged|lookup_qcompute|attention_bench")
+    files = [os.path.join(repo, f) for f in (
+        "chip_smoke.py", "CLAUDE.md", "README.md", "MIGRATION.md",
+        os.path.join(".claude", "skills", "verify", "SKILL.md"))]
+    for root in ("bigdl_tpu", "scripts"):
+        files += [os.path.join(d, f)
+                  for d, _, fs in os.walk(os.path.join(repo, root))
+                  for f in fs if f.endswith((".py", ".sh", ".md"))]
+    hits = []
+    for fn in files:
+        with open(fn, errors="replace") as f:
+            hits += [f"{os.path.relpath(fn, repo)}:{i}: {line.strip()}"
+                     for i, line in enumerate(f, 1) if word.search(line)]
+    assert not hits, hits
+    import importlib
+    for name in ("flash_attention", "grouped_attention", "grouped_matmul",
+                 "kda_step", "latent_attention"):
+        mod = importlib.import_module("bigdl_tpu.ops." + name)
+        assert not hasattr(mod, "_use_interpret"), name
+        assert mod._pallas.use_interpret() is True      # the CPU, here

@@ -329,12 +329,12 @@ def _eight_heads(head_dim=16):
 def test_the_engine_says_which_form_its_recurrence_takes(monkeypatch, engine):
     """``stats()["state"]["step_path"]``, resolved once at construction by
     ``ops.kda_step.kda_step_path`` -- the platform and the state's shape: the
-    CPU takes ``kda_step``; seen as a TPU (``_use_interpret`` steered false,
+    CPU takes ``kda_step``; seen as a TPU (``use_interpret`` steered false,
     as ``tests/test_chip_compile.py`` steers it) a state of 8 heads of 128 x
     128 the kernel, and the toy's 4 heads of 16 x 16 still ``kda_step``."""
-    from bigdl_tpu.ops import paged_attention as pa
+    from bigdl_tpu.ops import _pallas
     assert engine.stats()["state"]["step_path"] == "xla"
-    monkeypatch.setattr(pa, "_use_interpret", lambda: False)
+    monkeypatch.setattr(_pallas, "use_interpret", lambda: False)
     for c, path in ((_eight_heads(128), "kernel"), (toy(), "xla")):
         eng = D.build_engine(c, SEED)
         try:
